@@ -1,7 +1,7 @@
 """ctypes bindings for the native framing codec (cpp/framing.cpp).
 
-Compiled lazily via utils/native_build.py; if no compiler is available
-the pure-Python fallbacks (zlib.crc32 + bytes joins) are
+Compiled lazily via utils/native_build.py; on a host without g++ the
+pure-Python paths (zlib.crc32 + bytes joins, numpy delta/q8) are
 wire-compatible, so a C++-enabled learner host can talk to a
 Python-only actor host.
 
@@ -22,11 +22,12 @@ from ape_x_dqn_tpu.utils.native_build import build_and_load
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "cpp", "framing.cpp")
-_SO = os.path.join(os.path.dirname(_SRC), "libapex_framing.so")
 
 
 _lib: ctypes.CDLL | None = None
 _tried = False
+# per-transform switches (tests flip them to run the numpy paths as the
+# bit-exactness reference)
 _has_delta = False
 _has_q8 = False
 
@@ -39,57 +40,39 @@ def _load() -> ctypes.CDLL | None:
     global _lib, _tried, _has_delta, _has_q8
     if _tried:
         return _lib
-    lib = build_and_load(_SRC, _SO)
+    lib = build_and_load(_SRC, "libapex_framing")
     if lib is not None:
-        try:
-            # c_void_p (not c_char_p) for the data pointers so writable
-            # buffers (bytearray, numpy views) pass without a bytes copy
-            lib.apex_crc32.restype = ctypes.c_uint32
-            lib.apex_crc32.argtypes = [ctypes.c_void_p, ctypes.c_uint64,
-                                       ctypes.c_uint32]
-            lib.apex_pack.restype = ctypes.c_uint64
-            lib.apex_pack.argtypes = [
-                ctypes.c_void_p,
-                ctypes.POINTER(ctypes.c_void_p),
-                ctypes.POINTER(ctypes.c_uint64), ctypes.c_uint64]
-            lib.apex_unpack_offsets.restype = ctypes.c_uint64
-            lib.apex_unpack_offsets.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint64,
-                ctypes.POINTER(ctypes.c_uint64),
-                ctypes.POINTER(ctypes.c_uint64), ctypes.c_uint64]
-        except AttributeError:
-            lib = None  # stale .so missing a symbol: Python fallback
-    if lib is not None:
-        try:
-            # delta symbols bound separately: a stale .so predating the
-            # wire codec still serves crc/pack, and only the delta
-            # transform falls back to numpy (wire-compatible either way)
-            lib.apex_delta_encode.restype = None
-            lib.apex_delta_encode.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_uint64, ctypes.c_uint64]
-            lib.apex_delta_undo.restype = None
-            lib.apex_delta_undo.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint64]
-            _has_delta = True
-        except AttributeError:
-            _has_delta = False
-    if lib is not None:
-        try:
-            # q8 symbols likewise bound separately (param-plane codec,
-            # comm/param_codec.py): a stale .so predating it degrades
-            # only the quantizer to the bit-identical numpy fallback
-            lib.apex_q8_encode.restype = None
-            lib.apex_q8_encode.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64,
-                ctypes.c_float, ctypes.c_float]
-            lib.apex_q8_dequant_add.restype = None
-            lib.apex_q8_dequant_add.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64,
-                ctypes.c_float, ctypes.c_float]
-            _has_q8 = True
-        except AttributeError:
-            _has_q8 = False
+        # c_void_p (not c_char_p) for the data pointers so writable
+        # buffers (bytearray, numpy views) pass without a bytes copy
+        lib.apex_crc32.restype = ctypes.c_uint32
+        lib.apex_crc32.argtypes = [ctypes.c_void_p, ctypes.c_uint64,
+                                   ctypes.c_uint32]
+        lib.apex_pack.restype = ctypes.c_uint64
+        lib.apex_pack.argtypes = [
+            ctypes.c_void_p,
+            ctypes.POINTER(ctypes.c_void_p),
+            ctypes.POINTER(ctypes.c_uint64), ctypes.c_uint64]
+        lib.apex_unpack_offsets.restype = ctypes.c_uint64
+        lib.apex_unpack_offsets.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint64,
+            ctypes.POINTER(ctypes.c_uint64),
+            ctypes.POINTER(ctypes.c_uint64), ctypes.c_uint64]
+        lib.apex_delta_encode.restype = None
+        lib.apex_delta_encode.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_uint64, ctypes.c_uint64]
+        lib.apex_delta_undo.restype = None
+        lib.apex_delta_undo.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint64]
+        lib.apex_q8_encode.restype = None
+        lib.apex_q8_encode.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64,
+            ctypes.c_float, ctypes.c_float]
+        lib.apex_q8_dequant_add.restype = None
+        lib.apex_q8_dequant_add.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64,
+            ctypes.c_float, ctypes.c_float]
+        _has_delta = _has_q8 = True
     _lib, _tried = lib, True
     return _lib
 

@@ -1,9 +1,9 @@
 """ctypes bindings for the native Atari observation kernel
 (cpp/preproc.cpp).
 
-Compiled lazily via utils/native_build.py; without a toolchain,
-preproc() returns None and envs/atari.py falls back to the numpy
-pipeline, which is numerically identical (tests/test_envs.py asserts
+Compiled lazily via utils/native_build.py; on a host without g++,
+preproc() returns None and envs/atari.py uses the numpy pipeline,
+which is numerically identical (tests/test_envs.py asserts
 bit-equality) — just slower, since it materializes per-frame float
 intermediates.
 
@@ -21,12 +21,10 @@ import os
 
 import numpy as np
 
-from ape_x_dqn_tpu.utils.native_build import build_and_load, machine_tag
+from ape_x_dqn_tpu.utils.native_build import build_and_load
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "cpp", "preproc.cpp")
-_SO = os.path.join(os.path.dirname(_SRC),
-                   f"libapex_preproc.{machine_tag()}.so")
 
 
 _lib: ctypes.CDLL | None = None
@@ -41,17 +39,14 @@ def _load() -> ctypes.CDLL | None:
     global _lib, _tried
     if _tried:
         return _lib
-    lib = build_and_load(_SRC, _SO,
+    lib = build_and_load(_SRC, "libapex_preproc",
                          flags=("-march=native", "-ffp-contract=off"))
     if lib is not None:
-        try:
-            lib.apex_preproc.restype = None
-            lib.apex_preproc.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_uint64, ctypes.c_uint64,
-                ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint64]
-        except AttributeError:
-            lib = None  # stale .so missing the symbol: numpy fallback
+        lib.apex_preproc.restype = None
+        lib.apex_preproc.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_uint64, ctypes.c_uint64,
+            ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint64]
     _lib, _tried = lib, True
     return _lib
 

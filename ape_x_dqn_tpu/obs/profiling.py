@@ -11,11 +11,13 @@ turns it into live gauges riding the existing registry/JSONL surface so
   bracketed by its caller (the honest-timing contract the span tracer
   established in PR 2), so the window's wall time IS dispatch+device
   time. Combined with `compiled.cost_analysis()` FLOP / bytes-accessed
-  estimates captured at warmup, each window publishes per-stage `mfu`,
-  `hbm_bw_frac` and `device_ms` gauges. NOTE: on this image's backend
-  the compiler FLOP count omits most conv FLOPs (~0.9 vs ~47.9
-  analytic GFLOP/step — PERF.md round 4), so the live MFU gauge is a
-  LOWER bound; bench.py's analytic count stays the headline authority.
+  estimates captured at warmup, each window publishes per-stage
+  `device_ms` gauges and — only on a device whose peaks are known
+  (`device_peaks`, or the ObsConfig overrides) — `mfu` and
+  `hbm_bw_frac`. NOTE: the compiler FLOP count omits most conv FLOPs
+  (~0.9 vs ~47.9 analytic GFLOP/step — PERF.md), so the live MFU gauge
+  is a LOWER bound; bench.py's analytic count stays the headline
+  authority.
 - `CompileWatcher` — a process-global jax compile interceptor
   (jax.monitoring's backend_compile duration event) counting compiles,
   compile wall-time, and cumulative executable-cache growth. This
@@ -43,7 +45,6 @@ from __future__ import annotations
 
 import json
 import logging
-import os
 import time
 from contextlib import contextmanager
 from typing import Any, Callable
@@ -53,8 +54,11 @@ from ape_x_dqn_tpu.obs.health import make_lock
 # -- device peaks ----------------------------------------------------------
 
 # chip peak (bf16 FLOP/s, HBM bytes/s) by device_kind prefix; the MFU
-# and hbm_bw_frac denominators. Overridable via ObsConfig so a new chip
-# doesn't silently report against the wrong roof.
+# and hbm_bw_frac denominators (Google Cloud TPU documentation, per-chip
+# figures). A device_kind that is not here has NO roof: the mfu_* /
+# hbm_bw_frac_* gauges are then not published at all rather than
+# divided by a made-up number — ObsConfig.device_peak_flops /
+# device_peak_bytes_per_s are the explicit way in for a new chip.
 _PEAKS = (
     ("TPU v5p", 459e12, 2.77e12),
     ("TPU v5 lite", 197e12, 0.82e12),
@@ -63,25 +67,20 @@ _PEAKS = (
     ("TPU v3", 123e12, 0.90e12),
     ("TPU v2", 46e12, 0.70e12),
 )
-# CPU-host fallback, per core: deliberately generous (AVX-class FMA
-# throughput) so a smoke run's MFU stays a sane fraction < 1 — the CPU
-# number is a development proxy, not a claim about the host
-_CPU_PEAK_FLOPS_PER_CORE = 64e9
-_CPU_PEAK_BW = 40e9
 
 
-def device_peaks(device=None) -> tuple[float, float]:
-    """(peak FLOP/s, peak HBM bytes/s) for `device` (default: device 0)."""
+def device_peaks(device=None) -> tuple[float, float] | None:
+    """(peak FLOP/s, peak HBM bytes/s) for `device` (default: device
+    0), or None when its device_kind is not in the table."""
     import jax
 
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "cpu") or "cpu"
+    kind = device.device_kind.lower()
     for prefix, flops, bw in _PEAKS:
-        if kind.lower().startswith(prefix.lower()):
+        if kind.startswith(prefix.lower()):
             return flops, bw
-    cores = os.cpu_count() or 1
-    return cores * _CPU_PEAK_FLOPS_PER_CORE, _CPU_PEAK_BW
+    return None
 
 
 def compiled_cost(compiled) -> tuple[float, float]:
@@ -224,15 +223,20 @@ class StageProfiler:
         self._lock = make_lock("profiling.stages")
         # stage -> {"flops_per_step", "bytes_per_step", "ms"(ewma)}
         self._stages: dict[str, dict[str, float]] = {}  # guarded-by: _lock
-        self._peak_flops = peak_flops
-        self._peak_bw = peak_bw
+        self._peak_override = (peak_flops, peak_bw)
+        self._peaks_resolved: tuple[float, float] | None = None
 
     def _peaks(self) -> tuple[float, float]:
-        if not self._peak_flops or not self._peak_bw:
-            flops, bw = device_peaks()
-            self._peak_flops = self._peak_flops or flops
-            self._peak_bw = self._peak_bw or bw
-        return self._peak_flops, self._peak_bw
+        """(peak FLOP/s, peak bytes/s), resolved on first use: explicit
+        overrides win, the device_peaks() table fills the rest, and 0.0
+        means no roof is known for that axis."""
+        if self._peaks_resolved is None:
+            flops, bw = self._peak_override
+            if not (flops and bw):
+                table = device_peaks() or (0.0, 0.0)
+                flops, bw = flops or table[0], bw or table[1]
+            self._peaks_resolved = (flops, bw)
+        return self._peaks_resolved
 
     def attached(self, stage: str) -> bool:
         with self._lock:
@@ -286,35 +290,46 @@ class StageProfiler:
             flops = st["flops_per_step"] * steps
             nbytes = st["bytes_per_step"] * steps
             dev_ms = st["ms"]
-        mfu = (flops / wall_s) / peak_flops if flops else 0.0
-        bw = (nbytes / wall_s) / peak_bw if nbytes else 0.0
+        # a fraction of an unknown roof is not a number: None skips
+        # the gauge (device_ms_* never depends on a roof)
+        mfu = (flops / wall_s) / peak_flops if peak_flops else None
+        bw = (nbytes / wall_s) / peak_bw if peak_bw else None
         _publish_stage(self._obs, stage, mfu, bw, dev_ms)
 
 
-def _publish_stage(obs, stage: str, mfu: float, bw_frac: float,
-                   dev_ms: float) -> None:
+def _publish_stage(obs, stage: str, mfu: float | None,
+                   bw_frac: float | None, dev_ms: float) -> None:
     """Literal per-stage gauge emissions — spelled out per stage so the
     apexlint obs-names checker (string literals only) cross-references
-    every row both ways."""
+    every row both ways. None = no roof known, gauge not published."""
     if stage == "sample_k":
-        obs.gauge("mfu_sample_k", mfu)
-        obs.gauge("hbm_bw_frac_sample_k", bw_frac)
+        if mfu is not None:
+            obs.gauge("mfu_sample_k", mfu)
+        if bw_frac is not None:
+            obs.gauge("hbm_bw_frac_sample_k", bw_frac)
         obs.gauge("device_ms_sample_k", dev_ms)
     elif stage == "learn_k":
-        obs.gauge("mfu_learn_k", mfu)
-        obs.gauge("hbm_bw_frac_learn_k", bw_frac)
+        if mfu is not None:
+            obs.gauge("mfu_learn_k", mfu)
+        if bw_frac is not None:
+            obs.gauge("hbm_bw_frac_learn_k", bw_frac)
         obs.gauge("device_ms_learn_k", dev_ms)
     elif stage == "train":
-        obs.gauge("mfu_train", mfu)
-        obs.gauge("hbm_bw_frac_train", bw_frac)
+        if mfu is not None:
+            obs.gauge("mfu_train", mfu)
+        if bw_frac is not None:
+            obs.gauge("hbm_bw_frac_train", bw_frac)
         obs.gauge("device_ms_train", dev_ms)
     elif stage == "train_dist":
-        obs.gauge("mfu_train_dist", mfu)
-        obs.gauge("hbm_bw_frac_train_dist", bw_frac)
+        if mfu is not None:
+            obs.gauge("mfu_train_dist", mfu)
+        if bw_frac is not None:
+            obs.gauge("hbm_bw_frac_train_dist", bw_frac)
         obs.gauge("device_ms_train_dist", dev_ms)
     elif stage == "ingest":
         # staging/ship is a pure-bandwidth stage: no MFU roof
-        obs.gauge("hbm_bw_frac_ingest", bw_frac)
+        if bw_frac is not None:
+            obs.gauge("hbm_bw_frac_ingest", bw_frac)
         obs.gauge("device_ms_ingest", dev_ms)
 
 

@@ -41,17 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 def init_multihost(coordinator: str, num_processes: int,
                    process_id: int) -> None:
     """Join the JAX distributed coordination service. Must run before
-    any backend use (the CLI calls it first thing).
-
-    Honors a JAX_PLATFORMS env override through jax.config: interpreter
-    startup hooks (e.g. a sitecustomize registering an experimental TPU
-    plugin) can import jax before this runs, and the env var alone is
-    then too late — the config update still wins as long as no backend
-    has been initialized."""
-    import os
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms:
-        jax.config.update("jax_platforms", platforms)
+    any backend use (the CLI calls it first thing)."""
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

@@ -44,10 +44,10 @@ log = logging.getLogger(__name__)
 def codec_status() -> tuple[bool, str]:
     """-> (available, detail). The cold tier needs the delta+deflate
     codec from comm/native.py; `available` is False only when that
-    module genuinely fails to import (broken install), because a
-    stale/missing libapex_framing.so degrades to a bit-identical numpy
-    fallback — detail says which path is live ("native" /
-    "numpy-fallback") so ColdStore can log the one-liner."""
+    module genuinely fails to import (broken install), because a host
+    without g++ runs the bit-identical numpy codec instead — detail
+    says which path is live ("native" / "numpy-fallback") so ColdStore
+    can log the one-liner."""
     try:
         from ape_x_dqn_tpu.comm import native
     except Exception as e:  # pragma: no cover - broken install only
@@ -93,8 +93,9 @@ class ColdStore:
             raise RuntimeError(f"cold tier codec unavailable: {detail}")
         if detail != "native":
             log.warning(
-                "cold tier: libapex_framing.so missing or stale — using "
-                "the bit-identical numpy delta codec (slower, same bytes)")
+                "cold tier: no native framing library (host has no g++) — "
+                "using the bit-identical numpy delta codec (slower, same "
+                "bytes)")
         self.capacity = int(capacity_transitions)
         self.unit_items = int(unit_items)
         self.level = int(compress_level)

@@ -19,7 +19,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import socket as socket_mod
-import sys
 import threading
 import time
 
@@ -33,7 +32,8 @@ from ape_x_dqn_tpu.parallel.inference_server import (
     BatchedInferenceServer, build_serving_tier)
 from ape_x_dqn_tpu.runtime.family import (
     actor_class, family_of, server_apply_fn, warmup_example)
-from ape_x_dqn_tpu.utils.metrics import Metrics
+from ape_x_dqn_tpu.utils.compile_cache import ensure_compile_cache
+from ape_x_dqn_tpu.utils.metrics import Metrics, device_stamp
 
 
 def default_peer_id(actor_offset: int = 0) -> str:
@@ -165,15 +165,9 @@ def run_actor_host(cfg: RunConfig, host: str, port: int,
     server.update_params(params, version)
     if emitter is not None:
         emitter.start()
-    try:  # pre-compile the forward so first queries don't time out
-        server.warmup(warmup_example(family, cfg, probe.spec),
-                      extra_sizes=(cfg.actors.envs_per_actor,))
-    except (AttributeError, NotImplementedError):
-        # AOT lowering unavailable on this backend: compile lazily on
-        # first query. Anything else (shape mismatch, compile OOM) is a
-        # real bug that must surface, not a silent degraded start.
-        print("actor_host: AOT warmup unavailable; first query compiles "
-              "lazily", file=sys.stderr, flush=True)
+    # pre-compile the forward so first queries don't time out
+    server.warmup(warmup_example(family, cfg, probe.spec),
+                  extra_sizes=(cfg.actors.envs_per_actor,))
 
     # step-paced pulls (param_poll_s=None) read the live actors' frame
     # counters: refresh once the fleet advances param_pull_every frames
@@ -344,19 +338,16 @@ def run_actor_host(cfg: RunConfig, host: str, port: int,
 
 def main(argv: list[str] | None = None) -> int:
     import argparse
-    import os
+
+    import jax
 
     # actor hosts evaluate the policy on THEIR cpu (no TPU in the
-    # reference's actor machines either) — honor JAX_PLATFORMS through
-    # jax.config because interpreter-startup hooks (sitecustomize TPU
-    # plugins) may have imported jax already, making the env var alone
-    # too late (same dance as parallel/multihost.init_multihost); a
-    # co-located actor host grabbing the learner's chip would otherwise
-    # fight it for the device
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms:
-        import jax
-        jax.config.update("jax_platforms", platforms)
+    # reference's actor machines either). Selected here, before any
+    # backend use and whatever the environment says: a chip belongs to
+    # one process, and a co-located actor host that took the learner's
+    # would leave one of the two hung
+    jax.config.update("jax_platforms", "cpu")
+    ensure_compile_cache()
 
     from ape_x_dqn_tpu.configs import get_config
     from ape_x_dqn_tpu.runtime.train import apply_overrides
@@ -399,7 +390,8 @@ def main(argv: list[str] | None = None) -> int:
                          param_poll_s=args.param_poll_s,
                          peer_id=args.peer_id,
                          supervise=args.supervise)
-    print(out)
+    # the device stamp shows this host stayed off the learner's chip
+    print({**out, **device_stamp()})
     return 1 if out["errors"] else 0
 
 

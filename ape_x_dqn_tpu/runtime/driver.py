@@ -58,7 +58,8 @@ from ape_x_dqn_tpu.runtime.remediation import (
 from ape_x_dqn_tpu.runtime.sequence_learner import SequenceLearner
 from ape_x_dqn_tpu.runtime.single_process import build_replay
 from ape_x_dqn_tpu.utils.checkpoint import CheckpointManager
-from ape_x_dqn_tpu.utils.hbm import check_hbm_fits
+from ape_x_dqn_tpu.utils.hbm import (
+    check_hbm_fits, device_memory_summary)
 from ape_x_dqn_tpu.utils.metrics import (
     Metrics, Throughput, log_run_header)
 from ape_x_dqn_tpu.utils.misc import next_pow2
@@ -115,7 +116,7 @@ class ApexDriver:
         # the device BEFORE any allocation happens (utils/hbm.py; round-4
         # verdict missing #3 — a preset that outsizes its chip should
         # fail with a budget table, not an allocator abort mid-run)
-        check_hbm_fits(
+        self._hbm = check_hbm_fits(
             cfg, self.spec.obs_shape, self.spec.obs_dtype,
             param_count=sum(int(np.prod(l.shape))
                             for l in jax.tree.leaves(params)))
@@ -255,6 +256,7 @@ class ApexDriver:
         self._ship_seq = 0  # ingest thread only
         self._frames_total = 0  # guarded-by: _lock
         self._grad_steps_total = 0
+        self._last_loss: float | None = None  # learner thread, at exit
         self.actor_errors: list[tuple[int, Exception]] = []  # guarded-by: _lock
         self.actor_restarts: list[tuple[int, str]] = []  # guarded-by: _lock
         self.loop_errors: list[tuple[str, Exception]] = []  # guarded-by: _lock
@@ -1517,6 +1519,7 @@ class ApexDriver:
         last_ckpt = self._grad_steps_total
         cap = self.cfg.learner.steps_per_frame_cap
         sync_every = self.cfg.learner.target_sync_every
+        m = None
         while (not self.stop_event.is_set()
                and self._grad_steps_total < max_grad_steps):
             self.obs.beat("learner")
@@ -1642,6 +1645,11 @@ class ApexDriver:
                                    self.ingest_rows.rate(),
                                    step=self._grad_steps_total)
                 self.obs.publish(self._grad_steps_total)
+        if m is not None:
+            # the run summary reports the last step's loss: a run
+            # shorter than the 100-step log boundary above would
+            # otherwise end without ever having looked at one
+            self._last_loss = float(jax.device_get(m["loss"]))  # apexlint: host-sync(loop exit, once per run)
         # NOTE: a capture still open here (short run ending inside the
         # profile window) is closed by _learner_loop's finally
 
@@ -1718,14 +1726,14 @@ class ApexDriver:
         # self-describing JSONL: sampling semantics + storage layout
         # ride the stream itself (utils/metrics.log_run_header)
         log_run_header(self.metrics, self.cfg, self._grad_steps_total)
-        try:
-            self._warmup()
-        except (AttributeError, NotImplementedError) as e:
-            # AOT lowering genuinely unavailable on this backend/learner:
-            # first dispatches compile lazily (and hold _state_lock while
-            # they do). Anything else — shape mismatches, compile OOM —
-            # is a real bug that must surface, not a degraded start.
-            self.metrics.log(0, warmup_skipped=repr(e))
+        # what the fits-check priced and the limit it held it against
+        # (limit None / source "none" off the TPU)
+        self.metrics.log(self._grad_steps_total,
+                         hbm_budget_bytes=self._hbm.total,
+                         hbm_limit_bytes=self._hbm.limit,
+                         hbm_limit_source=self._hbm.limit_source,
+                         replay_capacity_allocated=self.capacity)
+        self._warmup()
         ingest = threading.Thread(target=self._ingest_loop, name="ingest",
                                   daemon=True)
         learner = threading.Thread(target=self._learner_loop,
@@ -1882,10 +1890,18 @@ class ApexDriver:
         out = {
             "frames": self._frames_total,
             "grad_steps": self._grad_steps_total,
+            "loss": self._last_loss,
             "avg_return": avg_ret,
             "episodes": len(self.episode_returns),
             "wall_s": time.monotonic() - t0,
             "server": self.server.stats,
+            "params_version": self.server.params_version,
+            # where the train state actually lives, and what each local
+            # device holds at teardown (memory_stats is None on CPU)
+            "state_platforms": sorted({
+                d.platform for leaf in jax.tree.leaves(self.state)
+                for d in leaf.devices()}),
+            "device_memory": device_memory_summary(),
             "ingest_dropped": self.transport.dropped + self._stage_dropped,
             # staged-drop attribution only: transport-queue drops happen
             # before the [dp, chunk] round-robin split exists

@@ -107,24 +107,8 @@ class MultihostApexDriver:
         # a 1-process fleet is valid ONLY under an initialized
         # jax.distributed runtime (the CLI's --coordinator path; the
         # driver artifact certifies the round protocol that way) —
-        # plain single-process training belongs in ApexDriver.
-        # jax.distributed.is_initialized is the public signal (jax
-        # >= 0.4.34); the private global_state probe is only a
-        # fallback for older jax, and falling back is logged so a
-        # silent False can't mask valid --coordinator runs after a
-        # jax upgrade moves the private symbol (round-4 advisor)
-        try:
-            dist_on = bool(jax.distributed.is_initialized())
-        except AttributeError:
-            import logging
-            logging.getLogger(__name__).warning(
-                "jax.distributed.is_initialized unavailable on this "
-                "jax version — probing the private global_state API")
-            try:
-                from jax._src import distributed as _dist
-                dist_on = _dist.global_state.client is not None
-            except Exception:  # noqa: BLE001 - internal-API probe only
-                dist_on = False
+        # plain single-process training belongs in ApexDriver
+        dist_on = jax.distributed.is_initialized()
         assert jax.process_count() > 1 or dist_on, \
             "MultihostApexDriver requires jax.distributed (use ApexDriver " \
             "for single-process runs)"
@@ -605,22 +589,10 @@ class MultihostApexDriver:
         # self-describing JSONL: sampling semantics + storage layout
         # ride the stream itself (utils/metrics.log_run_header)
         log_run_header(self.metrics, cfg, self._grad_steps)
-        try:
-            self._warmup(chunk_steps)
-        except (AttributeError, NotImplementedError) as e:
-            # AOT lowering genuinely unavailable: first dispatches
-            # compile lazily. Anything else is a real bug that must
-            # surface, not a degraded start (mirrors ApexDriver.run).
-            self.metrics.log(0, warmup_skipped=repr(e))
-        try:
-            self.server.warmup(
-                warmup_example(self.family, cfg, self.spec),
-                extra_sizes=(cfg.actors.envs_per_actor,))
-        except (AttributeError, NotImplementedError) as e:
-            # same degradation as the learner warmup above and the
-            # actor_host path: no AOT lowering -> lazy first-query
-            # compiles (anything else must surface)
-            self.metrics.log(0, server_warmup_skipped=repr(e))
+        self._warmup(chunk_steps)
+        self.server.warmup(
+            warmup_example(self.family, cfg, self.spec),
+            extra_sizes=(cfg.actors.envs_per_actor,))
         evaluator = None
         if (jax.process_index() == 0 and cfg.eval_every_steps > 0
                 and cfg.eval_episodes > 0):

@@ -26,7 +26,8 @@ import sys
 from typing import Any
 
 from ape_x_dqn_tpu.configs import PRESETS, RunConfig, get_config
-from ape_x_dqn_tpu.utils.metrics import Metrics
+from ape_x_dqn_tpu.utils.compile_cache import ensure_compile_cache
+from ape_x_dqn_tpu.utils.metrics import Metrics, device_stamp
 
 
 def _coerce(value: str, ref: Any) -> Any:
@@ -128,12 +129,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="jax.distributed coordinator address")
     ap.add_argument("--num-processes", type=int, default=None)
     ap.add_argument("--process-id", type=int, default=None)
-    ap.add_argument("--compilation-cache-dir", default=None,
-                    metavar="DIR",
-                    help="persistent XLA compilation cache: the hot "
-                         "jits compile once per (shape, topology) and "
-                         "every later run/restart/resume loads them in "
-                         "milliseconds instead of 20-40s per graph")
     ap.add_argument("--set", action="append", default=[],
                     metavar="dotted.key=value",
                     help="override any config field, e.g. "
@@ -142,16 +137,11 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: list[str] | None = None) -> int:
+    # before any backend compiles: resumed/preempted runs then load
+    # the warm-up graphs instead of recompiling them
+    ensure_compile_cache()
     parser = build_parser()
     args = parser.parse_args(argv)
-    if args.compilation_cache_dir:
-        # must be set before any backend compiles; resumed/preempted
-        # runs then skip straight past the warmup compiles
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          args.compilation_cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1.0)
     if args.coordinator is not None:
         if args.num_processes is None or args.process_id is None:
             parser.error("--coordinator requires --num-processes and "
@@ -241,7 +231,14 @@ def main(argv: list[str] | None = None) -> int:
         out["loop_errors"] = [f"{which}: {e!r}"
                               for which, e in out["loop_errors"]]
     metrics.close()
+    # the summary names the device its numbers came from
+    out = {**out, **device_stamp()}
     print(json.dumps(out))
+    if out.get("grad_steps") == 0:
+        # e.g. the wall-clock limit expired during fill: a training run
+        # that never took a step has not succeeded
+        print("train: run ended with 0 grad steps", file=sys.stderr)
+        return 1
     failed = bool(out.get("actor_errors") or out.get("loop_errors"))
     return 1 if failed else 0
 

@@ -127,6 +127,18 @@ class Metrics:
                 self._tb = None
 
 
+def device_stamp() -> dict[str, Any]:
+    """The device a run's numbers came from, as JAX reports it. Rides
+    the run header and the CLI summary so no result is read without
+    knowing whether a chip or the CPU backend produced it."""
+    import jax
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
+
+
 def log_run_header(metrics: "Metrics", cfg: Any, step: int = 0) -> None:
     """First-record run description (SURVEY.md §5 metrics/logging).
 
@@ -154,7 +166,8 @@ def log_run_header(metrics: "Metrics", cfg: Any, step: int = 0) -> None:
         replay_capacity=cfg.replay.capacity,
         batch_size=cfg.learner.batch_size,
         train_chunk=cfg.learner.train_chunk,
-        dp=cfg.parallel.dp, tp=cfg.parallel.tp)
+        dp=cfg.parallel.dp, tp=cfg.parallel.tp,
+        **device_stamp())
 
 
 # Atari-57 human / random score table for the human-normalized-score (HNS)

@@ -2,23 +2,30 @@
 
 The runtime's native pieces (cpp/framing.cpp wire codec, cpp/preproc.cpp
 observation kernel) compile with g++ on first use and cache the .so next
-to the source; without a toolchain the callers fall back to numpy/zlib
-paths that are wire/bit compatible. This module owns the
-concurrency-sensitive scaffolding once — per-pid temp + atomic rename
-(concurrent first use across processes must not cache a corrupt .so),
-temp cleanup on failed/timed-out compiles, mtime staleness, one-shot
-caching — so the per-component bindings don't each re-implement it.
+to the source. The .so is named by a hash of the source bytes and the
+full compiler command line (plus `machine_tag()` for -march=native
+builds), so a binary is only ever loaded if it was built from exactly
+this source with exactly these flags: one copied in from another
+checkout, or left behind by an older source, has another name and is
+never looked at. A host WITHOUT g++ gets None and the callers use their
+numpy/zlib paths, which are wire/bit compatible; with g++ present a
+failed build is a build break and raises.
+
+This module owns the concurrency-sensitive scaffolding once — per-pid
+temp + atomic rename (concurrent first use across processes must not
+cache a corrupt .so), temp cleanup on failed/timed-out compiles,
+one-shot caching — so the per-component bindings don't each
+re-implement it.
 
 Every build runs with -Wall -Wextra -Werror: the native modules are
 small enough that zero-warning is cheap to hold, and a warning in a
 memcpy/pointer-arithmetic data plane is usually a bug report.
 
 APEX_NATIVE_SANITIZE=1 additionally compiles with
--fsanitize=address,undefined for local debugging runs. Sanitized
-builds land in a separate `<name>.san.so` artifact so they can never
-poison the normal build cache. Loading one into a non-ASan python
-needs the process launched with the runtime preloaded (or the ASan
-link-order check relaxed), e.g.:
+-fsanitize=address,undefined for local debugging runs (the flags are
+part of the name hash, so a sanitized build never serves a normal run).
+Loading one into a non-ASan python needs the process launched with the
+runtime preloaded (or the ASan link-order check relaxed), e.g.:
 
     LD_PRELOAD=$(gcc -print-file-name=libasan.so) \
         APEX_NATIVE_SANITIZE=1 python ...
@@ -36,6 +43,7 @@ import ctypes
 import hashlib
 import os
 import platform
+import shutil
 import subprocess
 import threading
 
@@ -51,22 +59,29 @@ def _sanitize() -> bool:
     return os.environ.get("APEX_NATIVE_SANITIZE", "") not in ("", "0")
 
 
-def build_and_load(src: str, so: str,
-                   flags: tuple[str, ...] = ()) -> ctypes.CDLL | None:
-    """Compile src -> so with g++ (if missing/stale) and dlopen it.
+def _so_path(src: str, name: str, flags: tuple[str, ...]) -> str:
+    """`<src dir>/<name>.<hash of source bytes + flags>[.<machine>].so`"""
+    with open(src, "rb") as fh:
+        digest = hashlib.sha256(
+            fh.read() + "\0".join(flags).encode()).hexdigest()[:12]
+    tag = f".{machine_tag()}" if "-march=native" in flags else ""
+    return os.path.join(os.path.dirname(src), f"{name}.{digest}{tag}.so")
 
-    Returns None when no compiler is available or the build fails —
-    callers fall back to their pure-Python implementations. The result
-    (including None) is cached per so-path for the process lifetime.
+
+def build_and_load(src: str, name: str,
+                   flags: tuple[str, ...] = ()) -> ctypes.CDLL | None:
+    """Compile src with g++ (unless this exact build exists) and dlopen
+    it.
+
+    Returns None only when the host has no g++ (callers fall back to
+    their pure-Python implementations) or for an unloadable sanitized
+    build; a compile that fails raises. The result (including None) is
+    cached per so-path for the process lifetime.
     """
-    extra: tuple[str, ...] = WARNING_FLAGS
+    flags = ("-O3", *WARNING_FLAGS, *flags)
     load_ok = True
     if _sanitize():
-        # distinct artifact name: a sanitized .so must never be picked
-        # up by a later non-sanitized run's mtime check (or vice versa)
-        root, ext = os.path.splitext(so)
-        so = f"{root}.san{ext}"
-        extra = extra + SANITIZE_FLAGS
+        flags += SANITIZE_FLAGS
         # dlopen'ing an ASan .so into a python that wasn't started with
         # the runtime preloaded (or the link-order check relaxed) makes
         # the ASan init ABORT the whole process — and it snapshots the
@@ -82,33 +97,36 @@ def build_and_load(src: str, so: str,
             import sys
             print(
                 "[native-build] APEX_NATIVE_SANITIZE=1 but the ASan "
-                "runtime is not loadable in this process; built "
-                f"{os.path.basename(so)} but using the Python "
-                "fallback. Relaunch with LD_PRELOAD=$(gcc "
-                "-print-file-name=libasan.so) or "
+                "runtime is not loadable in this process; building "
+                f"{name} but using the Python fallback. Relaunch with "
+                "LD_PRELOAD=$(gcc -print-file-name=libasan.so) or "
                 "ASAN_OPTIONS=verify_asan_link_order=0.",
                 file=sys.stderr)
+    so = _so_path(src, name, flags)
     with _lock:
         if so in _cache:
             return _cache[so]
-        lib = None
-        tmp = f"{so}.{os.getpid()}"
-        try:
-            if (not os.path.exists(so)
-                    or os.path.getmtime(so) < os.path.getmtime(src)):
-                subprocess.run(
-                    ["g++", "-O3", *extra, *flags, "-shared", "-fPIC",
-                     src, "-o", tmp],
-                    check=True, capture_output=True, timeout=120)
-                os.replace(tmp, so)
-            lib = ctypes.CDLL(so) if load_ok else None
-        except (OSError, subprocess.SubprocessError):
-            lib = None
-        finally:
+        if not os.path.exists(so):
+            if shutil.which("g++") is None:
+                _cache[so] = None
+                return None
+            tmp = f"{so}.{os.getpid()}"
             try:
-                os.unlink(tmp)  # leftover from a failed/killed compile
-            except OSError:
-                pass
+                proc = subprocess.run(
+                    ["g++", *flags, "-shared", "-fPIC", src, "-o", tmp],
+                    capture_output=True, text=True, timeout=120)
+                if proc.returncode != 0:
+                    raise RuntimeError(
+                        f"native build of {os.path.basename(src)} failed "
+                        f"(g++ exit {proc.returncode}):\n"
+                        f"{proc.stderr[-4000:]}")
+                os.replace(tmp, so)
+            finally:
+                try:
+                    os.unlink(tmp)  # leftover of a failed/killed compile
+                except OSError:
+                    pass
+        lib = ctypes.CDLL(so) if load_ok else None
         _cache[so] = lib
         return lib
 
@@ -117,10 +135,10 @@ def machine_tag() -> str:
     """Stable per-CPU-model tag for arch-specific builds.
 
     -march=native binaries cached on a shared filesystem (NFS home,
-    cluster checkout) would SIGILL on hosts with a different ISA —
-    CDLL succeeds, so no graceful fallback fires. Embedding this tag
-    in the .so name gives identical CPUs a shared cache and everything
-    else its own build.
+    cluster checkout, a tree copied to another machine) would SIGILL
+    on hosts with a different ISA — CDLL succeeds, so nothing catches
+    it. Embedding this tag in the .so name gives identical CPUs a
+    shared cache and everything else its own build.
     """
     try:
         with open("/proc/cpuinfo") as fh:

@@ -2,9 +2,9 @@
 # Chunked test-suite runner: one pytest process per test file.
 #
 # Why: the documented one-command `pytest tests/` invocation
-# reproducibly SIGSEGVs at ~85% inside XLA's backend_compile_and_load
-# on this image (VERDICT.md round 5) — an accumulation crash in the
-# long-lived XLA CPU client, not a test failure. Running each file in
+# has reproducibly SIGSEGVed at ~85% inside XLA's
+# backend_compile_and_load — an accumulation crash in the long-lived
+# XLA CPU client, not a test failure. Running each file in
 # its own interpreter bounds per-process compile-cache growth and makes
 # the full tier-2 suite (including -m slow, if you drop the filter)
 # completable in one command. The tier-1 command in ROADMAP.md stays
@@ -92,17 +92,17 @@ elif ! python -m ape_x_dqn_tpu.obs.report LEARN_HEALTH_SMOKE.jsonl --check; then
     failed_files+=("obs.report LEARN_HEALTH_SMOKE.jsonl --check")
 fi
 
-# Multi-chip smoke: dp=1,2 over virtual devices (the lane
-# self-provisions --xla_force_host_platform_device_count in child
-# processes). Proves the sharded ingest/train path end-to-end and
+# Multi-chip smoke: dp=1,2 over virtual devices, asked for by name
+# (the lane then provisions --xla_force_host_platform_device_count in
+# its child processes; without "virtual:" it insists on real devices). Proves the sharded ingest/train path end-to-end and
 # anti-ratchets dp-scaling efficiency against the last comparable
 # (same dp set, same device mode) MULTICHIP_SMOKE.json — incomparable
 # baselines are skipped, never compared across shapes.
 echo
-echo "=== bench.py --multichip dp=1,2 --smoke"
-if ! python bench.py --multichip dp=1,2 --smoke --perf-gate; then
+echo "=== bench.py --multichip virtual:dp=1,2 --smoke"
+if ! python bench.py --multichip virtual:dp=1,2 --smoke --perf-gate; then
     fail=1
-    failed_files+=("bench.py --multichip dp=1,2 --smoke")
+    failed_files+=("bench.py --multichip virtual:dp=1,2 --smoke")
 fi
 
 # Tiered-replay smoke: the eviction-swap A/B + capacity soak

@@ -17,7 +17,7 @@ from ape_x_dqn_tpu.obs.core import NULL_OBS, build_obs
 from ape_x_dqn_tpu.utils.metrics import Metrics
 
 
-def _smoke_cfg(enabled: bool = True):
+def _smoke_cfg(enabled: bool = True, **obs_kw):
     """Catch smoke at test_obs.py's shapes: sample_chunk=2 routes the
     observed run through the split sample_k/learn_k macro-dispatch."""
     return get_config("pong").replace(
@@ -29,8 +29,20 @@ def _smoke_cfg(enabled: bool = True):
         learner=LearnerConfig(batch_size=16, n_step=3,
                               target_sync_every=16, sample_chunk=2),
         obs=ObsConfig(enabled=enabled, publish_every_steps=50,
-                      heartbeat_timeout_s=120.0),
+                      heartbeat_timeout_s=120.0, **obs_kw),
     )
+
+
+class _RecorderObs:
+    def __init__(self):
+        self.counts: dict = {}
+        self.gauges: dict = {}
+
+    def count(self, name, n=1.0):
+        self.counts[name] = self.counts.get(name, 0.0) + n
+
+    def gauge(self, name, value):
+        self.gauges[name] = value
 
 
 # -- device-time attribution / roofline gauges ------------------------------
@@ -38,14 +50,18 @@ def _smoke_cfg(enabled: bool = True):
 def test_mfu_gauges_on_catch_smoke(tmp_path):
     """The live roofline: a real observed catch run publishes per-stage
     mfu/hbm_bw_frac/device_ms gauges with sane values (0 < mfu < 1
-    needs cost_analysis FLOPs AND a detected peak), and the offline
-    report renders the roofline section from the same JSONL."""
+    needs cost_analysis FLOPs AND a peak — the CPU test device has no
+    table row, so the roof comes in through the explicit ObsConfig
+    overrides), and the offline report renders the roofline section
+    from the same JSONL."""
     from ape_x_dqn_tpu.obs import report
     from ape_x_dqn_tpu.runtime.single_process import train_single_process
 
     jsonl = str(tmp_path / "run.jsonl")
     metrics = Metrics(log_path=jsonl)
-    out = train_single_process(_smoke_cfg(), total_env_frames=420,
+    cfg = _smoke_cfg(device_peak_flops=512e9,
+                     device_peak_bytes_per_s=40e9)
+    out = train_single_process(cfg, total_env_frames=420,
                                metrics=metrics, train_every=2)
     metrics.close()
     assert out["grad_steps"] > 0
@@ -68,6 +84,25 @@ def test_mfu_gauges_on_catch_smoke(tmp_path):
     assert "roofline" in text
     assert "sample_k" in text and "learn_k" in text
     assert "compile telemetry:" in text
+
+
+def test_no_roof_for_unknown_device():
+    """A device_kind outside the peaks table (the CPU test device) has
+    no roof: device_peaks() says so, and without an explicit override
+    StageProfiler publishes device_ms_* but no mfu_* / hbm_bw_frac_*
+    gauge — a CPU run never writes under a device metric's name."""
+    from ape_x_dqn_tpu.obs.profiling import StageProfiler, device_peaks
+
+    assert device_peaks() is None
+    sink = _RecorderObs()
+    prof = StageProfiler(sink)
+    prof.record("train", 0.01, steps=4)
+    prof.record("ingest", 0.01)
+    assert set(sink.gauges) == {"device_ms_train", "device_ms_ingest"}
+    sink = _RecorderObs()
+    prof = StageProfiler(sink, peak_flops=1e12)  # one axis overridden
+    prof.record("train", 0.01, steps=4)
+    assert set(sink.gauges) == {"device_ms_train", "mfu_train"}
 
 
 def test_stage_profiler_cost_analysis_present():
@@ -109,18 +144,6 @@ def test_compile_watcher_counts_fresh_jit():
     assert n1 > n0
     assert s1 > s0
     assert watcher.entries == n1  # monotonic compile-work ledger
-
-
-class _RecorderObs:
-    def __init__(self):
-        self.counts: dict = {}
-        self.gauges: dict = {}
-
-    def count(self, name, n=1.0):
-        self.counts[name] = self.counts.get(name, 0.0) + n
-
-    def gauge(self, name, value):
-        self.gauges[name] = value
 
 
 def test_compile_telemetry_publishes_delta_only():
