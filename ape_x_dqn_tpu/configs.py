@@ -467,14 +467,18 @@ class ObsConfig:
     telemetry_every_s: float = 2.0
     # -- continuous perf plane (obs/profiling.py, ISSUE 8) --------------
     # live roofline gauges (per-stage mfu / hbm_bw_frac / device_ms):
-    # default ON with obs — they reuse the block_until_ready sync
-    # points the span tracer already pays for, so they add no new
-    # device synchronization and touch no jit
+    # default ON with obs, and they touch no jit. A gauge is published
+    # from a block_until_ready-bracketed window around its stage: the
+    # single-process trainer brackets every stage when obs is on; the
+    # threaded driver (runtime/driver.py) brackets nothing by default,
+    # so its train/ingest gauges appear only with profile_windows
     profile_gauges: bool = True
-    # EXTRA sampling windows on paths that are otherwise async (the
-    # zero-copy ingest ship): default OFF — enabling inserts a
-    # block_until_ready every profile_window_every-th ship, trading a
-    # sliver of pipeline overlap for honest ingest device time
+    # sampled windows on the threaded driver's async paths (the
+    # learner's train dispatch and the zero-copy ingest ship): default
+    # OFF — enabling brackets every profile_window_every-th dispatch
+    # and ship with a block_until_ready, made after the state lock is
+    # released, trading a sliver of pipeline overlap for honest device
+    # time per stage
     profile_windows: bool = False
     profile_window_every: int = 16
     # jit-compile interceptor (jit_compiles / jit_compile_ms counters

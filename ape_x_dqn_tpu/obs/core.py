@@ -33,7 +33,7 @@ from ape_x_dqn_tpu.obs.blackbox import NULL_BLACKBOX, FlightRecorder
 from ape_x_dqn_tpu.obs.health import (
     HeartbeatRegistry, HeartbeatWatchdog, StallError)
 from ape_x_dqn_tpu.obs.registry import MetricRegistry, geometric_edges
-from ape_x_dqn_tpu.obs.trace import NULL_TRACER, SpanTracer
+from ape_x_dqn_tpu.obs.trace import NULL_SPAN, NULL_TRACER, SpanTracer
 
 AGE_EDGES = geometric_edges(1.0, 1e6, per_decade=4)
 LAG_EDGES = geometric_edges(1.0, 1e5, per_decade=4)
@@ -61,10 +61,14 @@ class NullObs:
     blackbox = NULL_BLACKBOX
 
     def span(self, name: str, **args: Any):
-        return NULL_TRACER.span(name)
+        return NULL_SPAN
+
+    def record(self, name: str, t0: float, t1: float,
+               **args: Any) -> None:
+        pass
 
     def stage_window(self, stage: str, steps: int = 1):
-        return NULL_TRACER.span(stage)
+        return NULL_SPAN
 
     def stage_attach(self, stage: str, steps: int = 1,
                      compiled: Any = None, compile_fn=None) -> None:
@@ -278,6 +282,10 @@ class Obs:
     def span(self, name: str, **args: Any):
         return self.tracer.span(name, **args)
 
+    def record(self, name: str, t0: float, t1: float,
+               **args: Any) -> None:
+        self.tracer.record(name, t0, t1, **args)
+
     def mark(self, name: str, **args: Any) -> None:
         self.tracer.mark(name, **args)
 
@@ -363,7 +371,7 @@ class Obs:
         hbm_bw_frac / device_ms gauges on exit. No-op context when the
         roofline gauges are knob-disabled."""
         if self.profiler is None:
-            return NULL_TRACER.span(stage)
+            return NULL_SPAN
         return self.profiler.window(stage, steps)
 
     def stage_attach(self, stage: str, steps: int = 1,

@@ -196,6 +196,27 @@ class WitnessLock:
         return f"WitnessLock({self.name!r})"
 
 
+class TimedLock:
+    """`with TimedLock(lock, span):` is `with lock:` whose wait — from
+    asking for the lock to holding it — runs inside `span` (an
+    obs.span(...) context). `lock` is whatever make_lock returned: a
+    WitnessLock still sees the acquisition."""
+
+    __slots__ = ("_lock", "_wait")
+
+    def __init__(self, lock, wait_span):
+        self._lock = lock
+        self._wait = wait_span
+
+    def __enter__(self) -> "TimedLock":
+        with self._wait:
+            self._lock.acquire()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()
+
+
 def make_lock(name: str):
     """Runtime lock factory: a plain threading.Lock in production, a
     WitnessLock feeding the global order recorder when

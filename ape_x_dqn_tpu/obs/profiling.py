@@ -7,10 +7,12 @@ turns it into live gauges riding the existing registry/JSONL surface so
 
 - `StageProfiler` — per-jit wall-time windows around the split
   sample_k/learn_k stages (and the fused train dispatch + ingest
-  staging). Every observed stage is already `jax.block_until_ready`-
-  bracketed by its caller (the honest-timing contract the span tracer
-  established in PR 2), so the window's wall time IS dispatch+device
-  time. Combined with `compiled.cost_analysis()` FLOP / bytes-accessed
+  staging). Every window is `jax.block_until_ready`-bracketed by its
+  caller, so its wall time IS dispatch+device time: the single-process
+  trainer brackets each stage whenever obs is on, the threaded driver
+  only a sampled 1-in-N of its dispatches and ships
+  (ObsConfig.profile_windows), after it has released the state lock.
+  Combined with `compiled.cost_analysis()` FLOP / bytes-accessed
   estimates captured at warmup, each window publishes per-stage
   `device_ms` gauges and — only on a device whose peaks are known
   (`device_peaks`, or the ObsConfig overrides) — `mfu` and
@@ -33,11 +35,12 @@ turns it into live gauges riding the existing registry/JSONL surface so
   the PR 6 telemetry frames per-peer on the learner (peer attribution
   rides the event).
 
-Gauges are default-on when obs is enabled (they reuse the sync points
-the span tracer already pays for); the extra sampling windows on the
-async ingest ship path are default-off (ObsConfig.profile_windows) so
-the zero-copy pipeline's overlap — and every jit — stays untouched
-unless explicitly asked for. Disabled obs routes through NullObs and
+Gauges are default-on when obs is enabled; the windows that feed them
+on the threaded driver's async paths (train dispatch, ingest ship) are
+sampled and default-off (ObsConfig.profile_windows), so the learner's
+queued dispatches, the zero-copy pipeline's overlap — and every jit —
+stay untouched unless explicitly asked for. Disabled obs routes
+through NullObs and
 never imports this module's jax hooks at all.
 """
 
@@ -212,9 +215,9 @@ STAGES = ("sample_k", "learn_k", "train", "train_dist", "ingest")
 
 class StageProfiler:
     """Wall-time windows + cost-analysis roofs for the learner's
-    device stages. Callers guarantee the window body is
-    block_until_ready-bracketed (the existing span-tracer contract),
-    so window wall time is honest dispatch+device time."""
+    device stages. Callers guarantee the window body ends in a
+    block_until_ready, so window wall time is honest dispatch+device
+    time."""
 
     def __init__(self, obs, peak_flops: float = 0.0,
                  peak_bw: float = 0.0, ewma_alpha: float = 0.25):
@@ -398,7 +401,9 @@ class PerfMonitor:
         baseline = 0.0
         with self._lock:
             s = self._series.setdefault((peer, name), {
-                "ewma": value, "n": 0, "last_fire": 0.0})
+                # -inf, not 0.0: monotonic() starts near host boot, so
+                # a 0.0 seed would mute the first cooldown_s of uptime
+                "ewma": value, "n": 0, "last_fire": float("-inf")})
             baseline = s["ewma"]
             degraded = (s["n"] >= self._min_samples
                         and baseline > 0.0

@@ -614,9 +614,11 @@ def _fmt_slo(summary: dict[str, Any]) -> list[str]:
 
 
 # stage -> (mfu gauge, bw gauge, ewma-ms gauge, host span carrying the
-# stage's total wall time). The span totals give the honest device-time
-# SHARE (every window is block_until_ready-bracketed by contract);
-# the gauges give the per-dispatch roofline position.
+# stage's total wall time). The span totals give the time SHARE (the
+# single-process trainer syncs inside each span; the threaded driver's
+# learner.train / replay.add spans are host dispatch time, its synced
+# windows are the sampled ObsConfig.profile_windows ones); the gauges
+# give the per-dispatch roofline position.
 _ROOFLINE_STAGES = (
     ("sample_k", "mfu_sample_k", "hbm_bw_frac_sample_k",
      "device_ms_sample_k", "replay.sample"),

@@ -11,10 +11,20 @@ so the actor/ingest/learner overlap (or lack of it) is readable at a
 glance.
 
 Design constraints:
-- Low overhead: a span costs two `perf_counter` calls and one
-  lock-guarded list append; nothing is formatted or written until
-  `close()`. A bounded buffer (`max_events`) caps memory on long runs
-  — once full, new events are counted as dropped, never resized.
+- Low overhead: a span costs two `perf_counter` calls, one
+  `jax.profiler.TraceAnnotation` (a flag test outside a profiler
+  session) and one lock-guarded append of plain values; nothing is
+  formatted or written until `close()`. A bounded buffer (`max_events`) caps memory
+  on long runs — once full, new events are counted as dropped, never
+  resized.
+- One clock with the device: inside a `jax.profiler` session every
+  span also lands on the host plane of the xplane as `apex.<name>`, so
+  a device idle gap can be laid against the host span that covers it.
+  Aggregates and the JSON file keep the bare name.
+- Cross-thread intervals: `record(name, t0, t1)` folds an interval
+  that starts on one thread and ends on another (a request's queue
+  wait) into the aggregates and the JSON; it has no single thread to
+  annotate, so it carries no profiler annotation.
 - Fused stages: stages that execute INSIDE one XLA dispatch (the
   priority write-back and target sync live inside the learn jit)
   cannot be timed from the host; `mark()` emits a zero-ish-duration
@@ -26,7 +36,9 @@ Design constraints:
   stage-time breakdown (obs/report.py) without parsing the trace file.
 
 The no-op twin `NullTracer` keeps every call site branch-free when
-tracing is off (ObsConfig.trace_path empty / obs disabled).
+tracing is off (ObsConfig.trace_path empty / obs disabled): `span`
+hands back the one preallocated `NULL_SPAN`, so a disabled span site
+allocates nothing.
 """
 
 from __future__ import annotations
@@ -35,8 +47,24 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Iterator
+from typing import Any
+
+ANNOTATION_PREFIX = "apex."
+
+
+class _NullSpan:
+    """The context manager every disabled span site shares."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NULL_SPAN = _NullSpan()
 
 
 class NullTracer:
@@ -44,9 +72,12 @@ class NullTracer:
 
     enabled = False
 
-    @contextmanager
-    def span(self, name: str, **args: Any) -> Iterator[None]:
-        yield
+    def span(self, name: str, **args: Any) -> _NullSpan:
+        return NULL_SPAN
+
+    def record(self, name: str, t0: float, t1: float,
+               **args: Any) -> None:
+        pass
 
     def mark(self, name: str, **args: Any) -> None:
         pass
@@ -65,6 +96,28 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
+class _Span:
+    """One open span: the profiler annotation and the host stamps."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_annotation", "_t0")
+
+    def __init__(self, tracer: "SpanTracer", name: str, args: dict):
+        self._tracer = tracer
+        self._name = name
+        self._args = args
+        self._annotation = tracer._annotate(ANNOTATION_PREFIX + name)
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._tracer._record(self._name, self._t0, t1, self._args)
+        return False
+
+
 class SpanTracer:
     """Thread-safe span recorder writing one `trace_event` JSON file.
 
@@ -81,22 +134,38 @@ class SpanTracer:
         self._path = path
         self._max = max_events
         self._lock = threading.Lock()
-        self._events: list[dict] = []
+        # events as five parallel columns of plain values (args dicts
+        # of plain values are not GC-tracked either): a retained tuple
+        # or dict per event is a GC-tracked survivor, and thousands a
+        # second of those bring on full collections — 130 ms pauses
+        # and 4% of pong_live's throughput on the v5e host (PERF.md)
+        self._ev_name: list[str] = []
+        self._ev_t0: list[float] = []
+        self._ev_dur: list[float] = []
+        self._ev_tid: list[int] = []
+        self._ev_args: list[dict | None] = []
         self._dropped = 0
         self._thread_names: dict[int, str] = {}
         self._peer_tids: dict[str, int] = {}  # synthetic remote tracks
         self._agg: dict[str, list[float]] = {}  # name -> [count, total, max]
         self._t0 = time.perf_counter()
+        self._pid = os.getpid()
         self._closed = False
+        # jax only when a tracer is built: this module stays importable
+        # by processes that never touch a backend
+        import jax.profiler
 
-    @contextmanager
-    def span(self, name: str, **args: Any) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            self._record(name, t0, t1, args)
+        self._annotate = jax.profiler.TraceAnnotation
+
+    def span(self, name: str, **args: Any) -> _Span:
+        return _Span(self, name, args)
+
+    def record(self, name: str, t0: float, t1: float,
+               **args: Any) -> None:
+        """Fold an interval measured by the caller (`perf_counter`
+        stamps), e.g. one that starts on the thread that enqueues a
+        request and ends on the thread that collects it."""
+        self._record(name, t0, t1, args)
 
     def mark(self, name: str, **args: Any) -> None:
         """Instant-ish event for a stage fused inside a device dispatch
@@ -130,11 +199,7 @@ class SpanTracer:
         local = tid is None
         if local:
             tid = threading.get_ident()
-        ev = {"name": name, "cat": "apex", "ph": "X",
-              "ts": (t0 - self._t0) * 1e6, "dur": (t1 - t0) * 1e6,
-              "pid": os.getpid(), "tid": tid}
-        if args:
-            ev["args"] = args
+        dur = t1 - t0
         with self._lock:
             if local and tid not in self._thread_names:
                 self._thread_names[tid] = threading.current_thread().name
@@ -143,12 +208,18 @@ class SpanTracer:
                 a = self._agg[name] = [0, 0.0, 0.0]
             a[0] += 1
             if not fused:  # marks carry no host-measurable duration
-                a[1] += t1 - t0
-                a[2] = max(a[2], t1 - t0)
-            if len(self._events) >= self._max:
+                a[1] += dur
+                a[2] = max(a[2], dur)
+            if len(self._ev_name) >= self._max:
                 self._dropped += 1
                 return
-            self._events.append(ev)
+            # the trace_event dicts are built in close(), off every
+            # hot path
+            self._ev_name.append(name)
+            self._ev_t0.append(t0)
+            self._ev_dur.append(dur)
+            self._ev_tid.append(tid)
+            self._ev_args.append(args or None)
 
     def aggregates(self) -> dict[str, dict[str, float]]:
         """Per-span-name stage totals (counts every event, including
@@ -163,12 +234,22 @@ class SpanTracer:
             if self._closed:
                 return
             self._closed = True
-            events = self._events
-            self._events = []
-            meta = [{"name": "thread_name", "ph": "M", "pid": os.getpid(),
+            columns = (self._ev_name, self._ev_t0, self._ev_dur,
+                       self._ev_tid, self._ev_args)
+            self._ev_name, self._ev_t0, self._ev_dur = [], [], []
+            self._ev_tid, self._ev_args = [], []
+            meta = [{"name": "thread_name", "ph": "M", "pid": self._pid,
                      "tid": tid, "args": {"name": tname}}
                     for tid, tname in sorted(self._thread_names.items())]
             dropped = self._dropped
+        events = []
+        for name, t0, dur, tid, args in zip(*columns):
+            ev = {"name": name, "cat": "apex", "ph": "X",
+                  "ts": (t0 - self._t0) * 1e6, "dur": dur * 1e6,
+                  "pid": self._pid, "tid": tid}
+            if args:
+                ev["args"] = args
+            events.append(ev)
         payload = {"traceEvents": meta + events,
                    "displayTimeUnit": "ms",
                    "otherData": {"dropped_events": dropped}}

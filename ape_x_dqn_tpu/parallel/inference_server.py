@@ -54,6 +54,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ape_x_dqn_tpu.obs.core import NULL_OBS
 from ape_x_dqn_tpu.obs.health import make_lock
+from ape_x_dqn_tpu.obs.trace import ANNOTATION_PREFIX
 from ape_x_dqn_tpu.utils.misc import next_pow2
 
 
@@ -129,6 +130,10 @@ class BatchedInferenceServer:
         self._items_served = 0  # guarded-by: _lock
         self._obs = obs if obs is not None else NULL_OBS
         self._obs.register("inference-server")
+        # spans exist only under a live tracer; the per-request stamps
+        # of server.queue_wait are skipped outright without one
+        self._traced = bool(self._obs.tracer.enabled)
+        self._batch_seq = 0  # serve thread only
         self._thread = threading.Thread(target=self._serve_loop,
                                         name="inference-server", daemon=True)
         self._thread.start()
@@ -284,9 +289,31 @@ class BatchedInferenceServer:
             items += r.items
         return reqs
 
-    def _serve_loop(self) -> None:
-        while not self._stop.is_set():
+    def _collect_traced(self) -> list[_Request]:
+        """`_collect` as the `server.collect` span (the wait for the
+        first request plus the fill deadline; empty polls are not
+        recorded) and one `server.queue_wait` interval per request,
+        enqueue -> collected, tagged with the batch that serves it."""
+        t0 = time.perf_counter()
+        # the profiler sees every poll; the tracer only the ones that
+        # produced a batch, so an idle server does not dilute the mean
+        with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX
+                                          + "server.collect"):
             reqs = self._collect()
+        if reqs:
+            t1 = time.perf_counter()
+            batch = self._batch_seq + 1
+            self._obs.record("server.collect", t0, t1, batch=batch,
+                             requests=len(reqs))
+            for r in reqs:
+                self._obs.record("server.queue_wait", r.t_enq, t1,
+                                 batch=batch)
+        return reqs
+
+    def _serve_loop(self) -> None:
+        collect = self._collect_traced if self._traced else self._collect
+        while not self._stop.is_set():
+            reqs = collect()
             if not reqs:
                 # an idle-but-polling server is alive, not stalled: beat
                 # so a wedged ACTOR gets the stall attribution instead of
@@ -316,38 +343,55 @@ class BatchedInferenceServer:
     def _serve_batch(self, reqs: list[_Request]) -> None:
         n = sum(r.items for r in reqs)
         padded = self._bucket(n)
-        with self._obs.span("server.batch", items=n, padded=padded):
-            # every request's leaves get a leading batch dim (single-
-            # item requests gain one), then requests concatenate
-            leads = [r.inputs if r.n else
-                     jax.tree.map(lambda x: np.asarray(x)[None], r.inputs)
-                     for r in reqs]
-            stacked = jax.tree.map(lambda *xs: _pad_concat(xs, padded),
-                                   *leads)
-            if self._batched_sharding is not None:
-                stacked = jax.device_put(stacked, self._batched_sharding)
-            with self._lock:
-                params = self._params
-                version = self._params_version
-            out = self._apply(params, stacked)
-            out_np = jax.tree.map(np.asarray, out)
-        off = 0
-        t_done = time.perf_counter()
-        for r in reqs:
-            if r.n:
-                lo, hi = off, off + r.n
-                r.result = jax.tree.map(lambda x: x[lo:hi], out_np)
-            else:
-                idx = off
-                r.result = jax.tree.map(lambda x: x[idx], out_np)
-            off += r.items
-            # end-to-end request latency (enqueue -> result ready):
-            # the serving SLO — covers queue wait, batching deadline,
-            # the forward, and the scatter, which is what an actor
-            # actually blocks on
-            self._obs.observe("infer_latency_ms",
-                              (t_done - r.t_enq) * 1e3)
-            r.event.set()
+        span = self._obs.span
+        self._batch_seq = seq = self._batch_seq + 1
+        # server.batch is one batch end to end; its four children split
+        # the serve thread's time (server.collect, before it, is the
+        # fifth part of a batch's period) and repeat its seq as `batch`
+        with span("server.batch", items=n, padded=padded, seq=seq):
+            with span("server.stack", batch=seq):
+                # every request's leaves get a leading batch dim
+                # (single-item requests gain one), then requests
+                # concatenate
+                leads = [r.inputs if r.n else
+                         jax.tree.map(lambda x: np.asarray(x)[None],
+                                      r.inputs)
+                         for r in reqs]
+                stacked = jax.tree.map(
+                    lambda *xs: _pad_concat(xs, padded), *leads)
+                if self._batched_sharding is not None:
+                    stacked = jax.device_put(stacked,
+                                             self._batched_sharding)
+            with span("server.dispatch", batch=seq):
+                # host -> device copy of the batch and the enqueue
+                with self._lock:
+                    params = self._params
+                    version = self._params_version
+                out = self._apply(params, stacked)
+            with span("server.fetch", batch=seq):
+                # device time + device -> host + the wait for the GIL
+                out_np = jax.tree.map(np.asarray, out)
+            with span("server.scatter", batch=seq):
+                off = 0
+                t_done = time.perf_counter()
+                for r in reqs:
+                    if r.n:
+                        lo, hi = off, off + r.n
+                        r.result = jax.tree.map(lambda x: x[lo:hi],
+                                                out_np)
+                    else:
+                        idx = off
+                        r.result = jax.tree.map(lambda x: x[idx], out_np)
+                    off += r.items
+                    r.event.set()
+                # end-to-end request latency (enqueue -> result ready):
+                # the serving SLO — covers queue wait, batching
+                # deadline, the forward, and the scatter, which is what
+                # an actor actually blocks on. One histogram call per
+                # batch, after every caller has been released
+                self._obs.observe_many(
+                    "infer_latency_ms",
+                    [(t_done - r.t_enq) * 1e3 for r in reqs])
         # stats() reads these from other threads; the serve thread is
         # the only writer but += is still a read-modify-write
         with self._lock:

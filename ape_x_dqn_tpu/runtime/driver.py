@@ -17,7 +17,6 @@ Threads:
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 import threading
@@ -36,7 +35,8 @@ from ape_x_dqn_tpu.envs import make_env
 from ape_x_dqn_tpu.models import build_network
 from ape_x_dqn_tpu.obs.core import build_obs
 from ape_x_dqn_tpu.obs.fleet import MAX_SPAN_IDS, FleetAggregator
-from ape_x_dqn_tpu.obs.health import make_lock
+from ape_x_dqn_tpu.obs.health import TimedLock, make_lock
+from ape_x_dqn_tpu.obs.trace import NULL_SPAN
 from ape_x_dqn_tpu.parallel.dist_learner import (
     DistDQNLearner, DistSequenceLearner)
 from ape_x_dqn_tpu.parallel.inference_server import (
@@ -64,9 +64,6 @@ from ape_x_dqn_tpu.utils.metrics import (
     Metrics, Throughput, log_run_header)
 from ape_x_dqn_tpu.utils.misc import next_pow2
 from ape_x_dqn_tpu.utils.rng import component_key
-
-# reusable no-op context for the unprofiled (default) ship path
-_NULL_CM = contextlib.nullcontext()
 
 
 def build_prioritized_replay(cfg: RunConfig, spec, capacity: int,
@@ -246,14 +243,21 @@ class ApexDriver:
         # rows actually landed in replay (post-drop, post-coalesce) —
         # the perf-regression engine's local ingest baseline
         self.ingest_rows = Throughput(window_s=30.0)
-        # sampled block_until_ready windows on the ingest ship path are
-        # OFF by default: the zero-copy stager's whole point is decode/
-        # transfer overlap, and an every-dispatch sync would serialize it
+        # sampled block_until_ready windows (ObsConfig.profile_windows,
+        # 1-in-profile_window_every ingest ships and learner dispatches)
+        # are OFF by default: the zero-copy stager's whole point is
+        # decode/transfer overlap, the learner's is to keep dispatches
+        # queued, and an every-dispatch sync would serialize both. A
+        # sampled sync runs after _state_lock is released
         ocfg = getattr(cfg, "obs", None)
-        self._ship_window_every = (
+        self._window_every = (
             max(getattr(ocfg, "profile_window_every", 16), 1)
             if getattr(ocfg, "profile_windows", False) else 0)
         self._ship_seq = 0  # ingest thread only
+        self._train_seq = 0  # learner thread only
+        # state_lock.wait.* spans need a live tracer; without one the
+        # hot acquisitions take the bare lock
+        self._time_lock_waits = bool(self.obs.tracer.enabled)
         self._frames_total = 0  # guarded-by: _lock
         self._grad_steps_total = 0
         self._last_loss: float | None = None  # learner thread, at exit
@@ -928,9 +932,12 @@ class ApexDriver:
         # the origin's batch_id, so the trace reconstructs the
         # actor->wire->staging->add journey; the tag rides the stager
         # into the replay.add dispatch that carries it
+        # (an unstamped loopback message gets the same span, without
+        # the correlation args: host decode/copy of one message)
         bid = batch.get("batch_id")
         if bid is None:
-            self._stage_one(batch, n)
+            with self.obs.span("ingest.batch", rows=n):
+                self._stage_one(batch, n)
         else:
             peer = str(batch.get("peer", ""))
             with self.obs.span("ingest.batch", batch_id=int(bid),
@@ -1081,12 +1088,12 @@ class ApexDriver:
         # sees device time, not enqueue time. Off by default — syncing
         # here defeats the stager's decode/transfer overlap
         self._ship_seq += 1
-        windowed = (self._ship_window_every
-                    and self._ship_seq % self._ship_window_every == 0)
+        windowed = (self._window_every
+                    and self._ship_seq % self._window_every == 0)
         win = (self.obs.stage_window("ingest", count) if windowed
-               else _NULL_CM)
+               else NULL_SPAN)
         with win:
-            with self._state_lock:
+            with self._hold_state("ingest"):
                 with self.obs.span("replay.add", **span_args):
                     if g > 1:
                         self.state = self.learner.add_many(self.state,
@@ -1276,7 +1283,7 @@ class ApexDriver:
             items = {k: jnp.asarray(v) for k, v in take.items()
                      if k != "priorities"}
             pris = jnp.asarray(take["priorities"])
-        with self._state_lock:
+        with self._hold_state("ingest"):
             with self.obs.span("replay.add", units=count):
                 self.state = self.learner.add(self.state, items, pris)
         self.ingest_rows.add(count * self._unit_items)
@@ -1478,12 +1485,22 @@ class ApexDriver:
                 jax.profiler.stop_trace()
                 self._profiling = None
 
+    def _hold_state(self, who: str):  # apexlint: holds(_state_lock)
+        """`with self._hold_state("ingest"):` is `with
+        self._state_lock:` — the bare lock without a tracer, else one
+        whose wait (asking for the lock -> holding it) is the span
+        `state_lock.wait.<who>`. For the hot acquisitions only."""
+        if not self._time_lock_waits:
+            return self._state_lock
+        return TimedLock(self._state_lock,
+                         self.obs.span("state_lock.wait." + who))
+
     def _publish_params(self) -> None:
         # copy/reshard under the state lock: a concurrent add() or
         # train dispatch would donate the very buffers being published.
         # Dist publication is a tp all-gather + replication over ICI
         # (SURVEY.md §2.3 item 3); single-chip learners copy.
-        with self._state_lock:
+        with self._hold_state("publish"):
             with self.obs.span("learner.publish_params"):
                 pub = self.learner.publish_params(self.state)
         self.server.update_params(pub, self._grad_steps_total)
@@ -1547,11 +1564,20 @@ class ApexDriver:
             # a few steps late is equivalent)
             done = self._grad_steps_total
             k = chunk if chunk <= max_grad_steps - done else 1
-            with self._state_lock:
-                # the stage window rides the span's existing
-                # block_until_ready sync point — no extra sync is added
-                # for the roofline gauges on the fused train path
-                with self.obs.stage_window(self._train_stage, k):
+            # learner.train is the host's dispatch time: what this
+            # thread does while it holds the lock. The device's side is
+            # jit_train_many in the profiler trace. Only a 1-in-N
+            # sampled dispatch (ObsConfig.profile_windows) is bracketed
+            # with block_until_ready for the train roofline gauges, and
+            # its sync runs after the lock is released, so ingest and
+            # publish never wait out a device step behind it
+            self._train_seq += 1
+            windowed = (self._window_every
+                        and self._train_seq % self._window_every == 0)
+            win = (self.obs.stage_window(self._train_stage, k)
+                   if windowed else NULL_SPAN)
+            with win:
+                with self._hold_state("learner"):
                     with self.obs.span("learner.train", k=k):
                         if k > 1:
                             self.state, m = self.learner.train_many(
@@ -1559,10 +1585,8 @@ class ApexDriver:
                         else:
                             self.state, m = self.learner.train_step(
                                 self.state)
-                        if self.obs.enabled:
-                            # honest host timing under async dispatch;
-                            # only paid when observability is on
-                            m = jax.block_until_ready(m)
+                if windowed:
+                    m = jax.block_until_ready(m)
             self._grad_steps_total += k
             self.grad_steps.add(k)
             self.obs.set_learner_step(self._grad_steps_total)

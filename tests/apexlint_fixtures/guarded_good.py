@@ -22,3 +22,10 @@ class Good:
         with self._lock:
             self._items = []
             self._items[0:0] = [1]
+
+    def _hold(self, who):  # apexlint: holds(_lock)
+        return self._lock
+
+    def bump_through_holder(self, n):
+        with self._hold("bump"):
+            self._count += n

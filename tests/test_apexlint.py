@@ -160,6 +160,31 @@ def test_guarded_by_fixtures():
     assert bad.waivers == 1  # the waived closure write
 
 
+def test_guarded_by_holder_method_counts_as_its_declared_lock(tmp_path):
+    """`with self.<method>(...)` holds a lock only when the method's
+    def line declares it with `# apexlint: holds(<lock>)`."""
+    body = (
+        "class C:\n"
+        "    def __init__(self):\n"
+        "        self._lock = None\n"
+        "        self._n = 0  # guarded-by: _lock\n"
+        "    def _hold(self, who):{mark}\n"
+        "        return self._lock\n"
+        "    def bump(self):\n"
+        "        with self._hold('bump'):\n"
+        "            self._n += 1\n")
+    declared = tmp_path / "declared.py"
+    declared.write_text(body.format(mark="  # apexlint: holds(_lock)"))
+    assert guarded_by.check_paths([str(declared)]).findings == []
+    undeclared = tmp_path / "undeclared.py"
+    undeclared.write_text(body.format(mark=""))
+    found = guarded_by.check_paths([str(undeclared)]).findings
+    assert len(found) == 1 and "self._n" in found[0].message
+    wrong = tmp_path / "wrong.py"
+    wrong.write_text(body.format(mark="  # apexlint: holds(_other)"))
+    assert len(guarded_by.check_paths([str(wrong)]).findings) == 1
+
+
 def test_jit_purity_fixtures():
     good = jit_purity.check_paths([_fx("jit_good.py")])
     assert good.findings == []

@@ -78,6 +78,48 @@ def test_tracer_bounded_buffer(tmp_path):
 
 # -- registry --------------------------------------------------------------
 
+def test_tracer_record_and_span_share_one_aggregate(tmp_path):
+    """`record()` (caller-stamped, cross-thread) and `span()` fold into
+    the same per-name table and the same JSON timeline."""
+    import time
+
+    path = str(tmp_path / "trace.json")
+    tracer = SpanTracer(path)
+    with tracer.span("server.batch", seq=1):
+        t0 = time.perf_counter()
+    tracer.record("server.batch", t0, t0 + 0.5, seq=2)
+    agg = tracer.aggregates()["server.batch"]
+    assert agg["count"] == 2 and agg["max_s"] == pytest.approx(0.5)
+    tracer.close()
+    evs = [e for e in load_trace(path)["traceEvents"]
+           if e.get("ph") == "X"]
+    assert [e["args"]["seq"] for e in evs] == [1, 2]
+    # both intervals sit on the tracer's own clock: the recorded one
+    # starts where the span's body stamped t0
+    assert evs[0]["ts"] <= evs[1]["ts"] <= evs[0]["ts"] + evs[0]["dur"] + 1
+
+
+def test_bounded_buffer_counts_recorded_intervals_too(tmp_path):
+    tracer = SpanTracer(str(tmp_path / "t.json"), max_events=3)
+    for i in range(5):
+        tracer.record("server.queue_wait", float(i), float(i) + 1.0)
+    assert tracer.aggregates()["server.queue_wait"]["count"] == 5
+    tracer.close()
+    trace = load_trace(str(tmp_path / "t.json"))
+    assert trace["otherData"]["dropped_events"] == 2
+
+
+def test_null_facade_spans_are_one_preallocated_object():
+    from ape_x_dqn_tpu.obs.trace import NULL_SPAN, NULL_TRACER
+
+    assert NULL_OBS.span("learner.train", k=8) is NULL_SPAN
+    assert NULL_OBS.stage_window("ingest", 4) is NULL_SPAN
+    assert NULL_TRACER.span("x") is NULL_TRACER.span("y") is NULL_SPAN
+    assert NULL_TRACER.aggregates() == {}
+    with NULL_OBS.span("x"):
+        pass  # enters and exits without a tracer
+
+
 def test_geometric_edges_span_orders_of_magnitude():
     edges = geometric_edges(1.0, 1e3, per_decade=2)
     assert edges[0] == pytest.approx(1.0)

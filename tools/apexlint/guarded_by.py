@@ -11,6 +11,11 @@ The check is lexical: a write inside a helper that is only ever
 calling convention. Either inline the write under the `with`, or waive
 the line with `# apexlint: unguarded(<why it is safe>)`.
 
+A method that hands the lock back wrapped (a timed or traced
+acquisition) declares it on its `def` line with `# apexlint:
+holds(<lock>)`; `with self.<method>(...):` then counts as `with
+self.<lock>:`.
+
 Nested functions (thread targets, closures) defined inside a `with`
 block run later, after the lock is released, so the held-lock set is
 reset to empty inside them.
@@ -48,14 +53,28 @@ def _declared_guards(cls: ast.ClassDef,
     return guards
 
 
+def _declared_holders(cls: ast.ClassDef,
+                      src: ModuleSource) -> dict[str, str]:
+    """method name -> lock-attr from `# apexlint: holds(<lock>)` on a
+    method's `def` line."""
+    holders: dict[str, str] = {}
+    for stmt in cls.body:
+        if isinstance(stmt, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            lock = src.waiver(stmt.lineno, "holds")
+            if lock:
+                holders[stmt.name] = lock.strip()
+    return holders
+
+
 class _WriteScanner:
     """Walk one method body tracking the lexically-held lock set."""
 
     def __init__(self, src: ModuleSource, guards: dict[str, str],
-                 result: CheckResult):
+                 result: CheckResult, holders: dict[str, str]):
         self.src = src
         self.guards = guards
         self.result = result
+        self.holders = holders
 
     def scan(self, fn: ast.FunctionDef | ast.AsyncFunctionDef) -> None:
         for stmt in fn.body:
@@ -71,7 +90,11 @@ class _WriteScanner:
         if isinstance(node, (ast.With, ast.AsyncWith)):
             acquired = set(held)
             for item in node.items:
-                attr = attr_on_self(item.context_expr)
+                expr = item.context_expr
+                attr = attr_on_self(expr)
+                if attr is None and isinstance(expr, ast.Call):
+                    # `with self.<holder>(...)`: the declared lock
+                    attr = self.holders.get(attr_on_self(expr.func))
                 if attr is not None:
                     acquired.add(attr)
             for stmt in node.body:
@@ -113,7 +136,8 @@ def check_module(src: ModuleSource) -> CheckResult:
         guards = _declared_guards(node, src)
         if not guards:
             continue
-        scanner = _WriteScanner(src, guards, result)
+        scanner = _WriteScanner(src, guards, result,
+                                _declared_holders(node, src))
         for stmt in node.body:
             if (isinstance(stmt, (ast.FunctionDef,
                                   ast.AsyncFunctionDef))
