@@ -60,14 +60,46 @@ def update(tree: jax.Array, leaf_idx: jax.Array,
     return tree
 
 
+def chunk_major(x: jax.Array, chunks: int) -> jax.Array:
+    """Reorder the leading axis of a K*B draw from stratum order to
+    CHUNK-MAJOR order: position p = j*B + i takes element i*K + j, so
+    the contiguous block [j*B, (j+1)*B) of anything gathered in this
+    order is chunk j: the INTERLEAVED strata {j, j+K, j+2K, ...}.
+
+    Why interleaved: stratum s of a K*B descent covers cumulative-mass
+    slice [s, s+1)/(K*B) over leaves in ring-insertion order, so a
+    chunk of CONTIGUOUS strata would be one age-correlated 1/K slice
+    of the replay (oldest quarter, ..., newest quarter); each chunk
+    must span the full priority range. Why here, on the draw: the
+    permutation depends on the position alone, so applied to the K*B
+    scalars before the storage gather it moves a few kB, where applied
+    to the gathered batch it rewrites every sampled frame once more.
+    This is the ONE place the permutation lives — every storage layout
+    (and the uniform replay's indices) goes through it."""
+    if chunks <= 1:
+        return x
+    return x.reshape(x.shape[0] // chunks, chunks,
+                     *x.shape[1:]).swapaxes(0, 1).reshape(x.shape)
+
+
 def sample(tree: jax.Array, rng: jax.Array, batch: int,
-           size: jax.Array | None = None) -> tuple[jax.Array, jax.Array]:
+           size: jax.Array | None = None,
+           chunks: int = 1) -> tuple[jax.Array, jax.Array]:
     """Stratified proportional sampling.
 
     Returns (leaf_idx [batch] int32, probs [batch] f32) where probs are
-    normalized leaf probabilities p_i / total. Stratification: sample i
-    draws uniformly from the i-th of `batch` equal slices of the total
-    mass (variance reduction, as in standard PER implementations).
+    normalized leaf probabilities p_i / total. Stratification: the draw
+    for stratum s is uniform in the s-th of `batch` equal slices of the
+    total mass (variance reduction, as in standard PER implementations).
+
+    Order of the draw: with chunks=1 position s holds stratum s. With
+    chunks=K (the learners' K-batch cycle, batch = K*B) the stratified
+    u is laid out chunk-major BEFORE the descent (`chunk_major`):
+    position j*B + i holds stratum i*K + j. The descent is elementwise,
+    so that leaf is bit for bit the one a stratum-order draw followed
+    by a `reshape(B, K).swapaxes(0, 1)` puts at chunk j, slot i — the
+    same leaves from the same rng, emitted in the order the K SGD
+    steps consume them.
 
     `size` (int32, number of live leaves) clamps the descent's landing
     spot into the filled region: float32 rounding in the stratified u or
@@ -82,6 +114,7 @@ def sample(tree: jax.Array, rng: jax.Array, batch: int,
     tot = tree[1]
     u = (jnp.arange(batch, dtype=jnp.float32)
          + jax.random.uniform(rng, (batch,))) / batch * tot
+    u = chunk_major(u, chunks)
     idx = jnp.ones(batch, jnp.int32)
     for _ in range(depth):
         left = tree[2 * idx]

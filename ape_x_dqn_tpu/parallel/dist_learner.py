@@ -126,7 +126,7 @@ class _DistLearnerBase:
     # -- pure step ---------------------------------------------------------
 
     def _sample_weighted(self, replay_state: ReplayState, sk,
-                         n_per_shard):
+                         n_per_shard, chunks: int = 1):
         """Per-shard stratified sample of n_per_shard items + global IS
         weights over the [dp, n_per_shard] pool.
 
@@ -156,10 +156,13 @@ class _DistLearnerBase:
         contract, runtime/learner.py).
 
         Returns (items [dp, n, ...], idx [dp, n], w [dp, n]) with w
-        NOT yet max-normalized (callers normalize per training batch).
+        NOT yet max-normalized (callers normalize per training batch);
+        every shard's draw is in stratum order, or chunk-major with
+        `chunks`=K (ops/sum_tree.py::sample).
         """
         def shard_sample(rstate: ReplayState, key):
-            return self.replay.sample_items(rstate, key, n_per_shard)
+            return self.replay.sample_items(rstate, key, n_per_shard,
+                                            chunks)
 
         items, idx, probs = jax.vmap(shard_sample)(replay_state, sk)
         n_global = jnp.maximum(
@@ -236,32 +239,46 @@ class _DistLearnerBase:
         per-shard stratified K*b_local descent + gather + global IS
         weights, chunked for the K SGD steps.
 
+        Order of the draw: CHUNK-MAJOR within every shard — position
+        j*b_local + i = stratum i*K + j (ops/sum_tree.py::chunk_major
+        holds the permutation and why the strata interleave), so chunk
+        j is the contiguous block [:, j*b_local:(j+1)*b_local] of
+        everything gathered: the indices are permuted, never the
+        sampled payload. [K, dp, b_local, ...] is a stack of those K
+        blocks (not a reshape + moveaxis, which XLA does not fold:
+        see SingleChipLearner._sample_stage), so `items_k[j]` is block
+        j itself on every chip.
+
         -> (items_k [K, dp, b_local, ...], idx [dp, K*b_local]
-        UN-chunked for the per-shard write-back, w_k [K, dp, b_local]
-        raw — _sgd_step max-normalizes per training batch, and
-        pri [dp, K*b_local] descent-time leaf priorities appended LAST
-        for the staleness delta — positional readers of the tuple's
-        stable prefix are unmoved)."""
+        UN-chunked (in the draw's own order) for the per-shard
+        write-back, w_k [K, dp, b_local] raw — _sgd_step
+        max-normalizes per training batch, and pri [dp, K*b_local]
+        descent-time leaf priorities appended LAST for the staleness
+        delta — positional readers of the tuple's stable prefix are
+        unmoved)."""
         items, idx, w = self._sample_weighted(replay_state, sk,
-                                              k * self.b_local)
+                                              k * self.b_local, chunks=k)
         pri = jax.vmap(self.replay.leaf_priorities)(replay_state, idx)
 
-        def chunked(x):
-            # [dp, b_local*k, ...] -> [k, dp, b_local, ...] with chunk
-            # j = strata {j, j+k, ...} (stratum s = i*k + j at [j, :, i])
-            y = x.reshape(x.shape[0], self.b_local, k, *x.shape[2:])
-            return jnp.moveaxis(y, 2, 0)
+        def split(x):
+            # [dp, k*b_local, ...] -> [k, dp, b_local, ...]
+            return jnp.stack(jnp.split(x, k, axis=1))
 
-        items_k = jax.tree.map(chunked, items)
-        w_k = chunked(w)
-        return items_k, idx, w_k, pri
+        return jax.tree.map(split, items), idx, split(w), pri
 
     def _learn_stage(self, state: DistTrainState, sample,
                      k: int) -> tuple[DistTrainState, dict]:
         """Pure LEARN stage: K SGD steps over an already-drawn sample
         + ONE vmapped per-shard write-back + target sync (static
         unrolled loop — lax.scan conv bodies are pathologically slow
-        on CPU). `state.rng` must already be advanced past the draw."""
+        on CPU). `state.rng` must already be advanced past the draw.
+        Step j trains on chunk j and its [dp, b_local] |TD|s pair with
+        idx[:, j*b_local:(j+1)*b_local]: idx is in the draw's
+        chunk-major order (_sample_stage), so the write-back is a plain
+        concatenate of the K parts — the same (leaf, |TD|) pairs the
+        stratum-order idx and an inverse chunk transform gave; only the
+        order among duplicate leaves inside one `.at[].set` differs,
+        which XLA never specified."""
         items_k, idx, w_k, pri_k = sample
         params, target_params, opt_state, step = (
             state.params, state.target_params, state.opt_state,
@@ -280,9 +297,9 @@ class _DistLearnerBase:
         metrics["diag"] = {**metrics.get("diag", {}),
                            **learn_obs.replay_health_sharded(
                                self.replay, state.replay, idx, pri_k)}
-        # invert the chunk transform: td_all[d, i*k + j] = parts[j][d, i]
-        td_all = jnp.moveaxis(jnp.stack(td_parts, axis=0), 0, 2) \
-            .reshape(self.dp, k * self.b_local)
+        # td_parts[j] pairs with idx[:, j*b_local:(j+1)*b_local]: both
+        # sides of the write-back are in the draw's chunk-major order
+        td_all = jnp.concatenate(td_parts, axis=1)
         new_replay = jax.vmap(
             lambda rs, i, td: self.replay.update_priorities(rs, i, td)
         )(state.replay, idx, td_all)

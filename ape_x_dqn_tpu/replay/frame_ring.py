@@ -374,21 +374,38 @@ class FrameRingReplay(PrioritizedReplay):
         return self._write_segments(state, items, td_abs,
                                     lead=(td_abs.shape[0],), seg0=seg0)
 
-    def _gather(self, state: ReplayState, idx: jax.Array) -> dict:
+    def _gather(self, state: ReplayState, idx: jax.Array,
+                chunks: int = 1) -> dict:
         """Reconstruct flat transitions {obs, action, reward, next_obs,
         discount} for transition indices idx [Bt] — a row gather of
-        stack frames per side, then a batch-local reshape to [H, W]
-        planes (the ring itself is never relaid out)."""
+        stack frames per side, then a batch-local relayout to
+        [Bt, H, W, stack] planes (the ring itself is never relaid out).
+
+        The order of the operations is chosen for the one relayout XLA
+        cannot avoid: the ring holds pixel-minor byte rows and the
+        first conv reads the batch in the lanes. With the stack axis
+        FIRST in the index the rows arrive as [stack, B, row], so that
+        relayout is one 2-D transpose (rows x pixels); with the batch
+        first it took three more passes over the sampled frames
+        (PERF.md §6, PR 25). `chunks`=K (idx chunk-major, the K-batch
+        cycle) gathers each chunk's B rows on its own: chunk j of the
+        result is then a whole array, not a slice of the lane
+        dimension of a K*B one, and the K SGD steps read it as it
+        lands. The bytes returned are the same for every `chunks`."""
         st = state.storage
         seg, j = idx // self.B, idx % self.B
         base = seg * self.F + j
-        offs = jnp.arange(self.stack, dtype=jnp.int32)[None, :]
+        offs = jnp.arange(self.stack, dtype=jnp.int32)[:, None]
+
+        def stack_of(rows_base):
+            f = st["frames"][offs + rows_base[None, :]]  # [stack,B,row]
+            f = f[..., :self.frame_bytes].reshape(
+                self.stack, -1, self.h, self.w)
+            return jnp.transpose(f, (1, 2, 3, 0))        # -> [B,H,W,st]
 
         def stack_at(rows_base):
-            f = st["frames"][rows_base[:, None] + offs]  # [Bt,stack,row]
-            f = f[..., :self.frame_bytes].reshape(
-                -1, self.stack, self.h, self.w)
-            return jnp.moveaxis(f, 1, -1)                # -> [Bt,H,W,st]
+            return jnp.concatenate(
+                [stack_of(r) for r in jnp.split(rows_base, chunks)])
 
         return {
             "obs": stack_at(base),
@@ -399,12 +416,14 @@ class FrameRingReplay(PrioritizedReplay):
             "discount": st["discount"][idx],
         }
 
-    def sample_items(self, state: ReplayState, rng: jax.Array, batch: int
+    def sample_items(self, state: ReplayState, rng: jax.Array, batch: int,
+                     chunks: int = 1
                      ) -> tuple[Any, jax.Array, jax.Array]:
-        """-> (flat transition batch, leaf indices [B], probs [B])."""
+        """-> (flat transition batch, leaf indices [B], probs [B]);
+        `chunks` as in PrioritizedReplay.sample_items."""
         idx, probs = sum_tree.sample(state.tree, rng, batch,
-                                     size=state.size)
-        return self._gather(state, idx), idx, probs
+                                     size=state.size, chunks=chunks)
+        return self._gather(state, idx, chunks), idx, probs
 
     # sample() is inherited: PrioritizedReplay.sample composes
     # sample_items (overridden above) with IS weights and the

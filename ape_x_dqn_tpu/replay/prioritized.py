@@ -253,28 +253,32 @@ class PrioritizedReplay:
         return self._write_block(state, items, td_abs,
                                  lead=(td_abs.shape[0],), start=start)
 
-    def sample_items(self, state: ReplayState, rng: jax.Array, batch: int
+    def sample_items(self, state: ReplayState, rng: jax.Array, batch: int,
+                     chunks: int = 1
                      ) -> tuple[Any, jax.Array, jax.Array]:
         """-> (item batch pytree, leaf indices [B], probs [B]) without IS
         weights — the dist learner computes those globally across shards
         (parallel/dist_learner.py), and FrameRingReplay shares the
-        calling convention."""
+        calling convention. `chunks`=K emits the draw chunk-major
+        (ops/sum_tree.py::sample), so the gather writes the batch in
+        the order the K-batch cycle reads it."""
         idx, probs = sum_tree.sample(state.tree, rng, batch,
-                                     size=state.size)
+                                     size=state.size, chunks=chunks)
         items = jax.tree.map(lambda buf: buf[idx], state.storage)
         if self._packer is not None:
             items = self._packer.decode(items)
         return items, idx, probs
 
-    def sample(self, state: ReplayState, rng: jax.Array, batch: int
-               ) -> tuple[Any, jax.Array, jax.Array]:
-        """-> (item batch pytree, leaf indices [B], IS weights [B]).
+    def sample(self, state: ReplayState, rng: jax.Array, batch: int,
+               chunks: int = 1) -> tuple[Any, jax.Array, jax.Array]:
+        """-> (item batch pytree, leaf indices [B], IS weights [B]), in
+        stratum order, or chunk-major with `chunks`=K (sample_items).
 
         valid_mask zeroes the weight of storage layouts' dead slots
         BEFORE max-normalization (a ~zero-probability dead draw would
         otherwise become the max and crush every live weight); for flat
         storage it is all-ones and folds away."""
-        items, idx, probs = self.sample_items(state, rng, batch)
+        items, idx, probs = self.sample_items(state, rng, batch, chunks)
         n = jnp.maximum(state.size.astype(jnp.float32), 1.0)
         w = (n * jnp.maximum(probs, 1e-12)) ** (-self.beta)
         w = w * self.valid_mask(state, idx)
@@ -310,7 +314,8 @@ class PrioritizedReplay:
 
     # -- split entry points (double-buffered learner pipeline) -------------
 
-    def sample_state(self, state: ReplayState, rng: jax.Array, batch: int
+    def sample_state(self, state: ReplayState, rng: jax.Array, batch: int,
+                     chunks: int = 1
                      ) -> tuple[Any, jax.Array, jax.Array]:
         """SAMPLE half of the split learner cycle — `sample` under its
         pipeline-contract name. Reads only storage, tree, and size
@@ -320,7 +325,7 @@ class PrioritizedReplay:
         the double-buffered train_many accepts by design. Subclasses
         override sample/sample_items, not this delegator, so every
         storage layout inherits the contract."""
-        return self.sample(state, rng, batch)
+        return self.sample(state, rng, batch, chunks)
 
     def update_state(self, state: ReplayState, idx: jax.Array,
                      td_abs: jax.Array) -> ReplayState:
@@ -389,9 +394,14 @@ class UniformReplayDevice:
             pos=(start + b) % self.capacity,
             size=ring_write_size(state.size, start, b, self.capacity))
 
-    def sample(self, state: ReplayState, rng: jax.Array, batch: int):
-        idx = jax.random.randint(rng, (batch,), 0,
-                                 jnp.maximum(state.size, 1))
+    def sample(self, state: ReplayState, rng: jax.Array, batch: int,
+               chunks: int = 1):
+        # no strata here, but the K-batch cycle's chunk membership is a
+        # function of the position in the draw all the same: permute
+        # the indices, not the gathered items (sum_tree.chunk_major)
+        idx = sum_tree.chunk_major(
+            jax.random.randint(rng, (batch,), 0,
+                               jnp.maximum(state.size, 1)), chunks)
         items = jax.tree.map(lambda buf: buf[idx], state.storage)
         if self._packer is not None:
             items = self._packer.decode(items)
@@ -412,8 +422,9 @@ class UniformReplayDevice:
 
     # split entry points (see PrioritizedReplay): sampling is uniform
     # and updates are no-ops, so the commuting contract holds trivially
-    def sample_state(self, state: ReplayState, rng: jax.Array, batch: int):
-        return self.sample(state, rng, batch)
+    def sample_state(self, state: ReplayState, rng: jax.Array, batch: int,
+                     chunks: int = 1):
+        return self.sample(state, rng, batch, chunks)
 
     def update_state(self, state: ReplayState, idx, td_abs):
         return self.update_priorities(state, idx, td_abs)

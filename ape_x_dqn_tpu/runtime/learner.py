@@ -124,6 +124,19 @@ class SingleChipLearner:
         cursor), so a prefetched call commutes with an in-flight
         priority write-back — the double-buffering contract.
 
+        Order of the draw: CHUNK-MAJOR. The replay emits position
+        j*B + i = stratum i*K + j (ops/sum_tree.py::chunk_major holds
+        the permutation and why the strata interleave), so chunk j is
+        the contiguous block [j*B, (j+1)*B) of everything gathered:
+        the K*B indices are permuted (a few kB), never the sampled
+        payload. The [K, B, ...] view is a stack of those K blocks, not
+        a reshape — the first conv reads the batch in the lanes, so a
+        reshaped [K*B] array makes chunk j a slice of the lane
+        dimension (measured slower than the parent); as a stack of
+        slices XLA folds `items_k[j]` back to block j itself, which a
+        layout that gathers per chunk (FrameRingReplay._gather) hands
+        over as it lands (PERF.md §6, PR 25).
+
         -> (items_k [K, B, ...] pytree, idx_k [K, B], is_w_k [K, B],
             pri_k [K, B] descent-time leaf priorities — the staleness
             reference _learn_stage compares against at write-back time;
@@ -132,32 +145,27 @@ class SingleChipLearner:
         """
         b = self.lcfg.batch_size
         items, idx, is_w = self.replay.sample_state(replay_state, sk,
-                                                    k * b)
+                                                    k * b, chunks=k)
         pri = self.replay.leaf_priorities(replay_state, idx)
 
-        # stratum i of the K*B descent covers cumulative-mass slice
-        # [i, i+1)/(K*B) over leaves in ring-insertion order, so chunk
-        # j must take the INTERLEAVED strata {j, j+K, j+2K, ...} to
-        # span the full priority range — a contiguous reshape(k, b)
-        # would hand each chunk one age-correlated 1/K slice of the
-        # replay (oldest quarter, ..., newest quarter)
-        def chunked(x):
-            return x.reshape(b, k, *x.shape[1:]).swapaxes(0, 1)
+        def split(x):
+            return jnp.stack(jnp.split(x, k))
 
-        items_k = jax.tree.map(chunked, items)
-        idx_k = chunked(idx)
         # sample() max-normalized over the K*B pool; renormalizing per
         # chunk recovers the exact per-step IS convention
-        is_w_k = chunked(is_w)
+        is_w_k = split(is_w)
         is_w_k = is_w_k / jnp.maximum(
             is_w_k.max(axis=1, keepdims=True), 1e-12)
-        return items_k, idx_k, is_w_k, chunked(pri)
+        return jax.tree.map(split, items), split(idx), is_w_k, split(pri)
 
     def _learn_stage(self, state: TrainState, sample,
                      k: int) -> tuple[TrainState, dict]:
         """Pure LEARN stage: K SGD steps over an already-drawn sample
         + ONE priority write-back + target sync. `state.rng` must
         already be advanced past the draw that produced `sample`.
+        Step j trains on `sample`'s chunk j; its |TD|s go back to
+        idx_k[j], so both sides of the write-back are in the draw's
+        chunk-major order (_sample_stage) and nothing is un-permuted.
 
         The K chunks run as a STATIC unrolled loop, not lax.scan: K is
         small (4-8) and measured on CPU a scanned conv body ran ~17x
@@ -185,8 +193,8 @@ class SingleChipLearner:
         metrics["diag"] = {**metrics.get("diag", {}),
                            **learn_obs.replay_health(
                                self.replay, state.replay, idx_k, pri_k)}
-        # td_parts[j] pairs with idx_k[j] (chunk order), so flatten
-        # idx_k the same way for the single write-back
+        # td_parts[j] pairs with idx_k[j]: both sides of the single
+        # write-back are in the draw's own (chunk-major) order
         replay_state = self.replay.update_state(
             state.replay, idx_k.reshape(k * b),
             jnp.concatenate(td_parts))

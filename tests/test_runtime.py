@@ -428,7 +428,12 @@ def test_kbatch_chunks_span_full_priority_range():
     tree = sum_tree.update(tree, jnp.arange(cap, dtype=jnp.int32),
                            jnp.ones(cap))
     idx, _ = sum_tree.sample(tree, jax.random.key(0), k * b)
-    idx_k = np.asarray(idx).reshape(b, k).swapaxes(0, 1)  # learner's split
+    # the learners draw chunk-major (chunks=k) and cut contiguous
+    # blocks: the same chunks as interleaving the stratum-order draw
+    idx_k = np.asarray(sum_tree.sample(tree, jax.random.key(0), k * b,
+                                       chunks=k)[0]).reshape(k, b)
+    np.testing.assert_array_equal(
+        idx_k, np.asarray(idx).reshape(b, k).swapaxes(0, 1))
     for j in range(k):
         lo, hi = idx_k[j].min(), idx_k[j].max()
         assert lo < cap * 0.1 and hi > cap * 0.9, \
@@ -560,6 +565,26 @@ def test_prefetch_sample_learn_split_matches_fused():
     np.testing.assert_array_equal(np.asarray(s1.replay.tree),
                                   np.asarray(s2.replay.tree))
     assert np.isfinite(m1["loss"]) and np.isfinite(m2["loss"])
+
+
+def test_train_many_is_two_train_step_k():
+    """train_many(8) at sample_chunk=4 is two K-batch macro-steps: the
+    same params, written-back tree and step count as two train_step_k
+    calls on the same seed (the scan adds nothing but the loop)."""
+    import jax
+
+    l1, s1 = _prefetch_learner(False)
+    l2, s2 = _prefetch_learner(False)
+    s1, _ = l1.train_many(s1, 8)
+    for _ in range(2):
+        s2, _ = l2.train_step_k(s2, 4)
+    assert int(s1.step) == int(s2.step) == 8
+    jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a),
+                                                   np.asarray(b)),
+        s1.params, s2.params)
+    np.testing.assert_array_equal(np.asarray(s1.replay.tree),
+                                  np.asarray(s2.replay.tree))
 
 
 def test_eval_rotation_survives_transient_timeout(tmp_path, monkeypatch):
