@@ -736,23 +736,31 @@ def _preset_r2d2() -> RunConfig:
         total_env_frames=10_000_000_000,
         env=EnvConfig(id="atari57", kind="atari"),
         network=NetworkConfig(kind="lstm_q", dueling=True),
-        # frame_ring: sequences store single frames (~0.56MB packed
-        # byte-rows each at L=80) instead of per-step stacks (~2.2MB).
+        # frame_ring: a sequence stores its 83 single frames as 83 byte
+        # rows of 7,168 B (594,944 B at L=80; per-step stacks would be
+        # 2.2 MB) — one row per frame, because a TPU gather fetches a
+        # row whole only up to 32,640 B (replay/packing.py::row_layout).
         # Capacity is HBM-budgeted (utils/hbm.py): 65536 sequences over
-        # dp=4 shards = 16384/shard x 0.59MB = ~9.0GiB per 16GiB chip
+        # dp=4 shards = 16384/shard x 0.60MB = 9.16GiB per 16GiB chip
         # (~2.6M transitions fleet-wide at overlap 40 — above the
-        # attested ~2M-transition replay scale). R2D2-paper 100k+
-        # sequences: raise dp to 8 (--set parallel.dp=8) or run
-        # 32GiB-HBM chips; the driver's check_hbm_fits prints the
-        # budget table if a layout doesn't fit.
+        # attested ~2M-transition replay scale); measured on one v5e at
+        # dp=1, capacity 16384: peak HBM 9.67 GiB with the train step's
+        # temps (PERF.md §5, PR 26). R2D2-paper 100k+ sequences: raise
+        # dp to 8 (--set parallel.dp=8) or run 32GiB-HBM chips; the
+        # driver's check_hbm_fits prints the budget table if a layout
+        # doesn't fit.
         replay=ReplayConfig(kind="sequence", capacity=65_536,  # sequences
                             seq_length=80, seq_overlap=40, burn_in=40,
                             min_fill=5_000, storage="frame_ring"),
         # sample_chunk=4: the K-batch sampling relaxation, adopted for
-        # sequences in round 5 — +25% grad-steps/s on the real chip
-        # (52.5 -> 66 at these shapes, A/B'd both orders) with learning
-        # parity on the masked-CartPole POMDP e2e (K=1 eval 43.2 vs
-        # K=4 42.7, both >35 bar); PERF.md "K-batch for sequences"
+        # sequences in round 5 on a +25% grad-steps/s A/B (52.5 -> 66)
+        # taken on the pre-PR-1 rig, behind a slow host<->device link
+        # that no longer exists and in a storage layout whose gather
+        # copied the whole replay per draw; on the v5e this preset at
+        # dp=1 runs 91.85 grad-steps/s with K=4 (PERF.md §5, PR 26; K=1
+        # has not been timed there). Learning parity on the
+        # masked-CartPole POMDP e2e (K=1 eval 43.2 vs K=4 42.7, both
+        # >35 bar) is a CPU result and stands.
         learner=LearnerConfig(batch_size=64, n_step=5, value_rescale=True,
                               target_sync_every=2500, lr=1e-4,
                               sample_chunk=4),

@@ -51,17 +51,22 @@ class ApeXLSTMQNet(nn.Module):
         """obs: [B, T, ...] -> (q: [B, T, A] float32, final_state)."""
         dt = dtype_of(self.compute_dtype)
         b, t = obs.shape[:2]
-        feats = self._torso(obs.reshape(b * t, *obs.shape[2:]), dt)
-        feats = feats.reshape(b, t, -1).swapaxes(0, 1)  # [T, B, F]
-        scan_cell = nn.scan(
-            nn.OptimizedLSTMCell,
-            variable_broadcast="params", split_rngs={"params": False},
-            in_axes=0, out_axes=0,
-        )(self.lstm_size, dtype=dt, name="lstm")
-        state = tuple(s.astype(dt) for s in state)
-        final_state, ys = scan_cell(state, feats)  # ys: [T, B, H]
-        q = self._head(ys.swapaxes(0, 1).reshape(b * t, -1), dt)
-        q = q.reshape(b, t, self.num_actions)
+        # named scopes (metadata only): device time by part of the net,
+        # forward and backward (learner.lstm_scan_share)
+        with jax.named_scope("r2d2.torso"):
+            feats = self._torso(obs.reshape(b * t, *obs.shape[2:]), dt)
+            feats = feats.reshape(b, t, -1).swapaxes(0, 1)  # [T, B, F]
+        with jax.named_scope("r2d2.lstm_scan"):
+            scan_cell = nn.scan(
+                nn.OptimizedLSTMCell,
+                variable_broadcast="params", split_rngs={"params": False},
+                in_axes=0, out_axes=0,
+            )(self.lstm_size, dtype=dt, name="lstm")
+            state = tuple(s.astype(dt) for s in state)
+            final_state, ys = scan_cell(state, feats)  # ys: [T, B, H]
+        with jax.named_scope("r2d2.head"):
+            q = self._head(ys.swapaxes(0, 1).reshape(b * t, -1), dt)
+            q = q.reshape(b, t, self.num_actions)
         return q, tuple(s.astype(jnp.float32) for s in final_state)
 
     @nn.compact

@@ -163,19 +163,24 @@ def make_r2d2_loss(net_apply_seq: Callable, burn_in: int, n_step: int,
 
     def loss_fn(params: Any, target_params: Any, batch: SequenceBatch,
                 is_weights: jax.Array):
+        # named scopes are op metadata only (the program XLA builds is
+        # the same): the benchmark sums device time by them
+        # (learner.burn_in_share, benchmarks/harness/scope_stats.py)
         state0 = tuple(batch.init_state)
         if burn_in > 0:
-            _, state_b = net_apply_seq(params, batch.obs[:, :burn_in],
-                                       state0)
-            state_b = jax.tree.map(jax.lax.stop_gradient, state_b)
-            _, state_bt = net_apply_seq(target_params,
-                                        batch.obs[:, :burn_in], state0)
+            with jax.named_scope("r2d2.burn_in"):
+                _, state_b = net_apply_seq(
+                    params, batch.obs[:, :burn_in], state0)
+                state_b = jax.tree.map(jax.lax.stop_gradient, state_b)
+                _, state_bt = net_apply_seq(
+                    target_params, batch.obs[:, :burn_in], state0)
         else:
             state_b = state0
             state_bt = state0
-        obs_t = batch.obs[:, burn_in:]
-        q_online, _ = net_apply_seq(params, obs_t, state_b)  # [B, T, A]
-        q_target, _ = net_apply_seq(target_params, obs_t, state_bt)
+        with jax.named_scope("r2d2.unroll"):
+            obs_t = batch.obs[:, burn_in:]
+            q_online, _ = net_apply_seq(params, obs_t, state_b)  # [B,T,A]
+            q_target, _ = net_apply_seq(target_params, obs_t, state_bt)
 
         actions = batch.actions[:, burn_in:]
         rewards = batch.rewards[:, burn_in:]

@@ -160,27 +160,58 @@ def packable(spec) -> bool:
             and math.prod(spec.shape) >= 4096)
 
 
+# Widest byte row one storage gather fetches whole. Past it XLA:TPU
+# splits the gather by COLUMNS ("mini-gather-slice": 255 lane tiles =
+# 32,640 B each) and materializes every column slab of the whole
+# operand: an R2D2 sequence stored as one 585,728 B row compiled to 18
+# slabs of [capacity, 32640] — a copy of the entire 9 GiB ring inside
+# every sample, 7.2 GiB of HLO temp, out of memory on the v5e (PERF.md
+# §6, PR 26).
+GATHER_ROW_MAX_BYTES = 255 * LANE
+
+
+def row_layout(shape: tuple[int, ...]) -> tuple[int, int, int]:
+    """-> (rows per item, bytes per row, padded bytes per row) of a
+    packable uint8 leaf: ONE row per item while the padded row stays
+    under GATHER_ROW_MAX_BYTES, else one row per slice of the leading
+    axis (a frame of a sequence's [L + stack - 1, H, W], a stack of
+    [L, H, W, stack]) — the frame ring's own row shape. The one place
+    the rule lives: PixelPacker lays storage out by it and utils/hbm.py
+    prices by it."""
+    nbytes = math.prod(shape)
+    if pad128(nbytes) <= GATHER_ROW_MAX_BYTES:
+        return 1, nbytes, pad128(nbytes)
+    row = nbytes // shape[0]
+    if pad128(row) > GATHER_ROW_MAX_BYTES:
+        raise ValueError(
+            f"uint8 leaf {shape}: one slice of its leading axis is "
+            f"{row} B, wider than the {GATHER_ROW_MAX_BYTES} B a "
+            f"storage gather fetches whole")
+    return shape[0], row, pad128(row)
+
+
 class PixelPacker:
     """Per-leaf codec: pixel frames <-> exactly-tiled byte rows.
 
     Built from an item spec (pytree of ShapeDtypeStruct for ONE item).
-    `storage_spec` rewrites packable leaves to [pad128(nbytes)] uint8
-    rows; `encode` flattens+pads an incoming [b, ...] item block to row
-    form inside the add jit; `decode` restores a sampled [b, rows]
-    gather to the original frame shape (touches only the batch).
+    A packable leaf is stored as `rows` byte rows of pad128 bytes each
+    (`row_layout`: one row per item, or one per leading-axis slice when
+    the item is wider than a gather fetches whole), ALL items' rows in
+    one 2-D [capacity * rows, row] buffer — a third dimension would
+    tile-pad `rows` to a multiple of 32. `storage_spec` is the shape of
+    ONE row and `rows_per_item` the multiplicity; `encode` turns an
+    incoming [*lead, b, ...] block into [*lead, b * rows, row] inside
+    the add jit; `decode` restores sampled [*lead, rows, row] rows (or
+    [*lead, row] for one-row leaves) to the original frame shape
+    (touches only the batch).
     """
 
     def __init__(self, item_spec: Any):
         leaves, treedef = jax.tree.flatten(item_spec)
         self._treedef = treedef
-        self._plan = []  # per leaf: None | (orig_shape, nbytes, row)
-        for leaf in leaves:
-            if packable(leaf):
-                nbytes = math.prod(leaf.shape)
-                self._plan.append((tuple(leaf.shape), nbytes,
-                                   pad128(nbytes)))
-            else:
-                self._plan.append(None)
+        # per leaf: None | (orig_shape, rows, row_bytes, padded_row)
+        self._plan = [(tuple(leaf.shape), *row_layout(tuple(leaf.shape)))
+                      if packable(leaf) else None for leaf in leaves]
 
     @property
     def packs_anything(self) -> bool:
@@ -188,45 +219,48 @@ class PixelPacker:
 
     def storage_spec(self, item_spec: Any) -> Any:
         leaves = jax.tree.leaves(item_spec)
-        out = []
-        for leaf, plan in zip(leaves, self._plan):
-            if plan is None:
-                out.append(leaf)
-            else:
-                _, _, row = plan
-                out.append(jax.ShapeDtypeStruct((row,), jnp.uint8))
+        out = [leaf if plan is None
+               else jax.ShapeDtypeStruct((plan[3],), jnp.uint8)
+               for leaf, plan in zip(leaves, self._plan)]
         return jax.tree.unflatten(self._treedef, out)
 
+    def rows_per_item(self) -> Any:
+        """Pytree of ints: storage rows one item occupies, per leaf."""
+        return jax.tree.unflatten(
+            self._treedef, [1 if plan is None else plan[1]
+                            for plan in self._plan])
+
     def encode(self, items: Any) -> Any:
-        """[*lead, *orig] leaves -> [*lead, row] byte rows (zero pad).
-        Any number of leading batch axes ([b] single-chip, [dp, b] on
-        the mesh) — the item dims are always the trailing ones."""
+        """[*lead, b, *orig] leaves -> [*lead, b * rows, row] byte rows
+        (zero pad). Any number of leading axes before the block axis b
+        ([b] single-chip, [dp, b] on the mesh)."""
         leaves = jax.tree.leaves(items)
         out = []
         for leaf, plan in zip(leaves, self._plan):
             if plan is None:
                 out.append(leaf)
-            else:
-                shape, nbytes, row = plan
-                lead = leaf.shape[:leaf.ndim - len(shape)]
-                flat = leaf.reshape(*lead, nbytes)
-                if row != nbytes:
-                    pad = [(0, 0)] * len(lead) + [(0, row - nbytes)]
-                    flat = jnp.pad(flat, pad)
-                out.append(flat)
+                continue
+            shape, rows, nbytes, row = plan
+            lead = leaf.shape[:leaf.ndim - len(shape)]
+            flat = leaf.reshape(*lead[:-1], lead[-1] * rows, nbytes)
+            if row != nbytes:
+                pad = [(0, 0)] * len(lead) + [(0, row - nbytes)]
+                flat = jnp.pad(flat, pad)
+            out.append(flat)
         return jax.tree.unflatten(self._treedef, out)
 
     def decode(self, items: Any) -> Any:
-        """Sampled [*lead, row] byte rows -> [*lead, *orig] frames."""
+        """Sampled byte rows -> [*lead, *orig] frames: [*lead, row] for
+        a one-row leaf, [*lead, rows, row] for a split one."""
         leaves = jax.tree.leaves(items)
         out = []
         for leaf, plan in zip(leaves, self._plan):
             if plan is None:
                 out.append(leaf)
-            else:
-                shape, nbytes, row = plan
-                lead = leaf.shape[:-1]
-                out.append(leaf[..., :nbytes].reshape(*lead, *shape))
+                continue
+            shape, rows, nbytes, _ = plan
+            lead = leaf.shape[:-1] if rows == 1 else leaf.shape[:-2]
+            out.append(leaf[..., :nbytes].reshape(*lead, *shape))
         return jax.tree.unflatten(self._treedef, out)
 
 
@@ -330,13 +364,23 @@ def cold_unpack(payload: bytes, plan: list[tuple], n: int) -> dict:
     return out
 
 
-def make_packer(item_spec: Any) -> tuple[PixelPacker | None, Any]:
-    """-> (packer or None, storage spec): the one place the packing
-    decision is made, shared by every replay class so storage layout
-    and the HBM budget (utils/hbm.py) cannot drift."""
+def make_packer(item_spec: Any) -> tuple[PixelPacker | None, Any, Any]:
+    """-> (packer or None, storage spec of ONE row, rows per item):
+    the one place the packing decision is made, shared by every replay
+    class so storage layout and the HBM budget (utils/hbm.py) cannot
+    drift. A leaf's buffer is [capacity * rows, *row spec]."""
     spec = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype), item_spec)
     packer = PixelPacker(spec)
     if packer.packs_anything:
-        return packer, packer.storage_spec(spec)
-    return None, spec
+        return packer, packer.storage_spec(spec), packer.rows_per_item()
+    return None, spec, jax.tree.map(lambda _: 1, spec)
+
+
+def gather_rows(buf: jax.Array, idx: jax.Array, rows: int) -> jax.Array:
+    """Item gather from a [capacity * rows, ...] buffer: [n, ...] for a
+    one-row leaf, [n, rows, ...] for a split one (item i is the `rows`
+    consecutive rows from i * rows)."""
+    if rows == 1:
+        return buf[idx]
+    return buf[idx[:, None] * rows + jnp.arange(rows, dtype=idx.dtype)]

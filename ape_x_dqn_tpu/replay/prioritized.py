@@ -24,8 +24,8 @@ import jax.numpy as jnp
 
 from ape_x_dqn_tpu.ops import sum_tree
 from ape_x_dqn_tpu.replay.packing import (PixelPacker, dus_rows,
-                                          dus_rows_per_shard, make_packer,
-                                          ring_write_size,
+                                          dus_rows_per_shard, gather_rows,
+                                          make_packer, ring_write_size,
                                           ring_write_start)
 
 
@@ -92,6 +92,7 @@ class PrioritizedReplay:
         self.eps = eps
         self._packer: PixelPacker | None = None
         self._storage_spec: Any = None
+        self._rows: Any = None   # storage rows per item, per leaf
         # packer construction is DETERMINISTIC: given a spec here, the
         # codec exists from construction — encode/decode behavior no
         # longer depends on whether init() happened to run first (the
@@ -101,7 +102,8 @@ class PrioritizedReplay:
             self._build_packer(item_spec)
 
     def _build_packer(self, item_spec: Any) -> None:
-        self._packer, self._storage_spec = make_packer(item_spec)
+        self._packer, self._storage_spec, self._rows = make_packer(
+            item_spec)
 
     # -- state construction ------------------------------------------------
 
@@ -117,8 +119,8 @@ class PrioritizedReplay:
                 "PrioritizedReplay has no item spec — pass item_spec to "
                 "the constructor or to init()")
         storage = jax.tree.map(
-            lambda s: jnp.zeros((self.capacity, *s.shape), s.dtype),
-            self._storage_spec)
+            lambda s, m: jnp.zeros((self.capacity * m, *s.shape), s.dtype),
+            self._storage_spec, self._rows)
         return ReplayState(
             storage=storage, tree=sum_tree.init(self.capacity),
             pos=jnp.int32(0), size=jnp.int32(0))
@@ -156,12 +158,12 @@ class PrioritizedReplay:
             items = self._packer.encode(items)
         if per_shard:
             storage = jax.tree.map(
-                lambda buf, x: dus_rows_per_shard(buf, x, start),
-                state.storage, items)
+                lambda buf, x, m: dus_rows_per_shard(buf, x, start * m),
+                state.storage, items, self._rows)
         else:
             storage = jax.tree.map(
-                lambda buf, x: dus_rows(buf, x, start, lead=nl),
-                state.storage, items)
+                lambda buf, x, m: dus_rows(buf, x, start * m, lead=nl),
+                state.storage, items, self._rows)
         pri = (td_abs + self.eps) ** self.alpha
         tree, pos, size = ring_finish(state.tree, idx, pri, pos1, size1,
                                       lead)
@@ -222,9 +224,12 @@ class PrioritizedReplay:
                     block: int) -> tuple[Any, jax.Array]:
         """-> (items [block, ...] in staging layout, stored leaf
         priorities [block]) for the region about to be overwritten."""
-        items = jax.tree.map(
-            lambda buf: jax.lax.dynamic_slice_in_dim(buf, start, block),
-            state.storage)
+        def region(buf, m):
+            out = jax.lax.dynamic_slice_in_dim(buf, start * m, block * m)
+            return out if m == 1 else out.reshape(block, m,
+                                                  *out.shape[1:])
+
+        items = jax.tree.map(region, state.storage, self._rows)
         if self._packer is not None:
             items = self._packer.decode(items)
         pri = jax.lax.dynamic_slice_in_dim(
@@ -264,7 +269,11 @@ class PrioritizedReplay:
         the order the K-batch cycle reads it."""
         idx, probs = sum_tree.sample(state.tree, rng, batch,
                                      size=state.size, chunks=chunks)
-        items = jax.tree.map(lambda buf: buf[idx], state.storage)
+        # scope = op metadata: the benchmark reads the gather's device
+        # time by this name (replay.seq_gather_hbm_share)
+        with jax.named_scope("replay.sample_gather"):
+            items = jax.tree.map(lambda buf, m: gather_rows(buf, idx, m),
+                                 state.storage, self._rows)
         if self._packer is not None:
             items = self._packer.decode(items)
         return items, idx, probs
@@ -361,11 +370,13 @@ class UniformReplayDevice:
         self.capacity = capacity
         self._packer: PixelPacker | None = None
         self._storage_spec: Any = None
+        self._rows: Any = None   # storage rows per item, per leaf
         if item_spec is not None:  # deterministic, like PrioritizedReplay
             self._build_packer(item_spec)
 
     def _build_packer(self, item_spec: Any) -> None:
-        self._packer, self._storage_spec = make_packer(item_spec)
+        self._packer, self._storage_spec, self._rows = make_packer(
+            item_spec)
 
     def init(self, item_spec: Any = None) -> ReplayState:
         if item_spec is not None:
@@ -375,8 +386,8 @@ class UniformReplayDevice:
                 "UniformReplayDevice has no item spec — pass item_spec "
                 "to the constructor or to init()")
         storage = jax.tree.map(
-            lambda s: jnp.zeros((self.capacity, *s.shape), s.dtype),
-            self._storage_spec)
+            lambda s, m: jnp.zeros((self.capacity * m, *s.shape), s.dtype),
+            self._storage_spec, self._rows)
         return ReplayState(storage=storage,
                            tree=jnp.zeros(1, jnp.float32),  # unused
                            pos=jnp.int32(0), size=jnp.int32(0))
@@ -388,7 +399,8 @@ class UniformReplayDevice:
         if self._packer is not None:
             items = self._packer.encode(items)
         storage = jax.tree.map(
-            lambda buf, x: dus_rows(buf, x, start), state.storage, items)
+            lambda buf, x, m: dus_rows(buf, x, start * m),
+            state.storage, items, self._rows)
         return ReplayState(
             storage=storage, tree=state.tree,
             pos=(start + b) % self.capacity,
@@ -402,7 +414,8 @@ class UniformReplayDevice:
         idx = sum_tree.chunk_major(
             jax.random.randint(rng, (batch,), 0,
                                jnp.maximum(state.size, 1)), chunks)
-        items = jax.tree.map(lambda buf: buf[idx], state.storage)
+        items = jax.tree.map(lambda buf, m: gather_rows(buf, idx, m),
+                             state.storage, self._rows)
         if self._packer is not None:
             items = self._packer.decode(items)
         return items, idx, jnp.ones(batch, jnp.float32)

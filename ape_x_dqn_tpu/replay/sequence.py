@@ -212,6 +212,7 @@ def batch_to_sequence_batch(items: Any):
     per-step [B, L, H, W, stack] obs rebuild is `stack` slices stacked
     on the channel axis — contiguous reads, no gather, fused into the
     learner jit."""
+    import jax
     import jax.numpy as jnp
 
     from ape_x_dqn_tpu.ops.losses import SequenceBatch
@@ -219,8 +220,9 @@ def batch_to_sequence_batch(items: Any):
         f = items["seq_frames"]
         length = items["actions"].shape[-1]
         stack = f.shape[1] - length + 1
-        obs = jnp.stack([f[:, c:c + length] for c in range(stack)],
-                        axis=-1)
+        with jax.named_scope("r2d2.stack_rebuild"):
+            obs = jnp.stack([f[:, c:c + length] for c in range(stack)],
+                            axis=-1)
     else:
         obs = items["obs"]
     return SequenceBatch(

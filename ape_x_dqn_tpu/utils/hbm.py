@@ -27,20 +27,20 @@ from typing import Any
 import numpy as np
 
 from ape_x_dqn_tpu.replay.frame_ring import frame_ring_mode
-from ape_x_dqn_tpu.replay.packing import packable, pad128
+from ape_x_dqn_tpu.replay.packing import packable, pad128, row_layout
 from ape_x_dqn_tpu.replay.sequence import sequence_frame_mode
 from ape_x_dqn_tpu.utils.misc import next_pow2
 
 
 def _leaf_stored_bytes(shape: tuple[int, ...], dtype) -> int:
     """Bytes one stored leaf actually occupies: pad128 byte rows when
-    the leaf is packed (the SAME packing.packable predicate the replay
-    storage uses — the budget must not drift from the layout), raw
-    bytes otherwise."""
-    n = math.prod(shape) * np.dtype(dtype).itemsize
+    the leaf is packed (the SAME packing.packable predicate and
+    row_layout rule the replay storage uses — the budget must not
+    drift from the layout), raw bytes otherwise."""
     if packable(SimpleNamespace(shape=shape, dtype=dtype)):
-        return pad128(n)
-    return n
+        rows, _, row = row_layout(tuple(shape))
+        return rows * row
+    return math.prod(shape) * np.dtype(dtype).itemsize
 
 # bytes reserved for: XLA reserved segment (~258MB measured), train/add
 # HLO temps (<=0.2GB measured at batch 512), host-staged ingest blocks,

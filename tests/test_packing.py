@@ -17,9 +17,11 @@ import numpy as np
 import pytest
 
 from ape_x_dqn_tpu.configs import get_config
-from ape_x_dqn_tpu.replay.packing import (PixelPacker, pad128, packable,
-                                          ring_write_start)
-from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
+from ape_x_dqn_tpu.replay.packing import (GATHER_ROW_MAX_BYTES,
+                                          PixelPacker, pad128, packable,
+                                          ring_write_start, row_layout)
+from ape_x_dqn_tpu.replay.prioritized import (PrioritizedReplay,
+                                              UniformReplayDevice)
 from ape_x_dqn_tpu.utils import hbm
 
 
@@ -66,6 +68,124 @@ def test_packer_roundtrip_preserves_pixels():
                                   np.asarray(items["obs"]))
     np.testing.assert_array_equal(np.asarray(back["action"]),
                                   np.asarray(items["action"]))
+
+
+# an item wider than a TPU gather fetches whole is stored one row per
+# leading-axis slice (ISSUE 26: an R2D2 sequence as ONE 585,728 B row
+# made XLA copy the whole replay per sample, by 32,640 B column slabs)
+ROW_LAYOUTS = [
+    ((84, 84), (1, 7056, 7168)),                  # a frame: one row
+    ((84, 84, 4), (1, 28224, 28288)),             # a flat stack: one row
+    ((11, 36, 36), (1, 14256, 14336)),            # a small sequence
+    ((83, 84, 84), (83, 7056, 7168)),             # R2D2, frame mode
+    ((80, 84, 84, 4), (80, 28224, 28288)),        # R2D2, stacked obs
+    ((11, 60, 60), (11, 3600, 3712)),
+]
+
+
+@pytest.mark.parametrize("shape,want", ROW_LAYOUTS)
+def test_row_layout_splits_only_items_wider_than_a_gather_fetches(
+        shape, want):
+    assert row_layout(shape) == want
+    rows, _, row = want
+    assert row <= GATHER_ROW_MAX_BYTES and row % 128 == 0
+    # one row per item for as long as that row can be gathered whole
+    assert (rows == 1) == (pad128(int(np.prod(shape)))
+                           <= GATHER_ROW_MAX_BYTES)
+
+
+def test_row_layout_refuses_a_slice_no_gather_fetches_whole():
+    with pytest.raises(ValueError, match="wider than"):
+        row_layout((4, 256, 256))
+
+
+def _split_spec():
+    return {"seq_frames": jax.ShapeDtypeStruct((11, 60, 60), jnp.uint8),
+            "mask": jax.ShapeDtypeStruct((8,), jnp.float32)}
+
+
+def _split_items(rng, n, lead=()):
+    return {"seq_frames": jnp.asarray(
+                rng.integers(0, 255, (*lead, n, 11, 60, 60)), jnp.uint8),
+            "mask": jnp.asarray(rng.random((*lead, n, 8)), jnp.float32)}
+
+
+def test_packer_roundtrip_of_a_split_leaf():
+    spec = _split_spec()
+    packer = PixelPacker(spec)
+    assert packer.storage_spec(spec)["seq_frames"].shape == (3712,)
+    assert packer.rows_per_item() == {"seq_frames": 11, "mask": 1}
+    for lead in ((), (2,)):
+        items = _split_items(np.random.default_rng(0), 5, lead)
+        rows = packer.encode(items)
+        assert rows["seq_frames"].shape == (*lead, 5 * 11, 3712)
+        back = packer.decode({
+            "seq_frames": rows["seq_frames"].reshape(*lead, 5, 11, 3712),
+            "mask": rows["mask"]})
+        np.testing.assert_array_equal(np.asarray(back["seq_frames"]),
+                                      np.asarray(items["seq_frames"]))
+
+
+@pytest.mark.parametrize("kind", ["prioritized", "uniform"])
+def test_replay_with_a_split_leaf_returns_what_was_added(kind):
+    """add (incl. a skip-to-head wrap), sample and read_region over a
+    [capacity * rows, row] buffer give back the items of each slot."""
+    cap, b = 8, 3
+    replay = (PrioritizedReplay(cap, item_spec=_split_spec())
+              if kind == "prioritized"
+              else UniformReplayDevice(cap, item_spec=_split_spec()))
+    state = replay.init()
+    assert state.storage["seq_frames"].shape == (cap * 11, 3712)
+    rng = np.random.default_rng(1)
+    slots = {}
+    for start in (0, 3, 0):            # the third add wraps to the head
+        items = _split_items(rng, b)
+        state = replay.add(state, items, jnp.ones(b))
+        for i in range(b):
+            slots[start + i] = jax.tree.map(lambda x: np.asarray(x)[i],
+                                            items)
+    got, idx, _ = replay.sample(state, jax.random.PRNGKey(0), 16)
+    for j, slot in enumerate(np.asarray(idx)):
+        for k in ("seq_frames", "mask"):
+            np.testing.assert_array_equal(np.asarray(got[k])[j],
+                                          slots[int(slot)][k])
+    if kind == "prioritized":
+        region, _ = replay.read_region(state, jnp.int32(2), 3)
+        for j, slot in enumerate((2, 3, 4)):
+            np.testing.assert_array_equal(
+                np.asarray(region["seq_frames"])[j],
+                slots[slot]["seq_frames"])
+
+
+def test_lockstep_add_of_a_split_leaf_writes_every_shard():
+    dp, cap, b = 2, 8, 2
+    replay = PrioritizedReplay(cap, item_spec=_split_spec())
+    state = jax.vmap(lambda _: replay.init())(jnp.arange(dp))
+    items = _split_items(np.random.default_rng(2), b, (dp,))
+    for _ in range(2):
+        state = replay.add_lockstep(state, items, jnp.ones((dp, b)))
+    # directed per-shard write: shard 0 at slot 5, shard 1 at slot 0
+    state = replay.add_at_lockstep(state, items, jnp.ones((dp, b)),
+                                   jnp.asarray([5, 0], jnp.int32))
+    stored = np.asarray(state.storage["seq_frames"]).reshape(
+        dp, cap, 11, 3712)[..., :3600].reshape(dp, cap, 11, 60, 60)
+    want = np.asarray(items["seq_frames"])
+    for d, slots in enumerate(((0, 2, 5), (0, 2))):
+        for slot in slots:
+            np.testing.assert_array_equal(stored[d, slot:slot + b],
+                                          want[d])
+
+
+def test_budget_prices_a_sequence_by_its_rows():
+    """utils/hbm.py prices the row layout the replay allocates: 83 rows
+    of 7,168 B per R2D2 sequence, not one row of 585,728 B."""
+    cfg = get_config("r2d2")
+    b = hbm.run_budget(cfg, (84, 84, 4), np.uint8, param_count=3_800_000)
+    assert b.detail["seq_item_bytes"] == 83 * 7168 + 80 * 16 + 2 * 2048
+    replay = PrioritizedReplay(8, item_spec={
+        "seq_frames": jax.ShapeDtypeStruct((83, 84, 84), jnp.uint8)})
+    rows = jax.eval_shape(replay.init).storage["seq_frames"]
+    assert rows.shape == (8 * 83, 7168)
 
 
 # ---------------------------------------------------------------------------
