@@ -99,7 +99,11 @@ def make_dqn_loss(net_apply: Callable, double: bool = True,
 class SequenceBatch(NamedTuple):
     """Fixed-length sequences with stored recurrent state (SURVEY.md §3.4)."""
 
-    obs: jax.Array        # [B, L, ...]
+    # [B, L, ...] — what conv1 reads: the compute dtype, already scaled
+    # (replay/sequence.batch_to_sequence_batch, once per SGD step), or
+    # uint8 stacks from a caller that has not prepared them, which the
+    # net then scales in each application
+    obs: jax.Array
     actions: jax.Array    # [B, L] int32
     rewards: jax.Array    # [B, L] f32 (per-step, undiscounted)
     terminals: jax.Array  # [B, L] f32 (1 at true terminal steps)

@@ -590,9 +590,14 @@ class DistSequenceLearner(_DistLearnerBase):
 
     def __init__(self, net_apply_seq: Callable, replay: PrioritizedReplay,
                  lcfg, rcfg, mesh: Mesh,
-                 optimizer: optax.GradientTransformation | None = None):
+                 optimizer: optax.GradientTransformation | None = None,
+                 compute_dtype=None):
+        """compute_dtype: as SequenceLearner's — the net's, so conv1's
+        input is prepared once per SGD step."""
         super().__init__(replay, lcfg, mesh, optimizer)
         self.net_apply_seq = net_apply_seq
+        self.compute_dtype = compute_dtype
+        self.burn_in = rcfg.burn_in
         self.loss_fn = make_r2d2_loss(
             net_apply_seq, burn_in=rcfg.burn_in, n_step=lcfg.n_step,
             gamma=lcfg.gamma, huber_delta=lcfg.huber_delta,
@@ -600,4 +605,5 @@ class DistSequenceLearner(_DistLearnerBase):
             priority_eta=rcfg.priority_eta)
 
     def _make_batch(self, items: Any):
-        return batch_to_sequence_batch(items)
+        return batch_to_sequence_batch(items, self.compute_dtype,
+                                       self.burn_in)

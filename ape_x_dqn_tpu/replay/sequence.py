@@ -38,8 +38,9 @@ def sequence_item_spec(obs_shape: tuple[int, ...], obs_dtype,
     [seq_len, H, W, stack] — consecutive steps share all but one frame,
     so stacked storage is ~stack x redundant (~4x at Atari shapes; the
     attested 100k-sequence capacity only fits in HBM without it).
-    Stacks are rebuilt by `batch_to_sequence_batch` with `stack` cheap
-    slices inside the learner jit.
+    Stacks are rebuilt by `batch_to_sequence_batch` inside the learner
+    jit, once per SGD step. Not free: as four `stack`ed slices the
+    rebuild was 16.8% of an R2D2 step on the v5e (PERF.md §5, PR 26).
     """
     import jax
     f32 = np.float32
@@ -205,26 +206,87 @@ def stack_items(items: list[dict]) -> dict:
             for k in items[0] if k != "priority"}
 
 
-def batch_to_sequence_batch(items: Any):
-    """Device item batch (dict of [B, L, ...]) -> losses.SequenceBatch.
+def _stacks(frames, start: int, stop: int, stack: int, prepare):
+    """Single frames [B, n, H, W] -> what conv1 reads for steps
+    start..stop, [B, stop - start, H, W, stack]: channel c of step t is
+    frame t + c, passed once through `prepare`.
 
-    Frame-mode items carry "seq_frames" [B, L+stack-1, H, W]; the
-    per-step [B, L, H, W, stack] obs rebuild is `stack` slices stacked
-    on the channel axis — contiguous reads, no gather, fused into the
-    learner jit."""
+    Four uint8 frames are the four bytes of one 32-bit word, so the
+    stack is shifts and ORs on whole words and one bitcast, written in
+    the order conv1 reads (H, W, then batch x time in the lanes with
+    the stack beside it) — the order `prepare` sees and materialises.
+    On the v5e the compiled step then holds one pass over the words, one
+    transpose of them and one unpack to the compute dtype straight into
+    conv1's operand layout, where four `stack`ed slices on the last
+    axis (any other dtype or depth: the plain form below) cost a
+    concatenate that writes at a tenth of HBM speed and two relayout
+    copies of the 4x larger stacks (PERF.md §6, PR 27)."""
     import jax
     import jax.numpy as jnp
 
+    bsz, _, h, w = frames.shape
+    steps = stop - start
+    if frames.dtype == jnp.uint8 and stack == 4:
+        by_pixel = frames.transpose(2, 3, 0, 1)           # [H, W, B, n]
+        word = None
+        for c in range(stack):
+            shifted = by_pixel[..., start + c:stop + c].reshape(
+                h, w, bsz * steps).astype(jnp.uint32) << (8 * c)
+            word = shifted if word is None else word | shifted
+        obs = prepare(jax.lax.bitcast_convert_type(word, jnp.uint8))
+        return obs.transpose(2, 0, 1, 3).reshape(bsz, steps, h, w, stack)
+    return prepare(jnp.stack(
+        [frames[:, start + c:stop + c] for c in range(stack)], axis=-1))
+
+
+def batch_to_sequence_batch(items: Any, compute_dtype=None,
+                            burn_in: int = 0):
+    """Device item batch (dict of [B, L, ...]) -> losses.SequenceBatch.
+
+    With `compute_dtype` (the net's; the learners pass it) `obs` is
+    what conv1 reads, prepared ONCE per SGD step: stacked, scaled as
+    `models.base.preprocess_obs` scales (which passes a float input
+    through, so the net needs no second entry point) and materialised
+    behind an optimization barrier, so that the four net applications
+    of the R2D2 loss (online/target x burn-in/trained steps) read
+    time-slices of one array and XLA neither repeats the preparation
+    per application nor folds the convert into each conv's operand
+    read (measured slower). `burn_in` is where the loss will cut the
+    time axis: the two sides are prepared as arrays of their own and
+    joined, and XLA hands each slice the array it was cut from; any
+    other cut is still right, only slower. Without `compute_dtype` the
+    stored dtype is kept (uint8 stacks, scaled by the net).
+
+    Frame-mode items carry "seq_frames" [B, L+stack-1, H, W] and the
+    per-step stacks are rebuilt here (`_stacks`); per-step storage
+    ("obs") has nothing to rebuild. Measured 16.8% of an R2D2 step
+    before PR 27, with the scale and relayout behind it 32.7%, so
+    everything between the decoded frames and conv1's operand sits
+    under the one scope `r2d2.stack_rebuild`."""
+    import jax
+    import jax.numpy as jnp
+
+    from ape_x_dqn_tpu.models.base import preprocess_obs
     from ape_x_dqn_tpu.ops.losses import SequenceBatch
-    if "seq_frames" in items:
-        f = items["seq_frames"]
-        length = items["actions"].shape[-1]
-        stack = f.shape[1] - length + 1
-        with jax.named_scope("r2d2.stack_rebuild"):
-            obs = jnp.stack([f[:, c:c + length] for c in range(stack)],
-                            axis=-1)
-    else:
-        obs = items["obs"]
+
+    def prepare(x):
+        if compute_dtype is None:
+            return x
+        return jax.lax.optimization_barrier(
+            preprocess_obs(x, compute_dtype))
+
+    length = items["actions"].shape[-1]
+    cuts = (0, burn_in, length) if 0 < burn_in < length else (0, length)
+    spans = list(zip(cuts, cuts[1:]))
+    with jax.named_scope("r2d2.stack_rebuild"):
+        if "seq_frames" in items:
+            f = items["seq_frames"]
+            stack = f.shape[1] - length + 1
+            parts = [_stacks(f, a, b, stack, prepare) for a, b in spans]
+        else:
+            parts = [prepare(items["obs"][:, a:b]) for a, b in spans]
+        obs = parts[0] if len(parts) == 1 else jnp.concatenate(parts,
+                                                               axis=1)
     return SequenceBatch(
         obs=obs, actions=items["actions"],
         rewards=items["rewards"], terminals=items["terminals"],

@@ -33,6 +33,7 @@ from ape_x_dqn_tpu.comm.socket_transport import batch_rows
 from ape_x_dqn_tpu.comm.transport import LoopbackTransport
 from ape_x_dqn_tpu.envs import make_env
 from ape_x_dqn_tpu.models import build_network
+from ape_x_dqn_tpu.models.base import dtype_of
 from ape_x_dqn_tpu.obs.core import build_obs
 from ape_x_dqn_tpu.obs.fleet import MAX_SPAN_IDS, FleetAggregator
 from ape_x_dqn_tpu.obs.health import TimedLock, make_lock
@@ -137,7 +138,8 @@ class ApexDriver:
             if self.family == "r2d2":
                 self.learner = DistSequenceLearner(
                     lambda p, o, s: self.net.apply(p, o, s),
-                    self.replay, cfg.learner, cfg.replay, self.mesh)
+                    self.replay, cfg.learner, cfg.replay, self.mesh,
+                    compute_dtype=dtype_of(cfg.network.compute_dtype))
             else:
                 self.learner = DistDQNLearner(self.net.apply, self.replay,
                                               cfg.learner, self.mesh)
@@ -156,7 +158,8 @@ class ApexDriver:
             if self.family == "r2d2":
                 self.learner = SequenceLearner(
                     lambda p, o, s: self.net.apply(p, o, s),
-                    self.replay, cfg.learner, cfg.replay)
+                    self.replay, cfg.learner, cfg.replay,
+                    compute_dtype=dtype_of(cfg.network.compute_dtype))
                 self.state = self.learner.init(
                     params, self.replay.init(item_spec), lkey)
             elif self.family == "dpg":

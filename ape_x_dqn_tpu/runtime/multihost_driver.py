@@ -60,6 +60,7 @@ from ape_x_dqn_tpu.obs.health import StallWatchdog, make_lock  # noqa: F401
 from ape_x_dqn_tpu.obs.core import build_obs
 from ape_x_dqn_tpu.envs import make_env
 from ape_x_dqn_tpu.models import build_network
+from ape_x_dqn_tpu.models.base import dtype_of
 from ape_x_dqn_tpu.parallel.dist_learner import (
     DistDQNLearner, DistSequenceLearner)
 from ape_x_dqn_tpu.parallel.inference_server import (
@@ -173,7 +174,8 @@ class MultihostApexDriver:
         if self.family == "r2d2":
             self.learner = DistSequenceLearner(
                 lambda p, o, s: self.net.apply(p, o, s),
-                self.replay, cfg.learner, cfg.replay, self.mesh)
+                self.replay, cfg.learner, cfg.replay, self.mesh,
+                compute_dtype=dtype_of(cfg.network.compute_dtype))
         else:
             self.learner = DistDQNLearner(self.net.apply, self.replay,
                                           cfg.learner, self.mesh)

@@ -38,8 +38,15 @@ class SequenceLearner(SingleChipLearner):
     """
 
     def __init__(self, net_apply_seq: Callable, replay, lcfg, rcfg,
-                 optimizer: optax.GradientTransformation | None = None):
-        """net_apply_seq(params, obs[B,T,...], (c,h)) -> (q[B,T,A], state)."""
+                 optimizer: optax.GradientTransformation | None = None,
+                 compute_dtype=None):
+        """net_apply_seq(params, obs[B,T,...], (c,h)) -> (q[B,T,A], state).
+        compute_dtype: the net's (cfg.network.compute_dtype), so that
+        conv1's input is prepared once per SGD step and not in each of
+        the loss's four net applications; None leaves uint8 for the net
+        to scale."""
+        self.compute_dtype = compute_dtype
+        self.burn_in = rcfg.burn_in
         self.net_apply_seq = net_apply_seq
         self.replay = replay
         self.lcfg = lcfg
@@ -50,13 +57,17 @@ class SequenceLearner(SingleChipLearner):
             double=lcfg.double_dqn, rescale=lcfg.value_rescale,
             priority_eta=rcfg.priority_eta)
 
+    def _make_batch(self, items: Any):
+        return batch_to_sequence_batch(items, self.compute_dtype,
+                                       self.burn_in)
+
     def _sgd_step(self, params, target_params, opt_state, step,
                   items, is_w):
         """One unroll/loss/optimizer/target-sync update on an already-
         sampled sequence batch (shared by the exact per-step path and
         the K-batch relaxation). Returns the eta-mixed per-sequence
         |TD| priorities (aux['td_abs'])."""
-        batch = batch_to_sequence_batch(items)
+        batch = self._make_batch(items)
         (loss, aux), grads = jax.value_and_grad(
             self.loss_fn, has_aux=True)(
             params, target_params, batch, is_w)
