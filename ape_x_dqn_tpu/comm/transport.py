@@ -7,7 +7,7 @@ collectives over NCCL (SURVEY.md §2.2 "Comm"). The TPU-native mapping
 - learner-internal collectives: XLA psum/all-gather over ICI (see
   parallel/dist_learner.py) — nothing to do here.
 - learner -> inference-server weight publication: device-to-device
-  resharding over ICI (DistDQNLearner.publish_params).
+  resharding over ICI (DistLearner.publish_params).
 - actor <-> inference server and actor -> replay ingest: host-side
   message passing. In-process that's thread-safe queues (the
   `LoopbackTransport` below, also the deterministic test harness per

@@ -25,17 +25,21 @@ collectives" recipe):
 Ingest expects items pre-split per shard: [dp, B_ingest, ...]. The
 host-side driver round-robins actor staging units across shards.
 
-Two concrete learners share the machinery via _DistLearnerBase:
-DistDQNLearner (flat n-step transitions, SURVEY.md §3.3) and
-DistSequenceLearner (R2D2 stored-state sequences, §3.4 — the r2d2
-config attests dp=4 x tp=2). They differ only in the loss and how
-sampled items become a loss batch.
+DistLearner is runtime/learner.py's cycle with what sharding changes
+overridden; like it, it runs whichever family it is given — flat
+n-step transitions (SURVEY.md §3.3) or R2D2 stored-state sequences
+(§3.4 — the r2d2 config attests dp=4 x tp=2). For sequences the replay
+shards hold whole sequences as items (same per-shard trees); the
+burn-in unroll + n-step sequence loss runs on the flattened
+[dp*b_local] sequence batch — the LSTM time axis stays unsharded
+(SURVEY.md §5 long-context: shard the batch axis, scan the time axis),
+and the per-SEQUENCE eta-mixed |TD| writes back per shard.
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Callable, NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -44,12 +48,9 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ape_x_dqn_tpu.obs import learning as learn_obs
-from ape_x_dqn_tpu.ops.losses import (
-    TransitionBatch, make_dqn_loss, make_r2d2_loss)
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay, ReplayState
-from ape_x_dqn_tpu.replay.sequence import batch_to_sequence_batch
 from ape_x_dqn_tpu.parallel.sharding import make_param_shardings
-from ape_x_dqn_tpu.runtime.learner import make_optimizer
+from ape_x_dqn_tpu.runtime.learner import LearnerFamily, SingleChipLearner
 
 
 class DistTrainState(NamedTuple):
@@ -61,30 +62,27 @@ class DistTrainState(NamedTuple):
     step: jax.Array       # scalar int32
 
 
-class _DistLearnerBase:
-    """Shared (dp, tp) machinery; subclasses set self.loss_fn and
-    override _make_batch(flattened items) -> loss batch."""
+class DistLearner(SingleChipLearner):
+    """The learner cycle over a (dp, tp) mesh. Every jitted training
+    endpoint (train_step, train_step_k, sample_k, learn_k, train_many)
+    is SingleChipLearner's; defined here is what sharding changes."""
 
-    def __init__(self, replay: PrioritizedReplay, lcfg, mesh: Mesh,
+    def __init__(self, family: LearnerFamily, replay: PrioritizedReplay,
+                 lcfg, mesh: Mesh,
                  optimizer: optax.GradientTransformation | None = None):
         """`replay` is configured with the PER-SHARD capacity."""
-        self.replay = replay
-        self.lcfg = lcfg
+        super().__init__(family, replay, lcfg, optimizer)
         self.mesh = mesh
         self.dp = mesh.shape["dp"]
         assert lcfg.batch_size % self.dp == 0, \
             "batch_size must divide by dp"
         self.b_local = lcfg.batch_size // self.dp
-        self.optimizer = optimizer or make_optimizer(lcfg)
         self._dp_sharding = NamedSharding(mesh, P("dp"))
         # coalesced ingest groups [g, dp, ...]: replicate the group
         # axis, shard the dp axis (add_many)
         self._group_sharding = NamedSharding(mesh, P(None, "dp"))
         self._repl_sharding = NamedSharding(mesh, P())
         self._reshard = None  # publish_params' cached jit (built once)
-
-    def _make_batch(self, items: Any) -> Any:
-        raise NotImplementedError
 
     # -- state construction ------------------------------------------------
 
@@ -180,64 +178,42 @@ class _DistLearnerBase:
 
     def _sgd_step(self, params, target_params, opt_state, step,
                   items, w):
-        """One loss/grad/optimizer/target-sync update on an
-        already-sampled [dp, b_local] batch (shared by the exact
-        per-step path and the K-batch relaxation). `w` is the raw IS
-        weight ([dp, b_local]); max-normalization happens here so each
-        training batch is normalized over exactly its own draws."""
+        """One SGD step on an already-sampled [dp, b_local] batch.
+        `w` is the raw IS weight ([dp, b_local]); max-normalization
+        happens here so each training batch is normalized over exactly
+        its own draws. The loss runs on the flattened batch under the
+        dp sharding constraint (GSPMD emits the gradient psum); the
+        |TD|s go back per shard."""
         w = w / jnp.maximum(w.max(), 1e-12)
-        batch = self._make_batch(jax.tree.map(self._flat, items))
-        wf = self._flat(w)
-        (loss, aux), grads = jax.value_and_grad(
-            self.loss_fn, has_aux=True)(
-            params, target_params, batch, wf)
-        updates, opt_state = self.optimizer.update(
-            grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        step = step + 1
-        sync = (step % self.lcfg.target_sync_every == 0)
-        target_params = jax.tree.map(
-            lambda t, p: jnp.where(sync, p, t), target_params, params)
-        td_shard = aux["td_abs"].reshape(self.dp, self.b_local)
-        # learning-health scalars: the flat reductions inside sgd_diag
-        # run over the [dp]-sharded batch, so GSPMD lowers them to the
-        # psum'd GLOBAL statistics; the per-shard mean-|TD| min/max
-        # exposes shard skew the global mean would average away
+        batch = self.family.make_batch(jax.tree.map(self._flat, items))
+        params, target_params, opt_state, step, td_abs, metrics = \
+            self._sgd_update(params, target_params, opt_state, step,
+                             batch, self._flat(w))
+        td_shard = td_abs.reshape(self.dp, self.b_local)
+        # the flat reductions inside _sgd_update's diag run over the
+        # [dp]-sharded batch, so GSPMD lowers them to the psum'd GLOBAL
+        # statistics; the per-shard mean-|TD| min/max exposes shard
+        # skew the global mean would average away
         shard_means = td_shard.mean(axis=1)
-        diag = learn_obs.sgd_diag(aux, wf, grads, updates, params)
-        diag["shard_td_mean_min"] = shard_means.min()
-        diag["shard_td_mean_max"] = shard_means.max()
-        metrics = {"loss": loss, "q_mean": aux["q_mean"],
-                   "td_abs_mean": aux["td_abs"].mean(),
-                   "grad_norm": optax.global_norm(grads),
-                   "diag": diag}
+        metrics["diag"]["shard_td_mean_min"] = shard_means.min()
+        metrics["diag"]["shard_td_mean_max"] = shard_means.max()
         return params, target_params, opt_state, step, td_shard, metrics
 
-    def _train_step(self, state: DistTrainState
-                    ) -> tuple[DistTrainState, dict]:
-        rng, sk = self._split_rng(state.rng)
-        items, idx, w = self._sample_weighted(state.replay, sk,
-                                              self.b_local)
-        params, target_params, opt_state, step, td_shard, metrics = \
-            self._sgd_step(state.params, state.target_params,
-                           state.opt_state, state.step, items, w)
-        # fused path: draw and write-back see the same shard trees, so
-        # the priority-staleness delta is identically 0 (pri_then=None)
-        metrics["diag"] = {**metrics.get("diag", {}),
-                           **learn_obs.replay_health_sharded(
-                               self.replay, state.replay, idx, None)}
-        # per-shard priority write-back
-        new_replay = jax.vmap(
-            lambda rs, i, td: self.replay.update_priorities(rs, i, td)
-        )(state.replay, idx, td_shard)
-        return DistTrainState(params, target_params, opt_state, new_replay,
-                              rng, step), metrics
+    def _replay_health(self, replay_state: ReplayState, idx, pri_then):
+        return learn_obs.replay_health_sharded(
+            self.replay, replay_state, idx, pri_then)
+
+    def _write_back(self, replay_state: ReplayState, idx, td_parts):
+        """Per-shard priority write-back: the [dp, b_local] parts pair
+        with idx[:, j*b_local:(j+1)*b_local]."""
+        return jax.vmap(self.replay.update_priorities)(
+            replay_state, idx, jnp.concatenate(td_parts, axis=1))
 
     def _sample_stage(self, replay_state: ReplayState, sk, k: int):
         """Pure SAMPLE stage of the split K-batch cycle, dist form of
-        runtime/learner.py:SingleChipLearner._sample_stage: one
-        per-shard stratified K*b_local descent + gather + global IS
-        weights, chunked for the K SGD steps.
+        SingleChipLearner._sample_stage: one per-shard stratified
+        K*b_local descent + gather + global IS weights, chunked for
+        the K SGD steps.
 
         Order of the draw: CHUNK-MAJOR within every shard — position
         j*b_local + i = stratum i*K + j (ops/sum_tree.py::chunk_major
@@ -266,157 +242,12 @@ class _DistLearnerBase:
 
         return jax.tree.map(split, items), idx, split(w), pri
 
-    def _learn_stage(self, state: DistTrainState, sample,
-                     k: int) -> tuple[DistTrainState, dict]:
-        """Pure LEARN stage: K SGD steps over an already-drawn sample
-        + ONE vmapped per-shard write-back + target sync (static
-        unrolled loop — lax.scan conv bodies are pathologically slow
-        on CPU). `state.rng` must already be advanced past the draw.
-        Step j trains on chunk j and its [dp, b_local] |TD|s pair with
-        idx[:, j*b_local:(j+1)*b_local]: idx is in the draw's
-        chunk-major order (_sample_stage), so the write-back is a plain
-        concatenate of the K parts — the same (leaf, |TD|) pairs the
-        stratum-order idx and an inverse chunk transform gave; only the
-        order among duplicate leaves inside one `.at[].set` differs,
-        which XLA never specified."""
-        items_k, idx, w_k, pri_k = sample
-        params, target_params, opt_state, step = (
-            state.params, state.target_params, state.opt_state,
-            state.step)
-        td_parts = []
-        metrics = None
-        for j in range(k):
-            it = jax.tree.map(lambda x: x[j], items_k)
-            params, target_params, opt_state, step, td_shard, metrics = \
-                self._sgd_step(params, target_params, opt_state, step,
-                               it, w_k[j])
-            td_parts.append(td_shard)
-        # write-back-time replay health: the shard trees NOW vs the
-        # descent-time priorities pri_k — the measured staleness the
-        # prefetch/K-batch relaxations accept (ROADMAP item 3)
-        metrics["diag"] = {**metrics.get("diag", {}),
-                           **learn_obs.replay_health_sharded(
-                               self.replay, state.replay, idx, pri_k)}
-        # td_parts[j] pairs with idx[:, j*b_local:(j+1)*b_local]: both
-        # sides of the write-back are in the draw's chunk-major order
-        td_all = jnp.concatenate(td_parts, axis=1)
-        new_replay = jax.vmap(
-            lambda rs, i, td: self.replay.update_priorities(rs, i, td)
-        )(state.replay, idx, td_all)
-        return DistTrainState(params, target_params, opt_state,
-                              new_replay, state.rng, step), metrics
-
     def _split_rng(self, rng):
         """[dp] keys -> ([dp] advanced, [dp] subkeys)."""
         keys = jax.vmap(lambda kk: jax.random.split(kk, 2))(rng)
         return keys[:, 0], keys[:, 1]
 
-    def _train_step_k(self, state: DistTrainState,
-                      k: int) -> tuple[DistTrainState, dict]:
-        """K grad-steps from ONE per-shard stratified sample + ONE
-        priority write-back — the K-batch relaxation
-        (LearnerConfig.sample_chunk), dist form of
-        runtime/learner.py:DQNLearner._train_step_k; same staleness
-        semantics, same interleaved-strata chunking (chunk j takes
-        strata {j, j+K, ...} within every shard so each chunk spans
-        the full per-shard priority range). Composed from the split
-        _sample_stage/_learn_stage so the fused and double-buffered
-        paths cannot drift."""
-        rng, sk = self._split_rng(state.rng)
-        sample = self._sample_stage(state.replay, sk, k)
-        return self._learn_stage(state._replace(rng=rng), sample, k)
-
-    # -- jitted endpoints --------------------------------------------------
-
-    @partial(jax.jit, static_argnums=0, donate_argnums=1)
-    def train_step(self, state: DistTrainState):
-        return self._train_step(state)
-
-    @partial(jax.jit, static_argnums=(0, 2), donate_argnums=1)
-    def train_step_k(self, state: DistTrainState, k: int):
-        """Scan-free K-batch macro-step (see DQNLearner.train_step_k)."""
-        return self._train_step_k(state, k)
-
-    @partial(jax.jit, static_argnums=(0, 2))
-    def sample_k(self, state: DistTrainState, k: int):
-        """Standalone SAMPLE dispatch (host-side double-buffering, see
-        SingleChipLearner.sample_k) — NOT donated; the caller still
-        owns `state` for the learn_k on the previous draw.
-        -> (sample, advanced [dp] rng)."""
-        rng, sk = self._split_rng(state.rng)
-        return self._sample_stage(state.replay, sk, k), rng
-
-    @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
-    def learn_k(self, state: DistTrainState, sample, k: int):
-        """Standalone LEARN dispatch on a sample drawn earlier by
-        sample_k (see SingleChipLearner.learn_k; sample not donated —
-        its buffers match no output shape)."""
-        return self._learn_stage(state, sample, k)
-
-    @partial(jax.jit, static_argnums=(0, 2), donate_argnums=1)
-    def train_many(self, state: DistTrainState, n: int):
-        """n grad-steps per dispatch; with sample_chunk=K>1 runs n//K
-        K-batch macro-steps plus exact singles for any remainder; with
-        sample_prefetch the macro-steps run double-buffered (next
-        per-shard descent drawn before this macro-step's write-back —
-        see SingleChipLearner._train_many_prefetch for the staleness
-        contract)."""
-        k = getattr(self.lcfg, "sample_chunk", 1)
-
-        def body(s, _):
-            s, m = self._train_step(s)
-            return s, m
-
-        if getattr(self.lcfg, "sample_prefetch", False):
-            return self._train_many_prefetch(state, n, max(k, 1), body)
-
-        if k <= 1:
-            state, metrics = jax.lax.scan(body, state, None, length=n)
-            return state, jax.tree.map(lambda x: x[-1], metrics)
-
-        def body_k(s, _):
-            s, m = self._train_step_k(s, k)
-            return s, m
-
-        # remainder singles FIRST: the returned last-step metrics then
-        # come from the K-batch macro-steps that did the bulk of the
-        # work (see DQNLearner.train_many)
-        metrics = None
-        if n % k:
-            state, metrics = jax.lax.scan(body, state, None,
-                                          length=n % k)
-        if n // k:
-            state, metrics = jax.lax.scan(body_k, state, None,
-                                          length=n // k)
-        return state, jax.tree.map(lambda x: x[-1], metrics)
-
-    def _train_many_prefetch(self, state: DistTrainState, n: int,
-                             k: int, body):
-        """Dist mirror of SingleChipLearner._train_many_prefetch: the
-        scan body draws macro-step i+1's per-shard sample from the
-        shard trees BEFORE macro-step i's vmapped write-back, so XLA
-        overlaps the next descent/gather with the K SGD steps; one
-        macro-dispatch of priority staleness, prologue-fresh first
-        step, final prefetched sample discarded."""
-        metrics = None
-        if n % k:
-            state, metrics = jax.lax.scan(body, state, None,
-                                          length=n % k)
-        if n // k:
-            rng, sk = self._split_rng(state.rng)
-            pending = self._sample_stage(state.replay, sk, k)
-            state = state._replace(rng=rng)
-
-            def body_pf(carry, _):
-                s, pend = carry
-                rng, sk = self._split_rng(s.rng)
-                nxt = self._sample_stage(s.replay, sk, k)
-                s, m = self._learn_stage(s._replace(rng=rng), pend, k)
-                return (s, nxt), m
-
-            (state, _), metrics = jax.lax.scan(
-                body_pf, (state, pending), None, length=n // k)
-        return state, jax.tree.map(lambda x: x[-1], metrics)
+    # -- jitted endpoints (the training ones are inherited) ----------------
 
     @partial(jax.jit, static_argnums=0, donate_argnums=1)
     def add(self, state: DistTrainState, items: Any,
@@ -556,54 +387,3 @@ class _DistLearnerBase:
             "fill_min": float(fill.min()),
             "fill_max": float(fill.max()),
         }
-
-
-class DistDQNLearner(_DistLearnerBase):
-    """Flat n-step double-DQN over the mesh (SURVEY.md §3.3)."""
-
-    def __init__(self, net_apply: Callable, replay: PrioritizedReplay,
-                 lcfg, mesh: Mesh,
-                 optimizer: optax.GradientTransformation | None = None):
-        super().__init__(replay, lcfg, mesh, optimizer)
-        self.net_apply = net_apply
-        self.loss_fn = make_dqn_loss(
-            net_apply, double=lcfg.double_dqn, huber_delta=lcfg.huber_delta,
-            rescale=lcfg.value_rescale)
-
-    def _make_batch(self, items: Any) -> TransitionBatch:
-        return TransitionBatch(
-            obs=items["obs"], actions=items["action"],
-            rewards=items["reward"], next_obs=items["next_obs"],
-            discounts=items["discount"])
-
-
-class DistSequenceLearner(_DistLearnerBase):
-    """R2D2 stored-state sequences over the mesh (SURVEY.md §3.4; the
-    r2d2 config attests dp=4 x tp=2).
-
-    Replay shards hold whole sequences as items (same per-shard trees);
-    the burn-in unroll + n-step sequence loss runs on the flattened
-    [dp*b_local] sequence batch — the LSTM time axis stays unsharded
-    (SURVEY.md §5 long-context: shard the batch axis, scan the time
-    axis), and the per-SEQUENCE eta-mixed |TD| writes back per shard.
-    """
-
-    def __init__(self, net_apply_seq: Callable, replay: PrioritizedReplay,
-                 lcfg, rcfg, mesh: Mesh,
-                 optimizer: optax.GradientTransformation | None = None,
-                 compute_dtype=None):
-        """compute_dtype: as SequenceLearner's — the net's, so conv1's
-        input is prepared once per SGD step."""
-        super().__init__(replay, lcfg, mesh, optimizer)
-        self.net_apply_seq = net_apply_seq
-        self.compute_dtype = compute_dtype
-        self.burn_in = rcfg.burn_in
-        self.loss_fn = make_r2d2_loss(
-            net_apply_seq, burn_in=rcfg.burn_in, n_step=lcfg.n_step,
-            gamma=lcfg.gamma, huber_delta=lcfg.huber_delta,
-            double=lcfg.double_dqn, rescale=lcfg.value_rescale,
-            priority_eta=rcfg.priority_eta)
-
-    def _make_batch(self, items: Any):
-        return batch_to_sequence_batch(items, self.compute_dtype,
-                                       self.burn_in)

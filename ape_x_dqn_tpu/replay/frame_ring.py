@@ -195,7 +195,7 @@ class FrameRingReplay(PrioritizedReplay):
     valid_mask dead-slot zeroing) is inherited, while storage
     construction, segment `add`, the stack-gathering `sample_items`,
     and the dead-slot-preserving `update_priorities` are overridden —
-    so DQNLearner and DistDQNLearner use either layout unchanged. `add`
+    so both learners use either layout unchanged. `add`
     consumes staged segments {field: [G, ...]} with priorities [G, B]
     instead of flat items.
     """
@@ -453,5 +453,5 @@ class FrameRingReplay(PrioritizedReplay):
         state (scalar out) and on the dp-sharded lockstep state
         ([dp] out), where it feeds the per-shard fill stats of the
         multichip lane (bench.py --multichip) and
-        `_DistLearnerBase.shard_stats`."""
+        `DistLearner.shard_stats`."""
         return (state.storage["next_off"] > 0).sum(axis=-1)

@@ -1,6 +1,6 @@
 """Ape-X DPG learner: critic + policy + Polyak targets in one jit.
 
-The continuous-control counterpart of runtime/learner.DQNLearner
+The continuous-control counterpart of runtime/learner.SingleChipLearner
 (SURVEY.md §2.1 config 5, §2.2 "DPG actor-critic"): one donated XLA graph
 fuses prioritized sequence sampling, the critic TD update, the
 deterministic-policy-gradient actor update (through the *updated*
@@ -55,8 +55,8 @@ class DPGLearner:  # apexlint: parity(no train_step_k/sample_k/learn_k — K-chu
                  replay, lcfg):
         if getattr(lcfg, "sample_chunk", 1) > 1:
             # loud, not silent: the K-batch relaxation is implemented
-            # for the flat-transition DQN learners only (see
-            # runtime/sequence_learner.py for the same gate)
+            # by runtime/learner.py's cycle, which this learner does
+            # not share
             raise ValueError(
                 "learner.sample_chunk > 1 is not implemented by the "
                 "DPG learner — set sample_chunk=1")

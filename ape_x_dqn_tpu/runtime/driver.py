@@ -33,13 +33,10 @@ from ape_x_dqn_tpu.comm.socket_transport import batch_rows
 from ape_x_dqn_tpu.comm.transport import LoopbackTransport
 from ape_x_dqn_tpu.envs import make_env
 from ape_x_dqn_tpu.models import build_network
-from ape_x_dqn_tpu.models.base import dtype_of
 from ape_x_dqn_tpu.obs.core import build_obs
 from ape_x_dqn_tpu.obs.fleet import MAX_SPAN_IDS, FleetAggregator
 from ape_x_dqn_tpu.obs.health import TimedLock, make_lock
 from ape_x_dqn_tpu.obs.trace import NULL_SPAN
-from ape_x_dqn_tpu.parallel.dist_learner import (
-    DistDQNLearner, DistSequenceLearner)
 from ape_x_dqn_tpu.parallel.inference_server import (
     BatchedInferenceServer, MultiPolicyInferenceServer, build_serving_tier)
 from ape_x_dqn_tpu.parallel.mesh import make_mesh
@@ -47,16 +44,13 @@ from ape_x_dqn_tpu.replay.cold_store import ColdStore
 from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
 from ape_x_dqn_tpu.runtime.family import (
-    actor_class, family_of, family_setup, server_apply_fn,
-    warmup_example)
-from ape_x_dqn_tpu.runtime.dpg_learner import DPGLearner
+    actor_class, build_learner, family_of, family_setup,
+    server_apply_fn, warmup_example)
 from ape_x_dqn_tpu.runtime.evaluation import (
     EvalWorker, make_eval_policy_factory)
 from ape_x_dqn_tpu.runtime.ingest import IngestStager
-from ape_x_dqn_tpu.runtime.learner import DQNLearner
 from ape_x_dqn_tpu.runtime.remediation import (
     Actuators, RemediationEngine)
-from ape_x_dqn_tpu.runtime.sequence_learner import SequenceLearner
 from ape_x_dqn_tpu.runtime.single_process import build_replay
 from ape_x_dqn_tpu.utils.checkpoint import CheckpointManager
 from ape_x_dqn_tpu.utils.hbm import (
@@ -118,10 +112,6 @@ class ApexDriver:
             cfg, self.spec.obs_shape, self.spec.obs_dtype,
             param_count=sum(int(np.prod(l.shape))
                             for l in jax.tree.leaves(params)))
-        if self.is_dist and self.family == "dpg":
-            raise NotImplementedError(
-                "the distributed learner covers the DQN and R2D2 "
-                "families; DPG nets are small — run dp=tp=1")
         if self.is_dist:
             # Multi-chip learner (SURVEY.md §7 step 7): replay shards +
             # batch shards + gradient psum over the (dp, tp) mesh; ingest
@@ -135,14 +125,8 @@ class ApexDriver:
             self.mesh = make_mesh(dp=cfg.parallel.dp, tp=cfg.parallel.tp)
             shard_cap = next_pow2(max(cfg.replay.capacity // self.dp, 2))
             self.replay = self._build_prioritized(shard_cap)
-            if self.family == "r2d2":
-                self.learner = DistSequenceLearner(
-                    lambda p, o, s: self.net.apply(p, o, s),
-                    self.replay, cfg.learner, cfg.replay, self.mesh,
-                    compute_dtype=dtype_of(cfg.network.compute_dtype))
-            else:
-                self.learner = DistDQNLearner(self.net.apply, self.replay,
-                                              cfg.learner, self.mesh)
+            self.learner = build_learner(cfg, self.net, self.replay,
+                                         self.mesh)
             self.state = self.learner.init(  # guarded-by: _state_lock
                 params, item_spec, component_key(cfg.seed, "learner"))
             self.capacity = shard_cap * self.dp
@@ -154,24 +138,12 @@ class ApexDriver:
             self.replay = (self._build_prioritized(
                                next_pow2(cfg.replay.capacity))
                            if self._frame_mode else build_replay(cfg.replay))
+            self.learner = build_learner(cfg, self.net, self.replay)
             lkey = component_key(cfg.seed, "learner")
-            if self.family == "r2d2":
-                self.learner = SequenceLearner(
-                    lambda p, o, s: self.net.apply(p, o, s),
-                    self.replay, cfg.learner, cfg.replay,
-                    compute_dtype=dtype_of(cfg.network.compute_dtype))
-                self.state = self.learner.init(
-                    params, self.replay.init(item_spec), lkey)
-            elif self.family == "dpg":
-                actor_net, critic_net = self.net
-                self.learner = DPGLearner(
-                    actor_net.apply, critic_net.apply, self.replay,
-                    cfg.learner)
+            if self.family == "dpg":
                 self.state = self.learner.init(
                     params[0], params[1], self.replay.init(item_spec), lkey)
             else:
-                self.learner = DQNLearner(self.net.apply, self.replay,
-                                          cfg.learner)
                 self.state = self.learner.init(
                     params, self.replay.init(item_spec), lkey)
             self.capacity = self.replay.capacity

@@ -1,12 +1,17 @@
-"""Model-family dispatch shared by the driver and remote actor hosts.
+"""Model-family dispatch shared by the drivers and remote actor hosts.
 
 A RunConfig's network kind selects one of three runtime families —
 flat-DQN ("dqn"), recurrent R2D2 ("r2d2"), continuous Ape-X DPG
 ("dpg") — which differ in the inference-server protocol (plain Q-values
-vs stateful {obs,c,h} vs {a,q} actor-critic), the actor class, and the
-AOT-warmup example. ApexDriver (runtime/driver.py) and run_actor_host
-(runtime/actor_host.py) must agree on all three, so the dispatch lives
-here once.
+vs stateful {obs,c,h} vs {a,q} actor-critic), the actor class, the
+AOT-warmup example, and what the learner trains on (its loss and how
+sampled items become the loss's batch). ApexDriver (runtime/driver.py),
+MultihostApexDriver, single_process.py and run_actor_host
+(runtime/actor_host.py) must agree on these, so the dispatch lives here
+once: `build_learner` is the one place a learner is constructed, and
+the table behind `learner_family` the one place a loss is bound. A new
+Q-learning family is a loss in ops/losses.py, a row in that table and
+its net — no edit to a learner or a driver.
 """
 
 from __future__ import annotations
@@ -17,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ape_x_dqn_tpu.configs import RunConfig
+from ape_x_dqn_tpu.models.base import dtype_of
 from ape_x_dqn_tpu.replay.frame_ring import (frame_ring_mode,
                                              frame_segment_spec)
 from ape_x_dqn_tpu.replay.sequence import (sequence_frame_mode,
@@ -67,6 +73,99 @@ def server_apply_fn(family: str, net: Any) -> Callable:
             return {"a": a, "q": q}
         return apply_dpg
     return lambda p, obs: net.apply(p, obs)
+
+
+# -- the learner's side ------------------------------------------------------
+
+
+def transition_batch(items: Any):
+    """Sampled flat n-step items (transition_item_spec's keys, which
+    the frame-ring layout rebuilds too) -> losses.TransitionBatch."""
+    from ape_x_dqn_tpu.ops.losses import TransitionBatch
+
+    return TransitionBatch(
+        obs=items["obs"], actions=items["action"],
+        rewards=items["reward"], next_obs=items["next_obs"],
+        discounts=items["discount"])
+
+
+def dqn_family(net_apply: Callable, lcfg):
+    """Flat n-step double-DQN. net_apply(params, obs[B,...]) -> q[B,A]."""
+    from ape_x_dqn_tpu.ops.losses import make_dqn_loss
+    from ape_x_dqn_tpu.runtime.learner import LearnerFamily
+
+    return LearnerFamily(
+        name="dqn",
+        loss_fn=make_dqn_loss(
+            net_apply, double=lcfg.double_dqn,
+            huber_delta=lcfg.huber_delta, rescale=lcfg.value_rescale),
+        make_batch=transition_batch,
+        net_apply=net_apply)
+
+
+def r2d2_family(net_apply_seq: Callable, lcfg, rcfg, compute_dtype=None):
+    """R2D2 stored-state sequences (SURVEY.md §3.4): burn-in unroll,
+    n-step double-DQN sequence loss with value rescaling, eta-mixed
+    per-sequence priorities (aux['td_abs']).
+    net_apply_seq(params, obs[B,T,...], (c,h)) -> (q[B,T,A], state).
+    compute_dtype: the net's (cfg.network.compute_dtype), so that
+    conv1's input is prepared once per SGD step and not in each of the
+    loss's four net applications; None leaves uint8 for the net to
+    scale."""
+    from ape_x_dqn_tpu.ops.losses import make_r2d2_loss
+    from ape_x_dqn_tpu.replay.sequence import batch_to_sequence_batch
+    from ape_x_dqn_tpu.runtime.learner import LearnerFamily
+
+    return LearnerFamily(
+        name="r2d2",
+        loss_fn=make_r2d2_loss(
+            net_apply_seq, burn_in=rcfg.burn_in, n_step=lcfg.n_step,
+            gamma=lcfg.gamma, huber_delta=lcfg.huber_delta,
+            double=lcfg.double_dqn, rescale=lcfg.value_rescale,
+            priority_eta=rcfg.priority_eta),
+        make_batch=lambda items: batch_to_sequence_batch(
+            items, compute_dtype, rcfg.burn_in),
+        net_apply=net_apply_seq,
+        apply_attr="net_apply_seq",
+        metric_keys=("valid_frac",))
+
+
+# family name -> (cfg, net) -> LearnerFamily. DPG is not a row: its
+# learner (two nets, two optimizers, soft targets) is its own class.
+_LEARNER_FAMILIES = {
+    "dqn": lambda cfg, net: dqn_family(net.apply, cfg.learner),
+    "r2d2": lambda cfg, net: r2d2_family(
+        net.apply, cfg.learner, cfg.replay,
+        compute_dtype=dtype_of(cfg.network.compute_dtype)),
+}
+
+
+def learner_family(cfg: RunConfig, net: Any):
+    return _LEARNER_FAMILIES[family_of(cfg)](cfg, net)
+
+
+def build_learner(cfg: RunConfig, net: Any, replay: Any, mesh: Any = None):
+    """The learner for cfg's family over `replay`: the sharded one on a
+    mesh (`replay` then holds the PER-SHARD capacity), else the
+    single-chip one. States differ with the learner: see each `init`."""
+    if family_of(cfg) == "dpg":
+        from ape_x_dqn_tpu.runtime.dpg_learner import DPGLearner
+
+        if mesh is not None:
+            raise NotImplementedError(
+                "the distributed learner covers the DQN and R2D2 "
+                "families; DPG nets are small — run dp=tp=1")
+        actor_net, critic_net = net
+        return DPGLearner(actor_net.apply, critic_net.apply, replay,
+                          cfg.learner)
+    family = learner_family(cfg, net)
+    if mesh is not None:
+        from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
+
+        return DistLearner(family, replay, cfg.learner, mesh)
+    from ape_x_dqn_tpu.runtime.learner import SingleChipLearner
+
+    return SingleChipLearner(family, replay, cfg.learner)
 
 
 def warmup_example(family: str, cfg: RunConfig, spec: Any) -> Any:

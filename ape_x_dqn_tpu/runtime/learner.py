@@ -7,12 +7,19 @@ batch gather from HBM storage, n-step double-DQN Huber loss, optimizer
 update, priority write-back, and periodic target sync — is one XLA graph
 with the learner state donated (no host round-trips, no copies).
 
-`make_dqn_learner` also exposes `train_many`, a `lax.scan` over K steps,
-so the device runs unattended for K grad-steps per dispatch — this is
-what the benchmark (bench.py) measures.
+`train_many` is a `lax.scan` over the steps of one dispatch, so the
+device runs unattended for `train_chunk` grad-steps — this is what the
+offline cells of the benchmark (benchmarks/) measure.
 
 Replay ingest (`add`) is a separate donated jit: the actor/ingest thread
 feeds device-resident storage while the learner thread owns training.
+
+The cycle is written once, here. What a model family brings is a value
+(`LearnerFamily`: a loss and an items -> batch function; the table that
+builds one from a RunConfig is runtime/family.py); what sharding
+changes is a set of overrides (parallel/dist_learner.py). DPGLearner
+(two nets, two optimizers, soft targets, no K-batch) is the one learner
+still written apart.
 """
 
 from __future__ import annotations
@@ -25,7 +32,6 @@ import jax.numpy as jnp
 import optax
 
 from ape_x_dqn_tpu.obs import learning as learn_obs
-from ape_x_dqn_tpu.ops.losses import TransitionBatch, make_dqn_loss
 from ape_x_dqn_tpu.replay.prioritized import ReplayState
 
 
@@ -36,6 +42,23 @@ class TrainState(NamedTuple):
     replay: ReplayState
     rng: jax.Array
     step: jax.Array  # int32 grad-step counter
+
+
+class LearnerFamily(NamedTuple):
+    """What a model family brings to the learner cycle, bound once
+    (runtime/family.py::learner_family)."""
+    name: str
+    # (params, target_params, batch, is_weights) -> (loss, aux); aux
+    # carries "td_abs", the priorities the cycle writes back
+    loss_fn: Callable
+    make_batch: Callable  # sampled items pytree -> the loss's batch
+    net_apply: Callable
+    # attribute under which the learner exposes net_apply: the name
+    # says the signature (net_apply(params, obs) -> q; net_apply_seq
+    # (params, obs[B,T,...], (c,h)) -> (q[B,T,A], state))
+    apply_attr: str = "net_apply"
+    # aux scalars the family adds to every step's metrics
+    metric_keys: tuple[str, ...] = ()
 
 
 def transition_item_spec(obs_shape, obs_dtype) -> dict:
@@ -57,25 +80,36 @@ def make_optimizer(lcfg) -> optax.GradientTransformation:
 
 
 class SingleChipLearner:
-    """Shared single-chip learner machinery: state init, the exact
-    per-step path, the K-batch relaxation, the train_many scan, the
-    ingest add, and param publication.
+    """The learner cycle on one chip: state init, the exact per-step
+    path, the K-batch relaxation, the train_many scan, the ingest add,
+    and param publication — for whichever `family` it is given.
 
-    Subclasses provide `self.replay`, `self.lcfg`, `self.optimizer`
-    and define `_sgd_step(params, target_params, opt_state, step,
-    items, is_w) -> (params, target_params, opt_state, step, td_abs,
-    metrics)` — the only family-specific piece (batch construction +
-    loss). The K-batch semantics (interleaved strata, per-chunk IS
-    renorm, one write-back, remainder-first metrics) therefore cannot
-    drift between the flat-DQN and sequence learners.
+    The K-batch semantics (interleaved strata, per-chunk IS renorm, one
+    write-back, remainder-first metrics) are written here and nowhere
+    else, so they cannot drift between families or between one chip and
+    the mesh: the sharded learner (parallel/dist_learner.py) inherits
+    every jitted endpoint and overrides only what sharding changes —
+    `_split_rng`, `_sample_weighted`, `_sample_stage`, `_sgd_step`,
+    `_replay_health`, `_write_back`, and the state/ingest/publication
+    endpoints. A difference between the two is such a method, never a
+    branch on who is calling.
 
     The K-batch cycle itself is split into two pure stages —
     _sample_stage (stratified K*B descent + gather + chunked IS
     weights) and _learn_stage (K SGD steps + one write-back + target
     sync) — composed back-to-back by the fused path and pipelined
-    one-deep by the double-buffered path (sample_prefetch), which both
-    the sequence and dist learners inherit.
+    one-deep by the double-buffered path (sample_prefetch).
     """
+
+    def __init__(self, family: LearnerFamily, replay, lcfg,
+                 optimizer: optax.GradientTransformation | None = None):
+        self.family = family
+        setattr(self, family.apply_attr, family.net_apply)
+        self.replay = replay
+        self.lcfg = lcfg
+        self.optimizer = optimizer or make_optimizer(lcfg)
+        # draws per shard and training batch; one shard here
+        self.b_local = lcfg.batch_size
 
     # -- state ------------------------------------------------------------
 
@@ -93,26 +127,84 @@ class SingleChipLearner:
 
     # -- core step (pure) -------------------------------------------------
 
+    def _split_rng(self, rng):
+        """-> (advanced rng, the draw's subkey)."""
+        return jax.random.split(rng)
+
+    def _sample_weighted(self, replay_state: ReplayState, sk, n: int,
+                         chunks: int = 1):
+        """-> (items, idx, IS weights) of one stratified draw of n,
+        max-normalized over the draw (replay.sample). Reads only the
+        replay state, via `replay.sample_state`, which never touches
+        the write cursor."""
+        return self.replay.sample_state(replay_state, sk, n, chunks)
+
+    def _sgd_update(self, params, target_params, opt_state, step,
+                    batch, w):
+        """One loss/grad/optimizer/target-sync update on a batch and
+        IS weights already in the form the family's loss takes: the
+        SGD body of every learner but DPG's, called by each stack's
+        `_sgd_step` after it has prepared the two. Returns the
+        family's |TD| priorities (aux['td_abs'])."""
+        (loss, aux), grads = jax.value_and_grad(
+            self.family.loss_fn, has_aux=True)(
+            params, target_params, batch, w)
+        updates, opt_state = self.optimizer.update(
+            grads, opt_state, params)
+        params = optax.apply_updates(params, updates)
+        step = step + 1
+        # hard target sync every K steps, branchless (SURVEY.md §3.3)
+        sync = (step % self.lcfg.target_sync_every == 0)
+        target_params = jax.tree.map(
+            lambda t, p: jnp.where(sync, p, t), target_params, params)
+        metrics = {
+            "loss": loss,
+            "q_mean": aux["q_mean"],
+            "td_abs_mean": aux["td_abs"].mean(),
+            **{key: aux[key] for key in self.family.metric_keys},
+            "grad_norm": optax.global_norm(grads),
+            # learning-health scalars (obs/learning.py); rides the
+            # metrics pytree through every scan, read at existing
+            # host sync points only
+            "diag": learn_obs.sgd_diag(aux, w, grads, updates, params),
+        }
+        return params, target_params, opt_state, step, aux["td_abs"], \
+            metrics
+
     def _sgd_step(self, params, target_params, opt_state, step,
                   items, is_w):
-        raise NotImplementedError  # family-specific: batch + loss
+        """One SGD step on already-sampled items (shared by the exact
+        per-step path and the K-batch relaxation)."""
+        return self._sgd_update(params, target_params, opt_state, step,
+                                self.family.make_batch(items), is_w)
+
+    def _replay_health(self, replay_state: ReplayState, idx, pri_then):
+        return learn_obs.replay_health(self.replay, replay_state, idx,
+                                       pri_then)
+
+    def _write_back(self, replay_state: ReplayState, idx, td_parts):
+        """The ONE priority write-back of a step or macro-step.
+        `td_parts` holds each SGD step's |TD|s, in the order of `idx`'s
+        chunks. Touches only the sum-tree (`replay.update_state`),
+        which is what lets a prefetched draw be reordered against it."""
+        return self.replay.update_state(replay_state, idx.reshape(-1),
+                                        jnp.concatenate(td_parts))
 
     def _train_step(self, state: TrainState) -> tuple[TrainState, dict]:
-        rng, sk = jax.random.split(state.rng)
-        items, idx, is_w = self.replay.sample(
-            state.replay, sk, self.lcfg.batch_size)
+        rng, sk = self._split_rng(state.rng)
+        items, idx, w = self._sample_weighted(state.replay, sk,
+                                              self.b_local)
         params, target_params, opt_state, step, td_abs, metrics = \
             self._sgd_step(state.params, state.target_params,
-                           state.opt_state, state.step, items, is_w)
+                           state.opt_state, state.step, items, w)
         # fused path: draw and write-back see the same tree, so the
         # priority-staleness delta is identically 0 (pri_then=None)
         metrics["diag"] = {**metrics.get("diag", {}),
-                           **learn_obs.replay_health(
-                               self.replay, state.replay, idx, None)}
-        replay_state = self.replay.update_priorities(
-            state.replay, idx, td_abs)
-        new_state = TrainState(params, target_params, opt_state,
-                               replay_state, rng, step)
+                           **self._replay_health(state.replay, idx, None)}
+        replay_state = self._write_back(state.replay, idx, [td_abs])
+        new_state = state._replace(
+            params=params, target_params=target_params,
+            opt_state=opt_state, replay=replay_state, rng=rng, step=step)
         return new_state, metrics
 
     def _sample_stage(self, replay_state: ReplayState, sk: jax.Array,
@@ -120,9 +212,8 @@ class SingleChipLearner:
         """Pure SAMPLE stage of the (split) K-batch cycle: one
         stratified K*B tree descent + storage gather + IS weights,
         already chunked for the K SGD steps. Reads only the replay
-        state (via `replay.sample_state`, which never touches the write
-        cursor), so a prefetched call commutes with an in-flight
-        priority write-back — the double-buffering contract.
+        state (_sample_weighted), so a prefetched call commutes with an
+        in-flight priority write-back — the double-buffering contract.
 
         Order of the draw: CHUNK-MAJOR. The replay emits position
         j*B + i = stratum i*K + j (ops/sum_tree.py::chunk_major holds
@@ -143,9 +234,8 @@ class SingleChipLearner:
             appended LAST so positional readers of the tuple's stable
             prefix, e.g. single_process.py's `sample[1]`, are unmoved)
         """
-        b = self.lcfg.batch_size
-        items, idx, is_w = self.replay.sample_state(replay_state, sk,
-                                                    k * b, chunks=k)
+        items, idx, is_w = self._sample_weighted(
+            replay_state, sk, k * self.b_local, chunks=k)
         pri = self.replay.leaf_priorities(replay_state, idx)
 
         def split(x):
@@ -163,9 +253,14 @@ class SingleChipLearner:
         """Pure LEARN stage: K SGD steps over an already-drawn sample
         + ONE priority write-back + target sync. `state.rng` must
         already be advanced past the draw that produced `sample`.
-        Step j trains on `sample`'s chunk j; its |TD|s go back to
-        idx_k[j], so both sides of the write-back are in the draw's
-        chunk-major order (_sample_stage) and nothing is un-permuted.
+        Step j trains on `sample`'s chunk j and its |TD|s pair with
+        chunk j of the drawn indices: both sides of the write-back are
+        in the draw's chunk-major order (_sample_stage), so the K parts
+        are joined as they come (_write_back) and nothing is
+        un-permuted — the same (leaf, |TD|) pairs stratum order and an
+        inverse chunk transform gave; only the order among duplicate
+        leaves inside one `.at[].set` differs, which XLA never
+        specified.
 
         The K chunks run as a STATIC unrolled loop, not lax.scan: K is
         small (4-8) and measured on CPU a scanned conv body ran ~17x
@@ -173,8 +268,7 @@ class SingleChipLearner:
         ms/step — scan's carried buffers defeat in-place aliasing
         there), while unrolled code also gives XLA's scheduler the
         whole window to overlap."""
-        b = self.lcfg.batch_size
-        items_k, idx_k, is_w_k, pri_k = sample
+        items_k, idx, w_k, pri = sample
         params, target_params, opt_state, step = (
             state.params, state.target_params, state.opt_state,
             state.step)
@@ -184,22 +278,18 @@ class SingleChipLearner:
             it = jax.tree.map(lambda x: x[j], items_k)
             params, target_params, opt_state, step, td_abs, metrics = \
                 self._sgd_step(params, target_params, opt_state, step,
-                               it, is_w_k[j])
+                               it, w_k[j])
             td_parts.append(td_abs)
         # write-back-time replay health: state.replay's tree is what
-        # the sampler would see NOW, pri_k is what it saw at descent
+        # the sampler would see NOW, pri is what it saw at descent
         # time — their delta is the measured priority staleness the
         # prefetch/K-batch relaxations accept (ROADMAP item 3)
         metrics["diag"] = {**metrics.get("diag", {}),
-                           **learn_obs.replay_health(
-                               self.replay, state.replay, idx_k, pri_k)}
-        # td_parts[j] pairs with idx_k[j]: both sides of the single
-        # write-back are in the draw's own (chunk-major) order
-        replay_state = self.replay.update_state(
-            state.replay, idx_k.reshape(k * b),
-            jnp.concatenate(td_parts))
-        new_state = TrainState(params, target_params, opt_state,
-                               replay_state, state.rng, step)
+                           **self._replay_health(state.replay, idx, pri)}
+        replay_state = self._write_back(state.replay, idx, td_parts)
+        new_state = state._replace(
+            params=params, target_params=target_params,
+            opt_state=opt_state, replay=replay_state, step=step)
         return new_state, metrics
 
     def _train_step_k(self, state: TrainState,
@@ -217,7 +307,7 @@ class SingleChipLearner:
         Composed from the split _sample_stage/_learn_stage so the fused
         path and the double-buffered path (sample_prefetch) cannot
         drift."""
-        rng, sk = jax.random.split(state.rng)
+        rng, sk = self._split_rng(state.rng)
         sample = self._sample_stage(state.replay, sk, k)
         return self._learn_stage(state._replace(rng=rng), sample, k)
 
@@ -244,7 +334,7 @@ class SingleChipLearner:
         chunked sample from the current tree. Deliberately NOT donated
         — the caller keeps `state` alive for the learn_k that trains on
         the PREVIOUS draw. -> (sample, advanced rng)."""
-        rng, sk = jax.random.split(state.rng)
+        rng, sk = self._split_rng(state.rng)
         return self._sample_stage(state.replay, sk, k), rng
 
     @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
@@ -260,18 +350,19 @@ class SingleChipLearner:
 
     @partial(jax.jit, static_argnums=(0, 2), donate_argnums=1)
     def train_many(self, state: TrainState, n: int):
-        """n grad-steps in one dispatch via lax.scan (bench hot path).
+        """n grad-steps in one dispatch via lax.scan (the program the
+        offline cells of benchmarks/ time, found by this name).
         With sample_chunk=K>1, runs n//K K-batch macro-steps (plus
         exact single steps for any remainder) — same grad-step count
         either way. With sample_prefetch, the macro-step scan runs
         double-buffered (see _train_many_prefetch)."""
-        k = getattr(self.lcfg, "sample_chunk", 1)
+        k = self.lcfg.sample_chunk
 
         def body(s, _):
             s, m = self._train_step(s)
             return s, m
 
-        if getattr(self.lcfg, "sample_prefetch", False):
+        if self.lcfg.sample_prefetch:
             return self._train_many_prefetch(state, n, max(k, 1), body)
 
         if k <= 1:
@@ -321,13 +412,13 @@ class SingleChipLearner:
             state, metrics = jax.lax.scan(body, state, None,
                                           length=n % k)
         if n // k:
-            rng, sk = jax.random.split(state.rng)
+            rng, sk = self._split_rng(state.rng)
             pending = self._sample_stage(state.replay, sk, k)
             state = state._replace(rng=rng)
 
             def body_pf(carry, _):
                 s, pend = carry
-                rng, sk = jax.random.split(s.rng)
+                rng, sk = self._split_rng(s.rng)
                 # drawn BEFORE _learn_stage's write-back: no data
                 # dependency with the K SGD steps below
                 nxt = self._sample_stage(s.replay, sk, k)
@@ -392,51 +483,3 @@ class SingleChipLearner:
         jits donate the TrainState, so aliased buffers would be deleted
         under the server's feet."""
         return jax.tree.map(jnp.copy, state.params)
-
-
-class DQNLearner(SingleChipLearner):
-    """Jitted endpoints for the flat-transition DQN learner."""
-
-    def __init__(self, net_apply: Callable, replay, lcfg,
-                 optimizer: optax.GradientTransformation | None = None):
-        self.net_apply = net_apply
-        self.replay = replay
-        self.lcfg = lcfg
-        self.optimizer = optimizer or make_optimizer(lcfg)
-        self.loss_fn = make_dqn_loss(
-            net_apply, double=lcfg.double_dqn, huber_delta=lcfg.huber_delta,
-            rescale=lcfg.value_rescale)
-
-    def _sgd_step(self, params, target_params, opt_state, step,
-                  items, is_w):
-        """One loss/grad/optimizer/target-sync update on an already-
-        sampled batch (shared by the exact per-step path and the
-        K-batch relaxation)."""
-        batch = TransitionBatch(
-            obs=items["obs"], actions=items["action"],
-            rewards=items["reward"], next_obs=items["next_obs"],
-            discounts=items["discount"])
-        (loss, aux), grads = jax.value_and_grad(
-            self.loss_fn, has_aux=True)(
-            params, target_params, batch, is_w)
-        updates, opt_state = self.optimizer.update(
-            grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        step = step + 1
-        # hard target sync every K steps, branchless (SURVEY.md §3.3)
-        sync = (step % self.lcfg.target_sync_every == 0)
-        target_params = jax.tree.map(
-            lambda t, p: jnp.where(sync, p, t), target_params, params)
-        metrics = {
-            "loss": loss,
-            "q_mean": aux["q_mean"],
-            "td_abs_mean": aux["td_abs"].mean(),
-            "grad_norm": optax.global_norm(grads),
-            # learning-health scalars (obs/learning.py); rides the
-            # metrics pytree through every scan, read at existing
-            # host sync points only
-            "diag": learn_obs.sgd_diag(aux, is_w, grads, updates,
-                                       params),
-        }
-        return params, target_params, opt_state, step, aux["td_abs"], \
-            metrics

@@ -7,7 +7,7 @@ TPU-native shape of that design:
 
 - Every process builds the SAME global (dp, tp) mesh (parallel/mesh.py
   over jax.devices(), which spans hosts under jax.distributed) and the
-  same DistDQNLearner; GSPMD inserts the cross-host collectives.
+  same DistLearner; GSPMD inserts the cross-host collectives.
 - Each host runs its OWN actors + batched inference server + transport;
   experience lands only in the dp replay rows that host owns
   (parallel/multihost.process_rows) — experience never crosses hosts,
@@ -60,9 +60,6 @@ from ape_x_dqn_tpu.obs.health import StallWatchdog, make_lock  # noqa: F401
 from ape_x_dqn_tpu.obs.core import build_obs
 from ape_x_dqn_tpu.envs import make_env
 from ape_x_dqn_tpu.models import build_network
-from ape_x_dqn_tpu.models.base import dtype_of
-from ape_x_dqn_tpu.parallel.dist_learner import (
-    DistDQNLearner, DistSequenceLearner)
 from ape_x_dqn_tpu.parallel.inference_server import (
     BatchedInferenceServer, build_serving_tier)
 from ape_x_dqn_tpu.parallel.mesh import make_mesh
@@ -71,8 +68,8 @@ from ape_x_dqn_tpu.runtime.driver import build_prioritized_replay
 from ape_x_dqn_tpu.runtime.evaluation import (
     EvalWorker, make_eval_policy_factory)
 from ape_x_dqn_tpu.runtime.family import (
-    actor_class, family_of, family_setup, server_apply_fn,
-    warmup_example)
+    actor_class, build_learner, family_of, family_setup,
+    server_apply_fn, warmup_example)
 from ape_x_dqn_tpu.utils.checkpoint import CheckpointManager
 from ape_x_dqn_tpu.utils.hbm import check_hbm_fits
 from ape_x_dqn_tpu.utils.metrics import Metrics, log_run_header
@@ -171,14 +168,7 @@ class MultihostApexDriver:
         self.replay = build_prioritized_replay(cfg, self.spec, shard_cap,
                                                self._frame_mode)
         self.capacity = shard_cap * self.dp
-        if self.family == "r2d2":
-            self.learner = DistSequenceLearner(
-                lambda p, o, s: self.net.apply(p, o, s),
-                self.replay, cfg.learner, cfg.replay, self.mesh,
-                compute_dtype=dtype_of(cfg.network.compute_dtype))
-        else:
-            self.learner = DistDQNLearner(self.net.apply, self.replay,
-                                          cfg.learner, self.mesh)
+        self.learner = build_learner(cfg, self.net, self.replay, self.mesh)
         self.state = self.learner.init(
             params, item_spec, component_key(cfg.seed, "learner"))
 

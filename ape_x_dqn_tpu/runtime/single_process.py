@@ -26,8 +26,8 @@ from ape_x_dqn_tpu.obs.core import build_obs
 from ape_x_dqn_tpu.ops.nstep import NStepBuilder
 from ape_x_dqn_tpu.replay.prioritized import (
     PrioritizedReplay, UniformReplayDevice)
-from ape_x_dqn_tpu.runtime.learner import (
-    DQNLearner, transition_item_spec)
+from ape_x_dqn_tpu.runtime.family import build_learner
+from ape_x_dqn_tpu.runtime.learner import transition_item_spec
 from ape_x_dqn_tpu.utils.metrics import Metrics, log_run_header
 from ape_x_dqn_tpu.utils.misc import next_pow2
 from ape_x_dqn_tpu.utils.rng import RngStream, component_key
@@ -72,7 +72,7 @@ def train_single_process(cfg: RunConfig, total_env_frames: int | None = None,
     age_tracker = obs_.age_tracker(next_pow2(cfg.replay.capacity))
     item_spec = transition_item_spec(env.spec.obs_shape,
                                      env.spec.obs_dtype)
-    learner = DQNLearner(net.apply, replay, cfg.learner)
+    learner = build_learner(cfg, net, replay)
     state = learner.init(params, replay.init(item_spec),
                          component_key(cfg.seed, "learner"))
 

@@ -4,7 +4,7 @@ Measures the north-star number (BASELINE.md "Driver-set target"): learner
 grad-steps/s at batch 512 on the dueling Nature-CNN (84x84x4 uint8), with
 the prioritized sum-tree replay resident in HBM and the entire
 sample->loss->optimize->priority-writeback cycle fused in one XLA jit
-(`DQNLearner.train_many`, a lax.scan over K steps per dispatch).
+(the learner's `train_many`, a lax.scan over K steps per dispatch).
 
 Prints exactly ONE JSON line on stdout:
   {"metric": "learner_grad_steps_per_s", "value": N, "unit": "steps/s",
@@ -159,8 +159,8 @@ def build_learner(capacity: int, batch_size: int, storage: str,
     from ape_x_dqn_tpu.models import build_network
     from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
     from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
-    from ape_x_dqn_tpu.runtime.learner import (DQNLearner,
-                                               transition_item_spec)
+    from ape_x_dqn_tpu.runtime.family import build_learner as family_learner
+    from ape_x_dqn_tpu.runtime.learner import transition_item_spec
     from ape_x_dqn_tpu.utils.rng import component_key
 
     spec = EnvSpec(obs_shape=(84, 84, 4), obs_dtype=np.dtype(np.uint8),
@@ -179,7 +179,8 @@ def build_learner(capacity: int, batch_size: int, storage: str,
                        param_count=1_700_000)
     except ValueError as e:
         raise SystemExit(f"{e}\n(or use --storage frame_ring)") from e
-    net = build_network(NetworkConfig(kind="nature_cnn", dueling=True), spec)
+    ncfg = NetworkConfig(kind="nature_cnn", dueling=True)
+    net = build_network(ncfg, spec)
     params = net.init(component_key(0, "net_init"),
                       jnp.zeros((1, 84, 84, 4), jnp.uint8))
     lcfg = LearnerConfig(batch_size=batch_size, sample_chunk=sample_chunk,
@@ -192,7 +193,8 @@ def build_learner(capacity: int, batch_size: int, storage: str,
         replay = PrioritizedReplay(capacity=capacity)
         replay_state = replay.init(transition_item_spec(spec.obs_shape,
                                                         spec.obs_dtype))
-    learner = DQNLearner(net.apply, replay, lcfg)
+    learner = family_learner(bcfg.replace(network=ncfg, learner=lcfg),
+                             net, replay)
     state = learner.init(params, replay_state, component_key(0, "learner"))
     return net, learner, state, spec
 
@@ -511,15 +513,16 @@ def _build_seq_learner(batch_size: int, sample_chunk: int,
                        sample_prefetch: bool, capacity: int = 4096,
                        lstm: int = 64, seq_len: int = 16,
                        obs_dim: int = 16):
-    """Small vector-obs R2D2 SequenceLearner + filled replay for the
+    """Small vector-obs R2D2 learner + filled replay for the
     prefetch A/B (the recurrent family has the deepest sample stage —
     stored-state sequence gather — so it is where descent/backward
     overlap has the most to hide behind)."""
-    from ape_x_dqn_tpu.configs import LearnerConfig, ReplayConfig
+    from ape_x_dqn_tpu.configs import (LearnerConfig, NetworkConfig,
+                                       ReplayConfig, RunConfig)
     from ape_x_dqn_tpu.models import ApeXLSTMQNet
     from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
     from ape_x_dqn_tpu.replay.sequence import sequence_item_spec
-    from ape_x_dqn_tpu.runtime.sequence_learner import SequenceLearner
+    from ape_x_dqn_tpu.runtime.family import build_learner as family_learner
     from ape_x_dqn_tpu.utils.rng import component_key
 
     net = ApeXLSTMQNet(num_actions=18, lstm_size=lstm, dense=lstm,
@@ -533,8 +536,10 @@ def _build_seq_learner(batch_size: int, sample_chunk: int,
     rcfg = ReplayConfig(kind="sequence", seq_length=seq_len, burn_in=4)
     replay = PrioritizedReplay(capacity=capacity)
     spec = sequence_item_spec((obs_dim,), np.float32, seq_len, lstm)
-    learner = SequenceLearner(lambda p, o, s: net.apply(p, o, s),
-                              replay, lcfg, rcfg)
+    learner = family_learner(
+        RunConfig(network=NetworkConfig(kind="lstm_q",
+                                        compute_dtype="float32"),
+                  learner=lcfg, replay=rcfg), net, replay)
     state = learner.init(params, replay.init(spec),
                          component_key(0, "seq_learner"))
     rng = np.random.default_rng(0)
@@ -2489,18 +2494,19 @@ def bench_multichip_child(args) -> None:
     flag is read once at backend init).
 
     Builds the dp-sharded frame-ring stack the dist driver runs
-    (FrameRingReplay at per-shard capacity under DistDQNLearner on a
+    (FrameRingReplay at per-shard capacity under the dist learner on a
     (dp, 1) mesh), prefills via timed lockstep add dispatches, times
     the fused train_many, and attributes it through StageProfiler's
     "train_dist" stage — the same roofline math the live driver
     publishes. Emits ONE marker-prefixed JSON line on stdout."""
-    from ape_x_dqn_tpu.configs import LearnerConfig, NetworkConfig
+    from ape_x_dqn_tpu.configs import (LearnerConfig, NetworkConfig,
+                                       RunConfig)
     from ape_x_dqn_tpu.envs.base import EnvSpec
     from ape_x_dqn_tpu.models import build_network
     from ape_x_dqn_tpu.obs.profiling import StageProfiler
-    from ape_x_dqn_tpu.parallel.dist_learner import DistDQNLearner
     from ape_x_dqn_tpu.parallel.mesh import make_mesh
     from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
+    from ape_x_dqn_tpu.runtime.family import build_learner as family_learner
     from ape_x_dqn_tpu.utils.rng import component_key
 
     dp, n_want = (int(v) for v in args.multichip_child.split("/"))
@@ -2521,13 +2527,14 @@ def bench_multichip_child(args) -> None:
     cap_shard = max((args.capacity // dp) // seg, 4) * seg
     replay = FrameRingReplay(capacity=cap_shard, seg_transitions=seg,
                              n_step=3, obs_shape=spec.obs_shape)
-    net = build_network(NetworkConfig(kind="nature_cnn", dueling=True),
-                        spec)
+    ncfg = NetworkConfig(kind="nature_cnn", dueling=True)
+    net = build_network(ncfg, spec)
     params = net.init(component_key(0, "net_init"),
                       jnp.zeros((1, 84, 84, 4), jnp.uint8))
     lcfg = LearnerConfig(batch_size=args.batch_size,
                          sample_chunk=args.sample_chunk)
-    learner = DistDQNLearner(net.apply, replay, lcfg, mesh)
+    learner = family_learner(RunConfig(network=ncfg, learner=lcfg), net,
+                             replay, mesh)
     state = learner.init(params, None, component_key(0, "learner"))
 
     # -- timed lockstep ingest (equal [dp, g] blocks, like the driver's
@@ -3846,7 +3853,7 @@ def main() -> None:
                    help="run the dp-scaling sweep INSTEAD of the main "
                    "bench: one fresh child process per dp point, each "
                    "seeing the same device count, building the "
-                   "dp-sharded frame-ring stack (DistDQNLearner) and "
+                   "dp-sharded frame-ring stack (dist dqn learner) and "
                    "timing lockstep ingest + fused train_many. "
                    "'dp=1,2,4' (or '1,2,4') runs on real devices and "
                    "fails if a child finds fewer than max(dp); "

@@ -97,8 +97,8 @@ def test_hns():
 def test_sample_chunk_gated_for_unimplemented_families():
     """Families without the K-batch relaxation must reject
     sample_chunk>1 loudly, not silently train exact semantics under a
-    config that claims otherwise. (Round 5: the SequenceLearner now
-    implements K-batch — tests/test_r2d2_runtime.py covers its
+    config that claims otherwise. (Round 5: the r2d2 family now
+    runs K-batch — tests/test_r2d2_runtime.py covers its
     mechanics — so only DPG keeps the gate.)"""
     import pytest
 

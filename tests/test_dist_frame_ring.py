@@ -20,9 +20,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ape_x_dqn_tpu.configs import LearnerConfig
-from ape_x_dqn_tpu.parallel.dist_learner import DistDQNLearner
+from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
 from ape_x_dqn_tpu.parallel.mesh import make_mesh
 from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
+from ape_x_dqn_tpu.runtime.family import dqn_family
 
 OBS_SHAPE = (6, 6, 4)
 
@@ -96,7 +97,7 @@ def test_dp1_add_many_matches_single_chip_adds():
     replay = _ring()
     mesh = make_mesh(dp=1, tp=1)
     lcfg = LearnerConfig(batch_size=16)
-    learner = DistDQNLearner(lambda p, o: o, replay, lcfg, mesh)
+    learner = DistLearner(dqn_family(lambda p, o: o, lcfg), replay, lcfg, mesh)
     params = {"w": jnp.zeros((4,), jnp.float32)}
     state = learner.init(params, None, jax.random.key(0))
 
@@ -127,8 +128,8 @@ def test_skewed_shard_fill_is_weights_frame_ring():
     dp, cap, seg = 2, 64, 8
     replay = _ring(cap=cap, seg=seg, alpha=1.0, beta=1.0, eps=0.0)
     mesh = make_mesh(dp=dp, tp=1)
-    learner = DistDQNLearner(lambda p, o: o,
-                             replay, LearnerConfig(batch_size=64), mesh)
+    lcfg = LearnerConfig(batch_size=64)
+    learner = DistLearner(dqn_family(lambda p, o: o, lcfg), replay, lcfg, mesh)
 
     masses = [1e-3, 1.0]
     n_segs = [2, cap // seg]
@@ -168,8 +169,8 @@ def test_dead_pad_slots_sample_with_zero_weight():
     dp, cap, seg = 2, 32, 8
     replay = _ring(cap=cap, seg=seg, alpha=1.0, beta=1.0, eps=0.0)
     mesh = make_mesh(dp=dp, tp=1)
-    learner = DistDQNLearner(lambda p, o: o,
-                             replay, LearnerConfig(batch_size=64), mesh)
+    lcfg = LearnerConfig(batch_size=64)
+    learner = DistLearner(dqn_family(lambda p, o: o, lcfg), replay, lcfg, mesh)
     rng = np.random.default_rng(1)
     states = []
     for d in range(dp):
@@ -197,8 +198,8 @@ def test_shard_stats_reports_per_shard_fill_and_mass():
     dp, cap, seg = 2, 32, 8
     replay = _ring(cap=cap, seg=seg)
     mesh = make_mesh(dp=dp, tp=1)
-    learner = DistDQNLearner(lambda p, o: o,
-                             replay, LearnerConfig(batch_size=16), mesh)
+    lcfg = LearnerConfig(batch_size=16)
+    learner = DistLearner(dqn_family(lambda p, o: o, lcfg), replay, lcfg, mesh)
     state = learner.init({"w": jnp.zeros((2,), jnp.float32)}, None,
                          jax.random.key(0))
     rng = np.random.default_rng(2)

@@ -13,7 +13,9 @@ from ape_x_dqn_tpu.configs import (
 from ape_x_dqn_tpu.envs import make_env
 from ape_x_dqn_tpu.models import build_network
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
-from ape_x_dqn_tpu.runtime.learner import DQNLearner, transition_item_spec
+from ape_x_dqn_tpu.runtime.family import dqn_family
+from ape_x_dqn_tpu.runtime.learner import (
+    SingleChipLearner, transition_item_spec)
 from ape_x_dqn_tpu.runtime.single_process import train_single_process
 from ape_x_dqn_tpu.utils.rng import component_key
 
@@ -42,7 +44,8 @@ def test_cnn_learner_jit_runs_at_flagship_shapes():
     params = net.init(component_key(0, "net_init"), env.reset()[None])
     replay = PrioritizedReplay(capacity=2048)
     lcfg = cfg.learner.__class__(batch_size=512)
-    learner = DQNLearner(net.apply, replay, lcfg)
+    learner = SingleChipLearner(
+        dqn_family(net.apply, lcfg), replay, lcfg)
     spec = transition_item_spec(env.spec.obs_shape, env.spec.obs_dtype)
     state = learner.init(params, replay.init(spec), jax.random.key(0))
     rng = np.random.default_rng(0)

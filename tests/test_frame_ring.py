@@ -162,11 +162,12 @@ def test_dead_slots_never_sampled_and_stay_dead():
 
 
 def test_learner_runs_on_frame_ring():
-    """DQNLearner train_step over frame-ring storage: loss finite,
+    """dqn-family learner train_step over frame-ring storage: loss finite,
     priorities written back, donation-safe."""
     from ape_x_dqn_tpu.envs.base import EnvSpec
     from ape_x_dqn_tpu.models import build_network
-    from ape_x_dqn_tpu.runtime.learner import DQNLearner
+    from ape_x_dqn_tpu.runtime.family import dqn_family
+    from ape_x_dqn_tpu.runtime.learner import SingleChipLearner
     from ape_x_dqn_tpu.utils.rng import component_key
 
     spec = EnvSpec(obs_shape=(H, W, STACK), obs_dtype=np.dtype(np.uint8),
@@ -180,7 +181,8 @@ def test_learner_runs_on_frame_ring():
                              obs_shape=(H, W, STACK))
     lcfg = LearnerConfig(batch_size=16, n_step=N_STEP,
                          target_sync_every=10)
-    learner = DQNLearner(net.apply, replay, lcfg)
+    learner = SingleChipLearner(
+        dqn_family(net.apply, lcfg), replay, lcfg)
     state = learner.init(params, replay.init(), component_key(0, "learner"))
 
     b = FrameSegmentBuilder(B, N_STEP, STACK)

@@ -18,8 +18,9 @@ from ape_x_dqn_tpu.models import build_network
 from ape_x_dqn_tpu.obs.core import NULL_OBS, build_obs
 from ape_x_dqn_tpu.obs.learning import LearnMonitor
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
+from ape_x_dqn_tpu.runtime.family import dqn_family, r2d2_family
 from ape_x_dqn_tpu.runtime.learner import (
-    DQNLearner, transition_item_spec)
+    SingleChipLearner, transition_item_spec)
 from ape_x_dqn_tpu.utils.metrics import Metrics
 from ape_x_dqn_tpu.utils.rng import component_key
 
@@ -64,8 +65,9 @@ def test_dqn_learner_diag_finite():
                         VEC_SPEC)
     params = net.init(component_key(3, "net"),
                       np.zeros((1, 4), np.float32))
-    learner = DQNLearner(net.apply, PrioritizedReplay(capacity=512),
-                         LearnerConfig(batch_size=32))
+    lcfg = LearnerConfig(batch_size=32)
+    learner = SingleChipLearner(dqn_family(net.apply, lcfg),
+                                PrioritizedReplay(capacity=512), lcfg)
     state = learner.init(
         params, learner.replay.init(
             transition_item_spec(VEC_SPEC.obs_shape,
@@ -86,7 +88,6 @@ def test_dqn_learner_diag_finite():
 def test_sequence_learner_diag_finite():
     from ape_x_dqn_tpu.models import ApeXLSTMQNet
     from ape_x_dqn_tpu.replay.sequence import sequence_item_spec
-    from ape_x_dqn_tpu.runtime.sequence_learner import SequenceLearner
 
     net = ApeXLSTMQNet(num_actions=2, lstm_size=8, dense=16,
                        compute_dtype="float32", mlp_torso=True)
@@ -98,8 +99,9 @@ def test_sequence_learner_diag_finite():
     lcfg = LearnerConfig(batch_size=8, n_step=2, value_rescale=True,
                          target_sync_every=10, lr=1e-3)
     rcfg = ReplayConfig(seq_length=4, burn_in=1)
-    learner = SequenceLearner(lambda p, o, s: net.apply(p, o, s),
-                              replay, lcfg, rcfg)
+    learner = SingleChipLearner(
+        r2d2_family(lambda p, o, s: net.apply(p, o, s), lcfg, rcfg),
+        replay, lcfg)
     state = learner.init(params, replay.init(spec), jax.random.key(1))
     rng = np.random.default_rng(0)
     items = {
@@ -153,7 +155,7 @@ def test_dist_learner_diag_shard_closure():
     mean-|TD| envelope closes over the global mean (the min/max are the
     psum'd extremes of exactly the per-shard means the global averages,
     so min <= global <= max is an identity, not a tolerance)."""
-    from ape_x_dqn_tpu.parallel.dist_learner import DistDQNLearner
+    from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
     from ape_x_dqn_tpu.parallel.mesh import make_mesh
 
     dp = 2
@@ -162,9 +164,10 @@ def test_dist_learner_diag_shard_closure():
         NetworkConfig(kind="mlp", mlp_hidden=(64,), dueling=False,
                       compute_dtype="float32"), VEC_SPEC)
     params = net.init(jax.random.key(0), jnp.zeros((1, 4)))
-    learner = DistDQNLearner(
-        net.apply, PrioritizedReplay(capacity=64, alpha=0.6, beta=0.4),
-        LearnerConfig(batch_size=32, target_sync_every=10), mesh)
+    lcfg = LearnerConfig(batch_size=32, target_sync_every=10)
+    learner = DistLearner(
+        dqn_family(net.apply, lcfg),
+        PrioritizedReplay(capacity=64, alpha=0.6, beta=0.4), lcfg, mesh)
     state = learner.init(params,
                          transition_item_spec((4,), jnp.float32),
                          jax.random.key(1))

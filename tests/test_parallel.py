@@ -9,10 +9,11 @@ import pytest
 from ape_x_dqn_tpu.configs import LearnerConfig, NetworkConfig
 from ape_x_dqn_tpu.envs.base import EnvSpec
 from ape_x_dqn_tpu.models import build_network
-from ape_x_dqn_tpu.parallel.dist_learner import DistDQNLearner
+from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
 from ape_x_dqn_tpu.parallel.mesh import make_mesh
 from ape_x_dqn_tpu.parallel.sharding import make_param_shardings
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
+from ape_x_dqn_tpu.runtime.family import dqn_family
 from ape_x_dqn_tpu.runtime.learner import transition_item_spec
 
 VEC_SPEC = EnvSpec(obs_shape=(4,), obs_dtype=np.dtype(np.float32),
@@ -27,7 +28,8 @@ def _make_dist(dp=4, tp=2, batch=32):
     params = net.init(jax.random.key(0), jnp.zeros((1, 4)))
     lcfg = LearnerConfig(batch_size=batch, target_sync_every=10)
     replay = PrioritizedReplay(capacity=64, alpha=0.6, beta=0.4)
-    learner = DistDQNLearner(net.apply, replay, lcfg, mesh)
+    learner = DistLearner(
+        dqn_family(net.apply, lcfg), replay, lcfg, mesh)
     spec = transition_item_spec((4,), jnp.float32)
     state = learner.init(params, spec, jax.random.key(1))
     return mesh, learner, state
@@ -295,8 +297,9 @@ def test_dist_kbatch_train_step_k():
     params = net.init(jax.random.key(0), jnp.zeros((1, 4)))
     lcfg = LearnerConfig(batch_size=32, target_sync_every=3,
                          sample_chunk=4)
-    learner = DistDQNLearner(net.apply, PrioritizedReplay(capacity=64),
-                             lcfg, mesh)
+    learner = DistLearner(
+        dqn_family(net.apply, lcfg), PrioritizedReplay(capacity=64), lcfg,
+        mesh)
     spec = transition_item_spec((4,), jnp.float32)
     state = learner.init(params, spec, jax.random.key(1))
     state = _ingest(learner, state, 4, 48)
@@ -320,8 +323,9 @@ def test_dist_kbatch_train_step_k():
             NetworkConfig(kind="mlp", mlp_hidden=(256,), dueling=False,
                           compute_dtype="float32"), VEC_SPEC)
         p2 = net2.init(jax.random.key(0), jnp.zeros((1, 4)))
-        lrn = DistDQNLearner(net2.apply, PrioritizedReplay(capacity=64),
-                             lcfg, mesh)
+        lrn = DistLearner(
+            dqn_family(net2.apply, lcfg), PrioritizedReplay(capacity=64), lcfg,
+            mesh)
         st = lrn.init(p2, spec, jax.random.key(1))
         st = _ingest(lrn, st, 4, 48)
         st, _ = lrn.train_step_k(st, 4)
@@ -351,9 +355,9 @@ def test_dist_prefetch_train_many():
             NetworkConfig(kind="mlp", mlp_hidden=(256,), dueling=False,
                           compute_dtype="float32"), VEC_SPEC)
         params = net.init(jax.random.key(0), jnp.zeros((1, 4)))
-        lrn = DistDQNLearner(
-            net.apply, PrioritizedReplay(capacity=64),
-            dataclasses.replace(lcfg, sample_prefetch=prefetch), mesh)
+        lc = dataclasses.replace(lcfg, sample_prefetch=prefetch)
+        lrn = DistLearner(dqn_family(net.apply, lc),
+                          PrioritizedReplay(capacity=64), lc, mesh)
         st = lrn.init(params, spec, jax.random.key(1))
         return lrn, _ingest(lrn, st, 4, 48)
 

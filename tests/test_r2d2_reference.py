@@ -1,6 +1,6 @@
 """The R2D2 learner against the plain float32 reference
 (benchmarks/reference/r2d2.py) at a tiny size on the CPU: seeded
-weights, the program's own `SequenceLearner` through `sample_k` /
+weights, the program's own r2d2 learner through `sample_k` /
 `learn_k`, forward (Q [B, L, A]), loss, written priorities and the
 gradient of every parameter.
 
@@ -32,7 +32,8 @@ from ape_x_dqn_tpu.models import ApeXLSTMQNet
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
 from ape_x_dqn_tpu.replay.sequence import (batch_to_sequence_batch,
                                            sequence_item_spec)
-from ape_x_dqn_tpu.runtime.sequence_learner import SequenceLearner
+from ape_x_dqn_tpu.runtime.family import r2d2_family
+from ape_x_dqn_tpu.runtime.learner import SingleChipLearner
 from benchmarks.harness import correctness, r2d2_params, sequence_checks
 from benchmarks.reference import r2d2 as ref
 
@@ -121,8 +122,9 @@ def _build(layout_name, k, masked, dtype):
         capacity, alpha=rcfg.alpha, beta=rcfg.beta, eps=rcfg.eps,
         item_spec=sequence_item_spec(shape, obs_dtype, L, LSTM,
                                      frame_mode=pixels))
-    learner = SequenceLearner(lambda p, o, s: net.apply(p, o, s), replay,
-                              lcfg, rcfg)
+    learner = SingleChipLearner(
+        r2d2_family(lambda p, o, s: net.apply(p, o, s), lcfg, rcfg),
+        replay, lcfg)
     state = learner.init(params, replay.init(), jax.random.PRNGKey(2))
     # a target net that differs from the online net, as after a sync
     state = state._replace(target_params=net.init(
@@ -158,7 +160,7 @@ def _follow(net, learner, state, k):
     params, target_sys, opt, step = (state.params, state.target_params,
                                      state.opt_state, state.step)
     sgd = jax.jit(learner._sgd_step)
-    grad_fn = jax.jit(jax.value_and_grad(learner.loss_fn, has_aux=True))
+    grad_fn = jax.jit(jax.value_and_grad(learner.family.loss_fn, has_aux=True))
     chunks = []
     for j in range(k):
         items = jax.tree.map(lambda x: x[j], items_k)

@@ -19,7 +19,8 @@ from ape_x_dqn_tpu.replay.sequence import (
     SequenceBuilder, sequence_item_spec, split_priorities)
 from ape_x_dqn_tpu.runtime.actor import RecurrentActor
 from ape_x_dqn_tpu.runtime.driver import ApexDriver
-from ape_x_dqn_tpu.runtime.sequence_learner import SequenceLearner
+from ape_x_dqn_tpu.runtime.family import r2d2_family
+from ape_x_dqn_tpu.runtime.learner import SingleChipLearner
 
 
 def _r2d2_cfg(num_actors=2, lstm=32, seq=16, overlap=8, burn_in=4):
@@ -112,8 +113,9 @@ def test_sequence_learner_trains_and_updates_priorities():
     lcfg = cfg.learner.__class__(batch_size=8, n_step=2, value_rescale=True,
                                  target_sync_every=10, lr=1e-3)
     rcfg = cfg.replay.__class__(seq_length=4, burn_in=1)
-    learner = SequenceLearner(lambda p, o, s: net.apply(p, o, s),
-                              replay, lcfg, rcfg)
+    learner = SingleChipLearner(
+        r2d2_family(lambda p, o, s: net.apply(p, o, s), lcfg, rcfg),
+        replay, lcfg)
     state = learner.init(params, replay.init(spec), jax.random.key(1))
     rng = np.random.default_rng(0)
     items = {
@@ -161,13 +163,14 @@ def test_r2d2_dist_driver_end_to_end():
     """Distributed R2D2 (SURVEY.md §2.1 config 4 attests dp=4 x tp=2):
     sequence-replay shards + LSTM sequence loss over the virtual
     8-device mesh, sequence round-robin ingest, replicated publication."""
-    from ape_x_dqn_tpu.parallel.dist_learner import DistSequenceLearner
+    from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
 
     cfg = _r2d2_cfg(num_actors=2).replace(
         parallel=ParallelConfig(dp=4, tp=2))
     driver = ApexDriver(cfg)
     assert driver.is_dist and driver.family == "r2d2"
-    assert isinstance(driver.learner, DistSequenceLearner)
+    assert isinstance(driver.learner, DistLearner)
+    assert driver.learner.family.name == "r2d2"
     out = driver.run(total_env_frames=2500, max_grad_steps=40,
                      wall_clock_limit_s=240)
     assert out["actor_errors"] == [], out["actor_errors"]
@@ -286,7 +289,7 @@ def test_r2d2_improves_masked_cartpole():
 
 def _seq_learner_with_items(sample_chunk=1, n_items=64, seed=0,
                             sample_prefetch=False):
-    """Small SequenceLearner + filled replay for mechanics tests."""
+    """Small r2d2-family learner + filled replay for mechanics tests."""
     net = ApeXLSTMQNet(num_actions=2, lstm_size=8, dense=16,
                        compute_dtype="float32", mlp_torso=True)
     z = jnp.zeros((1, 8), jnp.float32)
@@ -299,8 +302,9 @@ def _seq_learner_with_items(sample_chunk=1, n_items=64, seed=0,
                          sample_chunk=sample_chunk,
                          sample_prefetch=sample_prefetch)
     rcfg = ReplayConfig(kind="sequence", seq_length=4, burn_in=1)
-    learner = SequenceLearner(lambda p, o, s: net.apply(p, o, s),
-                              replay, lcfg, rcfg)
+    learner = SingleChipLearner(
+        r2d2_family(lambda p, o, s: net.apply(p, o, s), lcfg, rcfg),
+        replay, lcfg)
     state = learner.init(params, replay.init(spec), jax.random.key(1))
     rng = np.random.default_rng(seed)
     items = {
@@ -319,7 +323,7 @@ def _seq_learner_with_items(sample_chunk=1, n_items=64, seed=0,
 
 
 def test_sequence_kbatch_train_many_mechanics():
-    """sample_chunk=K on the SequenceLearner (round-5 verdict item 5):
+    """sample_chunk=K on the r2d2-family learner (round-5 verdict item 5):
     one stratified K*B sequence sample + one priority write-back per K
     grad-steps; step counts, the remainder path, target sync inside the
     macro-step, and tree repair must all hold — mirroring
@@ -354,7 +358,7 @@ def test_sequence_kbatch_determinism():
 
 
 def test_sequence_prefetch_train_many_mechanics():
-    """sample_prefetch on the SequenceLearner: the double-buffered
+    """sample_prefetch on the r2d2-family learner: the double-buffered
     train_many pipeline (next chunk's sequence sample drawn before this
     chunk's priority write-back) holds the same step-count, remainder,
     and sync-boundary contract as the fused K-batch path, and its first
@@ -424,18 +428,19 @@ def test_r2d2_improves_masked_cartpole_prefetch():
 
 def test_dist_sequence_kbatch_train_step_k():
     """K-batch mechanics on the DIST sequence learner (round-4 advisor
-    finding: DistSequenceLearner inherited the K path with no test):
+    finding: the dist r2d2 learner inherited the K path with no test):
     the dp=4 x tp=2 driver trains with sample_chunk=4 through
     train_many, steps count correctly, and every shard's tree is
     repaired."""
-    from ape_x_dqn_tpu.parallel.dist_learner import DistSequenceLearner
+    from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
 
     cfg = _r2d2_cfg(num_actors=2).replace(
         parallel=ParallelConfig(dp=4, tp=2))
     cfg = cfg.replace(learner=dataclasses.replace(cfg.learner,
                                                   sample_chunk=4))
     driver = ApexDriver(cfg)
-    assert isinstance(driver.learner, DistSequenceLearner)
+    assert isinstance(driver.learner, DistLearner)
+    assert driver.learner.family.name == "r2d2"
     out = driver.run(total_env_frames=2500, max_grad_steps=40,
                      wall_clock_limit_s=240)
     assert out["actor_errors"] == [], out["actor_errors"]

@@ -298,7 +298,8 @@ def test_learner_fixed_seed_bitwise_deterministic():
     from ape_x_dqn_tpu.envs.base import EnvSpec
     from ape_x_dqn_tpu.models import build_network
     from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
-    from ape_x_dqn_tpu.runtime.learner import (DQNLearner,
+    from ape_x_dqn_tpu.runtime.family import dqn_family
+    from ape_x_dqn_tpu.runtime.learner import (SingleChipLearner,
                                                transition_item_spec)
     from ape_x_dqn_tpu.utils.rng import component_key
 
@@ -320,8 +321,9 @@ def test_learner_fixed_seed_bitwise_deterministic():
             NetworkConfig(kind="mlp", mlp_hidden=(32,)), spec)
         params = net.init(component_key(3, "net"),
                           np.zeros((1, 4), np.float32))
-        learner = DQNLearner(net.apply, PrioritizedReplay(capacity=512),
-                             LearnerConfig(batch_size=32))
+        lcfg = LearnerConfig(batch_size=32)
+        learner = SingleChipLearner(dqn_family(net.apply, lcfg),
+                                    PrioritizedReplay(capacity=512), lcfg)
         state = learner.init(
             params,
             learner.replay.init(transition_item_spec(spec.obs_shape,
@@ -347,7 +349,8 @@ def test_kbatch_train_many_mechanics():
     from ape_x_dqn_tpu.envs.cartpole import CartPole
     from ape_x_dqn_tpu.models import build_network
     from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
-    from ape_x_dqn_tpu.runtime.learner import (DQNLearner,
+    from ape_x_dqn_tpu.runtime.family import dqn_family
+    from ape_x_dqn_tpu.runtime.learner import (SingleChipLearner,
                                                transition_item_spec)
     from ape_x_dqn_tpu.utils.rng import component_key
 
@@ -365,7 +368,8 @@ def test_kbatch_train_many_mechanics():
     params = net.init(component_key(5, "net"), np.zeros((1, 4), np.float32))
     lcfg = LearnerConfig(batch_size=32, sample_chunk=4,
                          target_sync_every=3)
-    learner = DQNLearner(net.apply, PrioritizedReplay(capacity=512), lcfg)
+    learner = SingleChipLearner(
+        dqn_family(net.apply, lcfg), PrioritizedReplay(capacity=512), lcfg)
     state = learner.init(
         params,
         learner.replay.init(transition_item_spec(spec.obs_shape,
@@ -399,8 +403,9 @@ def test_kbatch_train_many_mechanics():
                              spec)
         p2 = net2.init(component_key(6, "net"),
                        np.zeros((1, 4), np.float32))
-        lrn = DQNLearner(net2.apply, PrioritizedReplay(capacity=512),
-                         _dc.replace(lcfg, sample_chunk=4))
+        lc4 = _dc.replace(lcfg, sample_chunk=4)
+        lrn = SingleChipLearner(dqn_family(net2.apply, lc4),
+                                PrioritizedReplay(capacity=512), lc4)
         st = lrn.init(p2, lrn.replay.init(
             transition_item_spec(spec.obs_shape, spec.obs_dtype)),
             component_key(6, "learner"))
@@ -444,13 +449,15 @@ def test_kbatch_chunks_span_full_priority_range():
 
 
 def _prefetch_learner(sample_prefetch, seed=5, sample_chunk=4):
-    """Small DQNLearner + filled replay for the prefetch pipeline tests.
+    """Small dqn-family learner + filled replay for the prefetch pipeline
+    tests.
     Identical construction across calls so the prefetch=True/False arms
     start from bit-identical state."""
     from ape_x_dqn_tpu.envs.cartpole import CartPole
     from ape_x_dqn_tpu.models import build_network
     from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
-    from ape_x_dqn_tpu.runtime.learner import (DQNLearner,
+    from ape_x_dqn_tpu.runtime.family import dqn_family
+    from ape_x_dqn_tpu.runtime.learner import (SingleChipLearner,
                                                transition_item_spec)
     from ape_x_dqn_tpu.utils.rng import component_key
 
@@ -470,7 +477,8 @@ def _prefetch_learner(sample_prefetch, seed=5, sample_chunk=4):
     lcfg = LearnerConfig(batch_size=32, sample_chunk=sample_chunk,
                          sample_prefetch=sample_prefetch,
                          target_sync_every=3)
-    learner = DQNLearner(net.apply, PrioritizedReplay(capacity=512), lcfg)
+    learner = SingleChipLearner(
+        dqn_family(net.apply, lcfg), PrioritizedReplay(capacity=512), lcfg)
     state = learner.init(
         params,
         learner.replay.init(transition_item_spec(spec.obs_shape,

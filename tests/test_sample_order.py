@@ -21,14 +21,14 @@ from ape_x_dqn_tpu.configs import LearnerConfig, NetworkConfig, ReplayConfig
 from ape_x_dqn_tpu.envs.base import EnvSpec
 from ape_x_dqn_tpu.models import build_network
 from ape_x_dqn_tpu.ops import sum_tree
-from ape_x_dqn_tpu.parallel.dist_learner import (DistDQNLearner,
-                                                 DistSequenceLearner)
+from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
 from ape_x_dqn_tpu.parallel.mesh import make_mesh
 from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
 from ape_x_dqn_tpu.replay.prioritized import (PrioritizedReplay,
                                               UniformReplayDevice)
-from ape_x_dqn_tpu.runtime.learner import DQNLearner, transition_item_spec
-from ape_x_dqn_tpu.runtime.sequence_learner import SequenceLearner
+from ape_x_dqn_tpu.runtime.family import dqn_family, r2d2_family
+from ape_x_dqn_tpu.runtime.learner import (
+    SingleChipLearner, transition_item_spec)
 
 STORAGES = ("flat", "seq", "ring")
 KS = (1, 2, 4)
@@ -99,16 +99,21 @@ def _filled(storage, seed, lead=()):
 def _single_learner(storage, replay):
     lcfg = LearnerConfig(batch_size=B)
     if storage == "seq":   # inherits _sample_stage from SingleChipLearner
-        return SequenceLearner(None, replay, lcfg, RCFG)
-    return DQNLearner(None, replay, lcfg)
+        return SingleChipLearner(
+            r2d2_family(None, lcfg, RCFG), replay, lcfg)
+    return SingleChipLearner(
+        dqn_family(None, lcfg), replay, lcfg)
 
 
 def _dist_learner(storage, replay):
     lcfg = LearnerConfig(batch_size=B * DP)
     mesh = make_mesh(dp=DP, tp=1)
     if storage == "seq":
-        return DistSequenceLearner(None, replay, lcfg, RCFG, mesh)
-    return DistDQNLearner(None, replay, lcfg, mesh)
+        return DistLearner(
+            r2d2_family(None, lcfg, RCFG),
+            replay, lcfg, mesh)
+    return DistLearner(
+        dqn_family(None, lcfg), replay, lcfg, mesh)
 
 
 # -- the permutation itself ------------------------------------------------
@@ -264,8 +269,9 @@ def test_dist_learn_k_writes_what_the_old_inverse_transform_wrote(k):
                       compute_dtype="float32"), spec_env)
     params = net.init(jax.random.key(0), jnp.zeros((1, 4)))
     lcfg = LearnerConfig(batch_size=B * DP, target_sync_every=3)
-    learner = DistDQNLearner(net.apply, PrioritizedReplay(capacity=256),
-                             lcfg, make_mesh(dp=DP, tp=1))
+    learner = DistLearner(
+        dqn_family(net.apply, lcfg), PrioritizedReplay(capacity=256), lcfg,
+        make_mesh(dp=DP, tp=1))
     state = learner.init(params, transition_item_spec((4,), jnp.float32),
                          jax.random.key(1))
     rng = np.random.default_rng(4)

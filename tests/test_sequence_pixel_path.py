@@ -13,7 +13,7 @@ Held here, on the CPU at tiny widths:
       uint8 to float exactly once (the parent's: twice, in four converts);
 (iii) `net_apply_seq` still takes uint8 stacks (the call the benchmark's
       check makes) and `sample_k` still returns the stored uint8 items;
-(iv)  `DistSequenceLearner` at dp=1 takes the same prepared batch.
+(iv)  the dist learner at dp=1 takes the same prepared batch.
 """
 
 import jax
@@ -26,11 +26,12 @@ from ape_x_dqn_tpu.configs import LearnerConfig, ReplayConfig
 from ape_x_dqn_tpu.models import ApeXLSTMQNet
 from ape_x_dqn_tpu.models.base import dtype_of
 from ape_x_dqn_tpu.ops.losses import SequenceBatch
-from ape_x_dqn_tpu.parallel.dist_learner import DistSequenceLearner
+from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
 from ape_x_dqn_tpu.parallel.mesh import make_mesh
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
 from ape_x_dqn_tpu.replay.sequence import sequence_item_spec
-from ape_x_dqn_tpu.runtime.sequence_learner import SequenceLearner
+from ape_x_dqn_tpu.runtime.family import r2d2_family
+from ape_x_dqn_tpu.runtime.learner import SingleChipLearner
 
 B, L, BURN_IN, N_STEP, LSTM, ACTIONS, STACK = 4, 8, 3, 2, 16, 5, 4
 CAPACITY, FILLED = 32, 24
@@ -92,15 +93,16 @@ def _build(layout_name, dtype, dist=False):
     items = jax.tree.map(jnp.asarray, _items(rng, layout, FILLED))
     pri = jnp.asarray(rng.uniform(0.05, 2.0, FILLED), jnp.float32)
     if dist:
-        learner = DistSequenceLearner(
-            apply, replay, lcfg, rcfg, make_mesh(dp=1, tp=1),
-            compute_dtype=dtype_of(dtype))
+        learner = DistLearner(
+            r2d2_family(apply, lcfg, rcfg, compute_dtype=dtype_of(dtype)),
+            replay, lcfg, make_mesh(dp=1, tp=1))
         state = learner.init(params, spec, jax.random.PRNGKey(2))
         state = learner.add(state, jax.tree.map(lambda x: x[None], items),
                             pri[None])
     else:
-        learner = SequenceLearner(apply, replay, lcfg, rcfg,
-                                  compute_dtype=dtype_of(dtype))
+        learner = SingleChipLearner(
+            r2d2_family(apply, lcfg, rcfg, compute_dtype=dtype_of(dtype)),
+            replay, lcfg)
         state = learner.init(params, replay.init(), jax.random.PRNGKey(2))
         state = learner.add(state, items, pri)
     # a target net that differs from the online net, as after a sync
@@ -127,7 +129,8 @@ def _parent_batch(items):
 
 
 def _parent_sgd_step(learner, params, target_params, opt_state, items, w):
-    (loss, aux), grads = jax.value_and_grad(learner.loss_fn, has_aux=True)(
+    (loss, aux), grads = jax.value_and_grad(
+        learner.family.loss_fn, has_aux=True)(
         params, target_params, _parent_batch(items), w)
     updates, _ = learner.optimizer.update(grads, opt_state, params)
     return loss, aux["td_abs"], grads, optax.apply_updates(params, updates)
@@ -151,8 +154,8 @@ def test_sgd_step_equals_the_parents_formulation_bit_for_bit(layout, dtype):
     args = (state.params, state.target_params, state.opt_state)
 
     def new(params, target, opt, items, w):
-        grads = jax.grad(lambda p: learner.loss_fn(
-            p, target, learner._make_batch(items), w)[0])(params)
+        grads = jax.grad(lambda p: learner.family.loss_fn(
+            p, target, learner.family.make_batch(items), w)[0])(params)
         out = learner._sgd_step(params, target, opt, jnp.int32(0), items, w)
         return out[5]["loss"], out[4], grads, out[0]
 
@@ -171,7 +174,7 @@ def test_sgd_step_equals_the_parents_formulation_bit_for_bit(layout, dtype):
     # division by 255 the same way on both sides)
     state0 = (items["init_c"], items["init_h"])
     q_new, _ = jax.jit(lambda p, it: net.apply(
-        p, learner._make_batch(it).obs, state0))(state.params, items)
+        p, learner.family.make_batch(it).obs, state0))(state.params, items)
     q_old, _ = jax.jit(lambda p, it: net.apply(
         p, _parent_batch(it).obs, state0))(state.params, items)
     np.testing.assert_array_equal(np.asarray(q_new), np.asarray(q_old))
@@ -228,13 +231,14 @@ def test_net_apply_seq_still_takes_uint8_stacks():
     items, _ = _draw(learner, state)
     state0 = (items["init_c"], items["init_h"])
     stacks = _parent_batch(items).obs
-    prepared = jax.eval_shape(lambda it: learner._make_batch(it).obs, items)
+    prepared = jax.eval_shape(
+        lambda it: learner.family.make_batch(it).obs, items)
     assert stacks.dtype == jnp.uint8 and prepared.dtype == jnp.bfloat16
     assert prepared.shape == stacks.shape
     q_u8, s_u8 = jax.jit(learner.net_apply_seq)(state.params, stacks,
                                                 state0)
     q_pre, s_pre = jax.jit(lambda p, it: learner.net_apply_seq(
-        p, learner._make_batch(it).obs, state0))(state.params, items)
+        p, learner.family.make_batch(it).obs, state0))(state.params, items)
     np.testing.assert_array_equal(np.asarray(q_u8), np.asarray(q_pre))
     for a, b in zip(s_u8, s_pre):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -271,8 +275,8 @@ def test_dist_sequence_learner_takes_the_same_prepared_batch():
                                float(out[5]["loss"]), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(d_out[4][0]),
                                np.asarray(out[4]), rtol=1e-6)
-    batch = dist._make_batch(items)
+    batch = dist.family.make_batch(items)
     assert batch.obs.dtype == jnp.bfloat16
     np.testing.assert_array_equal(
         np.asarray(batch.obs, np.float32),
-        np.asarray(single._make_batch(items).obs, np.float32))
+        np.asarray(single.family.make_batch(items).obs, np.float32))

@@ -8,7 +8,7 @@ resolves three call shapes across module boundaries:
 - `name(...)`        a module-level function, local or imported via
                      `from x import name [as alias]`
 - `self.m(...)`      a method on the enclosing class, walking base
-                     classes across modules (SequenceLearner inherits
+                     classes across modules (DistLearner inherits
                      SingleChipLearner from runtime/learner.py)
 - `alias.fn(...)`    a function in another module bound by
                      `import x.y as alias` / `from x import y` where

@@ -50,8 +50,8 @@ from tools.apexlint.common import (
 
 CHECKER = "host-sync"
 
-HOT_BASENAMES = {"learner.py", "dist_learner.py", "sequence_learner.py",
-                 "dpg_learner.py", "ingest.py"}
+HOT_BASENAMES = {"learner.py", "dist_learner.py", "dpg_learner.py",
+                 "ingest.py"}
 DRIVER_HOT_FUNCS = {"_learner_loop", "_learner_loop_inner",
                     "_publish_params", "_ship_staged",
                     "_ship_staged_cold", "_add_block"}

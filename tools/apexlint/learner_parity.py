@@ -1,16 +1,19 @@
-"""Learner-parity checker: the four learner variants stay in lockstep.
+"""Learner-parity checker: the learner stacks stay in lockstep.
 
-`runtime/learner.py`, `parallel/dist_learner.py`,
-`runtime/sequence_learner.py`, and `runtime/dpg_learner.py` each
-re-implement the sample→loss→optimize→write-back cycle, so every
-cross-cutting change must land four times (ROADMAP item 5 — PR 10
-threaded the in-graph diagnostics through all four jits by hand).
-Until the unification refactor collapses them, this checker is the
-enforcement: it statically compares the learners' jitted entry-point
-surfaces and flags drift.
+The sample→loss→optimize→write-back cycle is written twice: once in
+`runtime/learner.py` (SingleChipLearner, whose jitted training
+endpoints `parallel/dist_learner.py`'s DistLearner inherits, for every
+Q-learning family) and once in `runtime/dpg_learner.py` (two nets, two
+optimizers, soft targets). A driver is written against one endpoint
+surface, so a cross-cutting change must land in both (PR 10 threaded
+the in-graph diagnostics through every learner jit by hand). While
+DPGLearner stands apart, this checker is the enforcement: it
+statically compares the learners' jitted entry-point surfaces and
+flags drift — DistLearner's own endpoints (add, add_at, ...) against
+the ones it inherits included.
 
 Discovery — a "learner" is any class whose resolved method table
-(own + inherited, across modules via the call graph: SequenceLearner
+(own + inherited, across modules via the call graph: DistLearner
 inherits SingleChipLearner from another file) contains a jit-decorated
 `train_step` with `donate_argnums`. Only LEAF classes compare (a base
 like SingleChipLearner is represented by its subclasses).
