@@ -33,18 +33,29 @@ static under jit:
   of the flat layout's bytes (~6-7x less for B=16..64).
 
 Device state reuses ReplayState: storage holds a frames ring
-[S*F, pad128(H*W)] uint8 byte rows (S = capacity/B segments) plus
-per-transition fields [capacity] (action/reward/discount/next_off);
+uint32 [S*F, pad128(H*W) // 4] — one frame per row of 32-bit words
+(S = capacity/B segments) — plus per-transition fields [capacity]
+(action/reward/discount/next_off);
 `pos` counts SEGMENTS; the sum-tree indexes transitions. Segment k owns
 transition slots [k*B, (k+1)*B) and frame rows [k*F, (k+1)*F): eviction
 overwrites a whole segment at a time, so transition<->frame aliasing is
 impossible by construction.
 
-Frames are BYTE ROWS, not [H, W] planes, and adds are contiguous
-dynamic_update_slice blocks with skip-to-head wrap — the two rules that
-keep the ring resident in HBM at its logical size with zero-copy
-add/sample graphs (see replay/packing.py for the measured OOM story a
-plane layout + scatter produce at flagship capacity).
+Frames are ROWS OF 32-BIT WORDS, not [H, W] planes, and adds are
+contiguous dynamic_update_slice blocks with skip-to-head wrap — the two
+rules that keep the ring resident in HBM at its logical size with
+zero-copy add/sample graphs (see replay/packing.py for the measured OOM
+story a plane layout + scatter produce at flagship capacity). Word k of
+a row holds pixels 4k..4k+3 of ONE frame, least significant byte first:
+the bytes of the uint8 row [pad128(H*W)] in their order (pad128 is a
+multiple of 128, so always whole words), under a tiling in which a row
+is a row. As uint8 the TPU packs FOUR ROWS into each 32-bit word
+(T(8,128)(4,1)), so a frame was every fourth byte of the words it
+shared with three neighbours and the sample gather fetched four rows
+for each one it returned: 86 ns a row against 25 for the same bytes as
+words (PERF.md §6, PR 29). Bytes become words on the way in
+(`_as_words`) and pixels again only in the sampled batch (`_gather`)
+and in `read_region`; nothing else reads the leaf.
 
 Dead padding slots carry tree priority 0 and are never sampled (the
 descent clamp in ops/sum_tree.py keeps float rounding off them); their
@@ -188,6 +199,42 @@ class FrameSegmentBuilder:
         return out
 
 
+def _as_words(rows: jax.Array) -> jax.Array:
+    """uint8 [..., 4n] -> uint32 [..., n]: word k holds bytes
+    4k..4k+3, least significant first. Written as shifts and ORs, not
+    as `bitcast_convert_type` of a [..., n, 4] view: the two give the
+    same words, but XLA:TPU lays the bitcast's result out rows-minor
+    and, in the per-shard directed write on the mesh, then copies the
+    whole ring to that layout rather than the block to the ring's
+    (add_at_lockstep at flagship size: 9.6 GB of HLO temp, out of
+    memory; as arithmetic every write keeps the byte rows' temp)."""
+    b = rows.reshape(*rows.shape[:-1], -1, 4).astype(jnp.uint32)
+    return b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) | (b[..., 3] << 24)
+
+
+def _as_bytes(words: jax.Array) -> jax.Array:
+    """uint32 [..., n] -> uint8 [..., 4n], the inverse of _as_words."""
+    return jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(
+        *words.shape[:-1], -1)
+
+
+def _byte_transpose(w: jax.Array) -> list[jax.Array]:
+    """Four uint32 arrays (the leading axis of `w`) -> four: byte f of
+    out[i] is byte i of w[f] — a 4x4 transpose of the bytes held at
+    one position, as two rounds of mask-shift-OR on whole words (16-bit
+    halves between w[0]/w[2] and w[1]/w[3], then bytes between the
+    pairs). out[i] is pixel 4k+i of each of the four frames, packed in
+    the order a [..., stack] uint8 array keeps them."""
+    lo, hi = jnp.uint32(0x0000FFFF), jnp.uint32(0xFFFF0000)
+    even, odd = jnp.uint32(0x00FF00FF), jnp.uint32(0xFF00FF00)
+    t0 = (w[0] & lo) | (w[2] << 16)
+    t1 = (w[1] & lo) | (w[3] << 16)
+    t2 = (w[0] >> 16) | (w[2] & hi)
+    t3 = (w[1] >> 16) | (w[3] & hi)
+    return [(t0 & even) | ((t1 & even) << 8), ((t0 >> 8) & even) | (t1 & odd),
+            (t2 & even) | ((t3 & even) << 8), ((t2 >> 8) & even) | (t3 & odd)]
+
+
 class FrameRingReplay(PrioritizedReplay):
     """Device-side prioritized replay over segment storage.
 
@@ -219,10 +266,11 @@ class FrameRingReplay(PrioritizedReplay):
         self.S = capacity // self.B          # segment slots
         self.frame_bytes = self.h * self.w
         self.frame_row = pad128(self.frame_bytes)
+        self.row_words = self.frame_row // 4   # pad128: always whole
         self.obs_dtype = obs_dtype
         if np.dtype(obs_dtype) != np.uint8:
             raise ValueError(
-                f"frame-ring byte-row storage requires uint8 frames "
+                f"frame-ring word-row storage requires uint8 frames "
                 f"(got {np.dtype(obs_dtype)}); use replay.storage='flat' "
                 f"for non-uint8 pixel observations")
 
@@ -232,8 +280,8 @@ class FrameRingReplay(PrioritizedReplay):
         """item_spec is accepted for interface parity and ignored — the
         storage layout is fixed by the constructor arguments."""
         storage = {
-            "frames": jnp.zeros((self.S * self.F, self.frame_row),
-                                self.obs_dtype),
+            "frames": jnp.zeros((self.S * self.F, self.row_words),
+                                jnp.uint32),
             "action": jnp.zeros((self.capacity,), jnp.int32),
             "reward": jnp.zeros((self.capacity,), jnp.float32),
             "discount": jnp.zeros((self.capacity,), jnp.float32),
@@ -280,6 +328,9 @@ class FrameRingReplay(PrioritizedReplay):
         if self.frame_row != self.frame_bytes:
             rows = jnp.pad(rows, [(0, 0)] * (nl + 1)
                            + [(0, self.frame_row - self.frame_bytes)])
+        # pack before the ring write: dus_rows' own astype converts
+        # VALUES and would store one pixel per word
+        rows = _as_words(rows)
         storage = dict(state.storage)
         if per_shard:
             storage["frames"] = dus_rows_per_shard(
@@ -343,8 +394,8 @@ class FrameRingReplay(PrioritizedReplay):
         a cold round trip restages bit-identically."""
         g = block
         st = state.storage
-        rows = jax.lax.dynamic_slice_in_dim(st["frames"], seg0 * self.F,
-                                            g * self.F)
+        rows = _as_bytes(jax.lax.dynamic_slice_in_dim(
+            st["frames"], seg0 * self.F, g * self.F))
         items = {"seg_frames": rows[:, :self.frame_bytes].reshape(
             g, self.F, self.h, self.w)}
         for k in ("action", "reward", "discount", "next_off"):
@@ -379,29 +430,52 @@ class FrameRingReplay(PrioritizedReplay):
         """Reconstruct flat transitions {obs, action, reward, next_obs,
         discount} for transition indices idx [Bt] — a row gather of
         stack frames per side, then a batch-local relayout to
-        [Bt, H, W, stack] planes (the ring itself is never relaid out).
+        [Bt, H, W, stack] uint8 (the ring itself is never relaid out).
 
-        The order of the operations is chosen for the one relayout XLA
-        cannot avoid: the ring holds pixel-minor byte rows and the
-        first conv reads the batch in the lanes. With the stack axis
-        FIRST in the index the rows arrive as [stack, B, row], so that
-        relayout is one 2-D transpose (rows x pixels); with the batch
-        first it took three more passes over the sampled frames
-        (PERF.md §6, PR 25). `chunks`=K (idx chunk-major, the K-batch
-        cycle) gathers each chunk's B rows on its own: chunk j of the
-        result is then a whole array, not a slice of the lane
-        dimension of a K*B one, and the K SGD steps read it as it
-        lands. The bytes returned are the same for every `chunks`."""
+        The gather fetches whole rows of words, [stack, B, words]: one
+        7 kB read per sampled frame (25 ns a row on the v5e; the same
+        bytes as uint8 rows cost 86, four rows fetched per row
+        returned — module docstring). What follows is chosen for the
+        one relayout XLA cannot avoid: the ring is pixel-minor and the
+        first conv reads the batch in the lanes with the stack beside
+        it, i.e. 32-bit words [H*W, B] whose four bytes are the four
+        frames' values of one pixel. A stack of four is therefore
+        built on words: a 4x4 byte transpose across the four frames'
+        words (`_byte_transpose`), each result transposed rows x words
+        as a 32-bit 2-D transpose, the four pixel phases interleaved,
+        and ONE bitcast to uint8 — on the chip a single fusion from
+        the gathered words to conv1's operand layout. Of the forms
+        timed in the real step this was the fastest (PERF.md §6,
+        PR 29); turning the words back into bytes first and
+        transposing those — the plain form any other stack depth
+        takes — gives back most of what the gather saves.
+
+        PR 25's two rules stand. The stack axis is FIRST in the index,
+        so the rows of one frame position arrive together. `chunks`=K
+        (idx chunk-major, the K-batch cycle) gathers each chunk's B
+        rows on its own: chunk j of the result is then a whole array,
+        not a slice of the lane dimension of a K*B one, and the K SGD
+        steps read it as it lands. The bytes returned are the same for
+        every `chunks`."""
         st = state.storage
         seg, j = idx // self.B, idx % self.B
         base = seg * self.F + j
         offs = jnp.arange(self.stack, dtype=jnp.int32)[:, None]
 
         def stack_of(rows_base):
-            f = st["frames"][offs + rows_base[None, :]]  # [stack,B,row]
-            f = f[..., :self.frame_bytes].reshape(
-                self.stack, -1, self.h, self.w)
-            return jnp.transpose(f, (1, 2, 3, 0))        # -> [B,H,W,st]
+            with jax.named_scope("replay.sample_gather"):
+                f = st["frames"][offs + rows_base[None, :]]  # [st,B,words]
+            if self.stack != 4:      # plain form: bytes, then transpose
+                f = _as_bytes(f)[..., :self.frame_bytes].reshape(
+                    self.stack, -1, self.h, self.w)
+                return jnp.transpose(f, (1, 2, 3, 0))    # -> [B,H,W,st]
+            # [words, B] x4, pixel 4k+i of the stack in word k of px[i]
+            px = [p.T for p in _byte_transpose(f)]
+            px = jnp.stack(px, axis=1).reshape(self.frame_row, -1)
+            obs = jax.lax.bitcast_convert_type(
+                px[:self.frame_bytes], jnp.uint8)        # [H*W,B,st]
+            return obs.reshape(self.h, self.w, -1, self.stack) \
+                .transpose(2, 0, 1, 3)                   # -> [B,H,W,st]
 
         def stack_at(rows_base):
             return jnp.concatenate(

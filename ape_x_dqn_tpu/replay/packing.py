@@ -23,6 +23,18 @@ Two design rules eliminate both costs:
    Unpacking after a sample's row gather touches only the sampled
    batch (MBs, not GBs).
 
+   Which stores keep BYTE rows: the packed stores of this module —
+   flat DQN/DPG transitions and R2D2's sequences (PixelPacker). The
+   frame ring does not (replay/frame_ring.py, PR 29): its rows are
+   uint32 words, because a uint8 array's tile packs four ROWS into one
+   32-bit word and a row gather then fetches four rows for every one
+   it returns (measured 86 -> 25 ns a row). The same holds for the
+   rows here (R2D2's sequence gather: ~66 ns a row, PERF.md §7); they
+   stay bytes for now because R2D2's pixel path behind the gather was
+   tuned form by form on uint8 frames (PR 27) and moving its rows is an
+   experiment with a before and after of its own. dus_rows below
+   serves both and stays dtype-agnostic.
+
 2. **Ring writes are `dynamic_update_slice`, never scatter.** A
    scatter into a large donated buffer still materializes a full copy
    (measured: 19.1GB for the 9.47GB 2-D ring); a dynamic_update_slice

@@ -20,6 +20,12 @@ RuntimeError carrying the documented recovery guidance instead of a raw
 Orbax traceback: restart the run fresh, or restore on the old code and
 re-save a params-only checkpoint. Param-only checkpoints (the default)
 are unaffected by layout breaks either way.
+
+History: v2 = the round-5 byte rows; v3 (PR 29) = the frame ring's
+rows are 32-bit words, uint32 [S*F, pad128(H*W) // 4], four pixels of
+one frame to a word (replay/frame_ring.py) — the same bytes, but a v2
+``frames`` leaf is uint8 [S*F, pad128(H*W)] and does not restore into
+it. The packed flat/sequence stores (replay/packing.py) are unchanged.
 """
 
 from __future__ import annotations
@@ -32,9 +38,9 @@ import numpy as np
 import orbax.checkpoint as ocp
 
 # Bump on any break in the on-disk layout of checkpointed device state
-# (storage byte-rows, ReplayState fields, ...). v2 = the round-5
-# byte-row packing layout.
-STORAGE_LAYOUT_VERSION = 2
+# (storage rows, ReplayState fields, ...); the history is in the module
+# docstring.
+STORAGE_LAYOUT_VERSION = 3
 _LAYOUT_KEY = "storage_layout_version"
 
 _LAYOUT_GUIDANCE = (

@@ -179,6 +179,34 @@ def test_checkpoint_layout_version_stamp_transparent(tmp_path):
     mngr.close()
 
 
+def test_replay_checkpoint_of_the_byte_row_ring_is_refused(tmp_path):
+    """A replay-bearing payload stamped v2 (the frame ring's rows were
+    uint8 bytes; they are uint32 words since v3) is refused with the
+    recovery guidance, whatever its leaves would restore into."""
+    import pytest
+
+    from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
+    from ape_x_dqn_tpu.utils import checkpoint as ckpt_mod
+
+    replay = FrameRingReplay(capacity=32, seg_transitions=8, n_step=3,
+                             obs_shape=(12, 12, 4))
+    state = jax.tree.map(np.asarray, replay.init()._asdict())
+    payload = {"replay": state, "step": np.asarray(1, np.int32)}
+    mngr = CheckpointManager(str(tmp_path / "m"))
+    mngr.save(1, {**payload,
+                  ckpt_mod._LAYOUT_KEY: np.asarray(2, np.int32)},
+              wait=True)
+    with pytest.raises(RuntimeError) as err:
+        mngr.restore(step=1, template=jax.tree.map(np.zeros_like, payload))
+    assert "storage layout v2" in str(err.value)
+    assert ckpt_mod._LAYOUT_GUIDANCE in str(err.value)
+    # the same payload under this code's own stamp restores
+    mngr.save(2, payload, wait=True)
+    got = mngr.restore(step=2, template=jax.tree.map(np.zeros_like, payload))
+    assert got["replay"]["storage"]["frames"].dtype == np.uint32
+    mngr.close()
+
+
 def test_checkpoint_structure_mismatch_guidance(tmp_path):
     """An Orbax structure mismatch (e.g. a replay-bearing checkpoint
     written under the pre-versioning layout restored into new-layout

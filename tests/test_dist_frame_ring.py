@@ -18,6 +18,7 @@ flagship e2e test; these tests pin the SEMANTICS of that path:
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ape_x_dqn_tpu.configs import LearnerConfig
 from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
@@ -284,3 +285,92 @@ def test_multichip_baseline_comparable_shapes_only(tmp_path, monkeypatch):
     path, doc = bench._load_multichip_baseline(
         smoke=True, virtual=True, dp_list=[1, 2, 4, 8])
     assert path is None and doc is None
+
+
+# -- the ring's rows are 32-bit words, on the dp mesh too (PR 29) ----------
+
+
+@pytest.mark.parametrize("chunks", (1, 4))
+@pytest.mark.parametrize("write", ("lockstep", "per_shard"))
+def test_dp2_word_rows_sample_byte_for_byte(write, chunks):
+    """dp=2, both dist writes (add_lockstep: one DUS over the shard
+    axis; add_at_lockstep: per-shard unrolled DUS at each shard's own
+    segment): every shard's vmapped sample_items returns its own
+    segments' bytes, and its frames leaf is the single-chip ring's
+    words for the same writes."""
+    replay = _ring()
+    dp, rng = 2, np.random.default_rng(29)
+    stack2 = lambda *xs: jax.tree.map(lambda *v: jnp.stack(v), *xs)
+    per_shard = [[_segs(replay, 3, rng) for _ in range(2)]
+                 for _ in range(dp)]
+    singles = [replay.init() for _ in range(dp)]
+    state = stack2(*singles)
+    for n in range(2):
+        items = stack2(*[per_shard[d][n][0] for d in range(dp)])
+        pris = jnp.stack([per_shard[d][n][1] for d in range(dp)])
+        state = replay.add_lockstep(state, items, pris)
+        singles = [replay.add(singles[d], *per_shard[d][n])
+                   for d in range(dp)]
+    if write == "per_shard":
+        seg0 = jnp.asarray([1, 4], jnp.int32)
+        extra = [_segs(replay, 2, rng) for _ in range(dp)]
+        state = replay.add_at_lockstep(
+            state, stack2(*[e[0] for e in extra]),
+            jnp.stack([e[1] for e in extra]), seg0)
+        singles = [replay.add_at(singles[d], *extra[d], seg0[d])
+                   for d in range(dp)]
+    assert state.storage["frames"].dtype == jnp.uint32
+    assert state.storage["frames"].shape == (
+        dp, replay.S * replay.F, replay.frame_row // 4)
+    keys = jax.random.split(jax.random.key(3), dp)
+    got, idx, probs = jax.vmap(
+        lambda rs, k: replay.sample_items(rs, k, 16, chunks))(state, keys)
+    for d in range(dp):
+        for k in state.storage:
+            np.testing.assert_array_equal(
+                np.asarray(state.storage[k][d]),
+                np.asarray(singles[d].storage[k]), err_msg=k)
+        want, want_idx, want_probs = replay.sample_items(
+            singles[d], keys[d], 16, chunks)
+        np.testing.assert_array_equal(np.asarray(idx[d]),
+                                      np.asarray(want_idx))
+        np.testing.assert_array_equal(np.asarray(probs[d]),
+                                      np.asarray(want_probs))
+        # the single-chip sample is held to the segments themselves in
+        # tests/test_frame_ring.py; here: to this shard's staged frames
+        byte_rows = np.asarray(jax.lax.bitcast_convert_type(
+            singles[d].storage["frames"], jnp.uint8)).reshape(
+                -1, replay.frame_row)[:, :replay.frame_bytes]
+        for j, t in enumerate(np.asarray(idx[d])):
+            row = (t // replay.B) * replay.F + t % replay.B
+            off = int(singles[d].storage["next_off"][t])
+            for side, r0 in (("obs", row), ("next_obs", row + off)):
+                np.testing.assert_array_equal(
+                    np.asarray(got[side][d, j]),
+                    np.moveaxis(byte_rows[r0:r0 + replay.stack].reshape(
+                        replay.stack, replay.h, replay.w), 0, -1),
+                    err_msg=side)
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(got[k][d]),
+                                          np.asarray(want[k]), err_msg=k)
+
+
+def test_dp2_word_ring_is_written_in_place():
+    """The lockstep add aliases the donated [dp, rows, words] ring."""
+    replay = FrameRingReplay(capacity=4096, seg_transitions=16, n_step=3,
+                             obs_shape=(84, 84, 4))
+    shapes = jax.eval_shape(
+        lambda: jax.tree.map(lambda x: jnp.stack([x, x]), replay.init()))
+    ring_bytes = shapes.storage["frames"].size * 4
+    items = {
+        "seg_frames": jax.ShapeDtypeStruct((2, 4, replay.F, 84, 84),
+                                           jnp.uint8),
+        **{k: jax.ShapeDtypeStruct((2, 4, replay.B), dt) for k, dt in (
+            ("action", jnp.int32), ("reward", jnp.float32),
+            ("discount", jnp.float32), ("next_off", jnp.int32))}}
+    pris = jax.ShapeDtypeStruct((2, 4, replay.B), jnp.float32)
+    mem = jax.jit(replay.add_lockstep, donate_argnums=0).lower(
+        shapes, items, pris).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < ring_bytes // 4, (
+        mem.temp_size_in_bytes, ring_bytes)
+    assert mem.alias_size_in_bytes >= ring_bytes

@@ -338,3 +338,213 @@ def test_driver_rejects_frame_ring_for_non_dqn():
                                                  storage="frame_ring"))
     with pytest.raises(NotImplementedError):
         ApexDriver(cfg)
+
+
+# -- the ring's rows are 32-bit words (PR 29) --------------------------------
+
+# H*W = 63: not a multiple of 4, so the last word of a frame is partly pad
+L_OBS = (7, 9, 4)
+L_CAP, L_SEG = 64, 8
+
+
+def _layout_ring(obs=L_OBS):
+    return FrameRingReplay(capacity=L_CAP, seg_transitions=L_SEG,
+                           n_step=N_STEP, obs_shape=obs)
+
+
+def _stamped_segments(replay, g, serial0, rng):
+    """g segments as the ingest stages them; every frame carries its
+    4-byte little-endian serial in pixels 0-3 (the benchmark's stamp)
+    over random pixels, so a frame fetched from the wrong row or a
+    byte from the wrong lane of a word cannot pass."""
+    f, b = replay.F, replay.B
+    frames = rng.integers(0, 256, (g, f, replay.h * replay.w), np.uint8)
+    serial = (serial0 + np.arange(g * f, dtype=np.uint32)).reshape(g, f)
+    frames[..., :4] = serial[..., None].astype("<u4").view(np.uint8)
+    items = {
+        "seg_frames": frames.reshape(g, f, replay.h, replay.w),
+        "action": rng.integers(0, 4, (g, b)).astype(np.int32),
+        "reward": rng.normal(size=(g, b)).astype(np.float32),
+        "discount": rng.uniform(0.5, 1.0, (g, b)).astype(np.float32),
+        "next_off": rng.integers(1, N_STEP + 1, (g, b)).astype(np.int32),
+    }
+    pris = rng.uniform(0.1, 2.0, (g, b)).astype(np.float32)
+    return items, pris
+
+
+class _HostRing:
+    """Numpy oracle of the ring built from the segments themselves:
+    whole segments at the cursor with skip-to-head, or where told."""
+
+    def __init__(self, replay):
+        self.r = replay
+        self.items = {}          # segment slot -> its staged fields
+        self.pos = 0
+
+    def write(self, items, seg0=None):
+        g = items["action"].shape[0]
+        if seg0 is None:
+            seg0 = self.pos if self.pos + g <= self.r.S else 0
+        for i in range(g):
+            self.items[seg0 + i] = {k: v[i] for k, v in items.items()}
+        self.pos = (seg0 + g) % self.r.S
+
+    def transition(self, idx):
+        seg, j = divmod(int(idx), self.r.B)
+        it = self.items[seg]
+        off = int(it["next_off"][j])
+        st = self.r.stack
+        return {"obs": np.moveaxis(it["seg_frames"][j:j + st], 0, -1),
+                "next_obs": np.moveaxis(
+                    it["seg_frames"][j + off:j + off + st], 0, -1),
+                "action": it["action"][j], "reward": it["reward"][j],
+                "discount": it["discount"][j]}
+
+    def byte_rows(self):
+        rows = np.zeros((self.r.S * self.r.F, self.r.frame_row), np.uint8)
+        for seg, it in self.items.items():
+            rows[seg * self.r.F:(seg + 1) * self.r.F, :self.r.frame_bytes] \
+                = it["seg_frames"].reshape(self.r.F, -1)
+        return rows
+
+
+def _fill(case, obs=L_OBS):
+    """-> (replay, device state, oracle) after the case's writes."""
+    replay = _layout_ring(obs)
+    rng = np.random.default_rng(29)
+    state, host = replay.init(), _HostRing(replay)
+    add = jax.jit(replay.add)
+    add_at = jax.jit(replay.add_at)
+    blocks = {"add": 2, "wrap": 3, "add_at": 2}[case]
+    for n in range(blocks):         # 3 blocks of 3 in 8 slots: the third
+        items, pris = _stamped_segments(replay, 3, 1000 * n, rng)  # wraps
+        state = add(state, items, pris)
+        host.write(items)
+    if case == "wrap":
+        assert int(state.pos) == 3 and host.pos == 3
+    if case == "add_at":
+        items, pris = _stamped_segments(replay, 2, 7000, rng)
+        state = add_at(state, items, pris, jnp.int32(1))
+        host.write(items, seg0=1)
+    return replay, state, host
+
+
+# a stack of 4 is rebuilt on words; any other depth takes the plain form
+@pytest.mark.parametrize("case,chunks,obs", [
+    *[(case, chunks, L_OBS) for case in ("add", "wrap", "add_at")
+      for chunks in (1, 4)],
+    ("wrap", 4, (7, 9, 3)), ("add_at", 1, (5, 5, 6))])
+def test_word_rows_sample_byte_for_byte(case, chunks, obs):
+    """sample_items over a ring of 32-bit-word rows: obs / next_obs /
+    fields byte for byte the segments', idx / probs what the sum-tree
+    alone decides (the draw never reads the frames, so they are the
+    byte-row ring's for the same key)."""
+    from ape_x_dqn_tpu.ops import sum_tree
+    replay, state, host = _fill(case, obs)
+    key, batch = jax.random.key(5), 32
+    got, idx, probs = jax.jit(
+        replay.sample_items, static_argnums=(2, 3))(state, key, batch,
+                                                    chunks)
+    want_idx, want_probs = sum_tree.sample(state.tree, key, batch,
+                                           size=state.size, chunks=chunks)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_array_equal(np.asarray(probs), np.asarray(want_probs))
+    assert got["obs"].dtype == jnp.uint8
+    assert got["obs"].shape == got["next_obs"].shape == (batch, *obs)
+    want = [host.transition(i) for i in np.asarray(idx)]
+    for k in ("obs", "next_obs", "action", "reward", "discount"):
+        np.testing.assert_array_equal(
+            np.asarray(got[k]), np.stack([t[k] for t in want]), err_msg=k)
+
+
+@pytest.mark.parametrize("case", ("add", "wrap", "add_at"))
+def test_word_rows_hold_the_frames_bytes_in_order(case):
+    """storage["frames"] is uint32 [S*F, frame_row // 4]; read as bytes
+    (least significant first) it is the padded byte row of each frame,
+    and utils/hbm.py prices it as it priced the byte rows."""
+    from ape_x_dqn_tpu.utils import hbm
+    replay, state, host = _fill(case)
+    frames = state.storage["frames"]
+    assert frames.dtype == jnp.uint32
+    assert frames.shape == (replay.S * replay.F, replay.frame_row // 4)
+    words = np.asarray(frames)
+    got = np.stack([(words >> (8 * i)) & 0xFF for i in range(4)],
+                   axis=-1).astype(np.uint8).reshape(words.shape[0], -1)
+    np.testing.assert_array_equal(got, host.byte_rows())
+    priced, detail = hbm._frame_ring_bytes(L_CAP, L_SEG, N_STEP, L_OBS)
+    assert detail["frame_rows"] * detail["frame_row_bytes"] \
+        == frames.nbytes == replay.S * replay.F * 128
+    assert priced == sum(x.nbytes for x in state.storage.values())
+
+
+def test_word_rows_read_region_restages_bit_identically():
+    """read_region hands back uint8 segments (the cold tier's unit) and
+    add_at of them rewrites the same words."""
+    replay, state, host = _fill("add_at")
+    items, pri = jax.jit(replay.read_region, static_argnums=2)(
+        state, jnp.int32(1), 3)
+    assert items["seg_frames"].dtype == jnp.uint8
+    assert items["seg_frames"].shape == (3, replay.F, replay.h, replay.w)
+    for i in range(3):
+        for k, v in host.items[1 + i].items():
+            np.testing.assert_array_equal(np.asarray(items[k][i]), v,
+                                          err_msg=k)
+    # restage into an emptied copy of the region
+    blank = jax.tree.map(jnp.zeros_like, items)
+    wiped = replay.add_at(state, blank, jnp.zeros_like(pri), jnp.int32(1))
+    assert not np.array_equal(np.asarray(wiped.storage["frames"]),
+                              np.asarray(state.storage["frames"]))
+    back = replay.add_at(wiped, items, jnp.zeros_like(pri), jnp.int32(1))
+    for k in state.storage:
+        np.testing.assert_array_equal(np.asarray(back.storage[k]),
+                                      np.asarray(state.storage[k]),
+                                      err_msg=k)
+
+
+@pytest.mark.parametrize("write", ("add", "add_at", "add_lockstep",
+                                   "add_at_lockstep"))
+def test_no_byte_block_reaches_the_word_ring(write, monkeypatch):
+    """dus_rows converts VALUES (block.astype(buf.dtype)): a uint8
+    block handed to it for the uint32 ring would store one pixel per
+    word. Every write packs its bytes into words first, so each block
+    already has its buffer's dtype when the ring write sees it."""
+    from ape_x_dqn_tpu.replay import frame_ring
+    seen = []
+
+    def checked(real):
+        def write_rows(buf, block, *args, **kw):
+            seen.append((buf.dtype, block.dtype))
+            return real(buf, block, *args, **kw)
+        return write_rows
+
+    monkeypatch.setattr(frame_ring, "dus_rows",
+                        checked(frame_ring.dus_rows))
+    monkeypatch.setattr(frame_ring, "dus_rows_per_shard",
+                        checked(frame_ring.dus_rows_per_shard))
+    replay = _layout_ring()
+    items, pris = _stamped_segments(replay, 2, 0, np.random.default_rng(1))
+    args = [replay.init(), items, pris]
+    if "lockstep" in write:
+        args = [jax.tree.map(lambda x: jnp.stack([x, x]), a) for a in args]
+    if "at" in write:
+        args.append(jnp.asarray([1, 4], jnp.int32) if "lockstep" in write
+                    else jnp.int32(1))
+    getattr(replay, write)(*args)
+    assert (jnp.uint32, jnp.uint32) in seen       # the frames leaf
+    assert all(buf == block for buf, block in seen), seen
+
+
+def test_word_ring_is_written_in_place():
+    """The compiled add aliases the donated ring: no temporary the size
+    of the frames leaf (the byte rows' add temp 0, PERF.md)."""
+    replay = FrameRingReplay(capacity=4096, seg_transitions=16,
+                             n_step=N_STEP, obs_shape=(84, 84, 4))
+    items, pris = _stamped_segments(replay, 4, 0, np.random.default_rng(2))
+    shapes = jax.eval_shape(replay.init)
+    ring_bytes = shapes.storage["frames"].size * 4
+    compiled = jax.jit(replay.add, donate_argnums=0).lower(
+        shapes, items, pris).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < ring_bytes // 4, (
+        mem.temp_size_in_bytes, ring_bytes)
+    assert mem.alias_size_in_bytes >= ring_bytes

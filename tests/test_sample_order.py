@@ -177,8 +177,12 @@ def test_frame_ring_gather_bytes_do_not_depend_on_its_order(k):
     st = rs.storage
     base = (idx // replay.B) * replay.F + idx % replay.B
 
+    # the ring's rows as the bytes they hold (a row is 32-bit words)
+    byte_rows = jax.lax.bitcast_convert_type(
+        st["frames"], jnp.uint8).reshape(-1, replay.frame_row)
+
     def batch_first(rows_base):
-        f = st["frames"][rows_base[:, None] + jnp.arange(replay.stack)]
+        f = byte_rows[rows_base[:, None] + jnp.arange(replay.stack)]
         f = f[..., :replay.frame_bytes].reshape(
             -1, replay.stack, replay.h, replay.w)
         return jnp.moveaxis(f, 1, -1)
@@ -322,8 +326,9 @@ def test_dist_learn_k_writes_what_the_old_inverse_transform_wrote(k):
 
 # -- no second pass over the sampled frames ---------------------------------
 
-def _image_transposes(jaxpr, min_elems, shard_axis):
-    """Elements of uint8 operands (>= min_elems each) that `transpose`
+def _image_transposes(jaxpr, min_bytes, shard_axis):
+    """Bytes of sampled-frame operands (uint8 pixels or the uint32
+    words the ring keeps them in, >= min_bytes each) that `transpose`
     equations reorder, nested jaxprs included. With `shard_axis` (the
     dist stack's leading dp axis, extent 1 on each chip) a transpose
     that only moves that axis past the others is free and not
@@ -334,24 +339,28 @@ def _image_transposes(jaxpr, min_elems, shard_axis):
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    total += _image_transposes(inner, min_elems, shard_axis)
+                    total += _image_transposes(inner, min_bytes, shard_axis)
         if eqn.primitive.name != "transpose":
             continue
         aval = eqn.invars[0].aval
-        if aval.dtype != jnp.uint8 or aval.size < min_elems:
+        nbytes = aval.size * aval.dtype.itemsize
+        if aval.dtype not in (jnp.uint8, jnp.uint32) or nbytes < min_bytes:
             continue
         perm = [p for p in eqn.params["permutation"] if p != shard_axis]
         if perm != sorted(perm):
-            total += aval.size
+            total += nbytes
     return total
 
 
 @pytest.mark.parametrize("stack", ("single", "dist"))
 def test_sampled_frames_are_transposed_once(stack):
     """k=4, frame ring: the transposes over the sampled frames in
-    _sample_stage are those of FrameRingReplay._gather alone — one
-    pass per side (obs, next_obs). The parent's chunked() adds a pass
-    per side; the helper counts it (checked on a copy of it below)."""
+    _sample_stage are those of FrameRingReplay._gather alone — per
+    side (obs, next_obs) one pass over the gathered words (rows x
+    words, per pixel phase) and the [H,W,B,stack] -> [B,H,W,stack]
+    relabelling of the result, which is conv1's layout and no copy on
+    the chip. The parent's chunked() adds a pass per side; the helper
+    counts it (checked on a copy of it below)."""
     k = 4
     h, w, st = OBS
     if stack == "single":
@@ -361,7 +370,7 @@ def test_sampled_frames_are_transposed_once(stack):
         gather = lambda rs, idx: replay._gather(rs, idx, k)
         idx = jnp.zeros((k * B,), jnp.int32)
         old = lambda rs, key: _old_sample_stage(replay, rs, key, k, B)
-        images = k * B * h * w * st
+        chips = 1
     else:
         replay, rs = _filled("ring", seed=17, lead=(DP,))
         learner = _dist_learner("ring", replay)
@@ -369,11 +378,13 @@ def test_sampled_frames_are_transposed_once(stack):
         gather = jax.vmap(lambda rs, idx: replay._gather(rs, idx, k))
         idx = jnp.zeros((DP, k * B), jnp.int32)
         old = lambda rs, key: _old_dist_sample_stage(learner, rs, key, k)
-        images = DP * k * B * h * w * st
+        chips = DP
+    images = chips * k * B * h * w * st           # bytes of one side
+    words = chips * k * B * st * replay.frame_row  # its gathered rows
     count = lambda fn, *a: _image_transposes(
-        jax.make_jaxpr(fn)(*a).jaxpr, images // k, shard_axis)
+        jax.make_jaxpr(fn)(*a).jaxpr, B * h * w, shard_axis)
     in_gather = count(gather, rs, idx)
-    assert in_gather == 2 * images            # each frame once per side
+    assert in_gather == 2 * (words + images)
     assert count(lambda rs, key: learner._sample_stage(rs, key, k),
                  rs, key) == in_gather
-    assert count(old, rs, key) == 2 * in_gather   # what chunked() cost
+    assert count(old, rs, key) == in_gather + 2 * images  # chunked()'s
