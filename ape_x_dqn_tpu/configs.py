@@ -20,7 +20,7 @@ from typing import Any
 @dataclass(frozen=True)
 class EnvConfig:
     id: str = "CartPole-v1"
-    kind: str = "cartpole"  # cartpole | cartpole_po | atari | control | synthetic_atari
+    kind: str = "cartpole"  # cartpole | cartpole_po | atari | control | synthetic_atari | synthetic_tokens
     # Atari preprocessing (SURVEY.md §2.2 "Env wrappers")
     frame_skip: int = 4
     frame_stack: int = 4
@@ -34,11 +34,79 @@ class EnvConfig:
     # games with heterogeneous minimal sets), and set by per-game eval
     # workers evaluating such a net so action indices stay aligned.
     full_action_set: bool = False
+    # synthetic_tokens: how many token ids the environment emits and
+    # accepts as actions — the vocabulary rows the Q-network holds
+    # (network.glm.vocab_size / network.glm.shard_count)
+    num_tokens: int = 256
+
+
+@dataclass(frozen=True)
+class GlmMoeConfig:
+    """The decoder of network.kind="glm_moe_q" (models/glm_moe_q.py),
+    under the key names of the model's own config.json (model_type
+    glm4_moe_lite); defaults are GLM-4.7-Flash's. Multi-head latent
+    attention, `first_k_dense_replace` leading dense SwiGLU layers,
+    then layers of `n_routed_experts` routed experts (sigmoid scores,
+    top-`num_experts_per_tok` of score + a fixed selection bias,
+    weights normalised and scaled) beside `n_shared_experts` shared
+    ones; untied embedding and head."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 10240      # the dense layers' SwiGLU
+    moe_intermediate_size: int = 1536   # each routed / shared expert
+    num_hidden_layers: int = 47
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    n_routed_experts: int = 64
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 4
+    n_group: int = 1                    # only 1 is built: no group stage
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.8
+    vocab_size: int = 154_880
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1_000_000.0
+    # This chip's share of a deployment in which `shard_count` chips
+    # share each layer: attention, router and shared expert are
+    # replicated, the routed experts [shard_index * n / shard_count,
+    # (shard_index + 1) * n / shard_count) and as many of the
+    # vocabulary's rows live here. The router still scores all
+    # n_routed_experts; what absent experts would add is left out
+    # (models/glm_moe_q.py). 1 = the whole layer.
+    shard_count: int = 1
+    shard_index: int = 0
+    # For measuring with random weights only (Megatron-LM's
+    # --moe-router-force-load-balancing is the precedent): the top-k
+    # SELECTION follows a fixed pseudo-random function of (token id,
+    # position, layer, expert) instead of score + bias, so every expert
+    # sees its even share of the rows whatever the weights are; the
+    # weights of the selected experts are still the router's. Random
+    # weights select nearly the same k experts for every token
+    # (models/glm_moe_q.py says why), which no trained checkpoint does.
+    force_balanced_routing: bool = False
+
+    def __post_init__(self) -> None:
+        if not 0 <= self.shard_index < self.shard_count:
+            raise ValueError(
+                f"network.glm.shard_index must be in [0, "
+                f"{self.shard_count}) (got {self.shard_index})")
+        if (self.n_routed_experts % self.shard_count
+                or self.vocab_size % self.shard_count):
+            raise ValueError(
+                f"network.glm.shard_count={self.shard_count} must "
+                f"divide n_routed_experts={self.n_routed_experts} and "
+                f"vocab_size={self.vocab_size}")
 
 
 @dataclass(frozen=True)
 class NetworkConfig:
-    kind: str = "mlp"  # mlp | nature_cnn | lstm_q | dpg
+    kind: str = "mlp"  # mlp | nature_cnn | lstm_q | dpg | glm_moe_q
     mlp_hidden: tuple[int, ...] = (256, 256)
     cnn_channels: tuple[int, ...] = (32, 64, 64)
     cnn_kernels: tuple[int, ...] = (8, 4, 3)
@@ -50,6 +118,8 @@ class NetworkConfig:
     dpg_hidden: tuple[int, ...] = (300, 200)
     # Compute dtype for the forward/backward pass (params stay f32).
     compute_dtype: str = "bfloat16"
+    # the decoder of kind="glm_moe_q" (token-level Q-learning)
+    glm: GlmMoeConfig = field(default_factory=GlmMoeConfig)
 
 
 @dataclass(frozen=True)
@@ -785,12 +855,78 @@ def _preset_apex_dpg() -> RunConfig:
     )
 
 
+def _preset_glm47_flash_q() -> RunConfig:
+    """Config 6: GLM-4.7-Flash as a token-level Q-network (ILQL's form,
+    Snell et al. 2022, run the Ape-X way): observation = the token
+    sequence so far, action = the next token, Q(s_t, .) = the decoder's
+    own head. The sizes are the model's config.json
+    (https://huggingface.co/zai-org/GLM-4.7-Flash, model_type
+    glm4_moe_lite): 47 layers, 64 routed experts, 154,880 vocabulary
+    rows — 30 B parameters, which at this learner's 16 B a parameter
+    no chip holds: a run gives one chip its share with
+    network.glm.shard_count / num_hidden_layers and env.num_tokens
+    (benchmarks/configs/glm47_flash_ep8_1chip.json is the measured
+    one; check_hbm_fits refuses the preset as it stands). The learner
+    settings are this repo's: no model card gives them."""
+    glm = GlmMoeConfig()
+    return RunConfig(
+        name="glm47_flash_q",
+        total_env_frames=10_000_000_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=glm.vocab_size),
+        network=NetworkConfig(kind="glm_moe_q", dueling=False, glm=glm),
+        # a stored sequence is 512 tokens: 128 of burn-in, whose latent
+        # cache the trained 384 attend to without gradient; no state is
+        # stored with it (runtime/family.py). 65,536 of them are 0.7 GB
+        replay=ReplayConfig(kind="sequence", capacity=65_536,
+                            seq_length=512, seq_overlap=256, burn_in=128,
+                            min_fill=2_048),
+        # batch 16 sequences = 8,192 tokens a step; a step is ~0.26 s of
+        # matmuls, so a dispatch is two steps and the K-batch draw
+        # (which amortises a per-step tree round-trip) is off. The
+        # optimizer is Ape-X's (Adam 1e-4 / eps 1.5e-7, clip 40)
+        learner=LearnerConfig(batch_size=16, n_step=5, value_rescale=True,
+                              target_sync_every=2500, lr=1e-4,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=64, envs_per_actor=16),
+        inference=InferenceConfig(max_batch=16, deadline_ms=2.0),
+    )
+
+
+def _preset_glm_tiny_q() -> RunConfig:
+    """glm47_flash_q's sibling for CPU tests: the same decoder at
+    hidden 64 with 8 experts and a vocabulary of 64, float32."""
+    glm = GlmMoeConfig(
+        hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+        num_hidden_layers=3, num_attention_heads=2, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=8,
+        v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2,
+        vocab_size=64)
+    return RunConfig(
+        name="glm_tiny_q",
+        total_env_frames=100_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=glm.vocab_size),
+        network=NetworkConfig(kind="glm_moe_q", dueling=False, glm=glm,
+                              compute_dtype="float32"),
+        replay=ReplayConfig(kind="sequence", capacity=64, seq_length=16,
+                            seq_overlap=8, burn_in=4, min_fill=8),
+        learner=LearnerConfig(batch_size=4, n_step=3, value_rescale=True,
+                              target_sync_every=100, lr=1e-3,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=1, envs_per_actor=2),
+        inference=InferenceConfig(max_batch=8, deadline_ms=2.0),
+    )
+
+
 PRESETS = {
     "cartpole_smoke": _preset_cartpole_smoke,
     "pong": _preset_pong,
     "atari57_apex": _preset_atari57_apex,
     "r2d2": _preset_r2d2,
     "apex_dpg": _preset_apex_dpg,
+    "glm47_flash_q": _preset_glm47_flash_q,
+    "glm_tiny_q": _preset_glm_tiny_q,
 }
 
 

@@ -65,4 +65,9 @@ def make_env(cfg, seed: int = 0, actor_index: int = 0) -> Env:
         return atari.make_atari(cfg, seed=seed, actor_index=actor_index)
     if kind == "control":
         return control.make_control(cfg, seed=seed)
+    if kind == "synthetic_tokens":
+        from ape_x_dqn_tpu.envs.tokens import SyntheticTokens
+
+        return SyntheticTokens(cfg.num_tokens, seed=seed,
+                               max_episode_frames=cfg.max_episode_frames)
     raise ValueError(f"unknown env kind {kind!r}")

@@ -5,6 +5,7 @@ from ape_x_dqn_tpu.models.base import (
 from ape_x_dqn_tpu.models.qnets import MLPQNet, NatureDQN, DuelingHead
 from ape_x_dqn_tpu.models.lstm_q import ApeXLSTMQNet, LSTMState
 from ape_x_dqn_tpu.models.dpg import DPGActor, DPGCritic
+from ape_x_dqn_tpu.models.glm_moe_q import GlmMoeQNet
 
 
 def build_network(net_cfg, spec):
@@ -32,6 +33,11 @@ def build_network(net_cfg, spec):
                             dueling=net_cfg.dueling,
                             compute_dtype=net_cfg.compute_dtype,
                             mlp_torso=len(spec.obs_shape) == 1)
+    if net_cfg.kind == "glm_moe_q":
+        from ape_x_dqn_tpu.parallel.mesh import has_expert_exchange
+
+        return GlmMoeQNet(net_cfg.glm, compute_dtype=net_cfg.compute_dtype,
+                          expert_exchange=has_expert_exchange())
     if net_cfg.kind == "dpg":
         actor = DPGActor(action_dim=spec.action_dim,
                          action_low=spec.action_low,

@@ -1,10 +1,20 @@
-"""R2D2 sequence replay: host-side sequence assembly + device storage.
+"""Sequence replay: host-side sequence assembly + device storage.
 
-Actors assemble fixed-length overlapping sequences with the recurrent
-state stored from *before* the first step (SURVEY.md §2.2 "Sequence
-replay", §3.4); the sequences are then items in the generic
-device-resident PrioritizedReplay, so sampling/priority updates run
-inside the learner jit exactly like flat transitions.
+Actors assemble fixed-length overlapping sequences; the sequences are
+then items in the generic device-resident PrioritizedReplay, so
+sampling/priority updates run inside the learner jit exactly like flat
+transitions.
+
+What state a sequence carries is its family's (runtime/family.py
+`ACTOR_STATE`), and there are two kinds. R2D2 stores the recurrent
+state from *before* the first step (SURVEY.md §2.2 "Sequence replay",
+§3.4): `init_c`, `init_h`. The token-level decoder family stores none:
+its item is obs (one int32 id per step), actions, rewards, terminals
+and mask, nothing else — a replayed window starts from an empty
+attention cache and the burn-in prefix rebuilds its context as a latent
+cache inside the learner jit. `sequence_item_spec` and
+`SequenceBuilder` take the stored entries by name; no entry is stored
+by habit, and none is a zero-width row in the packed store.
 
 Defaults follow Kapturowski et al. 2019: length 80, overlap 40
 (adjacent sequences share half their steps), burn-in 40 handled by the
@@ -29,9 +39,14 @@ sequence_frame_mode = frame_mode
 
 
 def sequence_item_spec(obs_shape: tuple[int, ...], obs_dtype,
-                       seq_len: int, lstm_size: int,
+                       seq_len: int, state: int | dict,
                        frame_mode: bool = False) -> dict:
     """ShapeDtypeStruct-style pytree describing ONE stored sequence.
+
+    `state`: {name: shape} of the float32 state entries stored with a
+    sequence, each as `init_<name>` (family.stored_state_spec; {} for a
+    family that stores none); an int n is the LSTM's
+    {"c": (n,), "h": (n,)}.
 
     frame_mode (pixel obs only): store single frames
     [seq_len + stack - 1, H, W] instead of per-step stacks
@@ -52,14 +67,16 @@ def sequence_item_spec(obs_shape: tuple[int, ...], obs_dtype,
     else:
         obs_sds = jax.ShapeDtypeStruct((seq_len, *obs_shape), obs_dtype)
         obs_key = "obs"
+    if isinstance(state, int):
+        state = {"c": (state,), "h": (state,)}
     return {
         obs_key: obs_sds,
         "actions": jax.ShapeDtypeStruct((seq_len,), np.int32),
         "rewards": jax.ShapeDtypeStruct((seq_len,), f32),
         "terminals": jax.ShapeDtypeStruct((seq_len,), f32),
         "mask": jax.ShapeDtypeStruct((seq_len,), f32),
-        "init_c": jax.ShapeDtypeStruct((lstm_size,), f32),
-        "init_h": jax.ShapeDtypeStruct((lstm_size,), f32),
+        **{"init_" + name: jax.ShapeDtypeStruct(tuple(shape), f32)
+           for name, shape in state.items()},
     }
 
 
@@ -76,8 +93,13 @@ class SequenceBuilder:
 
     def __init__(self, seq_len: int = 80, overlap: int = 40,
                  lstm_size: int = 512, priority_eta: float = 0.9,
-                 frame_mode: bool = False):
-        """frame_mode: emit single frames ("seq_frames") instead of
+                 frame_mode: bool = False,
+                 state_keys: tuple[str, ...] = ("c", "h")):
+        """state_keys: the entries of `append`'s pre_state, in order,
+        that an emitted item stores as `init_<key>` (the LSTM's by
+        default; () for a family that stores no state).
+
+        frame_mode: emit single frames ("seq_frames") instead of
         per-step stacks — valid for [H, W, stack] pixel obs whose
         channels slide one frame per step (the Atari wrapper's
         invariant; holds within an episode, and sequences never span
@@ -88,14 +110,16 @@ class SequenceBuilder:
         self.lstm_size = lstm_size
         self.priority_eta = priority_eta
         self.frame_mode = frame_mode
-        self._steps: list[dict] = []  # each: obs/action/reward/terminal/pre_c/pre_h
+        self.state_keys = tuple(state_keys)
+        self._steps: list[dict] = []  # each: obs/action/reward/terminal/pre
         self._retained = 0  # leading steps already covered by a prior emit
 
     def append(self, obs, action, reward, terminal: bool,
-               pre_state: tuple[np.ndarray, np.ndarray],
+               pre_state: tuple[np.ndarray, ...],
                td: float = 0.0,
                episode_end: bool | None = None) -> list[dict]:
-        """Add one step; pre_state is the (c, h) fed to the net AT this step.
+        """Add one step; pre_state is the state fed to the net AT this
+        step, one array per `state_keys` entry ((c, h) for the LSTM).
 
         `terminal` marks a bootstrapping-relevant episode end (stored in
         the terminals array); `episode_end` (default: terminal) flushes
@@ -106,12 +130,11 @@ class SequenceBuilder:
         """
         if episode_end is None:
             episode_end = terminal
-        c, h = pre_state
         self._steps.append(dict(
             obs=np.asarray(obs), action=int(action), reward=float(reward),
             terminal=bool(terminal), td=abs(float(td)),
-            pre_c=np.asarray(c, np.float32).reshape(-1),
-            pre_h=np.asarray(h, np.float32).reshape(-1)))
+            pre=tuple(np.asarray(x, np.float32).reshape(-1)
+                      for x in pre_state[:len(self.state_keys)])))
         out = []
         if len(self._steps) == self.seq_len:
             out.append(self._emit(self._steps))
@@ -163,7 +186,8 @@ class SequenceBuilder:
         item = {
             "actions": actions, "rewards": rewards,
             "terminals": terminals, "mask": mask,
-            "init_c": first["pre_c"], "init_h": first["pre_h"],
+            **{"init_" + k: x
+               for k, x in zip(self.state_keys, first["pre"])},
             "priority": priority,
         }
         if self.frame_mode:

@@ -382,22 +382,28 @@ class RecurrentActor(Actor):
                  obs: object | None = None):
         super().__init__(cfg, actor_index, query_fn, transport, seed=seed,
                          episode_callback=episode_callback, obs=obs)
+        from ape_x_dqn_tpu.runtime.family import ACTOR_STATE, family_of
+
         self.gamma = cfg.learner.gamma
-        self.lstm_size = cfg.network.lstm_size
+        # what a query carries beside the observation, and which of it
+        # a sequence stores: the family's row (runtime/family.py)
+        self._state_spec = ACTOR_STATE[family_of(cfg)]
         frame_mode = cfg.replay.storage == "frame_ring"
         if frame_mode:
             assert len(self.env.spec.obs_shape) == 3, \
                 "frame_ring sequence storage needs [H, W, stack] pixel obs"
         self.builder = SequenceBuilder(
             seq_len=cfg.replay.seq_length, overlap=cfg.replay.seq_overlap,
-            lstm_size=self.lstm_size, priority_eta=cfg.replay.priority_eta,
-            frame_mode=frame_mode)
+            priority_eta=cfg.replay.priority_eta, frame_mode=frame_mode,
+            state_keys=self._state_spec.stored)
         self.ship_after = sequence_ship_after(cfg)
         self._outbox: list[dict] = []  # sequence items, not transitions
 
-    def _zero_state(self) -> tuple[np.ndarray, np.ndarray]:
-        z = np.zeros(self.lstm_size, np.float32)
-        return z, z.copy()
+    def _zero_state(self) -> dict:
+        return self._state_spec.zeros(self.cfg)
+
+    def _stored(self, state: dict) -> tuple:
+        return tuple(state[k] for k in self._state_spec.stored)
 
     def _feed(self, rec: dict, td: float) -> None:
         feed_sequence(self._outbox, self.builder, rec, td)
@@ -419,13 +425,13 @@ class RecurrentActor(Actor):
     def run(self, max_frames: int,
             stop_event: threading.Event | None = None) -> int:
         obs = self.env.reset()
-        c, h = self._zero_state()
+        state = self._zero_state()
         prev: dict | None = None  # step awaiting its 1-step TD bootstrap
         while self.frames < max_frames and not (
                 stop_event is not None and stop_event.is_set()):
             self.obs.beat(self._hb)
             with self.obs.span("actor.inference"):
-                out = self.query({"obs": obs, "c": c, "h": h})
+                out = self.query({"obs": obs, **state})
             q = out["q"]
             if prev is not None:
                 td = (prev["reward"] + self.gamma * float(np.max(q))
@@ -441,7 +447,7 @@ class RecurrentActor(Actor):
             self._frames_unshipped += 1
             terminal = info.get("terminal", done)
             rec = dict(obs=obs, action=action, reward=float(reward),
-                       terminal=terminal, pre_state=(c, h),
+                       terminal=terminal, pre_state=self._stored(state),
                        q_sa=float(q[action]), episode_end=done)
             if terminal:
                 # bootstrap is zero: the TD is fully determined now
@@ -450,7 +456,7 @@ class RecurrentActor(Actor):
                 # truncation: the sequence ends (state resets) but the
                 # bootstrap survives — one extra query on the final obs
                 out2 = self.query({"obs": next_obs,
-                                   "c": out["c"], "h": out["h"]})
+                                   **{k: out[k] for k in state}})
                 td = (reward + self.gamma * float(np.max(out2["q"]))
                       - rec["q_sa"])
                 self._feed(rec, td)
@@ -458,18 +464,18 @@ class RecurrentActor(Actor):
                 prev = rec
             if done:
                 obs = self.env.reset()
-                c, h = self._zero_state()
+                state = self._zero_state()
                 if self.episode_callback and "episode_return" in info:
                     self.episode_callback(self.index, info)
             else:
                 obs = next_obs
-                c, h = out["c"], out["h"]
+                state = {k: out[k] for k in state}
             self._ship()
         # shutdown: resolve the parked step with one final forward, flush
         # the builder's partial tail, and ship everything
         if prev is not None:
             try:
-                out = self.query({"obs": obs, "c": c, "h": h})
+                out = self.query({"obs": obs, **state})
                 td = (prev["reward"] + self.gamma * float(np.max(out["q"]))
                       - prev["q_sa"])
             except Exception:

@@ -44,8 +44,8 @@ from ape_x_dqn_tpu.replay.cold_store import ColdStore
 from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
 from ape_x_dqn_tpu.runtime.family import (
-    actor_class, build_learner, family_of, family_setup,
-    server_apply_fn, warmup_example)
+    SEQUENCE_FAMILIES, actor_class, build_learner, family_of, family_setup,
+    hbm_price, server_apply_fn, warmup_example)
 from ape_x_dqn_tpu.runtime.evaluation import (
     EvalWorker, make_eval_policy_factory)
 from ape_x_dqn_tpu.runtime.ingest import IngestStager
@@ -111,7 +111,8 @@ class ApexDriver:
         self._hbm = check_hbm_fits(
             cfg, self.spec.obs_shape, self.spec.obs_dtype,
             param_count=sum(int(np.prod(l.shape))
-                            for l in jax.tree.leaves(params)))
+                            for l in jax.tree.leaves(params)),
+            **hbm_price(cfg, self.net))
         if self.is_dist:
             # Multi-chip learner (SURVEY.md §7 step 7): replay shards +
             # batch shards + gradient psum over the (dp, tp) mesh; ingest
@@ -491,7 +492,7 @@ class ApexDriver:
 
     def _make_eval_worker(self, game: str | None = None) -> EvalWorker:
         factory = make_eval_policy_factory(
-            self.family, self.cfg.network.lstm_size, self.server.query)
+            self.family, self.cfg, self.server.query)
         return EvalWorker(self.cfg, self.server.query, game=game,
                           policy_factory=factory)
 
@@ -1287,7 +1288,7 @@ class ApexDriver:
                     live = (self._stager.tail_view("next_off") > 0
                             ).sum(axis=-1)
                     per_shard = self._tail_shard_counts(live)
-                elif self.family == "r2d2":
+                elif self.family in SEQUENCE_FAMILIES:
                     per_shard = np.asarray(
                         self._stager.tail_shard_units(self.dp),
                         np.int64) * self.cfg.replay.seq_length
@@ -1330,7 +1331,7 @@ class ApexDriver:
                     [(np.asarray(b["next_off"]) > 0).sum(axis=-1)
                      for b in self._stage])
                 per_shard = self._tail_shard_counts(live)
-            elif self.family == "r2d2":
+            elif self.family in SEQUENCE_FAMILIES:
                 # units are sequences; env frames also ride ingest
                 # messages separately here, so _frames_total stays.
                 # The drop stat is transition-denominated: seq_length

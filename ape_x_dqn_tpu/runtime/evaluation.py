@@ -216,27 +216,30 @@ def final_eval_game(cfg: RunConfig) -> str | None:
     return games[0] if rotate else None
 
 
-def make_eval_policy_factory(family: str, lstm_size: int,
+def make_eval_policy_factory(family: str, cfg: RunConfig,
                              query_fn: Callable) -> Callable | None:
     """Per-episode eval policy builder per model family (shared by
     ApexDriver's eval loop and the standalone suite runner).
 
-    Recurrent policies carry fresh (c, h) across one episode's queries;
-    continuous policies return the deterministic action mu(s); plain
-    Q-nets need no factory (EvalWorker queries directly).
+    Sequence families carry the state their queries carry (the LSTM's
+    fresh (c, h), the decoder's token window: family.ACTOR_STATE)
+    across one episode's queries; continuous policies return the
+    deterministic action mu(s); plain Q-nets need no factory
+    (EvalWorker queries directly).
     """
+    from ape_x_dqn_tpu.runtime.family import ACTOR_STATE
+
     if family == "dpg":
         return lambda: lambda obs: query_fn(obs)["a"]
-    if family != "r2d2":
+    if family not in ACTOR_STATE:
         return None
 
     def factory():
-        state = {"c": np.zeros(lstm_size, np.float32),
-                 "h": np.zeros(lstm_size, np.float32)}
+        state = ACTOR_STATE[family].zeros(cfg)
 
         def policy(obs):
-            out = query_fn({"obs": obs, "c": state["c"], "h": state["h"]})
-            state["c"], state["h"] = out["c"], out["h"]
+            out = query_fn({"obs": obs, **state})
+            state.update({k: out[k] for k in state})
             return out["q"]
 
         return policy
@@ -344,8 +347,7 @@ def run_suite_eval(cfg: RunConfig, games: Iterable[str] | None = None,
         return jax.tree.map(lambda x: np.asarray(x)[0],
                             fn(params, batched))
 
-    factory = make_eval_policy_factory(family, cfg.network.lstm_size,
-                                       query)
+    factory = make_eval_policy_factory(family, cfg, query)
     if games is None and cfg.env.kind not in ("atari", "synthetic_atari"):
         worker = EvalWorker(cfg, query, policy_factory=factory)
         out = worker.run(max(episodes_per_game or cfg.eval_episodes, 1),

@@ -1,21 +1,48 @@
 """Model-family dispatch shared by the drivers and remote actor hosts.
 
-A RunConfig's network kind selects one of three runtime families —
-flat-DQN ("dqn"), recurrent R2D2 ("r2d2"), continuous Ape-X DPG
-("dpg") — which differ in the inference-server protocol (plain Q-values
-vs stateful {obs,c,h} vs {a,q} actor-critic), the actor class, the
-AOT-warmup example, and what the learner trains on (its loss and how
-sampled items become the loss's batch). ApexDriver (runtime/driver.py),
-MultihostApexDriver, single_process.py and run_actor_host
-(runtime/actor_host.py) must agree on these, so the dispatch lives here
-once: `build_learner` is the one place a learner is constructed, and
-the table behind `learner_family` the one place a loss is bound. A new
-Q-learning family is a loss in ops/losses.py, a row in that table and
-its net — no edit to a learner or a driver.
+A RunConfig's network kind selects one of four runtime families —
+flat-DQN ("dqn"), recurrent R2D2 ("r2d2"), the token-level decoder
+Q-network ("decoder_q"), continuous Ape-X DPG ("dpg") — which differ in
+the inference-server protocol (plain Q-values vs a query that carries
+state vs {a,q} actor-critic), the actor class, the AOT-warmup example,
+and what the learner trains on (its loss and how sampled items become
+the loss's batch). ApexDriver (runtime/driver.py), MultihostApexDriver,
+single_process.py and run_actor_host (runtime/actor_host.py) must agree
+on these, so the dispatch lives here once: `build_learner` is the one
+place a learner is constructed, and the table behind `learner_family`
+the one place a loss is bound.
+
+Two kinds of sequence state. Both sequence families train with
+ops/losses.make_r2d2_loss over stored sequences (replay/sequence.py),
+and what differs is the state a sequence starts from:
+
+- "r2d2" STORES it: the LSTM's (c, h) from before the first step rides
+  with every sequence (`init_c`, `init_h` in the item), the actor's
+  query carries it ({obs, c, h} -> {q, c, h}) and the burn-in steps
+  only refresh it.
+- "decoder_q" stores NONE: a replayed window starts from an empty
+  attention cache and its burn-in prefix is its context — the prefix
+  pass leaves a latent cache (per layer c_kv and k_rope), the loss
+  stops its gradient, the trained steps attend to it. The item has no
+  state entry at all. The server is stateless too: a query carries the
+  last <= L token ids ({obs, ctx, n} -> {q, ctx, n}) and the server
+  re-runs the window; a per-slot latent cache inside
+  parallel/inference_server.py, which would make a step cost one token
+  instead of a window, is what is missing.
+
+How a further Q-learning family registers: its net in models/ with a
+row in `build_network`; its kind in `family_of`; a row in
+`_LEARNER_FAMILIES` binding a loss from ops/losses.py and an items ->
+batch function; a row in `ACTOR_STATE` (what a query carries beside
+the observation, and which of it is stored with a sequence) if it is a
+sequence family; its branch of `family_setup` (params, item spec,
+staging unit) and of `server_apply_fn`. No edit to a learner or a
+driver.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, NamedTuple
 
 import jax.numpy as jnp
@@ -33,21 +60,82 @@ from ape_x_dqn_tpu.utils.rng import component_key
 
 
 def family_of(cfg: RunConfig) -> str:
-    return {"lstm_q": "r2d2", "dpg": "dpg"}.get(cfg.network.kind, "dqn")
+    return {"lstm_q": "r2d2", "dpg": "dpg",
+            "glm_moe_q": "decoder_q"}.get(cfg.network.kind, "dqn")
+
+
+# families whose replay items are whole sequences (the staging unit is
+# a sequence, the loss is make_r2d2_loss)
+SEQUENCE_FAMILIES = ("r2d2", "decoder_q")
+
+
+class ActorState(NamedTuple):
+    """What a sequence family's query carries beside the observation.
+    `zeros(cfg)` -> {name: array} at an episode's start (no batch dim);
+    every name is sent with a query and read back from its reply.
+    `stored` names the entries kept with a replayed sequence, as
+    `init_<name>` (replay/sequence.py)."""
+    zeros: Callable
+    stored: tuple[str, ...]
+
+
+def _lstm_state(cfg: RunConfig) -> dict:
+    z = np.zeros(cfg.network.lstm_size, np.float32)
+    return {"c": z, "h": z.copy()}
+
+
+def _token_window(cfg: RunConfig) -> dict:
+    return {"ctx": np.zeros(cfg.replay.seq_length, np.int32),
+            "n": np.int32(0)}
+
+
+ACTOR_STATE = {
+    "r2d2": ActorState(_lstm_state, ("c", "h")),
+    "decoder_q": ActorState(_token_window, ()),
+}
+
+
+def stored_state_spec(family: str, cfg: RunConfig) -> dict:
+    """{name: shape} of the state entries a stored sequence carries."""
+    st = ACTOR_STATE[family]
+    zeros = st.zeros(cfg)
+    return {k: zeros[k].shape for k in st.stored}
+
+
+def hbm_price(cfg: RunConfig, net: Any) -> dict:
+    """What cfg's family tells utils/hbm.check_hbm_fits beside the
+    parameter count: the float32 values of state a sequence stores,
+    and, from a net whose learner state fills the chip, what a train
+    step holds beside that state (the decoder's own
+    `step_transient_bytes`; every other net's step sits inside the flat
+    headroom)."""
+    family = family_of(cfg)
+    price = {}
+    if family in ACTOR_STATE:
+        price["stored_state_floats"] = sum(
+            math.prod(s) for s in stored_state_spec(family, cfg).values())
+    if hasattr(net, "step_transient_bytes"):
+        price["step_transient"] = net.step_transient_bytes(
+            cfg.learner.batch_size,
+            cfg.replay.seq_length - cfg.replay.burn_in)
+    return price
 
 
 def actor_class(family: str, vector: bool = False) -> type:
     """Actor implementation per family. vector=True selects the
     K-envs-per-thread vectorized actors (runtime/vector_actor.py),
-    whose query contract is the server's `query_batch` (the recurrent
-    variant ships {obs, c, h} pytrees with a leading [K] axis)."""
+    whose query contract is the server's `query_batch` (the sequence
+    variant ships {obs, <state>} pytrees with a leading [K] axis).
+    Both sequence families run the same two actor classes: what their
+    queries carry is `ACTOR_STATE`'s row."""
     if vector:
         from ape_x_dqn_tpu.runtime.vector_actor import (
             ContinuousVectorActor, RecurrentVectorActor, VectorActor)
         return {"r2d2": RecurrentVectorActor,
+                "decoder_q": RecurrentVectorActor,
                 "dpg": ContinuousVectorActor}.get(family, VectorActor)
-    return {"r2d2": RecurrentActor, "dpg": ContinuousActor}.get(
-        family, Actor)
+    return {"r2d2": RecurrentActor, "decoder_q": RecurrentActor,
+            "dpg": ContinuousActor}.get(family, Actor)
 
 
 def server_apply_fn(family: str, net: Any) -> Callable:
@@ -55,9 +143,29 @@ def server_apply_fn(family: str, net: Any) -> Callable:
 
     - dqn:  obs [B, ...]          -> q [B, A]
     - r2d2: {obs, c, h}           -> {q, c, h}   (stateful step)
+    - decoder_q: {obs, ctx, n}    -> {q, ctx, n}: STATELESS — `ctx`
+      [B, L] holds the last n <= L token ids before `obs`; the server
+      appends `obs` (dropping the oldest id of a full window), re-runs
+      the whole window from an empty cache and answers with the
+      Q-values at the last position. Fixed shapes, so one compiled
+      graph; the cost is a window per step. What is missing is a
+      per-slot latent cache inside the server.
     - dpg:  obs [B, ...]          -> {a: mu(s), q: Q(s, mu(s))}
       (params are the {actor, critic} dict publish_params produces)
     """
+    if family == "decoder_q":
+        def apply_window(p, inp):
+            ctx, n = inp["ctx"], inp["n"]
+            length = ctx.shape[1]
+            full = (n >= length)[:, None]
+            ctx = jnp.where(full, jnp.roll(ctx, -1, axis=1), ctx)
+            at = jnp.minimum(n, length - 1)
+            ctx = jnp.where(jnp.arange(length)[None, :] == at[:, None],
+                            inp["obs"][:, None].astype(ctx.dtype), ctx)
+            q, _ = net.apply(p, ctx, ())
+            q = jnp.take_along_axis(q, at[:, None, None], axis=1)[:, 0]
+            return {"q": q, "ctx": ctx, "n": at + 1}
+        return apply_window
     if family == "r2d2":
         def apply_rec(p, inp):
             q, (c, h) = net.apply(p, inp["obs"], (inp["c"], inp["h"]),
@@ -130,6 +238,75 @@ def r2d2_family(net_apply_seq: Callable, lcfg, rcfg, compute_dtype=None):
         metric_keys=("valid_frac",))
 
 
+def decoder_q_family(net: Any, lcfg, rcfg):
+    """Token-level Q-learning on a decoder: make_r2d2_loss as it
+    stands, over stored token sequences with no stored state — the
+    loss's burn-in is the prefix pass that leaves the net's latent
+    cache (models/glm_moe_q.py). The net also says how its expert
+    layers were loaded; the loss's signature has no room for that, so
+    each of its four net applications leaves its counts in a list the
+    family's loss reads back inside the same trace:
+    `moe_rows` rows routed to the experts held here, summed over the
+    layers and the four applications (one forward each); `moe_rows_grad`
+    those of the online net's trained steps, which also pay a
+    recomputation and a backward; `moe_load_max_over_mean` the fullest
+    held expert over the mean, online net, mean over layers. The same
+    way the aux hands back what the loss saw and chose — `q` the online
+    net's Q-values on the trained steps, `topk_online`/`topk_target`
+    [expert layers, B, L, k] the experts selected — for whoever
+    differentiates this function to hold it to a reference (the
+    benchmark's check); a train step reads none of the three and XLA
+    drops them there."""
+    from ape_x_dqn_tpu.ops.losses import SequenceBatch, make_r2d2_loss
+    from ape_x_dqn_tpu.runtime.learner import LearnerFamily
+
+    def loss_fn(params, target_params, batch, is_weights):
+        tally, seen = [], []
+
+        def apply(p, tokens, state):
+            q, state, stats = net.apply_with_stats(p, tokens, state)
+            tally.append(stats["expert_rows"].astype(jnp.float32))
+            seen.append((q, stats["topk"]))
+            return q, state
+
+        loss, aux = make_r2d2_loss(
+            apply, burn_in=rcfg.burn_in, n_step=lcfg.n_step,
+            gamma=lcfg.gamma, huber_delta=lcfg.huber_delta,
+            double=lcfg.double_dqn, rescale=lcfg.value_rescale,
+            priority_eta=rcfg.priority_eta)(
+            params, target_params, batch, is_weights)
+        # the loss applies: online burn-in, target burn-in (when
+        # burn_in > 0), then online and target over the trained steps
+        online = tally[0::2]
+        load = sum(online)                          # [layers, held]
+        mean = jnp.maximum(load.mean(axis=-1), 1e-9)
+        aux = {**aux,
+               "moe_rows": sum(t.sum() for t in tally),
+               "moe_rows_grad": online[-1].sum(),
+               "moe_load_max_over_mean": (
+                   (load.max(axis=-1) / mean).mean() if load.size
+                   else jnp.float32(1.0)),
+               "q": seen[-2][0],
+               # prefix then trained steps, along the sequence
+               "topk_online": jnp.concatenate(
+                   [t for _, t in seen[0::2]], axis=2),
+               "topk_target": jnp.concatenate(
+                   [t for _, t in seen[1::2]], axis=2)}
+        return loss, aux
+
+    return LearnerFamily(
+        name="decoder_q",
+        loss_fn=loss_fn,
+        make_batch=lambda items: SequenceBatch(
+            obs=items["obs"], actions=items["actions"],
+            rewards=items["rewards"], terminals=items["terminals"],
+            mask=items["mask"], init_state=()),
+        net_apply=net.apply,
+        apply_attr="net_apply_seq",
+        metric_keys=("valid_frac", "moe_rows", "moe_rows_grad",
+                     "moe_load_max_over_mean"))
+
+
 # family name -> (cfg, net) -> LearnerFamily. DPG is not a row: its
 # learner (two nets, two optimizers, soft targets) is its own class.
 _LEARNER_FAMILIES = {
@@ -137,6 +314,8 @@ _LEARNER_FAMILIES = {
     "r2d2": lambda cfg, net: r2d2_family(
         net.apply, cfg.learner, cfg.replay,
         compute_dtype=dtype_of(cfg.network.compute_dtype)),
+    "decoder_q": lambda cfg, net: decoder_q_family(
+        net, cfg.learner, cfg.replay),
 }
 
 
@@ -172,9 +351,8 @@ def warmup_example(family: str, cfg: RunConfig, spec: Any) -> Any:
     """One server request pytree (no batch dim) for AOT warmup —
     shapes/dtypes only, content irrelevant."""
     obs = np.zeros(spec.obs_shape, spec.obs_dtype)
-    if family == "r2d2":
-        z = np.zeros(cfg.network.lstm_size, np.float32)
-        return {"obs": obs, "c": z, "h": z}
+    if family in ACTOR_STATE:
+        return {"obs": obs, **ACTOR_STATE[family].zeros(cfg)}
     return obs
 
 
@@ -213,6 +391,32 @@ def family_setup(cfg: RunConfig, spec: Any, net: Any,
     from ape_x_dqn_tpu.runtime.learner import transition_item_spec
 
     family = family_of(cfg)
+    if family == "decoder_q":
+        if spec.num_actions != net.num_actions or spec.obs_shape != ():
+            raise ValueError(
+                f"the decoder holds {net.num_actions} vocabulary rows "
+                f"(network.glm.vocab_size / shard_count) but the "
+                f"environment has {spec.num_actions} actions over "
+                f"observations {spec.obs_shape}: set env.num_tokens="
+                f"{net.num_actions} on a synthetic_tokens environment")
+        if cfg.replay.storage == "frame_ring":
+            raise ValueError(
+                "frame_ring storage is for pixel observations; a token "
+                "sequence is stored flat (replay.storage='flat')")
+        from ape_x_dqn_tpu.utils.hbm import check_hbm_fits
+
+        # before a single weight is made: the published model whole is
+        # 120 GB of float32 and should fail with a budget table
+        check_hbm_fits(cfg, spec.obs_shape, spec.obs_dtype,
+                       param_count=net.param_count(),
+                       **hbm_price(cfg, net))
+        params = net.init(component_key(cfg.seed, "net_init"))
+        item_spec = sequence_item_spec(
+            spec.obs_shape, spec.obs_dtype, cfg.replay.seq_length,
+            stored_state_spec(family, cfg))
+        return FamilySetup(
+            params, item_spec, False,
+            max(cfg.actors.ingest_batch // cfg.replay.seq_length, 1), 1)
     if family == "r2d2":
         z = jnp.zeros((1, cfg.network.lstm_size), jnp.float32)
         params = net.init(component_key(cfg.seed, "net_init"),
@@ -226,7 +430,7 @@ def family_setup(cfg: RunConfig, spec: Any, net: Any,
                 f"replay.storage='flat' for vector observations")
         item_spec = sequence_item_spec(
             spec.obs_shape, spec.obs_dtype, cfg.replay.seq_length,
-            cfg.network.lstm_size, frame_mode=seq_frame_mode)
+            stored_state_spec(family, cfg), frame_mode=seq_frame_mode)
         return FamilySetup(
             params, item_spec, False,
             max(cfg.actors.ingest_batch // cfg.replay.seq_length, 1), 1)

@@ -68,7 +68,7 @@ from ape_x_dqn_tpu.runtime.driver import build_prioritized_replay
 from ape_x_dqn_tpu.runtime.evaluation import (
     EvalWorker, make_eval_policy_factory)
 from ape_x_dqn_tpu.runtime.family import (
-    actor_class, build_learner, family_of, family_setup,
+    actor_class, build_learner, family_of, family_setup, hbm_price,
     server_apply_fn, warmup_example)
 from ape_x_dqn_tpu.utils.checkpoint import CheckpointManager
 from ape_x_dqn_tpu.utils.hbm import check_hbm_fits
@@ -159,7 +159,8 @@ class MultihostApexDriver:
         check_hbm_fits(
             cfg, self.spec.obs_shape, self.spec.obs_dtype,
             param_count=sum(int(np.prod(l.shape))
-                            for l in jax.tree.leaves(params)))
+                            for l in jax.tree.leaves(params)),
+            **hbm_price(cfg, self.net))
 
         # identical construction on every process (same cfg.seed) ->
         # identical initial params; learner.init then shards them over
@@ -413,7 +414,7 @@ class MultihostApexDriver:
 
     def _make_eval_worker(self, game: str | None = None) -> EvalWorker:
         factory = make_eval_policy_factory(
-            self.family, self.cfg.network.lstm_size, self.server.query)
+            self.family, self.cfg, self.server.query)
         return EvalWorker(self.cfg, self.server.query, game=game,
                           policy_factory=factory)
 

@@ -235,28 +235,31 @@ class VectorActor(DiscretePolicyHooks):
 
 
 class _RecurrentEnvCore:
-    """Per-env recurrent actor state: eps slot, sequence builder,
-    carried LSTM state, and the one-step-parked record awaiting its
-    1-step TD bootstrap (mirrors runtime.actor.RecurrentActor)."""
+    """Per-env sequence actor state: eps slot, sequence builder, the
+    state its queries carry (the family's: the LSTM's {c, h}, or the
+    decoder's token window), and the one-step-parked record awaiting
+    its 1-step TD bootstrap (mirrors runtime.actor.RecurrentActor)."""
 
-    __slots__ = ("eps", "builder", "c", "h", "prev")
+    __slots__ = ("eps", "builder", "state", "prev", "_zeros")
 
-    def __init__(self, eps: float, builder, lstm_size: int):
+    def __init__(self, eps: float, builder, zeros):
         self.eps = eps
         self.builder = builder
-        self.c = np.zeros(lstm_size, np.float32)
-        self.h = np.zeros(lstm_size, np.float32)
+        self._zeros = zeros
+        self.state: dict = zeros()
         self.prev: dict | None = None
 
     def zero_state(self) -> None:
-        self.c = np.zeros_like(self.c)
-        self.h = np.zeros_like(self.h)
+        self.state = self._zeros()
 
 
 class RecurrentVectorActor:
-    """R2D2 vector actor: K envs per thread, one batched stateful
-    query per vector step ({obs, c, h} each with a leading [K] axis),
-    per-env SequenceBuilders shipping stored-state sequences.
+    """Sequence-family vector actor (R2D2, decoder_q): K envs per
+    thread, one batched query per vector step that carries each env's
+    state beside its observation ({obs, c, h} or {obs, ctx, n}, each
+    with a leading [K] axis; runtime/family.py `ACTOR_STATE`), per-env
+    SequenceBuilders shipping sequences with what of that state the
+    family stores.
 
     Semantics mirror runtime.actor.RecurrentActor exactly per env
     core — the 1-step pending record, terminal/truncation TD seeds,
@@ -277,8 +280,10 @@ class RecurrentVectorActor:
         self._hb = f"actor-{actor_index}"
         seed = cfg.seed if seed is None else seed
         self.K = max(cfg.actors.envs_per_actor, 1)
+        from ape_x_dqn_tpu.runtime.family import ACTOR_STATE, family_of
+
         self.gamma = cfg.learner.gamma
-        self.lstm_size = cfg.network.lstm_size
+        self._state_spec = ACTOR_STATE[family_of(cfg)]
         total_slots = cfg.actors.num_actors * self.K
         frame_mode = cfg.replay.storage == "frame_ring"
         envs, self.cores = [], []
@@ -296,10 +301,10 @@ class RecurrentVectorActor:
                 SequenceBuilder(
                     seq_len=cfg.replay.seq_length,
                     overlap=cfg.replay.seq_overlap,
-                    lstm_size=self.lstm_size,
                     priority_eta=cfg.replay.priority_eta,
-                    frame_mode=frame_mode),
-                self.lstm_size))
+                    frame_mode=frame_mode,
+                    state_keys=self._state_spec.stored),
+                lambda: self._state_spec.zeros(cfg)))
         self.venv = SyncVectorEnv(envs)
         self.spec = self.venv.spec
         self.rng = np.random.default_rng(seed * 7919 + actor_index)
@@ -311,6 +316,11 @@ class RecurrentVectorActor:
 
     def _feed(self, core: _RecurrentEnvCore, rec: dict, td: float) -> None:
         feed_sequence(self._outbox, core.builder, rec, td)
+
+    def _states(self, cores) -> dict:
+        """The cores' carried states, stacked on a leading axis."""
+        return {k: np.stack([c.state[k] for c in cores])
+                for k in cores[0].state}
 
     def _resolve_prev(self, core: _RecurrentEnvCore, q_next) -> None:
         """The parked record's 1-step TD bootstrap arrives with the
@@ -339,12 +349,10 @@ class RecurrentVectorActor:
                 stop_event is not None and stop_event.is_set()):
             self.obs.beat(self._hb)
             with self.obs.span("actor.inference", k=self.K):
-                out = self.query({
-                    "obs": obs,
-                    "c": np.stack([c.c for c in self.cores]),
-                    "h": np.stack([c.h for c in self.cores])}, self.K)
-            q, cs, hs = (np.asarray(out["q"]), np.asarray(out["c"]),
-                         np.asarray(out["h"]))
+                out = self.query({"obs": obs, **self._states(self.cores)},
+                                 self.K)
+            q = np.asarray(out["q"])
+            after = {k: np.asarray(out[k]) for k in self.cores[0].state}
             actions = []
             for j, core in enumerate(self.cores):
                 self._resolve_prev(core, q[j])
@@ -365,7 +373,8 @@ class RecurrentVectorActor:
                 recs.append(dict(
                     obs=obs[j], action=actions[j],
                     reward=float(rewards[j]), terminal=terminal,
-                    pre_state=(core.c, core.h),
+                    pre_state=tuple(core.state[k]
+                                    for k in self._state_spec.stored),
                     q_sa=float(q[j][actions[j]]), episode_end=done))
                 if done and not terminal:
                     trunc_j.append(j)
@@ -377,13 +386,12 @@ class RecurrentVectorActor:
                 tout = self.query({
                     "obs": np.stack([infos[j]["terminal_obs"]
                                      for j in trunc_j]),
-                    "c": np.stack([cs[j] for j in trunc_j]),
-                    "h": np.stack([hs[j] for j in trunc_j])},
+                    **{k: v[trunc_j] for k, v in after.items()}},
                     len(trunc_j))
                 tq = np.asarray(tout["q"])
                 for i, j in enumerate(trunc_j):
                     v_term[j] = float(np.max(tq[i]))
-            # second pass: route records, advance/reset LSTM state
+            # second pass: route records, advance/reset the carried state
             for j, core in enumerate(self.cores):
                 rec = recs[j]
                 if rec["terminal"]:
@@ -401,17 +409,15 @@ class RecurrentVectorActor:
                             and "episode_return" in infos[j]):
                         self.episode_callback(self.index, infos[j])
                 else:
-                    core.c, core.h = cs[j], hs[j]
+                    core.state = {k: v[j] for k, v in after.items()}
             obs = next_obs
             self._ship()
         # shutdown: resolve parked records with one final batched
         # forward, flush partial sequence tails, ship everything
         if any(core.prev is not None for core in self.cores):
             try:
-                out = self.query({
-                    "obs": obs,
-                    "c": np.stack([c.c for c in self.cores]),
-                    "h": np.stack([c.h for c in self.cores])}, self.K)
+                out = self.query({"obs": obs, **self._states(self.cores)},
+                                 self.K)
                 q = np.asarray(out["q"])
                 for j, core in enumerate(self.cores):
                     if core.prev is not None:

@@ -99,32 +99,39 @@ def _flat_bytes(capacity: int, obs_shape: tuple[int, ...],
 
 
 def _sequence_bytes(capacity: int, seq_len: int, obs_shape: tuple[int, ...],
-                    obs_dtype, lstm_size: int,
+                    obs_dtype, state_floats: int,
                     frame_mode: bool) -> tuple[int, dict]:
+    """state_floats: float32 values of state stored with a sequence
+    (the LSTM's 2 x lstm_size; 0 for a family that stores none)."""
     if frame_mode:
         h, w, stack = obs_shape
         obs = _leaf_stored_bytes((seq_len + stack - 1, h, w), obs_dtype)
     else:
         obs = _leaf_stored_bytes((seq_len, *obs_shape), obs_dtype)
-    per_item = obs + seq_len * 4 * 4 + 2 * lstm_size * 4
+    per_item = obs + seq_len * 4 * 4 + state_floats * 4
     return capacity * per_item, {"layout": "sequence",
                                  "seq_item_bytes": per_item,
                                  "frame_mode": frame_mode}
 
 
 def replay_budget(cfg: Any, obs_shape: tuple[int, ...],
-                  obs_dtype=np.uint8) -> tuple[int, int, int, dict]:
+                  obs_dtype=np.uint8, stored_state_floats: int | None = None
+                  ) -> tuple[int, int, int, dict]:
     """-> (storage_bytes, tree_bytes, per_device_capacity, detail) for
     cfg (a RunConfig), per device after dp sharding, capacity rounded to
-    the pow2 the drivers actually allocate."""
+    the pow2 the drivers actually allocate. `stored_state_floats`: the
+    float32 values of state a sequence family stores with a sequence
+    (runtime/family.py says; by default the LSTM's (c, h))."""
     r = cfg.replay
     dp = max(getattr(cfg.parallel, "dp", 1), 1)
     cap = next_pow2(max(r.capacity // dp, 2)) if dp > 1 \
         else next_pow2(r.capacity)
     if r.kind == "sequence":
+        if stored_state_floats is None:
+            stored_state_floats = 2 * getattr(cfg.network, "lstm_size", 512)
         storage, detail = _sequence_bytes(
             cap, r.seq_length, obs_shape, obs_dtype,
-            lstm_size=getattr(cfg.network, "lstm_size", 512),
+            state_floats=stored_state_floats,
             # the SHARED predicate (replay/sequence.py) — pricing must
             # follow the layout runtime/family.py actually selects
             frame_mode=sequence_frame_mode(r.storage, obs_shape))
@@ -145,15 +152,23 @@ def model_state_bytes(param_count: int, adam: bool = True) -> int:
 
 
 def run_budget(cfg: Any, obs_shape: tuple[int, ...], obs_dtype=np.uint8,
-               param_count: int = 5_000_000) -> HbmBudget:
+               param_count: int = 5_000_000,
+               step_transient: int | None = None,
+               stored_state_floats: int | None = None) -> HbmBudget:
     """Budget a RunConfig per device. `param_count` defaults to a
     generous flagship-CNN-class estimate when the caller has not built
-    the network yet (Nature-CNN ~1.7M, LSTM-Q ~6.5M params)."""
-    storage, tree, cap, detail = replay_budget(cfg, obs_shape, obs_dtype)
+    the network yet (Nature-CNN ~1.7M, LSTM-Q ~6.5M params).
+    `step_transient`: what a train step holds beside the persistent
+    state, from a family whose step is not noise beside its replay
+    (runtime/family.hbm_price); by default the flat
+    TRANSIENT_HEADROOM."""
+    storage, tree, cap, detail = replay_budget(
+        cfg, obs_shape, obs_dtype, stored_state_floats)
     return HbmBudget(replay_storage=storage, replay_tree=tree,
                      model_state=model_state_bytes(param_count),
-                     headroom=TRANSIENT_HEADROOM, capacity=cap,
-                     detail=detail)
+                     headroom=(TRANSIENT_HEADROOM if step_transient is None
+                               else step_transient),
+                     capacity=cap, detail=detail)
 
 
 # usable HBM by device_kind substring, for a TPU backend whose
@@ -199,14 +214,18 @@ def device_hbm_bytes(device=None) -> tuple[int | None, str]:
 
 def check_hbm_fits(cfg: Any, obs_shape: tuple[int, ...], obs_dtype=np.uint8,
                    param_count: int = 5_000_000, device=None,
-                   hbm_bytes: int | None = None) -> HbmBudget:
+                   hbm_bytes: int | None = None,
+                   **family_price) -> HbmBudget:
     """Raise ValueError (loudly, with the budget table and the fix)
     when the config's per-device footprint exceeds the device's HBM.
     Returns the budget, stamped with the limit it was checked against
     and that limit's source (`limit` is None only off the TPU — the
     virtual dryrun is a compile check, not a memory model).
+    `family_price`: run_budget's `step_transient` and
+    `stored_state_floats`, as the run's family prices them.
     """
-    budget = run_budget(cfg, obs_shape, obs_dtype, param_count)
+    budget = run_budget(cfg, obs_shape, obs_dtype, param_count,
+                        **family_price)
     if hbm_bytes is not None:
         limit, source = hbm_bytes, "caller"
     else:

@@ -13,9 +13,11 @@ def test_eight_virtual_devices():
 
 
 def test_five_presets_exist():
-    # The five attested reference configs (SURVEY.md §2.1).
+    # The five attested reference configs (SURVEY.md §2.1), and the
+    # decoder Q-network with its CPU-test sibling (PR 30).
     assert set(PRESETS) == {
-        "cartpole_smoke", "pong", "atari57_apex", "r2d2", "apex_dpg"}
+        "cartpole_smoke", "pong", "atari57_apex", "r2d2", "apex_dpg",
+        "glm47_flash_q", "glm_tiny_q"}
 
 
 def test_preset_fields():

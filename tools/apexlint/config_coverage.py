@@ -12,10 +12,11 @@ Both directions of config/doc drift:
    deliberately-dormant field with `# apexlint: unread(<why>)` on its
    declaration line.
 
-2. Every `replay.` / `comm.` / `obs.` / `actors.` / `serving.` knob
+2. Every `replay.` / `comm.` / `obs.` / `actors.` / `serving.` /
+   `glm.` (as in `network.glm.shard_count`) knob
    mentioned in README must exist as a field on the matching dataclass
    (ReplayConfig / CommConfig / ObsConfig / ActorConfig /
-   ServingConfig). Mentions
+   ServingConfig / GlmMoeConfig). Mentions
    that name a package MODULE instead of a knob (`obs.health`,
    `obs.report` — `ape_x_dqn_tpu/obs/health.py` exists) are skipped.
 
@@ -40,9 +41,10 @@ CHECKER = "config-coverage"
 PREFIX_TO_CLASS = {"replay": "ReplayConfig", "comm": "CommConfig",
                    "obs": "ObsConfig", "actors": "ActorConfig",
                    "serving": "ServingConfig",
-                   "remediation": "RemediationConfig"}
+                   "remediation": "RemediationConfig",
+                   "glm": "GlmMoeConfig"}
 KNOB_RE = re.compile(
-    r"\b(replay|comm|obs|actors|serving|remediation)"
+    r"\b(replay|comm|obs|actors|serving|remediation|glm)"
     r"\.([a-z_][a-z0-9_]*)")
 
 
