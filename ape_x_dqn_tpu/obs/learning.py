@@ -6,8 +6,8 @@ nothing watched whether the RL itself was healthy. This module closes
 that in three pieces:
 
 - jit-safe diagnostic helpers (`sgd_diag`, `replay_health`,
-  `replay_health_sharded`) the four learner cycles call INSIDE their
-  existing jits. Everything is a cheap scalar reduction over arrays the
+  `replay_health_sharded`) the learner cycles call INSIDE their
+  existing jits. Everything is a scalar reduction over arrays the
   loss/optimizer already materialized (TD quantiles, overestimation
   gap, grad/update norms, IS-weight effective sample size,
   priority-mass concentration, in-graph sampled-transition age, and the
@@ -33,8 +33,10 @@ that in three pieces:
 
 Disabled obs routes through NullObs and never reaches this module's
 host side; the in-graph helpers import jax lazily and add the same
-handful of fused scalar reductions whether or not anyone reads them
-(measured in bench.py --smoke: below noise).
+handful of fused scalar reductions whether or not obs is on (measured
+in bench.py --smoke at 1.7 M parameters, 27 MB of learner state: below
+noise). The two that read parameter-sized trees are not free at every
+size, so `sgd_diag` runs them only on a step whose metrics are read.
 """
 
 from __future__ import annotations
@@ -59,12 +61,33 @@ TOP_FRAC_MAX = 0.5         # one transition holding half the priority mass
 
 # -- in-graph diagnostics (pure, jit-safe; called inside learner jits) ----
 
-def sgd_diag(aux: dict, is_w, grads, updates, params) -> dict:
+def sgd_diag(aux: dict, is_w, grads, updates, params, *,
+             grad_norm=None, want_tree_diag=True, update_of=None) -> dict:
     """Per-SGD-step learning diagnostics as a flat dict of f32 device
     scalars. `aux` is the loss aux (ops/losses.py), `is_w` the IS
     weights actually applied, `grads`/`updates`/`params` the optimizer
-    triple. Everything here is a reduction over arrays the step already
-    computed — no new matmuls, no new memory traffic beyond scalars."""
+    triple.
+
+    On EVERY step: what is batch-sized (TD quantiles, ESS, the `q_*`
+    scalars the loss already reduced) and `grad_norm` — the caller's
+    own `grad_norm` when it has one (clipping needs it every step
+    anyway), else one reduction over `grads`.
+
+    Only when `want_tree_diag`: `update_ratio`, two reductions over
+    parameter-sized trees (‖update‖, ‖params‖; 0.0 when not asked). At
+    27 MB of state those were noise; at 591 M float32 parameters one
+    read of a tree is 2.9 ms of a 275 ms step (PERF.md §6, PR 31), so a
+    step whose metrics never leave the program passes False (a Python
+    bool: not traced at all) or a traced flag (a `lax.cond`).
+
+    `update_of`: the `lax.cond`'s operands are `updates` and `params`,
+    so both must be trees that live in HBM whether or not the branch
+    runs. The update tree does not: as an operand it would be written
+    out on every step only to be one (+one parameter-sized temp, ISSUE
+    31's table). The caller then passes as `updates` the trees the
+    update can be rebuilt FROM — the optimizer's new state — and
+    `update_of(updates)` rebuilds it inside the branch."""
+    import jax
     import jax.numpy as jnp
     import optax
 
@@ -75,8 +98,21 @@ def sgd_diag(aux: dict, is_w, grads, updates, params) -> dict:
     # uniform weights, ->1/B when one sample dominates (beta pathology)
     ess = jnp.square(w.sum()) / (
         w.size * jnp.maximum((w * w).sum(), 1e-12))
-    pn = optax.global_norm(params)
+    if grad_norm is None:
+        grad_norm = optax.global_norm(grads)
+
+    def ratio(updates, params):
+        if update_of is not None:
+            updates = update_of(updates)
+        return (optax.global_norm(updates) / jnp.maximum(
+            optax.global_norm(params), 1e-12)).astype(jnp.float32)
+
     zero = jnp.float32(0.0)
+    if isinstance(want_tree_diag, bool):
+        update_ratio = ratio(updates, params) if want_tree_diag else zero
+    else:
+        update_ratio = jax.lax.cond(
+            want_tree_diag, ratio, lambda *_: zero, updates, params)
     return {
         "td_abs_p50": qs[0],
         "td_abs_p90": qs[1],
@@ -89,9 +125,8 @@ def sgd_diag(aux: dict, is_w, grads, updates, params) -> dict:
         # the double-DQN target-net bootstrap, the quantity Double-DQN
         # exists to shrink — computed in the loss, surfaced here
         "q_gap": aux.get("q_gap", zero),
-        "grad_norm": optax.global_norm(grads),
-        "update_ratio": optax.global_norm(updates)
-        / jnp.maximum(pn, 1e-12),
+        "grad_norm": grad_norm,
+        "update_ratio": update_ratio,
         "is_ess_frac": ess,
     }
 

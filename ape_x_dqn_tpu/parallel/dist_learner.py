@@ -44,7 +44,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ape_x_dqn_tpu.obs import learning as learn_obs
@@ -68,10 +67,9 @@ class DistLearner(SingleChipLearner):
     is SingleChipLearner's; defined here is what sharding changes."""
 
     def __init__(self, family: LearnerFamily, replay: PrioritizedReplay,
-                 lcfg, mesh: Mesh,
-                 optimizer: optax.GradientTransformation | None = None):
+                 lcfg, mesh: Mesh):
         """`replay` is configured with the PER-SHARD capacity."""
-        super().__init__(family, replay, lcfg, optimizer)
+        super().__init__(family, replay, lcfg)
         self.mesh = mesh
         self.dp = mesh.shape["dp"]
         assert lcfg.batch_size % self.dp == 0, \
@@ -177,7 +175,7 @@ class DistLearner(SingleChipLearner):
         return jax.lax.with_sharding_constraint(y, self._dp_sharding)
 
     def _sgd_step(self, params, target_params, opt_state, step,
-                  items, w):
+                  items, w, want_tree_diag=True):
         """One SGD step on an already-sampled [dp, b_local] batch.
         `w` is the raw IS weight ([dp, b_local]); max-normalization
         happens here so each training batch is normalized over exactly
@@ -188,7 +186,7 @@ class DistLearner(SingleChipLearner):
         batch = self.family.make_batch(jax.tree.map(self._flat, items))
         params, target_params, opt_state, step, td_abs, metrics = \
             self._sgd_update(params, target_params, opt_state, step,
-                             batch, self._flat(w))
+                             batch, self._flat(w), want_tree_diag)
         td_shard = td_abs.reshape(self.dp, self.b_local)
         # the flat reductions inside _sgd_update's diag run over the
         # [dp]-sharded batch, so GSPMD lowers them to the psum'd GLOBAL

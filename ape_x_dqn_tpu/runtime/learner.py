@@ -72,11 +72,46 @@ def transition_item_spec(obs_shape, obs_dtype) -> dict:
     }
 
 
+ADAM_B1, ADAM_B2 = 0.9, 0.999
+
+
 def make_optimizer(lcfg) -> optax.GradientTransformation:
     return optax.chain(
         optax.clip_by_global_norm(lcfg.max_grad_norm),
-        optax.adam(lcfg.lr, eps=lcfg.adam_eps),
+        optax.adam(lcfg.lr, b1=ADAM_B1, b2=ADAM_B2, eps=lcfg.adam_eps),
     )
+
+
+def applied_update(lcfg, opt_state):
+    """The update `make_optimizer(lcfg)` applied on the step that left
+    `opt_state`, rebuilt from Adam's NEW moments by optax's own formula
+    (`scale_by_adam`, then `scale(-lr)`). The moments live in HBM
+    anyway, so a diagnostic under a `lax.cond` can take them as
+    operands where the update tree itself would have to be written out
+    on every step to be one (obs/learning.py::sgd_diag)."""
+    adam = opt_state[1][0]
+    mu_hat = optax.tree.bias_correction(adam.mu, ADAM_B1, adam.count)
+    nu_hat = optax.tree.bias_correction(adam.nu, ADAM_B2, adam.count)
+    return jax.tree.map(
+        lambda m, v: -lcfg.lr * (m / (jnp.sqrt(v) + lcfg.adam_eps)),
+        mu_hat, nu_hat)
+
+
+# Parameter bytes from which the SGD tail branches (a `lax.cond` for the
+# target sync and for the tree-sized health norms) instead of passing
+# over the trees on every step. A branch is not free: it ends Adam's
+# fusion with what followed it and gives the loop's carry other
+# layouts, so a small net loses more than the passes cost. Measured on
+# a v5e (PERF.md §6, PR 31): 6.8 MB of parameters -2.8% (pong_offline),
+# 15 MB +1.0% (r2d2_offline), 2.4 GB +3.9% (glm47_flash_offline).
+TAIL_BRANCH_MIN_BYTES = 8 << 20
+
+
+def _last_of(length: int, returned: bool = True):
+    """The xs of a train_many scan: True on the iteration whose metrics
+    train_many returns (the last one of the last scan), False on the
+    others."""
+    return (jnp.arange(length) == length - 1) & returned
 
 
 class SingleChipLearner:
@@ -101,13 +136,13 @@ class SingleChipLearner:
     one-deep by the double-buffered path (sample_prefetch).
     """
 
-    def __init__(self, family: LearnerFamily, replay, lcfg,
-                 optimizer: optax.GradientTransformation | None = None):
+    def __init__(self, family: LearnerFamily, replay, lcfg):
         self.family = family
         setattr(self, family.apply_attr, family.net_apply)
         self.replay = replay
         self.lcfg = lcfg
-        self.optimizer = optimizer or make_optimizer(lcfg)
+        # always make_optimizer's: applied_update rebuilds ITS update
+        self.optimizer = make_optimizer(lcfg)
         # draws per shard and training batch; one shard here
         self.b_local = lcfg.batch_size
 
@@ -140,12 +175,21 @@ class SingleChipLearner:
         return self.replay.sample_state(replay_state, sk, n, chunks)
 
     def _sgd_update(self, params, target_params, opt_state, step,
-                    batch, w):
+                    batch, w, want_tree_diag=True):
         """One loss/grad/optimizer/target-sync update on a batch and
         IS weights already in the form the family's loss takes: the
         SGD body of every learner but DPG's, called by each stack's
         `_sgd_step` after it has prepared the two. Returns the
-        family's |TD| priorities (aux['td_abs'])."""
+        family's |TD| priorities (aux['td_abs']).
+
+        From TAIL_BRANCH_MIN_BYTES of parameters on, the tail passes
+        over a parameter-sized tree only when the pass has a consumer:
+        the target sync is a branch, and the health norms over trees
+        run when `want_tree_diag` — True where this step's metrics
+        leave the program, False (or a traced flag, in train_many's
+        scans) where they are dropped. Under it the sync is a select
+        and a traced flag counts as True: the passes are cheaper than
+        the branches."""
         (loss, aux), grads = jax.value_and_grad(
             self.family.loss_fn, has_aux=True)(
             params, target_params, batch, w)
@@ -153,30 +197,47 @@ class SingleChipLearner:
             grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         step = step + 1
-        # hard target sync every K steps, branchless (SURVEY.md §3.3)
+        # hard target sync every K steps (SURVEY.md §3.3). On a big tree
+        # a branch, not a select: `where` read two trees and wrote one on
+        # every step (7.1 GB, 9 ms of glm47_flash_offline's 275) to sync
+        # 1 in 2,500
         sync = (step % self.lcfg.target_sync_every == 0)
-        target_params = jax.tree.map(
-            lambda t, p: jnp.where(sync, p, t), target_params, params)
+        if sum(x.nbytes for x in jax.tree.leaves(params)) \
+                >= TAIL_BRANCH_MIN_BYTES:
+            target_params = jax.lax.cond(
+                sync, lambda t, p: p, lambda t, p: t,
+                target_params, params)
+        else:
+            target_params = jax.tree.map(
+                lambda t, p: jnp.where(sync, p, t), target_params, params)
+            want_tree_diag = want_tree_diag is not False
+        # the one gradient norm: the clip's is this expression too
+        grad_norm = optax.global_norm(grads)
         metrics = {
             "loss": loss,
             "q_mean": aux["q_mean"],
             "td_abs_mean": aux["td_abs"].mean(),
             **{key: aux[key] for key in self.family.metric_keys},
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": grad_norm,
             # learning-health scalars (obs/learning.py); rides the
             # metrics pytree through every scan, read at existing
-            # host sync points only
-            "diag": learn_obs.sgd_diag(aux, w, grads, updates, params),
+            # host sync points only. The update is rebuilt from the new
+            # opt_state: `updates` itself never outlives the apply
+            "diag": learn_obs.sgd_diag(
+                aux, w, grads, opt_state, params, grad_norm=grad_norm,
+                want_tree_diag=want_tree_diag,
+                update_of=partial(applied_update, self.lcfg)),
         }
         return params, target_params, opt_state, step, aux["td_abs"], \
             metrics
 
     def _sgd_step(self, params, target_params, opt_state, step,
-                  items, is_w):
+                  items, is_w, want_tree_diag=True):
         """One SGD step on already-sampled items (shared by the exact
         per-step path and the K-batch relaxation)."""
         return self._sgd_update(params, target_params, opt_state, step,
-                                self.family.make_batch(items), is_w)
+                                self.family.make_batch(items), is_w,
+                                want_tree_diag)
 
     def _replay_health(self, replay_state: ReplayState, idx, pri_then):
         return learn_obs.replay_health(self.replay, replay_state, idx,
@@ -190,13 +251,15 @@ class SingleChipLearner:
         return self.replay.update_state(replay_state, idx.reshape(-1),
                                         jnp.concatenate(td_parts))
 
-    def _train_step(self, state: TrainState) -> tuple[TrainState, dict]:
+    def _train_step(self, state: TrainState,
+                    want_tree_diag=True) -> tuple[TrainState, dict]:
         rng, sk = self._split_rng(state.rng)
         items, idx, w = self._sample_weighted(state.replay, sk,
                                               self.b_local)
         params, target_params, opt_state, step, td_abs, metrics = \
             self._sgd_step(state.params, state.target_params,
-                           state.opt_state, state.step, items, w)
+                           state.opt_state, state.step, items, w,
+                           want_tree_diag)
         # fused path: draw and write-back see the same tree, so the
         # priority-staleness delta is identically 0 (pri_then=None)
         metrics["diag"] = {**metrics.get("diag", {}),
@@ -248,8 +311,8 @@ class SingleChipLearner:
             is_w_k.max(axis=1, keepdims=True), 1e-12)
         return jax.tree.map(split, items), split(idx), is_w_k, split(pri)
 
-    def _learn_stage(self, state: TrainState, sample,
-                     k: int) -> tuple[TrainState, dict]:
+    def _learn_stage(self, state: TrainState, sample, k: int,
+                     want_tree_diag=True) -> tuple[TrainState, dict]:
         """Pure LEARN stage: K SGD steps over an already-drawn sample
         + ONE priority write-back + target sync. `state.rng` must
         already be advanced past the draw that produced `sample`.
@@ -267,7 +330,8 @@ class SingleChipLearner:
         slower than the identical straight-line code (855 vs 51
         ms/step — scan's carried buffers defeat in-place aliasing
         there), while unrolled code also gives XLA's scheduler the
-        whole window to overlap."""
+        whole window to overlap. Only step K-1's metrics are returned,
+        so the others are told not to make their tree-sized ones."""
         items_k, idx, w_k, pri = sample
         params, target_params, opt_state, step = (
             state.params, state.target_params, state.opt_state,
@@ -278,7 +342,8 @@ class SingleChipLearner:
             it = jax.tree.map(lambda x: x[j], items_k)
             params, target_params, opt_state, step, td_abs, metrics = \
                 self._sgd_step(params, target_params, opt_state, step,
-                               it, w_k[j])
+                               it, w_k[j],
+                               want_tree_diag if j == k - 1 else False)
             td_parts.append(td_abs)
         # write-back-time replay health: state.replay's tree is what
         # the sampler would see NOW, pri is what it saw at descent
@@ -292,8 +357,8 @@ class SingleChipLearner:
             opt_state=opt_state, replay=replay_state, step=step)
         return new_state, metrics
 
-    def _train_step_k(self, state: TrainState,
-                      k: int) -> tuple[TrainState, dict]:
+    def _train_step_k(self, state: TrainState, k: int,
+                      want_tree_diag=True) -> tuple[TrainState, dict]:
         """K grad-steps from ONE stratified sample + ONE priority
         write-back (the K-batch relaxation, LearnerConfig.sample_chunk).
 
@@ -309,7 +374,8 @@ class SingleChipLearner:
         drift."""
         rng, sk = self._split_rng(state.rng)
         sample = self._sample_stage(state.replay, sk, k)
-        return self._learn_stage(state._replace(rng=rng), sample, k)
+        return self._learn_stage(state._replace(rng=rng), sample, k,
+                                 want_tree_diag)
 
     # -- jitted endpoints --------------------------------------------------
 
@@ -355,23 +421,27 @@ class SingleChipLearner:
         With sample_chunk=K>1, runs n//K K-batch macro-steps (plus
         exact single steps for any remainder) — same grad-step count
         either way. With sample_prefetch, the macro-step scan runs
-        double-buffered (see _train_many_prefetch)."""
+        double-buffered (see _train_many_prefetch).
+
+        Only the LAST step's metrics leave the program, so every scan
+        runs over `_last_of(length)` and hands that flag down as
+        `want_tree_diag`: XLA cannot prune a scan body, and the norms
+        over parameter-sized trees are 2.9 ms a tree at 591 M
+        parameters (obs/learning.py::sgd_diag)."""
         k = self.lcfg.sample_chunk
 
-        def body(s, _):
-            s, m = self._train_step(s)
-            return s, m
+        def body(s, last):
+            return self._train_step(s, last)
 
         if self.lcfg.sample_prefetch:
             return self._train_many_prefetch(state, n, max(k, 1), body)
 
         if k <= 1:
-            state, metrics = jax.lax.scan(body, state, None, length=n)
+            state, metrics = jax.lax.scan(body, state, _last_of(n))
             return state, jax.tree.map(lambda x: x[-1], metrics)
 
-        def body_k(s, _):
-            s, m = self._train_step_k(s, k)
-            return s, m
+        def body_k(s, last):
+            return self._train_step_k(s, k, last)
 
         # exact singles for the remainder run FIRST so the returned
         # (last-step) metrics come from the K-batch macro-steps that do
@@ -380,11 +450,10 @@ class SingleChipLearner:
         # driver log exactly where they'd show (round-4 verdict weak #7)
         metrics = None
         if n % k:
-            state, metrics = jax.lax.scan(body, state, None,
-                                          length=n % k)
+            state, metrics = jax.lax.scan(
+                body, state, _last_of(n % k, returned=not n // k))
         if n // k:
-            state, metrics = jax.lax.scan(body_k, state, None,
-                                          length=n // k)
+            state, metrics = jax.lax.scan(body_k, state, _last_of(n // k))
         return state, jax.tree.map(lambda x: x[-1], metrics)
 
     def _train_many_prefetch(self, state: TrainState, n: int, k: int,
@@ -409,24 +478,25 @@ class SingleChipLearner:
         amortized over n//k macro-steps)."""
         metrics = None
         if n % k:
-            state, metrics = jax.lax.scan(body, state, None,
-                                          length=n % k)
+            state, metrics = jax.lax.scan(
+                body, state, _last_of(n % k, returned=not n // k))
         if n // k:
             rng, sk = self._split_rng(state.rng)
             pending = self._sample_stage(state.replay, sk, k)
             state = state._replace(rng=rng)
 
-            def body_pf(carry, _):
+            def body_pf(carry, last):
                 s, pend = carry
                 rng, sk = self._split_rng(s.rng)
                 # drawn BEFORE _learn_stage's write-back: no data
                 # dependency with the K SGD steps below
                 nxt = self._sample_stage(s.replay, sk, k)
-                s, m = self._learn_stage(s._replace(rng=rng), pend, k)
+                s, m = self._learn_stage(s._replace(rng=rng), pend, k,
+                                         last)
                 return (s, nxt), m
 
             (state, _), metrics = jax.lax.scan(
-                body_pf, (state, pending), None, length=n // k)
+                body_pf, (state, pending), _last_of(n // k))
         return state, jax.tree.map(lambda x: x[-1], metrics)
 
     @partial(jax.jit, static_argnums=0, donate_argnums=1)
