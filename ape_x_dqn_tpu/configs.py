@@ -36,7 +36,7 @@ class EnvConfig:
     full_action_set: bool = False
     # synthetic_tokens: how many token ids the environment emits and
     # accepts as actions — the vocabulary rows the Q-network holds
-    # (network.glm.vocab_size / network.glm.shard_count)
+    # (the decoder block's vocab_size / shard_count)
     num_tokens: int = 256
 
 
@@ -104,9 +104,94 @@ class GlmMoeConfig:
                 f"vocab_size={self.vocab_size}")
 
 
+_PUBLISHED_AFMOE_LAYER_TYPES = (
+    "sliding_attention", "sliding_attention", "sliding_attention",
+    "full_attention") * 8
+
+
+@dataclass(frozen=True)
+class AfmoeConfig:
+    """The decoder of network.kind="afmoe_q" (models/afmoe_q.py), under
+    the key names of the model's own config.json (model_type afmoe);
+    defaults are Trinity-Mini's. Grouped-query attention with a gated
+    output, `layer_types[i]` "sliding_attention" (RoPE, the last
+    `sliding_window` keys) or "full_attention" (no position encoding,
+    every key); `num_dense_layers` leading dense SwiGLU layers, then
+    layers of `num_experts` routed experts (sigmoid scores, top-
+    `num_experts_per_tok` of score + a fixed selection bias, weights
+    normalised if `route_norm` and times `route_scale`) beside
+    `num_shared_experts` shared ones; embedding scaled by
+    sqrt(hidden_size) if `mup_enabled`; untied embedding and head."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 6144       # the dense layers' SwiGLU
+    moe_intermediate_size: int = 1024   # each routed / shared expert
+    num_hidden_layers: int = 32
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    # one entry per layer held, in order; a run that holds fewer layers
+    # than the model names the kinds of the ones it holds
+    layer_types: tuple[str, ...] = _PUBLISHED_AFMOE_LAYER_TYPES
+    sliding_window: int = 2048
+    num_experts: int = 128
+    num_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    n_group: int = 1                    # only 1 is built: no group stage
+    topk_group: int = 1
+    route_norm: bool = True
+    route_scale: float = 2.826
+    mup_enabled: bool = True
+    vocab_size: int = 200_192
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10_000.0
+    # the three below are GlmMoeConfig's, with the same meaning: this
+    # chip's share of a deployment in which `shard_count` chips share
+    # each layer (routed experts [shard_index * n / shard_count, ...)
+    # and as many vocabulary rows live here), and the selection forced
+    # balanced for measuring with random weights
+    shard_count: int = 1
+    shard_index: int = 0
+    force_balanced_routing: bool = False
+    # over how many chips the embedding's and the head's rows are
+    # divided, where that is fewer than share the experts (128 experts
+    # go sixteen ways at 8 a chip; a sixteenth of the vocabulary would
+    # be under an eighth, the least that is still the model's head);
+    # rows [i * V / n, (i + 1) * V / n), i = shard_index % n, live here.
+    # 0: as shard_count
+    vocab_shard_count: int = 0
+
+    def __post_init__(self) -> None:
+        if not 0 <= self.shard_index < self.shard_count:
+            raise ValueError(
+                f"network.afmoe.shard_index must be in [0, "
+                f"{self.shard_count}) (got {self.shard_index})")
+        vocab_shards = self.vocab_shard_count or self.shard_count
+        if (self.num_experts % self.shard_count
+                or self.vocab_size % vocab_shards):
+            raise ValueError(
+                f"network.afmoe.shard_count={self.shard_count} must "
+                f"divide num_experts={self.num_experts} and "
+                f"vocab_shard_count={vocab_shards} must divide "
+                f"vocab_size={self.vocab_size}")
+        # (that there is one entry per layer held is the net's to
+        # check: overrides set the two fields one after the other)
+        kinds = {"sliding_attention", "full_attention"}
+        if not set(self.layer_types) <= kinds:
+            raise ValueError(
+                f"network.afmoe.layer_types must name {sorted(kinds)} "
+                f"(got {self.layer_types})")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"network.afmoe.num_key_value_heads="
+                f"{self.num_key_value_heads} must divide "
+                f"num_attention_heads={self.num_attention_heads}")
+
+
 @dataclass(frozen=True)
 class NetworkConfig:
-    kind: str = "mlp"  # mlp | nature_cnn | lstm_q | dpg | glm_moe_q
+    kind: str = "mlp"  # mlp | nature_cnn | lstm_q | dpg | glm_moe_q | afmoe_q
     mlp_hidden: tuple[int, ...] = (256, 256)
     cnn_channels: tuple[int, ...] = (32, 64, 64)
     cnn_kernels: tuple[int, ...] = (8, 4, 3)
@@ -120,6 +205,8 @@ class NetworkConfig:
     compute_dtype: str = "bfloat16"
     # the decoder of kind="glm_moe_q" (token-level Q-learning)
     glm: GlmMoeConfig = field(default_factory=GlmMoeConfig)
+    # the decoder of kind="afmoe_q" (the same family, another model)
+    afmoe: AfmoeConfig = field(default_factory=AfmoeConfig)
 
 
 @dataclass(frozen=True)
@@ -919,6 +1006,73 @@ def _preset_glm_tiny_q() -> RunConfig:
     )
 
 
+def _preset_trinity_mini_q() -> RunConfig:
+    """Config 7: Trinity-Mini (Arcee, 26B-A3B) as a token-level
+    Q-network, the decoder family's second net. The sizes are the
+    model's config.json
+    (https://huggingface.co/arcee-ai/Trinity-Mini, model_type afmoe):
+    32 layers (3 sliding-window : 1 full attention, window 2,048), 128
+    routed experts of 1,024, top-8, 200,192 vocabulary rows. Whole it
+    is 26 B parameters and check_hbm_fits refuses it: a run gives one
+    chip its share with network.afmoe.shard_count / num_hidden_layers /
+    layer_types and env.num_tokens
+    (benchmarks/configs/trinity_mini_ep16_1chip.json is the measured
+    one). The learner settings are this repo's: sequences of 8,192
+    tokens, so that the 2,048-token window and the full layers differ."""
+    afmoe = AfmoeConfig()
+    return RunConfig(
+        name="trinity_mini_q",
+        total_env_frames=10_000_000_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=afmoe.vocab_size),
+        network=NetworkConfig(kind="afmoe_q", dueling=False, afmoe=afmoe),
+        # a stored sequence is 8,192 tokens: 2,048 of burn-in, whose
+        # keys and values the trained 6,144 attend to without gradient
+        # (a sliding layer keeps the last 2,047 of them). 4,096
+        # sequences are GLM's token count, 0.63 GiB
+        replay=ReplayConfig(kind="sequence", capacity=4_096,
+                            seq_length=8_192, seq_overlap=4_096,
+                            burn_in=2_048, min_fill=128),
+        # batch 2 sequences = 16,384 tokens a step
+        learner=LearnerConfig(batch_size=2, n_step=5, value_rescale=True,
+                              target_sync_every=2500, lr=1e-4,
+                              sample_chunk=1, train_chunk=2),
+        # a query re-runs a window of up to 8,192 tokens (the family's
+        # stateless protocol): one at a time
+        actors=ActorConfig(num_actors=64, envs_per_actor=1),
+        inference=InferenceConfig(max_batch=1, deadline_ms=2.0),
+    )
+
+
+def _preset_trinity_tiny_q() -> RunConfig:
+    """trinity_mini_q's sibling for CPU tests: the same decoder at
+    hidden 64, 4 query / 2 key-value heads of 16, 8 experts, a
+    vocabulary of 64 and a window of 8 inside sequences of 32, float32."""
+    afmoe = AfmoeConfig(
+        hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+        num_hidden_layers=3, num_dense_layers=1, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "full_attention"),
+        sliding_window=8, num_experts=8, num_experts_per_tok=2,
+        vocab_size=64)
+    return RunConfig(
+        name="trinity_tiny_q",
+        total_env_frames=100_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=afmoe.vocab_size),
+        network=NetworkConfig(kind="afmoe_q", dueling=False, afmoe=afmoe,
+                              compute_dtype="float32"),
+        replay=ReplayConfig(kind="sequence", capacity=64, seq_length=32,
+                            seq_overlap=16, burn_in=12, min_fill=8),
+        learner=LearnerConfig(batch_size=4, n_step=3, value_rescale=True,
+                              target_sync_every=100, lr=1e-3,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=1, envs_per_actor=2),
+        inference=InferenceConfig(max_batch=8, deadline_ms=2.0),
+    )
+
+
 PRESETS = {
     "cartpole_smoke": _preset_cartpole_smoke,
     "pong": _preset_pong,
@@ -927,6 +1081,8 @@ PRESETS = {
     "apex_dpg": _preset_apex_dpg,
     "glm47_flash_q": _preset_glm47_flash_q,
     "glm_tiny_q": _preset_glm_tiny_q,
+    "trinity_mini_q": _preset_trinity_mini_q,
+    "trinity_tiny_q": _preset_trinity_tiny_q,
 }
 
 

@@ -6,6 +6,19 @@ from ape_x_dqn_tpu.models.qnets import MLPQNet, NatureDQN, DuelingHead
 from ape_x_dqn_tpu.models.lstm_q import ApeXLSTMQNet, LSTMState
 from ape_x_dqn_tpu.models.dpg import DPGActor, DPGCritic
 from ape_x_dqn_tpu.models.glm_moe_q import GlmMoeQNet
+from ape_x_dqn_tpu.models.afmoe_q import AfmoeQNet
+
+# network.kind -> the net's class: the token-level Q-networks of the
+# decoder_q family. A further decoder is a row here and in
+# `decoder_block`, a config block, and a row in runtime/family.family_of
+DECODER_NETS = {"glm_moe_q": GlmMoeQNet, "afmoe_q": AfmoeQNet}
+
+
+def decoder_block(net_cfg):
+    """-> (the name of net_cfg's decoder block in NetworkConfig, the
+    block)."""
+    return {"glm_moe_q": ("glm", net_cfg.glm),
+            "afmoe_q": ("afmoe", net_cfg.afmoe)}[net_cfg.kind]
 
 
 def build_network(net_cfg, spec):
@@ -33,11 +46,12 @@ def build_network(net_cfg, spec):
                             dueling=net_cfg.dueling,
                             compute_dtype=net_cfg.compute_dtype,
                             mlp_torso=len(spec.obs_shape) == 1)
-    if net_cfg.kind == "glm_moe_q":
+    if net_cfg.kind in DECODER_NETS:
         from ape_x_dqn_tpu.parallel.mesh import has_expert_exchange
 
-        return GlmMoeQNet(net_cfg.glm, compute_dtype=net_cfg.compute_dtype,
-                          expert_exchange=has_expert_exchange())
+        return DECODER_NETS[net_cfg.kind](
+            decoder_block(net_cfg)[1], compute_dtype=net_cfg.compute_dtype,
+            expert_exchange=has_expert_exchange())
     if net_cfg.kind == "dpg":
         actor = DPGActor(action_dim=spec.action_dim,
                          action_low=spec.action_low,

@@ -27,56 +27,21 @@ float32, independently):
   q_rope . k_r) / sqrt(nope + rope), causal, softmax in float32, out =
   sum p v -> W_o. No biases. The cache holds c_kv after its norm and
   k_r after its rotation.
-- Expert layer: router in float32, s = sigmoid(x W_g); the top-k of
-  s + b are selected (b: a fixed, seeded buffer, never trained:
-  `stop_gradient`, so Adam's update of it is exactly 0); their weights
-  are the selected s (without b) divided by their sum, times
-  routed_scaling_factor. FFN(x) = sum_k w_k E_k(x) + E_shared(x), E(x) =
-  W_down(silu(W_gate x) * W_up x). The first `first_k_dense_replace`
-  layers are one dense SwiGLU instead.
-- The share (GlmMoeConfig.shard_count / shard_index): the router scores
-  all n_routed_experts and the weights are normalised over all k
-  selected, but only selected experts held here add their w_k E_k(x);
-  what absent experts would add is left out and the partial sum goes
-  on. A share that runs WITHOUT THE EXCHANGE between the chips that
-  share the layer (`expert_exchange` False: parallel/mesh.py has no
-  expert axis yet) gives its router no gradient: that gradient is a sum
-  over all selected experts, of which one chip has its own term only
-  (`_route` says what that term alone does). Embedding and
-  head hold vocab_size / shard_count rows; ids, Q-values, argmax and
-  the loss are over that slice.
-- Forced balanced routing (`GlmMoeConfig.force_balanced_routing`, off
-  in every preset; Megatron-LM's `--moe-router-force-load-balancing` is
-  the precedent, and like it this is for measuring with random weights
-  only): the SELECTION is the top-k of `_balanced_scores`, a fixed
-  pseudo-random function of (token id, position, layer, expert),
-  instead of the top-k of s + b; the weights are still the selected s,
-  normalised and scaled, so the router's arithmetic stays in every
-  value. Why it exists: at random weights nearly every hidden state is
-  one common direction plus a little of its token, so s + b picks
-  nearly the same k experts for every token, how many of those k a
-  share holds is a draw of the seed (0 to k), and at Adam 1e-4 the draw
-  changes within a hundred steps; the grouped matmuls' cost follows
-  the rows routed here, so a step's time did too (PERF.md section 6,
-  PR 30). A trained checkpoint's router and its b spread the load;
-  this stands in for that and for nothing else.
+- Expert layer: models/expert_layer.py's routed + shared expert layer
+  (both decoder nets call it; its docstring has the equations, the
+  share, what a share without the exchange does to its router, the
+  forced balanced selection and how the grouped matmuls run) at this
+  model's numbers: top-`num_experts_per_tok` of sigmoid score + a fixed
+  bias, weights normalised (`norm_topk_prob`) and times
+  `routed_scaling_factor`. The first `first_k_dense_replace` layers are
+  one dense SwiGLU instead.
+- The share (GlmMoeConfig.shard_count / shard_index): only selected
+  experts held here add their part. Embedding and head hold
+  vocab_size / shard_count rows; ids, Q-values, argmax and the loss
+  are over that slice.
 - The config's one multi-token-prediction layer is NOT built: it serves
   the next-token likelihood in pre-training and speculation in serving;
   a TD loss has neither (HF's modelling code skips those weights too).
-
-How the expert matmuls run: no token is dropped and every shape is
-fixed. The k x N assignments are sorted by local expert (not-held ones
-last), the rows gathered in that order into a [k N, hidden] buffer —
-the worst case, every selection local — and the three matmuls are
-`jax.lax.ragged_dot` over the groups (XLA:TPU lowers it to a grouped
-matmul kernel), so their cost follows the rows actually routed here
-(about k N x held / total), not the buffer. Rows past the last group
-are masked to zero and combined with weight 0. The sort is a
-permutation, so dispatch and combine are GATHERS both ways
-(`_dispatch`, `_combine`: the transpose of a gather by a permutation is
-the gather by its inverse); as `x[token]` and `.at[token].add(y)` their
-transposes were scatter-adds and the dispatch took 16% of a step where
-the matmuls it feeds took 3.4% (PERF.md section 6, PR 30).
 
 Layers are a Python loop, not a `lax.scan` over stacked parameters.
 The scan was tried (PR 30): it compiles in 55 s instead of 87 and its
@@ -89,16 +54,9 @@ and keeps a bfloat16 copy of all of them per net: temp 6.2 GiB against
 Recomputation: every block is a `jax.checkpoint`, on by the family (at
 these widths a block's activations are what does not fit), so a
 differentiated pass keeps one [B, T, hidden] per block and recomputes
-the rest in the backward pass — all but THE SELECTION (`SELECTION`: the
-top-k ids, [N, k] int32), which is kept. The recomputation is another
-piece of compiled code than the forward pass and its bfloat16
-activations differ in the last bit, so a near-tie between the k-th and
-(k+1)-th score fell the other way in a handful of tokens: the backward
-pass then sorted those tokens to another expert than the one whose
-output the loss had seen. Held to the reference's `jax.grad` on the
-v5e, a stack of expert matrices was off by 16-19% of its norm where few
-rows were routed here, 5-13 times bfloat16's own error; with the
-selection kept, 1.1 times (PERF.md section 6, PR 30).
+the rest in the backward pass - all but THE SELECTION
+(expert_layer.SELECTION, whose docstring says what went wrong while the
+recomputation decided it again).
 
 Parameters are float32 and cast to the compute dtype at use; a plain
 pytree under HF's names (`init`: `embed_tokens`, `layers` a list of
@@ -114,97 +72,14 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from jax.ad_checkpoint import checkpoint_name
-
 from ape_x_dqn_tpu.models.base import dtype_of
+# `_dispatch`, `_combine`: the layer's two custom VJPs, under the names
+# they had here (a benchmark test reaches `glm_moe_q._combine`)
+from ape_x_dqn_tpu.models.expert_layer import (  # noqa: F401
+    SELECTION, ExpertShare, _balanced_scores, _combine, _dispatch, _rms_norm,
+    _rope, _swiglu, count_params, expert_ffn, seeded_params)
 
-INIT_STD = 0.02          # every matrix: normal(0, 0.02); norms 1
-ROUTER_BIAS_STD = 0.1    # the fixed selection bias b: normal(0, 0.1)
 STEP_REST = 1 << 30      # a step beside gradients and logits (see below)
-SELECTION = "glm.moe.selection"   # the one value a block's recomputation keeps
-
-
-def _rms_norm(x: jax.Array, g: jax.Array, eps: float) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps) * g).astype(x.dtype)
-
-
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x [B, T, ..., d], positions [T] -> rotated, half-split pairing."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]  # [T,d/2]
-    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (d // 2,)
-    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :d // 2], x32[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _swiglu(x: jax.Array, p: dict, dt) -> jax.Array:
-    gate = x @ p["gate_proj"].astype(dt)
-    up = x @ p["up_proj"].astype(dt)
-    return (jax.nn.silu(gate) * up) @ p["down_proj"].astype(dt)
-
-
-def _balanced_scores(tokens: jax.Array, positions: jax.Array, layer: int,
-                     experts: int) -> jax.Array:
-    """tokens [B, T] int32, positions [T] -> [B, T, experts] float32
-    selection scores for `force_balanced_routing`: no two of a token's
-    scores are equal, and their order is a fixed pseudo-random function
-    of (token id, position, layer). 32-bit integer arithmetic (murmur3's
-    finalizer over a sum of odd multiples), the top 18 bits kept and the
-    expert's id below them so that a tie falls to the lower id; 24 bits
-    in all, which float32 holds exactly."""
-    u = lambda x: jnp.asarray(x, jnp.uint32)  # noqa: E731
-    e = jnp.arange(experts, dtype=jnp.uint32)
-    h = (u(tokens)[:, :, None] * u(0x9E3779B1)
-         + u(positions)[None, :, None] * u(0x85EBCA77)
-         + u(layer) * u(0xC2B2AE3D) + e * u(0x27D4EB2F))
-    h = (h ^ (h >> 16)) * u(0x85EBCA6B)
-    h = (h ^ (h >> 13)) * u(0xC2B2AE35)
-    h = h ^ (h >> 16)
-    return (((h >> 14) << 6) | (u(experts - 1) - e)).astype(jnp.float32)
-
-
-@jax.custom_vjp
-def _dispatch(x: jax.Array, order: jax.Array, inverse: jax.Array
-              ) -> jax.Array:
-    """x [N, h] -> [k N, h]: row j is the token of assignment
-    `order[j]` (assignment a belongs to token a // k)."""
-    return x[order // (order.shape[0] // x.shape[0])]
-
-
-def _dispatch_fwd(x, order, inverse):
-    return _dispatch(x, order, inverse), (inverse, x.shape[0])
-
-
-def _dispatch_bwd(res, g):
-    inverse, n = res
-    return g[inverse].reshape(n, -1, g.shape[-1]).sum(axis=1), None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine(y: jax.Array, order: jax.Array, inverse: jax.Array, n: int
-             ) -> jax.Array:
-    """y [k N, h] in sorted order -> [N, h]: each token's k rows summed."""
-    return y[inverse].reshape(n, -1, y.shape[-1]).sum(axis=1)
-
-
-def _combine_fwd(y, order, inverse, n):
-    return _combine(y, order, inverse, n), order
-
-
-def _combine_bwd(n, order, g):
-    return g[order // (order.shape[0] // n)], None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 class GlmMoeQNet:
@@ -231,6 +106,11 @@ class GlmMoeQNet:
                                     g.num_hidden_layers)
         self.num_moe_layers = g.num_hidden_layers - self.num_dense_layers
         self.router_trains = g.shard_count == 1 or expert_exchange
+        self.share = ExpertShare(
+            experts=g.n_routed_experts, top_k=g.num_experts_per_tok,
+            held=self.experts_held, first=self.first_expert,
+            norm_topk=g.norm_topk_prob, scale=g.routed_scaling_factor,
+            router_trains=self.router_trains)
 
     # -- parameters --------------------------------------------------------
 
@@ -274,10 +154,7 @@ class GlmMoeQNet:
                 "norm": (h,), "lm_head": (h, self.num_actions)}
 
     def param_count(self) -> int:
-        import math
-
-        return sum(math.prod(s) for s in jax.tree.leaves(
-            self.param_shapes(), is_leaf=lambda x: isinstance(x, tuple)))
+        return count_params(self.param_shapes())
 
     def step_transient_bytes(self, batch_size: int,
                              trained_steps: int) -> int:
@@ -300,29 +177,10 @@ class GlmMoeQNet:
 
     def init(self, key: jax.Array, tokens: Any = None,
              state: Any = None) -> dict:
-        """Seeded float32 parameters: matrices normal(0, 0.02), norm
-        gains 1, the router's selection bias normal(0, 0.1) (a buffer:
-        `apply` never lets a gradient reach it). `tokens`/`state` are
-        taken for flax's call shape and ignored."""
+        """Seeded float32 parameters (expert_layer.seeded_params).
+        `tokens`/`state` are taken for flax's call shape and ignored."""
         del tokens, state
-        shapes = self.param_shapes()
-        is_shape = lambda x: isinstance(x, tuple)  # noqa: E731
-        paths = jax.tree_util.tree_flatten_with_path(
-            shapes, is_leaf=is_shape)[0]
-        keys = jax.random.split(key, len(paths))
-
-        def leaf(path, shape, k):
-            name = path[-1].key
-            if name.endswith("norm"):
-                return jnp.ones(shape, jnp.float32)
-            std = (ROUTER_BIAS_STD if name == "e_score_correction_bias"
-                   else INIT_STD)
-            return std * jax.random.normal(k, shape, jnp.float32)
-
-        leaves = [leaf(path, shape, k)
-                  for (path, shape), k in zip(paths, keys)]
-        return jax.tree.unflatten(
-            jax.tree.structure(shapes, is_leaf=is_shape), leaves)
+        return seeded_params(self.param_shapes(), key)
 
     # -- the layers --------------------------------------------------------
 
@@ -367,78 +225,11 @@ class GlmMoeQNet:
         out = out.reshape(b, t, heads * g.v_head_dim) @ p["o_proj"].astype(dt)
         return out, (c_kv, k_rope)
 
-    def _route(self, p: dict, x: jax.Array, balanced):
-        """x [N, hidden] -> (top-k expert ids [N, k] int32, their
-        weights [N, k] float32, normalised over all k and scaled).
-        `balanced` [N, experts]: `_balanced_scores`, which then decide
-        the selection, or None for the model's own s + b."""
-        g = self.g
-        with jax.named_scope("glm.moe.router"):
-            s = jax.nn.sigmoid(jnp.dot(
-                x.astype(jnp.float32), p["gate"],
-                precision=jax.lax.Precision.HIGHEST))
-            select = balanced
-            if select is None:
-                select = s + jax.lax.stop_gradient(
-                    p["e_score_correction_bias"])
-            _, ids = jax.lax.top_k(select, g.num_experts_per_tok)
-            ids = checkpoint_name(ids, SELECTION)
-            w = jnp.take_along_axis(s, ids, axis=-1)
-            if g.norm_topk_prob:
-                w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
-            if not self.router_trains:
-                # a share without the exchange sees only its own
-                # experts' term of the router's gradient (the sum runs
-                # over every
-                # selected expert, wherever it lives), and that term
-                # alone teaches the router to send tokens to the
-                # experts that are absent, whose part is left out: on
-                # the v5e the rows routed here fell from 9,600 to under
-                # 100 a step within 20 steps (PERF.md section 6, PR 30).
-                # Until the exchange sums the terms the router is held
-                # fixed, as b is
-                w = jax.lax.stop_gradient(w)
-            return ids.astype(jnp.int32), w * g.routed_scaling_factor
-
     def _moe(self, p: dict, x: jax.Array, dt, balanced=None):
-        """x [B, T, hidden] -> (FFN(x), rows routed to each held expert
-        [held] int32, the top-k ids [B, T, k]). `balanced` [B, T,
-        experts]: see `_route`."""
-        b, t, h = x.shape
-        n, k, held = b * t, self.g.num_experts_per_tok, self.experts_held
-        flat = x.reshape(n, h)
-        ids, w = self._route(
-            p, flat, None if balanced is None else balanced.reshape(n, -1))
-        with jax.named_scope("glm.moe.dispatch"):
-            local = ids.reshape(-1) - self.first_expert          # [k N]
-            here = (local >= 0) & (local < held)
-            slot = jnp.where(here, local, held)    # not held: sorts last
-            order = jnp.argsort(slot, stable=True).astype(jnp.int32)
-            inverse = jnp.zeros_like(order).at[order].set(
-                jnp.arange(n * k, dtype=jnp.int32))
-            rows = jnp.bincount(slot, length=held + 1)[:held].astype(
-                jnp.int32)
-            live = jnp.arange(n * k) < rows.sum()
-            # masked both ways: a row past the last group reads zeros,
-            # and whatever the grouped matmul's transpose leaves in its
-            # cotangent never reaches the token it was gathered from
-            gathered = jnp.where(live[:, None],
-                                 _dispatch(flat, order, inverse), 0)
-            w_sorted = jnp.where(live, w.reshape(-1)[order], 0.0)
-        with jax.named_scope("glm.moe.experts"):
-            e = p["experts"]
-            gate = jax.lax.ragged_dot(gathered, e["gate_proj"].astype(dt),
-                                      rows)
-            up = jax.lax.ragged_dot(gathered, e["up_proj"].astype(dt), rows)
-            y = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
-                                   e["down_proj"].astype(dt), rows)
-        with jax.named_scope("glm.moe.dispatch"):
-            # rows past the last group are whatever the kernel left
-            y = jnp.where(live[:, None], y, 0) * w_sorted[:, None].astype(dt)
-            routed = _combine(y, order, inverse, n)
-        with jax.named_scope("glm.moe.shared"):
-            shared = _swiglu(flat, p["shared_experts"], dt)
-        return (routed + shared).reshape(b, t, h), rows, ids.reshape(b, t, k)
+        """The shared expert layer (models/expert_layer.py) at this
+        net's share: x [B, T, hidden] -> (FFN(x), rows routed to each
+        held expert [held] int32, the top-k ids [B, T, k])."""
+        return expert_ffn(p, x, dt, self.share, balanced)
 
     def _block(self, p: dict, x: jax.Array, cache, tokens: jax.Array,
                layer: int):

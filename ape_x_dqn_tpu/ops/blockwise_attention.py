@@ -1,0 +1,240 @@
+"""Causal attention over blocks of queries and keys, for sequences
+whose score matrix cannot exist (models/afmoe_q.py: 32 heads x 6,144
+queries x 8,192 keys of float32 are 6.4 GB a layer and sequence).
+
+    blockwise_attention(q, k, v, cache, window=...) -> out [B, T, Hq, d]
+
+`q` [B, T, Hq, d], `k`/`v` [B, T, Hkv, d] are the new positions; `cache`
+= (k_c, v_c) [B, C, Hkv, d] the C positions before them, or None. Query
+t sits at key index C + t and sees key s iff s <= C + t and, with a
+`window`, (C + t) - s < window (the query itself counts, so `window`
+keys). Grouped queries: head j reads key-value head j // (Hq / Hkv).
+score = q . k / sqrt(d), softmax in float32.
+
+One function for every mask a decoder here uses; what the mask buys is
+in the loop bounds: a block of queries visits only the blocks of keys
+its mask admits (a causal block none after its diagonal, a windowed one
+none before its window), so a sliding layer at 6,144 queries over 8,191
+keys does a third of a full layer's work. Online softmax (running
+maximum and sum, one [.., block_q, block_k] tile of scores alive at a
+time), its own backward pass (`jax.custom_vjp`: the tiles are recomputed
+from the saved log-sum-exp, so nothing of size [T, S] is kept for it
+either), plain `jax.numpy` in two nested `fori_loop`s whose bounds
+follow the query block - the same code on the CPU and on the chip.
+
+THE CACHE CARRIES NO GRADIENT: `cache` enters under `stop_gradient`
+(ops/losses.make_r2d2_loss stops the prefix state's anyway), and the
+backward pass skips the key blocks that lie wholly inside it.
+
+What it reaches on a v5e, beside jax's splash-attention Pallas kernel
+at the same shapes, is in PERF.md (section 6, PR 32).
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+BLOCK_Q = 512
+BLOCK_K = 512
+_NEG = -1e30     # a masked score: finite, so an all-masked tile gives 0, not NaN
+
+
+class _Geometry(NamedTuple):
+    """Static shape of one call, in key-index space (keys are
+    [padding | cache | new])."""
+    pad: int            # zero keys in front, so that S is whole blocks
+    first: int          # key index of query 0 (= pad + C)
+    window: int | None
+    block_q: int
+    block_k: int
+
+
+def _divisor(n: int, target: int) -> int:
+    """The largest divisor of n that is <= target."""
+    return max(d for d in range(1, min(n, target) + 1) if n % d == 0)
+
+
+def _bounds(geo: _Geometry, i):
+    """Query block i -> [lo, hi): the key blocks its mask admits."""
+    top = geo.first + (i + 1) * geo.block_q - 1          # last row's own key
+    low = geo.pad
+    if geo.window is not None:
+        low = jnp.maximum(geo.pad,
+                          geo.first + i * geo.block_q - geo.window + 1)
+    return low // geo.block_k, top // geo.block_k + 1
+
+
+def _visible(geo: _Geometry, i, j):
+    """[block_q, block_k] bool: which of tile (i, j)'s pairs the mask
+    admits."""
+    rows = geo.first + i * geo.block_q + jnp.arange(geo.block_q)[:, None]
+    cols = j * geo.block_k + jnp.arange(geo.block_k)[None, :]
+    vis = (cols <= rows) & (cols >= geo.pad)
+    if geo.window is not None:
+        vis &= rows - cols < geo.window
+    return vis
+
+
+def _tile(x, index, size, axis):
+    return jax.lax.dynamic_slice_in_dim(x, index * size, size, axis)
+
+
+def _scores(geo: _Geometry, qi, kj, i, j):
+    """-> (scaled, masked scores [B, KV, G, block_q, block_k] float32,
+    the mask)."""
+    s = jnp.einsum("bkgtd,bksd->bkgts", qi, kj,
+                   preferred_element_type=jnp.float32)
+    vis = _visible(geo, i, j)
+    return jnp.where(vis, s * (qi.shape[-1] ** -0.5), _NEG), vis
+
+
+def _forward(geo: _Geometry, q, k, v):
+    """q [B, KV, G, T, d]; k, v [B, KV, S, d] -> (out [B, KV, G, T, d]
+    and log-sum-exp [B, KV, G, T], both float32)."""
+    b, kv, g, t, d = q.shape
+    bq, bk = geo.block_q, geo.block_k
+
+    def per_query_block(i, carry):
+        out, lse = carry
+        qi = _tile(q, i, bq, 3)
+
+        def per_key_block(j, c):
+            acc, m, total = c
+            s, vis = _scores(geo, qi, _tile(k, j, bk, 2), i, j)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.where(vis, jnp.exp(s - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bkgts,bksd->bkgtd", p.astype(v.dtype), _tile(v, j, bk, 2),
+                preferred_element_type=jnp.float32)
+            return acc, m_new, total * alpha + p.sum(axis=-1)
+
+        lo, hi = _bounds(geo, i)
+        acc, m, total = jax.lax.fori_loop(lo, hi, per_key_block, (
+            jnp.zeros((b, kv, g, bq, d), jnp.float32),
+            jnp.full((b, kv, g, bq), _NEG, jnp.float32),
+            jnp.zeros((b, kv, g, bq), jnp.float32)))
+        out = jax.lax.dynamic_update_slice_in_dim(
+            out, acc / total[..., None], i * bq, 3)
+        lse = jax.lax.dynamic_update_slice_in_dim(
+            lse, m + jnp.log(total), i * bq, 3)
+        return out, lse
+
+    return jax.lax.fori_loop(0, t // bq, per_query_block, (
+        jnp.zeros(q.shape, jnp.float32),
+        jnp.zeros((b, kv, g, t), jnp.float32)))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _attend(geo: _Geometry, q, k, v):
+    return _forward(geo, q, k, v)[0].astype(q.dtype)
+
+
+def _attend_fwd(geo, q, k, v):
+    # the backward pass keeps the output in float32: each row of ds is
+    # p (dp - delta) with delta = out . d_out, and where the values
+    # share a large common component (random weights do) dp - delta is
+    # a small difference of large numbers; an output rounded to
+    # bfloat16 puts the same relative error into a whole row of ds,
+    # which then sums coherently into the gradients of the q and k
+    # projections and head norms (on the v5e, held to a float32
+    # reference: 5 to 20 times bfloat16's own error on those leaves)
+    out, lse = _forward(geo, q, k, v)
+    return out.astype(q.dtype), (q, k, v, out, lse)
+
+
+def _attend_bwd(geo, res, d_out):
+    """The cotangents of q, k, v; keys below `geo.first` (padding and
+    cache) get none."""
+    q, k, v, out, lse = res
+    b, kv, g, t, d = q.shape
+    bq, bk = geo.block_q, geo.block_k
+    scale = d ** -0.5
+    delta = jnp.sum(out * d_out.astype(jnp.float32), axis=-1)  # [B,KV,G,T]
+    with_grad_from = geo.first // bk     # key blocks before it: all cache
+
+    def per_query_block(i, carry):
+        dq, dk, dv = carry
+        qi, doi = _tile(q, i, bq, 3), _tile(d_out, i, bq, 3)
+        lse_i, delta_i = _tile(lse, i, bq, 3), _tile(delta, i, bq, 3)
+
+        def d_scores(j):
+            kj, vj = _tile(k, j, bk, 2), _tile(v, j, bk, 2)
+            s, vis = _scores(geo, qi, kj, i, j)
+            p = jnp.where(vis, jnp.exp(s - lse_i[..., None]), 0.0)
+            dp = jnp.einsum("bkgtd,bksd->bkgts", doi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_i[..., None]) * scale).astype(q.dtype)
+            return p.astype(q.dtype), ds, kj
+
+        def add_dq(dq_i, ds, kj):
+            return dq_i + jnp.einsum("bkgts,bksd->bkgtd", ds, kj,
+                                     preferred_element_type=jnp.float32)
+
+        def cached_key_block(j, dq_i):
+            _, ds, kj = d_scores(j)
+            return add_dq(dq_i, ds, kj)
+
+        def per_key_block(j, c):
+            dq_i, dk, dv = c
+            p, ds, kj = d_scores(j)
+            dk_j = jnp.einsum("bkgts,bkgtd->bksd", ds, qi,
+                              preferred_element_type=jnp.float32)
+            dv_j = jnp.einsum("bkgts,bkgtd->bksd", p, doi,
+                              preferred_element_type=jnp.float32)
+            dk = jax.lax.dynamic_update_slice_in_dim(
+                dk, _tile(dk, j, bk, 2) + dk_j, j * bk, 2)
+            dv = jax.lax.dynamic_update_slice_in_dim(
+                dv, _tile(dv, j, bk, 2) + dv_j, j * bk, 2)
+            return add_dq(dq_i, ds, kj), dk, dv
+
+        lo, hi = _bounds(geo, i)
+        split = jnp.clip(with_grad_from, lo, hi)
+        dq_i = jax.lax.fori_loop(
+            lo, split, cached_key_block,
+            jnp.zeros((b, kv, g, bq, d), jnp.float32))
+        dq_i, dk, dv = jax.lax.fori_loop(split, hi, per_key_block,
+                                         (dq_i, dk, dv))
+        dq = jax.lax.dynamic_update_slice_in_dim(
+            dq, dq_i.astype(q.dtype), i * bq, 3)
+        return dq, dk, dv
+
+    dq, dk, dv = jax.lax.fori_loop(0, t // bq, per_query_block, (
+        jnp.zeros_like(q), jnp.zeros(k.shape, jnp.float32),
+        jnp.zeros(v.shape, jnp.float32)))
+    new = (jnp.arange(k.shape[2]) >= geo.first)[:, None]
+    return (dq, jnp.where(new, dk, 0.0).astype(k.dtype),
+            jnp.where(new, dv, 0.0).astype(v.dtype))
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                        cache: tuple | None = None, *,
+                        window: int | None = None,
+                        block_q: int = BLOCK_Q,
+                        block_k: int = BLOCK_K) -> jax.Array:
+    """See the module docstring. `block_q` is cut to a divisor of T;
+    the keys are padded in front to whole blocks of `block_k`."""
+    b, t, heads, d = q.shape
+    kv = k.shape[2]
+    if cache is not None:
+        k = jnp.concatenate(
+            [jax.lax.stop_gradient(cache[0]).astype(k.dtype), k], axis=1)
+        v = jnp.concatenate(
+            [jax.lax.stop_gradient(cache[1]).astype(v.dtype), v], axis=1)
+    s = k.shape[1]
+    bk = min(block_k, s)
+    pad = -s % bk
+    geo = _Geometry(pad=pad, first=pad + s - t, window=window,
+                    block_q=_divisor(t, block_q), block_k=bk)
+    front = ((0, 0), (pad, 0), (0, 0), (0, 0))
+    k, v = (jnp.pad(a, front).transpose(0, 2, 1, 3) for a in (k, v))
+    q = q.reshape(b, t, kv, heads // kv, d).transpose(0, 2, 3, 1, 4)
+    out = _attend(geo, q, k, v)                      # [B, KV, G, T, d]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)

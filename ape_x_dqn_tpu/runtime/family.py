@@ -22,13 +22,24 @@ and what differs is the state a sequence starts from:
   only refresh it.
 - "decoder_q" stores NONE: a replayed window starts from an empty
   attention cache and its burn-in prefix is its context — the prefix
-  pass leaves a latent cache (per layer c_kv and k_rope), the loss
-  stops its gradient, the trained steps attend to it. The item has no
-  state entry at all. The server is stateless too: a query carries the
+  pass leaves the net's cache (glm_moe_q: per layer a latent c_kv and
+  k_rope; afmoe_q: per layer (k, v), every position for a full layer
+  and the last window - 1 for a sliding one), the loss stops its
+  gradient, the trained steps attend to it. The item has no state
+  entry at all. The server is stateless too: a query carries the
   last <= L token ids ({obs, ctx, n} -> {q, ctx, n}) and the server
-  re-runs the window; a per-slot latent cache inside
+  re-runs the window; a per-slot cache inside
   parallel/inference_server.py, which would make a step cost one token
   instead of a window, is what is missing.
+
+The decoder_q family has two nets (network.kind "glm_moe_q",
+"afmoe_q"), which share models/expert_layer.py. A further decoder
+registers with: its net in models/ with the surface the family reads
+(`init`, `apply`, `apply_with_stats` -> stats `expert_rows` and `topk`,
+`param_count`, `step_transient_bytes`, `num_actions`, `router_trains`),
+a config block in NetworkConfig, a row in models.DECODER_NETS, in
+models.decoder_block and in `family_of`; tools/apexlint's `config_coverage` learns the block's
+name. Nothing else here names a decoder.
 
 How a further Q-learning family registers: its net in models/ with a
 row in `build_network`; its kind in `family_of`; a row in
@@ -60,8 +71,8 @@ from ape_x_dqn_tpu.utils.rng import component_key
 
 
 def family_of(cfg: RunConfig) -> str:
-    return {"lstm_q": "r2d2", "dpg": "dpg",
-            "glm_moe_q": "decoder_q"}.get(cfg.network.kind, "dqn")
+    return {"lstm_q": "r2d2", "dpg": "dpg", "glm_moe_q": "decoder_q",
+            "afmoe_q": "decoder_q"}.get(cfg.network.kind, "dqn")
 
 
 # families whose replay items are whole sequences (the staging unit is
@@ -393,9 +404,12 @@ def family_setup(cfg: RunConfig, spec: Any, net: Any,
     family = family_of(cfg)
     if family == "decoder_q":
         if spec.num_actions != net.num_actions or spec.obs_shape != ():
+            from ape_x_dqn_tpu.models import decoder_block
+
+            block = decoder_block(cfg.network)[0]
             raise ValueError(
                 f"the decoder holds {net.num_actions} vocabulary rows "
-                f"(network.glm.vocab_size / shard_count) but the "
+                f"(network.{block}.vocab_size / shard_count) but the "
                 f"environment has {spec.num_actions} actions over "
                 f"observations {spec.obs_shape}: set env.num_tokens="
                 f"{net.num_actions} on a synthetic_tokens environment")
@@ -405,8 +419,8 @@ def family_setup(cfg: RunConfig, spec: Any, net: Any,
                 "sequence is stored flat (replay.storage='flat')")
         from ape_x_dqn_tpu.utils.hbm import check_hbm_fits
 
-        # before a single weight is made: the published model whole is
-        # 120 GB of float32 and should fail with a budget table
+        # before a single weight is made: a published model whole is
+        # 100 GB of float32 and should fail with a budget table
         check_hbm_fits(cfg, spec.obs_shape, spec.obs_dtype,
                        param_count=net.param_count(),
                        **hbm_price(cfg, net))

@@ -507,7 +507,11 @@ def test_push_delta_chain_and_epoch_bump():
             return False
 
         assert _wait(seen_new_epoch)
-        assert np.array_equal(got["p"]["w"], _bf16(t["w"]))
+        # `bump_epoch` wakes the push thread: where that push (the old
+        # tree under the new epoch, a full) lands before the publish
+        # above, this version arrives as a delta on it and carries a
+        # delta's error; where it does not, as a full, bit for bit
+        assert np.abs(got["p"]["w"] - _bf16(t["w"])).max() < 4e-3
     finally:
         client.close()
         srv.stop()

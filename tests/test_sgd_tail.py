@@ -535,6 +535,14 @@ def test_a_small_net_keeps_the_select_and_every_steps_norms(tail_min_bytes):
     ("glm47_flash_q", ["network.glm.num_hidden_layers=5",
                        "network.glm.shard_count=8",
                        "env.num_tokens=19360"], True),
+    ("trinity_mini_q", ["network.afmoe.num_hidden_layers=5",
+                        "network.afmoe.num_dense_layers=1",
+                        "network.afmoe.layer_types=('sliding_attention',"
+                        "'sliding_attention','sliding_attention',"
+                        "'sliding_attention','full_attention')",
+                        "network.afmoe.shard_count=16",
+                        "network.afmoe.vocab_shard_count=8",
+                        "env.num_tokens=25024"], True),
 ])
 def test_each_cells_net_takes_the_tail_measured_for_it(preset, sets, branches,
                                                        tail_min_bytes):
