@@ -36,17 +36,46 @@ routed here, so a step's time did too (PERF.md section 6, PR 30).
 
 How the expert matmuls run: no token is dropped and every shape is
 fixed. The k x N assignments are sorted by local expert (not-held ones
-last), the rows gathered in that order into a [k N, hidden] buffer -
-the worst case, every selection local - and the three matmuls are
-`jax.lax.ragged_dot` over the groups (XLA:TPU lowers it to a grouped
-matmul kernel), so their cost follows the rows actually routed here
-(about k N x held / experts), not the buffer. Rows past the last group
-are masked to zero and combined with weight 0. The sort is a
-permutation, so dispatch and combine are GATHERS both ways
-(`_dispatch`, `_combine`: the transpose of a gather by a permutation is
-the gather by its inverse); as `x[token]` and `.at[token].add(y)` their
-transposes were scatter-adds and the dispatch took 16% of a step where
-the matmuls it feeds took 3.4% (PERF.md section 6, PR 30).
+last; the sort is stable, so a group's rows keep token order) and the
+three matmuls are `jax.lax.ragged_dot` over the groups (XLA:TPU lowers
+it to a grouped matmul kernel), so their cost follows the rows actually
+routed here. The buffers around them do not follow anything: a row
+gather costs per row fetched, live or not. So they are sized by
+`capacity(share, n)`: the rows a share EXPECTS, k N x held / experts,
+times `CAPACITY_SLACK`, rounded up to `ROW_TILE`, never above k N.
+- Where the capacity is k N (a whole layer, any share of 2/3 or more)
+  there is one path and no branch, `_full_width`: rows gathered in
+  sorted order into [k N, hidden], rows past the last group masked to
+  zero and combined with weight 0. The sort is a permutation, so
+  dispatch and combine are GATHERS both ways (`_dispatch`, `_combine`:
+  the transpose of a gather by a permutation is the gather by its
+  inverse); as `x[token]` and `.at[token].add(y)` over k N rows their
+  transposes were scatter-adds and the dispatch took 16% of a step
+  where the matmuls it feeds took 3.4% (PERF.md section 6, PR 30).
+- Below that (`_routed`) a step that routes at most C = capacity rows
+  here takes `_compact`: the first C sorted assignments' tokens are
+  gathered into [C, hidden] and the matmuls, masks and weights run
+  over C rows. Combine (and dispatch's transpose) is STILL A GATHER:
+  each of the k N assignments fetches its row of the buffer, or the
+  row of zeros appended to it if it has none (`_summed_per_token`),
+  and a token's k rows are summed. k N fetches again, but all except
+  C of them fetch that one row, and the layer's pass took a third of
+  the full width's time on the chip; a scatter-add of the C rows took
+  as long, and XLA:TPU emits 2.9 MiB of code for each one, which the
+  device holds (PERF.md section 6, PR 33 has both readings). A step
+  that routes MORE than C rows here takes `_full_width` under a
+  `lax.cond` (`_overflow`): the same arithmetic over k N rows; nothing
+  is dropped, clipped or deferred.
+  The branch spans dispatch -> experts -> combine; the router, the
+  sort and its inverse stay outside it. `_routed` is a `custom_vjp`
+  that keeps only its inputs and branches again in its backward pass:
+  differentiating the `cond` itself makes each branch write zeros for
+  the other's residuals, the compact one the full path's [k N, .]
+  arrays. What the backward branch hands out is copied at once
+  (`_routed_bwd` says why).
+Either way a row past the last group reads zeros and its cotangent
+never reaches a token: `ragged_dot`'s transpose leaves those rows
+unwritten (the NaN of PR 30), so both sides of the gather are masked.
 
 THE SELECTION (`SELECTION`: the top-k ids, [N, k] int32) is the one
 value a block's recomputation keeps (`checkpoint_name`; the nets wrap
@@ -81,6 +110,17 @@ INIT_STD = 0.02          # every matrix: normal(0, 0.02); norms 1
 ROUTER_BIAS_STD = 0.1    # the fixed selection bias b: normal(0, 0.1)
 
 
+# Rows of buffer for each row a share expects (`capacity`). The cells'
+# forced selection puts a step's total within 1% of the expectation
+# (fullest expert 1.05-1.07 of the mean); a trained router with a bias
+# update spreads more, and a step that passes the capacity pays the
+# full width (`_routed`), so the slack buys the compact path a margin
+# at half its own cost in rows: at 1.5 a 16-way share's buffers are
+# 3/32 of the worst case.
+CAPACITY_SLACK = 1.5
+ROW_TILE = 128           # the grouped matmul's row tile: the MXU's side
+
+
 class ExpertShare(NamedTuple):
     """The sizes of one chip's share of an expert layer."""
     experts: int           # routed experts the router scores (all of them)
@@ -90,6 +130,24 @@ class ExpertShare(NamedTuple):
     norm_topk: bool        # divide the selected scores by their sum
     scale: float           # then multiply by this
     router_trains: bool    # False in a share without the exchange
+
+
+def capacity(share: ExpertShare, n: int) -> int:
+    """Rows of the routed path's buffers for n tokens: what the share
+    expects times `CAPACITY_SLACK`, in whole `ROW_TILE`s, and never
+    more than all k n assignments (the worst case, every selection
+    local; any share of 2/3 of the experts or more)."""
+    worst = share.top_k * n
+    tiles = math.ceil(
+        CAPACITY_SLACK * worst * share.held / share.experts / ROW_TILE)
+    return min(ROW_TILE * tiles, worst)
+
+
+def fits(rows: jax.Array, c: int) -> jax.Array:
+    """rows [..., held] routed to each held expert -> whether they fit
+    buffers of c rows: the routed path's branch, and what the family's
+    `moe_compact_share` counts."""
+    return rows.sum(axis=-1) <= c
 
 
 def _is_shape(x) -> bool:
@@ -212,6 +270,51 @@ def _combine_bwd(n, order, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _summed_per_token(y: jax.Array, back: jax.Array, n: int) -> jax.Array:
+    """y [C, h], the rows of a buffer -> [N, h]: each token's k
+    assignments' rows summed. `back` [k N]: an assignment's row in the
+    buffer, C for one that has none (it reads a row of zeros)."""
+    rows = jnp.concatenate([y, jnp.zeros((1, y.shape[1]), y.dtype)])
+    return rows[back].reshape(n, -1, y.shape[1]).sum(axis=1)
+
+
+@jax.custom_vjp
+def _dispatch_some(x: jax.Array, token: jax.Array, back: jax.Array
+                   ) -> jax.Array:
+    """x [N, h] -> [C, h]: row j is token `token[j]`."""
+    return x[token]
+
+
+def _dispatch_some_fwd(x, token, back):
+    return x[token], (back, x.shape[0])
+
+
+def _dispatch_some_bwd(res, g):
+    back, n = res
+    return _summed_per_token(g, back, n), None, None
+
+
+_dispatch_some.defvjp(_dispatch_some_fwd, _dispatch_some_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_some(y: jax.Array, token: jax.Array, back: jax.Array, n: int
+                  ) -> jax.Array:
+    """y [C, h] -> [N, h]: `_summed_per_token`."""
+    return _summed_per_token(y, back, n)
+
+
+def _combine_some_fwd(y, token, back, n):
+    return _summed_per_token(y, back, n), token
+
+
+def _combine_some_bwd(n, token, g):
+    return g[token], None, None
+
+
+_combine_some.defvjp(_combine_some_fwd, _combine_some_bwd)
+
+
 
 def route(p: dict, x: jax.Array, share: ExpertShare, balanced):
     """x [N, hidden] -> (top-k expert ids [N, k] int32, their
@@ -246,6 +349,121 @@ def route(p: dict, x: jax.Array, share: ExpertShare, balanced):
         return ids.astype(jnp.int32), w * share.scale
 
 
+def _inverse(order: jax.Array) -> jax.Array:
+    return jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32))
+
+
+def _experts(gathered: jax.Array, e: dict, rows: jax.Array, dt
+             ) -> jax.Array:
+    with jax.named_scope("glm.moe.experts"):
+        gate = jax.lax.ragged_dot(gathered, e["gate_proj"].astype(dt),
+                                  rows)
+        up = jax.lax.ragged_dot(gathered, e["up_proj"].astype(dt), rows)
+        return jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+                                  e["down_proj"].astype(dt), rows)
+
+
+def _full_width(flat, e, w, order, inverse, rows, dt) -> jax.Array:
+    """The routed experts' sum over [k N, hidden] buffers: flat
+    [N, hidden], w [N, k], order/inverse [k N], rows [held] ->
+    [N, hidden]."""
+    with jax.named_scope("glm.moe.dispatch"):
+        live = jnp.arange(order.shape[0]) < rows.sum()
+        # masked both ways: a row past the last group reads zeros,
+        # and whatever the grouped matmul's transpose leaves in its
+        # cotangent never reaches the token it was gathered from
+        gathered = jnp.where(live[:, None],
+                             _dispatch(flat, order, inverse), 0)
+        w_sorted = jnp.where(live, w.reshape(-1)[order], 0.0)
+    y = _experts(gathered, e, rows, dt)
+    with jax.named_scope("glm.moe.dispatch"):
+        # rows past the last group are whatever the kernel left
+        y = jnp.where(live[:, None], y, 0) * w_sorted[:, None].astype(dt)
+        return _combine(y, order, inverse, flat.shape[0])
+
+
+def _compact(c: int, dt, flat, e, w, order, inverse, rows) -> jax.Array:
+    """The same sum over [c, hidden] buffers, for a step whose
+    `rows.sum() <= c`: the live rows are the first of the sorted
+    order."""
+    with jax.named_scope("glm.moe.dispatch"):
+        head = order[:c]
+        token = head // w.shape[1]
+        live = jnp.arange(c) < rows.sum()
+        # an assignment sorted past the buffer has no row in it; one
+        # sorted past the last group has a row of zeros, masked below
+        back = jnp.minimum(inverse, c)
+        gathered = jnp.where(live[:, None],
+                             _dispatch_some(flat, token, back), 0)
+        w_sorted = jnp.where(live, w.reshape(-1)[head], 0.0)
+    y = _experts(gathered, e, rows, dt)
+    with jax.named_scope("glm.moe.dispatch"):
+        y = jnp.where(live[:, None], y, 0) * w_sorted[:, None].astype(dt)
+        return _combine_some(y, token, back, flat.shape[0])
+
+
+def _transposed(path, dt, g, flat, e, w, order, inverse, rows):
+    """Cotangents of (flat, the matrices once in `dt`, w)."""
+    return jax.vjp(lambda *primals: path(*primals, order, inverse, rows),
+                   flat, jax.tree.map(lambda m: m.astype(dt), e), w)[1](g)
+
+
+# The branch a fitting step never takes is traced once for each shape,
+# not once for each layer and net application (`jax.jit`: calls of one
+# function): tracing and lowering Trinity-Mini's `train_many` took
+# 9.0 s on the host without this, 7.1 with it and 6.75 before the
+# branch existed, and set-up is a metric. The price: in a step that
+# takes it, its ops carry the name stack of the call that traced them.
+@partial(jax.jit, static_argnums=0)
+def _overflow(dt, flat, e, w, order, inverse, rows):
+    return _full_width(flat, e, w, order, inverse, rows, dt)
+
+
+@partial(jax.jit, static_argnums=0)
+def _overflow_transposed(dt, g, flat, e, w, order, inverse, rows):
+    return _transposed(partial(_overflow, dt), dt, g, flat, e, w, order,
+                       inverse, rows)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(c: int, dt, flat, e, w, order, inverse, rows) -> jax.Array:
+    """The routed experts' sum [N, hidden] through buffers of c rows
+    when the step's rows fit them, of k N rows when they do not. `e`:
+    the parameters as they are stored; the cast to `dt` is each
+    branch's own (`_experts`), so that no copy of them is an operand."""
+    return jax.lax.cond(fits(rows, c), partial(_compact, c, dt),
+                        partial(_overflow, dt),
+                        flat, e, w, order, inverse, rows)
+
+
+def _routed_fwd(c, dt, flat, e, w, order, inverse, rows):
+    return (_routed(c, dt, flat, e, w, order, inverse, rows),
+            (flat, e, w, order, inverse, rows))
+
+
+def _routed_bwd(c, dt, res, g):
+    rows = res[-1]
+    d_flat, d_e, d_w = jax.lax.optimization_barrier(jax.lax.cond(
+        fits(rows, c), partial(_transposed, partial(_compact, c, dt), dt),
+        partial(_overflow_transposed, dt), g, *res))
+    # A conditional's results are held apart from the arrays XLA:TPU
+    # packs into one heap for as long as they live, and the matrices'
+    # gradients live until the optimizer reads them: compiled for a
+    # v5e they cost GLM-4.7-Flash's share 0.47 GiB and Trinity-Mini's
+    # 0.44 (PERF.md section 6, PR 33). So they are copied out at once,
+    # by a select the compiler cannot see through, and stay in `dt`
+    # until then (without the barriers the select and the cast to
+    # float32 move into the branches: twice the bytes, held as long).
+    d_e = jax.lax.optimization_barrier(jax.tree.map(
+        lambda d: jnp.where(rows.sum() >= 0, d, 0), d_e))
+    return (d_flat, jax.tree.map(lambda d, m: d.astype(m.dtype), d_e,
+                                 res[1]), d_w, None, None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
 def expert_ffn(p: dict, x: jax.Array, dt, share: ExpertShare,
                balanced=None):
     """x [B, T, hidden] -> (FFN(x), rows routed to each held expert
@@ -258,33 +476,21 @@ def expert_ffn(p: dict, x: jax.Array, dt, share: ExpertShare,
     flat = x.reshape(n, h)
     ids, w = route(
         p, flat, share, None if balanced is None else balanced.reshape(n, -1))
+    c = capacity(share, n)
     with jax.named_scope("glm.moe.dispatch"):
         local = ids.reshape(-1) - share.first                # [k N]
         here = (local >= 0) & (local < held)
         slot = jnp.where(here, local, held)    # not held: sorts last
         order = jnp.argsort(slot, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n * k, dtype=jnp.int32))
+        inverse = _inverse(order)
         rows = jnp.bincount(slot, length=held + 1)[:held].astype(
             jnp.int32)
-        live = jnp.arange(n * k) < rows.sum()
-        # masked both ways: a row past the last group reads zeros,
-        # and whatever the grouped matmul's transpose leaves in its
-        # cotangent never reaches the token it was gathered from
-        gathered = jnp.where(live[:, None],
-                             _dispatch(flat, order, inverse), 0)
-        w_sorted = jnp.where(live, w.reshape(-1)[order], 0.0)
-    with jax.named_scope("glm.moe.experts"):
-        e = p["experts"]
-        gate = jax.lax.ragged_dot(gathered, e["gate_proj"].astype(dt),
-                                  rows)
-        up = jax.lax.ragged_dot(gathered, e["up_proj"].astype(dt), rows)
-        y = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
-                               e["down_proj"].astype(dt), rows)
-    with jax.named_scope("glm.moe.dispatch"):
-        # rows past the last group are whatever the kernel left
-        y = jnp.where(live[:, None], y, 0) * w_sorted[:, None].astype(dt)
-        routed = _combine(y, order, inverse, n)
+    if c == n * k:
+        routed = _full_width(flat, p["experts"], w, order, inverse, rows,
+                             dt)
+    else:
+        routed = _routed(c, dt, flat, p["experts"], w, order, inverse,
+                         rows)
     with jax.named_scope("glm.moe.shared"):
         shared = _swiglu(flat, p["shared_experts"], dt)
     return (routed + shared).reshape(b, t, h), rows, ids.reshape(b, t, k)

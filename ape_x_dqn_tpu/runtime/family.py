@@ -261,22 +261,29 @@ def decoder_q_family(net: Any, lcfg, rcfg):
     layers and the four applications (one forward each); `moe_rows_grad`
     those of the online net's trained steps, which also pay a
     recomputation and a backward; `moe_load_max_over_mean` the fullest
-    held expert over the mean, online net, mean over layers. The same
+    held expert over the mean, online net, mean over layers;
+    `moe_compact_share` of the step's expert-layer applications (layers
+    x the loss's net applications) the share whose rows fit the layer's
+    buffers (`expert_layer.capacity`; 1.0 where that is the full
+    width): under 1.0 a step paid the full width somewhere. The same
     way the aux hands back what the loss saw and chose — `q` the online
     net's Q-values on the trained steps, `topk_online`/`topk_target`
     [expert layers, B, L, k] the experts selected — for whoever
     differentiates this function to hold it to a reference (the
     benchmark's check); a train step reads none of the three and XLA
     drops them there."""
+    from ape_x_dqn_tpu.models.expert_layer import capacity, fits
     from ape_x_dqn_tpu.ops.losses import SequenceBatch, make_r2d2_loss
     from ape_x_dqn_tpu.runtime.learner import LearnerFamily
 
     def loss_fn(params, target_params, batch, is_weights):
-        tally, seen = [], []
+        tally, fitted, seen = [], [], []
 
         def apply(p, tokens, state):
             q, state, stats = net.apply_with_stats(p, tokens, state)
             tally.append(stats["expert_rows"].astype(jnp.float32))
+            fitted.append(fits(stats["expert_rows"],
+                               capacity(net.share, tokens.size)))
             seen.append((q, stats["topk"]))
             return q, state
 
@@ -297,6 +304,9 @@ def decoder_q_family(net: Any, lcfg, rcfg):
                "moe_load_max_over_mean": (
                    (load.max(axis=-1) / mean).mean() if load.size
                    else jnp.float32(1.0)),
+               "moe_compact_share": (
+                   jnp.concatenate(fitted).mean(dtype=jnp.float32)
+                   if load.size else jnp.float32(1.0)),
                "q": seen[-2][0],
                # prefix then trained steps, along the sequence
                "topk_online": jnp.concatenate(
@@ -315,7 +325,7 @@ def decoder_q_family(net: Any, lcfg, rcfg):
         net_apply=net.apply,
         apply_attr="net_apply_seq",
         metric_keys=("valid_frac", "moe_rows", "moe_rows_grad",
-                     "moe_load_max_over_mean"))
+                     "moe_load_max_over_mean", "moe_compact_share"))
 
 
 # family name -> (cfg, net) -> LearnerFamily. DPG is not a row: its

@@ -134,6 +134,38 @@ def test_loss_and_gradients_match_reference_float32(shards, index, balanced):
     assert bool(np.any(moe["gate"])) == (shards == 1)
 
 
+@pytest.mark.parametrize("selection", ["forced", "own"])
+def test_compact_share_says_which_steps_paid_the_full_width(selection):
+    """tests/test_glm_moe_q.py's case through this net: 16 sequences,
+    the trained segment's 640 assignments in buffers of 512 rows."""
+    from ape_x_dqn_tpu.models.expert_layer import capacity
+    from tests.test_glm_moe_q import big_batch, selecting_only_held
+
+    count = 16
+    cfg = tiny(balanced=selection == "forced")
+    net, params = net_and_params(cfg)
+    _, target = net_and_params(cfg, seed=5)
+    if selection == "own":
+        params = selecting_only_held(params, net)
+        target = selecting_only_held(target, net)
+    k = net.share.top_k
+    assert capacity(net.share, count * (L - BURN)) < k * count * (L - BURN)
+    assert capacity(net.share, count * BURN) == k * count * BURN
+    items = big_batch(cfg, count, L)
+    w = jnp.ones(count)
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        system_loss(cfg, net), has_aux=True))(params, target, items, w)
+    (want, raux), rgrads = jax.jit(
+        lambda p, t: reference_loss(cfg, net, p, t, items, w))(params, target)
+    np.testing.assert_allclose(loss, want, atol=1e-5)
+    np.testing.assert_allclose(aux["td_abs"], raux["priorities"], atol=1e-5)
+    rgrads = afmoe_params.system_gradients(rgrads)
+    for got, exp in zip(jax.tree.leaves(grads), jax.tree.leaves(rgrads)):
+        np.testing.assert_allclose(got, exp, atol=1e-5)
+    assert float(aux["moe_compact_share"]) == (
+        1.0 if selection == "forced" else 0.5)
+
+
 def test_bfloat16_stays_in_a_stated_band():
     """bfloat16 compute against the float32 reference forced to the
     system's selection: Q within 6% of its spread (8 bits of mantissa
@@ -339,7 +371,7 @@ def test_apexdriver_builds_and_trains():
         state, m = driver.learner.train_many(state, 2)
         assert int(state.step) == 2 and np.isfinite(float(m["loss"]))
         for key in ("valid_frac", "moe_rows", "moe_rows_grad",
-                    "moe_load_max_over_mean"):
+                    "moe_load_max_over_mean", "moe_compact_share"):
             assert np.isfinite(float(m[key])), key
         after = jax.device_get(state.params["layers"][1])
         assert not np.array_equal(before["q_proj"], after["q_proj"])

@@ -148,6 +148,75 @@ def test_loss_and_gradients_match_reference_float32(balanced):
     assert 0 < float(aux["moe_rows_grad"]) < float(aux["moe_rows"])
 
 
+def big_batch(cfg, count: int, length: int = L, seed: int = 3) -> dict:
+    """`count` whole sequences: enough tokens for a half share's
+    capacity to lie below its worst case (models/expert_layer.py)."""
+    rng = np.random.default_rng(seed)
+    v = cfg.env.num_tokens
+    shape = (count, length)
+    return {"obs": rng.integers(0, v, shape).astype(np.int32),
+            "actions": rng.integers(0, v, shape).astype(np.int32),
+            "rewards": (rng.integers(0, 4, shape) == 0).astype(np.float32),
+            "terminals": np.zeros(shape, np.float32),
+            "mask": np.ones(shape, np.float32)}
+
+
+def selecting_only_held(params: dict, net) -> dict:
+    """The same parameters with a selection bias under which every
+    token's top-k are experts this share holds."""
+    first, held = net.share.first, net.share.held
+    ids = np.arange(net.share.experts)
+    b = jnp.asarray(np.where((ids >= first) & (ids < first + held),
+                             10.0, -10.0), jnp.float32)
+    layers = [
+        {**p, "mlp": {**p["mlp"], "e_score_correction_bias": b}}
+        if "experts" in p["mlp"] else p for p in params["layers"]]
+    return {**params, "layers": layers}
+
+
+@pytest.mark.parametrize("selection", ["forced", "own"])
+def test_compact_share_says_which_steps_paid_the_full_width(selection):
+    """32 sequences through a half share: the trained segment's 768
+    assignments have buffers of 640 rows. Under the forced selection
+    half of them land here and every application fits (1.0); under a
+    selection that sends every token here the trained segments overflow
+    and take the full width (0.5: the prefix passes' capacity IS their
+    full width) - with the loss and the priorities the reference's
+    either way."""
+    from ape_x_dqn_tpu.models.expert_layer import capacity
+
+    count = 32
+    cfg = tiny(balanced=selection == "forced")
+    net, params = net_and_params(cfg)
+    target = net.init(jax.random.PRNGKey(7))
+    if selection == "own":
+        params = selecting_only_held(params, net)
+        target = selecting_only_held(target, net)
+    trained, prefix = count * (L - BURN), count * BURN
+    k = net.share.top_k
+    assert capacity(net.share, trained) < k * trained
+    assert capacity(net.share, prefix) == k * prefix
+    items, w = big_batch(cfg, count), np.ones(count, np.float32)
+    family, loss_fn = system_loss(cfg, net)
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        loss_fn, has_aux=True))(params, target, items, w)
+    (want, want_aux), want_grads = reference_loss(cfg, params, target,
+                                                  items, w)
+    assert float(loss) == pytest.approx(float(want), abs=1e-5)
+    np.testing.assert_allclose(aux["td_abs"], want_aux["priorities"],
+                               atol=1e-5)
+    back = glm_params.system_gradients(want_grads)
+    for got, exp in zip(jax.tree.leaves(grads), jax.tree.leaves(back)):
+        np.testing.assert_allclose(got, exp, atol=1e-5)
+    share = float(aux["moe_compact_share"])
+    if selection == "forced":
+        assert share == 1.0
+    else:
+        layers = sum("experts" in p["mlp"] for p in params["layers"])
+        assert float(aux["moe_rows"]) == layers * 2 * k * count * L
+        assert share == 0.5
+
+
 def test_router_trains_when_the_layer_is_whole():
     cfg = tiny(shards=1)
     net, params = net_and_params(cfg)
@@ -418,7 +487,7 @@ def test_apexdriver_builds_and_trains():
         state, m = driver.learner.train_many(state, 2)
         assert int(state.step) == 2 and np.isfinite(float(m["loss"]))
         for key in ("valid_frac", "moe_rows", "moe_rows_grad",
-                    "moe_load_max_over_mean"):
+                    "moe_load_max_over_mean", "moe_compact_share"):
             assert np.isfinite(float(m[key])), key
         after = jax.device_get(state.params["layers"][1]["mlp"])
         # Adam moved the experts; the selection bias and, in a share,
