@@ -495,7 +495,7 @@ def _fmt_spans(spans: dict[str, dict]) -> list[str]:
         count = int(s.get("count", 0))
         total = float(s.get("total_s", 0.0))
         mean_ms = total / count * 1e3 if count else 0.0
-        tag = " (fused)" if total == 0.0 and count else ""
+        tag = " (mark)" if total == 0.0 and count else ""
         lines.append(
             f"  {name:<28} {count:>8} {total:>9.3f} {mean_ms:>9.3f} "
             f"{float(s.get('max_s', 0.0)) * 1e3:>9.3f} "

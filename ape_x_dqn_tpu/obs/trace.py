@@ -26,11 +26,14 @@ Design constraints:
   wait) into the aggregates and the JSON; it has no single thread to
   annotate, so it carries no profiler annotation.
 - Fused stages: stages that execute INSIDE one XLA dispatch (the
-  priority write-back and target sync live inside the learn jit)
-  cannot be timed from the host; `mark()` emits a zero-ish-duration
-  event with `args["fused_into"]` naming the enclosing dispatch, so
-  the trace still shows *when* they happened and *that* they are
-  fused.
+  draw, the loss, the optimizer, the target sync, the health norms and
+  the priority write-back all live inside the train jit) cannot be
+  timed from the host and get no event here. They show on the DEVICE
+  plane of a `jax.profiler` trace: every op carries the
+  `jax.named_scope` of its stage (`cycle.sample` ... `cycle.write_back`,
+  runtime/learner.py::CYCLE_SCOPES), under the host's
+  `apex.learner.train` span on the same clock. `mark()` is for a host
+  event with no duration of its own (`actor.ship`).
 - Stage aggregates: every span also folds into a per-name
   (count, total_s, max_s) table so the JSONL stream can carry a
   stage-time breakdown (obs/report.py) without parsing the trace file.
@@ -168,10 +171,11 @@ class SpanTracer:
         self._record(name, t0, t1, args)
 
     def mark(self, name: str, **args: Any) -> None:
-        """Instant-ish event for a stage fused inside a device dispatch
-        (1us nominal duration so 'X' renderers still draw it)."""
+        """Instant-ish event: something that happened on this thread and
+        has no duration of its own (1us nominal so 'X' renderers still
+        draw it)."""
         t = time.perf_counter()
-        self._record(name, t, t + 1e-6, args, fused=True)
+        self._record(name, t, t + 1e-6, args, mark=True)
 
     def remote_span(self, name: str, dur_s: float, age_s: float = 0.0,
                     peer: str = "", **args: Any) -> None:
@@ -195,7 +199,7 @@ class SpanTracer:
         self._record(name, t0, t1, dict(args, peer=peer), tid=tid)
 
     def _record(self, name: str, t0: float, t1: float, args: dict,
-                fused: bool = False, tid: int | None = None) -> None:
+                mark: bool = False, tid: int | None = None) -> None:
         local = tid is None
         if local:
             tid = threading.get_ident()
@@ -207,7 +211,7 @@ class SpanTracer:
             if a is None:
                 a = self._agg[name] = [0, 0.0, 0.0]
             a[0] += 1
-            if not fused:  # marks carry no host-measurable duration
+            if not mark:  # marks carry no host-measurable duration
                 a[1] += dur
                 a[2] = max(a[2], dur)
             if len(self._ev_name) >= self._max:

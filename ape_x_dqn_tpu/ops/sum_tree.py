@@ -46,6 +46,14 @@ def leaves(tree: jax.Array) -> jax.Array:
     return tree[capacity_of(tree):]
 
 
+# the tree's two passes as `jax.named_scope`s (op metadata only). Both
+# nest in runtime/learner.py::CYCLE_SCOPES inside a learner's cycle:
+# the descent under `cycle.sample`, the learner's update under
+# `cycle.write_back`; an update under no `cycle.*` name is an ingest add
+DESCENT_SCOPE, UPDATE_SCOPE = "sum_tree.descent", "sum_tree.update"
+
+
+@jax.named_scope(UPDATE_SCOPE)
 def update(tree: jax.Array, leaf_idx: jax.Array,
            priorities: jax.Array) -> jax.Array:
     """Set priorities at leaf_idx ([B] int32) and repair ancestor sums."""
@@ -82,6 +90,7 @@ def chunk_major(x: jax.Array, chunks: int) -> jax.Array:
                      *x.shape[1:]).swapaxes(0, 1).reshape(x.shape)
 
 
+@jax.named_scope(DESCENT_SCOPE)
 def sample(tree: jax.Array, rng: jax.Array, batch: int,
            size: jax.Array | None = None,
            chunks: int = 1) -> tuple[jax.Array, jax.Array]:

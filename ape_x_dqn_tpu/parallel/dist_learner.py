@@ -49,7 +49,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ape_x_dqn_tpu.obs import learning as learn_obs
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay, ReplayState
 from ape_x_dqn_tpu.parallel.sharding import make_param_shardings
-from ape_x_dqn_tpu.runtime.learner import LearnerFamily, SingleChipLearner
+from ape_x_dqn_tpu.runtime.learner import (
+    BATCH, HEALTH, SAMPLE, LearnerFamily, SingleChipLearner)
 
 
 class DistTrainState(NamedTuple):
@@ -182,19 +183,23 @@ class DistLearner(SingleChipLearner):
         its own draws. The loss runs on the flattened batch under the
         dp sharding constraint (GSPMD emits the gradient psum); the
         |TD|s go back per shard."""
-        w = w / jnp.maximum(w.max(), 1e-12)
-        batch = self.family.make_batch(jax.tree.map(self._flat, items))
+        with jax.named_scope(BATCH):
+            w = w / jnp.maximum(w.max(), 1e-12)
+            batch = self.family.make_batch(
+                jax.tree.map(self._flat, items))
+            w = self._flat(w)
         params, target_params, opt_state, step, td_abs, metrics = \
             self._sgd_update(params, target_params, opt_state, step,
-                             batch, self._flat(w), want_tree_diag)
-        td_shard = td_abs.reshape(self.dp, self.b_local)
-        # the flat reductions inside _sgd_update's diag run over the
-        # [dp]-sharded batch, so GSPMD lowers them to the psum'd GLOBAL
-        # statistics; the per-shard mean-|TD| min/max exposes shard
-        # skew the global mean would average away
-        shard_means = td_shard.mean(axis=1)
-        metrics["diag"]["shard_td_mean_min"] = shard_means.min()
-        metrics["diag"]["shard_td_mean_max"] = shard_means.max()
+                             batch, w, want_tree_diag)
+        with jax.named_scope(HEALTH):
+            td_shard = td_abs.reshape(self.dp, self.b_local)
+            # the flat reductions inside _sgd_update's diag run over the
+            # [dp]-sharded batch, so GSPMD lowers them to the psum'd
+            # GLOBAL statistics; the per-shard mean-|TD| min/max exposes
+            # shard skew the global mean would average away
+            shard_means = td_shard.mean(axis=1)
+            metrics["diag"]["shard_td_mean_min"] = shard_means.min()
+            metrics["diag"]["shard_td_mean_max"] = shard_means.max()
         return params, target_params, opt_state, step, td_shard, metrics
 
     def _replay_health(self, replay_state: ReplayState, idx, pri_then):
@@ -207,6 +212,7 @@ class DistLearner(SingleChipLearner):
         return jax.vmap(self.replay.update_priorities)(
             replay_state, idx, jnp.concatenate(td_parts, axis=1))
 
+    @jax.named_scope(SAMPLE)
     def _sample_stage(self, replay_state: ReplayState, sk, k: int):
         """Pure SAMPLE stage of the split K-batch cycle, dist form of
         SingleChipLearner._sample_stage: one per-shard stratified

@@ -23,6 +23,8 @@ from ape_x_dqn_tpu.models.base import soft_update
 from ape_x_dqn_tpu.obs import learning as learn_obs
 from ape_x_dqn_tpu.ops.losses import ContinuousBatch, make_dpg_losses
 from ape_x_dqn_tpu.replay.prioritized import ReplayState
+from ape_x_dqn_tpu.runtime.learner import (
+    BATCH, HEALTH, LOSS_GRAD, OPTIMIZER, SAMPLE, TARGET_SYNC, WRITE_BACK)
 
 
 class DPGTrainState(NamedTuple):
@@ -100,48 +102,61 @@ class DPGLearner:  # apexlint: parity(no train_step_k/sample_k/learn_k — K-chu
     def _train_step(self, state: DPGTrainState
                     ) -> tuple[DPGTrainState, dict]:
         rng, sk = jax.random.split(state.rng)
-        items, idx, is_w = self.replay.sample(
-            state.replay, sk, self.lcfg.batch_size)
-        batch = ContinuousBatch(
-            obs=items["obs"], actions=items["action"],
-            rewards=items["reward"], next_obs=items["next_obs"],
-            discounts=items["discount"])
+        with jax.named_scope(SAMPLE):
+            items, idx, is_w = self.replay.sample(
+                state.replay, sk, self.lcfg.batch_size)
+        with jax.named_scope(BATCH):
+            batch = ContinuousBatch(
+                obs=items["obs"], actions=items["action"],
+                rewards=items["reward"], next_obs=items["next_obs"],
+                discounts=items["discount"])
 
-        (c_loss, c_aux), c_grads = jax.value_and_grad(
-            self.critic_loss, has_aux=True)(
-            state.critic_params, state.target_critic, state.target_actor,
-            batch, is_w)
-        c_updates, critic_opt = self.critic_optimizer.update(
-            c_grads, state.critic_opt, state.critic_params)
-        critic_params = optax.apply_updates(state.critic_params, c_updates)
+        with jax.named_scope(LOSS_GRAD):
+            (c_loss, c_aux), c_grads = jax.value_and_grad(
+                self.critic_loss, has_aux=True)(
+                state.critic_params, state.target_critic,
+                state.target_actor, batch, is_w)
+        with jax.named_scope(OPTIMIZER):
+            c_updates, critic_opt = self.critic_optimizer.update(
+                c_grads, state.critic_opt, state.critic_params)
+            critic_params = optax.apply_updates(state.critic_params,
+                                                c_updates)
 
         # policy ascends the UPDATED critic (standard DDPG ordering)
-        (p_loss, p_aux), p_grads = jax.value_and_grad(
-            self.policy_loss, has_aux=True)(
-            state.actor_params, critic_params, batch)
-        p_updates, actor_opt = self.actor_optimizer.update(
-            p_grads, state.actor_opt, state.actor_params)
-        actor_params = optax.apply_updates(state.actor_params, p_updates)
+        with jax.named_scope(LOSS_GRAD):
+            (p_loss, p_aux), p_grads = jax.value_and_grad(
+                self.policy_loss, has_aux=True)(
+                state.actor_params, critic_params, batch)
+        with jax.named_scope(OPTIMIZER):
+            p_updates, actor_opt = self.actor_optimizer.update(
+                p_grads, state.actor_opt, state.actor_params)
+            actor_params = optax.apply_updates(state.actor_params,
+                                               p_updates)
 
-        tau = self.lcfg.tau
-        target_actor = soft_update(state.target_actor, actor_params, tau)
-        target_critic = soft_update(state.target_critic, critic_params, tau)
+        with jax.named_scope(TARGET_SYNC):
+            tau = self.lcfg.tau
+            target_actor = soft_update(state.target_actor, actor_params,
+                                       tau)
+            target_critic = soft_update(state.target_critic,
+                                        critic_params, tau)
 
-        replay_state = self.replay.update_priorities(
-            state.replay, idx, c_aux["td_abs"])
-        metrics = {
-            "loss": c_loss,
-            "policy_loss": p_loss,
-            "q_mean": c_aux["q_mean"],
-            "td_abs_mean": c_aux["td_abs"].mean(),
-            "a_abs_mean": p_aux["a_abs_mean"],
-            # learning-health scalars over the CRITIC update (the TD
-            # learner); fused path, so staleness is identically 0
-            "diag": {**learn_obs.sgd_diag(c_aux, is_w, c_grads,
-                                          c_updates, critic_params),
-                     **learn_obs.replay_health(
-                         self.replay, state.replay, idx, None)},
-        }
+        with jax.named_scope(WRITE_BACK):
+            replay_state = self.replay.update_priorities(
+                state.replay, idx, c_aux["td_abs"])
+        with jax.named_scope(HEALTH):
+            metrics = {
+                "loss": c_loss,
+                "policy_loss": p_loss,
+                "q_mean": c_aux["q_mean"],
+                "td_abs_mean": c_aux["td_abs"].mean(),
+                "a_abs_mean": p_aux["a_abs_mean"],
+                # learning-health scalars over the CRITIC update (the TD
+                # learner); fused path, so staleness is identically 0
+                "diag": {**learn_obs.sgd_diag(c_aux, is_w, c_grads,
+                                              c_updates, critic_params),
+                         **learn_obs.replay_health(
+                             self.replay, state.replay, idx, None)},
+            }
         new_state = DPGTrainState(
             actor_params, critic_params, target_actor, target_critic,
             actor_opt, critic_opt, replay_state, rng, state.step + 1)

@@ -1511,7 +1511,6 @@ class ApexDriver:
         last_log = 0
         last_ckpt = self._grad_steps_total
         cap = self.cfg.learner.steps_per_frame_cap
-        sync_every = self.cfg.learner.target_sync_every
         m = None
         while (not self.stop_event.is_set()
                and self._grad_steps_total < max_grad_steps):
@@ -1566,14 +1565,6 @@ class ApexDriver:
             self._grad_steps_total += k
             self.grad_steps.add(k)
             self.obs.set_learner_step(self._grad_steps_total)
-            # sampling + priority write-back + (boundary permitting) the
-            # target sync are fused inside the train jit: mark, don't span
-            self.obs.mark("replay.sample", fused_into="learner.train")
-            self.obs.mark("replay.priority_update",
-                          fused_into="learner.train")
-            if done // sync_every != self._grad_steps_total // sync_every:
-                self.obs.mark("learner.target_sync",
-                              fused_into="learner.train")
             if done // publish_every != self._grad_steps_total // publish_every:
                 self._publish_params()
             if (self.ckpt is not None and self._grad_steps_total - last_ckpt

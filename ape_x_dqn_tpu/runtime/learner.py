@@ -97,6 +97,34 @@ def applied_update(lcfg, opt_state):
         mu_hat, nu_hat)
 
 
+# The cycle's own parts as `jax.named_scope`s: seven disjoint names that
+# tile a grad step, each opened at the one place its stage is entered.
+# Op metadata only (the lowered program is the same text without debug
+# info), so they cost nothing on the device; in a `jax.profiler` trace
+# every device op carries the name of its stage, and
+# benchmarks/harness/cycle_scopes.py sums self time by name. What a
+# trace shows under none (the scans' own `while` time, `_split_rng`,
+# copies XLA inserts without metadata) is the account's residual.
+# ops/sum_tree.py nests `sum_tree.descent` / `sum_tree.update` inside
+# the first and the last. A learner that overrides a stage opens the
+# SAME name from this tuple (parallel/dist_learner.py,
+# runtime/dpg_learner.py). The persistent compile cache's key leaves
+# debug info out: an executable cached before a scope was added or
+# renamed is loaded again and shows the OLD names, so empty the cache
+# before profiling such an edit (README "Observability").
+CYCLE_SCOPES = (
+    "cycle.sample",       # descent, storage gather, IS weights, K-split
+    "cycle.batch",        # items -> the family's batch
+    "cycle.loss_grad",    # the family's loss, forward and backward
+    "cycle.optimizer",    # optimizer.update + apply_updates
+    "cycle.target_sync",  # the cond / select over the target tree
+    "cycle.health",       # what the metrics inside the step cost
+    "cycle.write_back",   # the one priority write-back
+)
+(SAMPLE, BATCH, LOSS_GRAD, OPTIMIZER, TARGET_SYNC, HEALTH,
+ WRITE_BACK) = CYCLE_SCOPES
+
+
 # Parameter bytes from which the SGD tail branches (a `lax.cond` for the
 # target sync and for the tree-sized health norms) instead of passing
 # over the trees on every step. A branch is not free: it ends Adam's
@@ -190,44 +218,50 @@ class SingleChipLearner:
         scans) where they are dropped. Under it the sync is a select
         and a traced flag counts as True: the passes are cheaper than
         the branches."""
-        (loss, aux), grads = jax.value_and_grad(
-            self.family.loss_fn, has_aux=True)(
-            params, target_params, batch, w)
-        updates, opt_state = self.optimizer.update(
-            grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        step = step + 1
+        with jax.named_scope(LOSS_GRAD):
+            (loss, aux), grads = jax.value_and_grad(
+                self.family.loss_fn, has_aux=True)(
+                params, target_params, batch, w)
+        with jax.named_scope(OPTIMIZER):
+            updates, opt_state = self.optimizer.update(
+                grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         # hard target sync every K steps (SURVEY.md §3.3). On a big tree
         # a branch, not a select: `where` read two trees and wrote one on
         # every step (7.1 GB, 9 ms of glm47_flash_offline's 275) to sync
         # 1 in 2,500
-        sync = (step % self.lcfg.target_sync_every == 0)
-        if sum(x.nbytes for x in jax.tree.leaves(params)) \
-                >= TAIL_BRANCH_MIN_BYTES:
-            target_params = jax.lax.cond(
-                sync, lambda t, p: p, lambda t, p: t,
-                target_params, params)
-        else:
-            target_params = jax.tree.map(
-                lambda t, p: jnp.where(sync, p, t), target_params, params)
-            want_tree_diag = want_tree_diag is not False
-        # the one gradient norm: the clip's is this expression too
-        grad_norm = optax.global_norm(grads)
-        metrics = {
-            "loss": loss,
-            "q_mean": aux["q_mean"],
-            "td_abs_mean": aux["td_abs"].mean(),
-            **{key: aux[key] for key in self.family.metric_keys},
-            "grad_norm": grad_norm,
-            # learning-health scalars (obs/learning.py); rides the
-            # metrics pytree through every scan, read at existing
-            # host sync points only. The update is rebuilt from the new
-            # opt_state: `updates` itself never outlives the apply
-            "diag": learn_obs.sgd_diag(
-                aux, w, grads, opt_state, params, grad_norm=grad_norm,
-                want_tree_diag=want_tree_diag,
-                update_of=partial(applied_update, self.lcfg)),
-        }
+        with jax.named_scope(TARGET_SYNC):
+            step = step + 1
+            sync = (step % self.lcfg.target_sync_every == 0)
+            if sum(x.nbytes for x in jax.tree.leaves(params)) \
+                    >= TAIL_BRANCH_MIN_BYTES:
+                target_params = jax.lax.cond(
+                    sync, lambda t, p: p, lambda t, p: t,
+                    target_params, params)
+            else:
+                target_params = jax.tree.map(
+                    lambda t, p: jnp.where(sync, p, t),
+                    target_params, params)
+                want_tree_diag = want_tree_diag is not False
+        with jax.named_scope(HEALTH):
+            # the one gradient norm: the clip's is this expression too
+            grad_norm = optax.global_norm(grads)
+            metrics = {
+                "loss": loss,
+                "q_mean": aux["q_mean"],
+                "td_abs_mean": aux["td_abs"].mean(),
+                **{key: aux[key] for key in self.family.metric_keys},
+                "grad_norm": grad_norm,
+                # learning-health scalars (obs/learning.py); rides the
+                # metrics pytree through every scan, read at existing
+                # host sync points only. The update is rebuilt from the
+                # new opt_state: `updates` itself never outlives the
+                # apply
+                "diag": learn_obs.sgd_diag(
+                    aux, w, grads, opt_state, params,
+                    grad_norm=grad_norm, want_tree_diag=want_tree_diag,
+                    update_of=partial(applied_update, self.lcfg)),
+            }
         return params, target_params, opt_state, step, aux["td_abs"], \
             metrics
 
@@ -235,9 +269,10 @@ class SingleChipLearner:
                   items, is_w, want_tree_diag=True):
         """One SGD step on already-sampled items (shared by the exact
         per-step path and the K-batch relaxation)."""
+        with jax.named_scope(BATCH):
+            batch = self.family.make_batch(items)
         return self._sgd_update(params, target_params, opt_state, step,
-                                self.family.make_batch(items), is_w,
-                                want_tree_diag)
+                                batch, is_w, want_tree_diag)
 
     def _replay_health(self, replay_state: ReplayState, idx, pri_then):
         return learn_obs.replay_health(self.replay, replay_state, idx,
@@ -254,22 +289,27 @@ class SingleChipLearner:
     def _train_step(self, state: TrainState,
                     want_tree_diag=True) -> tuple[TrainState, dict]:
         rng, sk = self._split_rng(state.rng)
-        items, idx, w = self._sample_weighted(state.replay, sk,
-                                              self.b_local)
+        with jax.named_scope(SAMPLE):
+            items, idx, w = self._sample_weighted(state.replay, sk,
+                                                  self.b_local)
         params, target_params, opt_state, step, td_abs, metrics = \
             self._sgd_step(state.params, state.target_params,
                            state.opt_state, state.step, items, w,
                            want_tree_diag)
         # fused path: draw and write-back see the same tree, so the
         # priority-staleness delta is identically 0 (pri_then=None)
-        metrics["diag"] = {**metrics.get("diag", {}),
-                           **self._replay_health(state.replay, idx, None)}
-        replay_state = self._write_back(state.replay, idx, [td_abs])
+        with jax.named_scope(HEALTH):
+            metrics["diag"] = {
+                **metrics.get("diag", {}),
+                **self._replay_health(state.replay, idx, None)}
+        with jax.named_scope(WRITE_BACK):
+            replay_state = self._write_back(state.replay, idx, [td_abs])
         new_state = state._replace(
             params=params, target_params=target_params,
             opt_state=opt_state, replay=replay_state, rng=rng, step=step)
         return new_state, metrics
 
+    @jax.named_scope(SAMPLE)
     def _sample_stage(self, replay_state: ReplayState, sk: jax.Array,
                       k: int):
         """Pure SAMPLE stage of the (split) K-batch cycle: one
@@ -339,19 +379,23 @@ class SingleChipLearner:
         td_parts = []
         metrics = None
         for j in range(k):
-            it = jax.tree.map(lambda x: x[j], items_k)
+            with jax.named_scope(BATCH):
+                it, w = jax.tree.map(lambda x: x[j], (items_k, w_k))
             params, target_params, opt_state, step, td_abs, metrics = \
                 self._sgd_step(params, target_params, opt_state, step,
-                               it, w_k[j],
+                               it, w,
                                want_tree_diag if j == k - 1 else False)
             td_parts.append(td_abs)
         # write-back-time replay health: state.replay's tree is what
         # the sampler would see NOW, pri is what it saw at descent
         # time — their delta is the measured priority staleness the
         # prefetch/K-batch relaxations accept (ROADMAP item 3)
-        metrics["diag"] = {**metrics.get("diag", {}),
-                           **self._replay_health(state.replay, idx, pri)}
-        replay_state = self._write_back(state.replay, idx, td_parts)
+        with jax.named_scope(HEALTH):
+            metrics["diag"] = {
+                **metrics.get("diag", {}),
+                **self._replay_health(state.replay, idx, pri)}
+        with jax.named_scope(WRITE_BACK):
+            replay_state = self._write_back(state.replay, idx, td_parts)
         new_state = state._replace(
             params=params, target_params=target_params,
             opt_state=opt_state, replay=replay_state, step=step)

@@ -716,10 +716,6 @@ class MultihostApexDriver:
                             loss = float(m["loss"])  # blocks: honest timing
                     self._grad_steps += k
                     self.obs.set_learner_step(self._grad_steps)
-                    self.obs.mark("replay.sample",
-                                  fused_into="learner.train")
-                    self.obs.mark("replay.priority_update",
-                                  fused_into="learner.train")
                     progressed = True
                     if done // publish_every != \
                             self._grad_steps // publish_every:

@@ -135,7 +135,9 @@ def train_single_process(cfg: RunConfig, total_env_frames: int | None = None,
         sees replay.sample and learner.learn as real host spans —
         block_until_ready inside each span keeps the timing honest
         against jax's async dispatch. Priority write-back and target
-        sync are fused inside the learn jit, so they ride as marks."""
+        sync run inside the learn jit: a `jax.profiler` trace shows them
+        on the device plane as `cycle.write_back` / `cycle.target_sync`
+        (runtime/learner.py::CYCLE_SCOPES)."""
         nonlocal state
         # roofline attribution (obs/profiling.py): AOT lower/compile of
         # the exact dispatch signature captures cost_analysis FLOP/byte
@@ -162,10 +164,6 @@ def train_single_process(cfg: RunConfig, total_env_frames: int | None = None,
                 state, m = learner.learn_k(state._replace(rng=rng2),
                                            sample, k)
                 m = jax.block_until_ready(m)
-        obs_.mark("replay.priority_update", fused_into="learner.learn")
-        sync = cfg.learner.target_sync_every
-        if grad_steps // sync != (grad_steps + k) // sync:
-            obs_.mark("learner.target_sync", fused_into="learner.learn")
         obs_.observe("td_abs", float(m["td_abs_mean"]))
         # the acting policy reads state.params directly — lag is truly 0
         obs_.observe("param_lag_steps", 0)

@@ -1,0 +1,194 @@
+"""The cycle's own parts carry device-side names (ISSUE 35):
+`runtime/learner.py::CYCLE_SCOPES`, seven `jax.named_scope`s that tile a
+grad step, and `ops/sum_tree.py`'s two nested in the first and the last.
+
+(a) `train_many`'s lowered text WITH debug info holds every name, the
+    descent only under `cycle.sample` and the tree update only under
+    `cycle.write_back`, for every family, the sharded learner and DPG;
+(b) WITHOUT debug info the text is the parent commit's to the byte
+    (SHA-256 computed on a copy of the parent tree, commit 3bced62, with
+    `_lowered` below): a scope is op metadata and moves no arithmetic;
+(c) no endpoint bypasses a scope: `train_step`, `train_step_k`,
+    `sample_k` + `learn_k` and the prefetching `train_many` open the
+    same names, on one chip and on the mesh.
+"""
+
+import functools
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ape_x_dqn_tpu.configs import (
+    LearnerConfig, NetworkConfig, ParallelConfig, RunConfig, get_config)
+from ape_x_dqn_tpu.envs.base import EnvSpec
+from ape_x_dqn_tpu.models import build_network
+from ape_x_dqn_tpu.ops import sum_tree
+from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
+from ape_x_dqn_tpu.parallel.mesh import make_mesh
+from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
+from ape_x_dqn_tpu.runtime.family import learner_family
+from ape_x_dqn_tpu.runtime.learner import (
+    BATCH, CYCLE_SCOPES, SAMPLE, WRITE_BACK, SingleChipLearner,
+    transition_item_spec)
+from ape_x_dqn_tpu.runtime.train import apply_overrides
+
+# case -> (preset, overrides, n of train_many, the parent's SHA-256[:16]).
+# RELABELS: the family's `make_batch` renames the items' fields and the
+# preset has K = 1, so `cycle.batch` is opened around no op
+PROGRAMS = {
+    "pong": ("pong", ["replay.capacity=4096", "replay.min_fill=512"], 8,
+             "fa002ec06af372f3"),
+    "r2d2": ("r2d2", ["parallel.dp=1", "parallel.tp=1",
+                      "replay.capacity=64", "replay.min_fill=8"], 8,
+             "af980af0faadb7e4"),
+    "glm_tiny_q": ("glm_tiny_q", ["replay.capacity=64"], 2,
+                   "dfb4d0171f649268"),
+    "trinity_tiny_q": ("trinity_tiny_q", ["replay.capacity=64"], 2,
+                       "9187c5d2ae5b1298"),
+    "dist": ("pong", ["parallel.dp=2", "parallel.tp=1",
+                      "replay.capacity=4096", "replay.min_fill=512"], 8,
+             "6668f8be4d7f2de8"),
+    "apex_dpg": ("apex_dpg", ["replay.capacity=4096",
+                              "replay.min_fill=512"], 8,
+                 "a376acdbc487b640"),
+}
+RELABELS = ("glm_tiny_q", "trinity_tiny_q", "apex_dpg")
+QUIET = ["actors.num_actors=0", "eval_episodes=0", "eval_every_steps=0"]
+
+
+@functools.cache
+def _lowered(case: str) -> tuple[str, str]:
+    """-> `train_many`'s lowered text (without, with debug info) of the
+    learner `ApexDriver` builds for the case."""
+    from ape_x_dqn_tpu.runtime.driver import ApexDriver
+
+    preset, overrides, n, _ = PROGRAMS[case]
+    driver = ApexDriver(apply_overrides(get_config(preset),
+                                        overrides + QUIET))
+    try:
+        low = type(driver.learner).train_many.lower(
+            driver.learner, driver.state, n)
+        return low.as_text(), low.as_text(debug_info=True)
+    finally:
+        driver.server.stop()
+
+
+def _name_stacks(debug_text: str) -> set[str]:
+    return set(re.findall(r'loc\("([^"]+)"', debug_text))
+
+
+def _scopes_in(debug_text: str) -> set[str]:
+    stacks = _name_stacks(debug_text)
+    return {s for s in CYCLE_SCOPES if any(s in x for x in stacks)}
+
+
+def _assert_tree_passes_nest(debug_text: str) -> None:
+    for stack in _name_stacks(debug_text):
+        if sum_tree.DESCENT_SCOPE in stack:
+            assert SAMPLE in stack.split(sum_tree.DESCENT_SCOPE)[0], stack
+        if sum_tree.UPDATE_SCOPE in stack:
+            assert WRITE_BACK in stack.split(sum_tree.UPDATE_SCOPE)[0], \
+                stack
+
+
+def test_the_names_are_seven_disjoint_ones():
+    assert len(set(CYCLE_SCOPES)) == 7
+    every = CYCLE_SCOPES + (sum_tree.DESCENT_SCOPE, sum_tree.UPDATE_SCOPE)
+    # the reader asks "is the name in the op's stack": none may be a
+    # substring of another
+    for a in every:
+        assert not [b for b in every if a != b and a in b], a
+
+
+@pytest.mark.parametrize("case", list(PROGRAMS))
+def test_train_many_names_every_part_of_the_cycle(case):
+    _, debug = _lowered(case)
+    assert _scopes_in(debug) == set(CYCLE_SCOPES) - (
+        {BATCH} if case in RELABELS else set())
+    stacks = _name_stacks(debug)
+    assert any(sum_tree.DESCENT_SCOPE in s for s in stacks)
+    assert any(sum_tree.UPDATE_SCOPE in s for s in stacks)
+    _assert_tree_passes_nest(debug)
+
+
+@pytest.mark.parametrize("case", list(PROGRAMS))
+def test_the_program_is_the_parents_to_the_byte(case):
+    text, _ = _lowered(case)
+    assert not any(s in text for s in CYCLE_SCOPES)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PROGRAMS[case][3]
+
+
+# -- (c) every endpoint ---------------------------------------------------
+
+SPEC = EnvSpec(obs_shape=(5,), obs_dtype=np.dtype(np.float32),
+               discrete=True, num_actions=3)
+DP, N, K = 2, 32, 2
+
+
+def _learner_and_state(dist: bool, prefetch: bool = False):
+    cfg = RunConfig(
+        network=NetworkConfig(kind="mlp", mlp_hidden=(24,),
+                              compute_dtype="float32"),
+        learner=LearnerConfig(batch_size=4, n_step=2, sample_chunk=K,
+                              sample_prefetch=prefetch),
+        parallel=ParallelConfig(dp=DP if dist else 1, tp=1))
+    net = build_network(cfg.network, SPEC)
+    params = net.init(jax.random.key(0), jnp.zeros((1, 5)))
+    item_spec = transition_item_spec(SPEC.obs_shape, jnp.float32)
+    family = learner_family(cfg, net)
+    if dist:
+        learner = DistLearner(family, PrioritizedReplay(capacity=N // DP),
+                              cfg.learner, make_mesh(dp=DP, tp=1))
+        return learner, learner.init(params, item_spec, jax.random.key(1))
+    replay = PrioritizedReplay(capacity=N)
+    learner = SingleChipLearner(family, replay, cfg.learner)
+    return learner, learner.init(params, replay.init(item_spec),
+                                 jax.random.key(1))
+
+
+def _debug_text(learner, endpoint: str, *args) -> str:
+    return getattr(type(learner), endpoint).lower(
+        learner, *args).as_text(debug_info=True)
+
+
+EVERY = set(CYCLE_SCOPES)
+
+
+@pytest.mark.parametrize("dist", [False, True], ids=["single", "dist"])
+@pytest.mark.parametrize("endpoint", ["train_step", "train_step_k",
+                                      "train_many_prefetch"])
+def test_a_fused_endpoint_opens_all_seven(endpoint, dist):
+    learner, state = _learner_and_state(
+        dist, prefetch=endpoint == "train_many_prefetch")
+    if endpoint == "train_step":
+        debug = _debug_text(learner, endpoint, state)
+    elif endpoint == "train_step_k":
+        debug = _debug_text(learner, endpoint, state, K)
+    else:
+        # n = 2K + 1: the remainder single, the prologue draw and the
+        # double-buffered scan are all in the program
+        debug = _debug_text(learner, "train_many", state, 2 * K + 1)
+    # one chip, no K-split: the dqn family's batch is the items renamed
+    relabels = endpoint == "train_step" and not dist
+    assert _scopes_in(debug) == EVERY - ({BATCH} if relabels else set())
+    _assert_tree_passes_nest(debug)
+
+
+@pytest.mark.parametrize("dist", [False, True], ids=["single", "dist"])
+def test_the_split_endpoints_divide_the_names_between_them(dist):
+    learner, state = _learner_and_state(dist)
+    drawn = _debug_text(learner, "sample_k", state, K)
+    assert _scopes_in(drawn) == {SAMPLE}
+    sample, rng = jax.eval_shape(
+        lambda s: type(learner).sample_k(learner, s, K), state)
+    learnt = _debug_text(learner, "learn_k", state, sample, K)
+    assert _scopes_in(learnt) == EVERY - {SAMPLE}
+    stacks = _name_stacks(learnt)
+    assert not any(sum_tree.DESCENT_SCOPE in s for s in stacks)
+    _assert_tree_passes_nest(drawn)
+    _assert_tree_passes_nest(learnt)

@@ -35,7 +35,7 @@ def test_tracer_writes_valid_perfetto_json(tmp_path):
     with tracer.span("learner.train", k=4):
         with tracer.span("replay.sample"):
             pass
-    tracer.mark("learner.target_sync", fused_into="learner.train")
+    tracer.mark("actor.ship", segments=3)
 
     def worker():
         with tracer.span("actor.step"):
@@ -47,13 +47,13 @@ def test_tracer_writes_valid_perfetto_json(tmp_path):
     tracer.close()
     trace = load_trace(path)  # json.load would raise on a broken file
     assert span_names(trace) == {"learner.train", "replay.sample",
-                                 "learner.target_sync", "actor.step"}
+                                 "actor.ship", "actor.step"}
     evs = trace["traceEvents"]
     # thread metadata rows name the tracks (Perfetto track labels)
     tnames = {e["args"]["name"] for e in evs if e.get("ph") == "M"}
     assert "actor-0" in tnames
-    sync = next(e for e in evs if e["name"] == "learner.target_sync")
-    assert sync["args"]["fused_into"] == "learner.train"
+    ship = next(e for e in evs if e["name"] == "actor.ship")
+    assert ship["args"]["segments"] == 3 and ship["dur"] <= 2
     # spans nest: the inner sample sits inside the outer train window
     train = next(e for e in evs if e["name"] == "learner.train")
     sample = next(e for e in evs if e["name"] == "replay.sample")
@@ -418,9 +418,10 @@ def test_single_process_catch_traced(tmp_path):
     metrics.close()
     assert out["grad_steps"] > 0
     names = span_names(load_trace(trace))
+    # write-back and target sync run inside learn_k: device scopes
+    # (runtime/learner.py::CYCLE_SCOPES), no host event
     assert names >= {"actor.step", "replay.add", "replay.sample",
-                     "learner.learn", "replay.priority_update",
-                     "learner.target_sync"}, names
+                     "learner.learn"}, names
     recs = [json.loads(l) for l in open(jsonl)]
     hists = [r for r in recs if "hist/sample_age_steps" in r]
     assert hists, "no registry snapshot reached the JSONL"
