@@ -5,9 +5,15 @@ grad step, and `ops/sum_tree.py`'s two nested in the first and the last.
 (a) `train_many`'s lowered text WITH debug info holds every name, the
     descent only under `cycle.sample` and the tree update only under
     `cycle.write_back`, for every family, the sharded learner and DPG;
-(b) WITHOUT debug info the text is the parent commit's to the byte
-    (SHA-256 computed on a copy of the parent tree, commit 3bced62, with
-    `_lowered` below): a scope is op metadata and moves no arithmetic;
+(b) WITHOUT debug info the text is pinned by SHA-256 (`_lowered`
+    below): a scope is op metadata and moves no arithmetic. ISSUE 36
+    changed `sum_tree.update` and with it all six programs by design:
+    the hashes are re-pinned from that PR's tree (the commit after
+    fc151c0), and with `sum_tree.dense_levels` held at 0, the
+    all-indexed walk, every program is still PR 35's (4a42c99) to the
+    byte: nothing but the tree's update moved. The dense pass's ops
+    carry `sum_tree.update` in their name stacks, so the reader of
+    `replay.write_back_share` keeps seeing them;
 (c) no endpoint bypasses a scope: `train_step`, `train_step_k`,
     `sample_k` + `learn_k` and the prefetching `train_many` open the
     same names, on one chip and on the mesh.
@@ -36,45 +42,50 @@ from ape_x_dqn_tpu.runtime.learner import (
     transition_item_spec)
 from ape_x_dqn_tpu.runtime.train import apply_overrides
 
-# case -> (preset, overrides, n of train_many, the parent's SHA-256[:16]).
+# case -> (preset, overrides, n of train_many, SHA-256[:16] of the text,
+# the same with no dense level: PR 35's).
 # RELABELS: the family's `make_batch` renames the items' fields and the
 # preset has K = 1, so `cycle.batch` is opened around no op
 PROGRAMS = {
     "pong": ("pong", ["replay.capacity=4096", "replay.min_fill=512"], 8,
-             "fa002ec06af372f3"),
+             "ef63e79f3fa20d68", "fa002ec06af372f3"),
     "r2d2": ("r2d2", ["parallel.dp=1", "parallel.tp=1",
                       "replay.capacity=64", "replay.min_fill=8"], 8,
-             "af980af0faadb7e4"),
+             "584f01433367ea9c", "af980af0faadb7e4"),
     "glm_tiny_q": ("glm_tiny_q", ["replay.capacity=64"], 2,
-                   "dfb4d0171f649268"),
+                   "a88f0e52e73150b6", "dfb4d0171f649268"),
     "trinity_tiny_q": ("trinity_tiny_q", ["replay.capacity=64"], 2,
-                       "9187c5d2ae5b1298"),
+                       "f80b0f6ac1ea7140", "9187c5d2ae5b1298"),
     "dist": ("pong", ["parallel.dp=2", "parallel.tp=1",
                       "replay.capacity=4096", "replay.min_fill=512"], 8,
-             "6668f8be4d7f2de8"),
+             "8edfe2412a4bc64f", "6668f8be4d7f2de8"),
     "apex_dpg": ("apex_dpg", ["replay.capacity=4096",
                               "replay.min_fill=512"], 8,
-                 "a376acdbc487b640"),
+                 "836445fb85177e4e", "a376acdbc487b640"),
 }
 RELABELS = ("glm_tiny_q", "trinity_tiny_q", "apex_dpg")
 QUIET = ["actors.num_actors=0", "eval_episodes=0", "eval_every_steps=0"]
 
 
 @functools.cache
-def _lowered(case: str) -> tuple[str, str]:
+def _lowered(case: str, dense_top: bool = True) -> tuple[str, str]:
     """-> `train_many`'s lowered text (without, with debug info) of the
-    learner `ApexDriver` builds for the case."""
+    learner `ApexDriver` builds for the case; without `dense_top` every
+    `sum_tree.update` in it walks all its levels by index."""
     from ape_x_dqn_tpu.runtime.driver import ApexDriver
 
-    preset, overrides, n, _ = PROGRAMS[case]
-    driver = ApexDriver(apply_overrides(get_config(preset),
-                                        overrides + QUIET))
-    try:
-        low = type(driver.learner).train_many.lower(
-            driver.learner, driver.state, n)
-        return low.as_text(), low.as_text(debug_info=True)
-    finally:
-        driver.server.stop()
+    preset, overrides, n = PROGRAMS[case][:3]
+    with pytest.MonkeyPatch.context() as patch:
+        if not dense_top:
+            patch.setattr(sum_tree, "dense_levels", lambda capacity, n: 0)
+        driver = ApexDriver(apply_overrides(get_config(preset),
+                                            overrides + QUIET))
+        try:
+            low = type(driver.learner).train_many.lower(
+                driver.learner, driver.state, n)
+            return low.as_text(), low.as_text(debug_info=True)
+        finally:
+            driver.server.stop()
 
 
 def _name_stacks(debug_text: str) -> set[str]:
@@ -112,6 +123,13 @@ def test_train_many_names_every_part_of_the_cycle(case):
     stacks = _name_stacks(debug)
     assert any(sum_tree.DESCENT_SCOPE in s for s in stacks)
     assert any(sum_tree.UPDATE_SCOPE in s for s in stacks)
+    # the dense top's ops are the update's: `replay.write_back_share`
+    # reads them by this name (the prefix's write is a
+    # dynamic_update_slice, and a one-window scatter under `vmap`)
+    assert {"reduce_window_sum", "concatenate"} <= {
+        s.rsplit("/", 1)[-1] for s in stacks if sum_tree.UPDATE_SCOPE in s}
+    assert not [s for s in stacks if s.endswith("/reduce_window_sum")
+                and sum_tree.UPDATE_SCOPE not in s]
     _assert_tree_passes_nest(debug)
 
 
@@ -121,6 +139,13 @@ def test_the_program_is_the_parents_to_the_byte(case):
     assert not any(s in text for s in CYCLE_SCOPES)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PROGRAMS[case][3]
+
+
+@pytest.mark.parametrize("case", list(PROGRAMS))
+def test_only_the_trees_update_moved_since_pr35(case):
+    text, _ = _lowered(case, dense_top=False)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PROGRAMS[case][4]
 
 
 # -- (c) every endpoint ---------------------------------------------------

@@ -1,0 +1,271 @@
+"""`ops/sum_tree.update` repairs the top of the tree in one dense pass
+(ISSUE 36): by index through the levels wider than the batch, then the
+contiguous prefix above them as pairwise sums. The tree it leaves is the
+all-indexed level walk's BIT FOR BIT; `level_walk` below is that walk as
+it stood before the change, kept here as the reference.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ape_x_dqn_tpu.ops import sum_tree
+from ape_x_dqn_tpu.replay import prioritized
+from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
+from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay, ring_finish
+
+CAPACITIES = [8, 64, 4096, 2 ** 16]
+BATCHES = [1, 2, 16, 512, 2048]
+
+
+def level_walk(tree, leaf_idx, priorities):
+    """The parent commit's `update`: a leaf scatter, then two gathers
+    and a scatter of every index for each of log2(capacity) levels."""
+    cap = sum_tree.capacity_of(tree)
+    node = leaf_idx.astype(jnp.int32) + cap
+    tree = tree.at[node].set(priorities.astype(jnp.float32))
+    for _ in range(cap.bit_length() - 1):
+        node = node >> 1
+        tree = tree.at[node].set(tree[2 * node] + tree[2 * node + 1])
+    return tree
+
+
+def _bits(tree) -> np.ndarray:
+    return np.asarray(tree).view(np.uint32)
+
+
+def _batches(cap: int, n: int, rounds: int = 3):
+    """`rounds` batches of n leaves: duplicates inside a batch (all of
+    it when n > cap), zero priorities, leaves hit again by a later
+    batch, and magnitudes 1e-3 .. 1e3 so that a sum's rounding shows."""
+    rng = np.random.default_rng(cap * 31 + n)
+    for _ in range(rounds):
+        idx = rng.integers(0, cap, n)
+        idx[: n // 4] = idx[n // 4: 2 * (n // 4)]
+        pri = 10.0 ** rng.uniform(-3, 3, n)
+        pri[rng.random(n) < 0.2] = 0.0
+        # duplicates carry ONE value: which write a scatter keeps is
+        # the backend's to choose, and not what this file is about
+        _, first = np.unique(idx, return_index=True)
+        pri = pri[first][np.searchsorted(idx[first], idx)]
+        yield jnp.asarray(idx, jnp.int32), jnp.asarray(pri, jnp.float32)
+
+
+def _both(cap: int, n: int):
+    got, want = sum_tree.init(cap), sum_tree.init(cap)
+    new, old = jax.jit(sum_tree.update), jax.jit(level_walk)
+    for idx, pri in _batches(cap, n):
+        got, want = new(got, idx, pri), old(want, idx, pri)
+        yield got, want
+
+
+@pytest.mark.parametrize("n", BATCHES)
+@pytest.mark.parametrize("cap", CAPACITIES)
+def test_the_tree_is_the_level_walks_bit_for_bit(cap, n):
+    for got, want in _both(cap, n):
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("n", BATCHES)
+@pytest.mark.parametrize("cap", CAPACITIES)
+def test_every_node_is_the_sum_of_its_children(cap, n):
+    for tree, _ in _both(cap, n):
+        tree = np.asarray(tree)
+        assert tree[0] == 0.0
+        np.testing.assert_array_equal(
+            tree[1:cap], tree[2:2 * cap:2] + tree[3:2 * cap:2])
+    assert tree[1] > 0.0
+
+
+@pytest.mark.parametrize("dense", [0, 1, 5, 7, 8, 11])
+def test_every_split_of_one_tree_is_the_level_walk(dense, monkeypatch):
+    # the constant makes every level of this file's trees dense; the
+    # splits a wider tree gets are held here by hand, across the width
+    # (LANES) where the pair sums change their view
+    monkeypatch.setattr(sum_tree, "dense_levels", lambda capacity, n: dense)
+    for got, want in _both(4096, 16):
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("cap", CAPACITIES + [2 ** 14, 2 ** 20])
+def test_dense_levels_follows_the_shapes(cap):
+    depth = cap.bit_length() - 1
+    got = [sum_tree.dense_levels(cap, n) for n in range(0, 4200)]
+    assert got[0] == 0
+    assert all(0 <= d <= depth for d in got)
+    assert got == sorted(got), "monotone in n"
+    assert all(d == depth for d in got[cap:]), "n >= capacity"
+    assert sum_tree.dense_levels(cap, 10 * cap) == depth
+    # a level is dense only with every level above it, so the split is
+    # one number, and a level l is dense exactly when its 2^l nodes are
+    # no more than the constant allows the batch
+    for n in (1, 2, 16, 512, 2048):
+        dense = sum_tree.dense_levels(cap, n)
+        assert all((1 << l) <= n * sum_tree.DENSE_NODES_PER_INDEX
+                   for l in range(dense))
+        assert dense == depth or \
+            (1 << dense) > n * sum_tree.DENSE_NODES_PER_INDEX
+
+
+@pytest.mark.parametrize("site, dense", [
+    ((2 ** 20, 2048), 20),   # pong: K*B leaves a macro-step
+    ((2 ** 20, 512), 20),    # atari57 dp=4: a shard's leaves
+    ((2 ** 20, 16), 20),     # one ingested segment
+    ((2 ** 14, 256), 14),    # r2d2
+    ((2 ** 16, 16), 16),     # glm47_flash
+    ((4096, 2), 12),         # trinity_mini
+    ((2 ** 22, 2), 17),      # where an indexed level is left
+])
+def test_the_splits_of_the_deployments_call_sites(site, dense):
+    # the facts PERF.md quotes
+    assert sum_tree.dense_levels(*site) == dense
+
+
+# -- through the replays --------------------------------------------------
+
+DP = 4
+
+
+@pytest.mark.parametrize("dense", [None, 5], ids=["shipped", "5-levels"])
+@pytest.mark.parametrize("form", ["plain", "lockstep", "directed"])
+def test_ring_finish_under_both_vmaps(form, dense, monkeypatch):
+    cap, n = 4096, 64
+    if dense is not None:  # a split that leaves indexed levels
+        monkeypatch.setattr(sum_tree, "dense_levels", lambda c, m: dense)
+    rng = np.random.default_rng(5)
+    lead = () if form == "plain" else (DP,)
+    shape = lead + (n,)
+    idx = rng.integers(0, cap, shape if form == "directed" else (n,))
+    pri = rng.lognormal(0, 2, shape)
+    args = (jnp.asarray(idx, jnp.int32), jnp.asarray(pri, jnp.float32),
+            jnp.zeros(lead, jnp.int32), jnp.zeros(lead, jnp.int32), lead)
+    tree0 = jnp.zeros(lead + (2 * cap,), jnp.float32)
+    got = ring_finish(tree0, *args)[0]
+    monkeypatch.setattr(sum_tree, "update", level_walk)
+    want = ring_finish(tree0, *args)[0]
+    assert got.shape == want.shape == lead + (2 * cap,)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    assert float(jnp.min(got[..., 1])) > 0.0
+
+
+def _states(replay, step, monkeypatch):
+    """-> the replay's state after `step`, under today's `update` and
+    under the level walk."""
+    got = step(replay.init(ITEM))
+    monkeypatch.setattr(sum_tree, "update", level_walk)
+    return got, step(replay.init(ITEM))
+
+
+ITEM = {"x": jax.ShapeDtypeStruct((3,), jnp.float32)}
+
+
+def test_through_update_priorities(monkeypatch):
+    replay = PrioritizedReplay(capacity=1024)
+    rng = np.random.default_rng(11)
+    items = {"x": jnp.asarray(rng.random((512, 3)), jnp.float32)}
+    td0 = jnp.asarray(rng.random(512), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, 512, 96), jnp.int32)
+    td1 = jnp.asarray(rng.lognormal(0, 2, 96), jnp.float32)
+
+    def step(state):
+        state = replay.add(state, items, td0)
+        return replay.update_priorities(state, idx, td1)
+
+    got, want = _states(replay, step, monkeypatch)
+    np.testing.assert_array_equal(_bits(got.tree), _bits(want.tree))
+    assert float(sum_tree.total(got.tree)) > 0.0
+    assert prioritized.sum_tree is sum_tree  # the patch reached the replay
+
+
+def test_through_a_frame_ring_add(monkeypatch):
+    seg, n_step, stack, hw = 8, 3, 4, 6
+    replay = FrameRingReplay(capacity=256, seg_transitions=seg,
+                             n_step=n_step, obs_shape=(hw, hw, stack))
+    rng = np.random.default_rng(13)
+    g, frames = 4, seg + n_step + stack - 1
+    items = {
+        "seg_frames": jnp.asarray(
+            rng.integers(0, 255, (g, frames, hw, hw)), jnp.uint8),
+        "action": jnp.zeros((g, seg), jnp.int32),
+        "reward": jnp.zeros((g, seg), jnp.float32),
+        "discount": jnp.ones((g, seg), jnp.float32),
+        # the last two slots of every segment are dead pads
+        "next_off": jnp.asarray(
+            np.tile([n_step] * (seg - 2) + [0, 0], (g, 1)), jnp.int32),
+    }
+    td = jnp.asarray(rng.lognormal(0, 2, (g, seg)), jnp.float32)
+
+    def step(state):
+        for _ in range(3):
+            state = replay.add(state, items, td)
+        return state
+
+    got, want = _states(replay, step, monkeypatch)
+    np.testing.assert_array_equal(_bits(got.tree), _bits(want.tree))
+    leaves = np.asarray(sum_tree.leaves(got.tree))[: 3 * g * seg]
+    assert (leaves.reshape(-1, seg)[:, -2:] == 0).all()
+    assert (leaves.reshape(-1, seg)[:, :-2] > 0).all()
+
+
+# -- what the chip's compiler makes of it ----------------------------------
+# (on-chip-measurement guide, section 2: describe the chip inside a
+# fixture, in this one file; nothing runs)
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1), num_slices=1)
+    except Exception as e:
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_for_v5e(fn, cap: int, n: int, chip):
+    """-> (gather/scatter fusions, ops the entry computation runs one
+    after another, HLO temp bytes) of `fn` compiled for a described v5e."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda t, i, p: fn(t, i, p), donate_argnums=(0,)).lower(
+            arg((2 * cap,), jnp.float32), arg((n,), jnp.int32),
+            arg((n,), jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    free = r"= \S+ (parameter|constant|bitcast|get-tuple-element|tuple)\("
+    ops = [line for line in entry.splitlines()
+           if " = " in line and not re.search(free, line)]
+    indexed = [line for line in ops if "kind=kCustom" in line]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    return len(indexed), len(ops), temp
+
+
+def test_compiled_for_a_v5e_the_macro_step_walks_few_levels(one_chip):
+    indexed, _, temp = _compiled_for_v5e(sum_tree.update, 2 ** 20, 2048,
+                                         one_chip)
+    before, _, _ = _compiled_for_v5e(level_walk, 2 ** 20, 2048, one_chip)
+    assert before == 61       # the leaf scatter + 20 x (2 gathers, 1 scatter)
+    assert indexed == 1       # the leaf scatter
+    assert temp < 2 ** 20     # the rounds' intermediates live in VMEM
+
+
+def test_compiled_for_a_v5e_a_batch_of_two_costs_no_more_ops(one_chip):
+    _, ops, _ = _compiled_for_v5e(sum_tree.update, 4096, 2, one_chip)
+    _, before, _ = _compiled_for_v5e(level_walk, 4096, 2, one_chip)
+    assert ops <= before
