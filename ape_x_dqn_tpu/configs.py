@@ -189,9 +189,79 @@ class AfmoeConfig:
                 f"num_attention_heads={self.num_attention_heads}")
 
 
+_PUBLISHED_SMALLTHINKER_LAYOUT = (0, 1, 1, 1) * 13
+
+
+@dataclass(frozen=True)
+class SmallThinkerConfig:
+    """The decoder of network.kind="smallthinker_q"
+    (models/smallthinker_q.py), under the key names of the model's own
+    config.json (PowerInfer/SmallThinker-21BA3B-Instruct); defaults are
+    that model's. Grouped-query attention, layer i with a window of
+    `sliding_window_size` keys if `sliding_window_layout[i]` and RoPE
+    if `rope_layout[i]` (else every earlier key, no position encoding);
+    every layer `moe_num_primary_experts` routed ReGLU experts of
+    `moe_ffn_hidden_size`, top-`moe_num_active_primary_experts` of a
+    router that reads the attention's input, weights the softmax over
+    the selected logits; no shared expert, no dense layer; untied
+    embedding and head."""
+
+    hidden_size: int = 2560
+    num_hidden_layers: int = 52
+    num_attention_heads: int = 28
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    moe_ffn_hidden_size: int = 768
+    moe_num_primary_experts: int = 64
+    moe_num_active_primary_experts: int = 6
+    # only True/True is built: together they are the softmax over the
+    # selected logits
+    moe_primary_router_apply_softmax: bool = True
+    norm_topk_prob: bool = True
+    # one entry per layer held, in order (1: RoPE / a sliding window);
+    # a run that holds fewer layers than the model names the ones it
+    # holds
+    rope_layout: tuple[int, ...] = _PUBLISHED_SMALLTHINKER_LAYOUT
+    sliding_window_layout: tuple[int, ...] = _PUBLISHED_SMALLTHINKER_LAYOUT
+    sliding_window_size: int = 4096
+    max_position_embeddings: int = 16_384   # the most tokens in one pass
+    rope_theta: float = 1_500_000.0
+    vocab_size: int = 151_936
+    rms_norm_eps: float = 1e-6
+    # the three below are GlmMoeConfig's, with the same meaning: this
+    # chip's share of a deployment in which `shard_count` chips share
+    # each layer (routed experts [shard_index * n / shard_count, ...)
+    # and as many vocabulary rows live here), and the selection forced
+    # balanced for measuring with random weights
+    shard_count: int = 1
+    shard_index: int = 0
+    force_balanced_routing: bool = False
+
+    def __post_init__(self) -> None:
+        if not 0 <= self.shard_index < self.shard_count:
+            raise ValueError(
+                f"network.smallthinker.shard_index must be in [0, "
+                f"{self.shard_count}) (got {self.shard_index})")
+        if (self.moe_num_primary_experts % self.shard_count
+                or self.vocab_size % self.shard_count):
+            raise ValueError(
+                f"network.smallthinker.shard_count={self.shard_count} "
+                f"must divide moe_num_primary_experts="
+                f"{self.moe_num_primary_experts} and vocab_size="
+                f"{self.vocab_size}")
+        # (that there is one entry per layer held is the net's to
+        # check: overrides set the fields one after the other)
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"network.smallthinker.num_key_value_heads="
+                f"{self.num_key_value_heads} must divide "
+                f"num_attention_heads={self.num_attention_heads}")
+
+
 @dataclass(frozen=True)
 class NetworkConfig:
-    kind: str = "mlp"  # mlp | nature_cnn | lstm_q | dpg | glm_moe_q | afmoe_q
+    # mlp | nature_cnn | lstm_q | dpg | glm_moe_q | afmoe_q | smallthinker_q
+    kind: str = "mlp"
     mlp_hidden: tuple[int, ...] = (256, 256)
     cnn_channels: tuple[int, ...] = (32, 64, 64)
     cnn_kernels: tuple[int, ...] = (8, 4, 3)
@@ -207,6 +277,9 @@ class NetworkConfig:
     glm: GlmMoeConfig = field(default_factory=GlmMoeConfig)
     # the decoder of kind="afmoe_q" (the same family, another model)
     afmoe: AfmoeConfig = field(default_factory=AfmoeConfig)
+    # the decoder of kind="smallthinker_q" (the same family, a third)
+    smallthinker: SmallThinkerConfig = field(
+        default_factory=SmallThinkerConfig)
 
 
 @dataclass(frozen=True)
@@ -1073,6 +1146,75 @@ def _preset_trinity_tiny_q() -> RunConfig:
     )
 
 
+def _preset_smallthinker_21b_q() -> RunConfig:
+    """Config 8: SmallThinker-21BA3B-Instruct (PowerInfer, 21B-A3B) as
+    a token-level Q-network, the decoder family's third net. The sizes
+    are the model's config.json
+    (https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct):
+    52 layers (one global layer without position encoding, then three
+    with RoPE and a 4,096 window), 64 routed ReGLU experts of 768,
+    top-6, no shared expert, 151,936 vocabulary rows. Whole it is 21 B
+    parameters and check_hbm_fits refuses it: a run gives one chip its
+    share with network.smallthinker.shard_count / num_hidden_layers /
+    the two layouts and env.num_tokens
+    (benchmarks/configs/smallthinker_21b_ep8_1chip.json is the measured
+    one). The learner settings are this repo's: sequences of 16,384
+    tokens, the model's whole context, so that the 4,096 window saves
+    more than half of a sliding layer's pairs."""
+    st = SmallThinkerConfig()
+    return RunConfig(
+        name="smallthinker_21b_q",
+        total_env_frames=10_000_000_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=st.vocab_size),
+        network=NetworkConfig(kind="smallthinker_q", dueling=False,
+                              smallthinker=st),
+        # a stored sequence is 16,384 tokens: 4,096 of burn-in, whose
+        # keys and values the trained 12,288 attend to without gradient
+        # (a sliding layer keeps the last 4,095 of them). 2,048
+        # sequences are the other decoders' token count, 0.63 GiB
+        replay=ReplayConfig(kind="sequence", capacity=2_048,
+                            seq_length=16_384, seq_overlap=8_192,
+                            burn_in=4_096, min_fill=64),
+        # batch 1: one sequence is the 16,384 tokens a step
+        learner=LearnerConfig(batch_size=1, n_step=5, value_rescale=True,
+                              target_sync_every=2500, lr=1e-4,
+                              sample_chunk=1, train_chunk=2),
+        # a query re-runs a window of up to 16,384 tokens (the family's
+        # stateless protocol): one at a time
+        actors=ActorConfig(num_actors=64, envs_per_actor=1),
+        inference=InferenceConfig(max_batch=1, deadline_ms=2.0),
+    )
+
+
+def _preset_smallthinker_tiny_q() -> RunConfig:
+    """smallthinker_21b_q's sibling for CPU tests: the same decoder at
+    hidden 64, 7 query heads to each of 2 key-value heads of 16, 8
+    experts top-3, a vocabulary of 64 and a window of 8 inside
+    sequences of 32, one period of four layers, float32."""
+    st = SmallThinkerConfig(
+        hidden_size=64, num_hidden_layers=4, num_attention_heads=14,
+        num_key_value_heads=2, head_dim=16, moe_ffn_hidden_size=32,
+        moe_num_primary_experts=8, moe_num_active_primary_experts=3,
+        rope_layout=(0, 1, 1, 1), sliding_window_layout=(0, 1, 1, 1),
+        sliding_window_size=8, max_position_embeddings=32, vocab_size=64)
+    return RunConfig(
+        name="smallthinker_tiny_q",
+        total_env_frames=100_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=st.vocab_size),
+        network=NetworkConfig(kind="smallthinker_q", dueling=False,
+                              smallthinker=st, compute_dtype="float32"),
+        replay=ReplayConfig(kind="sequence", capacity=64, seq_length=32,
+                            seq_overlap=16, burn_in=12, min_fill=8),
+        learner=LearnerConfig(batch_size=4, n_step=3, value_rescale=True,
+                              target_sync_every=100, lr=1e-3,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=1, envs_per_actor=2),
+        inference=InferenceConfig(max_batch=8, deadline_ms=2.0),
+    )
+
+
 PRESETS = {
     "cartpole_smoke": _preset_cartpole_smoke,
     "pong": _preset_pong,
@@ -1083,6 +1225,8 @@ PRESETS = {
     "glm_tiny_q": _preset_glm_tiny_q,
     "trinity_mini_q": _preset_trinity_mini_q,
     "trinity_tiny_q": _preset_trinity_tiny_q,
+    "smallthinker_21b_q": _preset_smallthinker_21b_q,
+    "smallthinker_tiny_q": _preset_smallthinker_tiny_q,
 }
 
 

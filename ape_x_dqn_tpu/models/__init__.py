@@ -7,18 +7,22 @@ from ape_x_dqn_tpu.models.lstm_q import ApeXLSTMQNet, LSTMState
 from ape_x_dqn_tpu.models.dpg import DPGActor, DPGCritic
 from ape_x_dqn_tpu.models.glm_moe_q import GlmMoeQNet
 from ape_x_dqn_tpu.models.afmoe_q import AfmoeQNet
+from ape_x_dqn_tpu.models.smallthinker_q import SmallThinkerQNet
 
 # network.kind -> the net's class: the token-level Q-networks of the
 # decoder_q family. A further decoder is a row here and in
 # `decoder_block`, a config block, and a row in runtime/family.family_of
-DECODER_NETS = {"glm_moe_q": GlmMoeQNet, "afmoe_q": AfmoeQNet}
+DECODER_NETS = {"glm_moe_q": GlmMoeQNet, "afmoe_q": AfmoeQNet,
+                "smallthinker_q": SmallThinkerQNet}
 
 
 def decoder_block(net_cfg):
     """-> (the name of net_cfg's decoder block in NetworkConfig, the
     block)."""
     return {"glm_moe_q": ("glm", net_cfg.glm),
-            "afmoe_q": ("afmoe", net_cfg.afmoe)}[net_cfg.kind]
+            "afmoe_q": ("afmoe", net_cfg.afmoe),
+            "smallthinker_q": ("smallthinker", net_cfg.smallthinker),
+            }[net_cfg.kind]
 
 
 def build_network(net_cfg, spec):

@@ -40,9 +40,11 @@ as published; what the afmoe modelling code adds beyond them is marked
   layers, t - s < sliding_window; softmax in float32; o = sum p v.
   (+) Output gate: o <- o * sigmoid(u W_gate), W_gate hidden -> heads x
   d. Then W_o. The cache holds k after its norm and rotation.
-  ops/blockwise_attention.py computes it for both kinds with their
-  mask, never forming a [T, S] array and skipping the key blocks the
-  mask rules out.
+  The call and the cache of two kinds are models/windowed_gqa.py's
+  (shared with models/smallthinker_q.py; head norms and the gate are
+  this net's own, around it); ops/blockwise_attention.py computes both
+  kinds with their mask, never forming a [T, S] array and skipping the
+  key blocks the mask rules out.
 - FFN: the first `num_dense_layers` layers one SwiGLU of
   `intermediate_size`; the rest models/expert_layer.py's routed +
   shared expert layer (its docstring has the equations, the share, the
@@ -84,8 +86,8 @@ from ape_x_dqn_tpu.models.base import dtype_of
 from ape_x_dqn_tpu.models.expert_layer import (
     SELECTION, ExpertShare, _balanced_scores, _rms_norm, _rope, _swiglu,
     count_params, expert_ffn, seeded_params)
-from ape_x_dqn_tpu.ops.blockwise_attention import (BLOCK_K, BLOCK_Q,
-                                                    blockwise_attention)
+from ape_x_dqn_tpu.models import windowed_gqa
+from ape_x_dqn_tpu.ops.blockwise_attention import BLOCK_K, BLOCK_Q
 
 SLIDING = "sliding_attention"
 
@@ -214,28 +216,18 @@ class AfmoeQNet:
         if sliding:
             q = _rope(q, positions, a.rope_theta)
             k = _rope(k, positions, a.rope_theta)
-        with jax.named_scope("afmoe.attn.sliding" if sliding
-                             else "afmoe.attn.full"):
-            out = blockwise_attention(
-                q, k, v, cache,
-                window=a.sliding_window if sliding else None,
-                block_q=self.attn_blocks[0], block_k=self.attn_blocks[1])
+        window = a.sliding_window if sliding else None
+        out = windowed_gqa.attend(q, k, v, cache, window, self.attn_blocks)
         gate = jax.nn.sigmoid(u @ p["gate_proj"].astype(dt))
         out = out.reshape(b, t, -1) * gate
-        if cache is not None:
-            k = jnp.concatenate([cache[0].astype(dt), k], axis=1)
-            v = jnp.concatenate([cache[1].astype(dt), v], axis=1)
-        if sliding:     # keep what a later query's window can reach
-            start = max(k.shape[1] - (a.sliding_window - 1), 0)
-            k, v = k[:, start:], v[:, start:]
-        return out @ p["o_proj"].astype(dt), (k, v)
+        kv = windowed_gqa.extend(cache, k, v, window)
+        return out @ p["o_proj"].astype(dt), kv
 
     def _block(self, p: dict, x: jax.Array, cache, tokens: jax.Array,
                layer: int):
         a, eps = self.a, self.a.rms_norm_eps
         dt = x.dtype
-        seen = jnp.int32(0) if cache is None else cache[2]
-        positions = seen + jnp.arange(x.shape[1], dtype=jnp.int32)
+        seen, positions = windowed_gqa.positions_after(cache, x.shape[1])
         with jax.named_scope("afmoe.attn"):
             attn, kv = self._attention(
                 p, _rms_norm(x, p["input_layernorm"], eps),

@@ -1,15 +1,33 @@
-"""The routed + shared expert layer that the decoder Q-networks share
-(models/glm_moe_q.py, models/afmoe_q.py), with the three small functions
-every decoder block here is made of (`_rms_norm`, `_rope`, `_swiglu`) and
-how their parameters are seeded and counted.
-Parameterised by sizes (`ExpertShare`), never by a model's name.
+"""The routed (and, where the model has one, shared) expert layer that the
+decoder Q-networks share (models/glm_moe_q.py, models/afmoe_q.py,
+models/smallthinker_q.py), with the three small functions every decoder
+block here is made of (`_rms_norm`, `_rope`, `_swiglu`) and how their
+parameters are seeded and counted.
+Parameterised by sizes (`ExpertShare`) and by arguments, never by a
+model's name.
 
-The layer: router in float32, s = sigmoid(x W_g) over ALL routed
-experts; the top-k of s + b are selected (b: a fixed, seeded buffer,
-never trained: `stop_gradient`, so Adam's update of it is exactly 0);
-their weights are the selected s (without b), divided by their sum if
-`norm_topk`, times `scale`. FFN(x) = sum_k w_k E_k(x) + E_shared(x),
-E(x) = W_down(silu(W_gate x) * W_up x).
+The layer in two parts. THE PLAN (`plan` -> `Plan`) is everything
+decided before a row is fetched: the router's logits r = t W_g in
+float32 over ALL routed experts, the top-k selection, its weights, the
+sort of the k N assignments by held expert, the inverse of that sort
+and the rows each held expert gets. `t` is the tensor the router READS,
+which need not be the tensor the experts are fed: GLM-4.7-Flash and
+Trinity-Mini route from the rows themselves (`expert_ffn` then makes
+the plan from x), SmallThinker from the attention's input, ahead of
+attention (its block makes the plan and hands it over, `planned=`).
+THE APPLICATION (`expert_ffn`) gathers the rows, runs the grouped
+matmuls and combines: FFN(x) = sum_k w_k E_k(x) [+ E_shared(x) where
+`p` has `shared_experts`], E(x) = W_down(act(W_gate x) * W_up x), `act`
+an argument (SiLU; ReLU for SmallThinker's ReGLU).
+
+Two scorings (`route`'s `scoring`):
+- SIGMOID: s = sigmoid(r); the top-k of s + b are selected (b: a
+  fixed, seeded buffer, never trained: `stop_gradient`, so Adam's
+  update of it is exactly 0); their weights are the selected s (without
+  b), divided by their sum if `norm_topk`, times `scale`.
+- SOFTMAX_SELECTED: the top-k of r are selected; their weights are the
+  softmax over those k logits (what a softmax over all experts,
+  renormalised over the selected, comes to), times `scale`. No b.
 
 The share: the router scores all `experts` and the weights are
 normalised over all k selected, but only selected experts with an id in
@@ -25,10 +43,11 @@ Forced balanced routing (`balanced` scores from `_balanced_scores`;
 Megatron-LM's `--moe-router-force-load-balancing` is the precedent, and
 like it this is for measuring with random weights only): the SELECTION
 is the top-k of a fixed pseudo-random function of (token id, position,
-layer, expert) instead of the top-k of s + b; the weights are still the
-selected s, normalised and scaled, so the router's arithmetic stays in
-every value. Why it exists: at random weights nearly every hidden state
-is one common direction plus a little of its token, so s + b picks
+layer, expert) instead of the model's own; the weights are still the
+scoring's own at the selected experts, so the router's arithmetic -
+and the tensor it read - stays in every value. Why it exists: at
+random weights nearly every hidden state is one common direction plus
+a little of its token, so s + b picks
 nearly the same k experts for every token, how many of those k a share
 holds is a draw of the seed (0 to k), and at Adam 1e-4 the draw changes
 within a hundred steps; the grouped matmuls' cost follows the rows
@@ -66,8 +85,8 @@ times `CAPACITY_SLACK`, rounded up to `ROW_TILE`, never above k N.
   that routes MORE than C rows here takes `_full_width` under a
   `lax.cond` (`_overflow`): the same arithmetic over k N rows; nothing
   is dropped, clipped or deferred.
-  The branch spans dispatch -> experts -> combine; the router, the
-  sort and its inverse stay outside it. `_routed` is a `custom_vjp`
+  The branch spans dispatch -> experts -> combine; the plan (router,
+  sort, inverse, counts) stays outside it. `_routed` is a `custom_vjp`
   that keeps only its inputs and branches again in its backward pass:
   differentiating the `cond` itself makes each branch write zeros for
   the other's residuals, the compact one the full path's [k N, .]
@@ -316,24 +335,41 @@ _combine_some.defvjp(_combine_some_fwd, _combine_some_bwd)
 
 
 
-def route(p: dict, x: jax.Array, share: ExpertShare, balanced):
-    """x [N, hidden] -> (top-k expert ids [N, k] int32, their
-    weights [N, k] float32, normalised over all k and scaled).
-    `balanced` [N, experts]: `_balanced_scores`, which then decide
-    the selection, or None for the model's own s + b."""
+SIGMOID = "sigmoid"                     # s = sigmoid(x W_g), top-k of s + b
+SOFTMAX_SELECTED = "softmax_selected"   # top-k of x W_g, softmax over those k
+
+
+def route(p: dict, x: jax.Array, share: ExpertShare, balanced,
+          scoring: str = SIGMOID):
+    """x [N, hidden], the tensor the router reads (the rows the experts
+    are fed, or another: the module docstring's "plan") -> (top-k
+    expert ids [N, k] int32, their weights [N, k] float32, times
+    `share.scale`). `scoring`: SIGMOID (weights the selected s,
+    normalised over all k if `share.norm_topk`; the selection is the
+    top-k of s + b) or SOFTMAX_SELECTED (the selection is the top-k of
+    the logits, the weights their softmax over the k selected; no b).
+    `balanced` [N, experts]: `_balanced_scores`, which then decide the
+    selection, or None for the model's own."""
     with jax.named_scope("glm.moe.router"):
-        s = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), p["gate"],
-            precision=jax.lax.Precision.HIGHEST))
-        select = balanced
-        if select is None:
-            select = s + jax.lax.stop_gradient(
-                p["e_score_correction_bias"])
+        logits = jnp.dot(x.astype(jnp.float32), p["gate"],
+                         precision=jax.lax.Precision.HIGHEST)
+        if scoring == SIGMOID:
+            s = jax.nn.sigmoid(logits)
+            select = balanced
+            if select is None:
+                select = s + jax.lax.stop_gradient(
+                    p["e_score_correction_bias"])
+        else:
+            select = logits if balanced is None else balanced
         _, ids = jax.lax.top_k(select, share.top_k)
         ids = checkpoint_name(ids, SELECTION)
-        w = jnp.take_along_axis(s, ids, axis=-1)
-        if share.norm_topk:
-            w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+        if scoring == SIGMOID:
+            w = jnp.take_along_axis(s, ids, axis=-1)
+            if share.norm_topk:
+                w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+        else:
+            w = jax.nn.softmax(jnp.take_along_axis(logits, ids, axis=-1),
+                               axis=-1)
         if not share.router_trains:
             # a share without the exchange sees only its own
             # experts' term of the router's gradient (the sum runs
@@ -354,17 +390,17 @@ def _inverse(order: jax.Array) -> jax.Array:
         jnp.arange(order.shape[0], dtype=jnp.int32))
 
 
-def _experts(gathered: jax.Array, e: dict, rows: jax.Array, dt
+def _experts(gathered: jax.Array, e: dict, rows: jax.Array, dt, act
              ) -> jax.Array:
     with jax.named_scope("glm.moe.experts"):
         gate = jax.lax.ragged_dot(gathered, e["gate_proj"].astype(dt),
                                   rows)
         up = jax.lax.ragged_dot(gathered, e["up_proj"].astype(dt), rows)
-        return jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+        return jax.lax.ragged_dot(act(gate) * up,
                                   e["down_proj"].astype(dt), rows)
 
 
-def _full_width(flat, e, w, order, inverse, rows, dt) -> jax.Array:
+def _full_width(flat, e, w, order, inverse, rows, dt, act) -> jax.Array:
     """The routed experts' sum over [k N, hidden] buffers: flat
     [N, hidden], w [N, k], order/inverse [k N], rows [held] ->
     [N, hidden]."""
@@ -376,14 +412,15 @@ def _full_width(flat, e, w, order, inverse, rows, dt) -> jax.Array:
         gathered = jnp.where(live[:, None],
                              _dispatch(flat, order, inverse), 0)
         w_sorted = jnp.where(live, w.reshape(-1)[order], 0.0)
-    y = _experts(gathered, e, rows, dt)
+    y = _experts(gathered, e, rows, dt, act)
     with jax.named_scope("glm.moe.dispatch"):
         # rows past the last group are whatever the kernel left
         y = jnp.where(live[:, None], y, 0) * w_sorted[:, None].astype(dt)
         return _combine(y, order, inverse, flat.shape[0])
 
 
-def _compact(c: int, dt, flat, e, w, order, inverse, rows) -> jax.Array:
+def _compact(c: int, dt, act, flat, e, w, order, inverse, rows
+             ) -> jax.Array:
     """The same sum over [c, hidden] buffers, for a step whose
     `rows.sum() <= c`: the live rows are the first of the sorted
     order."""
@@ -397,7 +434,7 @@ def _compact(c: int, dt, flat, e, w, order, inverse, rows) -> jax.Array:
         gathered = jnp.where(live[:, None],
                              _dispatch_some(flat, token, back), 0)
         w_sorted = jnp.where(live, w.reshape(-1)[head], 0.0)
-    y = _experts(gathered, e, rows, dt)
+    y = _experts(gathered, e, rows, dt, act)
     with jax.named_scope("glm.moe.dispatch"):
         y = jnp.where(live[:, None], y, 0) * w_sorted[:, None].astype(dt)
         return _combine_some(y, token, back, flat.shape[0])
@@ -415,38 +452,40 @@ def _transposed(path, dt, g, flat, e, w, order, inverse, rows):
 # 9.0 s on the host without this, 7.1 with it and 6.75 before the
 # branch existed, and set-up is a metric. The price: in a step that
 # takes it, its ops carry the name stack of the call that traced them.
-@partial(jax.jit, static_argnums=0)
-def _overflow(dt, flat, e, w, order, inverse, rows):
-    return _full_width(flat, e, w, order, inverse, rows, dt)
+@partial(jax.jit, static_argnums=(0, 1))
+def _overflow(dt, act, flat, e, w, order, inverse, rows):
+    return _full_width(flat, e, w, order, inverse, rows, dt, act)
 
 
-@partial(jax.jit, static_argnums=0)
-def _overflow_transposed(dt, g, flat, e, w, order, inverse, rows):
-    return _transposed(partial(_overflow, dt), dt, g, flat, e, w, order,
-                       inverse, rows)
+@partial(jax.jit, static_argnums=(0, 1))
+def _overflow_transposed(dt, act, g, flat, e, w, order, inverse, rows):
+    return _transposed(partial(_overflow, dt, act), dt, g, flat, e, w,
+                       order, inverse, rows)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _routed(c: int, dt, flat, e, w, order, inverse, rows) -> jax.Array:
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _routed(c: int, dt, act, flat, e, w, order, inverse, rows
+            ) -> jax.Array:
     """The routed experts' sum [N, hidden] through buffers of c rows
     when the step's rows fit them, of k N rows when they do not. `e`:
     the parameters as they are stored; the cast to `dt` is each
     branch's own (`_experts`), so that no copy of them is an operand."""
-    return jax.lax.cond(fits(rows, c), partial(_compact, c, dt),
-                        partial(_overflow, dt),
+    return jax.lax.cond(fits(rows, c), partial(_compact, c, dt, act),
+                        partial(_overflow, dt, act),
                         flat, e, w, order, inverse, rows)
 
 
-def _routed_fwd(c, dt, flat, e, w, order, inverse, rows):
-    return (_routed(c, dt, flat, e, w, order, inverse, rows),
+def _routed_fwd(c, dt, act, flat, e, w, order, inverse, rows):
+    return (_routed(c, dt, act, flat, e, w, order, inverse, rows),
             (flat, e, w, order, inverse, rows))
 
 
-def _routed_bwd(c, dt, res, g):
+def _routed_bwd(c, dt, act, res, g):
     rows = res[-1]
     d_flat, d_e, d_w = jax.lax.optimization_barrier(jax.lax.cond(
-        fits(rows, c), partial(_transposed, partial(_compact, c, dt), dt),
-        partial(_overflow_transposed, dt), g, *res))
+        fits(rows, c),
+        partial(_transposed, partial(_compact, c, dt, act), dt),
+        partial(_overflow_transposed, dt, act), g, *res))
     # A conditional's results are held apart from the arrays XLA:TPU
     # packs into one heap for as long as they live, and the matrices'
     # gradients live until the optimizer reads them: compiled for a
@@ -464,19 +503,26 @@ def _routed_bwd(c, dt, res, g):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-def expert_ffn(p: dict, x: jax.Array, dt, share: ExpertShare,
-               balanced=None):
-    """x [B, T, hidden] -> (FFN(x), rows routed to each held expert
-    [held] int32, the top-k ids [B, T, k]). `p`: `gate` [hidden,
-    experts], `e_score_correction_bias` [experts], `experts` (the held
-    ones' three matrices stacked on a leading axis), `shared_experts`.
-    `balanced` [B, T, experts]: see `route`."""
-    b, t, h = x.shape
-    n, k, held = b * t, share.top_k, share.held
-    flat = x.reshape(n, h)
-    ids, w = route(
-        p, flat, share, None if balanced is None else balanced.reshape(n, -1))
-    c = capacity(share, n)
+class Plan(NamedTuple):
+    """Where each of a step's k N assignments goes: everything the
+    layer decides before a row is fetched. It is made from the tensor
+    the router reads, which need not be the tensor the experts are fed
+    (`plan`)."""
+    ids: jax.Array       # [N, k] int32, the selection
+    w: jax.Array         # [N, k] float32, its weights
+    order: jax.Array     # [k N] int32: the assignments sorted by local
+    inverse: jax.Array   # expert (not-held ones last), and back
+    rows: jax.Array      # [held] int32 routed to each held expert
+
+
+def plan(p: dict, x: jax.Array, share: ExpertShare, balanced=None,
+         scoring: str = SIGMOID) -> Plan:
+    """x [N, hidden], the tensor the router reads -> the step's Plan:
+    `route`, then the sort by held expert, its inverse and the counts.
+    `p`: `gate` [hidden, experts] and, for SIGMOID,
+    `e_score_correction_bias` [experts]; `balanced` [N, experts]."""
+    ids, w = route(p, x, share, balanced, scoring)
+    held = share.held
     with jax.named_scope("glm.moe.dispatch"):
         local = ids.reshape(-1) - share.first                # [k N]
         here = (local >= 0) & (local < held)
@@ -485,12 +531,34 @@ def expert_ffn(p: dict, x: jax.Array, dt, share: ExpertShare,
         inverse = _inverse(order)
         rows = jnp.bincount(slot, length=held + 1)[:held].astype(
             jnp.int32)
+    return Plan(ids, w, order, inverse, rows)
+
+
+def expert_ffn(p: dict, x: jax.Array, dt, share: ExpertShare,
+               balanced=None, *, planned: Plan | None = None,
+               act=jax.nn.silu):
+    """x [B, T, hidden] -> (FFN(x), rows routed to each held expert
+    [held] int32, the top-k ids [B, T, k]). `p`: `experts` (the held
+    ones' three matrices stacked on a leading axis), `shared_experts`
+    if the model has one, and what `plan` reads. `planned`: the Plan,
+    where the caller made it already from another tensor than x; else
+    it is made here from x, `balanced` [B, T, experts] deciding the
+    selection as in `route`. `act`: the experts' gate activation."""
+    b, t, h = x.shape
+    n, k = b * t, share.top_k
+    flat = x.reshape(n, h)
+    if planned is None:
+        planned = plan(p, flat, share,
+                       None if balanced is None else balanced.reshape(n, -1))
+    ids, w, order, inverse, rows = planned
+    c = capacity(share, n)
     if c == n * k:
-        routed = _full_width(flat, p["experts"], w, order, inverse, rows,
-                             dt)
+        out = _full_width(flat, p["experts"], w, order, inverse, rows,
+                          dt, act)
     else:
-        routed = _routed(c, dt, flat, p["experts"], w, order, inverse,
-                         rows)
-    with jax.named_scope("glm.moe.shared"):
-        shared = _swiglu(flat, p["shared_experts"], dt)
-    return (routed + shared).reshape(b, t, h), rows, ids.reshape(b, t, k)
+        out = _routed(c, dt, act, flat, p["experts"], w, order, inverse,
+                      rows)
+    if "shared_experts" in p:
+        with jax.named_scope("glm.moe.shared"):
+            out = out + _swiglu(flat, p["shared_experts"], dt)
+    return out.reshape(b, t, h), rows, ids.reshape(b, t, k)

@@ -22,6 +22,17 @@ from the saved log-sum-exp, so nothing of size [T, S] is kept for it
 either), plain `jax.numpy` in two nested `fori_loop`s whose bounds
 follow the query block - the same code on the CPU and on the chip.
 
+WITH `about_mean`, BELOW FLOAT32, THE KEYS AND THE VALUES GO IN LESS
+THEIR MEAN over the call's positions (as far as it can be taken off
+exactly: `_about_its_mean`) and the values' is added to the output:
+the same function, since a row's
+weights add up to 1 and its scores may move by one number, and rows
+that share one large common vector no longer make the q and k
+projections' gradients a small difference of large numbers.
+models/smallthinker_q.py asks for it (no q/k norms, no embedding scale);
+models/afmoe_q.py does not, and its program is what it was. float32
+compute is untouched either way.
+
 THE CACHE CARRIES NO GRADIENT: `cache` enters under `stop_gradient`
 (ops/losses.make_r2d2_loss stops the prefix state's anyway), and the
 backward pass skips the key blocks that lie wholly inside it.
@@ -131,7 +142,9 @@ def _forward(geo: _Geometry, q, k, v):
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _attend(geo: _Geometry, q, k, v):
-    return _forward(geo, q, k, v)[0].astype(q.dtype)
+    """-> the output in float32 (the caller rounds it once, after the
+    values' mean is back in it)."""
+    return _forward(geo, q, k, v)[0]
 
 
 def _attend_fwd(geo, q, k, v):
@@ -144,13 +157,14 @@ def _attend_fwd(geo, q, k, v):
     # projections and head norms (on the v5e, held to a float32
     # reference: 5 to 20 times bfloat16's own error on those leaves)
     out, lse = _forward(geo, q, k, v)
-    return out.astype(q.dtype), (q, k, v, out, lse)
+    return out, (q, k, v, out, lse)
 
 
 def _attend_bwd(geo, res, d_out):
     """The cotangents of q, k, v; keys below `geo.first` (padding and
     cache) get none."""
     q, k, v, out, lse = res
+    d_out = d_out.astype(q.dtype)        # as the rounded output's would be
     b, kv, g, t, d = q.shape
     bq, bk = geo.block_q, geo.block_k
     scale = d ** -0.5
@@ -214,13 +228,70 @@ def _attend_bwd(geo, res, d_out):
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
+MEAN_TAKEN_FROM = 4.0   # |mean| / spread of a coordinate, see below
+
+
+def _about_its_mean(x):
+    """x [B, S, KV, d] -> (x less what is taken off each coordinate, in
+    x's dtype; what was taken off [B, KV, d] float32, or None where
+    nothing was). ATTENTION DOES NOT SEE A VECTOR ADDED TO EVERY KEY
+    (each row's scores move by one number, q . m, which the softmax
+    drops) AND GIVES BACK A VECTOR ADDED TO EVERY VALUE (a row's weights
+    add up to 1), so below float32 the passes run on keys and values
+    less m and the values' m is added to the output: the same function,
+    m held as a constant under `stop_gradient` because the output does
+    not depend on it.
+
+    What it buys: a decoder with no q/k norms and no embedding scale has
+    hidden states that are one common vector plus a little of the token,
+    so keys, values and the output's cotangent are near equal along a
+    sequence and the q and k projections' gradients are small
+    differences of large numbers. The backward pass's ds = p (dp - delta)
+    sums to zero over a row only as far as the forward pass's rounded
+    weights and the backward pass's recomputed ones agree, and what is
+    left of the row sum is multiplied by the keys' common vector in dq
+    and carried by the values' common vector in delta = out . d_out: on
+    the v5e the sliding layers' q and k projections read 5-20 units of
+    bfloat16's own error after a window of Adam steps (PERF.md section
+    6, PR 39). Without a common vector there is nothing for either to
+    multiply.
+
+    WHAT IS TAKEN OFF IS CHOSEN SO THAT NOTHING IS ROUNDED TWICE: m is
+    the positions' mean ROUNDED TO x's DTYPE, and only in the
+    coordinates where it is at least MEAN_TAKEN_FROM times the spread
+    about it (elsewhere 0). x and m are then two numbers of one format
+    within a factor of two of each other, whose difference that format
+    holds exactly (Sterbenz), so x - m is x to the last bit wherever the
+    common vector matters, and x itself where it does not. A mean taken
+    off in full is rounded again on the way back to x's dtype, and the
+    MEAN of those roundings over the keys is one error added to every
+    row's output - it does not average out over a sequence as a
+    rounding does: the gradient's common error then read 0.4 to 2.3 of
+    the unit over fourteen seeds where this form reads what the plain
+    kernel does (PERF.md, same place). float32 compute: x as it is, and
+    no op."""
+    if x.dtype == jnp.float32:
+        return x, None
+    x32 = x.astype(jnp.float32)
+    mean = x32.mean(axis=1)
+    spread = jnp.sqrt(jnp.square(x32 - mean[:, None]).mean(axis=1))
+    held = mean.astype(x.dtype).astype(jnp.float32)
+    taken = jax.lax.stop_gradient(jnp.where(
+        jnp.abs(held) >= MEAN_TAKEN_FROM * spread, held, 0.0))
+    return (x32 - taken[:, None]).astype(x.dtype), taken
+
+
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         cache: tuple | None = None, *,
                         window: int | None = None,
                         block_q: int = BLOCK_Q,
-                        block_k: int = BLOCK_K) -> jax.Array:
+                        block_k: int = BLOCK_K,
+                        about_mean: bool = False) -> jax.Array:
     """See the module docstring. `block_q` is cut to a divisor of T;
-    the keys are padded in front to whole blocks of `block_k`."""
+    the keys are padded in front to whole blocks of `block_k`.
+    `about_mean`: keys and values go in less their mean
+    (`_about_its_mean`; a net whose rows share one large vector asks
+    for it)."""
     b, t, heads, d = q.shape
     kv = k.shape[2]
     if cache is not None:
@@ -228,6 +299,10 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             [jax.lax.stop_gradient(cache[0]).astype(k.dtype), k], axis=1)
         v = jnp.concatenate(
             [jax.lax.stop_gradient(cache[1]).astype(v.dtype), v], axis=1)
+    v_mean = None
+    if about_mean:
+        k, _ = _about_its_mean(k)
+        v, v_mean = _about_its_mean(v)
     s = k.shape[1]
     bk = min(block_k, s)
     pad = -s % bk
@@ -236,5 +311,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     front = ((0, 0), (pad, 0), (0, 0), (0, 0))
     k, v = (jnp.pad(a, front).transpose(0, 2, 1, 3) for a in (k, v))
     q = q.reshape(b, t, kv, heads // kv, d).transpose(0, 2, 3, 1, 4)
-    out = _attend(geo, q, k, v)                      # [B, KV, G, T, d]
+    out = _attend(geo, q, k, v)             # [B, KV, G, T, d] float32
+    if v_mean is not None:
+        out = out + v_mean[:, :, None, None]
+    out = out.astype(q.dtype)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)

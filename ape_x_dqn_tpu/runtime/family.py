@@ -23,23 +23,26 @@ and what differs is the state a sequence starts from:
 - "decoder_q" stores NONE: a replayed window starts from an empty
   attention cache and its burn-in prefix is its context — the prefix
   pass leaves the net's cache (glm_moe_q: per layer a latent c_kv and
-  k_rope; afmoe_q: per layer (k, v), every position for a full layer
-  and the last window - 1 for a sliding one), the loss stops its
-  gradient, the trained steps attend to it. The item has no state
-  entry at all. The server is stateless too: a query carries the
+  k_rope; afmoe_q and smallthinker_q: per layer (k, v), every position
+  for a full layer and the last window - 1 for a sliding one), the loss
+  stops its gradient, the trained steps attend to it. The item has no
+  state entry at all. The server is stateless too: a query carries the
   last <= L token ids ({obs, ctx, n} -> {q, ctx, n}) and the server
   re-runs the window; a per-slot cache inside
   parallel/inference_server.py, which would make a step cost one token
   instead of a window, is what is missing.
 
-The decoder_q family has two nets (network.kind "glm_moe_q",
-"afmoe_q"), which share models/expert_layer.py. A further decoder
-registers with: its net in models/ with the surface the family reads
-(`init`, `apply`, `apply_with_stats` -> stats `expert_rows` and `topk`,
-`param_count`, `step_transient_bytes`, `num_actions`, `router_trains`),
-a config block in NetworkConfig, a row in models.DECODER_NETS, in
-models.decoder_block and in `family_of`; tools/apexlint's `config_coverage` learns the block's
-name. Nothing else here names a decoder.
+The decoder_q family has three nets (network.kind "glm_moe_q",
+"afmoe_q", "smallthinker_q"), which share models/expert_layer.py (the
+plan and the application of an expert layer); the last two also share
+models/windowed_gqa.py (the attention call and the cache of two kinds).
+A further decoder registers with: its net in models/ with the surface
+the family reads (`init`, `apply`, `apply_with_stats` -> stats
+`expert_rows` and `topk`, `param_count`, `step_transient_bytes`,
+`num_actions`, `router_trains`, `share`), a config block in
+NetworkConfig, a row in models.DECODER_NETS, in models.decoder_block
+and in `family_of`; tools/apexlint's `config_coverage` learns the
+block's name. Nothing else here names a decoder.
 
 How a further Q-learning family registers: its net in models/ with a
 row in `build_network`; its kind in `family_of`; a row in
@@ -72,7 +75,8 @@ from ape_x_dqn_tpu.utils.rng import component_key
 
 def family_of(cfg: RunConfig) -> str:
     return {"lstm_q": "r2d2", "dpg": "dpg", "glm_moe_q": "decoder_q",
-            "afmoe_q": "decoder_q"}.get(cfg.network.kind, "dqn")
+            "afmoe_q": "decoder_q",
+            "smallthinker_q": "decoder_q"}.get(cfg.network.kind, "dqn")
 
 
 # families whose replay items are whole sequences (the staging unit is

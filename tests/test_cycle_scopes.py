@@ -56,6 +56,10 @@ PROGRAMS = {
                    "a88f0e52e73150b6", "dfb4d0171f649268"),
     "trinity_tiny_q": ("trinity_tiny_q", ["replay.capacity=64"], 2,
                        "f80b0f6ac1ea7140", "9187c5d2ae5b1298"),
+    # the decoder family's third net, pinned at the PR that added it (ISSUE
+    # 39); its second hash is the same tree's with no dense level
+    "smallthinker_tiny_q": ("smallthinker_tiny_q", ["replay.capacity=64"],
+                            2, "6d3781211705c6a0", "60c188e6200430d0"),
     "dist": ("pong", ["parallel.dp=2", "parallel.tp=1",
                       "replay.capacity=4096", "replay.min_fill=512"], 8,
              "8edfe2412a4bc64f", "6668f8be4d7f2de8"),
@@ -63,7 +67,8 @@ PROGRAMS = {
                               "replay.min_fill=512"], 8,
                  "836445fb85177e4e", "a376acdbc487b640"),
 }
-RELABELS = ("glm_tiny_q", "trinity_tiny_q", "apex_dpg")
+RELABELS = ("glm_tiny_q", "trinity_tiny_q", "smallthinker_tiny_q",
+            "apex_dpg")
 QUIET = ["actors.num_actors=0", "eval_episodes=0", "eval_every_steps=0"]
 
 

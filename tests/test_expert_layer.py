@@ -187,11 +187,12 @@ def test_the_compact_path_alone_would_drop_what_the_branch_keeps():
 
     def alone(rows_here):
         return expert_layer._compact(
-            c, jnp.float32, flat, p["experts"],
+            c, jnp.float32, jax.nn.silu, flat, p["experts"],
             *sorted_for(share, p, flat, rows_here))
 
     def branching(rows_here):
-        return expert_layer._routed(c, jnp.float32, flat, p["experts"],
+        return expert_layer._routed(c, jnp.float32, jax.nn.silu, flat,
+                                    p["experts"],
                                     *sorted_for(share, p, flat, rows_here))
 
     np.testing.assert_allclose(alone(c), branching(c), atol=1e-6)
@@ -217,8 +218,8 @@ def test_padded_rows_read_zeros_and_give_their_tokens_no_cotangent():
     probe = jax.random.normal(jax.random.PRNGKey(5), flat.shape)
 
     def f(flat, e, w):
-        out = expert_layer._routed(c, jnp.float32, flat, e, w, order,
-                                   inverse, rows)
+        out = expert_layer._routed(c, jnp.float32, jax.nn.silu, flat, e, w,
+                                   order, inverse, rows)
         return (out * probe).sum(), out
 
     (_, out), grads = jax.value_and_grad(f, argnums=(0, 1, 2),
@@ -279,3 +280,140 @@ def test_a_whole_layer_lowers_to_the_text_it_had_before_the_capacity():
 def test_a_share_below_its_worst_case_lowers_to_a_branch():
     text = lowered(share_of(4))
     assert "stablehlo.case" in text or "stablehlo.if" in text
+
+
+# -- what became an argument (ISSUE 39): the tensor the plan is read
+# from, the scoring, the activation, a layer without a shared expert ----
+
+def routed_only_params(share: ExpertShare, seed: int = 0) -> dict:
+    p = layer_params(share, seed)
+    return {"gate": p["gate"], "experts": p["experts"]}
+
+
+def loop_routed_only(p, x, read, share, balanced, scoring, act):
+    """The plain loop for a routed-only layer whose router reads `read`
+    [B, T, HIDDEN]: every held expert applied to every row of x."""
+    flat, r = x.reshape(-1, HIDDEN), read.reshape(-1, HIDDEN)
+    logits = jnp.dot(r, p["gate"], precision=jax.lax.Precision.HIGHEST)
+    if scoring == expert_layer.SIGMOID:
+        s = jax.nn.sigmoid(logits)
+        select = s if balanced is None else balanced.reshape(-1, EXPERTS)
+        _, ids = jax.lax.top_k(select, share.top_k)
+        w = jnp.take_along_axis(s, ids, axis=-1)
+        w = w / w.sum(axis=-1, keepdims=True)
+    else:
+        select = (logits if balanced is None
+                  else balanced.reshape(-1, EXPERTS))
+        _, ids = jax.lax.top_k(select, share.top_k)
+        w = jax.nn.softmax(jnp.take_along_axis(logits, ids, axis=-1), -1)
+    w = w * share.scale
+    out = jnp.zeros_like(flat)
+    e = p["experts"]
+    for j in range(share.held):
+        mine = (w * (ids == share.first + j)).sum(axis=-1)
+        out = out + mine[:, None] * (
+            (act(flat @ e["gate_proj"][j]) * (flat @ e["up_proj"][j]))
+            @ e["down_proj"][j])
+    return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize("ways", [1, 4], ids=["whole", "share"])
+@pytest.mark.parametrize("balanced", [True, False], ids=["forced", "own"])
+@pytest.mark.parametrize("scoring,act", [
+    (expert_layer.SOFTMAX_SELECTED, jax.nn.relu),
+    (expert_layer.SOFTMAX_SELECTED, jax.nn.silu),
+    (expert_layer.SIGMOID, jax.nn.relu)],
+    ids=["softmax-relu", "softmax-silu", "sigmoid-relu"])
+def test_a_plan_from_another_tensor_equals_the_loop(scoring, act, balanced,
+                                                    ways):
+    """The plan read off ANOTHER tensor than the rows the experts are
+    fed, either scoring, either activation, no shared expert: output
+    and every gradient (the rows', the read tensor's, the router's, the
+    experts') against the loop, on the one path of a whole layer and
+    through the compact path of a share."""
+    share = share_of(ways)
+    p = routed_only_params(share)
+    if scoring == expert_layer.SIGMOID:
+        p["e_score_correction_bias"] = jnp.zeros(EXPERTS)
+    x, read = inputs(), inputs(seed=7)
+    scores = forced() if balanced else None
+    probe = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def layer(p, x, read):
+        planned = expert_layer.plan(
+            p, read.reshape(N, HIDDEN), share,
+            None if scores is None else scores.reshape(N, EXPERTS), scoring)
+        out, rows, ids = expert_ffn(p, x, jnp.float32, share,
+                                    planned=planned, act=act)
+        return (out * probe).sum(), (out, rows)
+
+    def loop(p, x, read):
+        out = loop_routed_only(p, x, read, share, scores, scoring, act)
+        return (out * probe).sum(), out
+
+    (_, (out, rows)), grads = jax.jit(jax.value_and_grad(
+        layer, argnums=(0, 1, 2), has_aux=True))(p, x, read)
+    (_, want), want_grads = jax.jit(jax.value_and_grad(
+        loop, argnums=(0, 1, 2), has_aux=True))(p, x, read)
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    flat, tree = jax.tree.flatten(grads)
+    for got, exp in zip(flat, tree.flatten_up_to(want_grads)):
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, exp, atol=2e-4, rtol=1e-4)
+    # the router's gradient arrives through the tensor it READ
+    assert np.abs(np.asarray(grads[2])).max() > 0
+    assert np.abs(np.asarray(grads[0]["gate"])).max() > 0
+    assert int(rows.sum()) <= capacity(share, N)
+    # the stream the plan is read from decides the result
+    from_rows = expert_layer.plan(
+        p, x.reshape(N, HIDDEN), share,
+        None if scores is None else scores.reshape(N, EXPERTS), scoring)
+    own, _, _ = expert_ffn(p, x, jnp.float32, share, planned=from_rows,
+                           act=act)
+    assert np.abs(np.asarray(own) - np.asarray(out)).max() > 1e-3
+
+
+@pytest.mark.parametrize("over", [0, 1, "all"])
+def test_overflow_takes_the_full_width_with_a_plan_relu_and_no_shared(over):
+    """`test_rows_up_to_and_past_the_capacity_equal_the_loop` with what
+    became an argument: at C rows the compact path, past it the full
+    width, nothing dropped."""
+    share = share_of(4)
+    c = capacity(share, N)
+    want_rows = TOP_K * N if over == "all" else c + over
+    p, x, read = routed_only_params(share), inputs(), inputs(seed=7)
+    scores = selection_of(want_rows, share)
+    planned = expert_layer.plan(
+        p, read.reshape(N, HIDDEN), share, scores.reshape(N, EXPERTS),
+        expert_layer.SOFTMAX_SELECTED)
+    out, rows, _ = jax.jit(lambda p, x, planned: expert_ffn(
+        p, x, jnp.float32, share, planned=planned, act=jax.nn.relu))(
+        p, x, planned)
+    assert int(rows.sum()) == want_rows
+    np.testing.assert_allclose(out, loop_routed_only(
+        p, x, read, share, scores, expert_layer.SOFTMAX_SELECTED,
+        jax.nn.relu), atol=2e-5)
+
+
+def test_softmax_over_the_selected_sums_to_one_and_ignores_the_rest():
+    share = share_of(1)._replace(scale=1.0)
+    p, x = routed_only_params(share), inputs().reshape(N, HIDDEN)
+    ids, w = expert_layer.route(p, x, share, None,
+                                expert_layer.SOFTMAX_SELECTED)
+    np.testing.assert_allclose(w.sum(axis=-1), 1.0, atol=1e-6)
+    logits = x @ p["gate"]
+    np.testing.assert_array_equal(
+        np.sort(ids, -1), np.sort(jax.lax.top_k(logits, TOP_K)[1], -1))
+    # an unselected logit moved by any amount below the k-th changes nothing
+    low = jnp.argmin(logits, axis=-1)
+    bumped = logits.at[jnp.arange(N), low].add(-5.0)
+    w2 = jax.nn.softmax(jnp.take_along_axis(bumped, ids, -1), -1)
+    np.testing.assert_allclose(w, w2, atol=1e-6)
+
+
+def test_the_cells_capacities_smallthinker():
+    """`smallthinker_offline`'s share at its trained segment and its
+    prefix: the rows expected (9,216 and 3,072) x 1.5, in tiles of 128."""
+    st = ExpertShare(64, 6, 8, 0, True, 1.0, False)
+    assert (capacity(st, 12288), capacity(st, 4096)) == (13824, 4608)
+    assert capacity(st, 12288) < 6 * 12288

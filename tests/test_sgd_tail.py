@@ -543,6 +543,12 @@ def test_a_small_net_keeps_the_select_and_every_steps_norms(tail_min_bytes):
                         "network.afmoe.shard_count=16",
                         "network.afmoe.vocab_shard_count=8",
                         "env.num_tokens=25024"], True),
+    ("smallthinker_21b_q", ["network.smallthinker.num_hidden_layers=4",
+                            "network.smallthinker.rope_layout=(0,1,1,1)",
+                            "network.smallthinker.sliding_window_layout="
+                            "(0,1,1,1)",
+                            "network.smallthinker.shard_count=8",
+                            "env.num_tokens=18992"], True),
 ])
 def test_each_cells_net_takes_the_tail_measured_for_it(preset, sets, branches,
                                                        tail_min_bytes):

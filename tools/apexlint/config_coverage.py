@@ -13,10 +13,12 @@ Both directions of config/doc drift:
    declaration line.
 
 2. Every `replay.` / `comm.` / `obs.` / `actors.` / `serving.` /
-   `glm.` / `afmoe.` (as in `network.glm.shard_count`) knob
+   `glm.` / `afmoe.` / `smallthinker.` (as in
+   `network.glm.shard_count`) knob
    mentioned in README must exist as a field on the matching dataclass
    (ReplayConfig / CommConfig / ObsConfig / ActorConfig /
-   ServingConfig / GlmMoeConfig / AfmoeConfig). Mentions
+   ServingConfig / GlmMoeConfig / AfmoeConfig / SmallThinkerConfig).
+   Mentions
    that name a package MODULE instead of a knob (`obs.health`,
    `obs.report` — `ape_x_dqn_tpu/obs/health.py` exists) are skipped.
 
@@ -42,7 +44,8 @@ PREFIX_TO_CLASS = {"replay": "ReplayConfig", "comm": "CommConfig",
                    "obs": "ObsConfig", "actors": "ActorConfig",
                    "serving": "ServingConfig",
                    "remediation": "RemediationConfig",
-                   "glm": "GlmMoeConfig", "afmoe": "AfmoeConfig"}
+                   "glm": "GlmMoeConfig", "afmoe": "AfmoeConfig",
+                   "smallthinker": "SmallThinkerConfig"}
 KNOB_RE = re.compile(
     r"\b(" + "|".join(PREFIX_TO_CLASS) + r")"
     r"\.([a-z_][a-z0-9_]*)")
