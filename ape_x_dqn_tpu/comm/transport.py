@@ -44,7 +44,15 @@ class Transport(Protocol):
 class LoopbackTransport:
     """In-process transport: bounded queue + versioned param cell."""
 
-    def __init__(self, max_pending: int = 64):
+    def __init__(self, max_pending: int = 256):
+        # the bound is in messages, what it buys is TIME: how long
+        # ingest may be away from the queue before the oldest message
+        # is dropped. A fleet behind the pipelined inference server
+        # ships ~1,400 one-segment messages a second, and ingest is
+        # away ~10 ms for every coalesced staging buffer it lands under
+        # a busy GIL, eight in a row while a ring is being filled
+        # (PERF.md section 6, PR 40: 64 was 45 ms there and overflowed,
+        # a queue of 128 still peaked at 120); 256 is ~180 ms
         self._q: queue.Queue[dict] = queue.Queue(maxsize=max_pending)
         self._params: Any = None
         self._version = -1

@@ -11,6 +11,48 @@ waiting or the oldest has waited `deadline_ms` (latency/throughput
 trade-off, SURVEY.md §7 hard part 3). Batches are padded to the next
 power of two so XLA compiles a handful of bucket shapes once.
 
+The serve loop (ISSUE 40) keeps ONE forward in flight — a two-deep
+software pipeline inside the one serve thread:
+
+    collect -> stack -> dispatch batch k+1   (the jit call returns at
+                                              once; the outputs' copy to
+                                              the host is started behind it)
+            -> fetch -> scatter batch k      (its result has had a whole
+                                              host period to arrive)
+
+so the device round trip (host -> device copy, launch, device -> host
+copy, the wake-up and the wait to win the GIL back from the clients the
+last scatter released) runs beside the next batch's host work instead
+of being a part of every period. The rule that keeps a lone actor's
+latency what it was: **with a batch in flight the serve thread never
+waits on the queue** — neither for a first request nor for the fill
+deadline. Batch k+1 goes ahead of k's fetch only when its requests are
+ALREADY waiting (`_collect(block=False)`: the held deque, then
+`get_nowait`); with nothing waiting k is fetched and scattered at once,
+and only then does the loop collect with the deadline as ever. It
+adapts on the one thing the server can see, its own queue: a saturated
+fleet sees the pipeline, `query()`, warm-up traffic and a lone actor see
+the serial loop. At most one batch is ahead. Replies leave in arrival
+order, k before k+1; each batch keeps the params and version it read at
+its own dispatch; an error at dispatch or at fetch reaches exactly the
+requests of the batch it belongs to; `stop()` replies to a batch in
+flight. Spans: `server.stack` / `.dispatch` / `.fetch` / `.scatter`
+still tile the thread's time and carry `batch=seq`; `server.batch`
+(stack start -> scatter end) overlaps its successor's first half, so it
+is a `record()`ed interval with no profiler annotation; `server.ahead`,
+once per batch dispatched with its predecessor unfetched, runs from
+that dispatch to the start of the predecessor's fetch, and
+count(`server.ahead`) / count(`server.batch`) is the share of batches
+the pipeline engaged for. `server.fetch` is now what is LEFT of the
+round trip after a successor's stack + dispatch.
+
+`MultiPolicyInferenceServer` keeps a loop of its own (`_dispatch_loop`,
+strictly serial) and is not pipelined: its admission classes, shedding
+and per-request deadlines make "what is already waiting" a different
+question (a forward dispatched ahead has admitted requests a later,
+more urgent class could no longer overtake), and no benchmark cell runs
+it, so a before and after could not be measured.
+
 Generic over the request pytree: a request is (inputs_pytree,) and the
 reply is outputs_pytree — plain Q-nets send obs and get Q-values;
 recurrent nets send (obs, (c, h)) and get (q, (c', h')).
@@ -45,6 +87,7 @@ import queue
 import threading
 import time
 from collections import deque
+from dataclasses import dataclass
 from typing import Any, Callable
 
 import jax
@@ -74,6 +117,20 @@ class _Request:
     @property
     def items(self) -> int:
         return self.n if self.n else 1
+
+
+@dataclass(slots=True)
+class _Flight:
+    """A batch past its dispatch: what its reply still needs."""
+
+    reqs: list[_Request]
+    n: int              # items
+    padded: int
+    seq: int
+    version: int        # of the params it was dispatched with
+    out: Any            # device outputs, their copy to the host started
+    t0: float           # server.batch's start (stamped only when traced)
+    t_dispatch: float   # server.dispatch's start (likewise)
 
 
 class BatchedInferenceServer:
@@ -242,7 +299,10 @@ class BatchedInferenceServer:
 
     # -- server loop -------------------------------------------------------
 
-    def _collect(self) -> list[_Request]:
+    def _collect(self, block: bool = True) -> list[_Request]:
+        # block=False (a batch is in flight, its reply owed): take only
+        # what is ALREADY waiting — no wait for a first request, no
+        # fill deadline. Otherwise as ever:
         # max_batch counts ITEMS, not requests: a vector actor's K-item
         # request fills K slots of the batch budget. A request that
         # would overflow the budget is HELD for a later batch (never
@@ -268,18 +328,22 @@ class BatchedInferenceServer:
         self._held = kept
         if not reqs:
             try:
-                first = self._q.get(timeout=0.05)
+                first = (self._q.get(timeout=0.05) if block
+                         else self._q.get_nowait())
             except queue.Empty:
                 return []
             reqs.append(first)
             items = first.items
         deadline = time.monotonic() + self._deadline_s
         while items < self._max_batch:
-            remaining = deadline - time.monotonic()
-            if remaining <= 0:
-                break
             try:
-                r = self._q.get(timeout=remaining)
+                if block:
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        break
+                    r = self._q.get(timeout=remaining)
+                else:
+                    r = self._q.get_nowait()
             except queue.Empty:
                 break
             if items + r.items > self._max_batch:
@@ -289,17 +353,18 @@ class BatchedInferenceServer:
             items += r.items
         return reqs
 
-    def _collect_traced(self) -> list[_Request]:
+    def _collect_traced(self, block: bool = True) -> list[_Request]:
         """`_collect` as the `server.collect` span (the wait for the
-        first request plus the fill deadline; empty polls are not
-        recorded) and one `server.queue_wait` interval per request,
-        enqueue -> collected, tagged with the batch that serves it."""
+        first request plus the fill deadline, or with a batch in flight
+        the sweep of what is waiting; empty polls are not recorded) and
+        one `server.queue_wait` interval per request, enqueue ->
+        collected, tagged with the batch that serves it."""
         t0 = time.perf_counter()
         # the profiler sees every poll; the tracer only the ones that
         # produced a batch, so an idle server does not dilute the mean
         with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX
                                           + "server.collect"):
-            reqs = self._collect()
+            reqs = self._collect(block)
         if reqs:
             t1 = time.perf_counter()
             batch = self._batch_seq + 1
@@ -311,26 +376,32 @@ class BatchedInferenceServer:
         return reqs
 
     def _serve_loop(self) -> None:
+        """collect -> stack -> dispatch batch k+1, THEN fetch + scatter
+        batch k (module docstring, "The serve loop")."""
         collect = self._collect_traced if self._traced else self._collect
+        flight: _Flight | None = None  # dispatched, its reply still owed
         while not self._stop.is_set():
-            reqs = collect()
-            if not reqs:
+            # with a reply owed, never wait: not for a first request,
+            # not for the fill deadline
+            reqs = collect(flight is None)
+            if flight is None and not reqs:
                 # an idle-but-polling server is alive, not stalled: beat
                 # so a wedged ACTOR gets the stall attribution instead of
                 # the server it simply stopped querying
                 self._obs.beat("inference-server", "idle")
                 continue
-            try:
-                self._serve_batch(reqs)
-            except Exception as e:  # propagate to callers, keep serving
-                # forensics: the error surfaces in the CALLERS' threads;
-                # the ring keeps the server-side attribution
-                self._obs.blackbox.record(
-                    "serve_error", component="inference-server",
-                    error=repr(e)[:200])
-                for r in reqs:
-                    r.result = e
-                    r.event.set()
+            ahead = self._dispatch(reqs) if reqs else None
+            if flight is not None:
+                if ahead is not None and self._traced:
+                    # the mechanism's own counter: one per batch that
+                    # was dispatched with its predecessor unfetched
+                    self._obs.record("server.ahead", ahead.t_dispatch,
+                                     time.perf_counter(), batch=ahead.seq,
+                                     behind=flight.seq)
+                self._reply(flight)
+            flight = ahead
+        if flight is not None:  # stop() leaves no client in event.wait
+            self._reply(flight)
 
     def _bucket(self, n: int) -> int:
         """Padded batch size: next pow2, rounded up to a multiple of the
@@ -340,15 +411,33 @@ class BatchedInferenceServer:
             b = -(-b // self._min_bucket) * self._min_bucket
         return b
 
-    def _serve_batch(self, reqs: list[_Request]) -> None:
+    def _fail(self, reqs: list[_Request], e: Exception) -> None:
+        """An error reaches the requests of the batch it belongs to,
+        and only those; the server keeps serving."""
+        # forensics: the error surfaces in the CALLERS' threads; the
+        # ring keeps the server-side attribution
+        self._obs.blackbox.record(
+            "serve_error", component="inference-server",
+            error=repr(e)[:200])
+        for r in reqs:
+            r.result = e
+            r.event.set()
+
+    def _dispatch(self, reqs: list[_Request]) -> _Flight | None:
+        """First half of a batch: stack the requests, enqueue the
+        forward and the copy of its outputs back to the host. Returns
+        without waiting for either; None when the batch failed here
+        (its requests have the error)."""
         n = sum(r.items for r in reqs)
         padded = self._bucket(n)
         span = self._obs.span
         self._batch_seq = seq = self._batch_seq + 1
-        # server.batch is one batch end to end; its four children split
-        # the serve thread's time (server.collect, before it, is the
-        # fifth part of a batch's period) and repeat its seq as `batch`
-        with span("server.batch", items=n, padded=padded, seq=seq):
+        # server.batch is one batch end to end, stack start -> scatter
+        # end, and overlaps its successor's first half; the four
+        # children tile the serve thread's time (server.collect is the
+        # fifth part of a period) and repeat its seq as `batch`
+        t0 = time.perf_counter() if self._traced else 0.0
+        try:
             with span("server.stack", batch=seq):
                 # every request's leaves get a leading batch dim
                 # (single-item requests gain one), then requests
@@ -362,15 +451,32 @@ class BatchedInferenceServer:
                 if self._batched_sharding is not None:
                     stacked = jax.device_put(stacked,
                                              self._batched_sharding)
+            t_dispatch = time.perf_counter() if self._traced else 0.0
             with span("server.dispatch", batch=seq):
-                # host -> device copy of the batch and the enqueue
+                # host -> device copy of the batch, the enqueue, and
+                # the device -> host copy queued right behind it
                 with self._lock:
                     params = self._params
                     version = self._params_version
                 out = self._apply(params, stacked)
+                for leaf in jax.tree.leaves(out):
+                    leaf.copy_to_host_async()
+        except Exception as e:  # propagate to callers, keep serving
+            self._fail(reqs, e)
+            return None
+        return _Flight(reqs, n, padded, seq, version, out, t0, t_dispatch)
+
+    def _reply(self, flight: _Flight) -> None:
+        """Second half of a batch: fetch its outputs and release its
+        callers, with the version it was dispatched with."""
+        reqs, seq = flight.reqs, flight.seq
+        span = self._obs.span
+        try:
             with span("server.fetch", batch=seq):
-                # device time + device -> host + the wait for the GIL
-                out_np = jax.tree.map(np.asarray, out)
+                # what is left of device time + device -> host, and the
+                # wait for the GIL: next to nothing when a successor's
+                # stack + dispatch ran in between
+                out_np = jax.tree.map(np.asarray, flight.out)
             with span("server.scatter", batch=seq):
                 off = 0
                 t_done = time.perf_counter()
@@ -392,12 +498,20 @@ class BatchedInferenceServer:
                 self._obs.observe_many(
                     "infer_latency_ms",
                     [(t_done - r.t_enq) * 1e3 for r in reqs])
+        except Exception as e:  # propagate to callers, keep serving
+            self._fail(reqs, e)
+            return
+        if self._traced:
+            self._obs.record("server.batch", flight.t0,
+                             time.perf_counter(), items=flight.n,
+                             padded=flight.padded, seq=seq)
         # stats() reads these from other threads; the serve thread is
         # the only writer but += is still a read-modify-write
         with self._lock:
             self._batches_served += 1
-            self._items_served += n
-        self._obs.on_server_batch(n, version, self._q.qsize())
+            self._items_served += flight.n
+        self._obs.on_server_batch(flight.n, flight.version,
+                                  self._q.qsize())
 
 
 def _pad_concat(xs: tuple, padded: int) -> np.ndarray:
@@ -622,7 +736,11 @@ class MultiPolicyInferenceServer:
     batch — plain jit when the batch is single-tenant, the stacked/
     gather-indexed coalesced forward when tenants mix. Admission keeps
     running while a forward is in flight: capacity freeing IS the
-    admission signal, there are no collect-then-serve rounds."""
+    admission signal, there are no collect-then-serve rounds.
+
+    The dispatch thread itself is serial (one forward fetched before
+    the next is stacked): `BatchedInferenceServer`'s one-forward-ahead
+    pipeline is NOT carried over, the module docstring says why."""
 
     def __init__(self, max_batch: int = 64, deadline_ms: float = 2.0,
                  *, mesh: Mesh | None = None, obs: Any = None,
