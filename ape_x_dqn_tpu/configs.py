@@ -259,8 +259,49 @@ class SmallThinkerConfig:
 
 
 @dataclass(frozen=True)
+class OuroConfig:
+    """The decoder of network.kind="ouro_q" (models/ouro_q.py), under
+    the key names of the model's own config.json (ByteDance/Ouro-2.6B,
+    `model_type` ouro); defaults are that model's. A stack of
+    `num_hidden_layers` blocks of full causal attention (no grouping:
+    as many key-value heads as query heads) and a dense SwiGLU MLP of
+    `intermediate_size`, RUN `total_ut_steps` TIMES WITH THE SAME
+    WEIGHTS; untied embedding and head. The net has no expert layer and
+    so no share of one: a chip's cut is a number of layers."""
+
+    hidden_size: int = 2048
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 16
+    head_dim: int = 128
+    intermediate_size: int = 5632
+    # the whole stack is applied this many times a forward pass
+    total_ut_steps: int = 4
+    # the exit gate's cumulative mass at which a token would stop
+    # looping; only 1.0 (the published value: nothing stops, every step
+    # runs) is built
+    early_exit_threshold: float = 1.0
+    max_position_embeddings: int = 65_536   # the most tokens in one pass
+    rope_theta: float = 1_000_000.0
+    vocab_size: int = 49_152
+    rms_norm_eps: float = 1e-6
+
+    def __post_init__(self) -> None:
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"network.ouro.num_key_value_heads="
+                f"{self.num_key_value_heads} must divide "
+                f"num_attention_heads={self.num_attention_heads}")
+        if self.total_ut_steps < 1:
+            raise ValueError(
+                f"network.ouro.total_ut_steps={self.total_ut_steps}: the "
+                f"stack runs at least once")
+
+
+@dataclass(frozen=True)
 class NetworkConfig:
     # mlp | nature_cnn | lstm_q | dpg | glm_moe_q | afmoe_q | smallthinker_q
+    # | ouro_q
     kind: str = "mlp"
     mlp_hidden: tuple[int, ...] = (256, 256)
     cnn_channels: tuple[int, ...] = (32, 64, 64)
@@ -280,6 +321,8 @@ class NetworkConfig:
     # the decoder of kind="smallthinker_q" (the same family, a third)
     smallthinker: SmallThinkerConfig = field(
         default_factory=SmallThinkerConfig)
+    # the decoder of kind="ouro_q" (the same family; no expert layer)
+    ouro: OuroConfig = field(default_factory=OuroConfig)
 
 
 @dataclass(frozen=True)
@@ -1215,6 +1258,72 @@ def _preset_smallthinker_tiny_q() -> RunConfig:
     )
 
 
+def _preset_ouro_2p6b_q() -> RunConfig:
+    """Config 9: Ouro-2.6B (ByteDance, a looped language model) as a
+    token-level Q-network, the decoder family's fourth net and its
+    first without experts. The sizes are the model's config.json
+    (https://huggingface.co/ByteDance/Ouro-2.6B): 48 blocks of full
+    attention (16 heads of 128, ungrouped) and a SwiGLU MLP of 5,632,
+    the whole stack run `total_ut_steps` = 4 times with the same
+    weights, 49,152 vocabulary rows. Whole it is 2.67 B parameters =
+    39.8 GiB at the learner's 16 B and check_hbm_fits refuses it: a run
+    gives one chip its pipeline stage with
+    network.ouro.num_hidden_layers
+    (benchmarks/configs/ouro_2p6b_1chip.json is the measured one). The
+    learner settings are this repo's: sequences of 4,096 tokens, the
+    model's own pre-training length, where float32 Q over the whole
+    vocabulary still fits."""
+    ou = OuroConfig()
+    return RunConfig(
+        name="ouro_2p6b_q",
+        total_env_frames=10_000_000_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=ou.vocab_size),
+        network=NetworkConfig(kind="ouro_q", dueling=False, ouro=ou),
+        # a stored sequence is 4,096 tokens: 1,024 of burn-in, whose
+        # keys and values - one set per (loop step, layer) - the
+        # trained 3,072 attend to without gradient. 8,192 sequences are
+        # the other decoders' token count, 0.63 GiB
+        replay=ReplayConfig(kind="sequence", capacity=8_192,
+                            seq_length=4_096, seq_overlap=2_048,
+                            burn_in=1_024, min_fill=64),
+        # batch 1: float32 Q over 49,152 actions is 0.56 GiB a copy at
+        # 3,072 trained tokens
+        learner=LearnerConfig(batch_size=1, n_step=5, value_rescale=True,
+                              target_sync_every=2500, lr=1e-4,
+                              sample_chunk=1, train_chunk=2),
+        # a query re-runs a window of up to 4,096 tokens (the family's
+        # stateless protocol): one at a time
+        actors=ActorConfig(num_actors=64, envs_per_actor=1),
+        inference=InferenceConfig(max_batch=1, deadline_ms=2.0),
+    )
+
+
+def _preset_ouro_tiny_q() -> RunConfig:
+    """ouro_2p6b_q's sibling for CPU tests: the same looped decoder at
+    hidden 64, 4 ungrouped heads of 16, an MLP of 96, a vocabulary of
+    64, 2 layers run 4 times, sequences of 32, float32."""
+    ou = OuroConfig(
+        hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
+        num_key_value_heads=4, head_dim=16, intermediate_size=96,
+        max_position_embeddings=32, vocab_size=64)
+    return RunConfig(
+        name="ouro_tiny_q",
+        total_env_frames=100_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=ou.vocab_size),
+        network=NetworkConfig(kind="ouro_q", dueling=False, ouro=ou,
+                              compute_dtype="float32"),
+        replay=ReplayConfig(kind="sequence", capacity=64, seq_length=32,
+                            seq_overlap=16, burn_in=12, min_fill=8),
+        learner=LearnerConfig(batch_size=4, n_step=3, value_rescale=True,
+                              target_sync_every=100, lr=1e-3,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=1, envs_per_actor=2),
+        inference=InferenceConfig(max_batch=8, deadline_ms=2.0),
+    )
+
+
 PRESETS = {
     "cartpole_smoke": _preset_cartpole_smoke,
     "pong": _preset_pong,
@@ -1227,6 +1336,8 @@ PRESETS = {
     "trinity_tiny_q": _preset_trinity_tiny_q,
     "smallthinker_21b_q": _preset_smallthinker_21b_q,
     "smallthinker_tiny_q": _preset_smallthinker_tiny_q,
+    "ouro_2p6b_q": _preset_ouro_2p6b_q,
+    "ouro_tiny_q": _preset_ouro_tiny_q,
 }
 
 

@@ -8,12 +8,13 @@ from ape_x_dqn_tpu.models.dpg import DPGActor, DPGCritic
 from ape_x_dqn_tpu.models.glm_moe_q import GlmMoeQNet
 from ape_x_dqn_tpu.models.afmoe_q import AfmoeQNet
 from ape_x_dqn_tpu.models.smallthinker_q import SmallThinkerQNet
+from ape_x_dqn_tpu.models.ouro_q import OuroQNet
 
 # network.kind -> the net's class: the token-level Q-networks of the
 # decoder_q family. A further decoder is a row here and in
 # `decoder_block`, a config block, and a row in runtime/family.family_of
 DECODER_NETS = {"glm_moe_q": GlmMoeQNet, "afmoe_q": AfmoeQNet,
-                "smallthinker_q": SmallThinkerQNet}
+                "smallthinker_q": SmallThinkerQNet, "ouro_q": OuroQNet}
 
 
 def decoder_block(net_cfg):
@@ -22,6 +23,7 @@ def decoder_block(net_cfg):
     return {"glm_moe_q": ("glm", net_cfg.glm),
             "afmoe_q": ("afmoe", net_cfg.afmoe),
             "smallthinker_q": ("smallthinker", net_cfg.smallthinker),
+            "ouro_q": ("ouro", net_cfg.ouro),
             }[net_cfg.kind]
 
 

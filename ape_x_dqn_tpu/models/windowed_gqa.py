@@ -34,18 +34,21 @@ def positions_after(cache, t: int):
 
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, cache,
            window: int | None, blocks: tuple[int, int],
-           about_mean: bool = False) -> jax.Array:
+           about_mean: bool = False,
+           recompute_delta: bool = False) -> jax.Array:
     """q [B, T, heads, d], k/v [B, T, kv heads, d] of the new positions,
     `cache` = (k, v) of the ones before or None -> [B, T, heads, d].
     `window`: a sliding layer's (the query counts), None for a full one;
     `blocks`: ops/blockwise_attention.py's (query, key) block sizes;
-    `about_mean`: its argument of that name (for a net without q/k
-    norms, whose keys and values share one large vector)."""
+    `about_mean`, `recompute_delta`: its arguments of those names (two
+    ways to serve a net without q/k norms, whose keys and values share
+    one large vector)."""
     with jax.named_scope("afmoe.attn.full" if window is None
                          else "afmoe.attn.sliding"):
         return blockwise_attention(q, k, v, cache, window=window,
                                    block_q=blocks[0], block_k=blocks[1],
-                                   about_mean=about_mean)
+                                   about_mean=about_mean,
+                                   recompute_delta=recompute_delta)
 
 
 def extend(cache, k: jax.Array, v: jax.Array, window: int | None):

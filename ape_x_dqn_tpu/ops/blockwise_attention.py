@@ -33,6 +33,30 @@ models/smallthinker_q.py asks for it (no q/k norms, no embedding scale);
 models/afmoe_q.py does not, and its program is what it was. float32
 compute is untouched either way.
 
+WITH `recompute_delta` THE BACKWARD PASS TAKES A ROW'S DELTA FROM ITS
+OWN WEIGHTS. ds = p (dp - delta) needs delta = sum_j p_j dp_j, the row's
+mean of dp under its weights. The plain pass reads it off the forward
+pass's output (delta = out . d_out, the usual saving: no second visit of
+the row's key blocks), which is the same number only as far as the
+forward pass's weights - exp(s - running max), rescaled block by block,
+over their float32 total - and the backward pass's exp(s - lse) are the
+same weights. On the v5e they are not to the last bits, a row of ds then
+sums to e = sum_j (p_j - p_fwd_j) dp_j and not to 0, and e multiplies
+whatever the keys share (dq) and is itself multiplied by whatever the
+values share (dp). In a net whose rows are one common vector plus a
+little of the token that is most of the q and k projections' gradient
+error (PERF.md section 6, PRs 39 and 41: on the chip 3.3-4.9 times
+bfloat16's own error on the worst such leaf where the autodiff of a
+materialised softmax reads 2.6, on the CPU no difference). With the
+argument a query block first walks its key blocks once for delta_i =
+sum_j p_ij dp_ij in float32, from the very p and dp the second walk
+recomputes, so every row of ds sums to zero as exactly as autodiff's
+would: two more tile products and one more exp a tile of the backward
+pass (7 products for 5), nothing more kept (the output is no residual
+then). models/ouro_q.py asks for it, and not for `about_mean`, which
+takes away what e multiplies and not e - and on that net's rows left
+some leaves worse than it found them (same place).
+
 THE CACHE CARRIES NO GRADIENT: `cache` enters under `stop_gradient`
 (ops/losses.make_r2d2_loss stops the prefix state's anyway), and the
 backward pass skips the key blocks that lie wholly inside it.
@@ -62,6 +86,9 @@ class _Geometry(NamedTuple):
     window: int | None
     block_q: int
     block_k: int
+    # the backward pass takes a row's delta from its own weights, in a
+    # first pass over the row's key blocks (`blockwise_attention`)
+    recompute_delta: bool = False
 
 
 def _divisor(n: int, target: int) -> int:
@@ -157,7 +184,7 @@ def _attend_fwd(geo, q, k, v):
     # projections and head norms (on the v5e, held to a float32
     # reference: 5 to 20 times bfloat16's own error on those leaves)
     out, lse = _forward(geo, q, k, v)
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, None if geo.recompute_delta else out, lse)
 
 
 def _attend_bwd(geo, res, d_out):
@@ -168,20 +195,29 @@ def _attend_bwd(geo, res, d_out):
     b, kv, g, t, d = q.shape
     bq, bk = geo.block_q, geo.block_k
     scale = d ** -0.5
-    delta = jnp.sum(out * d_out.astype(jnp.float32), axis=-1)  # [B,KV,G,T]
+    if not geo.recompute_delta:
+        # [B, KV, G, T]
+        delta = jnp.sum(out * d_out.astype(jnp.float32), axis=-1)
     with_grad_from = geo.first // bk     # key blocks before it: all cache
 
     def per_query_block(i, carry):
         dq, dk, dv = carry
         qi, doi = _tile(q, i, bq, 3), _tile(d_out, i, bq, 3)
-        lse_i, delta_i = _tile(lse, i, bq, 3), _tile(delta, i, bq, 3)
+        lse_i = _tile(lse, i, bq, 3)
+        if not geo.recompute_delta:
+            delta_i = _tile(delta, i, bq, 3)
 
-        def d_scores(j):
+        def weights(j):
+            """Tile (i, j)'s softmax weights and d_out . v, float32."""
             kj, vj = _tile(k, j, bk, 2), _tile(v, j, bk, 2)
             s, vis = _scores(geo, qi, kj, i, j)
             p = jnp.where(vis, jnp.exp(s - lse_i[..., None]), 0.0)
             dp = jnp.einsum("bkgtd,bksd->bkgts", doi, vj,
                             preferred_element_type=jnp.float32)
+            return p, dp, kj
+
+        def d_scores(j):
+            p, dp, kj = weights(j)
             ds = (p * (dp - delta_i[..., None]) * scale).astype(q.dtype)
             return p.astype(q.dtype), ds, kj
 
@@ -207,6 +243,13 @@ def _attend_bwd(geo, res, d_out):
             return add_dq(dq_i, ds, kj), dk, dv
 
         lo, hi = _bounds(geo, i)
+        if geo.recompute_delta:
+            def row_delta(j, total):
+                p, dp, _ = weights(j)
+                return total + (p * dp).sum(axis=-1)
+
+            delta_i = jax.lax.fori_loop(
+                lo, hi, row_delta, jnp.zeros((b, kv, g, bq), jnp.float32))
         split = jnp.clip(with_grad_from, lo, hi)
         dq_i = jax.lax.fori_loop(
             lo, split, cached_key_block,
@@ -286,12 +329,15 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         window: int | None = None,
                         block_q: int = BLOCK_Q,
                         block_k: int = BLOCK_K,
-                        about_mean: bool = False) -> jax.Array:
+                        about_mean: bool = False,
+                        recompute_delta: bool = False) -> jax.Array:
     """See the module docstring. `block_q` is cut to a divisor of T;
     the keys are padded in front to whole blocks of `block_k`.
     `about_mean`: keys and values go in less their mean
     (`_about_its_mean`; a net whose rows share one large vector asks
-    for it)."""
+    for it). `recompute_delta`: the backward pass walks a row's key
+    blocks twice, first for its delta (the other way to serve such a
+    net: the rows of ds then sum to zero whatever they share)."""
     b, t, heads, d = q.shape
     kv = k.shape[2]
     if cache is not None:
@@ -307,7 +353,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     bk = min(block_k, s)
     pad = -s % bk
     geo = _Geometry(pad=pad, first=pad + s - t, window=window,
-                    block_q=_divisor(t, block_q), block_k=bk)
+                    block_q=_divisor(t, block_q), block_k=bk,
+                    recompute_delta=recompute_delta)
     front = ((0, 0), (pad, 0), (0, 0), (0, 0))
     k, v = (jnp.pad(a, front).transpose(0, 2, 1, 3) for a in (k, v))
     q = q.reshape(b, t, kv, heads // kv, d).transpose(0, 2, 3, 1, 4)

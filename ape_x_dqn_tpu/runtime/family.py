@@ -24,25 +24,31 @@ and what differs is the state a sequence starts from:
   attention cache and its burn-in prefix is its context — the prefix
   pass leaves the net's cache (glm_moe_q: per layer a latent c_kv and
   k_rope; afmoe_q and smallthinker_q: per layer (k, v), every position
-  for a full layer and the last window - 1 for a sliding one), the loss
-  stops its gradient, the trained steps attend to it. The item has no
+  for a full layer and the last window - 1 for a sliding one; ouro_q:
+  per (loop step, layer) (k, v)), the loss stops its gradient, the
+  trained steps attend to it. The item has no
   state entry at all. The server is stateless too: a query carries the
   last <= L token ids ({obs, ctx, n} -> {q, ctx, n}) and the server
   re-runs the window; a per-slot cache inside
   parallel/inference_server.py, which would make a step cost one token
   instead of a window, is what is missing.
 
-The decoder_q family has three nets (network.kind "glm_moe_q",
-"afmoe_q", "smallthinker_q"), which share models/expert_layer.py (the
-plan and the application of an expert layer); the last two also share
-models/windowed_gqa.py (the attention call and the cache of two kinds).
+The decoder_q family has four nets. Three (network.kind "glm_moe_q",
+"afmoe_q", "smallthinker_q") share models/expert_layer.py (the plan and
+the application of an expert layer); the last two of them and the
+fourth, "ouro_q" - a stack of dense blocks run several times with the
+same weights, no expert layer - share models/windowed_gqa.py (the
+attention call and its cache).
 A further decoder registers with: its net in models/ with the surface
-the family reads (`init`, `apply`, `apply_with_stats` -> stats
-`expert_rows` and `topk`, `param_count`, `step_transient_bytes`,
-`num_actions`, `router_trains`, `share`), a config block in
-NetworkConfig, a row in models.DECODER_NETS, in models.decoder_block
-and in `family_of`; tools/apexlint's `config_coverage` learns the
-block's name. Nothing else here names a decoder.
+the family reads (`init`, `apply`, `apply_with_stats`, `param_count`,
+`step_transient_bytes`, `num_actions`; a net WITH an expert layer also
+`router_trains`, `share` and the stats `expert_rows` and `topk`, a net
+without one the stats `block_applications` and `exit_gates`: the
+family's loss reads expert statistics only from a net that has a
+`share`), a config block in NetworkConfig, a row in
+models.DECODER_NETS, in models.decoder_block and in `family_of`;
+tools/apexlint's `config_coverage` learns the block's name. Nothing
+else here names a decoder.
 
 How a further Q-learning family registers: its net in models/ with a
 row in `build_network`; its kind in `family_of`; a row in
@@ -75,8 +81,8 @@ from ape_x_dqn_tpu.utils.rng import component_key
 
 def family_of(cfg: RunConfig) -> str:
     return {"lstm_q": "r2d2", "dpg": "dpg", "glm_moe_q": "decoder_q",
-            "afmoe_q": "decoder_q",
-            "smallthinker_q": "decoder_q"}.get(cfg.network.kind, "dqn")
+            "afmoe_q": "decoder_q", "smallthinker_q": "decoder_q",
+            "ouro_q": "decoder_q"}.get(cfg.network.kind, "dqn")
 
 
 # families whose replay items are whole sequences (the staging unit is
@@ -253,32 +259,11 @@ def r2d2_family(net_apply_seq: Callable, lcfg, rcfg, compute_dtype=None):
         metric_keys=("valid_frac",))
 
 
-def decoder_q_family(net: Any, lcfg, rcfg):
-    """Token-level Q-learning on a decoder: make_r2d2_loss as it
-    stands, over stored token sequences with no stored state — the
-    loss's burn-in is the prefix pass that leaves the net's latent
-    cache (models/glm_moe_q.py). The net also says how its expert
-    layers were loaded; the loss's signature has no room for that, so
-    each of its four net applications leaves its counts in a list the
-    family's loss reads back inside the same trace:
-    `moe_rows` rows routed to the experts held here, summed over the
-    layers and the four applications (one forward each); `moe_rows_grad`
-    those of the online net's trained steps, which also pay a
-    recomputation and a backward; `moe_load_max_over_mean` the fullest
-    held expert over the mean, online net, mean over layers;
-    `moe_compact_share` of the step's expert-layer applications (layers
-    x the loss's net applications) the share whose rows fit the layer's
-    buffers (`expert_layer.capacity`; 1.0 where that is the full
-    width): under 1.0 a step paid the full width somewhere. The same
-    way the aux hands back what the loss saw and chose — `q` the online
-    net's Q-values on the trained steps, `topk_online`/`topk_target`
-    [expert layers, B, L, k] the experts selected — for whoever
-    differentiates this function to hold it to a reference (the
-    benchmark's check); a train step reads none of the three and XLA
-    drops them there."""
+def _routed_loss(net: Any, r2d2: Callable) -> Callable:
+    """decoder_q_family's loss for a net WITH an expert layer (its
+    docstring says what the counters are). `r2d2(apply)` -> the R2D2
+    sequence loss over `apply`."""
     from ape_x_dqn_tpu.models.expert_layer import capacity, fits
-    from ape_x_dqn_tpu.ops.losses import SequenceBatch, make_r2d2_loss
-    from ape_x_dqn_tpu.runtime.learner import LearnerFamily
 
     def loss_fn(params, target_params, batch, is_weights):
         tally, fitted, seen = [], [], []
@@ -291,12 +276,7 @@ def decoder_q_family(net: Any, lcfg, rcfg):
             seen.append((q, stats["topk"]))
             return q, state
 
-        loss, aux = make_r2d2_loss(
-            apply, burn_in=rcfg.burn_in, n_step=lcfg.n_step,
-            gamma=lcfg.gamma, huber_delta=lcfg.huber_delta,
-            double=lcfg.double_dqn, rescale=lcfg.value_rescale,
-            priority_eta=rcfg.priority_eta)(
-            params, target_params, batch, is_weights)
+        loss, aux = r2d2(apply)(params, target_params, batch, is_weights)
         # the loss applies: online burn-in, target burn-in (when
         # burn_in > 0), then online and target over the trained steps
         online = tally[0::2]
@@ -319,17 +299,94 @@ def decoder_q_family(net: Any, lcfg, rcfg):
                    [t for _, t in seen[1::2]], axis=2)}
         return loss, aux
 
+    return loss_fn
+
+
+def _looped_loss(net: Any, r2d2: Callable) -> Callable:
+    """decoder_q_family's loss for a net WITHOUT an expert layer, whose
+    blocks are applied several times a forward pass (models/ouro_q.py):
+    `loop_block_applications` the blocks applied per net forward (loop
+    steps x layers, counted where they are applied; the online net's
+    pass over the trained steps), `loop_exit_mass_last` the mean over
+    the trained tokens of the exit distribution's mass on the last loop
+    step, the product over the earlier steps of (1 - lambda_t): the one
+    place the net's exit gate is seen. `q` as for a routed net."""
+
+    def loss_fn(params, target_params, batch, is_weights):
+        seen = []
+
+        def apply(p, tokens, state):
+            q, state, stats = net.apply_with_stats(p, tokens, state)
+            seen.append((q, stats))
+            return q, state
+
+        loss, aux = r2d2(apply)(params, target_params, batch, is_weights)
+        # the online net over the trained steps: the last but one
+        q, stats = seen[-2]
+        stay = 1.0 - stats["exit_gates"][:-1]       # [steps - 1, B, T]
+        return loss, {
+            **aux,
+            "loop_block_applications": stats["block_applications"].astype(
+                jnp.float32),
+            "loop_exit_mass_last": jnp.prod(stay, axis=0).mean(),
+            "q": q}
+
+    return loss_fn
+
+
+def decoder_q_family(net: Any, lcfg, rcfg):
+    """Token-level Q-learning on a decoder: make_r2d2_loss as it
+    stands, over stored token sequences with no stored state — the
+    loss's burn-in is the prefix pass that leaves the net's latent
+    cache (models/glm_moe_q.py). The net also says how its layers were
+    used; the loss's signature has no room for that, so each of its
+    four net applications leaves its statistics in a list the family's
+    loss reads back inside the same trace. WHICH statistics depends on
+    what the net has: expert statistics are read only from a net with
+    an expert layer (it has a `share`: `_routed_loss`), a net without
+    one reports its loop (`_looped_loss`).
+
+    A routed net's:
+    `moe_rows` rows routed to the experts held here, summed over the
+    layers and the four applications (one forward each); `moe_rows_grad`
+    those of the online net's trained steps, which also pay a
+    recomputation and a backward; `moe_load_max_over_mean` the fullest
+    held expert over the mean, online net, mean over layers;
+    `moe_compact_share` of the step's expert-layer applications (layers
+    x the loss's net applications) the share whose rows fit the layer's
+    buffers (`expert_layer.capacity`; 1.0 where that is the full
+    width): under 1.0 a step paid the full width somewhere. The same
+    way the aux hands back what the loss saw and chose — `q` the online
+    net's Q-values on the trained steps, `topk_online`/`topk_target`
+    [expert layers, B, L, k] the experts selected — for whoever
+    differentiates this function to hold it to a reference (the
+    benchmark's check); a train step reads none of the three and XLA
+    drops them there."""
+    from ape_x_dqn_tpu.ops.losses import SequenceBatch, make_r2d2_loss
+    from ape_x_dqn_tpu.runtime.learner import LearnerFamily
+
+    def r2d2(apply):
+        return make_r2d2_loss(
+            apply, burn_in=rcfg.burn_in, n_step=lcfg.n_step,
+            gamma=lcfg.gamma, huber_delta=lcfg.huber_delta,
+            double=lcfg.double_dqn, rescale=lcfg.value_rescale,
+            priority_eta=rcfg.priority_eta)
+
+    routed = hasattr(net, "share")
     return LearnerFamily(
         name="decoder_q",
-        loss_fn=loss_fn,
+        loss_fn=(_routed_loss if routed else _looped_loss)(net, r2d2),
         make_batch=lambda items: SequenceBatch(
             obs=items["obs"], actions=items["actions"],
             rewards=items["rewards"], terminals=items["terminals"],
             mask=items["mask"], init_state=()),
         net_apply=net.apply,
         apply_attr="net_apply_seq",
-        metric_keys=("valid_frac", "moe_rows", "moe_rows_grad",
-                     "moe_load_max_over_mean", "moe_compact_share"))
+        metric_keys=(("valid_frac", "moe_rows", "moe_rows_grad",
+                      "moe_load_max_over_mean", "moe_compact_share")
+                     if routed else
+                     ("valid_frac", "loop_block_applications",
+                      "loop_exit_mass_last")))
 
 
 # family name -> (cfg, net) -> LearnerFamily. DPG is not a row: its

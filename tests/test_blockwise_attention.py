@@ -31,17 +31,22 @@ def dense_attention(q, k, v, cache, window):
     (12, 0, 8), (20, 7, 8),             # sliding: prefix, after a trimmed one
     (20, 12, 8),                        # a cache longer than the window - 1
     (16, 5, 3)])                        # key padding and a window < a block
-def test_blockwise_attention_equals_dense_masked(t, cached, window):
+@pytest.mark.parametrize("recompute_delta", [False, True])
+def test_blockwise_attention_equals_dense_masked(t, cached, window,
+                                                 recompute_delta):
     """Forward and every gradient, both masks, with and without a
-    cache; blocks of 4 so that diagonal, window edge, padding and the
-    cache boundary each fall inside a tile somewhere."""
+    cache, the backward pass with delta from the output and from its
+    own first walk of the key blocks; blocks of 4 so that diagonal,
+    window edge, padding and the cache boundary each fall inside a tile
+    somewhere."""
     rng = np.random.default_rng(t + cached)
     new = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa
     q, k, v = new(2, t, 4, 16), new(2, t, 2, 16), new(2, t, 2, 16)
     cache = (new(2, cached, 2, 16), new(2, cached, 2, 16)) if cached else None
     weight = new(2, t, 4, 16)
     blockwise = lambda *a: blockwise_attention(            # noqa: E731
-        *a, window=window, block_q=4, block_k=4)
+        *a, window=window, block_q=4, block_k=4,
+        recompute_delta=recompute_delta)
     np.testing.assert_allclose(blockwise(q, k, v, cache),
                                dense_attention(q, k, v, cache, window),
                                atol=1e-5)
@@ -104,6 +109,41 @@ def test_a_group_of_seven_and_thirty_two_key_blocks(t, cached, window):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
 
+# `ouro_offline`'s geometry at 1/128 of its lengths (ISSUE 41): a GROUP
+# OF ONE (16 query heads on 16 key-value heads; 4 on 4 here) at the
+# model's head size of 128, every layer a full one, a prefix of 8 =
+# 1,024 / 128 positions and 24 trained, 8 key blocks of 4 in all - plain,
+# with the `recompute_delta` that OuroQNet asks for and with SmallThinker's
+# `about_mean` (float32: no op). The shapes needed nothing of
+# ops/blockwise_attention.py; the chip's check asked for the argument.
+@pytest.mark.parametrize("how", [{}, {"recompute_delta": True},
+                                 {"about_mean": True}],
+                         ids=["plain", "recompute_delta", "about_mean"])
+@pytest.mark.parametrize("t,cached", [
+    (8, 0),        # the prefix pass
+    (24, 8)])      # the trained segment over the prefix's cache
+def test_a_group_of_one_at_head_size_128_with_a_cache(t, cached, how):
+    rng = np.random.default_rng(11 * t + cached)
+    new = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa
+    q, k, v = new(2, t, 4, 128), new(2, t, 4, 128), new(2, t, 4, 128)
+    cache = (new(2, cached, 4, 128), new(2, cached, 4, 128)) if cached \
+        else None
+    weight = new(2, t, 4, 128)
+    blockwise = lambda *a: blockwise_attention(            # noqa: E731
+        *a, window=None, block_q=4, block_k=4, **how)
+    if cached:
+        assert (cached + t) // 4 == 8
+    np.testing.assert_allclose(blockwise(q, k, v, cache),
+                               dense_attention(q, k, v, cache, None),
+                               atol=1e-5)
+    got = jax.jit(jax.grad(lambda *a: (blockwise(*a) * weight).sum(),
+                           (0, 1, 2)))(q, k, v, cache)
+    want = jax.grad(lambda *a: (dense_attention(*a, None) * weight).sum(),
+                    (0, 1, 2))(q, k, v, cache)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
 def test_a_sliding_layer_visits_the_blocks_its_window_admits_and_no_more():
     """At the cell's proportions (window = 8 blocks, 32 key blocks) a
     query block of a sliding layer visits 9 key blocks and the last
@@ -120,12 +160,13 @@ def test_a_sliding_layer_visits_the_blocks_its_window_admits_and_no_more():
     assert (int(lo), int(hi)) == (0, 32)
 
 
-def _errors_on_near_equal_keys_and_values() -> list[float]:
+def _errors_on_near_equal_keys_and_values(**how) -> list[float]:
     """bfloat16, keys, values and the output's cotangent each one common
     vector plus 1% of noise (what a decoder without q/k norms has after
     a few optimizer steps): the relative errors of the queries', keys'
     and values' gradients against dense float32 attention on the same
-    rounded inputs."""
+    rounded inputs. `how`: the call's way with such rows (by default
+    `about_mean`)."""
     from ape_x_dqn_tpu.ops import blockwise_attention as ba
 
     rng = np.random.default_rng(0)
@@ -138,7 +179,8 @@ def _errors_on_near_equal_keys_and_values() -> list[float]:
     k, v, weight = (bf(near(1, t, 1, d)), bf(near(1, t, 1, d)),
                     bf(near(1, t, 2, d)))
     got = jax.grad(lambda *a: (f32(ba.blockwise_attention(
-        *a, block_q=64, block_k=64, about_mean=True)) * f32(weight)).sum(),
+        *a, block_q=64, block_k=64, **(how or {"about_mean": True})))
+        * f32(weight)).sum(),
         (0, 1, 2))(q, k, v)
     want = jax.grad(lambda *a: (dense_attention(
         *a, None, None) * f32(weight)).sum(), (0, 1, 2))(
@@ -160,6 +202,36 @@ def test_keys_and_values_go_in_less_their_mean_below_float32(monkeypatch):
     assert dq < 0.02 and dk < 0.01 and dv < 0.01
     monkeypatch.setattr(ba, "_about_its_mean", lambda x: (x, None))
     assert _errors_on_near_equal_keys_and_values()[0] > 2.0
+
+
+def test_delta_from_the_backward_passes_own_weights():
+    """`recompute_delta` (ISSUE 41's check on the chip): a row of ds
+    sums to zero whatever the forward pass's weights were, so the row
+    sum has nothing to leave on what keys and values share. Read here,
+    on the same near-equal rows: the queries' gradient 5.0 of its norm
+    with delta from the output, 0.14 with the first walk (what is left
+    is the rounding of ds itself against the keys' common vector, which
+    the autodiff of a materialised bfloat16 softmax has too and
+    `about_mean` takes away); keys and values as with `about_mean`."""
+    dq, dk, dv = _errors_on_near_equal_keys_and_values(recompute_delta=True)
+    assert dq < 0.3 and dk < 0.01 and dv < 0.01
+    assert _errors_on_near_equal_keys_and_values(about_mean=False)[0] > 2.0
+
+
+def test_the_first_walk_is_two_tile_products_and_an_exp():
+    """What `recompute_delta` adds to the backward pass, and that the
+    plain pass holds none of it."""
+    x = jnp.ones((1, 16, 2, 4), jnp.float32)
+
+    def ops(flag):
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: blockwise_attention(
+                q, k, v, block_q=4, block_k=4,
+                recompute_delta=flag).sum(), (0, 1, 2)))(x, x, x))
+        return text.count("dot_general"), text.count(" exp ")
+
+    (dots, exps), (dots_twice, exps_twice) = ops(False), ops(True)
+    assert (dots_twice, exps_twice) == (dots + 2, exps + 1)
 
 
 def test_float32_attention_takes_no_mean():

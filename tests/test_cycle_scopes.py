@@ -60,6 +60,10 @@ PROGRAMS = {
     # 39); its second hash is the same tree's with no dense level
     "smallthinker_tiny_q": ("smallthinker_tiny_q", ["replay.capacity=64"],
                             2, "6d3781211705c6a0", "60c188e6200430d0"),
+    # the family's fourth net, the one without experts, pinned at the PR
+    # that added it (ISSUE 41) beside the three that must not move
+    "ouro_tiny_q": ("ouro_tiny_q", ["replay.capacity=64"], 2,
+                    "12a333f806383e44", "ef83733402056c73"),
     "dist": ("pong", ["parallel.dp=2", "parallel.tp=1",
                       "replay.capacity=4096", "replay.min_fill=512"], 8,
              "8edfe2412a4bc64f", "6668f8be4d7f2de8"),
@@ -68,7 +72,7 @@ PROGRAMS = {
                  "836445fb85177e4e", "a376acdbc487b640"),
 }
 RELABELS = ("glm_tiny_q", "trinity_tiny_q", "smallthinker_tiny_q",
-            "apex_dpg")
+            "ouro_tiny_q", "apex_dpg")
 QUIET = ["actors.num_actors=0", "eval_episodes=0", "eval_every_steps=0"]
 
 

@@ -124,6 +124,58 @@ def test_build_learner_trains_with_todays_metric_keys(family, dist):
     assert hasattr(learner, "net_apply") == (family == "dqn")
 
 
+DECODER_KEYS = {
+    # a net WITH an expert layer reports it; a net without one reports
+    # its loop, and nothing of an expert layer
+    "glm_tiny_q": {"valid_frac", "moe_rows", "moe_rows_grad",
+                   "moe_load_max_over_mean", "moe_compact_share"},
+    "ouro_tiny_q": {"valid_frac", "loop_block_applications",
+                    "loop_exit_mass_last"}}
+
+
+@pytest.mark.parametrize("preset", list(DECODER_KEYS))
+def test_decoder_q_family_reads_expert_statistics_only_from_a_net_with_them(
+        preset):
+    """`decoder_q_family` over a net with and without an expert layer:
+    the metric keys, no `moe_*` from the looped net, and the aux that
+    the benchmark's check differentiates for (`q`; the selections only
+    where there is one)."""
+    from ape_x_dqn_tpu.configs import get_config
+
+    cfg = get_config(preset)
+    net = build_network(cfg.network, None)
+    assert hasattr(net, "share") == (preset == "glm_tiny_q")
+    family = learner_family(cfg, net)
+    assert family.name == "decoder_q"
+    assert set(family.metric_keys) == DECODER_KEYS[preset]
+    length, n = cfg.replay.seq_length, cfg.learner.batch_size
+    rng = np.random.default_rng(0)
+    items = {
+        "obs": rng.integers(0, net.num_actions, (N, length)).astype(np.int32),
+        "actions": rng.integers(0, net.num_actions,
+                                (N, length)).astype(np.int32),
+        "rewards": rng.normal(size=(N, length)).astype(np.float32),
+        "terminals": np.zeros((N, length), np.float32),
+        "mask": np.ones((N, length), np.float32)}
+    params = net.init(jax.random.key(0))
+    _, aux = jax.jit(family.loss_fn)(
+        params, params, family.make_batch(
+            {k: v[:n] for k, v in items.items()}), jnp.ones(n))
+    assert set(family.metric_keys) <= set(aux) and "q" in aux
+    assert ("topk_online" in aux) == (preset == "glm_tiny_q")
+    replay = PrioritizedReplay(capacity=32)
+    learner = build_learner(cfg, net, replay)
+    assert type(learner) is SingleChipLearner
+    state = learner.init(
+        params, replay.init(sequence_item_spec((), np.int32, length, {})),
+        jax.random.key(1))
+    state = learner.add(state, items, np.ones(N, np.float32))
+    state, m = learner.train_many(state, 2)
+    assert set(m) == STEP_KEYS | DECODER_KEYS[preset]
+    assert any(k.startswith("moe_") for k in m) == (preset == "glm_tiny_q")
+    assert np.isfinite(float(m["loss"]))
+
+
 def test_learner_family_is_an_immutable_value():
     cfg, net, *_ = _tiny("dqn", False)
     fam = learner_family(cfg, net)
