@@ -261,7 +261,7 @@ class DistLearner(SingleChipLearner):
         add_lockstep, NOT jax.vmap(add): vmap batches the in-place
         dynamic_update_slice ring write into a lax.scatter, which
         materializes a full shard-storage copy per add (the exact HLO
-        temp the byte-row layout eliminated — replay/packing.py). The
+        temp the packed-row layout eliminated — replay/packing.py). The
         lockstep form exploits the dist ingest contract (equal [dp, B]
         blocks every add -> equal shard cursors) to write all shards
         with one in-place multi-axis DUS.

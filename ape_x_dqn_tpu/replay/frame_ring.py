@@ -54,7 +54,7 @@ is a row. As uint8 the TPU packs FOUR ROWS into each 32-bit word
 shared with three neighbours and the sample gather fetched four rows
 for each one it returned: 86 ns a row against 25 for the same bytes as
 words (PERF.md §6, PR 29). Bytes become words on the way in
-(`_as_words`) and pixels again only in the sampled batch (`_gather`)
+(`as_words`) and pixels again only in the sampled batch (`_gather`)
 and in `read_region`; nothing else reads the leaf.
 
 Dead padding slots carry tree priority 0 and are never sampled (the
@@ -74,9 +74,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ape_x_dqn_tpu.ops import sum_tree
-from ape_x_dqn_tpu.replay.packing import (dus_rows, dus_rows_per_shard,
-                                          frame_mode, pad128,
-                                          ring_write_size)
+from ape_x_dqn_tpu.replay.packing import (as_bytes, as_words, dus_rows,
+                                          dus_rows_per_shard, frame_mode,
+                                          pad128, ring_write_size,
+                                          stacks_of_four)
 from ape_x_dqn_tpu.replay.prioritized import (PrioritizedReplay,
                                               ReplayState, ring_cursor,
                                               ring_finish)
@@ -199,42 +200,6 @@ class FrameSegmentBuilder:
         return out
 
 
-def _as_words(rows: jax.Array) -> jax.Array:
-    """uint8 [..., 4n] -> uint32 [..., n]: word k holds bytes
-    4k..4k+3, least significant first. Written as shifts and ORs, not
-    as `bitcast_convert_type` of a [..., n, 4] view: the two give the
-    same words, but XLA:TPU lays the bitcast's result out rows-minor
-    and, in the per-shard directed write on the mesh, then copies the
-    whole ring to that layout rather than the block to the ring's
-    (add_at_lockstep at flagship size: 9.6 GB of HLO temp, out of
-    memory; as arithmetic every write keeps the byte rows' temp)."""
-    b = rows.reshape(*rows.shape[:-1], -1, 4).astype(jnp.uint32)
-    return b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) | (b[..., 3] << 24)
-
-
-def _as_bytes(words: jax.Array) -> jax.Array:
-    """uint32 [..., n] -> uint8 [..., 4n], the inverse of _as_words."""
-    return jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(
-        *words.shape[:-1], -1)
-
-
-def _byte_transpose(w: jax.Array) -> list[jax.Array]:
-    """Four uint32 arrays (the leading axis of `w`) -> four: byte f of
-    out[i] is byte i of w[f] — a 4x4 transpose of the bytes held at
-    one position, as two rounds of mask-shift-OR on whole words (16-bit
-    halves between w[0]/w[2] and w[1]/w[3], then bytes between the
-    pairs). out[i] is pixel 4k+i of each of the four frames, packed in
-    the order a [..., stack] uint8 array keeps them."""
-    lo, hi = jnp.uint32(0x0000FFFF), jnp.uint32(0xFFFF0000)
-    even, odd = jnp.uint32(0x00FF00FF), jnp.uint32(0xFF00FF00)
-    t0 = (w[0] & lo) | (w[2] << 16)
-    t1 = (w[1] & lo) | (w[3] << 16)
-    t2 = (w[0] >> 16) | (w[2] & hi)
-    t3 = (w[1] >> 16) | (w[3] & hi)
-    return [(t0 & even) | ((t1 & even) << 8), ((t0 >> 8) & even) | (t1 & odd),
-            (t2 & even) | ((t3 & even) << 8), ((t2 >> 8) & even) | (t3 & odd)]
-
-
 class FrameRingReplay(PrioritizedReplay):
     """Device-side prioritized replay over segment storage.
 
@@ -330,7 +295,7 @@ class FrameRingReplay(PrioritizedReplay):
                            + [(0, self.frame_row - self.frame_bytes)])
         # pack before the ring write: dus_rows' own astype converts
         # VALUES and would store one pixel per word
-        rows = _as_words(rows)
+        rows = as_words(rows)
         storage = dict(state.storage)
         if per_shard:
             storage["frames"] = dus_rows_per_shard(
@@ -394,7 +359,7 @@ class FrameRingReplay(PrioritizedReplay):
         a cold round trip restages bit-identically."""
         g = block
         st = state.storage
-        rows = _as_bytes(jax.lax.dynamic_slice_in_dim(
+        rows = as_bytes(jax.lax.dynamic_slice_in_dim(
             st["frames"], seg0 * self.F, g * self.F))
         items = {"seg_frames": rows[:, :self.frame_bytes].reshape(
             g, self.F, self.h, self.w)}
@@ -440,10 +405,11 @@ class FrameRingReplay(PrioritizedReplay):
         first conv reads the batch in the lanes with the stack beside
         it, i.e. 32-bit words [H*W, B] whose four bytes are the four
         frames' values of one pixel. A stack of four is therefore
-        built on words: a 4x4 byte transpose across the four frames'
-        words (`_byte_transpose`), each result transposed rows x words
-        as a 32-bit 2-D transpose, the four pixel phases interleaved,
-        and ONE bitcast to uint8 — on the chip a single fusion from
+        built on words (`packing.stacks_of_four`): a 4x4 byte
+        transpose across the four frames' words, each result transposed
+        rows x words as a 32-bit 2-D transpose, the four pixel phases
+        interleaved, and ONE bitcast to uint8 — on the chip a single
+        fusion from
         the gathered words to conv1's operand layout. Of the forms
         timed in the real step this was the fastest (PERF.md §6,
         PR 29); turning the words back into bytes first and
@@ -466,14 +432,10 @@ class FrameRingReplay(PrioritizedReplay):
             with jax.named_scope("replay.sample_gather"):
                 f = st["frames"][offs + rows_base[None, :]]  # [st,B,words]
             if self.stack != 4:      # plain form: bytes, then transpose
-                f = _as_bytes(f)[..., :self.frame_bytes].reshape(
+                f = as_bytes(f)[..., :self.frame_bytes].reshape(
                     self.stack, -1, self.h, self.w)
                 return jnp.transpose(f, (1, 2, 3, 0))    # -> [B,H,W,st]
-            # [words, B] x4, pixel 4k+i of the stack in word k of px[i]
-            px = [p.T for p in _byte_transpose(f)]
-            px = jnp.stack(px, axis=1).reshape(self.frame_row, -1)
-            obs = jax.lax.bitcast_convert_type(
-                px[:self.frame_bytes], jnp.uint8)        # [H*W,B,st]
+            obs = stacks_of_four(f, self.frame_bytes)    # [H*W,B,st]
             return obs.reshape(self.h, self.w, -1, self.stack) \
                 .transpose(2, 0, 1, 3)                   # -> [B,H,W,st]
 

@@ -14,26 +14,31 @@ total — OOM on a 15.75GB chip).
 
 Two design rules eliminate both costs:
 
-1. **Pack pixel leaves into exactly-tiled byte rows.** Store
-   [capacity, pad128(prod(frame_dims))] uint8 — the minor dim a
-   multiple of 128 and the major dim a multiple of 32 makes the padded
-   tiled layout bit-identical to the compact layout, so no relayout
-   copy can exist anywhere, and the storage overhead is the row
-   padding alone (<=1.6%, e.g. 7056 -> 7168 bytes for an 84x84 frame).
-   Unpacking after a sample's row gather touches only the sampled
-   batch (MBs, not GBs).
+1. **Pack pixel leaves into exactly-tiled rows of 32-bit words.**
+   Store [capacity, pad_row(prod(frame_dims)) // 4] uint32, word k
+   holding bytes 4k..4k+3 of the row, least significant first — the
+   minor dim a multiple of 128 words and the major dim a multiple of 8
+   makes the padded tiled layout bit-identical to the compact layout,
+   so no relayout copy can exist anywhere, and the storage overhead is
+   the row padding alone (1.6% for an 84x84 frame: 7056 -> 7168 bytes
+   = 14 x 128 words). Unpacking after a sample's row gather touches
+   only the sampled batch (MBs, not GBs).
 
-   Which stores keep BYTE rows: the packed stores of this module —
-   flat DQN/DPG transitions and R2D2's sequences (PixelPacker). The
-   frame ring does not (replay/frame_ring.py, PR 29): its rows are
-   uint32 words, because a uint8 array's tile packs four ROWS into one
-   32-bit word and a row gather then fetches four rows for every one
-   it returns (measured 86 -> 25 ns a row). The same holds for the
-   rows here (R2D2's sequence gather: ~66 ns a row, PERF.md §7); they
-   stay bytes for now because R2D2's pixel path behind the gather was
-   tuned form by form on uint8 frames (PR 27) and moving its rows is an
-   experiment with a before and after of its own. dus_rows below
-   serves both and stays dtype-agnostic.
+   Which stores keep BYTE rows: none. A uint8 array's tile packs four
+   ROWS into one 32-bit word (T(8,128)(4,1)), so a row gather from a
+   uint8 buffer fetches four rows for every one it returns: the frame
+   ring's gather went 86 -> 25 ns a row when its rows became words
+   (replay/frame_ring.py, PR 29), R2D2's sequence gather 70 -> 25
+   (the fusion alone; with the relayout of what it gathered, the whole
+   `replay.sample_gather` scope, 117 -> 33) when the packed stores of
+   this module followed (PixelPacker, PR 42; PERF.md §6). The cost
+   belongs to the dtype's tile and not to a workload, so every
+   packable leaf is stored as words: flat DQN transitions, R2D2's
+   sequences. Bytes become words
+   inside the add jit (`as_words`) and bytes again only in what a
+   sample or `read_region` hands out (`as_bytes`); what leaves the
+   device — cold segments, replay-bearing checkpoints — is bytes.
+   dus_rows below serves every store and stays dtype-agnostic.
 
 2. **Ring writes are `dynamic_update_slice`, never scatter.** A
    scatter into a large donated buffer still materializes a full copy
@@ -67,16 +72,86 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Minor-dim tile width (lanes) shared by every TPU dtype; uint8 arrays
-# additionally want the second-minor dim a multiple of 32 (8 sublanes x
-# 4-byte packing) for the padded layout to equal the compact one.
+# Minor-dim tile width (lanes) shared by every TPU dtype; uint32 arrays
+# additionally want the second-minor dim a multiple of 8 (sublanes) for
+# the padded layout to equal the compact one.
 LANE = 128
-U8_SUBLANE = 32
+WORD = 4    # bytes in one 32-bit word
 
 
 def pad128(n: int) -> int:
     """Round up to the 128-byte lane tile."""
     return -(-int(n) // LANE) * LANE
+
+
+def pad_row(n: int) -> int:
+    """Round a row's bytes up to whole lane tiles of WORDS (512 B)."""
+    return -(-int(n) // (LANE * WORD)) * LANE * WORD
+
+
+# -- bytes <-> 32-bit words (every word-row store: this module's packer,
+# replay/frame_ring.py) --------------------------------------------------
+
+
+def as_words(rows: jax.Array, strided: bool = False) -> jax.Array:
+    """uint8 [..., 4n] -> uint32 [..., n]: word k holds bytes
+    4k..4k+3, least significant first. Written as shifts and ORs, not
+    as `bitcast_convert_type` of a [..., n, 4] view: the two give the
+    same words, but XLA:TPU lays the bitcast's result out rows-minor
+    and, in the per-shard directed write on the mesh, then copies the
+    whole ring to that layout rather than the block to the ring's
+    (add_at_lockstep at flagship size: 9.6 GB of HLO temp, out of
+    memory; as arithmetic every write keeps the byte rows' temp).
+
+    `strided`: byte i of every word as the stride-4 slice `[i::4]` of
+    the rows, widened after the slice — the same words again. The
+    view's form widens the whole block first: for the packed store's
+    block of 64 R2D2 sequences (38 MB) `add` compiled to 294 MiB of
+    HLO temp, held by every add in flight, and the cell's peak HBM
+    rose 1.2%; sliced first it compiles to 0 (PERF.md §6, PR 42). The
+    frame ring's blocks are a fifteenth of that and its programs are
+    pinned on the view's form."""
+    if strided:
+        b = [rows[..., i::WORD].astype(jnp.uint32) for i in range(WORD)]
+        return b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)
+    b = rows.reshape(*rows.shape[:-1], -1, WORD).astype(jnp.uint32)
+    return b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) | (b[..., 3] << 24)
+
+
+def as_bytes(words: jax.Array) -> jax.Array:
+    """uint32 [..., n] -> uint8 [..., 4n], the inverse of as_words."""
+    return jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(
+        *words.shape[:-1], -1)
+
+
+def byte_transpose(w) -> list[jax.Array]:
+    """Four uint32 arrays (`w[0]`..`w[3]`) -> four: byte f of out[i]
+    is byte i of w[f] — a 4x4 transpose of the bytes held at one
+    position, as two rounds of mask-shift-OR on whole words (16-bit
+    halves between w[0]/w[2] and w[1]/w[3], then bytes between the
+    pairs). out[i] is pixel 4k+i of each of the four frames, packed in
+    the order a [..., stack] uint8 array keeps them."""
+    lo, hi = jnp.uint32(0x0000FFFF), jnp.uint32(0xFFFF0000)
+    even, odd = jnp.uint32(0x00FF00FF), jnp.uint32(0xFF00FF00)
+    t0 = (w[0] & lo) | (w[2] << 16)
+    t1 = (w[1] & lo) | (w[3] << 16)
+    t2 = (w[0] >> 16) | (w[2] & hi)
+    t3 = (w[1] >> 16) | (w[3] & hi)
+    return [(t0 & even) | ((t1 & even) << 8), ((t0 >> 8) & even) | (t1 & odd),
+            (t2 & even) | ((t3 & even) << 8), ((t2 >> 8) & even) | (t3 & odd)]
+
+
+def stacks_of_four(w, pixels: int) -> jax.Array:
+    """Four uint32 [cols, row] arrays, the word rows of four
+    consecutive frames -> uint8 [pixels, cols, 4], each pixel's
+    four-frame stack with the columns in the lanes: the byte transpose,
+    each result transposed as a 32-bit 2-D transpose, the four pixel
+    phases interleaved, the rows' pad cut and ONE bitcast — the order
+    a first conv reads (FrameRingReplay._gather says how it was
+    arrived at; replay/sequence.py::_stacks is the other caller)."""
+    px = [p.T for p in byte_transpose(w)]       # pixel 4k+i: px[i][k]
+    px = jnp.stack(px, axis=1).reshape(-1, px[0].shape[-1])
+    return jax.lax.bitcast_convert_type(px[:pixels], jnp.uint8)
 
 
 def frame_mode(storage: str, obs_shape: tuple[int, ...]) -> bool:
@@ -86,7 +161,7 @@ def frame_mode(storage: str, obs_shape: tuple[int, ...]) -> bool:
     through them by runtime/family.py (layout selection) and
     utils/hbm.py (budget pricing), so the selection and the pricing can
     never drift: frame mode applies to [H, W, stack] pixel observations
-    under frame_ring storage, any dtype (the byte-row packing inside
+    under frame_ring storage, any dtype (the word-row packing inside
     the replay additionally engages only for uint8, but the item SHAPE
     is the same either way; the frame-ring layout's uint8 requirement
     is enforced with a ValueError at FrameRingReplay construction).
@@ -178,7 +253,9 @@ def packable(spec) -> bool:
 # operand: an R2D2 sequence stored as one 585,728 B row compiled to 18
 # slabs of [capacity, 32640] — a copy of the entire 9 GiB ring inside
 # every sample, 7.2 GiB of HLO temp, out of memory on the v5e (PERF.md
-# §6, PR 26).
+# §6, PR 26). Measured on byte rows; a row of words of the same bytes
+# is a quarter of the lane tiles, so for word rows the bound is on the
+# safe side (not measured again).
 GATHER_ROW_MAX_BYTES = 255 * LANE
 
 
@@ -187,35 +264,42 @@ def row_layout(shape: tuple[int, ...]) -> tuple[int, int, int]:
     packable uint8 leaf: ONE row per item while the padded row stays
     under GATHER_ROW_MAX_BYTES, else one row per slice of the leading
     axis (a frame of a sequence's [L + stack - 1, H, W], a stack of
-    [L, H, W, stack]) — the frame ring's own row shape. The one place
-    the rule lives: PixelPacker lays storage out by it and utils/hbm.py
-    prices by it."""
+    [L, H, W, stack]) — the frame ring's own row shape. A row is padded
+    to whole lane tiles of words (`pad_row`). The one place the rule
+    lives: PixelPacker lays storage out by it and utils/hbm.py prices
+    by it."""
     nbytes = math.prod(shape)
-    if pad128(nbytes) <= GATHER_ROW_MAX_BYTES:
-        return 1, nbytes, pad128(nbytes)
+    if pad_row(nbytes) <= GATHER_ROW_MAX_BYTES:
+        return 1, nbytes, pad_row(nbytes)
     row = nbytes // shape[0]
-    if pad128(row) > GATHER_ROW_MAX_BYTES:
+    if pad_row(row) > GATHER_ROW_MAX_BYTES:
         raise ValueError(
             f"uint8 leaf {shape}: one slice of its leading axis is "
             f"{row} B, wider than the {GATHER_ROW_MAX_BYTES} B a "
             f"storage gather fetches whole")
-    return shape[0], row, pad128(row)
+    return shape[0], row, pad_row(row)
+
+
+# What a sample hands on beside a split leaf `k`: the word rows it was
+# gathered as, under `k + WORDS` (PixelPacker.decode).
+WORDS = "_words"
 
 
 class PixelPacker:
-    """Per-leaf codec: pixel frames <-> exactly-tiled byte rows.
+    """Per-leaf codec: pixel frames <-> exactly-tiled rows of words.
 
     Built from an item spec (pytree of ShapeDtypeStruct for ONE item).
-    A packable leaf is stored as `rows` byte rows of pad128 bytes each
-    (`row_layout`: one row per item, or one per leading-axis slice when
-    the item is wider than a gather fetches whole), ALL items' rows in
-    one 2-D [capacity * rows, row] buffer — a third dimension would
-    tile-pad `rows` to a multiple of 32. `storage_spec` is the shape of
-    ONE row and `rows_per_item` the multiplicity; `encode` turns an
-    incoming [*lead, b, ...] block into [*lead, b * rows, row] inside
-    the add jit; `decode` restores sampled [*lead, rows, row] rows (or
-    [*lead, row] for one-row leaves) to the original frame shape
-    (touches only the batch).
+    A packable leaf is stored as `rows` rows of pad_row bytes each, as
+    uint32 words (`row_layout`: one row per item, or one per
+    leading-axis slice when the item is wider than a gather fetches
+    whole), ALL items' rows in one 2-D [capacity * rows, row // 4]
+    buffer — a third dimension would tile-pad `rows` to a multiple of
+    8. `storage_spec` is the shape of ONE row and `rows_per_item` the
+    multiplicity; `encode` turns an incoming [*lead, b, ...] block of
+    bytes into [*lead, b * rows, row // 4] words inside the add jit;
+    `decode` restores sampled [*lead, rows, row // 4] rows (or
+    [*lead, row // 4] for one-row leaves) to the original uint8 frame
+    shape (touches only the batch).
     """
 
     def __init__(self, item_spec: Any):
@@ -232,7 +316,7 @@ class PixelPacker:
     def storage_spec(self, item_spec: Any) -> Any:
         leaves = jax.tree.leaves(item_spec)
         out = [leaf if plan is None
-               else jax.ShapeDtypeStruct((plan[3],), jnp.uint8)
+               else jax.ShapeDtypeStruct((plan[3] // WORD,), jnp.uint32)
                for leaf, plan in zip(leaves, self._plan)]
         return jax.tree.unflatten(self._treedef, out)
 
@@ -243,9 +327,9 @@ class PixelPacker:
                             for plan in self._plan])
 
     def encode(self, items: Any) -> Any:
-        """[*lead, b, *orig] leaves -> [*lead, b * rows, row] byte rows
-        (zero pad). Any number of leading axes before the block axis b
-        ([b] single-chip, [dp, b] on the mesh)."""
+        """[*lead, b, *orig] uint8 leaves -> [*lead, b * rows, row // 4]
+        word rows (zero pad). Any number of leading axes before the
+        block axis b ([b] single-chip, [dp, b] on the mesh)."""
         leaves = jax.tree.leaves(items)
         out = []
         for leaf, plan in zip(leaves, self._plan):
@@ -254,16 +338,27 @@ class PixelPacker:
                 continue
             shape, rows, nbytes, row = plan
             lead = leaf.shape[:leaf.ndim - len(shape)]
-            flat = leaf.reshape(*lead[:-1], lead[-1] * rows, nbytes)
+            flat = leaf.astype(jnp.uint8).reshape(
+                *lead[:-1], lead[-1] * rows, nbytes)
             if row != nbytes:
                 pad = [(0, 0)] * len(lead) + [(0, row - nbytes)]
                 flat = jnp.pad(flat, pad)
-            out.append(flat)
+            # dus_rows' own astype converts VALUES and would store one
+            # pixel per word: pack here
+            out.append(as_words(flat, strided=True))
         return jax.tree.unflatten(self._treedef, out)
 
-    def decode(self, items: Any) -> Any:
-        """Sampled byte rows -> [*lead, *orig] frames: [*lead, row] for
-        a one-row leaf, [*lead, rows, row] for a split one."""
+    def decode(self, items: Any, words: bool = False) -> Any:
+        """Sampled word rows -> [*lead, *orig] uint8 frames: [*lead,
+        row // 4] for a one-row leaf, [*lead, rows, row // 4] for a
+        split one.
+
+        `words` (a dict of items; what a SAMPLE hands on): a split leaf
+        `k` also goes on as the rows it was gathered as, under
+        `k + WORDS`, for a reader that works on whole words
+        (replay/sequence.py::_stacks builds conv1's operand from them
+        and takes only the frame shape from the bytes). Inside a jit
+        whichever of the two forms nobody reads is never computed."""
         leaves = jax.tree.leaves(items)
         out = []
         for leaf, plan in zip(leaves, self._plan):
@@ -272,7 +367,37 @@ class PixelPacker:
                 continue
             shape, rows, nbytes, _ = plan
             lead = leaf.shape[:-1] if rows == 1 else leaf.shape[:-2]
-            out.append(leaf[..., :nbytes].reshape(*lead, *shape))
+            out.append(as_bytes(leaf)[..., :nbytes].reshape(*lead, *shape))
+        frames = jax.tree.unflatten(self._treedef, out)
+        if not words:
+            return frames
+        return {**frames, **{k + WORDS: items[k] for k, m
+                             in self.rows_per_item().items() if m > 1}}
+
+    def checkpoint_rows(self, storage: Any, restore: bool = False) -> Any:
+        """HOST storage (numpy leaves) <-> what a replay-bearing
+        checkpoint holds of it (utils/checkpoint.py): a packed leaf as
+        the BYTE rows these stores kept before their rows were words,
+        uint8 [..., R, pad128(row bytes)] (layout v2/v3), so a file on
+        disk means what it meant. A word's bytes lie least significant
+        first, as a little-endian view of the rows reads them;
+        `restore` goes back to [..., R, row // 4] uint32."""
+        leaves = jax.tree.leaves(storage)
+        out = []
+        for leaf, plan in zip(leaves, self._plan):
+            if plan is None:
+                out.append(leaf)
+                continue
+            _, _, nbytes, row = plan
+            if restore:
+                if leaf.shape[-1] != row:
+                    leaf = np.pad(leaf, [(0, 0)] * (leaf.ndim - 1)
+                                  + [(0, row - leaf.shape[-1])])
+                out.append(np.ascontiguousarray(leaf).view("<u4"))
+            else:
+                out.append(np.ascontiguousarray(
+                    leaf.astype("<u4", copy=False).view(np.uint8)
+                    [..., :pad128(nbytes)]))
         return jax.tree.unflatten(self._treedef, out)
 
 
@@ -392,7 +517,14 @@ def make_packer(item_spec: Any) -> tuple[PixelPacker | None, Any, Any]:
 def gather_rows(buf: jax.Array, idx: jax.Array, rows: int) -> jax.Array:
     """Item gather from a [capacity * rows, ...] buffer: [n, ...] for a
     one-row leaf, [n, rows, ...] for a split one (item i is the `rows`
-    consecutive rows from i * rows)."""
+    consecutive rows from i * rows). The row position is FIRST in the
+    index, as in the frame ring's gather, so the n rows of one position
+    arrive together and the result is [rows, n, ...] seen item-major: a
+    reader that slices positions (replay/sequence.py::_stacks) then
+    takes whole blocks, and no [n * rows] -> [n, rows] reshape pads
+    `rows` to the tile (r2d2_offline: 6.64 -> 6.31 ms a step on the
+    v5e, PERF.md §6, PR 42)."""
     if rows == 1:
         return buf[idx]
-    return buf[idx[:, None] * rows + jnp.arange(rows, dtype=idx.dtype)]
+    offs = jnp.arange(rows, dtype=idx.dtype)[:, None]
+    return buf[offs + idx[None, :] * rows].swapaxes(0, 1)

@@ -75,7 +75,7 @@ def ring_finish(tree, idx, pri, pos1, size1, lead: tuple[int, ...]):
 class PrioritizedReplay:
     """Static config + pure state-transition functions.
 
-    Pixel leaves are stored as exactly-tiled byte rows and ring writes
+    Pixel leaves are stored as exactly-tiled rows of words and ring writes
     are in-place dynamic_update_slice blocks with skip-to-head wrap —
     see replay/packing.py for the measured HBM rationale (a scatter or
     a tile-padded layout each cost a full-buffer copy per add/sample on
@@ -124,6 +124,13 @@ class PrioritizedReplay:
         return ReplayState(
             storage=storage, tree=sum_tree.init(self.capacity),
             pos=jnp.int32(0), size=jnp.int32(0))
+
+    def checkpoint_rows(self, storage: Any, restore: bool = False) -> Any:
+        """HOST storage <-> its form in a replay-bearing checkpoint:
+        packed leaves as byte rows (PixelPacker.checkpoint_rows)."""
+        if self._packer is None:
+            return storage
+        return self._packer.checkpoint_rows(storage, restore)
 
     # -- transitions (all pure, jit-friendly) ------------------------------
 
@@ -266,16 +273,25 @@ class PrioritizedReplay:
         (parallel/dist_learner.py), and FrameRingReplay shares the
         calling convention. `chunks`=K emits the draw chunk-major
         (ops/sum_tree.py::sample), so the gather writes the batch in
-        the order the K-batch cycle reads it."""
+        the order the K-batch cycle reads it, and each chunk's rows are
+        gathered on their own, as FrameRingReplay._gather gathers them:
+        chunk j of the result is then a whole array and not a slice of
+        a K*B one, and the K SGD steps read it as it lands
+        (r2d2_offline: 6.30 -> 5.99 ms a step, PERF.md §6, PR 42). The
+        items returned are the same for every `chunks`."""
         idx, probs = sum_tree.sample(state.tree, rng, batch,
                                      size=state.size, chunks=chunks)
+        # (a one-way split would still be a slice in the K = 1 programs)
+        parts = jnp.split(idx, chunks) if chunks > 1 else [idx]
         # scope = op metadata: the benchmark reads the gather's device
         # time by this name (replay.seq_gather_hbm_share)
         with jax.named_scope("replay.sample_gather"):
-            items = jax.tree.map(lambda buf, m: gather_rows(buf, idx, m),
-                                 state.storage, self._rows)
+            items = jax.tree.map(
+                lambda buf, m: jnp.concatenate(
+                    [gather_rows(buf, part, m) for part in parts]),
+                state.storage, self._rows)
         if self._packer is not None:
-            items = self._packer.decode(items)
+            items = self._packer.decode(items, words=True)
         return items, idx, probs
 
     def sample(self, state: ReplayState, rng: jax.Array, batch: int,
@@ -392,6 +408,8 @@ class UniformReplayDevice:
                            tree=jnp.zeros(1, jnp.float32),  # unused
                            pos=jnp.int32(0), size=jnp.int32(0))
 
+    checkpoint_rows = PrioritizedReplay.checkpoint_rows
+
     def add(self, state: ReplayState, items: Any,
             td_abs: jax.Array | None = None) -> ReplayState:
         b = jax.tree.leaves(items)[0].shape[0]
@@ -417,7 +435,7 @@ class UniformReplayDevice:
         items = jax.tree.map(lambda buf, m: gather_rows(buf, idx, m),
                              state.storage, self._rows)
         if self._packer is not None:
-            items = self._packer.decode(items)
+            items = self._packer.decode(items, words=True)
         return items, idx, jnp.ones(batch, jnp.float32)
 
     def update_priorities(self, state: ReplayState, idx, td_abs):

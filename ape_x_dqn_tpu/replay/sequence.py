@@ -27,7 +27,8 @@ from typing import Any
 
 import numpy as np
 
-from ape_x_dqn_tpu.replay.packing import frame_mode
+from ape_x_dqn_tpu.replay.packing import (WORDS, frame_mode,
+                                          stacks_of_four)
 
 
 # THE predicate for single-frame sequence storage — an alias of the
@@ -230,34 +231,50 @@ def stack_items(items: list[dict]) -> dict:
             for k in items[0] if k != "priority"}
 
 
-def _stacks(frames, start: int, stop: int, stack: int, prepare):
+def _stacks(frames, words, start: int, stop: int, stack: int, prepare):
     """Single frames [B, n, H, W] -> what conv1 reads for steps
     start..stop, [B, stop - start, H, W, stack]: channel c of step t is
     frame t + c, passed once through `prepare`.
 
-    Four uint8 frames are the four bytes of one 32-bit word, so the
-    stack is shifts and ORs on whole words and one bitcast, written in
-    the order conv1 reads (H, W, then batch x time in the lanes with
-    the stack beside it) — the order `prepare` sees and materialises.
-    On the v5e the compiled step then holds one pass over the words, one
-    transpose of them and one unpack to the compute dtype straight into
-    conv1's operand layout, where four `stack`ed slices on the last
-    axis (any other dtype or depth: the plain form below) cost a
-    concatenate that writes at a tenth of HBM speed and two relayout
-    copies of the 4x larger stacks (PERF.md §6, PR 27)."""
-    import jax
+    `words`: the same frames as the packed store gathered them,
+    uint32 [B, n, row] with word k of a row holding pixels 4k..4k+3 of
+    one frame (replay/packing.py), or None. With them a stack of four
+    is built on whole words, as `FrameRingReplay._gather` builds its
+    own (`packing.stacks_of_four`): step t's four rows t..t+3 go
+    through a 4x4 byte transpose (four words of four adjacent pixels of
+    four consecutive frames -> four words, each one pixel's stack), each
+    result is transposed [B*T, row] -> [row, B*T] as a 32-bit 2-D
+    transpose, the four pixel phases are interleaved, and ONE bitcast
+    gives [H*W, B*T, 4] uint8 in the order conv1 reads (H, W, then
+    batch x time in the lanes with the stack beside it) — the order
+    `prepare` sees and materialises. `frames` then gives its shape and
+    nothing else, and its bytes are never made. On the v5e the
+    compiled step then holds, a side of the cut: one fusion over the
+    four slices (the byte transpose, four outputs), the four 2-D
+    transposes as layout changes of those outputs and not as copies,
+    one fusion that interleaves the phases and cuts the row's pad, one
+    copy and one reshape of the words to [H, W, B*T] (the one pass
+    left that moves data without computing: 84 is not a multiple of
+    the 8-sublane tile), and one unpack-and-scale to the compute dtype
+    straight into conv1's operand layout. With the gather that feeds
+    it (replay/packing.py::gather_rows) r2d2_offline's step went 7.72
+    -> 5.99 ms (PERF.md §6, PR 42); the same words turned back into
+    bytes and `stack`ed read 10.78.
+
+    Any other dtype or depth, or frames that came as bytes alone,
+    take the plain form: four `stack`ed slices on the last axis, which
+    on the v5e cost a concatenate that writes at a tenth of HBM speed
+    and two relayout copies of the 4x larger stacks (PERF.md §6,
+    PR 27)."""
     import jax.numpy as jnp
 
     bsz, _, h, w = frames.shape
     steps = stop - start
-    if frames.dtype == jnp.uint8 and stack == 4:
-        by_pixel = frames.transpose(2, 3, 0, 1)           # [H, W, B, n]
-        word = None
-        for c in range(stack):
-            shifted = by_pixel[..., start + c:stop + c].reshape(
-                h, w, bsz * steps).astype(jnp.uint32) << (8 * c)
-            word = shifted if word is None else word | shifted
-        obs = prepare(jax.lax.bitcast_convert_type(word, jnp.uint8))
+    if words is not None and stack == 4:
+        obs = stacks_of_four(
+            [words[:, start + c:stop + c].reshape(bsz * steps, -1)
+             for c in range(stack)], h * w)              # [H*W, B*T, 4]
+        obs = prepare(obs.reshape(h, w, bsz * steps, stack))
         return obs.transpose(2, 0, 1, 3).reshape(bsz, steps, h, w, stack)
     return prepare(jnp.stack(
         [frames[:, start + c:stop + c] for c in range(stack)], axis=-1))
@@ -281,7 +298,9 @@ def batch_to_sequence_batch(items: Any, compute_dtype=None,
     other cut is still right, only slower. Without `compute_dtype` the
     stored dtype is kept (uint8 stacks, scaled by the net).
 
-    Frame-mode items carry "seq_frames" [B, L+stack-1, H, W] and the
+    Frame-mode items carry "seq_frames" [B, L+stack-1, H, W] — and,
+    sampled from the packed store, the word rows those frames were
+    gathered as beside them (replay/packing.py::WORDS) — and the
     per-step stacks are rebuilt here (`_stacks`); per-step storage
     ("obs") has nothing to rebuild. Measured 16.8% of an R2D2 step
     before PR 27, with the scale and relayout behind it 32.7%, so
@@ -306,7 +325,9 @@ def batch_to_sequence_batch(items: Any, compute_dtype=None,
         if "seq_frames" in items:
             f = items["seq_frames"]
             stack = f.shape[1] - length + 1
-            parts = [_stacks(f, a, b, stack, prepare) for a, b in spans]
+            words = items.get("seq_frames" + WORDS)
+            parts = [_stacks(f, words, a, b, stack, prepare)
+                     for a, b in spans]
         else:
             parts = [prepare(items["obs"][:, a:b]) for a, b in spans]
         obs = parts[0] if len(parts) == 1 else jnp.concatenate(parts,

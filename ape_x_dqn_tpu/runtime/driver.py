@@ -438,7 +438,13 @@ class ApexDriver:
             dev = {k: jax.tree.map(self._dev_copy, v)
                    for k, v in self.state._asdict().items()
                    if k not in skip}
-        return {k: jax.tree.map(np.asarray, v) for k, v in dev.items()}
+        host = {k: jax.tree.map(np.asarray, v) for k, v in dev.items()}
+        if "replay" in host:
+            # on disk a packed leaf stays the byte rows it always was
+            host["replay"] = host["replay"]._replace(
+                storage=self.replay.checkpoint_rows(
+                    host["replay"].storage))
+        return host
 
     def _save_checkpoint(self, wait: bool = False) -> None:
         with self.obs.span("ckpt.save", step=self._grad_steps_total):
@@ -462,6 +468,10 @@ class ApexDriver:
             restored = self.ckpt.restore(template=template)
         if restored is None:
             return
+        if "replay" in restored:
+            restored["replay"] = restored["replay"]._replace(
+                storage=self.replay.checkpoint_rows(
+                    restored["replay"].storage, restore=True))
         # land each leaf back on device with the layout the learner state
         # already has (replicated/sharded alike), then resume the counter
         def put_leaf(x, ref):

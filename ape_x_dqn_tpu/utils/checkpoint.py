@@ -25,7 +25,10 @@ History: v2 = the round-5 byte rows; v3 (PR 29) = the frame ring's
 rows are 32-bit words, uint32 [S*F, pad128(H*W) // 4], four pixels of
 one frame to a word (replay/frame_ring.py) — the same bytes, but a v2
 ``frames`` leaf is uint8 [S*F, pad128(H*W)] and does not restore into
-it. The packed flat/sequence stores (replay/packing.py) are unchanged.
+it. The packed flat/sequence stores (replay/packing.py) keep words on
+the device too since PR 42, but their leaves are saved as the byte rows
+they always were (`PixelPacker.checkpoint_rows`, converted on the host
+by the driver), so v3 stands and a v3 file restores.
 """
 
 from __future__ import annotations

@@ -4,8 +4,8 @@ Round-4 verdict missing #3: the shipping pong preset's frame ring did
 not fit the 16GB bench chip, and nothing in the config system said so —
 the bench silently measured at 1/4 capacity. This module makes the
 budget explicit: `replay_budget` prices a RunConfig's replay storage the
-way the device will actually hold it (byte-row packed pixel leaves, see
-replay/packing.py), `run_budget` adds the model/optimizer state, and
+way the device will actually hold it (pixel leaves packed into rows of
+words, see replay/packing.py), `run_budget` adds the model/optimizer state, and
 `check_hbm_fits` raises before any device allocation happens if the
 preset cannot fit its chip.
 
@@ -33,8 +33,8 @@ from ape_x_dqn_tpu.utils.misc import next_pow2
 
 
 def _leaf_stored_bytes(shape: tuple[int, ...], dtype) -> int:
-    """Bytes one stored leaf actually occupies: pad128 byte rows when
-    the leaf is packed (the SAME packing.packable predicate and
+    """Bytes one stored leaf actually occupies: rows of whole lane
+    tiles of words when the leaf is packed (the SAME packing.packable predicate and
     row_layout rule the replay storage uses — the budget must not
     drift from the layout), raw bytes otherwise."""
     if packable(SimpleNamespace(shape=shape, dtype=dtype)):

@@ -163,6 +163,58 @@ def test_flat_cold_round_trip_bitwise():
         np.testing.assert_array_equal(a, blocks[2][k], err_msg=k)
 
 
+def _sequence_batch(n, rng):
+    return {
+        "seq_frames": rng.integers(0, 255, (n, 11, 60, 60)).astype(np.uint8),
+        "mask": rng.random((n, 8)).astype(np.float32),
+    }
+
+
+# SHA-256[:16] of the cold segment the PARENT of ISSUE 42 wrote for
+# these seeds, when the packed store's rows were bytes
+COLD_SEGMENTS = {
+    # one row per item, padded 28,224 -> 28,672 B
+    "flat": (lambda: transition_item_spec(OBS_SHAPE, np.uint8),
+             _flat_batch, "dab4852d639073a2"),
+    # a split leaf: 11 rows of 3,600 B, padded to 4,096
+    "sequence": (lambda: {
+        "seq_frames": jax.ShapeDtypeStruct((11, 60, 60), jnp.uint8),
+        "mask": jax.ShapeDtypeStruct((8,), jnp.float32)},
+        _sequence_batch, "c2d7f9d6420188cf"),
+}
+
+
+@pytest.mark.parametrize("case", list(COLD_SEGMENTS))
+def test_a_cold_segment_holds_the_bytes_it_held_as_byte_rows(case):
+    """The packed store's rows are 32-bit words on the device (ISSUE
+    42); what leaves it is bytes: the segment a region serialises to
+    is the one its host items serialise to, and the one the parent
+    wrote."""
+    import hashlib
+
+    from ape_x_dqn_tpu.replay.packing import cold_pack, cold_plan
+
+    make_spec, make_batch, parents = COLD_SEGMENTS[case]
+    spec = make_spec()
+    rng = np.random.default_rng(7)
+    r = PrioritizedReplay(16, item_spec=spec)
+    st = r.init()
+    blocks = [make_batch(4, rng) for _ in range(3)]
+    for b in blocks:
+        st = r.add(st, b, np.full((4,), 0.5, np.float32))
+    assert all(leaf.dtype != jnp.uint8
+               for leaf in jax.tree.leaves(st.storage))
+    items, pri = r.read_region(st, jnp.int32(4), 4)
+    items = {**jax.tree.map(np.asarray, items), "priorities": np.asarray(pri)}
+    for k, v in blocks[1].items():
+        assert items[k].dtype == v.dtype, k
+    plan = cold_plan(spec)
+    payload, raw = cold_pack(items, plan)
+    assert (payload, raw) == cold_pack(
+        {**blocks[1], "priorities": np.asarray(pri)}, plan)
+    assert hashlib.sha256(payload).hexdigest()[:16] == parents
+
+
 # -- eviction placement + the cold-off FIFO pin ----------------------------
 
 

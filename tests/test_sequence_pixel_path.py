@@ -13,7 +13,13 @@ Held here, on the CPU at tiny widths:
       uint8 to float exactly once (the parent's: twice, in four converts);
 (iii) `net_apply_seq` still takes uint8 stacks (the call the benchmark's
       check makes) and `sample_k` still returns the stored uint8 items;
-(iv)  the dist learner at dp=1 takes the same prepared batch.
+(iv)  the dist learner at dp=1 takes the same prepared batch;
+(v)   since ISSUE 42 the packed store's rows are 32-bit words and a
+      sample hands a split leaf's rows on beside its bytes:
+      `batch_to_sequence_batch` on those word rows gives the plain
+      `jnp.stack` form element for element at every cut of the time
+      axis, widens no byte to a word on the way, and any other depth or
+      dtype still takes the plain form.
 """
 
 import jax
@@ -24,12 +30,14 @@ import pytest
 
 from ape_x_dqn_tpu.configs import LearnerConfig, ReplayConfig
 from ape_x_dqn_tpu.models import ApeXLSTMQNet
-from ape_x_dqn_tpu.models.base import dtype_of
+from ape_x_dqn_tpu.models.base import dtype_of, preprocess_obs
 from ape_x_dqn_tpu.ops.losses import SequenceBatch
 from ape_x_dqn_tpu.parallel.dist_learner import DistLearner
 from ape_x_dqn_tpu.parallel.mesh import make_mesh
+from ape_x_dqn_tpu.replay.packing import WORDS, PixelPacker
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
-from ape_x_dqn_tpu.replay.sequence import sequence_item_spec
+from ape_x_dqn_tpu.replay.sequence import (batch_to_sequence_batch,
+                                           sequence_item_spec)
 from ape_x_dqn_tpu.runtime.family import r2d2_family
 from ape_x_dqn_tpu.runtime.learner import SingleChipLearner
 
@@ -248,8 +256,11 @@ def test_sample_k_still_returns_the_stored_uint8_items():
     _, learner, state = _build("frame_rows", "bfloat16")
     sample, _ = learner.sample_k(state, 1)
     items_k, idx_k, _, _ = sample
-    assert set(items_k) == {"seq_frames", "actions", "rewards",
-                            "terminals", "mask", "init_c", "init_h"}
+    # the frames as bytes, and the word rows they were gathered as
+    assert set(items_k) == {"seq_frames", "seq_frames" + WORDS, "actions",
+                            "rewards", "terminals", "mask", "init_c",
+                            "init_h"}
+    assert items_k["seq_frames" + WORDS].dtype == jnp.uint32
     assert items_k["seq_frames"].dtype == jnp.uint8
     h, w = LAYOUTS["frame_rows"]["hw"]
     assert items_k["seq_frames"].shape == (1, B, L + STACK - 1, h, w)
@@ -280,3 +291,103 @@ def test_dist_sequence_learner_takes_the_same_prepared_batch():
     np.testing.assert_array_equal(
         np.asarray(batch.obs, np.float32),
         np.asarray(single.family.make_batch(items).obs, np.float32))
+
+
+# -- (v) the rebuild on word rows -----------------------------------------
+
+
+def _sampled(frames, frame_dtype=np.uint8, stack=STACK):
+    """Items as a sample of the packed store hands them on: `frames`
+    [B, n, H, W] through the store's codec, with the fields the batch
+    needs beside them."""
+    bsz, n = frames.shape[:2]
+    length = n - stack + 1
+    spec = {"seq_frames": jax.ShapeDtypeStruct(frames.shape[1:],
+                                               frame_dtype)}
+    packer = PixelPacker(spec)
+    rows = packer.rows_per_item()["seq_frames"]
+    stored = packer.encode({"seq_frames": jnp.asarray(frames)})
+    if rows > 1:
+        stored = {"seq_frames": stored["seq_frames"].reshape(
+            bsz, rows, -1)}
+    z = jnp.zeros((bsz, length), jnp.float32)
+    return {**packer.decode(stored, words=True),
+            "actions": z.astype(jnp.int32), "rewards": z, "terminals": z,
+            "mask": z, "init_c": z[:, :1], "init_h": z[:, :1]}
+
+
+def _plain(frames, length, stack, compute_dtype):
+    obs = jnp.stack([jnp.asarray(frames)[:, c:c + length]
+                     for c in range(stack)], axis=-1)
+    return obs if compute_dtype is None else preprocess_obs(
+        obs, dtype_of(compute_dtype))
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn.params):
+            yield from _primitives(sub)
+
+
+# the cell's own geometry (80 steps of 84 x 84, cut at 40), a cut at
+# an odd step, none; and frames whose lines are not whole words
+@pytest.mark.parametrize("compute_dtype", [None, "float32", "bfloat16"])
+@pytest.mark.parametrize("hw,length,burn_in", [
+    ((84, 84), 80, 40), ((84, 84), 80, 27), ((84, 84), 80, 0),
+    ((61, 62), 12, 5)])
+def test_word_rows_give_the_plain_stacks_element_for_element(
+        hw, length, burn_in, compute_dtype):
+    rng = np.random.default_rng(5)
+    frames = rng.integers(0, 256, (2, length + STACK - 1, *hw),
+                          dtype=np.uint8)
+    items = _sampled(frames)
+    assert items["seq_frames" + WORDS].dtype == jnp.uint32   # split rows
+    dt = None if compute_dtype is None else dtype_of(compute_dtype)
+    make = jax.jit(lambda it: batch_to_sequence_batch(it, dt, burn_in).obs)
+    got = make(items)
+    want = jax.jit(lambda f: _plain(f, length, STACK, compute_dtype))(frames)
+    assert got.shape == (2, length, *hw, STACK) and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    # built on whole words: no byte is widened, the bytes of
+    # `seq_frames` are never made, one bitcast a side of the cut
+    names = [(e.primitive.name, e.invars[0].aval.dtype,
+              e.outvars[0].aval.dtype)
+             for e in _primitives(jax.make_jaxpr(make)(items).jaxpr)]
+    assert ("convert_element_type", jnp.uint8, jnp.uint32) not in names
+    casts = [n for n in names if n[0] == "bitcast_convert_type"]
+    assert casts == [("bitcast_convert_type", jnp.uint32, jnp.uint8)] * (
+        2 if 0 < burn_in < length else 1)
+
+
+@pytest.mark.parametrize("case", ["stack_of_3", "float32_frames",
+                                  "one_row_sequence", "bytes_alone"])
+def test_every_other_depth_dtype_and_form_takes_the_plain_path(case):
+    """A stack that is not four bytes of a word, frames that are not
+    bytes (the packer leaves them alone), a sequence stored as ONE row
+    and frames that came without their word rows: four `stack`ed
+    slices, equal to the plain form all the same."""
+    rng = np.random.default_rng(6)
+    length, stack, hw, dtype = 12, STACK, (60, 60), np.uint8
+    if case == "stack_of_3":
+        stack = 3
+    if case == "one_row_sequence":
+        hw = (36, 36)
+    frames = rng.integers(0, 256, (2, length + stack - 1, *hw),
+                          dtype=np.uint8)
+    if case == "float32_frames":
+        frames, dtype = frames.astype(np.float32) / 7, np.float32
+    items = _sampled(frames, dtype, stack)
+    if case == "bytes_alone":
+        del items["seq_frames" + WORDS]
+    assert ("seq_frames" + WORDS in items) == (case == "stack_of_3")
+    make = jax.jit(lambda it: batch_to_sequence_batch(
+        it, jnp.bfloat16, 5).obs)
+    np.testing.assert_array_equal(
+        np.asarray(make(items), np.float32),
+        np.asarray(jax.jit(lambda f: _plain(f, length, stack, "bfloat16"))(
+            frames), np.float32))
+    names = [e.primitive.name
+             for e in _primitives(jax.make_jaxpr(make)(items).jaxpr)]
+    assert "shift_left" not in names and "concatenate" in names
