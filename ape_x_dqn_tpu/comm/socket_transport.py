@@ -2287,8 +2287,7 @@ class SocketTransport:
     @property
     def backpressure_engaged(self) -> bool:
         """Current state of the serving-tier backpressure latch (read
-        by the remediation plane's stale-controller watchdog and the
-        chaos bench's remediated arm)."""
+        by the remediation plane's stale-controller watchdog)."""
         return self._bp_engaged
 
     def kick(self) -> bool:

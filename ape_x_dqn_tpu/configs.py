@@ -366,9 +366,6 @@ class ReplayConfig:
     ingest_coalesce: int = 4
     # host staging buffers to rotate through (>= 2 for double buffering)
     stage_buffers: int = 2
-    # compat escape hatch: False restores the list-append +
-    # concatenate-per-flush legacy staging path in runtime/driver.py
-    ingest_zero_copy: bool = True
     # -- tiered cold store (replay/cold_store.py), default OFF ----------
     # cold_tier_capacity > 0 enables the host-RAM cold tier behind the
     # device ring: when the ring is full, each ingest block overwrites
@@ -507,8 +504,8 @@ class LearnerConfig:
     # one-dispatch staleness identical in kind to sample_chunk's
     # within-chunk staleness and to the reference's async host-side
     # replay server (its sampler always lags the learner by an update
-    # round-trip). Default off until an on-chip A/B clears the ±3-5%
-    # noise band (bench.py --prefetch-ab records both orders).
+    # round-trip). Default off until an on-chip on/off by the pairs
+    # rule clears the noise band (ROADMAP D4-prefetch).
     sample_prefetch: bool = False
     # Pacing: cap grad-steps at this multiple of ingested transitions
     # (None = free-run, the Ape-X default where the learner trains as

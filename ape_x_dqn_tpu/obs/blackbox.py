@@ -214,7 +214,7 @@ class FlightRecorder:
             except Exception:
                 pass
             # instrument + span + heartbeat context when riding a full
-            # Obs (minimal facades — e.g. the chaos bench sink — only
+            # Obs (minimal facades — e.g. a test's counting sink — only
             # need .count)
             reg = getattr(self._obs, "registry", None)
             if reg is not None:
@@ -284,7 +284,9 @@ class FlightRecorder:
         if not self._installed:
             return
         self._installed = False
-        if sys.excepthook is self._excepthook:
+        # == and not `is`: every read of self._excepthook makes a new
+        # bound-method object, equal to the installed one, never it
+        if sys.excepthook == self._excepthook:
             sys.excepthook = self._prev_excepthook
         try:
             atexit.unregister(self._atexit_dump)

@@ -4,8 +4,8 @@ Drivers build one `Obs` from `configs.ObsConfig` and hand it to their
 components (actors, ingest, learner loop, inference server). Every
 call site goes through this facade so the disabled path is a method
 call on the `NullObs` singleton — no conditionals in runtime code, and
-~zero overhead when observability is off (the acceptance bar: bench
-grad-steps/s unchanged with ObsConfig disabled, which trivially holds
+~zero overhead when observability is off (the acceptance bar: the
+learner's rate unchanged with ObsConfig disabled, which trivially holds
 because the learner jits are untouched and disabled drivers never call
 into numpy or locks here).
 

@@ -33,9 +33,9 @@ that in three pieces:
 
 Disabled obs routes through NullObs and never reaches this module's
 host side; the in-graph helpers import jax lazily and add the same
-handful of fused scalar reductions whether or not obs is on (measured
-in bench.py --smoke at 1.7 M parameters, 27 MB of learner state: below
-noise). The two that read parameter-sized trees are not free at every
+handful of fused scalar reductions whether or not obs is on (at the
+CNN's 1.7 M parameters, 27 MB of learner state, their cost is
+`learner.health_share` in PERF.md). The two that read parameter-sized trees are not free at every
 size, so `sgd_diag` runs them only on a step whose metrics are read.
 """
 

@@ -18,8 +18,8 @@ turns it into live gauges riding the existing registry/JSONL surface so
   (`device_peaks`, or the ObsConfig overrides) — `mfu` and
   `hbm_bw_frac`. NOTE: the compiler FLOP count omits most conv FLOPs
   (~0.9 vs ~47.9 analytic GFLOP/step — PERF.md), so the live MFU gauge
-  is a LOWER bound; bench.py's analytic count stays the headline
-  authority.
+  is a LOWER bound; the analytic count kept with the benchmark
+  (benchmarks/harness, `learner.mfu`) is the authority.
 - `CompileWatcher` — a process-global jax compile interceptor
   (jax.monitoring's backend_compile duration event) counting compiles,
   compile wall-time, and cumulative executable-cache growth. This
@@ -342,9 +342,9 @@ def publish_multichip(obs, efficiency: float | None = None,
     """Literal gauge emissions for the dp-scaling plane (ISSUE 9):
 
     - dp_scaling_efficiency: grad-steps/s at dp normalized by dp x the
-      dp=1 rate — 1.0 is linear scaling. Published by the multichip
-      bench lane (bench.py --multichip), which is the only place the
-      dp=1 baseline exists; live driver runs carry the fill gauges.
+      dp=1 rate — 1.0 is linear scaling. Only a sweep that also ran
+      dp=1 has the baseline to publish it; live driver runs carry the
+      fill gauges.
     - replay_shard_fill_min / _max: bounds of per-shard replay
       occupancy fractions. Lockstep ingest keeps these equal; a gap
       means shards are filling unevenly and the stratified sampler is

@@ -94,7 +94,8 @@ INSTRUMENTS = {
     "mfu_train_dist": {"kind": "gauge"},
     "hbm_bw_frac_train_dist": {"kind": "gauge"},
     "device_ms_train_dist": {"kind": "gauge"},
-    # dp-scaling plane (bench.py --multichip + dist driver runs):
+    # dp-scaling plane (dist driver runs, and any dp sweep that
+    # appends `multichip/dp<N>/*` records to the JSONL):
     # "value_min" warn rows flag values BELOW the bound (efficiency
     # and fill are healthy when high, unlike every gauge above)
     "dp_scaling_efficiency": {
@@ -404,9 +405,9 @@ def summarize(records: list[dict]) -> dict[str, Any]:
         elif len(parts) == 4:
             peers.setdefault(parts[1], {}).setdefault(
                 parts[2], {})[parts[3]] = v
-    # multichip scaling lane: `multichip/dp<N>/<stat>` keys the bench
-    # lane (bench.py --multichip) appends to the JSONL — one group per
-    # dp point, same raw-key pattern as the fleet peer frames
+    # multichip scaling: `multichip/dp<N>/<stat>` keys a dp sweep
+    # appends to the JSONL — one group per dp point, same raw-key
+    # pattern as the fleet peer frames
     multichip: dict[int, dict[str, Any]] = {}
     for k, v in latest.items():
         if not k.startswith("multichip/dp"):
@@ -681,8 +682,8 @@ def _fmt_roofline(summary: dict[str, Any]) -> list[str]:
 
 
 def _fmt_multichip(summary: dict[str, Any]) -> list[str]:
-    """dp-scaling curve from the multichip bench lane (bench.py
-    --multichip): one row per dp point with throughput, efficiency vs
+    """dp-scaling curve from a stream's `multichip/dp<N>/*` records:
+    one row per dp point with throughput, efficiency vs
     dp=1, per-shard fill bounds, and the dist-dispatch roofline gauges.
     Efficiency on virtual devices (one shared host) is a correctness/
     overhead signal, not a speedup claim — see PERF.md."""

@@ -357,9 +357,9 @@ class DistLearner(SingleChipLearner):
 
     # -- per-shard observability -------------------------------------------
 
-    def shard_stats(self, state: DistTrainState) -> dict:  # apexlint: host-sync(documented off the hot loop: teardown, publish boundaries, bench epilogues)
+    def shard_stats(self, state: DistTrainState) -> dict:  # apexlint: host-sync(documented off the hot loop: teardown, publish boundaries)
         """Per-shard replay fill/sample statistics for the obs plane
-        and the multichip bench lane (bench.py --multichip):
+        and the run report:
 
         - sizes: ring occupancy per shard in the replay's native item
           units (transitions for flat/frame-ring, sequences for R2D2);
@@ -372,7 +372,7 @@ class DistLearner(SingleChipLearner):
           the global-N recipe in _sample_weighted), not an error.
 
         Host-side device fetch; call off the hot loop (teardown,
-        publish boundaries, bench epilogues)."""
+        publish boundaries)."""
         rs = state.replay
         sizes = np.asarray(rs.size).reshape(-1).astype(np.int64)
         live = sizes

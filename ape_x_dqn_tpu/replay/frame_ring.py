@@ -487,7 +487,6 @@ class FrameRingReplay(PrioritizedReplay):
         """Count of live (non-pad) transition slots, reducing only the
         trailing slot axis — so it works unchanged on a single-chip
         state (scalar out) and on the dp-sharded lockstep state
-        ([dp] out), where it feeds the per-shard fill stats of the
-        multichip lane (bench.py --multichip) and
+        ([dp] out), where it feeds the per-shard fill stats of
         `DistLearner.shard_stats`."""
         return (state.storage["next_off"] > 0).sum(axis=-1)

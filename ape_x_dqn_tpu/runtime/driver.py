@@ -101,7 +101,6 @@ class ApexDriver:
         setup = family_setup(cfg, self.spec, self.net, obs0)
         params, item_spec = setup.params, setup.item_spec
         self._frame_mode = setup.frame_mode
-        self._item_keys = tuple(item_spec.keys())
         self.dp = cfg.parallel.dp
         self.is_dist = cfg.parallel.dp * cfg.parallel.tp > 1
         # early, loud HBM fits-check: the replay + model state must fit
@@ -300,8 +299,6 @@ class ApexDriver:
         # compile a fresh add graph (20-40s each on TPU). A staging unit
         # is one transition (flat storage) or one whole frame segment of
         # seg_transitions transitions (frame-ring storage).
-        self._stage: list[dict] = []
-        self._stage_n = 0
         self._stage_chunk = setup.stage_chunk
         self._unit_items = setup.unit_items
         self._stage_dropped = 0
@@ -320,18 +317,14 @@ class ApexDriver:
         # decode directly into preallocated [coalesce*block] buffers,
         # double-buffered against the async host->device transfer, and
         # full buffers ship as ONE coalesced add_many dispatch.
-        # ingest_zero_copy=False restores the legacy list-append +
-        # concatenate-per-flush staging (compat escape hatch).
-        self._stager: IngestStager | None = None
-        if getattr(cfg.replay, "ingest_zero_copy", True):
-            ptail = (cfg.replay.seg_transitions,) if self._frame_mode \
-                else ()
-            self._stager = IngestStager(
-                item_spec, ptail,
-                block_units=self.dp * self._stage_chunk,
-                coalesce=getattr(cfg.replay, "ingest_coalesce", 4),
-                buffers=getattr(cfg.replay, "stage_buffers", 2),
-                ship=self._ship_staged)
+        ptail = (cfg.replay.seg_transitions,) if self._frame_mode \
+            else ()
+        self._stager = IngestStager(
+            item_spec, ptail,
+            block_units=self.dp * self._stage_chunk,
+            coalesce=getattr(cfg.replay, "ingest_coalesce", 4),
+            buffers=getattr(cfg.replay, "stage_buffers", 2),
+            ship=self._ship_staged)
         # tiered cold store (replay/cold_store.py; ROADMAP item 3):
         # host-RAM compressed segments behind the ring, default OFF.
         # With the tier on and the ring full, every ship evicts the
@@ -370,11 +363,6 @@ class ApexDriver:
                     "DQN replay (priority-mass eviction has no meaning "
                     "without a sum tree); set cold_tier_capacity=0 for "
                     f"family={self.family!r}, kind={cfg.replay.kind!r}")
-            if self._stager is None:
-                raise ValueError(
-                    "the cold tier refills through the zero-copy ingest "
-                    "stager — replay.ingest_zero_copy=False and "
-                    "cold_tier_capacity > 0 are incompatible")
             disk_cap = getattr(cfg.replay, "cold_tier_disk_capacity", 0)
             if disk_cap > 0:
                 from ape_x_dqn_tpu.replay.disk_store import DiskStore
@@ -895,8 +883,7 @@ class ApexDriver:
                     # coalescing costs bounded latency (<= the 0.1s poll)
                     # instead of holding a partial group hostage behind
                     # a slow actor stream
-                    if self._stager is not None:
-                        self._stager.drain()
+                    self._stager.drain()
                     # idle bandwidth goes to cold recalls: high-mass
                     # cold segments restage through the same stager
                     self._cold_refill_tick()
@@ -1004,33 +991,19 @@ class ApexDriver:
             self.obs.gauge("param_compression_ratio", ratio)
 
     def _stage_one(self, batch: dict, n: int, tag=None) -> None:
-        if self._stager is not None:
-            self._stager.put(batch, tag=tag)
-            # below min_fill the learner is stalled waiting on replay:
-            # ship complete blocks eagerly (warmed g=1 graph) instead of
-            # letting coalescing delay the first train dispatch by up to
-            # a full buffer — steady-state keeps the coalesced cadence
-            if self._replay_filled < self._min_fill():
-                self._stager.drain()
-            self.obs.gauge("ingest_staging_occupancy",
-                           self._stager.occupancy())
-            self.obs.gauge("ingest_decode_ms",
-                           self._stager.last_put_decode_ms)
-            self.obs.gauge("ingest_ship_ms",
-                           self._stager.last_ship_ms)
-        else:
-            rel = getattr(batch, "release", None)
-            if rel is not None:
-                # shm slot batch on the legacy (stagerless) path: the
-                # deferred concatenate in _flush_stage would pin the
-                # ring slot for an unbounded stay in self._stage, so
-                # materialize the rows now and free the slot
-                batch = {k: np.asarray(batch[k]).copy()
-                         for k in self._item_keys + ("priorities",)}
-                rel()
-            self._stage.append(batch)
-            self._stage_n += n
-            self._flush_stage()
+        self._stager.put(batch, tag=tag)
+        # below min_fill the learner is stalled waiting on replay:
+        # ship complete blocks eagerly (warmed g=1 graph) instead of
+        # letting coalescing delay the first train dispatch by up to
+        # a full buffer — steady-state keeps the coalesced cadence
+        if self._replay_filled < self._min_fill():
+            self._stager.drain()
+        self.obs.gauge("ingest_staging_occupancy",
+                       self._stager.occupancy())
+        self.obs.gauge("ingest_decode_ms",
+                       self._stager.last_put_decode_ms)
+        self.obs.gauge("ingest_ship_ms",
+                       self._stager.last_ship_ms)
 
     def _ship_staged(self, views: dict, g: int) -> list:
         """Ship g coalesced staged blocks (IngestStager callback): async
@@ -1065,8 +1038,7 @@ class ApexDriver:
         # correlation tail: the origin batch_ids staged into this
         # dispatch (truncated — attribution, not an exhaustive ledger)
         span_args: dict = {"units": count}
-        tags = self._stager.shipping_tags if self._stager is not None \
-            else ()
+        tags = self._stager.shipping_tags
         if tags:
             span_args["batch_ids"] = [t[1] for t in tags[:MAX_SPAN_IDS]]
         # 1-in-N profiled ship (ObsConfig.profile_windows): bracket the
@@ -1256,110 +1228,47 @@ class ApexDriver:
         if d:
             self.obs.count("cold_disk_errors", d)
 
-    def _add_block(self, take: dict, count: int) -> None:
-        """count is in staging units; priorities reshape like items (they
-        carry a trailing [seg_transitions] axis in frame-ring mode)."""
-        if self.is_dist:
-            shard = lambda v: jnp.asarray(v).reshape(
-                self.dp, self._stage_chunk, *v.shape[1:])
-            items = {k: shard(v) for k, v in take.items()
-                     if k != "priorities"}
-            pris = shard(take["priorities"])
-        else:
-            items = {k: jnp.asarray(v) for k, v in take.items()
-                     if k != "priorities"}
-            pris = jnp.asarray(take["priorities"])
-        with self._hold_state("ingest"):
-            with self.obs.span("replay.add", units=count):
-                self.state = self.learner.add(self.state, items, pris)
-        self.ingest_rows.add(count * self._unit_items)
-        with self._lock:
-            self._replay_filled = min(
-                self._replay_filled + count * self._unit_items,
-                self.capacity)
-
     def _flush_stage(self, force: bool = False) -> None:
-        """Ship staged transitions to the learner in fixed-size blocks —
+        """Ship every complete staged block through the stager —
         [dp, chunk] on the mesh (consecutive chunks round-robin across
         shards, keeping priority masses balanced for the dist IS-weight
-        approximation), [chunk] single-chip. Fixed shapes keep the add
-        jit at exactly one compiled graph."""
-        if self._stager is not None:
-            # zero-copy path: complete blocks ship through the stager;
-            # at force-flush the sub-block tail is DROPPED and counted
-            # in the SAME three denominations as the legacy path below
-            # (the accounting is pinned by tests/test_ingest.py)
-            self._stager.drain()
-            tail = self._stager.tail_units()
-            if force and tail:
-                if self._frame_mode:
-                    # live transitions per staged unit, then folded to
-                    # shards — segments carry dead episode-tail pads
-                    live = (self._stager.tail_view("next_off") > 0
-                            ).sum(axis=-1)
-                    per_shard = self._tail_shard_counts(live)
-                elif self.family in SEQUENCE_FAMILIES:
-                    per_shard = np.asarray(
-                        self._stager.tail_shard_units(self.dp),
-                        np.int64) * self.cfg.replay.seq_length
-                else:
-                    per_shard = np.asarray(
-                        self._stager.tail_shard_units(self.dp), np.int64)
-                    with self._lock:
-                        self._frames_total -= tail
-                self._stage_dropped += int(per_shard.sum())
-                self._stage_dropped_per_shard += per_shard
-                self._stager.discard_tail()
+        approximation), [chunk] single-chip; fixed shapes keep the add
+        jits at exactly two compiled graphs. With `force`, the
+        sub-block tail is DROPPED and counted, single-chip and mesh
+        alike, matching the lossy-tolerant transport semantics: a
+        ragged add would compile a brand-new XLA graph (20-40s on TPU)
+        during DRIVER TEARDOWN to save under one block of transitions
+        the learner is about to stop sampling anyway. The drop is
+        transition-denominated in all three staging-unit kinds (the
+        accounting is pinned by tests/test_ingest.py)."""
+        self._stager.drain()
+        tail = self._stager.tail_units()
+        if not (force and tail):
             return
-        block = self.dp * self._stage_chunk
-        while self._stage_n >= block:
-            fields = {
-                k: np.concatenate([np.asarray(b[k]) for b in self._stage])
-                for k in self._item_keys + ("priorities",)}
-            take = {k: v[:block] for k, v in fields.items()}
-            rest = {k: v[block:] for k, v in fields.items()}
-            self._stage = [rest] if rest["priorities"].shape[0] else []
-            self._stage_n -= block
-            self._add_block(take, block)
-        if force and self._stage_n:
-            # the partial tail block is DROPPED (counted), single-chip
-            # and mesh alike, matching the lossy-tolerant transport
-            # semantics. Single-chip used to ship it as one ragged add,
-            # but that compiles a brand-new XLA graph (20-40s on TPU,
-            # tens of seconds on a busy CPU host) during DRIVER
-            # TEARDOWN to save under one block of transitions the
-            # learner is about to stop sampling anyway — and an
-            # in-teardown compile was on the stack of a rare LLVM
-            # segfault observed in the round-5 CI soak. Ape-X tolerates
-            # far larger losses at every actor crash.
-            if self._frame_mode:
-                # count LIVE transitions (segments carry dead episode-
-                # tail pads), and leave _frames_total alone: env-frame
-                # counts ride ingest messages separately in frame mode
-                # and those frames were genuinely consumed
-                live = np.concatenate(
-                    [(np.asarray(b["next_off"]) > 0).sum(axis=-1)
-                     for b in self._stage])
-                per_shard = self._tail_shard_counts(live)
-            elif self.family in SEQUENCE_FAMILIES:
-                # units are sequences; env frames also ride ingest
-                # messages separately here, so _frames_total stays.
-                # The drop stat is transition-denominated: seq_length
-                # per sequence (an upper bound — overlapping
-                # sequences double-count their shared steps)
-                per_shard = self._tail_shard_counts(np.full(
-                    self._stage_n, self.cfg.replay.seq_length, np.int64))
-            else:
-                # flat mode: 1 unit = 1 env frame, keep the frames
-                # counter reconciled with what actually reached replay
-                per_shard = self._tail_shard_counts(
-                    np.ones(self._stage_n, np.int64))
-                with self._lock:
-                    self._frames_total -= self._stage_n
-            self._stage_dropped += int(per_shard.sum())
-            self._stage_dropped_per_shard += per_shard
-            self._stage = []
-            self._stage_n = 0
+        if self._frame_mode:
+            # LIVE transitions per staged unit (segments carry dead
+            # episode-tail pads), then folded to shards; _frames_total
+            # stays: env-frame counts ride ingest messages separately
+            # in frame mode and those frames were genuinely consumed
+            live = (self._stager.tail_view("next_off") > 0).sum(axis=-1)
+            per_shard = self._tail_shard_counts(live)
+        elif self.family in SEQUENCE_FAMILIES:
+            # units are sequences, seq_length transitions each (an
+            # upper bound — overlapping sequences double-count their
+            # shared steps); env frames ride ingest messages here too
+            per_shard = np.asarray(
+                self._stager.tail_shard_units(self.dp),
+                np.int64) * self.cfg.replay.seq_length
+        else:
+            # flat mode: 1 unit = 1 env frame, keep the frames counter
+            # reconciled with what actually reached replay
+            per_shard = np.asarray(
+                self._stager.tail_shard_units(self.dp), np.int64)
+            with self._lock:
+                self._frames_total -= tail
+        self._stage_dropped += int(per_shard.sum())
+        self._stage_dropped_per_shard += per_shard
+        self._stager.discard_tail()
 
     def _tail_shard_counts(self, per_unit) -> np.ndarray:
         """Fold unit-indexed drop counts into per-shard totals: staged
@@ -1420,9 +1329,9 @@ class ApexDriver:
                                        pris, start0).compile()
             self.obs.log_compiled("evict_region", c_ev)
             self.obs.log_compiled("add_at", c_addat)
-        if self._stager is not None and self._stager.coalesce > 1:
+        if self._stager.coalesce > 1:
             # coalesced ingest groups [g, ...block shape] — the other
-            # add graph the zero-copy stager dispatches (full buffers)
+            # add graph the stager dispatches (full buffers)
             g = self._stager.coalesce
             gexample = jax.tree.map(
                 lambda t: jnp.zeros((g,) + t.shape, t.dtype), example)
@@ -1628,7 +1537,7 @@ class ApexDriver:
                     # live bounds come from the host fill mirror (no
                     # device fetch on the hot loop); any future
                     # non-lockstep ingest shows up as divergence in the
-                    # bench lane's true per-shard stats (shard_stats)
+                    # true per-shard stats (shard_stats, at teardown)
                     from ape_x_dqn_tpu.obs.profiling import (
                         publish_multichip)
                     fill = replay_size / max(self.capacity, 1)

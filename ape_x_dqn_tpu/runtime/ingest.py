@@ -1,10 +1,8 @@
 """Zero-copy pipelined ingest staging (actor wire -> device replay).
 
-The legacy driver staging appended each received batch to a Python list
-and re-concatenated the whole backlog per flush — every wire byte was
-copied at decode, again at concatenate, and the carried `rest` dict was
-re-copied at every subsequent flush. This module replaces that with
-preallocated fixed-shape staging buffers:
+The driver's one staging path: preallocated fixed-shape staging
+buffers, so no received batch is appended to a list and concatenated
+again per flush.
 
 - Wire batches decode DIRECTLY into a contiguous staging row at a write
   cursor (comm/socket_transport.decode_batch_into): ONE copy per wire
@@ -30,8 +28,8 @@ runs dry (its 0.1s recv timeout), which ships every COMPLETE block in
 the partial buffer block-by-block through the warmed `add` graph and
 compacts the remainder to the buffer front — so coalescing never holds
 experience hostage behind a slow actor stream. The sub-block tail only
-drops (counted by the driver, in the same three denominations as the
-legacy path) at force-flush during teardown.
+drops (counted by the driver, in its three denominations) at
+force-flush during teardown.
 """
 
 from __future__ import annotations

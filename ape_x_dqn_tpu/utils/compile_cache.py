@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-Every entry point (runtime/train.py, runtime/actor_host.py, bench.py,
-chip_smoke.py) calls `ensure_compile_cache()` first thing, before any
+Every entry point (runtime/train.py, runtime/actor_host.py,
+chip_smoke.py, benchmarks/harness/runner.py) calls `ensure_compile_cache()` first thing, before any
 backend compiles. The directory is decided from outside the program:
 
 - `JAX_COMPILATION_CACHE_DIR` set: JAX reads it itself and this module

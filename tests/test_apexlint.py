@@ -42,7 +42,7 @@ def test_package_has_zero_findings():
         "apexlint found violations in the package:\n" + "\n".join(
             f"{f['path']}:{f['line']}: [{f['checker']}] {f['message']}"
             for f in summary["findings"]))
-    # waivers exist (each justified in-line); creep shows up in bench
+    # waivers exist (each justified in-line); the summary counts them
     assert summary["checked_files"] > 50
 
 
@@ -60,8 +60,8 @@ def test_cli_json_subprocess():
         "use-after-donate", "host-sync",
         "config-coverage", "learner-parity",
         "thread-lifecycle", "resource-lifecycle", "counter-closure"}
-    # per-checker shape feeds bench.py's secondary.apexlint lane;
-    # "ms" is the wall-clock CI watches for a checker gone slow
+    # per-checker shape is what the CLI prints and run_chunked.sh
+    # asserts on; "ms" is the wall-clock to watch for a checker gone slow
     for counts in summary["per_checker"].values():
         assert set(counts) == {"findings", "waivers", "ms"}
         assert counts["ms"] >= 0
@@ -438,7 +438,7 @@ def test_remediation_accounting_fixtures():
 def test_remediation_accounting_scope_is_runtime(tmp_path):
     # an uncounted actuator call OUTSIDE runtime/ is not flagged: the
     # rule enforces the remediation plane's audit-trail contract, not a
-    # repo-wide naming ban (bench.py wires bare actuators on purpose)
+    # repo-wide naming ban (a harness may wire bare actuators on purpose)
     bad_src = open(
         _fx(os.path.join("runtime", "remediation_bad.py")),
         encoding="utf-8").read()

@@ -348,6 +348,38 @@ def test_check_flags_dump_that_lost_its_window(tmp_path):
                 if x.startswith("blackbox_dropped")]
 
 
+# -- a healthy run ----------------------------------------------------------
+
+def test_healthy_training_run_leaves_no_dump(tmp_path):
+    """The recorder rides a real run for free of side effects: a short
+    synthetic-Atari run through the single-process driver with the
+    recorder live (crash hooks installed; publish, stall and perf
+    events going into the ring) writes no blackbox-*.json, and
+    obs.close() has taken the crash hooks out again."""
+    from ape_x_dqn_tpu.configs import (
+        EnvConfig, LearnerConfig, NetworkConfig, ReplayConfig, get_config)
+    from ape_x_dqn_tpu.runtime.single_process import train_single_process
+
+    hook = sys.excepthook
+    cfg = get_config("pong").replace(
+        env=EnvConfig(id="catch", kind="synthetic_atari"),
+        network=NetworkConfig(kind="nature_cnn", dueling=True,
+                              compute_dtype="float32"),
+        replay=ReplayConfig(kind="prioritized", capacity=2048,
+                            min_fill=300),
+        learner=LearnerConfig(batch_size=16, n_step=3,
+                              target_sync_every=16, sample_chunk=2),
+        obs=ObsConfig(enabled=True, publish_every_steps=50,
+                      heartbeat_timeout_s=120.0, blackbox=True,
+                      blackbox_dir=str(tmp_path)))
+    out = train_single_process(cfg, total_env_frames=600,
+                               metrics=Metrics(), train_every=2)
+    assert out["grad_steps"] > 0
+    assert not [f for f in os.listdir(tmp_path)
+                if f.startswith("blackbox-")]
+    assert sys.excepthook is hook
+
+
 # -- disabled contract ------------------------------------------------------
 
 def test_disabled_blackbox_is_a_noop(tmp_path):

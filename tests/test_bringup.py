@@ -1,16 +1,13 @@
 """Chip bring-up guards (ISSUE 21): the things that would let a chip
 run pass without the chip, or fail for the wrong reason, checked on the
 CPU — chip_smoke.py refuses anything but a TPU, the compile cache is
-placed from outside, the multichip sweep's parent leaves the backend to
-its children, and a native .so is only ever loaded if it was built from
-exactly the source on disk."""
+placed from outside, and a native .so is only ever loaded if it was
+built from exactly the source on disk."""
 
-import json
 import os
 import shutil
 import subprocess
 import sys
-import types
 
 import pytest
 
@@ -84,70 +81,6 @@ def test_compile_cache_fixed_path_from_any_cwd(tmp_path):
     want = os.path.join(REPO, ".jax_cache")
     assert outs[0].stdout.split() == [want, want]
     assert outs[1].stdout == outs[0].stdout
-
-
-def test_multichip_parent_never_touches_backend(tmp_path, monkeypatch):
-    """A chip belongs to one process: bench_multichip's parent must not
-    initialise a backend before (or while) its dp children run. Real
-    device mode is the default; virtual devices only by name."""
-    import jax
-
-    import bench
-
-    def boom(*a, **kw):
-        raise AssertionError("multichip parent touched the JAX backend")
-
-    monkeypatch.setattr(jax, "devices", boom)
-    monkeypatch.setattr(jax, "local_devices", boom)
-    monkeypatch.setattr(jax, "device_count", boom)
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-    seen = []
-
-    def fake_run(cmd, env=None, **kw):
-        seen.append((cmd, env))
-        dp = int(cmd[cmd.index("--multichip-child") + 1].split("/")[0])
-        point = {"dp": dp, "grad_steps_per_s": {"median": 10.0 / dp},
-                 "ingest_rows_per_s": 1.0, "gauges": {},
-                 "shards": {"fill_min": 1.0, "fill_max": 1.0}}
-        return types.SimpleNamespace(
-            returncode=0, stderr="",
-            stdout=bench._MULTICHIP_MARKER + json.dumps(point) + "\n")
-
-    monkeypatch.setattr(subprocess, "run", fake_run)
-    monkeypatch.setenv("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=8")
-    args = types.SimpleNamespace(
-        multichip="dp=1,2", capacity=256, batch_size=32, prefill=64,
-        steps_per_dispatch=2, dispatches=1, repeats=1, sample_chunk=1,
-        smoke=True, perf_gate=False)
-    with pytest.raises(SystemExit) as exit_info:
-        bench.bench_multichip(args)
-    assert exit_info.value.code == 0
-    assert [c[c.index("--multichip-child") + 1] for c, _ in seen] == [
-        "1/2", "2/2"]
-    # real mode: no inherited forcing flag lets CPU pass for two devices
-    assert all("xla_force_host_platform_device_count"
-               not in env["XLA_FLAGS"] for _, env in seen)
-    doc = json.loads((tmp_path / "MULTICHIP_SMOKE.json").read_text())
-    assert doc["virtual_devices"] is False
-
-    seen.clear()
-    args.multichip = "virtual:dp=1,2"
-    with pytest.raises(SystemExit):
-        bench.bench_multichip(args)
-    assert all("--xla_force_host_platform_device_count=2"
-               in env["XLA_FLAGS"] and env["JAX_PLATFORMS"] == "cpu"
-               for _, env in seen)
-
-
-def test_multichip_child_fails_short_of_devices():
-    """Asked for more real devices than exist: the child fails, naming
-    what it found, instead of quietly going virtual."""
-    proc = _run([os.path.join(REPO, "bench.py"), "--multichip-child",
-                 "1/4", "--smoke"], REPO,
-                {"JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""})
-    assert proc.returncode != 0
-    assert "needs 4 devices but JAX found 1" in proc.stderr
 
 
 @pytest.mark.skipif(shutil.which("g++") is None, reason="needs g++")

@@ -319,6 +319,33 @@ def test_cold_store_compression_ratio_floor():
     assert cs.bytes_compressed <= cs.bytes_raw + 9 * 2
 
 
+def test_cold_tier_holds_eight_rings_at_an_eighth_of_the_bytes():
+    """The tier's capacity criterion, at a 4,096-transition ring of
+    16-transition segments evicted 512 transitions at a time: the cold
+    store ends up holding 8x the ring's transitions, the door having
+    dropped none of them, at under 1/8 of the bytes per transition the
+    ring's device state costs (frames that share a base image with
+    sparse per-frame noise, like emulator play)."""
+    rng = np.random.default_rng(7)
+    capacity, block_units = 4096, 32
+    r = FrameRingReplay(capacity, seg_transitions=16, n_step=3,
+                        obs_shape=OBS_SHAPE)
+    ring_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(r.init()))
+    cold = ColdStore(frame_segment_spec(r.B, r.n, OBS_SHAPE, np.uint8),
+                     capacity_transitions=16 * capacity, unit_items=r.B,
+                     ptail=(r.B,), compress_level=1)
+    block = _seg_batch(r, block_units, rng)
+    pri = rng.uniform(0.1, 2.0, (block_units, r.B)).astype(np.float32)
+    evicted = 0
+    while cold.transitions < 8 * capacity:
+        assert cold.put(block, pri, live=block_units * r.B) == "stored"
+        evicted += block_units * r.B
+    assert cold.transitions == evicted == 8 * capacity
+    assert cold.dropped == 0 and cold.displaced == 0
+    cold_bpt = cold.bytes_compressed / cold.transitions
+    assert cold_bpt < (ring_bytes / capacity) / 8, cold_bpt
+
+
 def test_codec_status_reports_available():
     ok, detail = codec_status()
     assert ok
@@ -415,6 +442,50 @@ def test_cold_spill_all_dead_regions_never_offered():
     items, pri = _tiny_seg(rng, 0.0)
     assert cs.put(items, pri, live=0) == "dropped"
     assert spill.offers == []  # zero mass: nothing worth disk bytes
+
+
+def test_disk_rung_retains_eight_cold_tiers_without_errors(tmp_path):
+    """The disk rung's retention criterion through the real spill
+    chain (ColdStore door -> DiskStore writeback), at a cold tier of
+    8,192 transitions fed 512-transition eviction blocks: every door
+    loser is offered, the disk ends up holding 8x the cold tier's
+    capacity with no I/O error and no corrupt segment, and the
+    heaviest segments read back through their CRCs."""
+    from ape_x_dqn_tpu.replay.disk_store import DiskStore
+
+    rng = np.random.default_rng(7)
+    r = FrameRingReplay(4096, seg_transitions=16, n_step=3,
+                        obs_shape=OBS_SHAPE)
+    cold_cap, block_units = 8192, 32
+    target = 8 * cold_cap
+    disk = DiskStore(str(tmp_path / "disk"), 2 * target, queue_depth=16)
+    cold = ColdStore(frame_segment_spec(r.B, r.n, OBS_SHAPE, np.uint8),
+                     capacity_transitions=cold_cap, unit_items=r.B,
+                     ptail=(r.B,), compress_level=1, spill=disk)
+    block = _seg_batch(r, block_units, rng)
+    live = block_units * r.B
+    try:
+        puts = 0
+        while disk.transitions < target:
+            pri = np.full((block_units, r.B), rng.uniform(0.1, 2.0),
+                          np.float32)
+            cold.put(block, pri, live=live)
+            puts += 1
+            if disk.stats()["queue_full"]:
+                disk.drain(timeout=60.0)  # offer() never waits; we do
+            assert puts <= 4 * (target // live + cold_cap // live), puts
+        disk.drain(timeout=60.0)
+        stats = disk.stats()
+        assert stats["transitions"] >= target
+        assert stats["io_errors"] == 0
+        assert stats["corrupt_segments"] == 0
+        assert cold.spilled * live >= stats["transitions"]
+        promoted = disk.promote(4, floor=0.0)
+        assert len(promoted) == 4 and all(s.live == live
+                                          for s in promoted)
+        assert disk.stats()["corrupt_segments"] == 0
+    finally:
+        disk.close()
 
 
 def test_put_segment_door_without_touching_eviction_counters():

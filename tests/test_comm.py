@@ -828,16 +828,37 @@ def test_actor_loss_fault_injection():
     server = SocketIngestServer("127.0.0.1", 0)
     driver = ApexDriver(cfg, transport=server)
     proc = _spawn_actor_host(server.port, frames=10**7)  # would run forever
+    # the kill rides an event the driver already reports, not a clock:
+    # the learner thread hands the server its first params published
+    # after training began (publish_every=20), and the host dies right
+    # there, so every later grad step strictly follows the kill. (A
+    # thread polling driver.grad_steps cannot see this run mid-way: the
+    # 60 steps take less than one 50 ms poll.)
+    killed_at = []
+    publish = server.publish_params
 
-    def killer():
-        time.sleep(6.0)
-        proc.send_signal(signal.SIGKILL)
+    def publish_then_kill(params, version):
+        publish(params, version)
+        if version > 0 and not killed_at:
+            proc.send_signal(signal.SIGKILL)
+            proc.wait()
+            killed_at.append(version)
 
-    threading.Thread(target=killer, daemon=True).start()
+    server.publish_params = publish_then_kill
     try:
+        # params v0 went out when the driver was built, so the host acts
+        # and ships as soon as its interpreter is up (seconds); wait for
+        # its first experience, or the host is not yet a producer when
+        # it dies. Its batches park in the ingest queue until run().
+        deadline = time.monotonic() + 120
+        while not server.ever_connected and time.monotonic() < deadline:
+            assert proc.poll() is None, proc.stderr.read()
+            time.sleep(0.05)
+        assert server.ever_connected, "actor host never delivered"
         out = driver.run(total_env_frames=1500, max_grad_steps=60,
                          wall_clock_limit_s=180)
         assert proc.poll() is not None, "actor host was not killed"
+        assert killed_at and killed_at[0] < 60, killed_at  # mid-run
         assert out["actor_errors"] == [], out["actor_errors"]
         assert out["loop_errors"] == [], out["loop_errors"]
         assert out["grad_steps"] >= 60, out

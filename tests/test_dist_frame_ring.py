@@ -12,7 +12,8 @@ flagship e2e test; these tests pin the SEMANTICS of that path:
   frame-ring storage where shard fills (not just priority masses) can
   diverge and dead episode-pad slots must train with weight 0.
 - shard_stats: the per-shard fill/mass observability surface the
-  multichip lane (bench.py --multichip) and the run report consume.
+  dist driver publishes (obs/report.py's multichip section) and the
+  run report consumes.
 """
 
 import jax
@@ -234,57 +235,6 @@ def test_live_transitions_single_and_sharded():
     sd = replay.add_lockstep(_stack1(replay.init()), _stack1(items),
                              pris[None])
     assert np.asarray(replay.live_transitions(sd)).tolist() == [16]
-
-
-def test_multichip_baseline_comparable_shapes_only(tmp_path, monkeypatch):
-    """The --multichip anti-ratchet gate only compares like with like:
-    same device mode, same dp set, real curve artifacts only. A
-    cross-mode or cross-shape artifact (or a pre-curve raw capture like
-    MULTICHIP_r01.json) is skipped, never compared."""
-    import importlib
-    import json as _json
-    import sys as _sys
-
-    repo_root = __file__.rsplit("/tests/", 1)[0]
-    if repo_root not in _sys.path:
-        _sys.path.insert(0, repo_root)
-    bench = importlib.import_module("bench")
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-
-    def _write(name, doc):
-        (tmp_path / name).write_text(_json.dumps(doc))
-
-    # pre-curve raw capture: no metric/value -> never a baseline
-    _write("MULTICHIP_r01.json", {"ok": False, "n_devices": 1})
-    # real-device curve: wrong mode for a virtual run
-    _write("MULTICHIP_r02.json",
-           {"metric": "multichip_dp_scaling_efficiency", "value": 0.9,
-            "virtual_devices": False, "dp": [1, 2, 4, 8]})
-    path, doc = bench._load_multichip_baseline(
-        smoke=False, virtual=True, dp_list=[1, 2, 4, 8])
-    assert path is None and doc is None
-
-    # comparable virtual curve, but a different dp set -> skipped
-    _write("MULTICHIP_r03.json",
-           {"metric": "multichip_dp_scaling_efficiency", "value": 0.5,
-            "virtual_devices": True, "dp": [1, 2]})
-    path, doc = bench._load_multichip_baseline(
-        smoke=False, virtual=True, dp_list=[1, 2, 4, 8])
-    assert path is None and doc is None
-
-    # the genuinely comparable artifact wins
-    _write("MULTICHIP_r04.json",
-           {"metric": "multichip_dp_scaling_efficiency", "value": 0.5,
-            "virtual_devices": True, "dp": [8, 4, 2, 1]})  # order-free
-    path, doc = bench._load_multichip_baseline(
-        smoke=False, virtual=True, dp_list=[1, 2, 4, 8])
-    assert path is not None and path.endswith("MULTICHIP_r04.json")
-    assert doc["value"] == 0.5
-
-    # smoke class never reads the full-shape artifacts
-    path, doc = bench._load_multichip_baseline(
-        smoke=True, virtual=True, dp_list=[1, 2, 4, 8])
-    assert path is None and doc is None
 
 
 # -- the ring's rows are 32-bit words, on the dp mesh too (PR 29) ----------

@@ -472,8 +472,8 @@ def test_apexdriver_builds_and_trains():
     try:
         assert type(driver.learner) is SingleChipLearner
         assert driver.learner.family.name == "decoder_q"
-        assert driver._item_keys == ("obs", "actions", "rewards",
-                                     "terminals", "mask")
+        assert tuple(driver._item_spec) == (
+            "obs", "actions", "rewards", "terminals", "mask")
         state = driver.state
         rng = np.random.default_rng(0)
         n = 16

@@ -1,14 +1,15 @@
-"""Zero-copy ingest staging (runtime/ingest.py + driver integration):
+"""Ingest staging (runtime/ingest.py + driver integration):
 
 - partial-tail drop accounting at _flush_stage(force=True) in all three
   denominations (flat units, frame-ring live transitions, r2d2 sequence
-  upper bound), on BOTH staging paths (legacy list-append and zero-copy
-  stager) — the accounting must survive the staging rewrite exactly
-- bitwise ingest parity: the same recorded wire stream lands identical
-  replay-bound blocks through decode-into-staging as through the legacy
-  decode_batch + concatenate path, for flat + frame-ring + r2d2 — and
-  the delta-deflate wire codec must land the same bits as raw through
-  both paths (split decodes exercise the delta continuation cache)
+  upper bound), with blocks shipped one at a time (ingest_coalesce=1,
+  the `add` graph) and coalesced (ingest_coalesce=4, `add_many`)
+- bitwise ingest parity: the same recorded wire stream lands the
+  replay-bound blocks a plain numpy reference in this file computes
+  (decode_batch, concatenate, cut into blocks, drop the tail), for
+  flat + frame-ring + r2d2 — and the delta-deflate wire codec must land
+  the same bits as raw (split decodes exercise the delta continuation
+  cache)
 - IngestStager unit behavior: boundary splitting, coalesced ships,
   drain compaction, tail exposure
 """
@@ -19,7 +20,8 @@ import jax
 import numpy as np
 import pytest
 
-from ape_x_dqn_tpu.comm.socket_transport import WireBatch, encode_batch
+from ape_x_dqn_tpu.comm.socket_transport import (
+    WireBatch, decode_batch, encode_batch)
 from ape_x_dqn_tpu.configs import (
     ActorConfig, EnvConfig, InferenceConfig, LearnerConfig, NetworkConfig,
     ParallelConfig, ReplayConfig, RunConfig, get_config)
@@ -92,55 +94,69 @@ def _synth_batch(driver, n, seed=0, frames=None):
     return batch
 
 
-# -- drop accounting (pins the legacy semantics; the stager must match) ----
+# -- drop accounting, in both ship regimes ---------------------------------
+# ingest_coalesce=1: every full buffer is one block and ships through
+# the single-block `add` graph (what every run does below min_fill);
+# ingest_coalesce=4: a full buffer ships as one `add_many` of 4 blocks.
+# One message of coalesce * block + tail units fills exactly one buffer
+# and leaves the tail staged; the accounting must close in both.
+
+COALESCE = pytest.mark.parametrize("coalesce", [1, 4])
 
 
-@pytest.mark.parametrize("zero_copy", [False, True])
-def test_flat_tail_drop_accounting(zero_copy):
+def _full_buffer_plus(d, tail, coalesce, **batch_kw):
+    """Build one message of coalesce full blocks + `tail` units; returns
+    (the batch, units in the buffer it will fill and ship)."""
+    assert d._stager.coalesce == coalesce
+    shipped = coalesce * d.dp * d._stage_chunk
+    batch = _synth_batch(d, shipped + tail, **batch_kw)
+    return batch, shipped
+
+
+@COALESCE
+def test_flat_tail_drop_accounting(coalesce):
     """Flat denomination: 1 unit = 1 env frame; the dropped tail comes
     OFF _frames_total so frames reconcile with replay contents."""
-    d = ApexDriver(_flat_cfg(ingest_zero_copy=zero_copy))
-    assert (d._stager is not None) == zero_copy
-    block = d.dp * d._stage_chunk
+    d = ApexDriver(_flat_cfg(ingest_coalesce=coalesce))
     tail = 3
-    d._ingest_one(_synth_batch(d, block + tail), block + tail)
+    batch, shipped = _full_buffer_plus(d, tail, coalesce)
+    d._ingest_one(batch, shipped + tail)
     d._flush_stage(force=True)
     assert d._stage_dropped == tail
-    assert d._frames_total == block  # ingested minus dropped tail
-    assert d._replay_filled == block * d._unit_items
+    assert d._frames_total == shipped  # ingested minus dropped tail
+    assert d._replay_filled == shipped * d._unit_items
 
 
-@pytest.mark.parametrize("zero_copy", [False, True])
-def test_frame_ring_tail_drop_accounting(zero_copy):
+@COALESCE
+def test_frame_ring_tail_drop_accounting(coalesce):
     """Frame-ring denomination: dropped segments count their LIVE
     transitions (next_off > 0); _frames_total stays (env frames ride
     ingest messages separately in frame mode)."""
-    d = ApexDriver(_ring_cfg(ingest_zero_copy=zero_copy))
-    block = d.dp * d._stage_chunk
+    d = ApexDriver(_ring_cfg(ingest_coalesce=coalesce))
     tail = 1
-    batch = _synth_batch(d, block + tail, frames=37)
+    batch, shipped = _full_buffer_plus(d, tail, coalesce, frames=37)
     # make the tail segment's liveness pattern explicit
-    batch["next_off"][block:] = 0
-    batch["next_off"][block:, :5] = 2  # 5 live transitions in the tail
-    d._ingest_one(batch, block + tail)
+    batch["next_off"][shipped:] = 0
+    batch["next_off"][shipped:, :5] = 2  # 5 live transitions in the tail
+    d._ingest_one(batch, shipped + tail)
     d._flush_stage(force=True)
     assert d._stage_dropped == 5
     assert d._frames_total == 37  # untouched by the drop
-    assert d._replay_filled == block * d._unit_items
+    assert d._replay_filled == shipped * d._unit_items
 
 
-@pytest.mark.parametrize("zero_copy", [False, True])
-def test_r2d2_tail_drop_accounting(zero_copy):
+@COALESCE
+def test_r2d2_tail_drop_accounting(coalesce):
     """R2D2 denomination: units are sequences; drops count seq_length
     transitions per sequence (upper bound); _frames_total stays."""
-    d = ApexDriver(_r2d2_cfg(ingest_zero_copy=zero_copy))
-    block = d.dp * d._stage_chunk
+    d = ApexDriver(_r2d2_cfg(ingest_coalesce=coalesce))
     tail = 2
-    d._ingest_one(_synth_batch(d, block + tail, frames=29), block + tail)
+    batch, shipped = _full_buffer_plus(d, tail, coalesce, frames=29)
+    d._ingest_one(batch, shipped + tail)
     d._flush_stage(force=True)
     assert d._stage_dropped == tail * d.cfg.replay.seq_length
     assert d._frames_total == 29
-    assert d._replay_filled == block * d._unit_items
+    assert d._replay_filled == shipped * d._unit_items
 
 
 # -- per-shard drop closure under the [dp, chunk] round-robin split --------
@@ -153,62 +169,64 @@ def _dp2(cfg):
     return cfg.replace(parallel=ParallelConfig(dp=2, tp=1))
 
 
-@pytest.mark.parametrize("zero_copy", [False, True])
-def test_flat_per_shard_drop_closure_dp2(zero_copy):
-    d = ApexDriver(_dp2(_flat_cfg(ingest_zero_copy=zero_copy)))
+@COALESCE
+def test_flat_per_shard_drop_closure_dp2(coalesce):
+    d = ApexDriver(_dp2(_flat_cfg(ingest_coalesce=coalesce)))
     assert d.is_dist and d.dp == 2
     chunk = d._stage_chunk
-    block = d.dp * chunk
     tail = chunk + 2  # spans shard 0 fully + 2 units into shard 1
-    assert block > tail  # a tail is always shorter than one block
-    d._ingest_one(_synth_batch(d, block + tail), block + tail)
+    assert d.dp * chunk > tail  # a tail is always shorter than one block
+    batch, shipped = _full_buffer_plus(d, tail, coalesce)
+    d._ingest_one(batch, shipped + tail)
     d._flush_stage(force=True)
     assert d._stage_dropped == tail
     assert d._stage_dropped_per_shard.tolist() == [chunk, 2]
     assert int(d._stage_dropped_per_shard.sum()) == d._stage_dropped
-    assert d._frames_total == block
+    assert d._frames_total == shipped
+    assert d._replay_filled == shipped * d._unit_items
 
 
-@pytest.mark.parametrize("zero_copy", [False, True])
-def test_frame_ring_per_shard_drop_closure_dp2(zero_copy):
+@COALESCE
+def test_frame_ring_per_shard_drop_closure_dp2(coalesce):
     """Frame-ring denomination per shard: each dropped tail segment
     contributes its LIVE transition count to the shard it was bound
     for."""
-    d = ApexDriver(_dp2(_ring_cfg(ingest_zero_copy=zero_copy)))
+    d = ApexDriver(_dp2(_ring_cfg(ingest_coalesce=coalesce)))
     assert d.is_dist and d._frame_mode
     chunk = d._stage_chunk
-    block = d.dp * chunk
     tail = chunk + 1
-    assert block > tail
-    batch = _synth_batch(d, block + tail, frames=11)
+    assert d.dp * chunk > tail
+    batch, shipped = _full_buffer_plus(d, tail, coalesce, frames=11)
     # tail unit j carries exactly j+1 live transitions
-    batch["next_off"][block:] = 0
+    batch["next_off"][shipped:] = 0
     for j in range(tail):
-        batch["next_off"][block + j, :j + 1] = 2
-    d._ingest_one(batch, block + tail)
+        batch["next_off"][shipped + j, :j + 1] = 2
+    d._ingest_one(batch, shipped + tail)
     d._flush_stage(force=True)
     assert d._stage_dropped == sum(j + 1 for j in range(tail))
     assert d._stage_dropped_per_shard.tolist() == [
         sum(j + 1 for j in range(chunk)), chunk + 1]
     assert int(d._stage_dropped_per_shard.sum()) == d._stage_dropped
     assert d._frames_total == 11  # untouched by frame-mode drops
+    assert d._replay_filled == shipped * d._unit_items
 
 
-@pytest.mark.parametrize("zero_copy", [False, True])
-def test_r2d2_per_shard_drop_closure_dp2(zero_copy):
-    d = ApexDriver(_dp2(_r2d2_cfg(ingest_zero_copy=zero_copy)))
+@COALESCE
+def test_r2d2_per_shard_drop_closure_dp2(coalesce):
+    d = ApexDriver(_dp2(_r2d2_cfg(ingest_coalesce=coalesce)))
     assert d.is_dist and d.family == "r2d2"
     chunk = d._stage_chunk
-    block = d.dp * chunk
     tail = chunk + 1
-    assert block > tail
-    d._ingest_one(_synth_batch(d, block + tail, frames=29), block + tail)
+    assert d.dp * chunk > tail
+    batch, shipped = _full_buffer_plus(d, tail, coalesce, frames=29)
+    d._ingest_one(batch, shipped + tail)
     d._flush_stage(force=True)
     seq = d.cfg.replay.seq_length
     assert d._stage_dropped == tail * seq
     assert d._stage_dropped_per_shard.tolist() == [chunk * seq, seq]
     assert int(d._stage_dropped_per_shard.sum()) == d._stage_dropped
     assert d._frames_total == 29
+    assert d._replay_filled == shipped * d._unit_items
 
 
 def test_stager_tail_shard_units_round_robin():
@@ -227,7 +245,7 @@ def test_stager_tail_shard_units_round_robin():
 
 def test_drop_accounting_in_run_report():
     """_stage_dropped reaches the run report's ingest_dropped."""
-    d = ApexDriver(_flat_cfg(ingest_zero_copy=True))
+    d = ApexDriver(_flat_cfg())
     block = d.dp * d._stage_chunk
     d._ingest_one(_synth_batch(d, block + 2), block + 2)
     d._flush_stage(force=True)
@@ -311,70 +329,86 @@ def test_cold_off_never_routes_to_eviction_ship():
     d._cold_refill_tick()
 
 
-def test_cold_tier_rejects_legacy_staging():
-    with pytest.raises(ValueError, match="ingest_zero_copy"):
-        ApexDriver(_cold_ring_cfg(ingest_zero_copy=False))
-
-
-# -- bitwise ingest parity: zero-copy vs legacy on a recorded stream -------
+# -- bitwise ingest parity: the stager vs a plain reference ----------------
 
 
 def _record_stream(cfg_fn, sizes, payloads):
-    """Feed the same recorded wire payloads through one driver built
-    from cfg_fn, with device shipping stubbed to capture host blocks;
+    """Feed the recorded wire payloads through one driver built from
+    cfg_fn, with device shipping stubbed to capture host blocks;
     returns (per-key concatenated rows, dropped, frames_total)."""
-    cfg = cfg_fn()
-    d = ApexDriver(cfg)
+    d = ApexDriver(cfg_fn())
     recorded = []
-    if d._stager is not None:
-        def ship(views, g):
-            recorded.append({k: np.array(v) for k, v in views.items()})
-            return []
-        d._stager._ship = ship
-    else:
-        def add_block(take, count):
-            recorded.append({k: np.array(v) for k, v in take.items()})
-        d._add_block = add_block
-    from ape_x_dqn_tpu.comm.socket_transport import decode_batch
+
+    def ship(views, g):
+        recorded.append({k: np.array(v) for k, v in views.items()})
+        return []
+
+    d._stager._ship = ship
     for n, payload in zip(sizes, payloads):
-        batch = WireBatch(payload) if d._stager is not None \
-            else decode_batch(payload)
-        d._ingest_one(batch, n)
+        d._ingest_one(WireBatch(payload), n)
     d._flush_stage(force=True)
-    keys = d._item_keys + ("priorities",)
+    keys = tuple(d._item_spec) + ("priorities",)
     rows = {k: (np.concatenate([r[k] for r in recorded])
                 if recorded else None) for k in keys}
     return rows, d._stage_dropped, d._frames_total
 
 
-@pytest.mark.parametrize("cfg_fn", [_flat_cfg, _ring_cfg, _r2d2_cfg],
-                         ids=["flat", "frame_ring", "r2d2"])
-def test_ingest_parity_zero_copy_vs_legacy(cfg_fn):
-    """The SAME recorded wire stream (ragged batch sizes, so staging
-    boundaries are crossed mid-batch) must land bitwise-identical
-    replay-bound blocks through both staging paths, with identical
-    drop accounting."""
+def _reference_stream(cfg_fn, payloads):
+    """What the recorded stream must come to, in plain numpy and with
+    no staging code: decode every payload, concatenate in arrival
+    order, keep the whole dp * stage_chunk blocks, drop the tail and
+    count it in the configuration's denomination. Only the block
+    geometry and the family are read off a driver."""
     probe = ApexDriver(cfg_fn())
-    sizes = [3, 7, 1, 6, 5, 2]
-    payloads = []
-    for i, n in enumerate(sizes):
-        b = _synth_batch(probe, n, seed=100 + i, frames=n)
-        payloads.append(encode_batch(b))
-    del probe
-    new = _record_stream(lambda: cfg_fn(), sizes, payloads)
-    old = _record_stream(
-        lambda: cfg_fn().replace(
-            replay=dataclasses.replace(cfg_fn().replay,
-                                       ingest_zero_copy=False)),
-        sizes, payloads)
-    assert new[1] == old[1]  # dropped
-    assert new[2] == old[2]  # frames_total
-    for k in new[0]:
-        a, b = new[0][k], old[0][k]
+    block = probe.dp * probe._stage_chunk
+    keys = tuple(probe._item_spec) + ("priorities",)
+    batches = [decode_batch(p) for p in payloads]
+    rows = {k: np.concatenate([np.asarray(b[k]) for b in batches])
+            for k in keys}
+    total = rows["priorities"].shape[0]
+    kept = total // block * block
+    frames = sum(int(b["frames"]) for b in batches)
+    if probe._frame_mode:
+        dropped = int((rows["next_off"][kept:] > 0).sum())
+    elif probe.cfg.replay.kind == "sequence":
+        dropped = (total - kept) * probe.cfg.replay.seq_length
+    else:
+        dropped = total - kept
+        frames -= dropped
+    return ({k: (v[:kept] if kept else None) for k, v in rows.items()},
+            dropped, frames)
+
+
+def _assert_same_stream(got, want):
+    assert got[1] == want[1]  # dropped
+    assert got[2] == want[2]  # frames_total
+    for k in want[0]:
+        a, b = got[0][k], want[0][k]
         assert (a is None) == (b is None), k
         if a is not None:
             assert a.dtype == b.dtype, k
             np.testing.assert_array_equal(a, b, err_msg=k)
+
+
+def _recorded_payloads(cfg_fn, codecs):
+    """Ragged batch sizes, so staging boundaries are crossed
+    mid-batch; one payload list per codec, of the same batches."""
+    probe = ApexDriver(cfg_fn())
+    sizes = [3, 7, 1, 6, 5, 2]
+    batches = [_synth_batch(probe, n, seed=100 + i, frames=n)
+               for i, n in enumerate(sizes)]
+    return sizes, [[encode_batch(b, c) for b in batches] for c in codecs]
+
+
+@pytest.mark.parametrize("cfg_fn", [_flat_cfg, _ring_cfg, _r2d2_cfg],
+                         ids=["flat", "frame_ring", "r2d2"])
+def test_ingest_parity_stager_vs_reference(cfg_fn):
+    """The recorded wire stream must land, through the driver's
+    stager, bitwise the replay-bound blocks the plain reference cuts
+    from it, with the same drop accounting."""
+    sizes, (payloads,) = _recorded_payloads(cfg_fn, ["raw"])
+    _assert_same_stream(_record_stream(cfg_fn, sizes, payloads),
+                        _reference_stream(cfg_fn, payloads))
 
 
 @pytest.mark.parametrize("cfg_fn", [_flat_cfg, _ring_cfg, _r2d2_cfg],
@@ -382,33 +416,15 @@ def test_ingest_parity_zero_copy_vs_legacy(cfg_fn):
 def test_ingest_parity_codec_vs_raw(cfg_fn):
     """The delta-deflate wire codec must be invisible to replay: the
     SAME recorded stream encoded raw vs codec lands bitwise-identical
-    blocks through the zero-copy staging path (split decodes, delta
-    continuation across buffer boundaries and all) AND through the
-    legacy decode_batch path, in every denomination."""
-    probe = ApexDriver(cfg_fn())
-    sizes = [3, 7, 1, 6, 5, 2]
-    raw_payloads, codec_payloads = [], []
-    for i, n in enumerate(sizes):
-        b = _synth_batch(probe, n, seed=100 + i, frames=n)
-        raw_payloads.append(encode_batch(b, "raw"))
-        codec_payloads.append(encode_batch(b, "delta-deflate"))
-    del probe
-    raw = _record_stream(lambda: cfg_fn(), sizes, raw_payloads)
-    codec = _record_stream(lambda: cfg_fn(), sizes, codec_payloads)
-    legacy = _record_stream(
-        lambda: cfg_fn().replace(
-            replay=dataclasses.replace(cfg_fn().replay,
-                                       ingest_zero_copy=False)),
-        sizes, codec_payloads)
-    for other in (codec, legacy):
-        assert raw[1] == other[1]  # dropped
-        assert raw[2] == other[2]  # frames_total
-        for k in raw[0]:
-            a, b = raw[0][k], other[0][k]
-            assert (a is None) == (b is None), k
-            if a is not None:
-                assert a.dtype == b.dtype, k
-                np.testing.assert_array_equal(a, b, err_msg=k)
+    blocks through the staging path (split decodes, delta continuation
+    across buffer boundaries and all), and both are what the plain
+    reference makes of the codec stream, in every denomination."""
+    sizes, (raw_payloads, codec_payloads) = _recorded_payloads(
+        cfg_fn, ["raw", "delta-deflate"])
+    raw = _record_stream(cfg_fn, sizes, raw_payloads)
+    _assert_same_stream(_record_stream(cfg_fn, sizes, codec_payloads),
+                        raw)
+    _assert_same_stream(raw, _reference_stream(cfg_fn, codec_payloads))
 
 
 # -- IngestStager unit behavior --------------------------------------------
@@ -597,7 +613,7 @@ def test_cold_disk_dp2_per_shard_closure(tmp_path):
 
 def test_cold_disk_stats_reach_run_report_shape(tmp_path):
     """The disk block in the driver's run() output mirrors
-    DiskStore.stats() — pin the keys the bench and obs read."""
+    DiskStore.stats() — pin the keys obs and the tests read."""
     d = ApexDriver(_disk_cfg(tmp_path))
     s = d._disk.stats()
     assert set(s) >= {"segments", "transitions", "bytes", "files",

@@ -193,17 +193,19 @@ def test_dist_learner_diag_shard_closure():
 
 # -- end-to-end: catch run publishes the plane ----------------------------
 
-def test_single_process_catch_publishes_learn_gauges(tmp_path):
-    """Tier-1 acceptance (ISSUE 10): a short catch run with obs ON
-    publishes finite, in-healthy-range learn_* gauges plus the
-    tenant-prefixed duplicates, and a clean learner fires zero
-    degradation events."""
-    from ape_x_dqn_tpu.obs.report import summarize
+@pytest.mark.parametrize("game", ["catch", "pong"])
+def test_single_process_publishes_learn_gauges(tmp_path, game):
+    """Tier-1 acceptance (ISSUE 10), one env family = one tenant: a
+    short synthetic-Atari run with obs ON publishes finite,
+    in-healthy-range learn_* gauges plus the tenant-prefixed
+    duplicates, a clean learner fires zero degradation events, and the
+    report's --check rows find nothing to flag."""
+    from ape_x_dqn_tpu.obs.report import check_violations, summarize
     from ape_x_dqn_tpu.runtime.single_process import train_single_process
 
     jsonl = str(tmp_path / "run.jsonl")
     cfg = get_config("pong").replace(
-        env=EnvConfig(id="catch", kind="synthetic_atari"),
+        env=EnvConfig(id=game, kind="synthetic_atari"),
         network=NetworkConfig(kind="nature_cnn", dueling=True,
                               compute_dtype="float32"),
         replay=ReplayConfig(kind="prioritized", capacity=2048,
@@ -228,8 +230,8 @@ def test_single_process_catch_publishes_learn_gauges(tmp_path):
         assert v is not None, f"learn_{key} never published"
         assert np.isfinite(v), (key, v)
         # tenant duplicate under the env-family prefix
-        assert gauges.get(f"gauge/learn/catch/{key}") == v, key
-    # a healthy catch learner sits inside every monitor bound
+        assert gauges.get(f"gauge/learn/{game}/{key}") == v, key
+    # a healthy learner sits inside every monitor bound
     assert abs(gauges["gauge/learn_q_max"]) < 1e3
     assert gauges["gauge/learn_is_ess_frac"] > 0.05
     assert gauges["gauge/learn_update_ratio"] > 1e-9
@@ -237,10 +239,11 @@ def test_single_process_catch_publishes_learn_gauges(tmp_path):
     assert not any("learning_degradation" in r for r in recs)
     # the report regroups the tenant keys and collects no events
     summary = summarize(recs)
-    assert "catch" in summary["tenants"]
-    assert summary["tenants"]["catch"]["q_mean"] == \
+    assert game in summary["tenants"]
+    assert summary["tenants"][game]["q_mean"] == \
         gauges["gauge/learn_q_mean"]
     assert summary["learn_events"] == []
+    assert check_violations(summary) == []
 
 
 # -- the anomaly engine ---------------------------------------------------

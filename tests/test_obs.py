@@ -306,7 +306,7 @@ def test_report_summarize_and_format():
 
 
 def test_report_multichip_section():
-    """The dp-scaling records the bench lane writes (`multichip/dpN/*`
+    """The dp-scaling records a dp sweep writes (`multichip/dpN/*`
     keys + the top-level virtual_devices flag) regroup into a per-dp
     curve and render as the multichip table, with the below-healthy
     efficiency warn and the virtual-device framing."""

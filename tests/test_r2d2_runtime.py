@@ -248,7 +248,7 @@ def test_r2d2_driver_end_to_end_frame_sequences_dist():
     driver = ApexDriver(cfg)
     assert driver.family == "r2d2" and driver.is_dist
     assert not driver._frame_mode  # segment staging is flat-family-only
-    assert "seq_frames" in driver._item_keys
+    assert "seq_frames" in driver._item_spec
     out = driver.run(total_env_frames=1600, max_grad_steps=10,
                      wall_clock_limit_s=300)
     assert out["actor_errors"] == [], out["actor_errors"]

@@ -225,6 +225,74 @@ def test_shm_end_to_end_accounting_closes():
         srv.stop()
 
 
+def test_shm_contended_producers_accounting_closes():
+    """The topology the shm plane exists for: several same-host
+    producers posting concurrently into one ingest queue through their
+    own rings (3 producers x 2 messages x 16 rounds on 8 slots each).
+    The books close across all of them: every send is a post, a
+    counted fallback or a counted client drop; every post rang one
+    doorbell; everything offered was delivered or counted dropped; no
+    torn slot was delivered; no slot stays leased."""
+    import threading
+
+    producers, rounds = 3, 16
+    msgs = [_batch(i) for i in range(2)]
+    slot_bytes = len(encode_batch(msgs[0], "raw")) + 4096
+    srv = SocketIngestServer("127.0.0.1", 0, shm=True, shm_slots=8,
+                             shm_slot_bytes=slot_bytes)
+    trs = [SocketTransport("127.0.0.1", srv.port, shm=True, shm_slots=8,
+                           shm_slot_bytes=slot_bytes)
+           for _ in range(producers)]
+    offered = producers * rounds * len(msgs)
+    sent = threading.Event()
+    got = {"msgs": 0, "shm": 0}
+
+    def consume():
+        while True:
+            m = srv.recv_experience(timeout=0.25)
+            if m is None:
+                if sent.is_set():
+                    return
+                continue
+            got["msgs"] += 1
+            got["shm"] += isinstance(m, ShmSlotBatch)
+            _release(m)
+
+    def produce(tr):
+        for _ in range(rounds):
+            for batch in msgs:
+                tr.send_experience(batch)
+
+    consumer = threading.Thread(target=consume, daemon=True)
+    workers = [threading.Thread(target=produce, args=(tr,), daemon=True)
+               for tr in trs]
+    try:
+        consumer.start()
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=60)
+            assert not w.is_alive()
+        sent.set()
+        consumer.join(timeout=60)
+        assert not consumer.is_alive()
+        assert all(tr.shm_negotiated for tr in trs)
+        posts = sum(tr.shm_posts for tr in trs)
+        falls = sum(tr.shm_fallbacks for tr in trs)
+        client_dropped = sum(tr.dropped for tr in trs)
+        assert posts + falls + client_dropped == offered
+        assert srv.shm_doorbells == posts
+        assert (got["msgs"] + srv.dropped + client_dropped
+                + srv.shm_torn_slots == offered)
+        assert got["shm"] >= 1
+        assert srv.shm_torn_slots == 0
+        assert srv.shm_slots_inflight == 0
+    finally:
+        for tr in trs:
+            tr.close()
+        srv.stop()
+
+
 def test_shm_interop_matrix():
     """old-client/new-server, new-client/old-server, cross-host: every
     cell degrades to plain TCP with identical delivered bytes."""

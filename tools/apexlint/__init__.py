@@ -85,16 +85,15 @@ def run(package_dir: str,
         report_path: str | None = None,
         readme_path: str | None = None) -> dict:
     """Run all checkers over a package tree; returns the JSON-shaped
-    summary the CLI, tests, and bench.py all consume.
+    summary the CLI and tests/test_apexlint.py consume.
 
     per_checker maps each checker to {"findings": n, "waivers": n,
     "ms": wall-clock} so waiver creep AND a checker gone slow are both
-    attributable per rule in the bench artifact trail
-    (`secondary.apexlint`); top-level `findings`/`waivers` stay the
-    aggregate view. `closures` lists the counter-closure declarations
-    the static pass verified — the debug-mode runtime hook
-    (counter_closure.check_object) asserts the same laws on live
-    objects in bench lanes.
+    attributable per rule in that summary; top-level
+    `findings`/`waivers` stay the aggregate view. `closures` lists the
+    counter-closure declarations the static pass verified — the
+    debug-mode runtime hook (counter_closure.check_object) asserts the
+    same laws on live objects.
     """
     paths = package_files(package_dir)
     total = CheckResult()

@@ -1,7 +1,7 @@
 """Shared plumbing for the apexlint checkers.
 
 Everything here is stdlib-only on purpose: the lint gate must run in
-any environment that can run the tests (and in bench.py's subprocess),
+any environment that can run the tests,
 with no dependency on jax/numpy being importable — the checkers parse
 source, they never import the code under analysis.
 
@@ -13,8 +13,8 @@ suppresses it with a justification:
     # apexlint: unhandled(MSG_LEGACY)          (wire-protocol checker)
     obs.gauge("scratch", v)  # apexlint: unlisted(debug-only gauge)
 
-Waivers are counted and reported so creep is visible in the bench
-trajectory (`secondary.apexlint.waivers`).
+Waivers are counted and reported so creep is visible in the summary
+the CLI prints and tests/test_apexlint.py reads (`waivers`).
 """
 
 from __future__ import annotations

@@ -30,8 +30,8 @@ declaration.
 The same declarations feed a debug-mode runtime hook: `declarations()`
 returns them machine-readable, and `check_object(obj, decl)` evaluates
 the law on a live object (ints, per-shard numpy arrays, and
-reason->count dict terms all compare), so bench lanes can assert
-dynamically what CI proved statically.
+reason->count dict terms all compare), so a test can assert
+dynamically what the static pass proved.
 """
 
 from __future__ import annotations

@@ -37,7 +37,7 @@ sync covering the batch) sanitizes the rest of the function.
 Waive with `# apexlint: host-sync(<why>)` on the call line, or on the
 `def` line to waive a whole documented-off-hot-loop function (each
 suppressed site still counts toward the waiver total, so creep stays
-visible in `secondary.apexlint`).
+visible in the summary's `waivers`).
 """
 
 from __future__ import annotations
@@ -54,7 +54,7 @@ HOT_BASENAMES = {"learner.py", "dist_learner.py", "dpg_learner.py",
                  "ingest.py"}
 DRIVER_HOT_FUNCS = {"_learner_loop", "_learner_loop_inner",
                     "_publish_params", "_ship_staged",
-                    "_ship_staged_cold", "_add_block"}
+                    "_ship_staged_cold"}
 SCOPE_MARK = "apexlint-scope: hot-path"
 
 WINDOW_WITH_ATTRS = {"span", "stage_window"}

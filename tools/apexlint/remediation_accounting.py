@@ -14,7 +14,8 @@ raises is counted on the failure path), but it must be in the same
 function scope — accounting a restart from a different module is how
 actions go missing from the run JSONL when the call site is
 refactored. Waivers are counted so accounting-by-reference creep
-stays visible in the bench trajectory.
+stays visible in the summary (the CLI and tests/test_apexlint.py
+read it).
 
 Scope: modules under `/runtime/` — the engine itself, the driver's
 actuator wrappers, and the actor host's watchdogs.

@@ -29,7 +29,8 @@ must do at least one of:
           pass
 
 The waiver text is the justification; waivers are counted so silent-
-loss creep stays visible in the bench trajectory. Handlers that
+loss creep stays visible in the summary (the CLI and
+tests/test_apexlint.py read it). Handlers that
 re-raise (even conditionally) are exempt — they don't swallow.
 """
 

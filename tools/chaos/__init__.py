@@ -26,8 +26,7 @@ not runtime code):
   standalone proxy for manual soaks.
 
 tests/test_chaos.py drives all of it as the chaos soak (fast variants
-tier-1, full soak slow-marked); bench.py --chaos-ab measures clean vs
-fault-injected throughput through the same proxy.
+tier-1, full soak slow-marked).
 """
 
 from tools.chaos.faults import (CORRUPTION_MODES, corrupt_frame, garble,

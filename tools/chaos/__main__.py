@@ -9,7 +9,7 @@ prints the fault stats and exits.
 Reproducible drills: the startup line prints the RNG seed, and
 `--scenario <name>` runs a named preset built from the set_fault/cut
 primitives — each phase transition is printed, so any drill can be
-re-run exactly from a log or bench artifact (same seed, same
+re-run exactly from a log (same seed, same
 scenario, same phase schedule). A scenario takes over fault control:
 its clean phases reset ALL rates, including ones given on the
 command line.
