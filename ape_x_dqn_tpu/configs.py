@@ -298,10 +298,86 @@ class OuroConfig:
                 f"stack runs at least once")
 
 
+_PUBLISHED_KIMI_FULL_ATTN_LAYERS = (4, 8, 12, 16, 20, 24, 27)
+
+
+@dataclass(frozen=True)
+class KimiLinearConfig:
+    """The decoder of network.kind="kimi_linear_q"
+    (models/kimi_linear_q.py), under the key names of the model's own
+    config.json (moonshotai/Kimi-Linear-48B-A3B-Instruct, `model_type`
+    kimi_linear; its nested `linear_attn_config` flat here as
+    `linear_*` / `full_attn_layers`); defaults are that model's. Layers
+    are numbered from 1 as in that file: layer l is multi-head latent
+    attention WITHOUT any rotation (`mla_use_nope`) if l is in
+    `full_attn_layers`, else Kimi Delta Attention (a gated delta rule
+    with a decay per key channel behind a short causal convolution);
+    the first `first_k_dense_replace` layers' FFN is one dense SwiGLU,
+    every other layer's `num_experts` routed experts (sigmoid scores,
+    top-`num_experts_per_token` of score + a fixed selection bias,
+    weights normalised if `moe_renormalize` and times
+    `routed_scaling_factor`) beside `num_shared_experts` shared ones;
+    untied embedding and head. The query has no low rank (`q_lora_rank`
+    null in the model's file: no field)."""
+
+    hidden_size: int = 2304
+    intermediate_size: int = 9216       # the dense layers' SwiGLU
+    moe_intermediate_size: int = 1024   # each routed / shared expert
+    num_hidden_layers: int = 27
+    first_k_dense_replace: int = 1
+    # the MLA layers, numbered from 1; every other layer is KDA. A run
+    # that holds fewer layers holds layers 1 .. num_hidden_layers
+    full_attn_layers: tuple[int, ...] = _PUBLISHED_KIMI_FULL_ATTN_LAYERS
+    # KDA (linear_attn_config): heads, the key and value size of each,
+    # the causal convolution's kernel
+    linear_num_heads: int = 32
+    linear_head_dim: int = 128
+    linear_short_conv_kernel_size: int = 4
+    # MLA
+    num_attention_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64      # plain dims: nothing is rotated
+    v_head_dim: int = 128
+    mla_use_nope: bool = True       # only True is built
+    num_experts: int = 256
+    num_shared_experts: int = 1
+    num_experts_per_token: int = 8
+    num_expert_group: int = 1           # only 1 is built: no group stage
+    topk_group: int = 1
+    moe_renormalize: bool = True
+    routed_scaling_factor: float = 2.446
+    vocab_size: int = 163_840
+    rms_norm_eps: float = 1e-5
+    # the four below are AfmoeConfig's, with the same meaning: this
+    # chip's share of a deployment in which `shard_count` chips share
+    # each layer's experts and `vocab_shard_count` (0: as shard_count)
+    # the embedding's and the head's rows, and the selection forced
+    # balanced for measuring with random weights
+    shard_count: int = 1
+    shard_index: int = 0
+    vocab_shard_count: int = 0
+    force_balanced_routing: bool = False
+
+    def __post_init__(self) -> None:
+        if not 0 <= self.shard_index < self.shard_count:
+            raise ValueError(
+                f"network.kimi_linear.shard_index must be in [0, "
+                f"{self.shard_count}) (got {self.shard_index})")
+        vocab_shards = self.vocab_shard_count or self.shard_count
+        if (self.num_experts % self.shard_count
+                or self.vocab_size % vocab_shards):
+            raise ValueError(
+                f"network.kimi_linear.shard_count={self.shard_count} must "
+                f"divide num_experts={self.num_experts} and "
+                f"vocab_shard_count={vocab_shards} must divide "
+                f"vocab_size={self.vocab_size}")
+
+
 @dataclass(frozen=True)
 class NetworkConfig:
     # mlp | nature_cnn | lstm_q | dpg | glm_moe_q | afmoe_q | smallthinker_q
-    # | ouro_q
+    # | ouro_q | kimi_linear_q
     kind: str = "mlp"
     mlp_hidden: tuple[int, ...] = (256, 256)
     cnn_channels: tuple[int, ...] = (32, 64, 64)
@@ -323,6 +399,9 @@ class NetworkConfig:
         default_factory=SmallThinkerConfig)
     # the decoder of kind="ouro_q" (the same family; no expert layer)
     ouro: OuroConfig = field(default_factory=OuroConfig)
+    # the decoder of kind="kimi_linear_q" (the same family: KDA scan
+    # layers beside latent attention, with experts)
+    kimi_linear: KimiLinearConfig = field(default_factory=KimiLinearConfig)
 
 
 @dataclass(frozen=True)
@@ -1321,6 +1400,82 @@ def _preset_ouro_tiny_q() -> RunConfig:
     )
 
 
+def _preset_kimi_linear_48b_q() -> RunConfig:
+    """Config 10: Kimi-Linear-48B-A3B-Instruct (Moonshot) as a
+    token-level Q-network, the decoder family's fifth net and its first
+    with a scan layer that is not an LSTM. The sizes are the model's
+    config.json
+    (https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct):
+    27 layers, three of Kimi Delta Attention (32 heads of 128, a
+    128 x 128 float32 state a head) to one of latent attention without
+    rotation, a leading dense layer, then 256 routed experts of 1,024,
+    top-8, one shared, 163,840 vocabulary rows. Whole it is 48 B
+    parameters and check_hbm_fits refuses it: a run gives one chip its
+    share with network.kimi_linear.shard_count / vocab_shard_count /
+    num_hidden_layers and env.num_tokens
+    (benchmarks/configs/kimi_linear_48b_ep32_1chip.json is the measured
+    one). The learner settings are this repo's: sequences of 4,096
+    tokens, long episodes replayed whole - the job the linear layers
+    were built for - at the longest length whose step fits one chip
+    beside 602 M parameters' learner state (8,192 does not: the
+    configuration file's `memory`)."""
+    kl = KimiLinearConfig()
+    return RunConfig(
+        name="kimi_linear_48b_q",
+        total_env_frames=10_000_000_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=kl.vocab_size),
+        network=NetworkConfig(kind="kimi_linear_q", dueling=False,
+                              kimi_linear=kl),
+        # a stored sequence is 4,096 tokens: 1,024 of burn-in, which
+        # leaves a KDA layer one state matrix a head and three rows of
+        # its convolutions however long it is, and an MLA layer a latent
+        # row per position; the trained 3,072 start from both without
+        # gradient. 8,192 sequences are the other decoders' token
+        # count, 0.63 GiB
+        replay=ReplayConfig(kind="sequence", capacity=8_192,
+                            seq_length=4_096, seq_overlap=2_048,
+                            burn_in=1_024, min_fill=64),
+        # batch 1: one sequence is the 4,096 tokens a step
+        learner=LearnerConfig(batch_size=1, n_step=5, value_rescale=True,
+                              target_sync_every=2500, lr=1e-4,
+                              sample_chunk=1, train_chunk=2),
+        # a query re-runs a window of up to 4,096 tokens (the family's
+        # stateless protocol): one at a time
+        actors=ActorConfig(num_actors=64, envs_per_actor=1),
+        inference=InferenceConfig(max_batch=1, deadline_ms=2.0),
+    )
+
+
+def _preset_kimi_linear_tiny_q() -> RunConfig:
+    """kimi_linear_48b_q's sibling for CPU tests: every kind of layer
+    (KDA + dense FFN, KDA + experts, MLA + experts, KDA + experts) at
+    hidden 48, 3 KDA heads of 8 (so no KDA width is the hidden size over
+    the heads), 2 MLA heads of 12 + 4 over values of 8, 8 experts top-2,
+    a vocabulary of 64, sequences of 32, float32."""
+    kl = KimiLinearConfig(
+        hidden_size=48, intermediate_size=96, moe_intermediate_size=24,
+        num_hidden_layers=4, full_attn_layers=(3,), linear_num_heads=3,
+        linear_head_dim=8, num_attention_heads=2, kv_lora_rank=16,
+        qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=8,
+        num_experts=8, num_experts_per_token=2, vocab_size=64)
+    return RunConfig(
+        name="kimi_linear_tiny_q",
+        total_env_frames=100_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=kl.vocab_size),
+        network=NetworkConfig(kind="kimi_linear_q", dueling=False,
+                              kimi_linear=kl, compute_dtype="float32"),
+        replay=ReplayConfig(kind="sequence", capacity=64, seq_length=32,
+                            seq_overlap=16, burn_in=12, min_fill=8),
+        learner=LearnerConfig(batch_size=4, n_step=3, value_rescale=True,
+                              target_sync_every=100, lr=1e-3,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=1, envs_per_actor=2),
+        inference=InferenceConfig(max_batch=8, deadline_ms=2.0),
+    )
+
+
 PRESETS = {
     "cartpole_smoke": _preset_cartpole_smoke,
     "pong": _preset_pong,
@@ -1335,6 +1490,8 @@ PRESETS = {
     "smallthinker_tiny_q": _preset_smallthinker_tiny_q,
     "ouro_2p6b_q": _preset_ouro_2p6b_q,
     "ouro_tiny_q": _preset_ouro_tiny_q,
+    "kimi_linear_48b_q": _preset_kimi_linear_48b_q,
+    "kimi_linear_tiny_q": _preset_kimi_linear_tiny_q,
 }
 
 

@@ -1,4 +1,7 @@
-"""Network factory + model helpers."""
+"""Network factory + model helpers. The decoder_q family's five nets
+(`DECODER_NETS`) share models/expert_layer.py (four of them),
+models/windowed_gqa.py (three), models/mla.py (glm_moe_q,
+kimi_linear_q) and, the fifth alone so far, ops/chunked_delta_rule.py."""
 
 from ape_x_dqn_tpu.models.base import (
     hard_update, init_params, param_count, preprocess_obs, soft_update)
@@ -9,12 +12,15 @@ from ape_x_dqn_tpu.models.glm_moe_q import GlmMoeQNet
 from ape_x_dqn_tpu.models.afmoe_q import AfmoeQNet
 from ape_x_dqn_tpu.models.smallthinker_q import SmallThinkerQNet
 from ape_x_dqn_tpu.models.ouro_q import OuroQNet
+from ape_x_dqn_tpu.models.kimi_linear_q import KimiLinearQNet
 
-# network.kind -> the net's class: the token-level Q-networks of the
-# decoder_q family. A further decoder is a row here and in
-# `decoder_block`, a config block, and a row in runtime/family.family_of
+# network.kind -> the net's class: the five token-level Q-networks of the
+# decoder_q family (GLM-4.7-Flash, Trinity-Mini, SmallThinker, Ouro,
+# Kimi-Linear). A further decoder is a row here and in `decoder_block`, a
+# config block, and a row in runtime/family.family_of
 DECODER_NETS = {"glm_moe_q": GlmMoeQNet, "afmoe_q": AfmoeQNet,
-                "smallthinker_q": SmallThinkerQNet, "ouro_q": OuroQNet}
+                "smallthinker_q": SmallThinkerQNet, "ouro_q": OuroQNet,
+                "kimi_linear_q": KimiLinearQNet}
 
 
 def decoder_block(net_cfg):
@@ -24,6 +30,7 @@ def decoder_block(net_cfg):
             "afmoe_q": ("afmoe", net_cfg.afmoe),
             "smallthinker_q": ("smallthinker", net_cfg.smallthinker),
             "ouro_q": ("ouro", net_cfg.ouro),
+            "kimi_linear_q": ("kimi_linear", net_cfg.kimi_linear),
             }[net_cfg.kind]
 
 

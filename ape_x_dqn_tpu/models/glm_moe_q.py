@@ -19,7 +19,9 @@ float32, independently):
 - RMSNorm(x) = x / sqrt(mean(x^2) + eps) * g, statistics in float32.
 - Block: h = x + MLA(RMSNorm(x)); y = h + FFN(RMSNorm(h)). After the
   last block RMSNorm, then the head.
-- MLA: c_q = RMSNorm(x W_qa); q = c_q W_qb -> heads x [q_nope | q_rope].
+- MLA (models/mla.py, shared with models/kimi_linear_q.py; here with
+  the low-rank query, RoPE and the scores materialised):
+  c_q = RMSNorm(x W_qa); q = c_q W_qb -> heads x [q_nope | q_rope].
   x W_kva -> [c_kv | k_r]; c_kv = RMSNorm(c_kv); c_kv W_kvb -> heads x
   [k_nope | v]. RoPE (theta, every rope dim, no scaling, HALF-SPLIT
   pairing: dim i rotates with dim i + d/2, HF's `rotate_half`) on
@@ -77,7 +79,8 @@ from ape_x_dqn_tpu.models.base import dtype_of
 # they had here (a benchmark test reaches `glm_moe_q._combine`)
 from ape_x_dqn_tpu.models.expert_layer import (  # noqa: F401
     SELECTION, ExpertShare, _balanced_scores, _combine, _dispatch, _rms_norm,
-    _rope, _swiglu, count_params, expert_ffn, seeded_params)
+    _swiglu, count_params, expert_ffn, seeded_params)
+from ape_x_dqn_tpu.models.mla import MlaSizes, mla
 
 STEP_REST = 1 << 30      # a step beside gradients and logits (see below)
 
@@ -102,6 +105,11 @@ class GlmMoeQNet:
         self.experts_held = g.n_routed_experts // g.shard_count
         self.first_expert = g.shard_index * self.experts_held
         self.q_head_dim = g.qk_nope_head_dim + g.qk_rope_head_dim
+        self.mla_sizes = MlaSizes(
+            heads=g.num_attention_heads, nope=g.qk_nope_head_dim,
+            rope=g.qk_rope_head_dim, v_dim=g.v_head_dim,
+            kv_rank=g.kv_lora_rank, eps=g.rms_norm_eps,
+            rope_theta=g.rope_theta)
         self.num_dense_layers = min(g.first_k_dense_replace,
                                     g.num_hidden_layers)
         self.num_moe_layers = g.num_hidden_layers - self.num_dense_layers
@@ -185,45 +193,9 @@ class GlmMoeQNet:
     # -- the layers --------------------------------------------------------
 
     def _mla(self, p: dict, x: jax.Array, cache, dt):
-        g = self.g
-        b, t, _ = x.shape
-        heads, nope, rope = (g.num_attention_heads, g.qk_nope_head_dim,
-                             g.qk_rope_head_dim)
-        seen = 0 if cache is None else cache[0].shape[1]
-        positions = seen + jnp.arange(t)
-        c_q = _rms_norm(x @ p["q_a_proj"].astype(dt), p["q_a_layernorm"],
-                        g.rms_norm_eps)
-        q = (c_q @ p["q_b_proj"].astype(dt)).reshape(
-            b, t, heads, self.q_head_dim)
-        q = jnp.concatenate(
-            [q[..., :nope], _rope(q[..., nope:], positions, g.rope_theta)],
-            axis=-1)
-        kv_a = x @ p["kv_a_proj_with_mqa"].astype(dt)
-        c_kv = _rms_norm(kv_a[..., :g.kv_lora_rank], p["kv_a_layernorm"],
-                         g.rms_norm_eps)
-        k_rope = _rope(kv_a[..., g.kv_lora_rank:], positions, g.rope_theta)
-        if cache is not None:
-            c_kv = jnp.concatenate([cache[0].astype(dt), c_kv], axis=1)
-            k_rope = jnp.concatenate([cache[1].astype(dt), k_rope], axis=1)
-        s = seen + t
-        kv = (c_kv @ p["kv_b_proj"].astype(dt)).reshape(
-            b, s, heads, nope + g.v_head_dim)
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_rope[:, :, None, :], (b, s, heads, rope))],
-            axis=-1)
-        v = kv[..., nope:]
-        with jax.named_scope("glm.mla.scores"):
-            scores = jnp.einsum("bthd,bshd->bhts", q, k,
-                                preferred_element_type=jnp.float32)
-            scores = scores * (self.q_head_dim ** -0.5)
-            causal = (jnp.arange(s)[None, :]
-                      <= positions[:, None])               # [T, S]
-            scores = jnp.where(causal[None, None], scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-            out = jnp.einsum("bhts,bshd->bthd", probs, v)
-        out = out.reshape(b, t, heads * g.v_head_dim) @ p["o_proj"].astype(dt)
-        return out, (c_kv, k_rope)
+        """models/mla.py at this net's sizes: a low-rank query, RoPE,
+        the scores materialised (512 positions a sequence)."""
+        return mla(p, x, cache, dt, self.mla_sizes)
 
     def _moe(self, p: dict, x: jax.Array, dt, balanced=None):
         """The shared expert layer (models/expert_layer.py) at this

@@ -4,8 +4,11 @@ queries x 8,192 keys of float32 are 6.4 GB a layer and sequence).
 
     blockwise_attention(q, k, v, cache, window=...) -> out [B, T, Hq, d]
 
-`q` [B, T, Hq, d], `k`/`v` [B, T, Hkv, d] are the new positions; `cache`
-= (k_c, v_c) [B, C, Hkv, d] the C positions before them, or None. Query
+`q` [B, T, Hq, d], `k` [B, T, Hkv, d], `v` [B, T, Hkv, dv] are the new
+positions (dv need not be d: multi-head latent attention's keys carry
+64 shared dims its values do not, models/mla.py; the output is [B, T,
+Hq, dv] then); `cache` = (k_c, v_c) the C positions before them, or
+None. Query
 t sits at key index C + t and sees key s iff s <= C + t and, with a
 `window`, (C + t) - s < window (the query itself counts, so `window`
 keys). Grouped queries: head j reads key-value head j // (Hq / Hkv).
@@ -181,9 +184,10 @@ def _scores(geo: _Geometry, qi, kj, i, j):
 
 
 def _forward(geo: _Geometry, q, k, v):
-    """q [B, KV, G, T, d]; k, v [B, KV, S, d] -> (out [B, KV, G, T, d]
-    and log-sum-exp [B, KV, G, T], both float32)."""
-    b, kv, g, t, d = q.shape
+    """q [B, KV, G, T, d]; k [B, KV, S, d], v [B, KV, S, dv] -> (out
+    [B, KV, G, T, dv] and log-sum-exp [B, KV, G, T], both float32)."""
+    b, kv, g, t, _ = q.shape
+    d = v.shape[-1]
     bq, bk = geo.block_q, geo.block_k
 
     def per_query_block(i, carry):
@@ -213,7 +217,7 @@ def _forward(geo: _Geometry, q, k, v):
         return out, lse
 
     return jax.lax.fori_loop(0, t // bq, per_query_block, (
-        jnp.zeros(q.shape, jnp.float32),
+        jnp.zeros((b, kv, g, t, d), jnp.float32),
         jnp.zeros((b, kv, g, t), jnp.float32)))
 
 
@@ -400,8 +404,13 @@ def _bwd_two_nests(geo, res, d_out):
             return dk_j + dk_ij, dv_j + dv_ij
 
         tile = jnp.zeros((b, kv, bk, d), jnp.float32)
+        # one zero tile for both where the sizes agree: two would be a
+        # second broadcast op in the lowered text, which tier-1 pins for
+        # the nets whose values have the keys' size
+        v_tile = (tile if v.shape[-1] == d
+                  else jnp.zeros((b, kv, bk, v.shape[-1]), jnp.float32))
         dk_j, dv_j = jax.lax.fori_loop(*_admitting(geo, j, t // bq),
-                                       per_query_block, (tile, tile))
+                                       per_query_block, (tile, v_tile))
         new = (j * bk + jnp.arange(bk) >= geo.first)[:, None]
         return (jax.lax.dynamic_update_slice_in_dim(
                     dk, jnp.where(new, dk_j, 0.0).astype(k.dtype), j * bk, 2),
@@ -502,8 +511,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     for it). `recompute_delta`: the backward pass walks a row's key
     blocks twice, first for its delta (the other way to serve such a
     net: the rows of ds then sum to zero whatever they share)."""
-    b, t, heads, d = q.shape
-    kv = k.shape[2]
+    b, t, heads, _ = q.shape
+    kv, d = k.shape[2], v.shape[-1]
     if cache is not None:
         k = jnp.concatenate(
             [jax.lax.stop_gradient(cache[0]).astype(k.dtype), k], axis=1)
@@ -521,7 +530,7 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     recompute_delta=recompute_delta)
     front = ((0, 0), (pad, 0), (0, 0), (0, 0))
     k, v = (jnp.pad(a, front).transpose(0, 2, 1, 3) for a in (k, v))
-    q = q.reshape(b, t, kv, heads // kv, d).transpose(0, 2, 3, 1, 4)
+    q = q.reshape(b, t, kv, heads // kv, -1).transpose(0, 2, 3, 1, 4)
     out = _attend(geo, q, k, v)             # [B, KV, G, T, d] float32
     if v_mean is not None:
         out = out + v_mean[:, :, None, None]

@@ -25,20 +25,28 @@ and what differs is the state a sequence starts from:
   pass leaves the net's cache (glm_moe_q: per layer a latent c_kv and
   k_rope; afmoe_q and smallthinker_q: per layer (k, v), every position
   for a full layer and the last window - 1 for a sliding one; ouro_q:
-  per (loop step, layer) (k, v)), the loss stops its gradient, the
-  trained steps attend to it. The item has no
+  per (loop step, layer) (k, v); kimi_linear_q: a KDA layer's state
+  matrix and convolution tails, THE SAME SIZE HOWEVER LONG THE PREFIX,
+  beside an MLA layer's latent row per position), the loss stops its
+  gradient, the trained steps start from it. The item has no
   state entry at all. The server is stateless too: a query carries the
   last <= L token ids ({obs, ctx, n} -> {q, ctx, n}) and the server
   re-runs the window; a per-slot cache inside
   parallel/inference_server.py, which would make a step cost one token
   instead of a window, is what is missing.
 
-The decoder_q family has four nets. Three (network.kind "glm_moe_q",
-"afmoe_q", "smallthinker_q") share models/expert_layer.py (the plan and
-the application of an expert layer); the last two of them and the
-fourth, "ouro_q" - a stack of dense blocks run several times with the
-same weights, no expert layer - share models/windowed_gqa.py (the
-attention call and its cache).
+The decoder_q family has five nets. Four (network.kind "glm_moe_q",
+"afmoe_q", "smallthinker_q", "kimi_linear_q") share
+models/expert_layer.py (the plan and the application of an expert
+layer); the second and third of them and "ouro_q" - a stack of dense
+blocks run several times with the same weights, no expert layer - share
+models/windowed_gqa.py (the attention call and its cache); the first
+and the fifth share models/mla.py (latent attention; the fifth's scores
+go through ops/blockwise_attention.py as the windowed nets' do). The
+fifth, "kimi_linear_q", is the family's first with a scan layer
+(ops/chunked_delta_rule.py): a net that has one also reports
+`kda_chunks` and `kda_state_rms` in its stats, and the family's loss
+hands them on (`_routed_loss`).
 A further decoder registers with: its net in models/ with the surface
 the family reads (`init`, `apply`, `apply_with_stats`, `param_count`,
 `step_transient_bytes`, `num_actions`; a net WITH an expert layer also
@@ -82,7 +90,8 @@ from ape_x_dqn_tpu.utils.rng import component_key
 def family_of(cfg: RunConfig) -> str:
     return {"lstm_q": "r2d2", "dpg": "dpg", "glm_moe_q": "decoder_q",
             "afmoe_q": "decoder_q", "smallthinker_q": "decoder_q",
-            "ouro_q": "decoder_q"}.get(cfg.network.kind, "dqn")
+            "ouro_q": "decoder_q",
+            "kimi_linear_q": "decoder_q"}.get(cfg.network.kind, "dqn")
 
 
 # families whose replay items are whole sequences (the staging unit is
@@ -139,6 +148,13 @@ def hbm_price(cfg: RunConfig, net: Any) -> dict:
         price["step_transient"] = net.step_transient_bytes(
             cfg.learner.batch_size,
             cfg.replay.seq_length - cfg.replay.burn_in)
+    if hasattr(net, "sequence_state_bytes"):
+        # what the burn-in leaves, online and target net: for a net
+        # whose state does not grow with the prefix (a scan layer's)
+        # the net says so itself; the attention caches of the older
+        # nets sit inside their `step_transient_bytes` anchors
+        price["step_transient"] += 2 * net.sequence_state_bytes(
+            cfg.learner.batch_size, cfg.replay.burn_in)
     return price
 
 
@@ -259,17 +275,28 @@ def r2d2_family(net_apply_seq: Callable, lcfg, rcfg, compute_dtype=None):
         metric_keys=("valid_frac",))
 
 
+def has_scan_layer(net: Any) -> bool:
+    """Whether `net` has a chunked-scan layer and reports its two
+    counters (`kda_chunks`, `kda_state_rms` in its stats:
+    models/kimi_linear_q.py)."""
+    return getattr(net, "num_kda_layers", 0) > 0
+
+
 def _routed_loss(net: Any, r2d2: Callable) -> Callable:
     """decoder_q_family's loss for a net WITH an expert layer (its
     docstring says what the counters are). `r2d2(apply)` -> the R2D2
     sequence loss over `apply`."""
     from ape_x_dqn_tpu.models.expert_layer import capacity, fits
 
+    scan_layer = has_scan_layer(net)
+
     def loss_fn(params, target_params, batch, is_weights):
-        tally, fitted, seen = [], [], []
+        tally, fitted, seen, scans = [], [], [], []
 
         def apply(p, tokens, state):
             q, state, stats = net.apply_with_stats(p, tokens, state)
+            if scan_layer:
+                scans.append((stats["kda_chunks"], stats["kda_state_rms"]))
             tally.append(stats["expert_rows"].astype(jnp.float32))
             fitted.append(fits(stats["expert_rows"],
                                capacity(net.share, tokens.size)))
@@ -297,6 +324,11 @@ def _routed_loss(net: Any, r2d2: Callable) -> Callable:
                    [t for _, t in seen[0::2]], axis=2),
                "topk_target": jnp.concatenate(
                    [t for _, t in seen[1::2]], axis=2)}
+        if scan_layer:
+            # the online net's forward: prefix, then trained steps
+            aux["kda_chunks"] = sum(
+                n for n, _ in scans[0::2]).astype(jnp.float32)
+            aux["kda_state_rms_last"] = scans[-2][1]
         return loss, aux
 
     return loss_fn
@@ -361,7 +393,14 @@ def decoder_q_family(net: Any, lcfg, rcfg):
     [expert layers, B, L, k] the experts selected — for whoever
     differentiates this function to hold it to a reference (the
     benchmark's check); a train step reads none of the three and XLA
-    drops them there."""
+    drops them there. A routed net WITH A SCAN LAYER (its stats have
+    `kda_chunks`: models/kimi_linear_q.py) adds `kda_chunks`, the chunks
+    the delta rule's scan walked in the online net's forward pass
+    (prefix and trained steps, summed over the KDA layers in the scan's
+    own carry: KDA layers x positions / chunk), and
+    `kda_state_rms_last`, the RMS of the state matrices after the last
+    trained position (mean over the KDA layers): the one place a state
+    that blew up or died is seen."""
     from ape_x_dqn_tpu.ops.losses import SequenceBatch, make_r2d2_loss
     from ape_x_dqn_tpu.runtime.learner import LearnerFamily
 
@@ -384,6 +423,8 @@ def decoder_q_family(net: Any, lcfg, rcfg):
         apply_attr="net_apply_seq",
         metric_keys=(("valid_frac", "moe_rows", "moe_rows_grad",
                       "moe_load_max_over_mean", "moe_compact_share")
+                     + (("kda_chunks", "kda_state_rms_last")
+                        if has_scan_layer(net) else ())
                      if routed else
                      ("valid_frac", "loop_block_applications",
                       "loop_exit_mass_last")))

@@ -439,3 +439,37 @@ def test_the_rule_puts_the_cells_shapes_where_the_docstring_says(
         assert bool(under) == two_nests, (cell, scope)
         # every reader that sums under the caller's scope still sees them
         assert all("afmoe.attn.full" in s.split(scope)[0] for s in under)
+
+
+@pytest.mark.parametrize("recompute_delta", [False, True])
+@pytest.mark.parametrize("group,schedule", [(1, "two_nests"),
+                                            (4, "one_nest")])
+@pytest.mark.parametrize("t,cached", [(20, 0), (20, 12), (16, 5)])
+def test_a_value_head_size_other_than_the_keys(t, cached, group, schedule,
+                                               recompute_delta):
+    """Multi-head latent attention's shapes (models/mla.py): keys of 24,
+    values of 16; the output and dv take the values' size, dq and dk the
+    keys', forward and every gradient against a materialised softmax,
+    under both backward schedules (a group of one runs two nests)."""
+    rng = np.random.default_rng(t + cached + group)
+    new = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa
+    kv = 2
+    q, k, v = new(2, t, kv * group, 24), new(2, t, kv, 24), new(2, t, kv, 16)
+    cache = (new(2, cached, kv, 24), new(2, cached, kv, 16)) if cached else None
+    weight = new(2, t, kv * group, 16)
+    blockwise = lambda *a: blockwise_attention(            # noqa: E731
+        *a, block_q=4, block_k=4, recompute_delta=recompute_delta)
+    out = blockwise(q, k, v, cache)
+    assert out.shape == (2, t, kv * group, 16)
+    np.testing.assert_allclose(out, dense_attention(q, k, v, cache, None),
+                               atol=1e-5)
+    loss = lambda *a: (blockwise(*a) * weight).sum()       # noqa: E731
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        q, k, v, cache).as_text(debug_info=True)
+    assert ("attn.bwd.dkv" in text) == (schedule == "two_nests")
+    got = jax.jit(jax.grad(loss, (0, 1, 2)))(q, k, v, cache)
+    want = jax.grad(lambda *a: (dense_attention(*a, None) * weight).sum(),
+                    (0, 1, 2))(q, k, v, cache)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=1e-5)

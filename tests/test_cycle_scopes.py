@@ -22,7 +22,10 @@ grad step, and `ops/sum_tree.py`'s two nested in the first and the last.
     `ouro_tiny_q` alone, by design (a group of one: the blockwise
     attention's backward pass as a dq nest and a dk/dv nest); the
     groups of 2 and 7 of `trinity_tiny_q` and `smallthinker_tiny_q`
-    keep the one nest and their text;
+    keep the one nest and their text. ISSUE 46 added
+    `kimi_linear_tiny_q` and moved none of the eight: GLM's attention
+    is models/mla.py's now, and ops/blockwise_attention.py takes values
+    of another head size than the keys';
 (c) no endpoint bypasses a scope: `train_step`, `train_step_k`,
     `sample_k` + `learn_k` and the prefetching `train_many` open the
     same names, on one chip and on the mesh.
@@ -76,6 +79,13 @@ PROGRAMS = {
     # constant at 0 the text is PR 41's 12a333f806383e44 again)
     "ouro_tiny_q": ("ouro_tiny_q", ["replay.capacity=64"], 2,
                     "574b3f311835f9a8", "bfb0aedd29a1ffac"),
+    # the family's fifth net, the first with a scan layer (the chunked
+    # delta rule) and the second through models/mla.py, pinned at the PR
+    # that added it (ISSUE 46) beside the eight that must not move: GLM's
+    # through the moved MLA, `ouro_tiny_q`'s and the two one-nest nets'
+    # through the blockwise attention's value head size
+    "kimi_linear_tiny_q": ("kimi_linear_tiny_q", ["replay.capacity=64"], 2,
+                           "2e3871ec0de17355", "6e9793cd4ff11254"),
     "dist": ("pong", ["parallel.dp=2", "parallel.tp=1",
                       "replay.capacity=4096", "replay.min_fill=512"], 8,
              "8edfe2412a4bc64f", "6668f8be4d7f2de8"),
@@ -84,7 +94,7 @@ PROGRAMS = {
                  "836445fb85177e4e", "a376acdbc487b640"),
 }
 RELABELS = ("glm_tiny_q", "trinity_tiny_q", "smallthinker_tiny_q",
-            "ouro_tiny_q", "apex_dpg")
+            "ouro_tiny_q", "kimi_linear_tiny_q", "apex_dpg")
 QUIET = ["actors.num_actors=0", "eval_episodes=0", "eval_every_steps=0"]
 
 

@@ -130,7 +130,12 @@ DECODER_KEYS = {
     "glm_tiny_q": {"valid_frac", "moe_rows", "moe_rows_grad",
                    "moe_load_max_over_mean", "moe_compact_share"},
     "ouro_tiny_q": {"valid_frac", "loop_block_applications",
-                    "loop_exit_mass_last"}}
+                    "loop_exit_mass_last"},
+    # a routed net WITH A SCAN LAYER adds the scan's two counters
+    "kimi_linear_tiny_q": {"valid_frac", "moe_rows", "moe_rows_grad",
+                           "moe_load_max_over_mean", "moe_compact_share",
+                           "kda_chunks", "kda_state_rms_last"}}
+ROUTED = ("glm_tiny_q", "kimi_linear_tiny_q")
 
 
 @pytest.mark.parametrize("preset", list(DECODER_KEYS))
@@ -144,7 +149,7 @@ def test_decoder_q_family_reads_expert_statistics_only_from_a_net_with_them(
 
     cfg = get_config(preset)
     net = build_network(cfg.network, None)
-    assert hasattr(net, "share") == (preset == "glm_tiny_q")
+    assert hasattr(net, "share") == (preset in ROUTED)
     family = learner_family(cfg, net)
     assert family.name == "decoder_q"
     assert set(family.metric_keys) == DECODER_KEYS[preset]
@@ -162,7 +167,7 @@ def test_decoder_q_family_reads_expert_statistics_only_from_a_net_with_them(
         params, params, family.make_batch(
             {k: v[:n] for k, v in items.items()}), jnp.ones(n))
     assert set(family.metric_keys) <= set(aux) and "q" in aux
-    assert ("topk_online" in aux) == (preset == "glm_tiny_q")
+    assert ("topk_online" in aux) == (preset in ROUTED)
     replay = PrioritizedReplay(capacity=32)
     learner = build_learner(cfg, net, replay)
     assert type(learner) is SingleChipLearner
@@ -172,7 +177,9 @@ def test_decoder_q_family_reads_expert_statistics_only_from_a_net_with_them(
     state = learner.add(state, items, np.ones(N, np.float32))
     state, m = learner.train_many(state, 2)
     assert set(m) == STEP_KEYS | DECODER_KEYS[preset]
-    assert any(k.startswith("moe_") for k in m) == (preset == "glm_tiny_q")
+    assert any(k.startswith("moe_") for k in m) == (preset in ROUTED)
+    assert any(k.startswith("kda_") for k in m) == (
+        preset == "kimi_linear_tiny_q")
     assert np.isfinite(float(m["loss"]))
 
 
