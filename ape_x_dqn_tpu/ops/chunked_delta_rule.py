@@ -29,10 +29,14 @@ writes: S_i = Diag(alpha_i) S_{i-1} + k_i u_i^T), unrolling gives
 
 A is strictly lower triangular, so T = (I + A)^-1 exists and depends on
 nothing but the chunk's own k, beta and g: `kda.scan.intra` makes A, B,
-T, W = T (beta K e^G) and U_0 = T (beta V), `kda.scan.carry` is what is
-left and sequential, U = U_0 - W S_0, O and S_C: three small matmuls a
-chunk. A `lax.scan` over chunks carries S (float32) and the count of
-chunks walked (`with_chunks`: what the family's `kda_chunks` reads).
+T and, from ONE product with T, W = T (beta K e^G) beside U_0 = T (beta
+V). It also stacks there, where no state is needed, what the chunk's
+walk multiplies by: W over Q e^G ([2C, dk]: what the chunk READS of S_0)
+and B over (K e^{G_C - G})^T ([C + dk, C]: what it WRITES with U).
+`kda.scan.carry` is what is left and sequential, TWO products and four
+sums a chunk: U = U_0 - W S_0, O and S_C. A `lax.scan` over chunks
+carries S (float32) and the count of chunks walked (`with_chunks`: what
+the family's `kda_chunks` reads).
 
 THE DIFFERENCE IS FORMED BEFORE THE EXPONENTIAL. The factored form
 (q e^{G_i}) . (k e^{-G_j}) would put A and B on the MXU, and it
@@ -44,9 +48,15 @@ BEFORE the exponential, so no infinity meets a zero in the backward
 pass either), G_i, or G_C - G_i: all <= 0, so the worst is an underflow
 to 0 of a term that is 0 to float32 anyway. What it costs is a [C, C,
 dk] tile of elementwise work a chunk and head on the VPU where the
-factored form has a matmul; re-basing inside sub-chunks (both factors
-<= 1 across sub-chunks, the difference form on the diagonal ones) is
-the known cure and the next `perf_opt`'s (PERF.md section 7).
+factored form has a matmul. Re-basing inside sub-chunks (both factors
+<= 1 across sub-chunks, the difference form on the diagonal ones) was
+prototyped for ISSUE 47 (XLA's estimated cycles for one layer's forward
++ backward at [1, 3072, 32, 128], compiled for a described v5e; nothing
+of it run on a chip): at chunks of 32 it gives back in extra small ops
+what the smaller tile saves (64.8 M cycles in sub-chunks of 8 against
+64.3 M plain); it pays at chunks of 64 (54.0 M against plain 64's
+74.0 M), which waits for the configuration's `kda_chunk` to follow the
+program (PERF.md section 7).
 
 THE SOLVE IS BLOCK FORWARD SUBSTITUTION, NOT A POWER SERIES. (I + A)^-1
 = sum_n (-A)^n is finite (A is nilpotent) and is six matmuls by
@@ -57,8 +67,29 @@ inverse's entries are below 1: float32 keeps nothing of it. Diagonal
 blocks of `_BASE` = 8 are inverted by the doubled series (terms up to
 C(6, 3) = 20: harmless), then pairs of blocks are joined,
 [[P, 0], [R, Q]]^-1 = [[P^-1, 0], [-Q^-1 R P^-1, Q^-1]], twice to 32
-(three times to 64): exact algebra, as stable as substitution, and all
-of it batched matmuls.
+(three times to 64): exact algebra, as stable as substitution. Every
+step multiplies WHOLE [C, C] matrices that are block diagonal (a join is
+inv - inv [[0, 0], [R, 0]] inv; products of block diagonal matrices are
+block diagonal: the same sums, and zeros) under masks, eight products a
+chunk at 32: gathered 8 x 8 and 16 x 16 blocks took 22, over arrays
+whose rows fill a sixteenth of a vector register (ISSUE 47).
+
+WHY NOT SEVERAL CHUNKS A SCAN ITERATION (ISSUE 47 asked for four, with
+everything under `kda.scan.intra` made for the group at once; built,
+held to the recurrence and measured on the chip: `kimi_linear_offline`,
+one v5e, my chip runs, PR 47; samples/s | `peak_hbm_gib` with the
+check). On ISSUE 46's arithmetic 1 chunk an iteration read 3.040 |
+12.878, 2 3.101, 4 3.172 | 13.291, 8 3.040: the gain at four was real
+and its cost was CODE - XLA:TPU unrolls a batched product over its batch,
+so the scan's code grew from 21 to 67 MiB a layer, `train_many`'s by
+0.146 GiB, the check's programs likewise, and the cell's peak by 3.2%
+against a bound of 1%. On THIS file's arithmetic 1 reads 3.178 |
+12.888 and 2 (its solves as one block diagonal [2C, 2C] matrix) 3.108 |
+13.002: what the group bought was larger arrays for the small ops, the
+whole-matrix products buy the same at one chunk an iteration with no
+more code than before, and beside them a group only adds its code.
+The scan's 1 / C term (`CHUNK`'s comment) was never launches: an
+iteration's ops are each in proportion to their data (PERF.md section 6).
 
 PRECISION. The cumulative g, the solve, S and every product in the scan
 are float32, the matmuls at `Precision.HIGHEST`: the products are 7 dk
@@ -100,40 +131,27 @@ def _mm(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.matmul(a, b, precision=_HI)
 
 
-def _diagonal_blocks(m: jax.Array, s: int, row: int = 0, col: int = 0,
-                     of: int = 1) -> jax.Array:
-    """m [..., C, C] -> [..., C / (of s), s, s]: of every diagonal block
-    of `of` x `of` blocks of s, the block at (`row`, `col`); a mask and a
-    sum, no gather."""
-    n = m.shape[-1] // (of * s)
-    lead = m.shape[:-2]
-    m = m.reshape(*lead, n, of, s, n, of, s)[..., :, row, :, :, col, :]
-    eye = jnp.eye(n, dtype=m.dtype)[:, None, :, None]
-    return (m * eye).sum(axis=-2)
-
-
 def _unit_lower_inverse(a: jax.Array) -> jax.Array:
     """a [..., C, C] strictly lower triangular, C = `_BASE` x a power of
-    two (or below `_BASE`) -> (I + a)^-1 (module docstring)."""
+    two (or below `_BASE`) -> (I + a)^-1 (module docstring). Every
+    product is of whole [C, C] matrices that are block diagonal."""
     c = a.shape[-1]
     s = min(c, _BASE)
-    x = -_diagonal_blocks(a, s)
-    inv = jnp.eye(s, dtype=a.dtype) + x
+    at = jnp.arange(c)
+    row, col = at[:, None], at[None, :]
+    x = jnp.where(row // s == col // s, -a, 0.0)
+    inv = jnp.eye(c, dtype=a.dtype) + x
     power, reach = x, 2
     while reach < s:                    # (I + x)(I + x^2)(I + x^4) ...
         power = _mm(power, power)
         inv = inv + _mm(inv, power)
         reach *= 2
-    while s < c:
-        below = _diagonal_blocks(a, s, 1, 0, of=2)       # [..., n, s, s]
-        pairs = inv.reshape(*inv.shape[:-3], -1, 2, s, s)
-        p, q = pairs[..., 0, :, :], pairs[..., 1, :, :]
-        corner = -_mm(_mm(q, below), p)
-        inv = jnp.concatenate([
-            jnp.concatenate([p, jnp.zeros_like(p)], axis=-1),
-            jnp.concatenate([corner, q], axis=-1)], axis=-2)
+    while s < c:        # [[P, 0], [R, Q]]^-1 = inv - inv [[0, 0], [R, 0]] inv
+        below = jnp.where((row // (2 * s) == col // (2 * s))
+                          & (row // s > col // s), a, 0.0)
+        inv = inv - _mm(_mm(inv, below), inv)
         s *= 2
-    return inv[..., 0, :, :]
+    return inv
 
 
 def _chunk(carry, xs):
@@ -141,7 +159,7 @@ def _chunk(carry, xs):
     chunk's q, k, g [B, H, C, dk], v [B, H, C, dv], beta [B, H, C]."""
     s0, walked = carry
     q, k, v, g, beta = xs
-    c = q.shape[-2]
+    c, dk = q.shape[-2:]
     with jax.named_scope(INTRA):
         total = jnp.cumsum(g, axis=-2)                       # G [.., C, dk]
         at = jnp.arange(c)
@@ -156,15 +174,22 @@ def _chunk(carry, xs):
         inv = _unit_lower_inverse(
             beta[..., None] * jnp.where(below, kk, 0.0))
         grown = jnp.exp(total)                               # e^G
-        w = _mm(inv, beta[..., None] * k * grown)            # [.., C, dk]
-        u0 = _mm(inv, beta[..., None] * v)                   # [.., C, dv]
-        q_in = q * grown
+        wu = _mm(inv, beta[..., None] * jnp.concatenate(
+            [k * grown, v], axis=-1))            # W | U_0 [.., C, dk + dv]
         k_out = k * jnp.exp(total[..., -1:, :] - total)
+        # what the chunk reads of S_0 and what it writes with U, stacked
+        # here, where no state is needed: W over Q e^G [.., 2C, dk] and
+        # B over (K e^{G_C - G})^T [.., C + dk, C]
+        reads = jnp.concatenate([wu[..., :dk], q * grown], axis=-2)
+        writes = jnp.concatenate(
+            [qk, jnp.swapaxes(k_out, -1, -2)], axis=-2)
         kept = grown[..., -1, :, None]                       # e^{G_C}
     with jax.named_scope(CARRY):
-        u = u0 - _mm(w, s0)
-        o = _mm(q_in, s0) + _mm(qk, u)
-        s1 = kept * s0 + _mm(jnp.swapaxes(k_out, -1, -2), u)
+        read = _mm(reads, s0)               # W S_0 over (Q e^G) S_0
+        u = wu[..., dk:] - read[..., :c, :]
+        written = _mm(writes, u)            # B U over (K e^{G_C - G})^T U
+        o = read[..., c:, :] + written[..., :c, :]
+        s1 = kept * s0 + written[..., c:, :]
     return (s1, walked + 1), o
 
 
