@@ -87,12 +87,13 @@ from ape_x_dqn_tpu.models.expert_layer import (
     SELECTION, ExpertShare, _balanced_scores, _rms_norm, _rope, _swiglu,
     count_params, expert_ffn, seeded_params)
 from ape_x_dqn_tpu.models import windowed_gqa
+from ape_x_dqn_tpu.models.q_head import ColumnHead
 from ape_x_dqn_tpu.ops.blockwise_attention import BLOCK_K, BLOCK_Q
 
 SLIDING = "sliding_attention"
 
 
-class AfmoeQNet:
+class AfmoeQNet(ColumnHead):
     """The net as a value: `init(key, tokens, state)` and
     `apply(params, tokens, state)`; `a` is a configs.AfmoeConfig."""
 
@@ -172,23 +173,26 @@ class AfmoeQNet:
         """What a train step holds beside the persistent state (16 B a
         parameter), for the HBM fits-check, as GlmMoeQNet's. Two moments
         compete for the peak: the end of the forward pass, when the
-        online and the target net's float32 Q-values [batch, trained
-        steps, vocabulary held] are both alive; and the backward pass
-        of the first expert block, when nearly all gradients exist (4 B
-        a parameter) beside one block's recomputed activations and its
-        dispatch buffers, which run over all k assignment rows of a
+        online net's float32 Q-values [batch, trained steps, vocabulary
+        held] are alive (ONE such array: the loss reads the target net
+        and Q(s, a) by column, models/q_head.py; two before PR 49); and
+        the backward pass of the first expert block, when nearly all
+        gradients exist (4 B a parameter) beside one block's recomputed
+        activations and its dispatch buffers, which run over all k assignment rows of a
         token in bfloat16 and float32 (12 B x hidden a row, 8 B x
         hidden a token beside them: fitted to the one reading there
         is). Anchor (PR 32, published widths, 1 + 4 layers, batch 2 x
         6,144 trained; PERF.md section 4): compiled for a described
-        v5e the step's temp is 4.28 GiB, this gives 4.28; the inference
+        v5e the step's temp is 4.28 GiB, this gives 4.28 (the second
+        moment's; PR 49, the same shapes with the head by column: temp
+        4.29 GiB before and after); the inference
         server's own float32 copy of the parameters (1.88 GiB), which
         nothing prices, comes on top."""
         tokens = batch_size * trained_steps
         logits = tokens * self.num_actions * 4
         block = tokens * self.a.hidden_size * (
             12 * self.a.num_experts_per_tok + 8)
-        return max(2 * logits, 4 * self.param_count() + block)
+        return max(logits, 4 * self.param_count() + block)
 
     def init(self, key: jax.Array, tokens: Any = None,
              state: Any = None) -> dict:
@@ -257,7 +261,9 @@ class AfmoeQNet:
                          state: Any = ()):
         """-> (q [B, T, A] float32, state, stats): `stats["expert_rows"]`
         [expert layers, held] int32 rows routed to each held expert,
-        `stats["topk"]` [expert layers, B, T, k] the selected ids."""
+        `stats["topk"]` [expert layers, B, T, k] the selected ids,
+        `stats["head_input"]` [B, T, hidden] what the head read (the
+        loss's column read goes over it: `head_at`)."""
         a = self.a
         dt = dtype_of(self.compute_dtype)
         caches = list(state) if state else [None] * a.num_hidden_layers
@@ -282,6 +288,7 @@ class AfmoeQNet:
                         preferred_element_type=jnp.float32)
         b, t = tokens.shape
         stats = {
+            "head_input": x,
             "expert_rows": (jnp.stack(rows) if rows else jnp.zeros(
                 (0, self.experts_held), jnp.int32)),
             "topk": (jnp.stack(topk) if topk else jnp.zeros(

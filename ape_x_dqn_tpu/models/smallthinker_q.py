@@ -80,10 +80,11 @@ from ape_x_dqn_tpu.models.base import dtype_of
 from ape_x_dqn_tpu.models.expert_layer import (
     SELECTION, SOFTMAX_SELECTED, ExpertShare, _balanced_scores, _rms_norm,
     _rope, count_params, expert_ffn, plan, seeded_params)
+from ape_x_dqn_tpu.models.q_head import ColumnHead
 from ape_x_dqn_tpu.ops.blockwise_attention import BLOCK_K, BLOCK_Q
 
 
-class SmallThinkerQNet:
+class SmallThinkerQNet(ColumnHead):
     """The net as a value: `init(key, tokens, state)` and
     `apply(params, tokens, state)`; `s` is a configs.SmallThinkerConfig."""
 
@@ -145,15 +146,17 @@ class SmallThinkerQNet:
                              trained_steps: int) -> int:
         """What a train step holds beside the persistent state (16 B a
         parameter), for the HBM fits-check: AfmoeQNet's two moments (its
-        docstring), with this net's numbers. Anchor (PR 39, published
-        widths, 4 layers, batch 1 x 12,288 trained; PERF.md section 4):
-        compiled for a described v5e the step's temp is 3.29 GiB, this
-        gives 3.72."""
+        docstring; ONE float32 [tokens, vocabulary held] array since the
+        loss reads the head by column, PR 49), with this net's numbers.
+        Anchor (PR 39, published widths, 4 layers, batch 1 x 12,288
+        trained; PERF.md section 4): compiled for a described v5e the
+        step's temp is 3.29 GiB, this gives 3.72 (PR 49, the head by
+        column: 3.32 GiB compiled, the second moment's)."""
         tokens = batch_size * trained_steps
         logits = tokens * self.num_actions * 4
         block = tokens * self.s.hidden_size * (
             12 * self.s.moe_num_active_primary_experts + 8)
-        return max(2 * logits, 4 * self.param_count() + block)
+        return max(logits, 4 * self.param_count() + block)
 
     def init(self, key: jax.Array, tokens: Any = None,
              state: Any = None) -> dict:
@@ -218,7 +221,9 @@ class SmallThinkerQNet:
                          state: Any = ()):
         """-> (q [B, T, A] float32, state, stats): `stats["expert_rows"]`
         [layers, held] int32 rows routed to each held expert,
-        `stats["topk"]` [layers, B, T, k] the selected ids."""
+        `stats["topk"]` [layers, B, T, k] the selected ids,
+        `stats["head_input"]` [B, T, hidden] what the head read (the
+        loss's column read goes over it: `head_at`)."""
         s = self.s
         if tokens.shape[1] > s.max_position_embeddings:
             raise ValueError(
@@ -244,7 +249,8 @@ class SmallThinkerQNet:
             q = jnp.dot(x, params["lm_head"].astype(dt),
                         preferred_element_type=jnp.float32)
         return q, tuple(new_state), {"expert_rows": jnp.stack(rows),
-                                     "topk": jnp.stack(topk)}
+                                     "topk": jnp.stack(topk),
+                                     "head_input": x}
 
     def apply(self, params: dict, tokens: jax.Array, state: Any = ()):
         q, state, _ = self.apply_with_stats(params, tokens, state)

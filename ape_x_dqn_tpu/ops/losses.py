@@ -156,14 +156,79 @@ def nstep_targets_in_sequence(rewards: jax.Array, terminals: jax.Array,
     return target, valid
 
 
+def dense_read(double: bool) -> Callable:
+    """How the sequence loss reads two dense Q arrays [B, T, A], the
+    online and the target net's on the trained steps: -> read(params,
+    target_params, q_online, q_target, actions [B, T]) -> (Q(s, a),
+    the bootstrap value, the online Q), each [B, T] but the last."""
+
+    def read(params: Any, target_params: Any, q_online: jax.Array,
+             q_target: jax.Array, actions: jax.Array):
+        del params, target_params
+        q_sa = jnp.take_along_axis(
+            q_online, actions[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        if double:
+            a_star = jnp.argmax(q_online, axis=-1)
+            boot = jnp.take_along_axis(
+                q_target, a_star[..., None], axis=-1)[..., 0]
+        else:
+            boot = jnp.max(q_target, axis=-1)
+        return q_sa, boot, q_online
+
+    return read
+
+
+def column_read(head_at: Callable, double: bool) -> Callable:
+    """`dense_read` for a net whose Q-values are the columns of a matrix
+    over its head's input (models/q_head.py) and that hands that input
+    back beside Q: its application gives (q [B, T, A], x [B, T,
+    hidden]), and `head_at(params, x, ids [B, T]) -> [B, T]` reads one
+    column a token. The loss reads columns where it reads columns: the
+    online Q(s, a), the only place a gradient enters, and under
+    double-Q the target net's bootstrap at a*. The online net's whole
+    slice is read without gradient (the argmax, the two diagnostics),
+    the target's not at all, so of a step's four [tokens, hidden] x
+    [hidden, A] products one is left (XLA drops the target's) and the
+    loss holds ONE float32 [tokens, A] array where the dense read holds
+    three. Without double-Q the bootstrap is a max over the target's
+    whole slice, which stays."""
+
+    def read(params: Any, target_params: Any, online: tuple, target: tuple,
+             actions: jax.Array):
+        q_online = jax.lax.stop_gradient(online[0])
+        q_sa = head_at(params, online[1], actions)
+        if double:
+            boot = head_at(target_params, target[1],
+                           jnp.argmax(q_online, axis=-1))
+        else:
+            boot = jnp.max(target[0], axis=-1)
+        return q_sa, boot, q_online
+
+    return read
+
+
 def make_r2d2_loss(net_apply_seq: Callable, burn_in: int, n_step: int,
                    gamma: float, huber_delta: float = 1.0,
                    double: bool = True, rescale: bool = True,
-                   priority_eta: float = 0.9):
+                   priority_eta: float = 0.9,
+                   reader: Callable = dense_read):
     """Build the R2D2 sequence loss.
 
     net_apply_seq(params, obs[B,T,...], state) -> (q[B,T,A], final_state)
+
+    `reader(double)`: how Q(s, a), the bootstrap and the online Q come
+    out of what the two nets' applications over the trained steps gave.
+    `dense_read`, the default, takes two [B, T, A] arrays, and the loss
+    holds three float32 [tokens, A] arrays (the two and the online one's
+    cotangent). `column_read` over a net's `head_at` (net_apply_seq then
+    gives ((q, x), final_state)) holds ONE, the online Q without
+    gradient: compiled for a described v5e, `train_many(2)`'s temp
+    stays 4.29 GiB in `trinity_mini_offline` and goes 3.29 -> 3.32 GiB
+    in `smallthinker_offline` (PERF.md section 6, PR 49; the peak there
+    is the first expert block's backward pass either way). Everything
+    that does not touch Q's last axis is the same for both.
     """
+    read = reader(double)
 
     def loss_fn(params: Any, target_params: Any, batch: SequenceBatch,
                 is_weights: jax.Array):
@@ -183,22 +248,16 @@ def make_r2d2_loss(net_apply_seq: Callable, burn_in: int, n_step: int,
             state_bt = state0
         with jax.named_scope("r2d2.unroll"):
             obs_t = batch.obs[:, burn_in:]
-            q_online, _ = net_apply_seq(params, obs_t, state_b)  # [B,T,A]
-            q_target, _ = net_apply_seq(target_params, obs_t, state_bt)
+            out_online, _ = net_apply_seq(params, obs_t, state_b)  # [B,T,A]
+            out_target, _ = net_apply_seq(target_params, obs_t, state_bt)
 
         actions = batch.actions[:, burn_in:]
         rewards = batch.rewards[:, burn_in:]
         terminals = batch.terminals[:, burn_in:]
         mask = batch.mask[:, burn_in:]
 
-        q_sa = jnp.take_along_axis(
-            q_online, actions[..., None].astype(jnp.int32), axis=-1)[..., 0]
-        if double:
-            a_star = jnp.argmax(q_online, axis=-1)
-            boot = jnp.take_along_axis(
-                q_target, a_star[..., None], axis=-1)[..., 0]
-        else:
-            boot = jnp.max(q_target, axis=-1)
+        q_sa, boot, q_online = read(params, target_params, out_online,
+                                    out_target, actions)
         target, valid = nstep_targets_in_sequence(
             rewards, terminals, boot, mask, n_step, gamma, rescale)
         td = (q_sa - jax.lax.stop_gradient(target)) * valid

@@ -49,7 +49,11 @@ fifth, "kimi_linear_q", is the family's first with a scan layer
 hands them on (`_routed_loss`).
 A further decoder registers with: its net in models/ with the surface
 the family reads (`init`, `apply`, `apply_with_stats`, `param_count`,
-`step_transient_bytes`, `num_actions`; a net WITH an expert layer also
+`step_transient_bytes`, `num_actions`; a net whose loss may read the
+head by column ALSO OFFERS `head_at(params, x, ids)` over the
+`stats["head_input"]` its `apply_with_stats` hands back beside Q,
+models/q_head.py, and a net that does not offer it keeps the dense
+read; a net WITH an expert layer also
 `router_trains`, `share` and the stats `expert_rows` and `topk`, a net
 without one the stats `block_applications` and `exit_gates`: the
 family's loss reads expert statistics only from a net that has a
@@ -71,6 +75,7 @@ driver.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Callable, NamedTuple
 
 import jax.numpy as jnp
@@ -282,16 +287,25 @@ def has_scan_layer(net: Any) -> bool:
     return getattr(net, "num_kda_layers", 0) > 0
 
 
+def reads_by_column(net: Any) -> bool:
+    """Whether `net` offers the head's column read (`head_at` over the
+    `head_input` in its stats: models/q_head.py)."""
+    return hasattr(net, "head_at")
+
+
 def _routed_loss(net: Any, r2d2: Callable) -> Callable:
     """decoder_q_family's loss for a net WITH an expert layer (its
-    docstring says what the counters are). `r2d2(apply)` -> the R2D2
-    sequence loss over `apply`."""
+    docstring says what the counters are). `r2d2(apply[, reader])` ->
+    the R2D2 sequence loss over `apply`, reading Q through `reader`
+    (ops/losses.dense_read where none is given)."""
     from ape_x_dqn_tpu.models.expert_layer import capacity, fits
+    from ape_x_dqn_tpu.ops.losses import column_read
 
     scan_layer = has_scan_layer(net)
+    by_column = reads_by_column(net)
 
     def loss_fn(params, target_params, batch, is_weights):
-        tally, fitted, seen, scans = [], [], [], []
+        tally, fitted, seen, scans, columns = [], [], [], [], []
 
         def apply(p, tokens, state):
             q, state, stats = net.apply_with_stats(p, tokens, state)
@@ -301,9 +315,15 @@ def _routed_loss(net: Any, r2d2: Callable) -> Callable:
             fitted.append(fits(stats["expert_rows"],
                                capacity(net.share, tokens.size)))
             seen.append((q, stats["topk"]))
-            return q, state
+            return ((q, stats["head_input"]) if by_column else q), state
 
-        loss, aux = r2d2(apply)(params, target_params, batch, is_weights)
+        def head_at(p, x, ids):
+            columns.append(ids.size)
+            return net.head_at(p, x, ids)
+
+        loss_of = (r2d2(apply, partial(column_read, head_at)) if by_column
+                   else r2d2(apply))
+        loss, aux = loss_of(params, target_params, batch, is_weights)
         # the loss applies: online burn-in, target burn-in (when
         # burn_in > 0), then online and target over the trained steps
         online = tally[0::2]
@@ -329,6 +349,8 @@ def _routed_loss(net: Any, r2d2: Callable) -> Callable:
             aux["kda_chunks"] = sum(
                 n for n, _ in scans[0::2]).astype(jnp.float32)
             aux["kda_state_rms_last"] = scans[-2][1]
+        if by_column:
+            aux["head_columns"] = jnp.float32(sum(columns))
         return loss, aux
 
     return loss_fn
@@ -370,7 +392,25 @@ def decoder_q_family(net: Any, lcfg, rcfg):
     """Token-level Q-learning on a decoder: make_r2d2_loss as it
     stands, over stored token sequences with no stored state — the
     loss's burn-in is the prefix pass that leaves the net's latent
-    cache (models/glm_moe_q.py). The net also says how its layers were
+    cache (models/glm_moe_q.py). HOW THE LOSS READS THE HEAD is the
+    net's to offer, as its transient bytes and its scan's counters are:
+    a routed net with `head_at` (`reads_by_column`: AfmoeQNet,
+    SmallThinkerQNet) gets losses.column_read — the target net's
+    bootstrap and the online net's Q(s, a) are one column of `lm_head`
+    a token, the online net's whole slice stays without gradient for
+    the argmax and `aux["q"]`, of a step's four [tokens, hidden] x
+    [hidden, A] products one is left and the loss holds ONE float32
+    [tokens, A] array (compiled for a described v5e, `train_many(2)`'s
+    temp: 4.29 GiB in `trinity_mini_offline`, 3.32 in
+    `smallthinker_offline`; PERF.md section 6, PR 49) — and its aux
+    gains `head_columns`, the columns read a step, online + target:
+    2 x trained tokens under `double_dqn`, 1 x without it (the target's
+    whole slice stays then, a max needs it). A net without `head_at`
+    (GlmMoeQNet, OuroQNet, KimiLinearQNet; ROADMAP S5.9 says what each
+    waits for) gets losses.dense_read, three such arrays, the program
+    it had, and no `head_columns` (`_looped_loss` knows no other read).
+    tests/test_decoder_head_columns.py holds the column read to the
+    dense form. The net also says how its layers were
     used; the loss's signature has no room for that, so each of its
     four net applications leaves its statistics in a list the family's
     loss reads back inside the same trace. WHICH statistics depends on
@@ -401,15 +441,16 @@ def decoder_q_family(net: Any, lcfg, rcfg):
     `kda_state_rms_last`, the RMS of the state matrices after the last
     trained position (mean over the KDA layers): the one place a state
     that blew up or died is seen."""
-    from ape_x_dqn_tpu.ops.losses import SequenceBatch, make_r2d2_loss
+    from ape_x_dqn_tpu.ops.losses import (
+        SequenceBatch, dense_read, make_r2d2_loss)
     from ape_x_dqn_tpu.runtime.learner import LearnerFamily
 
-    def r2d2(apply):
+    def r2d2(apply, reader=dense_read):
         return make_r2d2_loss(
             apply, burn_in=rcfg.burn_in, n_step=lcfg.n_step,
             gamma=lcfg.gamma, huber_delta=lcfg.huber_delta,
             double=lcfg.double_dqn, rescale=lcfg.value_rescale,
-            priority_eta=rcfg.priority_eta)
+            priority_eta=rcfg.priority_eta, reader=reader)
 
     routed = hasattr(net, "share")
     return LearnerFamily(
@@ -425,6 +466,7 @@ def decoder_q_family(net: Any, lcfg, rcfg):
                       "moe_load_max_over_mean", "moe_compact_share")
                      + (("kda_chunks", "kda_state_rms_last")
                         if has_scan_layer(net) else ())
+                     + (("head_columns",) if reads_by_column(net) else ())
                      if routed else
                      ("valid_frac", "loop_block_applications",
                       "loop_exit_mass_last")))

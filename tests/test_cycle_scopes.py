@@ -30,7 +30,15 @@ grad step, and `ops/sum_tree.py`'s two nested in the first and the last.
     a chunk's solve multiplies whole block diagonal matrices, W and U_0
     come from one product and the chunk's walk is two);
     `kda.scan`, `kda.scan.intra` and `kda.scan.carry` are still in its
-    name stacks;
+    name stacks. ISSUE 49 moved `trinity_tiny_q` and
+    `smallthinker_tiny_q`, by design, and nothing else: the family's
+    loss reads the head by column for the two nets that offer `head_at`
+    (models/q_head.py, ops/losses.column_read), so those two hashes are
+    re-pinned from that PR's tree, both columns; `pong`, `r2d2`,
+    `dist`, `apex_dpg`, `glm_tiny_q`, `ouro_tiny_q` and
+    `kimi_linear_tiny_q` pass UNCHANGED, which is the proof that the
+    other seven cells run the parent's program (UNMOVED_BY_ISSUE_49
+    names them in the hash test's message);
 (c) no endpoint bypasses a scope: `train_step`, `train_step_k`,
     `sample_k` + `learn_k` and the prefetching `train_many` open the
     same names, on one chip and on the mesh.
@@ -71,12 +79,15 @@ PROGRAMS = {
              "6c63d1b14997ec19", "96e219598457572d"),
     "glm_tiny_q": ("glm_tiny_q", ["replay.capacity=64"], 2,
                    "a88f0e52e73150b6", "dfb4d0171f649268"),
+    # moved by ISSUE 49, with `smallthinker_tiny_q` and nothing else: the
+    # loss reads these two nets' head by column (PR 47's: f80b0f6ac1ea7140)
     "trinity_tiny_q": ("trinity_tiny_q", ["replay.capacity=64"], 2,
-                       "f80b0f6ac1ea7140", "9187c5d2ae5b1298"),
+                       "082554aa406ffca0", "3c5a52828b08e728"),
     # the decoder family's third net, pinned at the PR that added it (ISSUE
-    # 39); its second hash is the same tree's with no dense level
+    # 39); its second hash is the same tree's with no dense level; moved by
+    # ISSUE 49 (PR 47's: 6d3781211705c6a0)
     "smallthinker_tiny_q": ("smallthinker_tiny_q", ["replay.capacity=64"],
-                            2, "6d3781211705c6a0", "60c188e6200430d0"),
+                            2, "85c7f305db804121", "13b93cf6b8bd527e"),
     # the family's fourth net, the one without experts, pinned at the PR
     # that added it (ISSUE 41) beside the three that must not move; moved
     # by ISSUE 44 and by nothing else: at its group of one the blockwise
@@ -99,6 +110,12 @@ PROGRAMS = {
                               "replay.min_fill=512"], 8,
                  "836445fb85177e4e", "a376acdbc487b640"),
 }
+# the programs ISSUE 49 must not move: the dense read is
+# `make_r2d2_loss`'s body as it stood, and the three decoder nets that
+# do not offer `head_at` keep it (their cells' checks cannot carry the
+# change yet: ROADMAP S5.9)
+UNMOVED_BY_ISSUE_49 = ("pong", "r2d2", "dist", "apex_dpg", "glm_tiny_q",
+                       "ouro_tiny_q", "kimi_linear_tiny_q")
 RELABELS = ("glm_tiny_q", "trinity_tiny_q", "smallthinker_tiny_q",
             "ouro_tiny_q", "kimi_linear_tiny_q", "apex_dpg")
 QUIET = ["actors.num_actors=0", "eval_episodes=0", "eval_every_steps=0"]
@@ -175,7 +192,10 @@ def test_the_program_is_the_parents_to_the_byte(case):
     text, _ = _lowered(case)
     assert not any(s in text for s in CYCLE_SCOPES)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-        PROGRAMS[case][3]
+        PROGRAMS[case][3], (
+            "ISSUE 49 moved the two column-reading nets and must move no "
+            "other program: this pin is PR 47's" if case in UNMOVED_BY_ISSUE_49
+            else "moved by ISSUE 49 (the head by column), re-pinned there")
 
 
 def test_the_delta_rules_scan_keeps_its_three_scopes():

@@ -134,8 +134,12 @@ DECODER_KEYS = {
     # a routed net WITH A SCAN LAYER adds the scan's two counters
     "kimi_linear_tiny_q": {"valid_frac", "moe_rows", "moe_rows_grad",
                            "moe_load_max_over_mean", "moe_compact_share",
-                           "kda_chunks", "kda_state_rms_last"}}
-ROUTED = ("glm_tiny_q", "kimi_linear_tiny_q")
+                           "kda_chunks", "kda_state_rms_last"},
+    # a routed net THAT OFFERS THE HEAD'S COLUMN READ adds its counter
+    "trinity_tiny_q": {"valid_frac", "moe_rows", "moe_rows_grad",
+                       "moe_load_max_over_mean", "moe_compact_share",
+                       "head_columns"}}
+ROUTED = ("glm_tiny_q", "kimi_linear_tiny_q", "trinity_tiny_q")
 
 
 @pytest.mark.parametrize("preset", list(DECODER_KEYS))
