@@ -374,10 +374,80 @@ class KimiLinearConfig:
                 f"vocab_size={self.vocab_size}")
 
 
+_PUBLISHED_LFM2_LAYER_TYPES = ("conv", "conv") + (
+    "full_attention", "conv", "conv", "conv") * 9 + ("full_attention", "conv")
+
+
+@dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """The decoder of network.kind="lfm2_moe_q" (models/lfm2_moe_q.py),
+    under the key names of the model's own config.json
+    (LiquidAI/LFM2-24B-A2B, `model_type` lfm2_moe; its nested
+    `rope_parameters.rope_theta` flat here as `rope_theta`); defaults
+    are that model's. `layer_types[i]` "conv" is the gated short
+    convolution (two multiplicative gates around a causal depthwise
+    filter of `conv_L_cache` taps, no activation), "full_attention"
+    grouped-query attention with q/k head norms and RoPE on every dim;
+    the first `num_dense_layers` layers' FFN is one dense SwiGLU, every
+    other layer's `num_experts` routed experts (sigmoid scores, top-
+    `num_experts_per_tok` of score + a fixed selection bias if
+    `use_expert_bias`, weights normalised if `norm_topk_prob` and times
+    `routed_scaling_factor`), none shared. The head is the embedding
+    (`tie_embedding`; only True is built)."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 11776      # the dense layers' SwiGLU
+    moe_intermediate_size: int = 1536   # each routed expert
+    num_hidden_layers: int = 40
+    num_dense_layers: int = 2
+    # one kind per layer HELD (a run that holds fewer layers names
+    # theirs): "conv" | "full_attention"
+    layer_types: tuple[str, ...] = _PUBLISHED_LFM2_LAYER_TYPES
+    conv_L_cache: int = 3       # the filter's taps; a prefix leaves one less
+    conv_bias: bool = False     # only False is built
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    # the model's file has none (null): 0 is hidden_size over the heads
+    head_dim: int = 0
+    rope_theta: float = 1_000_000.0
+    max_position_embeddings: int = 128_000
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    use_expert_bias: bool = True        # only True is built
+    vocab_size: int = 65_536
+    norm_eps: float = 1e-5
+    tie_embedding: bool = True          # only True is built
+    # the four below are AfmoeConfig's, with the same meaning: this
+    # chip's share of a deployment in which `shard_count` chips share
+    # each layer's experts and `vocab_shard_count` (0: as shard_count)
+    # the embedding's rows, and the selection forced balanced for
+    # measuring with random weights
+    shard_count: int = 1
+    shard_index: int = 0
+    vocab_shard_count: int = 0
+    force_balanced_routing: bool = False
+
+    def __post_init__(self) -> None:
+        if not 0 <= self.shard_index < self.shard_count:
+            raise ValueError(
+                f"network.lfm2_moe.shard_index must be in [0, "
+                f"{self.shard_count}) (got {self.shard_index})")
+        vocab_shards = self.vocab_shard_count or self.shard_count
+        if (self.num_experts % self.shard_count
+                or self.vocab_size % vocab_shards):
+            raise ValueError(
+                f"network.lfm2_moe.shard_count={self.shard_count} must "
+                f"divide num_experts={self.num_experts} and "
+                f"vocab_shard_count={vocab_shards} must divide "
+                f"vocab_size={self.vocab_size}")
+
+
 @dataclass(frozen=True)
 class NetworkConfig:
     # mlp | nature_cnn | lstm_q | dpg | glm_moe_q | afmoe_q | smallthinker_q
-    # | ouro_q | kimi_linear_q
+    # | ouro_q | kimi_linear_q | lfm2_moe_q
     kind: str = "mlp"
     mlp_hidden: tuple[int, ...] = (256, 256)
     cnn_channels: tuple[int, ...] = (32, 64, 64)
@@ -402,6 +472,9 @@ class NetworkConfig:
     # the decoder of kind="kimi_linear_q" (the same family: KDA scan
     # layers beside latent attention, with experts)
     kimi_linear: KimiLinearConfig = field(default_factory=KimiLinearConfig)
+    # the decoder of kind="lfm2_moe_q" (the same family: gated short
+    # convolutions beside full attention, with experts, a tied head)
+    lfm2_moe: Lfm2MoeConfig = field(default_factory=Lfm2MoeConfig)
 
 
 @dataclass(frozen=True)
@@ -1476,6 +1549,83 @@ def _preset_kimi_linear_tiny_q() -> RunConfig:
     )
 
 
+def _preset_lfm2_24b_q() -> RunConfig:
+    """Config 11: LFM2-24B-A2B (Liquid AI, `model_type` lfm2_moe) as a
+    token-level Q-network, the decoder family's sixth net and its first
+    with a convolution mixer and a head that is its own embedding. The
+    sizes are the model's config.json
+    (https://huggingface.co/LiquidAI/LFM2-24B-A2B): 40 layers, three
+    gated short convolutions (two gates around a causal depthwise
+    filter of 3 taps) to one of grouped-query attention (32 : 8 heads
+    of 64, q/k norms, RoPE), two leading dense layers, then 64 routed
+    experts of 1,536, top-4, none shared, 65,536 vocabulary rows. Whole
+    it is 24 B parameters and check_hbm_fits refuses it: a run gives
+    one chip its share with network.lfm2_moe.shard_count /
+    vocab_shard_count / num_hidden_layers / layer_types /
+    num_dense_layers and env.num_tokens
+    (benchmarks/configs/lfm2_24b_ep8_1chip.json is the measured one).
+    The learner settings are this repo's: sequences of 16,384 tokens,
+    long episodes replayed whole, where pair work exists in one layer
+    of four."""
+    lf = Lfm2MoeConfig()
+    return RunConfig(
+        name="lfm2_24b_q",
+        total_env_frames=10_000_000_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=lf.vocab_size),
+        network=NetworkConfig(kind="lfm2_moe_q", dueling=False,
+                              lfm2_moe=lf),
+        # a stored sequence is 16,384 tokens: 4,096 of burn-in, which
+        # leaves a conv layer TWO ROWS however long it is and an
+        # attention layer keys and values per position; the trained
+        # 12,288 start from both without gradient. 2,048 sequences are
+        # the other decoders' token count, 0.63 GiB
+        replay=ReplayConfig(kind="sequence", capacity=2_048,
+                            seq_length=16_384, seq_overlap=8_192,
+                            burn_in=4_096, min_fill=64),
+        # batch 2: 32,768 tokens a step (the configuration file's
+        # `memory` has what fits beside the learner's state)
+        learner=LearnerConfig(batch_size=2, n_step=5, value_rescale=True,
+                              target_sync_every=2500, lr=1e-4,
+                              sample_chunk=1, train_chunk=2),
+        # a query re-runs a window of up to 16,384 tokens (the family's
+        # stateless protocol): one at a time
+        actors=ActorConfig(num_actors=64, envs_per_actor=1),
+        inference=InferenceConfig(max_batch=1, deadline_ms=2.0),
+    )
+
+
+def _preset_lfm2_tiny_q() -> RunConfig:
+    """lfm2_24b_q's sibling for CPU tests: both kinds of layer and both
+    kinds of FFN (conv + dense, attention + experts, conv + experts) at
+    hidden 32, 4 query heads to each 2 key-value heads of 12 (48: no
+    attention width is the hidden size), 8 experts top-2, a vocabulary
+    of 64 (off a lane tile of 128; tests also run one on it),
+    sequences of 32, float32."""
+    lf = Lfm2MoeConfig(
+        hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+        num_hidden_layers=3, num_dense_layers=1,
+        layer_types=("conv", "full_attention", "conv"),
+        num_attention_heads=4, num_key_value_heads=2, head_dim=12,
+        max_position_embeddings=32, num_experts=8, num_experts_per_tok=2,
+        vocab_size=64)
+    return RunConfig(
+        name="lfm2_tiny_q",
+        total_env_frames=100_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=lf.vocab_size),
+        network=NetworkConfig(kind="lfm2_moe_q", dueling=False,
+                              lfm2_moe=lf, compute_dtype="float32"),
+        replay=ReplayConfig(kind="sequence", capacity=64, seq_length=32,
+                            seq_overlap=16, burn_in=12, min_fill=8),
+        learner=LearnerConfig(batch_size=4, n_step=3, value_rescale=True,
+                              target_sync_every=100, lr=1e-3,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=1, envs_per_actor=2),
+        inference=InferenceConfig(max_batch=8, deadline_ms=2.0),
+    )
+
+
 PRESETS = {
     "cartpole_smoke": _preset_cartpole_smoke,
     "pong": _preset_pong,
@@ -1492,6 +1642,8 @@ PRESETS = {
     "ouro_tiny_q": _preset_ouro_tiny_q,
     "kimi_linear_48b_q": _preset_kimi_linear_48b_q,
     "kimi_linear_tiny_q": _preset_kimi_linear_tiny_q,
+    "lfm2_24b_q": _preset_lfm2_24b_q,
+    "lfm2_tiny_q": _preset_lfm2_tiny_q,
 }
 
 

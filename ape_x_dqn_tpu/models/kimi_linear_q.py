@@ -36,7 +36,8 @@ configuration file. H = hidden_size.
 - KDA, on u = RMSNorm(x) (heads x d = 32 x 128 = 4096):
       q~, k~, v~ = u W_q, u W_k, u W_v, each through a causal depthwise
       convolution of kernel K = 4 over time (one filter a channel, no
-      bias; out_t = sum_j w_j x_{t - (K - 1) + j}) and SiLU;
+      bias; out_t = sum_j w_j x_{t - (K - 1) + j}: models/short_conv.py,
+      which models/lfm2_moe_q.py calls too) and SiLU;
       (+) q = L2norm_head(q~) d^-1/2, k = L2norm_head(k~) (x rsqrt(sum
       x^2 + 1e-6)), v = v~;
       g_t = -exp(A_log_h) softplus(u_t W_f_down W_f_up + dt_bias), per
@@ -106,6 +107,8 @@ from ape_x_dqn_tpu.models.expert_layer import (
     SELECTION, ExpertShare, _balanced_scores, count_params, expert_ffn,
     seeded_params)
 from ape_x_dqn_tpu.models.ouro_q import _add, _dot, _held, _norm
+# under the name a benchmark test replaces to make a departure
+from ape_x_dqn_tpu.models.short_conv import short_conv as _short_conv
 from ape_x_dqn_tpu.ops.blockwise_attention import BLOCK_K, BLOCK_Q
 from ape_x_dqn_tpu.ops.chunked_delta_rule import CHUNK, chunked_delta_rule
 
@@ -118,15 +121,6 @@ STREAMS = ("q", "k", "v")
 def _l2_norm(x32: jax.Array) -> jax.Array:
     return x32 * jax.lax.rsqrt(
         jnp.sum(x32 * x32, axis=-1, keepdims=True) + L2_EPS)
-
-
-def _short_conv(seen: jax.Array, w: jax.Array, t: int) -> jax.Array:
-    """seen [B, K - 1 + T, width] (the tail, then the new rows), w [K,
-    width] -> [B, T, width] float32: out_t = sum_j w_j x_{t - (K - 1) + j},
-    one filter a channel."""
-    w = w.astype(jnp.float32)
-    return sum(w[j] * seen[:, j:j + t].astype(jnp.float32)
-               for j in range(w.shape[0]))
 
 
 _output_gate = jax.nn.sigmoid       # (+) a sigmoid, not SiLU

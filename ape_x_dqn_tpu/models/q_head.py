@@ -27,6 +27,11 @@ would rather flip `lm_head`, its two moments and the target's copy to
 the scatter's layout for the whole train loop (+1.5 GiB of temp there)
 than transpose one gradient a step: such a net needs the gradient
 pinned to the parameter's layout before it offers this (ROADMAP S5.9).
+A NET WHOSE HEAD IS ITS EMBEDDING (Lfm2MoeQNet; `q_at(..., by_row=True)`
+over E [A, hidden]) has that by construction: Q(s_t, a) = x_t . E[a], the
+gathered "columns" are E's own rows and the scatter-add lands in the
+parameter's own layout, to which autodiff adds the lookup's
+scatter-add (the matrix has two uses and one gradient).
 """
 
 from __future__ import annotations
@@ -39,56 +44,73 @@ import jax.numpy as jnp
 COLUMNS_SCOPE = "head.columns"
 
 
-def _columns(x: jax.Array, lm_head: jax.Array, ids: jax.Array) -> jax.Array:
-    """The columns `ids` of `lm_head` as the matmul reads them (rounded
-    to x's dtype), float32 [B, T, hidden]. Whole rows of the TRANSPOSED,
-    ROUNDED matrix: a column of [hidden, A] is one value a tile, and the
-    transposed compute-dtype copy is the operand XLA:TPU lays out for
-    the head's matmul anyway; gathered from the float32 matrix they
-    cost three whole-matrix transposes a step (PERF.md section 6, PR
-    48)."""
+def _rounded_rows(table: jax.Array, ids: jax.Array, dt) -> jax.Array:
+    """Rows `ids` of `table` [A, hidden] (already in the compute dtype
+    `dt`) as the matmul reads them, float32 [B, T, hidden]."""
     f32 = jnp.float32
-    columns = jnp.take(lm_head.astype(x.dtype).T, ids, axis=0).astype(f32)
-    if x.dtype != f32:
+    rows = jnp.take(table, ids, axis=0).astype(f32)
+    if dt != f32:
         # held whatever XLA fuses (ouro_q._held says why)
-        info = jnp.finfo(x.dtype)
-        columns = jax.lax.reduce_precision(columns, info.nexp, info.nmant)
-    return columns
+        info = jnp.finfo(dt)
+        rows = jax.lax.reduce_precision(rows, info.nexp, info.nmant)
+    return rows
 
 
-@jax.custom_vjp
-def _q_at(x: jax.Array, lm_head: jax.Array, ids: jax.Array) -> jax.Array:
-    return jnp.sum(x.astype(jnp.float32) * _columns(x, lm_head, ids),
-                   axis=-1)
+def _reader(by_row: bool):
+    """-> the read over a head kept [hidden, A] (`by_row` False: an
+    untied `lm_head`) or [A, hidden] (`by_row` True: a head that IS the
+    embedding, models/lfm2_moe_q.py). The first gathers whole rows of
+    the TRANSPOSED, ROUNDED matrix: a column of [hidden, A] is one
+    value a tile, and the transposed compute-dtype copy is the operand
+    XLA:TPU lays out for the head's matmul anyway; gathered from the
+    float32 matrix they cost three whole-matrix transposes a step
+    (PERF.md section 6, PR 48). The second's rows are the parameter's
+    own: no transpose in the gather and none on the scatter's result."""
+
+    def rows_of(x, head, ids):
+        table = head.astype(x.dtype)
+        return _rounded_rows(table if by_row else table.T, ids, x.dtype)
+
+    @jax.custom_vjp
+    def read(x, head, ids):
+        return jnp.sum(x.astype(jnp.float32) * rows_of(x, head, ids),
+                       axis=-1)
+
+    def fwd(x, head, ids):
+        return read(x, head, ids), (x, head, ids)
+
+    def bwd(res, g):
+        """d x = g x the column; d head = the scatter-add of g x x_t
+        into the columns `ids`, summed in float32 over an id's repeats.
+        Written out because the forward pass gathers ROUNDED columns:
+        autodiff's transpose of that would add an id's repeats in the
+        compute dtype."""
+        x, head, ids = res
+        f32 = jnp.float32
+        g = g[..., None]
+        d_x = (g * rows_of(x, head, ids)).astype(x.dtype)
+        rows = (g * x.astype(f32)).reshape(-1, x.shape[-1])
+        shape = head.shape if by_row else head.shape[::-1]
+        d_head = jnp.zeros(shape, f32).at[ids.reshape(-1)].add(rows)
+        return (d_x, (d_head if by_row else d_head.T).astype(head.dtype),
+                None)
+
+    read.defvjp(fwd, bwd)
+    return read
 
 
-def _q_at_fwd(x, lm_head, ids):
-    return _q_at(x, lm_head, ids), (x, lm_head, ids)
+_q_at, _q_at_rows = _reader(False), _reader(True)
 
 
-def _q_at_bwd(res, g):
-    """d x = g x the column; d lm_head = the scatter-add of g x x_t into
-    the columns `ids`, summed in float32 over an id's repeats. Written
-    out because the forward pass gathers ROUNDED columns: autodiff's
-    transpose of that would add an id's repeats in the compute dtype."""
-    x, lm_head, ids = res
-    f32 = jnp.float32
-    g = g[..., None]
-    d_x = (g * _columns(x, lm_head, ids)).astype(x.dtype)
-    rows = (g * x.astype(f32)).reshape(-1, x.shape[-1])
-    d_head = jnp.zeros(lm_head.shape[::-1], f32).at[ids.reshape(-1)].add(rows)
-    return d_x, d_head.T.astype(lm_head.dtype), None
-
-
-_q_at.defvjp(_q_at_fwd, _q_at_bwd)
-
-
-def q_at(x: jax.Array, lm_head: jax.Array, ids: jax.Array) -> jax.Array:
-    """x [B, T, hidden], lm_head float32 [hidden, A], ids [B, T] ->
-    Q(s_t, ids_t) [B, T] float32: the head's matmul at `ids` without
-    the other columns."""
+def q_at(x: jax.Array, lm_head: jax.Array, ids: jax.Array,
+         by_row: bool = False) -> jax.Array:
+    """x [B, T, hidden], lm_head float32 [hidden, A] (`by_row`: [A,
+    hidden], the embedding of a tied head), ids [B, T] -> Q(s_t, ids_t)
+    [B, T] float32: the head's matmul at `ids` without the other
+    columns."""
     with jax.named_scope(COLUMNS_SCOPE):
-        return _q_at(x, lm_head, ids.astype(jnp.int32))
+        return (_q_at_rows if by_row else _q_at)(
+            x, lm_head, ids.astype(jnp.int32))
 
 
 class ColumnHead:

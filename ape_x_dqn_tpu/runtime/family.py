@@ -27,7 +27,10 @@ and what differs is the state a sequence starts from:
   for a full layer and the last window - 1 for a sliding one; ouro_q:
   per (loop step, layer) (k, v); kimi_linear_q: a KDA layer's state
   matrix and convolution tails, THE SAME SIZE HOWEVER LONG THE PREFIX,
-  beside an MLA layer's latent row per position), the loss stops its
+  beside an MLA layer's latent row per position; lfm2_moe_q: a conv
+  layer's last two rows of its gated input, 8 KiB a sequence however
+  long the prefix, beside an attention layer's (k, v) per position),
+  the loss stops its
   gradient, the trained steps start from it. The item has no
   state entry at all. The server is stateless too: a query carries the
   last <= L token ids ({obs, ctx, n} -> {q, ctx, n}) and the server
@@ -35,18 +38,22 @@ and what differs is the state a sequence starts from:
   parallel/inference_server.py, which would make a step cost one token
   instead of a window, is what is missing.
 
-The decoder_q family has five nets. Four (network.kind "glm_moe_q",
-"afmoe_q", "smallthinker_q", "kimi_linear_q") share
+The decoder_q family has six nets. Five (network.kind "glm_moe_q",
+"afmoe_q", "smallthinker_q", "kimi_linear_q", "lfm2_moe_q") share
 models/expert_layer.py (the plan and the application of an expert
-layer); the second and third of them and "ouro_q" - a stack of dense
-blocks run several times with the same weights, no expert layer - share
-models/windowed_gqa.py (the attention call and its cache); the first
-and the fifth share models/mla.py (latent attention; the fifth's scores
+layer); the second, third and sixth of them and "ouro_q" - a stack of
+dense blocks run several times with the same weights, no expert layer -
+share models/windowed_gqa.py (the attention call and its cache); the
+first and the fifth share models/mla.py (latent attention; the fifth's scores
 go through ops/blockwise_attention.py as the windowed nets' do). The
 fifth, "kimi_linear_q", is the family's first with a scan layer
 (ops/chunked_delta_rule.py): a net that has one also reports
 `kda_chunks` and `kda_state_rms` in its stats, and the family's loss
-hands them on (`_routed_loss`).
+hands them on (`_routed_loss`). The sixth, "lfm2_moe_q", is the first
+whose mixer is a gated short convolution (models/short_conv.py's
+filter, which the fifth calls ahead of its scan) and the first whose
+head is its embedding (models/q_head.py's read over [A, hidden]): a
+net with conv layers reports `conv_positions`, handed on the same way.
 A further decoder registers with: its net in models/ with the surface
 the family reads (`init`, `apply`, `apply_with_stats`, `param_count`,
 `step_transient_bytes`, `num_actions`; a net whose loss may read the
@@ -96,7 +103,8 @@ def family_of(cfg: RunConfig) -> str:
     return {"lstm_q": "r2d2", "dpg": "dpg", "glm_moe_q": "decoder_q",
             "afmoe_q": "decoder_q", "smallthinker_q": "decoder_q",
             "ouro_q": "decoder_q",
-            "kimi_linear_q": "decoder_q"}.get(cfg.network.kind, "dqn")
+            "kimi_linear_q": "decoder_q",
+            "lfm2_moe_q": "decoder_q"}.get(cfg.network.kind, "dqn")
 
 
 # families whose replay items are whole sequences (the staging unit is
@@ -155,9 +163,10 @@ def hbm_price(cfg: RunConfig, net: Any) -> dict:
             cfg.replay.seq_length - cfg.replay.burn_in)
     if hasattr(net, "sequence_state_bytes"):
         # what the burn-in leaves, online and target net: for a net
-        # whose state does not grow with the prefix (a scan layer's)
-        # the net says so itself; the attention caches of the older
-        # nets sit inside their `step_transient_bytes` anchors
+        # whose state does not grow with the prefix (a scan layer's
+        # matrix, a conv layer's two rows) the net says so itself; the
+        # attention caches of the older nets sit inside their
+        # `step_transient_bytes` anchors
         price["step_transient"] += 2 * net.sequence_state_bytes(
             cfg.learner.batch_size, cfg.replay.burn_in)
     return price
@@ -287,6 +296,12 @@ def has_scan_layer(net: Any) -> bool:
     return getattr(net, "num_kda_layers", 0) > 0
 
 
+def has_conv_layer(net: Any) -> bool:
+    """Whether `net` has a short-convolution mixer and reports
+    `conv_positions` in its stats (models/lfm2_moe_q.py)."""
+    return getattr(net, "num_conv_layers", 0) > 0
+
+
 def reads_by_column(net: Any) -> bool:
     """Whether `net` offers the head's column read (`head_at` over the
     `head_input` in its stats: models/q_head.py)."""
@@ -301,16 +316,18 @@ def _routed_loss(net: Any, r2d2: Callable) -> Callable:
     from ape_x_dqn_tpu.models.expert_layer import capacity, fits
     from ape_x_dqn_tpu.ops.losses import column_read
 
-    scan_layer = has_scan_layer(net)
+    scan_layer, conv_layer = has_scan_layer(net), has_conv_layer(net)
     by_column = reads_by_column(net)
 
     def loss_fn(params, target_params, batch, is_weights):
-        tally, fitted, seen, scans, columns = [], [], [], [], []
+        tally, fitted, seen, scans, columns, convs = [], [], [], [], [], []
 
         def apply(p, tokens, state):
             q, state, stats = net.apply_with_stats(p, tokens, state)
             if scan_layer:
                 scans.append((stats["kda_chunks"], stats["kda_state_rms"]))
+            if conv_layer:
+                convs.append(stats["conv_positions"])
             tally.append(stats["expert_rows"].astype(jnp.float32))
             fitted.append(fits(stats["expert_rows"],
                                capacity(net.share, tokens.size)))
@@ -349,6 +366,8 @@ def _routed_loss(net: Any, r2d2: Callable) -> Callable:
             aux["kda_chunks"] = sum(
                 n for n, _ in scans[0::2]).astype(jnp.float32)
             aux["kda_state_rms_last"] = scans[-2][1]
+        if conv_layer:
+            aux["conv_positions"] = sum(convs[0::2]).astype(jnp.float32)
         if by_column:
             aux["head_columns"] = jnp.float32(sum(columns))
         return loss, aux
@@ -395,7 +414,8 @@ def decoder_q_family(net: Any, lcfg, rcfg):
     cache (models/glm_moe_q.py). HOW THE LOSS READS THE HEAD is the
     net's to offer, as its transient bytes and its scan's counters are:
     a routed net with `head_at` (`reads_by_column`: AfmoeQNet,
-    SmallThinkerQNet) gets losses.column_read — the target net's
+    SmallThinkerQNet, and Lfm2MoeQNet over its tied head) gets
+    losses.column_read — the target net's
     bootstrap and the online net's Q(s, a) are one column of `lm_head`
     a token, the online net's whole slice stays without gradient for
     the argmax and `aux["q"]`, of a step's four [tokens, hidden] x
@@ -440,7 +460,11 @@ def decoder_q_family(net: Any, lcfg, rcfg):
     own carry: KDA layers x positions / chunk), and
     `kda_state_rms_last`, the RMS of the state matrices after the last
     trained position (mean over the KDA layers): the one place a state
-    that blew up or died is seen."""
+    that blew up or died is seen. A routed net WITH CONV LAYERS (its
+    stats have `conv_positions`: models/lfm2_moe_q.py) adds
+    `conv_positions`, the positions that passed a conv operator in the
+    online net's forward pass (prefix and trained steps, summed where
+    the operator runs: conv layers x sequence length x batch)."""
     from ape_x_dqn_tpu.ops.losses import (
         SequenceBatch, dense_read, make_r2d2_loss)
     from ape_x_dqn_tpu.runtime.learner import LearnerFamily
@@ -466,6 +490,7 @@ def decoder_q_family(net: Any, lcfg, rcfg):
                       "moe_load_max_over_mean", "moe_compact_share")
                      + (("kda_chunks", "kda_state_rms_last")
                         if has_scan_layer(net) else ())
+                     + (("conv_positions",) if has_conv_layer(net) else ())
                      + (("head_columns",) if reads_by_column(net) else ())
                      if routed else
                      ("valid_frac", "loop_block_applications",

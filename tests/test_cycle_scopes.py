@@ -38,7 +38,11 @@ grad step, and `ops/sum_tree.py`'s two nested in the first and the last.
     `dist`, `apex_dpg`, `glm_tiny_q`, `ouro_tiny_q` and
     `kimi_linear_tiny_q` pass UNCHANGED, which is the proof that the
     other seven cells run the parent's program (UNMOVED_BY_ISSUE_49
-    names them in the hash test's message);
+    names them in the hash test's message). ISSUE 50 added
+    `lfm2_tiny_q` and moved none of the ten: `kimi_linear_tiny_q`'s
+    filter is models/short_conv.py's now, and the two column-reading
+    nets' read is one of models/q_head.py's two (the other reads a
+    head that is the embedding);
 (c) no endpoint bypasses a scope: `train_step`, `train_step_k`,
     `sample_k` + `learn_k` and the prefetching `train_many` open the
     same names, on one chip and on the mesh.
@@ -103,6 +107,12 @@ PROGRAMS = {
     # (moved by ISSUE 47 and by nothing else: ops/chunked_delta_rule.py)
     "kimi_linear_tiny_q": ("kimi_linear_tiny_q", ["replay.capacity=64"], 2,
                            "f2232d0ad15b7a42", "c5d7cc95256d237b"),
+    # the family's sixth net, the first with a convolution mixer and a
+    # head that is its embedding, pinned at the PR that added it (ISSUE
+    # 50) beside the ten that must not move: Kimi's through the shared
+    # filter, Trinity's and SmallThinker's through models/q_head.py
+    "lfm2_tiny_q": ("lfm2_tiny_q", ["replay.capacity=64"], 2,
+                    "2072e7df22e882b4", "f11bd9b1f01519e7"),
     "dist": ("pong", ["parallel.dp=2", "parallel.tp=1",
                       "replay.capacity=4096", "replay.min_fill=512"], 8,
              "8edfe2412a4bc64f", "6668f8be4d7f2de8"),
@@ -117,7 +127,7 @@ PROGRAMS = {
 UNMOVED_BY_ISSUE_49 = ("pong", "r2d2", "dist", "apex_dpg", "glm_tiny_q",
                        "ouro_tiny_q", "kimi_linear_tiny_q")
 RELABELS = ("glm_tiny_q", "trinity_tiny_q", "smallthinker_tiny_q",
-            "ouro_tiny_q", "kimi_linear_tiny_q", "apex_dpg")
+            "ouro_tiny_q", "kimi_linear_tiny_q", "lfm2_tiny_q", "apex_dpg")
 QUIET = ["actors.num_actors=0", "eval_episodes=0", "eval_every_steps=0"]
 
 
@@ -195,6 +205,7 @@ def test_the_program_is_the_parents_to_the_byte(case):
         PROGRAMS[case][3], (
             "ISSUE 49 moved the two column-reading nets and must move no "
             "other program: this pin is PR 47's" if case in UNMOVED_BY_ISSUE_49
+            else "pinned by ISSUE 50, which added it" if case == "lfm2_tiny_q"
             else "moved by ISSUE 49 (the head by column), re-pinned there")
 
 

@@ -223,34 +223,87 @@ def test_mla_layers_are_position_free_and_kda_layers_carry_order():
     assert not np.allclose(state[0][0], other[0][0], atol=1e-4)
 
 
-@pytest.mark.parametrize("ways", [2, 8])
-def test_the_shares_add_up(ways):
-    """The routed parts that the shares compute, with the shared expert
-    counted once, add up to what the uncut reference gives for the
-    whole layer (sigmoid scores, top-2 of score + bias, normalised x
-    2.446: GLM's arithmetic at this net's numbers)."""
+def _kimi_whole_layer():
+    """-> (an expert layer's parameters of the uncut net, the net of a
+    share, what the uncut reference gives for x, the shared expert's
+    part of it, hidden, experts a token): sigmoid scores, top-2 of
+    score + bias, normalised x 2.446 - GLM's arithmetic at this net's
+    numbers - with one shared expert."""
     whole = tiny(shards=1)
     _, params = net_and_params(whole)
-    layer = params["layers"][1]
-    x = jax.random.normal(jax.random.PRNGKey(3), (B, L, 48))
     sizes = mapper.sizes(whole.network.kimi_linear)
     ref_layer = mapper.reference_layer(params, 1)
-    want, _, _ = afmoe_ref.expert_layer(ref_layer, x, sizes, None,
-                                        lambda a: a)
-    shared = glm_ref.swiglu(x, ref_layer["shared"], lambda a: a)
+
+    def want(x):
+        out, _, _ = afmoe_ref.expert_layer(ref_layer, x, sizes, None,
+                                           lambda a: a)
+        return out, glm_ref.swiglu(x, ref_layer["shared"], lambda a: a)
+
+    def share(ways, index):
+        net, _ = net_and_params(tiny(shards=ways, index=index))
+        assert net.share.scale == 2.446 and net.share.norm_topk
+        return net
+
+    return (params["layers"][1]["mlp"], share, want, 48,
+            whole.network.kimi_linear.num_experts_per_token)
+
+
+def _lfm2_whole_layer():
+    """The decoder family's sixth net (models/lfm2_moe_q.py): sigmoid
+    scores, top-2 of score + bias, normalised x 1, NO shared expert."""
+    from ape_x_dqn_tpu.models.lfm2_moe_q import Lfm2MoeQNet
+    from benchmarks.harness import lfm2_params
+    from benchmarks.reference import lfm2_moe_q as lfm2_ref
+
+    lf = get_config("lfm2_tiny_q").network.lfm2_moe
+    params = Lfm2MoeQNet(lf, "float32").init(jax.random.PRNGKey(0))
+    # times 8: at hidden 32 a matrix of normal(0, 0.02) leaves an
+    # expert's output a thousandth of its input
+    params["layers"] = jax.tree.map(
+        lambda a: 8.0 * a if a.ndim >= 2 else a, params["layers"])
+    sizes = lfm2_params.sizes(lf)
+    ref_layer = lfm2_params.reference_layer(params, 1)
+
+    def want(x):
+        out, _, _ = lfm2_ref.expert_layer(ref_layer, x, sizes, None,
+                                          lambda a: a)
+        return out, jnp.zeros_like(out)
+
+    def share(ways, index):
+        net = Lfm2MoeQNet(dataclasses.replace(
+            lf, shard_count=ways, shard_index=index), "float32")
+        assert net.share.scale == 1.0 and net.share.norm_topk
+        return net
+
+    assert "shared_experts" not in params["layers"][1]["mlp"]
+    return (params["layers"][1]["mlp"], share, want, lf.hidden_size,
+            lf.num_experts_per_tok)
+
+
+@pytest.mark.parametrize("ways", [2, 8])
+@pytest.mark.parametrize("whole_layer", [_kimi_whole_layer,
+                                         _lfm2_whole_layer],
+                         ids=["kimi_linear", "lfm2_moe"])
+def test_the_shares_add_up(whole_layer, ways):
+    """The routed parts that the shares compute, with the shared expert
+    (where the model has one) counted once, add up to what the uncut
+    reference gives for the whole layer - for both nets that came with
+    a share after the family's layer was made general."""
+    layer, share, want_of, hidden, top_k = whole_layer()
+    x = jax.random.normal(jax.random.PRNGKey(3), (B, L, hidden))
+    want, shared = want_of(x)
     total, rows = jnp.zeros_like(want), 0
     for index in range(ways):
-        net, _ = net_and_params(tiny(shards=ways, index=index))
+        net = share(ways, index)
         held = net.experts_held
-        mlp = dict(layer["mlp"])
+        mlp = dict(layer)
         mlp["experts"] = {k: v[index * held:(index + 1) * held]
-                          for k, v in layer["mlp"]["experts"].items()}
+                          for k, v in layer["experts"].items()}
         out, n, _ = expert_ffn(mlp, x, jnp.float32, net.share)
         total = total + (out - shared)
         rows += int(n.sum())
     np.testing.assert_allclose(total + shared, want, atol=1e-5)
-    assert rows == B * L * whole.network.kimi_linear.num_experts_per_token
-    assert net.share.scale == 2.446 and net.share.norm_topk
+    assert rows == B * L * top_k
 
 
 @pytest.mark.parametrize("departure", [

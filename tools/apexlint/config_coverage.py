@@ -13,12 +13,12 @@ Both directions of config/doc drift:
    declaration line.
 
 2. Every `replay.` / `comm.` / `obs.` / `actors.` / `serving.` /
-   `glm.` / `afmoe.` / `smallthinker.` / `ouro.` / `kimi_linear.` (as
-   in `network.glm.shard_count`) knob
+   `glm.` / `afmoe.` / `smallthinker.` / `ouro.` / `kimi_linear.` /
+   `lfm2_moe.` (as in `network.glm.shard_count`) knob
    mentioned in README must exist as a field on the matching dataclass
    (ReplayConfig / CommConfig / ObsConfig / ActorConfig /
    ServingConfig / GlmMoeConfig / AfmoeConfig / SmallThinkerConfig /
-   OuroConfig / KimiLinearConfig).
+   OuroConfig / KimiLinearConfig / Lfm2MoeConfig).
    Mentions
    that name a package MODULE instead of a knob (`obs.health`,
    `obs.report` — `ape_x_dqn_tpu/obs/health.py` exists) are skipped.
@@ -48,7 +48,8 @@ PREFIX_TO_CLASS = {"replay": "ReplayConfig", "comm": "CommConfig",
                    "glm": "GlmMoeConfig", "afmoe": "AfmoeConfig",
                    "smallthinker": "SmallThinkerConfig",
                    "ouro": "OuroConfig",
-                   "kimi_linear": "KimiLinearConfig"}
+                   "kimi_linear": "KimiLinearConfig",
+                   "lfm2_moe": "Lfm2MoeConfig"}
 KNOB_RE = re.compile(
     r"\b(" + "|".join(PREFIX_TO_CLASS) + r")"
     r"\.([a-z_][a-z0-9_]*)")
