@@ -51,9 +51,66 @@ TPU-first design notes:
   and the time falls level by level all the way. The one tie places
   the constant: 16 indices walk level 19 in the time its 2^19 nodes
   are re-added, 32,768 nodes an index.
-- Sampling is a vectorized prefix-sum descent: log2(capacity) iterations
-  of a batched gather — no data-dependent control flow, fully unrolled
-  by XLA (static trip count).
+- Sampling is a vectorized prefix-sum descent: log2(capacity) rounds
+  of "read every draw's left child, go right where u >= left", each
+  waiting on the one before; no data-dependent control flow, unrolled
+  (static trip count). Read by index a round is a gather of n random
+  rows whatever the level's width: 14.5 us for 2,048 draws, and level 5
+  has 32 nodes (ISSUE 51; `sample`, `dense_descent_levels`). At level l
+  the left children are the even entries of tree[2^(l+1) : 2^(l+2)], a
+  static slice of the contiguous prefix, so down to level L-1 the read
+  is a SELECT over the level's own left children: compare the draw's
+  position in the level against a row of 2^l positions, keep the one
+  that matches, sum. One term is not zero, so `left` is the gather's
+  float32 and u, idx, the leaf, the clamp and probs are the
+  all-indexed descent's bit for bit (tests/test_sum_tree_dense_top.py
+  keeps that descent; on the chip every row below compared equal to
+  L = 0 as 32-bit words, leaves and probs). Below level L-1 the walk
+  is `tree[2 * idx]` as it was.
+- What the descent costs by form and L (my chip runs, PR 51: one v5e,
+  64 descents a program with the tree changing between them, us a call,
+  the least of 7; 2^20 tree, 2,048 draws in 4 chunks; L = 0 is 333.8):
+  * where the even entries come from decides the high end. One strided
+    slice `tree[:2^(L+1):2]` a call "gathers again", as the update's
+    did: 204 at L = 9, 190 at 11, 192 at 12, 210 at 13 (a slice per
+    level: 188 / 194 / 214). The update's own pair sums over the prefix
+    with its right children zeroed (`_left_children`: x + 0.0 is x) are
+    vectorised and cost nothing that shows: 203 / 177 / 165 / **155**
+    at L = 9 / 11 / 12 / 13, 152 at 14, 160 at 15, 194 at 16, 269 at
+    17. `reshape(-1, 2)[:, 0]` ties to L = 12 and pays for its padded
+    minor dimension after (161 at 13, 167 at 14, 389 at 17);
+  * which operand becomes the column (XLA relayouts it) decides the
+    rest. Draws in the lanes, nodes down the sublanes (the level is
+    the column): 155.5 / 154.5 / 159 at L = 13 / 14 / 15, but 62.6 /
+    69.5 / 85 for 512 draws and 36 / 44 for 256. Nodes in the lanes
+    (the draws are the column): 158 / 160 / 172, and 58.0 / 58.9 / 61
+    for 512, 32 / 29 for 256. The SHORTER operand as the column
+    (`_select`): 155.0 / 153.8 / 168, 56.6 / 57.9 / 61.5, 31.0 / 29.8 -
+    the best or a tie in every cell of the table; the longer one as
+    the column is the worst in every cell;
+  * a one-hot [n, 2^l] bfloat16 times the level as three bfloat16
+    pieces (exact: they hold a float32), or times the float32 level at
+    `Precision.HIGHEST`, from 256 nodes up: 159 / 161 / 177 at L = 13 /
+    14 / 15, never ahead of the select, and an inf in the tree would
+    reach every draw as a NaN.
+- Where the constant was placed: a level costs n * 2^l lane-operations
+  dense and n row fetches indexed, so the crossover in l hardly
+  depends on n, and it is the one tie again. In the probe, (2^20,
+  2,048): level 12 (4,096 nodes) still saves 10 us, level 13 saves 1.2,
+  level 14 costs 14; (2^20, 512), L = 0 / 12 / 13 / 14 / 15: 150.9 /
+  59.2 / 56.6 / 57.9 / 61.5; (2^14, 256): 46.2 / 30.5 / 31.0 / 29.8 (=
+  every level); (2^16, 128) and (4,096, 128): 35.8 -> 28.5 and 31.0 ->
+  25.3, flat from L = 10. Level 13 is the tie, and the cell broke it
+  (`pong_offline`, samples/s on one seed, L = 13 / 14 / 15 / 16:
+  515,456 / **516,639** / 515,552 / 510,753 against the indexed walk's
+  493,821, where two runs of one tree differ by 8; traced, a gather is
+  14.71 us, the selects of levels 10 / 11 / 12 / 13 are 1.30 / 3.64 /
+  6.05 / 13.15, and the fusion that ends the walk gave back 7.6 more
+  when level 13's gather left its side): 8,192 nodes a draw, fourteen
+  levels. Under a row of lanes of draws nothing wins: (2^16, 16) reads
+  25 at L = 0, 27-29 up to L = 9 and 86 at 13, (2^11, 2) 23 and 24-26;
+  those call sites (the decoder family's, 1-16 sequences a step) keep
+  the indexed walk and their lowered programs to the byte.
 """
 
 from __future__ import annotations
@@ -114,6 +171,28 @@ def dense_levels(capacity: int, n: int) -> int:
 
 
 LANES = 128  # a TPU vector register's minor dimension
+
+
+# How many nodes of a level the descent selects among, for every draw,
+# in the time it takes to fetch ONE draw's left child by index. Measured,
+# not derived (one v5e, my chip runs, PR 51; the module docstring has
+# the table: the probe's tie at level 13, which `pong_offline` broke).
+# A select costs n * 2^l lane-operations and a gather n row fetches, so
+# the crossover hardly depends on n.
+DENSE_NODES_PER_DRAW = 1 << 13
+
+
+def dense_descent_levels(capacity: int, n: int) -> int:
+    """How many levels from the root down `sample` reads densely for n
+    draws: levels 0 .. L-1, whose left children are the even entries of
+    the contiguous prefix tree[: 2^(L+1)]. Level l is dense when it has
+    no more than DENSE_NODES_PER_DRAW nodes; under a row of lanes of
+    draws an op costs its launch either way and the walk stays indexed.
+    From the shapes alone, at trace time, as `dense_levels`."""
+    if n < LANES:
+        return 0
+    depth = capacity.bit_length() - 1
+    return min(depth, DENSE_NODES_PER_DRAW.bit_length())
 
 
 def _pair_sums(level: jax.Array) -> jax.Array:
@@ -184,6 +263,29 @@ def chunk_major(x: jax.Array, chunks: int) -> jax.Array:
                      *x.shape[1:]).swapaxes(0, 1).reshape(x.shape)
 
 
+def _left_children(tree: jax.Array, dense: int) -> jax.Array:
+    """-> [2^dense], out[k] = tree[2k]: the left children of levels
+    0 .. dense-1, level l's at out[2^l : 2^(l+1)]. The update's pair
+    sums over the prefix with its right children zeroed: x + 0.0 is x,
+    and a strided slice gathers (module docstring)."""
+    top = tree[:2 << dense]
+    is_left = (jnp.arange(2 << dense, dtype=jnp.int32) & 1) == 0
+    return _pair_sums(jnp.where(is_left, top, 0.0))
+
+
+def _select(level: jax.Array, pos: jax.Array) -> jax.Array:
+    """level[pos] ([n] from [m]) as a compare, a select and a sum over
+    the level's own nodes: one term of a sum is not zero, so the sum is
+    that term, exactly. The shorter of the two operands becomes the
+    column (its relayout is the form's own cost: module docstring)."""
+    nodes = jnp.arange(level.shape[0], dtype=jnp.int32)
+    if level.shape[0] < pos.shape[0]:
+        return jnp.sum(jnp.where(nodes[:, None] == pos[None, :],
+                                 level[:, None], 0.0), axis=0)
+    return jnp.sum(jnp.where(pos[:, None] == nodes[None, :],
+                             level[None, :], 0.0), axis=1)
+
+
 @jax.named_scope(DESCENT_SCOPE)
 def sample(tree: jax.Array, rng: jax.Array, batch: int,
            size: jax.Array | None = None,
@@ -211,6 +313,11 @@ def sample(tree: jax.Array, rng: jax.Array, batch: int,
     deterministically return the rightmost leaf. Probs are re-gathered
     after clamping so IS weights always describe the leaf actually
     returned.
+
+    The top `dense_descent_levels` levels read a draw's left child as a
+    select over the level's own nodes, the levels below by index: the
+    same float32 either way, so the draw is the all-indexed descent's
+    bit for bit (module docstring).
     """
     cap = capacity_of(tree)
     depth = cap.bit_length() - 1
@@ -219,8 +326,14 @@ def sample(tree: jax.Array, rng: jax.Array, batch: int,
          + jax.random.uniform(rng, (batch,))) / batch * tot
     u = chunk_major(u, chunks)
     idx = jnp.ones(batch, jnp.int32)
-    for _ in range(depth):
-        left = tree[2 * idx]
+    dense = dense_descent_levels(cap, batch)
+    if dense:
+        lefts = _left_children(tree, dense)
+    for level in range(depth):
+        if level < dense:
+            left = _select(lefts[1 << level:2 << level], idx - (1 << level))
+        else:
+            left = tree[2 * idx]
         go_right = u >= left
         u = jnp.where(go_right, u - left, u)
         idx = 2 * idx + go_right.astype(jnp.int32)

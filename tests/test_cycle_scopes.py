@@ -36,13 +36,22 @@ grad step, and `ops/sum_tree.py`'s two nested in the first and the last.
     (models/q_head.py, ops/losses.column_read), so those two hashes are
     re-pinned from that PR's tree, both columns; `pong`, `r2d2`,
     `dist`, `apex_dpg`, `glm_tiny_q`, `ouro_tiny_q` and
-    `kimi_linear_tiny_q` pass UNCHANGED, which is the proof that the
-    other seven cells run the parent's program (UNMOVED_BY_ISSUE_49
-    names them in the hash test's message). ISSUE 50 added
+    `kimi_linear_tiny_q` passed UNCHANGED, which was the proof that
+    the other seven cells ran the parent's program. ISSUE 50 added
     `lfm2_tiny_q` and moved none of the ten: `kimi_linear_tiny_q`'s
     filter is models/short_conv.py's now, and the two column-reading
     nets' read is one of models/q_head.py's two (the other reads a
-    head that is the embedding);
+    head that is the embedding). ISSUE 51 moved `pong`, `r2d2`, `dist`
+    and `apex_dpg`, by design, and nothing else: `sum_tree.sample`
+    reads the top of the tree densely where a draw is a row of lanes
+    or more (`dense_descent_levels`), so those four first hashes are
+    re-pinned from that PR's tree; the six `*_tiny_q` programs draw 4
+    sequences a step, keep the indexed walk and pass UNCHANGED
+    (UNMOVED_BY_ISSUE_51), which is the proof that the six decoder
+    cells run the parent's program. The dense levels' ops carry
+    `sum_tree.descent` in their name stacks, so `replay.sample_share`
+    keeps seeing them, and with BOTH rules held at 0 every program is
+    still the parent's to the byte (the second hashes, untouched);
 (c) no endpoint bypasses a scope: `train_step`, `train_step_k`,
     `sample_k` + `learn_k` and the prefetching `train_many` open the
     same names, on one chip and on the mesh.
@@ -76,11 +85,15 @@ from ape_x_dqn_tpu.runtime.train import apply_overrides
 # RELABELS: the family's `make_batch` renames the items' fields and the
 # preset has K = 1, so `cycle.batch` is opened around no op
 PROGRAMS = {
+    # moved by ISSUE 51, with `r2d2`, `dist` and `apex_dpg` and nothing
+    # else: 2,048 / 256 / 1,024 a shard / 256 draws a cycle read the top
+    # of the tree densely (PR 50's first hashes: ef63e79f3fa20d68,
+    # 6c63d1b14997ec19, 8edfe2412a4bc64f, 836445fb85177e4e)
     "pong": ("pong", ["replay.capacity=4096", "replay.min_fill=512"], 8,
-             "ef63e79f3fa20d68", "fa002ec06af372f3"),
+             "e75393e30b36dc88", "fa002ec06af372f3"),
     "r2d2": ("r2d2", ["parallel.dp=1", "parallel.tp=1",
                       "replay.capacity=64", "replay.min_fill=8"], 8,
-             "6c63d1b14997ec19", "96e219598457572d"),
+             "1b798c4e84fed946", "96e219598457572d"),
     "glm_tiny_q": ("glm_tiny_q", ["replay.capacity=64"], 2,
                    "a88f0e52e73150b6", "dfb4d0171f649268"),
     # moved by ISSUE 49, with `smallthinker_tiny_q` and nothing else: the
@@ -115,17 +128,15 @@ PROGRAMS = {
                     "2072e7df22e882b4", "f11bd9b1f01519e7"),
     "dist": ("pong", ["parallel.dp=2", "parallel.tp=1",
                       "replay.capacity=4096", "replay.min_fill=512"], 8,
-             "8edfe2412a4bc64f", "6668f8be4d7f2de8"),
+             "f0a2402ba816b811", "6668f8be4d7f2de8"),
     "apex_dpg": ("apex_dpg", ["replay.capacity=4096",
                               "replay.min_fill=512"], 8,
-                 "836445fb85177e4e", "a376acdbc487b640"),
+                 "e1c130ce8b3309f6", "a376acdbc487b640"),
 }
-# the programs ISSUE 49 must not move: the dense read is
-# `make_r2d2_loss`'s body as it stood, and the three decoder nets that
-# do not offer `head_at` keep it (their cells' checks cannot carry the
-# change yet: ROADMAP S5.9)
-UNMOVED_BY_ISSUE_49 = ("pong", "r2d2", "dist", "apex_dpg", "glm_tiny_q",
-                       "ouro_tiny_q", "kimi_linear_tiny_q")
+# the programs ISSUE 51 must not move: a draw of under a row of lanes
+# (these presets draw 4 sequences a step) keeps the indexed descent
+UNMOVED_BY_ISSUE_51 = ("glm_tiny_q", "trinity_tiny_q", "smallthinker_tiny_q",
+                       "ouro_tiny_q", "kimi_linear_tiny_q", "lfm2_tiny_q")
 RELABELS = ("glm_tiny_q", "trinity_tiny_q", "smallthinker_tiny_q",
             "ouro_tiny_q", "kimi_linear_tiny_q", "lfm2_tiny_q", "apex_dpg")
 QUIET = ["actors.num_actors=0", "eval_episodes=0", "eval_every_steps=0"]
@@ -135,13 +146,16 @@ QUIET = ["actors.num_actors=0", "eval_episodes=0", "eval_every_steps=0"]
 def _lowered(case: str, dense_top: bool = True) -> tuple[str, str]:
     """-> `train_many`'s lowered text (without, with debug info) of the
     learner `ApexDriver` builds for the case; without `dense_top` every
-    `sum_tree.update` in it walks all its levels by index."""
+    `sum_tree.update` and every `sum_tree.sample` in it walks all its
+    levels by index."""
     from ape_x_dqn_tpu.runtime.driver import ApexDriver
 
     preset, overrides, n = PROGRAMS[case][:3]
     with pytest.MonkeyPatch.context() as patch:
         if not dense_top:
             patch.setattr(sum_tree, "dense_levels", lambda capacity, n: 0)
+            patch.setattr(sum_tree, "dense_descent_levels",
+                          lambda capacity, n: 0)
         driver = ApexDriver(apply_overrides(get_config(preset),
                                             overrides + QUIET))
         try:
@@ -192,8 +206,15 @@ def test_train_many_names_every_part_of_the_cycle(case):
     # dynamic_update_slice, and a one-window scatter under `vmap`)
     assert {"reduce_window_sum", "concatenate"} <= {
         s.rsplit("/", 1)[-1] for s in stacks if sum_tree.UPDATE_SCOPE in s}
+    # (the descent lays its left children out with the same pair sums)
     assert not [s for s in stacks if s.endswith("/reduce_window_sum")
-                and sum_tree.UPDATE_SCOPE not in s]
+                and sum_tree.UPDATE_SCOPE not in s
+                and sum_tree.DESCENT_SCOPE not in s]
+    # the descent's dense levels are the descent's: a select summed over
+    # a level's nodes, where the draw is a row of lanes or more
+    descent = {s.rsplit("/", 1)[-1] for s in stacks
+               if sum_tree.DESCENT_SCOPE in s}
+    assert ("reduce_sum" in descent) == (case not in UNMOVED_BY_ISSUE_51)
     _assert_tree_passes_nest(debug)
 
 
@@ -203,10 +224,11 @@ def test_the_program_is_the_parents_to_the_byte(case):
     assert not any(s in text for s in CYCLE_SCOPES)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PROGRAMS[case][3], (
-            "ISSUE 49 moved the two column-reading nets and must move no "
-            "other program: this pin is PR 47's" if case in UNMOVED_BY_ISSUE_49
-            else "pinned by ISSUE 50, which added it" if case == "lfm2_tiny_q"
-            else "moved by ISSUE 49 (the head by column), re-pinned there")
+            "ISSUE 51 moved the four programs whose draw is a row of lanes "
+            "or more and must move no other: this pin is PR 50's"
+            if case in UNMOVED_BY_ISSUE_51
+            else "moved by ISSUE 51 (the descent's dense top), re-pinned "
+                 "there")
 
 
 def test_the_delta_rules_scan_keeps_its_three_scopes():
@@ -218,8 +240,10 @@ def test_the_delta_rules_scan_keeps_its_three_scopes():
 
 
 @pytest.mark.parametrize("case", list(PROGRAMS))
-def test_only_the_trees_update_moved_since_pr35(case):
-    # (and, in `r2d2`, the packed store's rows since ISSUE 42)
+def test_only_the_trees_two_passes_moved_since_pr35(case):
+    # (and, in `r2d2`, the packed store's rows since ISSUE 42): with no
+    # dense level in the update (ISSUE 36) nor in the descent (ISSUE 51)
+    # every program is still its parent's to the byte
     text, _ = _lowered(case, dense_top=False)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PROGRAMS[case][4]

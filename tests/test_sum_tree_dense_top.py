@@ -3,6 +3,11 @@
 contiguous prefix above them as pairwise sums. The tree it leaves is the
 all-indexed level walk's BIT FOR BIT; `level_walk` below is that walk as
 it stood before the change, kept here as the reference.
+
+`ops/sum_tree.sample` reads the top of the tree densely too (ISSUE 51):
+a select over a level's own left children down to level
+`dense_descent_levels`, by index below. The draw is the all-indexed
+descent's BIT FOR BIT; `indexed_descent` below is that descent.
 """
 
 import re
@@ -12,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ape_x_dqn_tpu.configs import get_config
 from ape_x_dqn_tpu.ops import sum_tree
 from ape_x_dqn_tpu.replay import prioritized
 from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
@@ -19,6 +25,7 @@ from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay, ring_finish
 
 CAPACITIES = [8, 64, 4096, 2 ** 16]
 BATCHES = [1, 2, 16, 512, 2048]
+DP = 4
 
 
 def level_walk(tree, leaf_idx, priorities):
@@ -124,9 +131,172 @@ def test_the_splits_of_the_deployments_call_sites(site, dense):
     assert sum_tree.dense_levels(*site) == dense
 
 
-# -- through the replays --------------------------------------------------
+# -- the read side: the descent (ISSUE 51) -----------------------------------
 
-DP = 4
+def indexed_descent(tree, rng, batch, size=None, chunks=1):
+    """The parent commit's `sample`: log2(capacity) gathers of every
+    draw's left child, each waiting on the one before."""
+    cap = sum_tree.capacity_of(tree)
+    tot = tree[1]
+    u = (jnp.arange(batch, dtype=jnp.float32)
+         + jax.random.uniform(rng, (batch,))) / batch * tot
+    u = sum_tree.chunk_major(u, chunks)
+    idx = jnp.ones(batch, jnp.int32)
+    for _ in range(cap.bit_length() - 1):
+        left = tree[2 * idx]
+        go_right = u >= left
+        u = jnp.where(go_right, u - left, u)
+        idx = 2 * idx + go_right.astype(jnp.int32)
+    leaf = idx - cap
+    if size is not None:
+        leaf = jnp.minimum(leaf, jnp.maximum(size, 1) - 1)
+    return leaf, tree[cap + leaf] / jnp.maximum(tot, 1e-12)
+
+
+def _tree_of(kind: str, cap: int, n: int):
+    """-> (tree, size): `size` None where the whole ring is live."""
+    if kind == "all_zero":
+        return sum_tree.init(cap), jnp.int32(0)
+    if kind == "one_leaf":  # every draw has to find it, from either side
+        return sum_tree.update(sum_tree.init(cap),
+                               jnp.asarray([cap // 3], jnp.int32),
+                               jnp.asarray([7.25], jnp.float32)), None
+    if kind == "partly_filled":  # the clamp by `size` bites
+        live = max(2, cap // 5)
+        rng = np.random.default_rng(cap + n)
+        tree = sum_tree.update(
+            sum_tree.init(cap), jnp.arange(live, dtype=jnp.int32),
+            jnp.asarray(10.0 ** rng.uniform(-3, 3, live), jnp.float32))
+        return tree, jnp.int32(live - 1)
+    tree = sum_tree.init(cap)  # "duplicates": `_batches`' updates
+    for idx, pri in _batches(cap, max(n, cap // 8), rounds=4):
+        tree = sum_tree.update(tree, idx, pri)
+    return tree, None
+
+
+# (capacity, draws, chunks): pong's macro-step, a shard's of atari57
+# dp=4, r2d2's, one with an indexed level left, one with every level dense
+DRAWS = [(2 ** 20, 2048, 4), (2 ** 20, 512, 4), (2 ** 14, 256, 4),
+         (4096, 128, 1), (64, 128, 1)]
+TREES = ["duplicates", "partly_filled", "all_zero", "one_leaf"]
+
+
+def _assert_same_draw(got, want):
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("kind", TREES)
+@pytest.mark.parametrize("cap, n, chunks", DRAWS)
+def test_the_draw_is_the_indexed_descents_bit_for_bit(cap, n, chunks, kind):
+    assert sum_tree.dense_descent_levels(cap, n) > 0
+    tree, size = _tree_of(kind, cap, n)
+    new = jax.jit(sum_tree.sample, static_argnums=(2, 4))
+    old = jax.jit(indexed_descent, static_argnums=(2, 4))
+    for seed in range(3):
+        key = jax.random.key(seed)
+        got, want = new(tree, key, n, size, chunks), \
+            old(tree, key, n, size, chunks)
+        _assert_same_draw(got, want)
+    leaf = np.asarray(got[0])
+    assert leaf.min() >= 0 and leaf.max() < cap
+    if kind == "one_leaf":
+        assert (leaf == cap // 3).all() and (np.asarray(got[1]) == 1).all()
+    if kind == "all_zero":
+        assert (leaf == 0).all()
+
+
+@pytest.mark.parametrize("dense", range(13))
+def test_every_split_of_one_descent_is_one_draw(dense, monkeypatch):
+    # every level dense down to none: the splits a shape does not get by
+    # the rule are held here by hand, across the width (LANES) where the
+    # left children's pair sums change their view
+    cap, n = 4096, 256
+    monkeypatch.setattr(sum_tree, "dense_descent_levels", lambda c, m: dense)
+    tree, size = _tree_of("partly_filled", cap, n)
+    for t, s in ((tree, size), _tree_of("duplicates", cap, n)):
+        key = jax.random.key(dense)
+        _assert_same_draw(sum_tree.sample(t, key, n, s, 4),
+                          indexed_descent(t, key, n, s, 4))
+
+
+@pytest.mark.parametrize("cap", CAPACITIES + [2 ** 14, 2 ** 20])
+def test_dense_descent_levels_follows_the_shapes(cap):
+    depth = cap.bit_length() - 1
+    got = [sum_tree.dense_descent_levels(cap, n) for n in range(0, 4200)]
+    assert not any(got[:sum_tree.LANES]), "under a row of lanes: the walk"
+    # from a row of lanes up one split, whatever the batch: a select and
+    # a gather both cost in proportion to the draws
+    assert set(got[sum_tree.LANES:]) == {got[-1]}
+    assert 0 < got[-1] <= depth
+    assert got[-1] == depth or \
+        (1 << got[-1]) > sum_tree.DENSE_NODES_PER_DRAW >= (1 << (got[-1] - 1))
+
+
+@pytest.mark.parametrize("site, dense", [
+    ((2 ** 20, 2048), 14),   # pong, pong_live: K*B draws a macro-step
+    ((2 ** 20, 512), 14),    # atari57 dp=4: a shard's draws
+    ((2 ** 14, 256), 14),    # r2d2: every level
+    ((2 ** 20, 256), 14),    # apex_dpg
+    ((2 ** 16, 16), 0),      # glm47_flash
+    ((4096, 2), 0),          # trinity_mini
+    ((2 ** 11, 1), 0),       # smallthinker
+    ((2 ** 13, 1), 0),       # ouro, kimi_linear
+    ((2 ** 11, 2), 0),       # lfm2_moe
+    ((4096, 2048), 12),      # tests/test_cycle_scopes.py's `pong`
+    ((64, 128), 6),          # every level
+])
+def test_the_dense_levels_of_the_deployments_draws(site, dense):
+    # the mechanism engages by shape at trace time, so this table is its
+    # counter: the facts PERF.md quotes
+    assert sum_tree.dense_descent_levels(*site) == dense
+
+
+@pytest.mark.parametrize("preset", [
+    "glm47_flash_q", "trinity_mini_q", "smallthinker_21b_q", "ouro_2p6b_q",
+    "kimi_linear_48b_q", "lfm2_24b_q", "glm_tiny_q", "trinity_tiny_q",
+    "smallthinker_tiny_q", "ouro_tiny_q", "kimi_linear_tiny_q",
+    "lfm2_tiny_q"])
+def test_a_decoder_presets_draw_keeps_the_indexed_walk(preset):
+    cfg = get_config(preset)
+    n = cfg.learner.batch_size * cfg.learner.sample_chunk
+    assert n < sum_tree.LANES
+    for cap in (64, cfg.replay.capacity, 2 ** 20):
+        assert sum_tree.dense_descent_levels(cap, n) == 0
+
+
+@pytest.mark.parametrize("dense", [None, 0, 5], ids=["shipped", "0", "5"])
+@pytest.mark.parametrize("form", ["directed", "lockstep"])
+def test_the_descent_under_both_vmaps(form, dense, monkeypatch):
+    # the mesh draws as `vmap(shard_sample)(replay_state, keys)`:
+    # "directed" is that; "lockstep" shares one key between the shards
+    cap, n, chunks = 4096, 128, 4
+    if dense is not None:
+        monkeypatch.setattr(sum_tree, "dense_descent_levels",
+                            lambda c, m: dense)
+    trees, sizes = zip(*[
+        _tree_of("partly_filled", cap, n + shard) for shard in range(DP)])
+    trees, sizes = jnp.stack(trees), jnp.stack(sizes)
+    keys = jax.random.split(jax.random.key(3), DP)
+    rng_axis = 0 if form == "directed" else None
+    rng = keys if form == "directed" else keys[0]
+
+    def over_shards(fn):
+        return jax.vmap(lambda t, k, s: fn(t, k, n, s, chunks),
+                        in_axes=(0, rng_axis, 0))(trees, rng, sizes)
+
+    got, want = over_shards(sum_tree.sample), over_shards(indexed_descent)
+    assert got[0].shape == (DP, n)
+    _assert_same_draw(got, want)
+    for shard in range(DP):  # and each shard's is its own tree's
+        alone = indexed_descent(
+            trees[shard], keys[shard] if form == "directed" else keys[0],
+            n, sizes[shard], chunks)
+        _assert_same_draw([g[shard] for g in got], alone)
+
+
+# -- through the replays --------------------------------------------------
 
 
 @pytest.mark.parametrize("dense", [None, 5], ids=["shipped", "5-levels"])
@@ -227,22 +397,17 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled_for_v5e(fn, cap: int, n: int, chip):
+def _compiled(jitted, *args):
     """-> (gather/scatter fusions, ops the entry computation runs one
-    after another, HLO temp bytes) of `fn` compiled for a described v5e."""
+    after another, HLO temp bytes) of `jitted` compiled for the device
+    its arguments' shardings describe."""
     from jax.experimental.compilation_cache import compilation_cache
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        compiled = jax.jit(
-            lambda t, i, p: fn(t, i, p), donate_argnums=(0,)).lower(
-            arg((2 * cap,), jnp.float32), arg((n,), jnp.int32),
-            arg((n,), jnp.float32)).compile()
+        compiled = jitted.lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
@@ -254,6 +419,26 @@ def _compiled_for_v5e(fn, cap: int, n: int, chip):
     indexed = [line for line in ops if "kind=kCustom" in line]
     temp = compiled.memory_analysis().temp_size_in_bytes
     return len(indexed), len(ops), temp
+
+
+def _compiled_for_v5e(fn, cap: int, n: int, chip):
+    """`_compiled` of an update `fn` for a described v5e."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    return _compiled(
+        jax.jit(lambda t, i, p: fn(t, i, p), donate_argnums=(0,)),
+        arg((2 * cap,), jnp.float32), arg((n,), jnp.int32),
+        arg((n,), jnp.float32))
+
+
+def _draw_compiled_for_v5e(fn, cap: int, n: int, chip):
+    """`_compiled` of a descent `fn`, n draws in 4 chunks."""
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    return _compiled(
+        jax.jit(lambda t, k: fn(t, k, n, jnp.int32(cap), 4)),
+        jax.ShapeDtypeStruct((2 * cap,), jnp.float32, sharding=chip),
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=chip))
 
 
 def test_compiled_for_a_v5e_the_macro_step_walks_few_levels(one_chip):
@@ -269,3 +454,22 @@ def test_compiled_for_a_v5e_a_batch_of_two_costs_no_more_ops(one_chip):
     _, ops, _ = _compiled_for_v5e(sum_tree.update, 4096, 2, one_chip)
     _, before, _ = _compiled_for_v5e(level_walk, 4096, 2, one_chip)
     assert ops <= before
+
+
+def test_compiled_for_a_v5e_the_macro_steps_draw_gathers_seven_times(one_chip):
+    indexed, _, temp = _draw_compiled_for_v5e(sum_tree.sample, 2 ** 20,
+                                              2048, one_chip)
+    before, _, _ = _draw_compiled_for_v5e(indexed_descent, 2 ** 20, 2048,
+                                          one_chip)
+    assert before == 21       # 20 levels + the leaves' priorities
+    assert indexed == 7       # levels 14 .. 19 + the leaves' priorities
+    # a select is a fused compare-select-reduce: no [8192, 2048] float32
+    # (64 MiB) is ever an array
+    assert temp < 2 ** 20
+
+
+def test_compiled_for_a_v5e_a_draw_of_sixteen_is_the_indexed_descents(
+        one_chip):
+    now = _draw_compiled_for_v5e(sum_tree.sample, 2 ** 16, 16, one_chip)
+    before = _draw_compiled_for_v5e(indexed_descent, 2 ** 16, 16, one_chip)
+    assert now == before and now[0] == 17
