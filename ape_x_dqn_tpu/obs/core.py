@@ -67,6 +67,10 @@ class NullObs:
                **args: Any) -> None:
         pass
 
+    def lap(self, name: str, since: tuple | None = None,
+            **args: Any) -> None:
+        return None
+
     def stage_window(self, stage: str, steps: int = 1):
         return NULL_SPAN
 
@@ -285,6 +289,10 @@ class Obs:
     def record(self, name: str, t0: float, t1: float,
                **args: Any) -> None:
         self.tracer.record(name, t0, t1, **args)
+
+    def lap(self, name: str, since: tuple | None = None,
+            **args: Any) -> tuple:
+        return self.tracer.lap(name, since, **args)
 
     def mark(self, name: str, **args: Any) -> None:
         self.tracer.mark(name, **args)

@@ -24,6 +24,8 @@ import os
 import sys
 from typing import Any
 
+from ape_x_dqn_tpu.obs.trace import CPU_SUFFIX, PROCESS_CPU, THREAD_PREFIX
+
 # The canonical instrument table: one row per metric name the runtime
 # can emit, keyed by JSONL name with the registry's kind prefix
 # (hist/ gauge/ ctr/). apexlint's obs-names checker cross-references
@@ -420,8 +422,9 @@ def summarize(records: list[dict]) -> dict[str, Any]:
         except ValueError:
             continue
         multichip.setdefault(dp, {})[parts[2]] = v
-    spans = {k[len("span/"):]: v for k, v in latest.items()
-             if k.startswith("span/") and isinstance(v, dict)}
+    spans, span_cpu = _split_cpu(
+        {k[len("span/"):]: v for k, v in latest.items()
+         if k.startswith("span/") and isinstance(v, dict)})
     hists = {k[len("hist/"):]: v for k, v in latest.items()
              if k.startswith("hist/") and isinstance(v, dict)}
     gauges = {k[len("gauge/"):]: v for k, v in latest.items()
@@ -465,6 +468,7 @@ def summarize(records: list[dict]) -> dict[str, Any]:
             "avg_return": latest.get("avg_return"),
         },
         "spans": spans,
+        "span_cpu": span_cpu,
         "hists": hists,
         "gauges": gauges,
         "ctrs": ctrs,
@@ -485,10 +489,22 @@ def summarize(records: list[dict]) -> dict[str, Any]:
     }
 
 
-def _fmt_spans(spans: dict[str, dict]) -> list[str]:
-    lines = ["stage-time breakdown (host spans):",
+def _split_cpu(rows: dict[str, dict]) -> tuple[dict, dict]:
+    """The tracer's table as (wall rows, CPU rows): `<name>.cpu`,
+    `thread.<role>.cpu` and `process.cpu` are CPU seconds (obs/trace.py)
+    and belong in no sum of stage time."""
+    wall = {k: v for k, v in rows.items() if not k.endswith(CPU_SUFFIX)}
+    return wall, {k: v for k, v in rows.items() if k not in wall}
+
+
+def _fmt_spans(spans: dict[str, dict],
+               cpu: dict[str, dict] | None = None) -> list[str]:
+    cpu = cpu or {}
+    lines = ["stage-time breakdown (host spans; cpu_ms = mean CPU of "
+             "the span's own thread over the spans that stamped it, "
+             "mean_ms - cpu_ms = on no core):",
              f"  {'stage':<28} {'count':>8} {'total_s':>9} "
-             f"{'mean_ms':>9} {'max_ms':>9} {'share':>7}"]
+             f"{'mean_ms':>9} {'max_ms':>9} {'cpu_ms':>9} {'share':>7}"]
     grand = sum(s.get("total_s", 0.0) for s in spans.values()) or 1.0
     order = sorted(spans.items(),
                    key=lambda kv: -kv[1].get("total_s", 0.0))
@@ -497,10 +513,21 @@ def _fmt_spans(spans: dict[str, dict]) -> list[str]:
         total = float(s.get("total_s", 0.0))
         mean_ms = total / count * 1e3 if count else 0.0
         tag = " (mark)" if total == 0.0 and count else ""
+        c = cpu.get(name + CPU_SUFFIX, {})
+        stamped = int(c.get("count", 0))
+        cpu_ms = (f"{float(c['total_s']) / stamped * 1e3:>9.3f}"
+                  if stamped else f"{'-':>9}")
         lines.append(
             f"  {name:<28} {count:>8} {total:>9.3f} {mean_ms:>9.3f} "
-            f"{float(s.get('max_s', 0.0)) * 1e3:>9.3f} "
+            f"{float(s.get('max_s', 0.0)) * 1e3:>9.3f} {cpu_ms} "
             f"{total / grand:>6.1%}{tag}")
+    clocks = [(name, float(c.get("total_s", 0.0)))
+              for name, c in sorted(cpu.items())
+              if name == PROCESS_CPU or name.startswith(THREAD_PREFIX)]
+    if clocks:
+        lines.append("  CPU seconds so far: " + ", ".join(
+            f"{name[:-len(CPU_SUFFIX)]} {total:.3f}"
+            for name, total in clocks))
     return lines
 
 
@@ -1006,8 +1033,8 @@ def _fmt_peers(summary: dict[str, Any]) -> list[str]:
             ages = ", ".join(f"{name}={float(age):.1f}s"
                              for name, age in sorted(hb.items()))
             lines.append(f"    heartbeat ages: {ages}")
-        spans = {k: v for k, v in p.get("span", {}).items()
-                 if isinstance(v, dict)}
+        spans, _ = _split_cpu({k: v for k, v in p.get("span", {}).items()
+                               if isinstance(v, dict)})
         if spans:
             lines.append(f"    stage-time breakdown ({peer}):")
             grand = sum(s.get("total_s", 0.0)
@@ -1056,7 +1083,7 @@ def format_report(summary: dict[str, Any]) -> str:
         f"loss={_n(tp['loss'])} avg_return={_n(tp['avg_return'])}")
     if summary["spans"]:
         lines.append("")
-        lines.extend(_fmt_spans(summary["spans"]))
+        lines.extend(_fmt_spans(summary["spans"], summary["span_cpu"]))
     roofline_lines = _fmt_roofline(summary)
     if roofline_lines:
         lines.append("")

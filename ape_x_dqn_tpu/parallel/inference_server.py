@@ -44,7 +44,12 @@ once per batch dispatched with its predecessor unfetched, runs from
 that dispatch to the start of the predecessor's fetch, and
 count(`server.ahead`) / count(`server.batch`) is the share of batches
 the pipeline engaged for. `server.fetch` is now what is LEFT of the
-round trip after a successor's stack + dispatch.
+round trip after a successor's stack + dispatch. `server.period`
+(ISSUE 52) is the thread's whole time a batch answered — one loop
+iteration with a forward in flight, the dispatching and the replying
+iteration together otherwise — with `server.collect` and the four spans
+as its children; what it holds beyond them is the loop's own remainder
+(the stats lock, `on_server_batch`, the records themselves).
 
 `MultiPolicyInferenceServer` keeps a loop of its own (`_dispatch_loop`,
 strictly serial) and is not pipelined: its admission classes, shedding
@@ -378,9 +383,26 @@ class BatchedInferenceServer:
     def _serve_loop(self) -> None:
         """collect -> stack -> dispatch batch k+1, THEN fetch + scatter
         batch k (module docstring, "The serve loop")."""
-        collect = self._collect_traced if self._traced else self._collect
+        traced = self._traced
+        collect = self._collect_traced if traced else self._collect
         flight: _Flight | None = None  # dispatched, its reply still owed
+        # server.period (traced only): the serve thread's time a batch
+        # answered, as laps of the tracer (wall always, the thread's
+        # CPU clock when the name is due). One iteration while a
+        # forward is in flight (dispatch k+1, reply k); an iteration
+        # that only dispatched, because nothing was in flight, leaves
+        # its period open for the next one, which sweeps the queue and
+        # replies — so periods are batches answered. A period starts
+        # where the last one ended (after an empty poll, at the loop's
+        # top): they tile the thread's time but for its idle polls, and
+        # the five children tile a period but for the loop's own
+        # remainder
+        lap = self._obs.lap
+        since = None  # the open period's start; none is open
+        dispatched = 0  # the seq the open period dispatched last
         while not self._stop.is_set():
+            if traced and since is None:
+                since = lap("server.period")
             # with a reply owed, never wait: not for a first request,
             # not for the fill deadline
             reqs = collect(flight is None)
@@ -389,19 +411,39 @@ class BatchedInferenceServer:
                 # so a wedged ACTOR gets the stall attribution instead of
                 # the server it simply stopped querying
                 self._obs.beat("inference-server", "idle")
+                since = None
                 continue
             ahead = self._dispatch(reqs) if reqs else None
+            answered = 0  # the seq this iteration replied to
             if flight is not None:
-                if ahead is not None and self._traced:
+                if ahead is not None and traced:
                     # the mechanism's own counter: one per batch that
                     # was dispatched with its predecessor unfetched
                     self._obs.record("server.ahead", ahead.t_dispatch,
                                      time.perf_counter(), batch=ahead.seq,
                                      behind=flight.seq)
                 self._reply(flight)
+                answered = flight.seq
+            # the answered flight's last reference goes HERE, traced or
+            # not: where its requests and device outputs are freed is
+            # where the thread next waits for the interpreter (PERF.md
+            # section 6, PR 52), so no local may keep it past this line
             flight = ahead
+            if traced:
+                if reqs:
+                    dispatched = self._batch_seq
+                if answered or ahead is None:
+                    # `batch`: the seq the period dispatched (its last,
+                    # where one that was left open dispatched two),
+                    # `behind`: the seq it answered; 0 for none
+                    since = lap("server.period", since, batch=dispatched,
+                                behind=answered)
+                    dispatched = 0
         if flight is not None:  # stop() leaves no client in event.wait
             self._reply(flight)
+            if traced:
+                lap("server.period", since, batch=dispatched,
+                    behind=flight.seq)
 
     def _bucket(self, n: int) -> int:
         """Padded batch size: next pow2, rounded up to a multiple of the

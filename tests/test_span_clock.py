@@ -4,6 +4,7 @@ the inference server's per-batch spans, the state lock's timed waits,
 the learner loop's sampled sync, and `ingest.batch` for loopback
 messages."""
 
+import gc
 import json
 import sys
 import threading
@@ -47,7 +48,14 @@ def annotations(monkeypatch):
 
     _FakeAnnotation.log = []
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
-    return _FakeAnnotation.log
+    # a collection under a live tracer is a span of its own,
+    # `apex.host.gc` (ISSUE 52): none may start while a test compares
+    # the whole log
+    was_enabled = gc.isenabled()
+    gc.disable()
+    yield _FakeAnnotation.log
+    if was_enabled:
+        gc.enable()
 
 
 def _traced_obs(tmp_path, **kw) -> Obs:
@@ -68,12 +76,14 @@ def test_span_opens_prefixed_annotation_and_folds_bare_name(
                            ("exit", "apex.server.stack")]
     assert ANNOTATION_PREFIX == "apex."
     agg = tracer.aggregates()
-    assert set(agg) == {"server.stack"}
+    assert set(agg) == {"server.stack", "server.stack.cpu", "process.cpu",
+                        "thread.MainThread.cpu"}
     assert agg["server.stack"]["count"] == 1
     tracer.close()
     ev = [e for e in load_trace(str(tmp_path / "t.json"))["traceEvents"]
           if e.get("ph") == "X"]
     assert [e["name"] for e in ev] == ["server.stack"]
+    assert ev[0]["args"].pop("cpu_us") >= 0.0
     assert ev[0]["args"] == {"batch": 7}
 
 
@@ -113,12 +123,30 @@ def test_record_folds_a_cross_thread_interval_without_annotation(
     assert all(e["args"] == {"batch": 3} for e in ev)
 
 
+def test_close_inside_a_collection_leaves_no_annotation_entered(
+        annotations, tmp_path):
+    """`close()` takes the hook out, so a collection that had started
+    never sees its stop: the tracer exits the annotation itself and
+    drops the half span."""
+    tracer = SpanTracer(str(tmp_path / "t.json"))
+    tracer._on_gc("start", {"generation": 1})
+    tracer.close()
+    assert annotations == [("enter", "apex.host.gc"),
+                           ("exit", "apex.host.gc")]
+    tracer._on_gc("stop", {"generation": 1})   # a late one finds nothing
+    assert "host.gc" not in tracer.aggregates()
+    assert len(annotations) == 2
+
+
 def test_null_path_hands_back_one_shared_object_and_allocates_nothing():
     assert NULL_TRACER.span("a", k=1) is NULL_SPAN
     assert NULL_OBS.span("b", batch=2) is NULL_SPAN
     assert NULL_OBS.stage_window("train", 8) is NULL_SPAN
     assert NULL_TRACER.record("c", 0.0, 1.0, batch=1) is None
     assert NULL_OBS.record("c", 0.0, 1.0) is None
+    # a lap (ISSUE 52) opens nothing through either twin
+    assert NULL_TRACER.lap("c") is None
+    assert NULL_OBS.lap("c", None, batch=1) is None
     with NULL_OBS.span("d") as got:
         assert got is None
     span = NULL_OBS.span
