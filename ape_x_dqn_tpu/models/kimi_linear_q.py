@@ -71,12 +71,14 @@ models/expert_layer.py round by `astype`, as in the nets that share them
 
 Recomputation: every block is a `jax.checkpoint` that keeps THE
 SELECTION (expert_layer.SELECTION) and nothing else; the scan inside a
-KDA block checkpoints each chunk again (ops/chunked_delta_rule.py).
+KDA block keeps a chunk's start state and its solve and makes the rest
+again in a backward rule of its own (ops/chunked_delta_rule.py).
 
 Scopes: `kimi.embed`; `kda` around a KDA mixer, inside it `kda.proj`,
 `kda.conv`, `kda.gates`, `kda.scan` (the op's: `kda.scan.intra`,
-`kda.scan.carry`), `kda.out`; `glm.mla` around an MLA mixer
-(`glm.mla.scores` inside); `glm.moe` / `glm.dense_ffn`; `kimi.head`.
+`kda.scan.carry`, `kda.scan.back`), `kda.out`; `glm.mla` around an MLA
+mixer (`glm.mla.scores` inside); `glm.moe` / `glm.dense_ffn`;
+`kimi.head`.
 Counters (`apply_with_stats`): the expert layer's `expert_rows` and
 `topk`, `kda_chunks` (chunks the scan walked, summed over the KDA
 layers where a chunk is walked) and `kda_state_rms` (RMS of S after the

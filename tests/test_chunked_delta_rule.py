@@ -2,7 +2,9 @@
 a time in float32: outputs, the final state and the gradients of q, k,
 v, g, beta and of the initial state. ISSUE 47: a chunk's solve is
 products of whole block diagonal matrices and its walk two products; the
-cases hold calls of one, four, five and sixteen chunks."""
+cases hold calls of one, four, five and sixteen chunks. ISSUE 54: the
+chunk's backward pass is a rule of its own (`jax.custom_vjp`), held here
+to autodiff of the recurrence, which knows nothing of it."""
 
 import jax
 import jax.numpy as jnp
@@ -71,25 +73,49 @@ def test_outputs_and_state_match_the_recurrence(t, chunk, with_state):
     assert o.dtype == s.dtype == jnp.float32
 
 
-@pytest.mark.parametrize("t,chunk,with_state", [
-    (32, 8, False), (32, 8, True),
-    (128, 8, True), (128, 32, True),    # sixteen chunks, four
-    (37, 8, True)])                     # five chunks, the last padded
-def test_gradients_match_the_recurrence(t, chunk, with_state):
+def _gradient_cases():
+    """The parent's five cases, then ISSUE 54's: every (t, chunk) of its
+    list with and without a state, the cotangent on the outputs alone,
+    on the final state alone and on both, the int32 count beside the
+    rule in every other case (it changes no gradient: no `float0`)."""
+    cases = [
+        (32, 8, False, False, "both"), (32, 8, True, False, "both"),
+        (128, 8, True, False, "both"),          # sixteen chunks
+        (128, 32, True, False, "both"),         # four
+        (37, 8, True, False, "both"),           # five chunks, the last padded
+        (5, 8, True, True, "state"),            # one chunk, mostly padding
+        (128, 64, True, False, "both")]         # a solve of three joins
+    for t, chunk in [(32, 8), (64, 16), (64, 64), (8, 2), (37, 16), (37, 8)]:
+        for on in ("outputs", "state", "both"):
+            for with_state in (False, True):
+                case = (t, chunk, with_state, len(cases) % 2 == 0, on)
+                if case not in cases:
+                    cases.append(case)
+    return cases
+
+
+@pytest.mark.parametrize("t,chunk,with_state,with_chunks,on",
+                         _gradient_cases())
+def test_gradients_match_the_recurrence(t, chunk, with_state, with_chunks,
+                                        on):
     args = inputs(t, seed=3)
     r = np.random.default_rng(9)
     co = jnp.asarray(r.normal(size=(B, t, H, DV)), jnp.float32)
     cs = jnp.asarray(r.normal(size=(B, H, DK, DV)), jnp.float32)
+    if on == "state":               # no cotangent on any output position
+        co = 0.0 * co
+    if on == "outputs":             # none on the final state
+        cs = 0.0 * cs
 
     def scalar(fn):
         def f(*a):
             state = a[5] if with_state else 0.0 * a[5]
-            o, s = fn(*a[:5], state)
+            o, s = fn(*a[:5], state)[:2]
             return (o * co).sum() + (s * cs).sum()
         return f
 
     got = jax.grad(scalar(lambda *a: cdr.chunked_delta_rule(
-        *a, chunk=chunk)), argnums=range(6))(*args)
+        *a, chunk=chunk, with_chunks=with_chunks)), argnums=range(6))(*args)
     want = jax.grad(scalar(recurrence), argnums=range(6))(*args)
     for name, a, b in zip(("q", "k", "v", "g", "beta", "state"), got, want):
         if name == "state" and not with_state:
@@ -112,6 +138,31 @@ def test_strongest_decay_over_a_whole_chunk_is_finite_and_equal(chunk):
                     argnums=range(6))(*args)
     for a, b in zip(grads, want):
         close(a, b, 1e-4)
+
+
+@pytest.mark.parametrize("chunk", [64, 32, 8])
+def test_strongest_decay_gives_every_input_a_finite_gradient(chunk):
+    """ISSUE 54: the backward rule makes the tile again, masked before
+    the exponential as the forward's is, so where -G passes 88 inside a
+    chunk no infinity meets a zero; cotangents on outputs AND state, all
+    six inputs."""
+    args = inputs(128, seed=7, decay=1.6)
+    r = np.random.default_rng(11)
+    co = jnp.asarray(r.normal(size=(B, 128, H, DV)), jnp.float32)
+    cs = jnp.asarray(r.normal(size=(B, H, DK, DV)), jnp.float32)
+
+    def scalar(fn):
+        def f(*a):
+            o, s = fn(*a)
+            return (o * co).sum() + (s * cs).sum()
+        return f
+
+    got = jax.grad(scalar(lambda *a: cdr.chunked_delta_rule(
+        *a, chunk=chunk)), argnums=range(6))(*args)
+    want = jax.grad(scalar(recurrence), argnums=range(6))(*args)
+    assert len(got) == 6
+    for a, b in zip(got, want):
+        close(a, b, 1e-4)               # `close` asserts finite first
 
 
 @pytest.mark.parametrize("chunk", [64, 32])     # two chunks, four
@@ -155,6 +206,108 @@ def test_a_chunk_is_a_scan_iteration_of_few_whole_products(
     assert scan.params["length"] == -(-t // chunk)
     assert str(scan.params["jaxpr"]).count("dot_general") == products
     assert str(jaxpr).count("dot_general") == products  # none outside it
+
+
+@pytest.mark.parametrize("t,chunk,forward", [
+    (128, 32, 11), (37, 8, 7), (128, 64, 13)])
+def test_the_backward_pass_is_a_scan_of_eight_products(t, chunk, forward):
+    """ISSUE 54: the chunk's backward rule is written out. The gradient
+    program is two scans over the chunks: the forward rule's (the
+    forward's products, nothing more) and a backward one whose body holds
+    EIGHT products whatever the chunk - W | U_0 and W S_0 made again, the
+    carry's four transposed, and the solve's two (d rhs = T^T d wu, dA =
+    -(d rhs) wu^T): `_unit_lower_inverse` is not run again, T comes saved.
+    Autodiff under a `jax.checkpoint` held 33 at chunks of 32 (the
+    forward's 11 again and 22 transposes)."""
+    def loss(*a):
+        o, s, _ = cdr.chunked_delta_rule(*a, chunk=chunk, with_chunks=True)
+        return (o * o).sum() + (s * s).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=range(6)))(
+        *inputs(t)).jaxpr
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == [-(-t // chunk)] * 2
+    assert [e.params["reverse"] for e in scans] == [False, True]
+    products = [str(e.params["jaxpr"]).count("dot_general") for e in scans]
+    assert products == [forward, 8]
+    assert str(jaxpr).count("dot_general") == forward + 8
+    # what the forward scan hands the backward one a chunk: S_0 and T | B
+    # ([C, 2C], one array), nothing of the tile's size and no W | U_0
+    stacked = [v.aval.shape[1:] for v in
+               scans[0].outvars[scans[0].params["num_carry"]:]]
+    assert sorted(stacked) == sorted([
+        (B, H, chunk, DV), (B, H, DK, DV), (B, H, chunk, 2 * chunk)])
+    # a `custom_vjp`'s backward function opens no scope of its own: every
+    # op of the backward scan's body names `kda.scan` > `kda.scan.back`
+    # (what `learner.kda_scan_share` reads), the forward's none of it
+    under = cdr.SCOPE + "/" + cdr.BACK
+    assert all(str(e.source_info.name_stack).startswith(under)
+               for e in scans[1].params["jaxpr"].jaxpr.eqns)
+    assert not any(cdr.BACK in str(e.source_info.name_stack)
+                   for e in scans[0].params["jaxpr"].jaxpr.eqns)
+
+
+@pytest.mark.parametrize("t,chunk", [(37, 16), (37, 8), (5, 8)])
+def test_a_padded_tail_takes_no_gradient_and_passes_the_states(t, chunk):
+    """ISSUE 54: the rule on a chunk whose tail is padding (g = 0, beta
+    = 0, zeros, as `chunked_delta_rule` pads, whose slice leaves no
+    cotangent on a padded output) gives the padded q, k, v and beta
+    cotangents of exactly zero (g's is finite and not zero - the state
+    after the chunk does depend on it - and the pad's transpose drops
+    it), and a chunk that is all padding hands dS back as it came."""
+    *x, state = inputs(chunk, seed=t)
+    real = t % chunk
+    keep = (jnp.arange(chunk) < real).astype(jnp.float32)
+    tail = lambda a: keep.reshape((chunk,) + (1,) * (a.ndim - 3))  # noqa: E731,E501
+    q, k, v, g, beta = (jnp.moveaxis(a, 1, 2) for a in x)   # [B, H, C, ..]
+    q, k, v, g, beta = (a * tail(a) for a in (q, k, v, g, beta))
+    r = np.random.default_rng(t)
+    d_s1 = jnp.asarray(r.normal(size=state.shape), jnp.float32)
+    d_o = jnp.asarray(r.normal(size=(B, H, chunk, DV)), jnp.float32)
+    d_o = d_o * tail(d_o)
+    _, back = jax.vjp(cdr._chunk, state, q, k, v, g, beta)
+    d_s0, *d_x = (np.asarray(d) for d in back((d_s1, d_o)))
+    assert np.isfinite(d_s0).all() and all(np.isfinite(d).all() for d in d_x)
+    for name, d in zip("qkvgb", d_x):
+        assert d[:, :, :real].any()
+        assert name == "g" or not d[:, :, real:].any()
+    zeros = tuple(0.0 * a for a in (q, k, v, g, beta))
+    (s1, _), back = jax.vjp(cdr._chunk, state, *zeros)
+    d_s0, dq, dk, dv, _, d_beta = back((d_s1, 0.0 * d_o))
+    np.testing.assert_array_equal(np.asarray(s1), np.asarray(state))
+    np.testing.assert_array_equal(np.asarray(d_s0), np.asarray(d_s1))
+    assert not any(np.asarray(d).any() for d in (dq, dk, dv, d_beta))
+
+
+@pytest.mark.parametrize("differentiated", [False, True])
+def test_two_calls_at_one_shape_trace_the_chunk_once(
+        monkeypatch, differentiated):
+    """ISSUE 54: the walk is a `jax.jit(..., inline=True)`, so a net's
+    KDA layers (one shape) trace the chunk's forward ONCE, as the
+    `jax.checkpoint` this replaced did, and not once a call as a bare
+    `jax.custom_vjp` would (set-up time: the module's `_walk`). Under
+    `jax.grad` that is twice whatever the calls: the walk, and the
+    forward rule when the walk is differentiated."""
+    traced = []
+    tile = cdr._tile
+    monkeypatch.setattr(
+        cdr, "_tile", lambda *a: traced.append(1) or tile(*a))
+    # shapes no other test of this file walks: `_walk`'s cache is the
+    # process's
+    args = inputs(24 if differentiated else 20, seed=1)
+
+    def three(*a):
+        o1, s = cdr.chunked_delta_rule(*a, chunk=4)
+        o2, s = cdr.chunked_delta_rule(*a[:5], s, chunk=4)
+        o3, s = cdr.chunked_delta_rule(*a[:5], s, chunk=4)
+        return (o1 * o2 * o3).sum() + (s * s).sum()
+
+    if differentiated:
+        three = jax.grad(three, argnums=range(6))
+    jaxpr = jax.make_jaxpr(three)(*args)
+    assert len(traced) == (2 if differentiated else 1)
+    scans = str(jaxpr).count(" scan[")
+    assert scans == (6 if differentiated else 3)    # inlined: all there
 
 
 def test_unit_lower_inverse_is_the_inverse():

@@ -51,7 +51,16 @@ grad step, and `ops/sum_tree.py`'s two nested in the first and the last.
     cells run the parent's program. The dense levels' ops carry
     `sum_tree.descent` in their name stacks, so `replay.sample_share`
     keeps seeing them, and with BOTH rules held at 0 every program is
-    still the parent's to the byte (the second hashes, untouched);
+    still the parent's to the byte (the second hashes, untouched).
+    ISSUE 54 moved `kimi_linear_tiny_q` alone, by design
+    (ops/chunked_delta_rule.py: the chunk's backward pass is a rule of
+    its own, a `jax.custom_vjp`, where autodiff ran under a
+    `jax.checkpoint`): both its hashes are re-pinned from that PR's
+    tree (they are the ones PR 53, the same change refused for a file it
+    added to the benchmark, had read), and its name stacks now hold `kda.scan.back` under `kda.scan`
+    beside the forward's two; the other TEN programs passed UNCHANGED,
+    which is the proof that the nine other cells run the parent's
+    program (nothing but Kimi's net reaches the file);
 (c) no endpoint bypasses a scope: `train_step`, `train_step_k`,
     `sample_k` + `learn_k` and the prefetching `train_many` open the
     same names, on one chip and on the mesh.
@@ -117,9 +126,11 @@ PROGRAMS = {
     # that added it (ISSUE 46) beside the eight that must not move: GLM's
     # through the moved MLA, `ouro_tiny_q`'s and the two one-nest nets'
     # through the blockwise attention's value head size
-    # (moved by ISSUE 47 and by nothing else: ops/chunked_delta_rule.py)
+    # (moved by ISSUE 47 and by ISSUE 54, each time alone and through
+    # ops/chunked_delta_rule.py; PR 52's: f2232d0ad15b7a42,
+    # c5d7cc95256d237b)
     "kimi_linear_tiny_q": ("kimi_linear_tiny_q", ["replay.capacity=64"], 2,
-                           "f2232d0ad15b7a42", "c5d7cc95256d237b"),
+                           "80df8af98cc14ae8", "b4eaea1f0904ba67"),
     # the family's sixth net, the first with a convolution mixer and a
     # head that is its embedding, pinned at the PR that added it (ISSUE
     # 50) beside the ten that must not move: Kimi's through the shared
@@ -224,6 +235,8 @@ def test_the_program_is_the_parents_to_the_byte(case):
     assert not any(s in text for s in CYCLE_SCOPES)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PROGRAMS[case][3], (
+            "ISSUE 54 moved this program alone (the delta rule's backward "
+            "pass), re-pinned there" if case == "kimi_linear_tiny_q" else
             "ISSUE 51 moved the four programs whose draw is a row of lanes "
             "or more and must move no other: this pin is PR 50's"
             if case in UNMOVED_BY_ISSUE_51
@@ -232,11 +245,30 @@ def test_the_program_is_the_parents_to_the_byte(case):
 
 
 def test_the_delta_rules_scan_keeps_its_three_scopes():
-    # what `learner.kda_scan_share` and PERF.md's split of it read
+    # what `learner.kda_scan_share` and PERF.md's split of it read, and
+    # (ISSUE 54) the backward rule's own scope with its three parts: a
+    # `custom_vjp`'s backward function opens them itself, under
+    # `kda.scan`, or its time would fall out of that share
     stacks = _name_stacks(_lowered("kimi_linear_tiny_q")[1])
     for scope in (chunked_delta_rule.SCOPE, chunked_delta_rule.INTRA,
-                  chunked_delta_rule.CARRY):
+                  chunked_delta_rule.CARRY, chunked_delta_rule.BACK,
+                  chunked_delta_rule.BACK_TILE,
+                  chunked_delta_rule.BACK_SOLVE,
+                  chunked_delta_rule.BACK_CARRY):
         assert any(scope + "/" in s for s in stacks), scope
+    back = [s for s in stacks if chunked_delta_rule.BACK in s]
+    assert all(chunked_delta_rule.SCOPE + "/" in s for s in back)
+    # the scan's products are the forward's two kinds and the backward
+    # rule's two, each under its scope: none unscoped, and none a
+    # transpose of the forward's (autodiff of the chunk left the program)
+    assert {s for s in stacks if chunked_delta_rule.SCOPE in s
+            and s.endswith("dot_general")} == {
+        f"{scope}/dot_general" for scope in (
+            chunked_delta_rule.INTRA, chunked_delta_rule.CARRY,
+            "/".join((chunked_delta_rule.SCOPE, chunked_delta_rule.BACK,
+                      chunked_delta_rule.BACK_CARRY)),
+            "/".join((chunked_delta_rule.SCOPE, chunked_delta_rule.BACK,
+                      chunked_delta_rule.BACK_SOLVE)))}
 
 
 @pytest.mark.parametrize("case", list(PROGRAMS))
