@@ -444,10 +444,75 @@ class Lfm2MoeConfig:
                 f"vocab_size={self.vocab_size}")
 
 
+_PUBLISHED_SALA_MIXERS = (
+    ("minicpm4",) + ("lightning-attn",) * 8 + ("minicpm4",)
+    + ("lightning-attn",) * 6 + ("minicpm4",) * 2 + ("lightning-attn",) * 4
+    + ("minicpm4",) + ("lightning-attn",) * 6 + ("minicpm4",) * 3)
+
+
+@dataclass(frozen=True)
+class MiniCpmSalaConfig:
+    """The decoder of network.kind="minicpm_sala_q"
+    (models/minicpm_sala_q.py), under the key names of the model's own
+    config.json (openbmb/MiniCPM-SALA, `model_type` minicpm_sala);
+    defaults are that model's. `mixer_types[i]` "minicpm4" is grouped-
+    query attention WITHOUT position encoding (`attn_use_rope` false)
+    over key blocks the data chooses once a context passes
+    `sparse_dense_len` (InfLLM v2; ops/block_select_attention.py),
+    "lightning-attn" linear attention with one scalar decay a head and
+    a float32 `lightning_head_dim` x `lightning_head_dim` state
+    (ops/lightning_attention.py); both behind q/k head norms
+    (`qk_norm`) and a sigmoid output gate, every FFN one dense SwiGLU,
+    untied embedding and head. The row has no `sparse_config`: the
+    seven `sparse_*` sizes are the MiniCPM4 report's as recalled
+    (`assumed` in the benchmark's configuration file). The net has no
+    expert layer and so no share of one: a chip's cut is a number of
+    layers, set with `num_hidden_layers` AND `mixer_types` (the kinds of
+    the layers held; the net checks that they agree when it is built,
+    so that two overrides may arrive one after the other)."""
+
+    hidden_size: int = 4096
+    num_hidden_layers: int = 32
+    mixer_types: tuple[str, ...] = _PUBLISHED_SALA_MIXERS
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    lightning_nh: int = 32
+    lightning_nkv: int = 32
+    lightning_head_dim: int = 128
+    lightning_use_rope: bool = True
+    attn_use_rope: bool = False
+    qk_norm: bool = True
+    use_output_gate: bool = True
+    use_output_norm: bool = True
+    attn_use_output_gate: bool = True
+    intermediate_size: int = 16_384
+    rope_theta: float = 10_000.0
+    # muP: x0 = scale_emb E[token]; a sublayer's output times
+    # scale_depth / sqrt(depth_scale_layers); Q over hidden_size /
+    # dim_model_base. The depth is the PUBLISHED one whatever a cut
+    # holds, so a pipeline stage's blocks are the model's
+    scale_emb: float = 12.0
+    scale_depth: float = 1.4
+    depth_scale_layers: int = 32
+    dim_model_base: int = 256
+    max_position_embeddings: int = 524_288
+    vocab_size: int = 73_448
+    rms_norm_eps: float = 1e-6
+    # the selection (ops/block_select_attention.Sizes)
+    sparse_block_size: int = 64
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2_048
+    sparse_topk: int = 64
+    sparse_dense_len: int = 8_192
+
+
 @dataclass(frozen=True)
 class NetworkConfig:
     # mlp | nature_cnn | lstm_q | dpg | glm_moe_q | afmoe_q | smallthinker_q
-    # | ouro_q | kimi_linear_q | lfm2_moe_q
+    # | ouro_q | kimi_linear_q | lfm2_moe_q | minicpm_sala_q
     kind: str = "mlp"
     mlp_hidden: tuple[int, ...] = (256, 256)
     cnn_channels: tuple[int, ...] = (32, 64, 64)
@@ -475,6 +540,11 @@ class NetworkConfig:
     # the decoder of kind="lfm2_moe_q" (the same family: gated short
     # convolutions beside full attention, with experts, a tied head)
     lfm2_moe: Lfm2MoeConfig = field(default_factory=Lfm2MoeConfig)
+    # the decoder of kind="minicpm_sala_q" (the same family: attention
+    # over key blocks the data chooses beside lightning linear
+    # attention, dense FFNs; the first net the server keeps in slots)
+    minicpm_sala: MiniCpmSalaConfig = field(
+        default_factory=MiniCpmSalaConfig)
 
 
 @dataclass(frozen=True)
@@ -722,6 +792,21 @@ class InferenceConfig:
     # params, leading axis split) when running distributed; forwards/s
     # then scales with chip count
     shard_over_mesh: bool = True
+    # for a net the server keeps in SLOTS (it offers `slot_state`:
+    # models/minicpm_sala_q.py; runtime/family.server_slots). slots: how
+    # many sessions may be live (0: one per env of the actor fleet,
+    # and one for the eval worker); slot_max_len: the longest episode
+    # a session may declare, and what one that declares nothing gets
+    # (0: env.max_episode_frames + 1); slot_pool_tokens: positions of
+    # keys and values the shared pool holds (0: slots x slot_max_len);
+    # prefill_chunk: tokens a row of the second bucket kind (a request
+    # whose `obs` is [rows, prefill_chunk]); prefill_rows: the most
+    # rows of that kind in one dispatch
+    slots: int = 0
+    slot_max_len: int = 0
+    slot_pool_tokens: int = 0
+    prefill_chunk: int = 2_048
+    prefill_rows: int = 8
 
 
 @dataclass(frozen=True)
@@ -1626,6 +1711,77 @@ def _preset_lfm2_tiny_q() -> RunConfig:
     )
 
 
+def _preset_minicpm_sala_9b_q() -> RunConfig:
+    """Config 12: MiniCPM-SALA (OpenBMB, 9B dense) as a token-level
+    Q-network, the decoder family's seventh net and the first the
+    inference server keeps IN SLOTS. The sizes are the model's
+    config.json (https://huggingface.co/openbmb/MiniCPM-SALA): 32
+    layers, 8 of attention over key blocks the data chooses (32 heads of
+    128 over 2 key-value heads, no position encoding) to 24 of lightning
+    linear attention (32 heads of 128, a 128 x 128 float32 state a
+    head), SwiGLU of 16,384, 73,448 vocabulary rows, untied. Whole it
+    is 9.48 B parameters and no learner fits it: check_hbm_fits refuses
+    the preset as it stands, and a run gives one chip its pipeline
+    stage with network.minicpm_sala.num_hidden_layers / mixer_types
+    (benchmarks/configs/minicpm_sala_9b_pp4_1chip.json is the measured
+    one: eight layers SERVED, not trained). The learner settings are
+    this repo's, as Ouro's; a session's context is the whole episode,
+    up to inference.slot_max_len positions."""
+    sala = MiniCpmSalaConfig()
+    return RunConfig(
+        name="minicpm_sala_9b_q",
+        total_env_frames=10_000_000_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=sala.vocab_size, max_episode_frames=4_096),
+        network=NetworkConfig(kind="minicpm_sala_q", dueling=False,
+                              minicpm_sala=sala),
+        replay=ReplayConfig(kind="sequence", capacity=8_192,
+                            seq_length=4_096, seq_overlap=2_048,
+                            burn_in=1_024, min_fill=64),
+        learner=LearnerConfig(batch_size=1, n_step=5, value_rescale=True,
+                              target_sync_every=2500, lr=1e-4,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=8, envs_per_actor=8),
+        inference=InferenceConfig(max_batch=64, deadline_ms=2.0),
+    )
+
+
+def _preset_minicpm_sala_tiny_q() -> RunConfig:
+    """minicpm_sala_9b_q's sibling for CPU tests: both kinds of mixer
+    (sparse, lightning, lightning, sparse) at hidden 64, 4 heads of 16
+    over 1 key-value head, an MLP of 96, a vocabulary of 64; key blocks
+    of 8, compressed keys over 4 positions every 2, a local window of
+    16, 6 blocks attended, dense up to 32 positions, so that sequences
+    of 64 cross `sparse_dense_len`; float32."""
+    sala = MiniCpmSalaConfig(
+        hidden_size=64, num_hidden_layers=4,
+        mixer_types=("minicpm4", "lightning-attn", "lightning-attn",
+                     "minicpm4"),
+        num_attention_heads=4, num_key_value_heads=1, head_dim=16,
+        lightning_nh=4, lightning_nkv=4, lightning_head_dim=16,
+        intermediate_size=96, dim_model_base=16,
+        max_position_embeddings=4_096, vocab_size=64,
+        sparse_block_size=8, sparse_kernel_size=4, sparse_kernel_stride=2,
+        sparse_init_blocks=1, sparse_window_size=16, sparse_topk=6,
+        sparse_dense_len=32)
+    return RunConfig(
+        name="minicpm_sala_tiny_q",
+        total_env_frames=100_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=sala.vocab_size, max_episode_frames=64),
+        network=NetworkConfig(kind="minicpm_sala_q", dueling=False,
+                              minicpm_sala=sala, compute_dtype="float32"),
+        replay=ReplayConfig(kind="sequence", capacity=64, seq_length=64,
+                            seq_overlap=32, burn_in=24, min_fill=8),
+        learner=LearnerConfig(batch_size=4, n_step=3, value_rescale=True,
+                              target_sync_every=100, lr=1e-3,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=1, envs_per_actor=2),
+        inference=InferenceConfig(max_batch=8, deadline_ms=2.0,
+                                  prefill_chunk=16, prefill_rows=2),
+    )
+
+
 PRESETS = {
     "cartpole_smoke": _preset_cartpole_smoke,
     "pong": _preset_pong,
@@ -1644,6 +1800,8 @@ PRESETS = {
     "kimi_linear_tiny_q": _preset_kimi_linear_tiny_q,
     "lfm2_24b_q": _preset_lfm2_24b_q,
     "lfm2_tiny_q": _preset_lfm2_tiny_q,
+    "minicpm_sala_9b_q": _preset_minicpm_sala_9b_q,
+    "minicpm_sala_tiny_q": _preset_minicpm_sala_tiny_q,
 }
 
 

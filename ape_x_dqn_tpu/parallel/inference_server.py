@@ -58,6 +58,42 @@ question (a forward dispatched ahead has admitted requests a later,
 more urgent class could no longer overtake), and no benchmark cell runs
 it, so a before and after could not be measured.
 
+The slot path (ISSUE 55): a net whose per-session state cannot ride a
+query (models/minicpm_sala_q.py: megabytes of float32 matrices and two
+KiB a position) is served with that state ON THE DEVICE BETWEEN
+QUERIES. `BatchedInferenceServer(..., slots=Slots(...))` makes the
+choice once, at construction, by binding `_collect`, `_dispatch` and
+`_reply` to their slot forms; without `slots` every method, span and
+jitted program is what it was. The program is `apply_fn(params, state,
+inputs) -> (outputs, state)`: `state` is DONATED through every dispatch
+and never copied whole, and dispatch order is state order, so the one
+batch in flight needs no lock - a closed-loop caller has no second
+request for a slot until the first is answered, and a request for a
+slot that the batch being stacked already holds waits for the next
+batch. A query names its sessions (`slot` [rows]) and says which begin
+(`fresh`); the host's ledger (parallel/slot_pool.py) hands a beginning
+session one contiguous range of the shared pool for the length it
+declares (`max_len`, optional) and the dispatch carries each row's
+`base`. A session that does not fit fails its query with `SlotPoolFull`
+and leaves the rest of the batch alone. A dispatch that fails fails its
+own requests and puts the ledger back to what the device's lengths
+agree with; one that took the donated state with it leaves a zeroed
+state and every session that was live lost by name (`SlotStateLost`,
+`_lose_slot_state`), and the server serves on. Two kinds of request, told
+apart by `obs`: [rows] one token a session (a decode step) and [rows,
+prefill_chunk] with `n_valid` [rows] (a prefill chunk); a dispatch
+holds rows of ONE kind, rows pad to a power of two and no further than
+the kind's budget (`max_batch`, `prefill_rows`; a padding row is the
+scratch slot), and `warmup()` runs every bucket of both kinds once on
+scratch rows. With a reply owed that the device has not finished, the
+serve thread spends the wait on its queue (`_collect_slots`): what
+arrives while a step runs is dispatched behind it. The reply is `outputs` minus `counters` (summed into
+`slot_counters`) and, unless some request of the batch said
+`want_sel`, minus `sel`. Spans `server.stack` / `server.dispatch` carry
+`n=` and `rows=` there; gauges `server.slots_live`,
+`server.slot_blocks_held`; marks `server.slot_admit` /
+`server.slot_free`.
+
 Generic over the request pytree: a request is (inputs_pytree,) and the
 reply is outputs_pytree — plain Q-nets send obs and get Q-values;
 recurrent nets send (obs, (c, h)) and get (q, (c', h')).
@@ -103,6 +139,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ape_x_dqn_tpu.obs.core import NULL_OBS
 from ape_x_dqn_tpu.obs.health import make_lock
 from ape_x_dqn_tpu.obs.trace import ANNOTATION_PREFIX
+from ape_x_dqn_tpu.parallel.slot_pool import SlotPool
 from ape_x_dqn_tpu.utils.misc import next_pow2
 
 
@@ -136,13 +173,31 @@ class _Flight:
     out: Any            # device outputs, their copy to the host started
     t0: float           # server.batch's start (stamped only when traced)
     t_dispatch: float   # server.dispatch's start (likewise)
+    counters: Any = None  # slot path: the program's counters, on the device
+
+
+@dataclass
+class Slots:
+    """What makes a BatchedInferenceServer a slot server: the host's
+    ledger, the device state the program threads (replaced at every
+    dispatch), and the second bucket kind's geometry."""
+
+    pool: SlotPool
+    state: Any
+    prefill_chunk: int
+    prefill_rows: int
 
 
 class BatchedInferenceServer:
     def __init__(self, apply_fn: Callable, params: Any,
                  max_batch: int = 64, deadline_ms: float = 2.0,
-                 mesh: Mesh | None = None, obs: Any = None):
+                 mesh: Mesh | None = None, obs: Any = None,
+                 slots: Slots | None = None):
         """apply_fn(params, batched_inputs_pytree) -> batched outputs.
+
+        slots: optional — serve a net whose state lives in slots on the
+        device: apply_fn(params, state, inputs) -> (outputs, state); see
+        module docstring, "The slot path".
 
         mesh: optional — shard every batch's leading axis over all mesh
         devices (params replicated); see module docstring.
@@ -150,7 +205,27 @@ class BatchedInferenceServer:
         / param-lag / queue-depth instruments and the server heartbeat
         (NULL_OBS when omitted, so the hot loop stays branch-free).
         """
-        if mesh is not None:
+        self._slots = slots
+        if slots is not None:
+            if mesh is not None:
+                raise NotImplementedError(
+                    "a slot server runs on one device: its state is not "
+                    "sharded over a mesh")
+            self._apply = jax.jit(apply_fn, donate_argnums=(1,))
+            self._batched_sharding = None
+            self._min_bucket = 1
+            # the choice of path, made once
+            self._collect = self._collect_slots
+            self._dispatch = self._dispatch_slots
+            self._reply = self._reply_slots
+            self.slot_counters: dict[str, int] = {}  # serve thread writes
+            self._owed: Any = None  # the newest dispatch's `q`, on the device
+            # what a zeroed state is made from, should a failed dispatch
+            # take the donated one with it (`_lose_slot_state`)
+            self._state_spec = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                slots.state)
+        elif mesh is not None:
             # One sharding as a pytree prefix: dim 0 of every input and
             # output leaf is split over the flattened (dp, tp) device
             # grid; params replicate. Numpy inputs commit to these
@@ -249,6 +324,9 @@ class BatchedInferenceServer:
         alone in its own bucket, which must therefore be warm too)."""
         with self._lock:
             params = self._params
+        if self._slots is not None:
+            self._warmup_slots(params)
+            return
         # every bucket a pow2 REQUEST size up to max_batch can land in:
         # coalesced batches hit any of them (e.g. 2-3 K-item vector
         # requests -> bucket 2K/4K, truncation flushes -> small
@@ -301,6 +379,32 @@ class BatchedInferenceServer:
     def stop(self) -> None:
         self._stop.set()
         self._thread.join(timeout=5)
+
+    # a slot server's public reads (None / empty without slots); the
+    # state and the ledger are the serve thread's: read them at rest
+
+    @property
+    def slot_state(self) -> Any:
+        """The device pytree as the newest dispatch left it."""
+        return None if self._slots is None else self._slots.state
+
+    @property
+    def slot_ledger(self) -> dict:
+        pool = None if self._slots is None else self._slots.pool
+        return {} if pool is None else {
+            "slots_live": pool.live, "blocks_held": pool.blocks_held,
+            "pool_blocks": pool.pool_blocks}
+
+    @property
+    def warm_buckets(self) -> list:
+        """What `warmup()` has compiled: padded rows, or (tokens a row,
+        padded rows) on the slot path."""
+        return sorted(self._warm_buckets)
+
+    def release_slots(self) -> None:
+        """After `stop()`: give the device back what the sessions held."""
+        for leaf in jax.tree.leaves(self._slots.state):
+            leaf.delete()
 
     # -- server loop -------------------------------------------------------
 
@@ -554,6 +658,226 @@ class BatchedInferenceServer:
             self._items_served += flight.n
         self._obs.on_server_batch(flight.n, flight.version,
                                   self._q.qsize())
+
+
+
+    # -- the slot path (module docstring) ------------------------------------
+
+    def _rows_kind(self, r: _Request) -> int:
+        """Tokens a row of request `r` brings: 1 (a decode step) or the
+        prefill chunk."""
+        decode = np.ndim(r.inputs["obs"]) == (1 if r.n else 0)
+        return 1 if decode else self._slots.prefill_chunk
+
+    def _collect_slots(self, block: bool = True) -> list[_Request]:
+        """`_collect`, then: a batch holds rows of ONE kind (the first
+        request's), at most `prefill_rows` of the prefill kind, and no
+        slot twice - whatever else was collected waits, in arrival
+        order, for the next batch. And WITH A REPLY OWED THAT THE DEVICE
+        HAS NOT FINISHED (`block` False, nothing waiting): the wait for
+        that reply is spent on the queue - a request that arrives while
+        the step still runs is stacked and dispatched behind it, so a
+        closed loop whose clients fall into groups keeps them: the
+        groups take turns and the host's part of one group's period
+        hides behind another's step (PERF.md section 6, PR 55: at the
+        9B preset's 64 rows / 2 ms, p50 -8% and tokens/s +15% in every
+        run against the loop without it, p99 inside its spread). The
+        owed reply is late by the fill deadline at most, and only when
+        the step ends inside that fill."""
+        reqs = BatchedInferenceServer._collect(self, block)
+        if not reqs and not block:
+            owed = self._owed
+            while (owed is not None and not owed.is_ready()
+                   and not self._stop.is_set()):
+                try:
+                    self._held.append(self._q.get(timeout=0.0005))
+                except queue.Empty:
+                    continue
+                reqs = BatchedInferenceServer._collect(self, True)
+                break
+        if len(reqs) < 2 and (not reqs or self._rows_kind(reqs[0]) == 1):
+            return reqs
+        kind = self._rows_kind(reqs[0])
+        budget = self._max_batch if kind == 1 else self._slots.prefill_rows
+        keep, later, seen, rows = [], [], set(), 0
+        for r in reqs:
+            slots = set(np.reshape(r.inputs["slot"], -1).tolist())
+            if keep and (self._rows_kind(r) != kind or slots & seen
+                         or rows + r.items > budget):
+                later.append(r)
+            else:
+                keep.append(r)
+                seen |= slots
+                rows += r.items
+        self._held.extendleft(reversed(later))
+        return keep
+
+    def _slot_bucket(self, n: int, kind: int) -> int:
+        """Rows a dispatch of `n` rows is padded to: the next power of
+        two, and no more than the kind's budget (a full batch pads no
+        row: a padding row still reads and writes scratch state)."""
+        most = self._max_batch if kind == 1 else self._slots.prefill_rows
+        return max(min(self._bucket(n), most), n)
+
+    def _admit(self, r: _Request, n_valid: np.ndarray) -> np.ndarray:
+        """Request `r`'s rows into the ledger -> each row's first block.
+        A session that begins is admitted (what its slot held is freed),
+        every row's session grows by its tokens; on an error the ledger
+        is as it was."""
+        pool = self._slots.pool
+        slot = np.reshape(r.inputs["slot"], -1)
+        fresh = np.reshape(r.inputs["fresh"], -1)
+        declared = np.reshape(r.inputs.get("max_len", np.zeros_like(slot)),
+                              -1)
+        before = {int(s): pool.held(int(s)) for s in slot}
+        try:
+            for s, f, d, n in zip(slot, fresh, declared, n_valid):
+                if f:
+                    gave = pool.free(int(s))
+                    if gave:
+                        self._obs.mark("server.slot_free", slot=int(s),
+                                       blocks=gave)
+                    base = pool.admit(int(s), int(d))
+                    self._obs.mark("server.slot_admit", slot=int(s),
+                                   base=base, declared=int(d))
+                pool.advance(int(s), int(n))
+        except Exception:
+            for s, held in before.items():
+                pool.restore(s, held)
+            raise
+        return np.asarray([pool.base(int(s)) for s in slot], np.int32)
+
+    def _stack_slots(self, reqs: list[_Request], kind: int, padded: int
+                     ) -> tuple[dict, list[_Request]]:
+        """-> (the program's inputs for the requests that were admitted,
+        those requests); one that was not has its error already."""
+        pool = self._slots.pool
+        rows, served = [], []
+        for r in reqs:
+            inp = r.inputs if r.n else jax.tree.map(
+                lambda x: np.asarray(x)[None], r.inputs)
+            n_valid = (np.asarray(inp["n_valid"], np.int32) if kind > 1
+                       else np.ones(r.items, np.int32))
+            try:
+                base = self._admit(r, n_valid)
+            except Exception as e:
+                self._fail([r], e)
+                continue
+            rows.append({"obs": np.asarray(inp["obs"], np.int32),
+                         "slot": np.asarray(inp["slot"], np.int32),
+                         "fresh": np.asarray(inp["fresh"], np.int32),
+                         "base": base, "n_valid": n_valid})
+            served.append(r)
+        if not served:
+            return {}, served
+        stacked = {k: np.concatenate([row[k] for row in rows])
+                   for k in rows[0]}
+        n = stacked["slot"].shape[0]
+        # a padding row is a fresh session of no tokens in the scratch
+        # slot
+        fill = {"slot": pool.scratch_slot, "fresh": 1,
+                "base": pool.scratch_base, "obs": 0, "n_valid": 0}
+        return {k: np.concatenate([v, np.full(
+            (padded - n, *v.shape[1:]), fill[k], v.dtype)])
+            for k, v in stacked.items()}, served
+
+    def _dispatch_slots(self, reqs: list[_Request]) -> _Flight | None:
+        """`_dispatch` for a slot server: admission on the host, then
+        the program with the state donated; the state it returns is the
+        next dispatch's."""
+        kind = self._rows_kind(reqs[0])
+        n = sum(r.items for r in reqs)
+        padded = self._slot_bucket(n, kind)
+        span = self._obs.span
+        self._batch_seq = seq = self._batch_seq + 1
+        t0 = time.perf_counter() if self._traced else 0.0
+        pool = self._slots.pool
+        ledger = pool.snapshot()    # what the device's lengths agree with
+        try:
+            with span("server.stack", batch=seq, n=kind, rows=n):
+                stacked, reqs = self._stack_slots(reqs, kind, padded)
+            if not reqs:
+                return None
+            n = sum(r.items for r in reqs)
+            t_dispatch = time.perf_counter() if self._traced else 0.0
+            with span("server.dispatch", batch=seq, n=kind, rows=n):
+                with self._lock:
+                    params = self._params
+                    version = self._params_version
+                out, self._slots.state = self._apply(
+                    params, self._slots.state, stacked)
+                counters = out.pop("counters")
+                if not any(r.inputs.get("want_sel") is not None
+                           for r in reqs):
+                    del out["sel"]
+                for leaf in jax.tree.leaves((out, counters)):
+                    leaf.copy_to_host_async()
+                self._owed = out["q"]
+            self._obs.gauge("server.slots_live", pool.live)
+            self._obs.gauge("server.slot_blocks_held", pool.blocks_held)
+        except Exception as e:  # propagate to callers, keep serving
+            self._fail(reqs, e)
+            pool.reset(ledger)  # the device's lengths did not move
+            # a trace or compile error leaves the donated state whole;
+            # a failed execution takes it along
+            if any(leaf.is_deleted()
+                   for leaf in jax.tree.leaves(self._slots.state)):
+                self._lose_slot_state()
+            return None
+        return _Flight(reqs, n, padded, seq, version, out, t0, t_dispatch,
+                       counters)
+
+    def _lose_slot_state(self) -> None:
+        """A failed dispatch took the state with it and left nothing to
+        serve from: the state is made again, zeroed, and every session
+        that was live is lost by name (`SlotStateLost`) until it begins
+        again - the server keeps serving."""
+        self._slots.state = jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), self._state_spec)
+        self._slots.pool.lose_all()
+        self._obs.mark("server.slot_state_lost")
+
+    def _reply_slots(self, flight: _Flight) -> None:
+        """The program's counters into `slot_counters`, then `_reply`:
+        a caller that has its answer finds the batch counted."""
+        try:
+            for name, value in flight.counters.items():
+                self.slot_counters[name] = (
+                    self.slot_counters.get(name, 0) + int(value))
+        except Exception as e:  # the batch failed on the device: the
+            # state it handed on is no state
+            self._fail(flight.reqs, e)
+            self._owed = None
+            self._lose_slot_state()
+            return
+        BatchedInferenceServer._reply(self, flight)
+
+    def _warmup_slots(self, params: Any) -> None:
+        """Run every bucket of both kinds once on scratch rows: each
+        compiles, and the state the program hands back stays the
+        server's (a scratch row touches no session)."""
+        plan = self._slots
+        kinds = [(1, self._max_batch)]
+        if plan.prefill_chunk > 1:
+            kinds.append((plan.prefill_chunk, plan.prefill_rows))
+        for kind, most in kinds:
+            # every power of two under the budget, and the budget itself
+            sizes = {self._slot_bucket(n, kind) for n in (
+                *(1 << i for i in range(most.bit_length())), most)
+                if n <= most}
+            for rows in sorted(sizes):
+                if (kind, rows) in self._warm_buckets:
+                    continue
+                shape = (rows,) if kind == 1 else (rows, kind)
+                stacked = {
+                    "obs": np.zeros(shape, np.int32),
+                    "slot": np.full(rows, plan.pool.scratch_slot, np.int32),
+                    "fresh": np.ones(rows, np.int32),
+                    "base": np.full(rows, plan.pool.scratch_base, np.int32),
+                    "n_valid": np.zeros(rows, np.int32)}
+                out, plan.state = self._apply(params, plan.state, stacked)
+                jax.block_until_ready(out)
+                self._warm_buckets.add((kind, rows))
 
 
 def _pad_concat(xs: tuple, padded: int) -> np.ndarray:

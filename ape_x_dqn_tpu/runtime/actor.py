@@ -382,12 +382,12 @@ class RecurrentActor(Actor):
                  obs: object | None = None):
         super().__init__(cfg, actor_index, query_fn, transport, seed=seed,
                          episode_callback=episode_callback, obs=obs)
-        from ape_x_dqn_tpu.runtime.family import ACTOR_STATE, family_of
+        from ape_x_dqn_tpu.runtime.family import actor_state
 
         self.gamma = cfg.learner.gamma
         # what a query carries beside the observation, and which of it
         # a sequence stores: the family's row (runtime/family.py)
-        self._state_spec = ACTOR_STATE[family_of(cfg)]
+        self._state_spec = actor_state(cfg)
         frame_mode = cfg.replay.storage == "frame_ring"
         if frame_mode:
             assert len(self.env.spec.obs_shape) == 3, \
@@ -400,7 +400,10 @@ class RecurrentActor(Actor):
         self._outbox: list[dict] = []  # sequence items, not transitions
 
     def _zero_state(self) -> dict:
-        return self._state_spec.zeros(self.cfg)
+        from ape_x_dqn_tpu.runtime.family import episode_state
+
+        # this actor's one env is global slot `index`
+        return episode_state(self.cfg, self.index)
 
     def _stored(self, state: dict) -> tuple:
         return tuple(state[k] for k in self._state_spec.stored)

@@ -31,7 +31,7 @@ from ape_x_dqn_tpu.obs.fleet import StampingTransport, TelemetryEmitter
 from ape_x_dqn_tpu.parallel.inference_server import (
     BatchedInferenceServer, build_serving_tier)
 from ape_x_dqn_tpu.runtime.family import (
-    actor_class, family_of, server_apply_fn, warmup_example)
+    actor_class, family_of, server_apply_fn, server_slots, warmup_example)
 from ape_x_dqn_tpu.utils.compile_cache import ensure_compile_cache
 from ape_x_dqn_tpu.utils.metrics import Metrics, device_stamp
 
@@ -154,14 +154,14 @@ def run_actor_host(cfg: RunConfig, host: str, port: int,
         if serving.backpressure:
             tier.on_backpressure = raw_transport.set_backpressure
         server = tier.register_policy(
-            cfg.env.id, server_apply_fn(family, net), params,
+            cfg.env.id, server_apply_fn(family, net, cfg), params,
             family=family, priority=serving.default_class)
     else:
         server = BatchedInferenceServer(
-            server_apply_fn(family, net), params,
+            server_apply_fn(family, net, cfg), params,
             max_batch=cfg.inference.max_batch,
             deadline_ms=cfg.inference.deadline_ms,
-            obs=obs if obs.enabled else None)
+            obs=obs if obs.enabled else None, **server_slots(cfg, net))
     server.update_params(params, version)
     if emitter is not None:
         emitter.start()

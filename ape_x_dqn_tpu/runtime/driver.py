@@ -45,7 +45,7 @@ from ape_x_dqn_tpu.replay.frame_ring import FrameRingReplay
 from ape_x_dqn_tpu.replay.prioritized import PrioritizedReplay
 from ape_x_dqn_tpu.runtime.family import (
     SEQUENCE_FAMILIES, actor_class, build_learner, family_of, family_setup,
-    hbm_price, server_apply_fn, warmup_example)
+    hbm_price, server_apply_fn, server_slots, warmup_example)
 from ape_x_dqn_tpu.runtime.evaluation import (
     EvalWorker, make_eval_policy_factory)
 from ape_x_dqn_tpu.runtime.ingest import IngestStager
@@ -180,7 +180,7 @@ class ApexDriver:
                 max_batch=cfg.inference.max_batch,
                 deadline_ms=cfg.inference.deadline_ms,
                 mesh=server_mesh,
-                obs=self.obs)
+                obs=self.obs, **server_slots(cfg, self.net))
         self.transport = transport if transport is not None \
             else LoopbackTransport()
         # fleet telemetry plane (obs/fleet.py): with obs on and a
@@ -486,7 +486,7 @@ class ApexDriver:
 
     def _server_apply_fn(self):
         """The batched forward the inference server jits (family.py)."""
-        return server_apply_fn(self.family, self.net)
+        return server_apply_fn(self.family, self.net, self.cfg)
 
     def _make_eval_worker(self, game: str | None = None) -> EvalWorker:
         factory = make_eval_policy_factory(

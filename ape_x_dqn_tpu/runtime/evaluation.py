@@ -222,12 +222,14 @@ def make_eval_policy_factory(family: str, cfg: RunConfig,
     ApexDriver's eval loop and the standalone suite runner).
 
     Sequence families carry the state their queries carry (the LSTM's
-    fresh (c, h), the decoder's token window: family.ACTOR_STATE)
+    fresh (c, h), the decoder's token window, or the name of the slot
+    that holds the decoder's state in the server: family.ACTOR_STATE)
     across one episode's queries; continuous policies return the
     deterministic action mu(s); plain Q-nets need no factory
     (EvalWorker queries directly).
     """
-    from ape_x_dqn_tpu.runtime.family import ACTOR_STATE
+    from ape_x_dqn_tpu.runtime.family import (
+        ACTOR_STATE, episode_state, slot_geometry)
 
     if family == "dpg":
         return lambda: lambda obs: query_fn(obs)["a"]
@@ -235,7 +237,9 @@ def make_eval_policy_factory(family: str, cfg: RunConfig,
         return None
 
     def factory():
-        state = ACTOR_STATE[family].zeros(cfg)
+        # the eval worker's slot, where the state lives in the server,
+        # is the one behind the fleet's
+        state = episode_state(cfg, slot_geometry(cfg)[0] - 1)
 
         def policy(obs):
             out = query_fn({"obs": obs, **state})
@@ -306,7 +310,8 @@ def run_suite_eval(cfg: RunConfig, games: Iterable[str] | None = None,
     from ape_x_dqn_tpu.envs import make_env
     from ape_x_dqn_tpu.models import build_network
     from ape_x_dqn_tpu.runtime.family import (
-        family_of, family_setup, server_apply_fn)
+        family_of, family_setup, keeps_slots, server_apply_fn,
+        server_slots)
 
     if games is not None and cfg.env.kind not in ("atari",
                                                   "synthetic_atari"):
@@ -340,12 +345,24 @@ def run_suite_eval(cfg: RunConfig, games: Iterable[str] | None = None,
                 params = raw["params"]
         mngr.close()
 
-    fn = jax.jit(server_apply_fn(family, net))
+    server = None
+    if keeps_slots(net):
+        # the state lives in the server between queries: the episode is
+        # served as the fleet's are
+        from ape_x_dqn_tpu.parallel.inference_server import (
+            BatchedInferenceServer)
 
-    def query(inp):
-        batched = jax.tree.map(lambda x: np.asarray(x)[None], inp)
-        return jax.tree.map(lambda x: np.asarray(x)[0],
-                            fn(params, batched))
+        server = BatchedInferenceServer(
+            server_apply_fn(family, net, cfg), params, max_batch=1,
+            **server_slots(cfg, net))
+        query = server.query
+    else:
+        fn = jax.jit(server_apply_fn(family, net))
+
+        def query(inp):
+            batched = jax.tree.map(lambda x: np.asarray(x)[None], inp)
+            return jax.tree.map(lambda x: np.asarray(x)[0],
+                                fn(params, batched))
 
     factory = make_eval_policy_factory(family, cfg, query)
     if games is None and cfg.env.kind not in ("atari", "synthetic_atari"):
@@ -357,5 +374,7 @@ def run_suite_eval(cfg: RunConfig, games: Iterable[str] | None = None,
                              episodes_per_game=episodes_per_game,
                              max_frames=max_frames,
                              policy_factory=factory)
+    if server is not None:
+        server.stop()
     out["restored_step"] = restored_step
     return out

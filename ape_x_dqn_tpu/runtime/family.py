@@ -32,13 +32,16 @@ and what differs is the state a sequence starts from:
   long the prefix, beside an attention layer's (k, v) per position),
   the loss stops its
   gradient, the trained steps start from it. The item has no
-  state entry at all. The server is stateless too: a query carries the
-  last <= L token ids ({obs, ctx, n} -> {q, ctx, n}) and the server
-  re-runs the window; a per-slot cache inside
-  parallel/inference_server.py, which would make a step cost one token
-  instead of a window, is what is missing.
+  state entry at all. For the six older nets the server is stateless
+  too: a query carries the last <= L token ids ({obs, ctx, n} -> {q,
+  ctx, n}) and the server re-runs the window. The seventh,
+  "minicpm_sala_q", is served FROM SLOTS: its state (a lightning
+  layer's float32 matrix, a sparse layer's keys, values and compressed
+  keys) lives in parallel/inference_server.py between queries, a query
+  names its slot ({obs, slot, fresh} -> {q, slot, fresh}) and a step
+  costs one token.
 
-The decoder_q family has six nets. Five (network.kind "glm_moe_q",
+The decoder_q family has seven nets. Five (network.kind "glm_moe_q",
 "afmoe_q", "smallthinker_q", "kimi_linear_q", "lfm2_moe_q") share
 models/expert_layer.py (the plan and the application of an expert
 layer); the second, third and sixth of them and "ouro_q" - a stack of
@@ -68,6 +71,19 @@ family's loss reads expert statistics only from a net that has a
 models.DECODER_NETS, in models.decoder_block and in `family_of`;
 tools/apexlint's `config_coverage` learns the block's name. Nothing
 else here names a decoder.
+
+A decoder WITH A SLOT STATE registers the same way and OFFERS five
+more things, which `keeps_slots` finds by name on the net and never by
+the net's name: `slot_state(slots, pool_tokens, max_len)` (the zeroed
+device pytree), `slot_state_bytes(...)` (its price, for `hbm_price`),
+`extend(params, slot_state, inputs, max_len=)` (one dispatch: a decode
+step or a prefill chunk), `slot_block` (the unit the host's ledger,
+parallel/slot_pool.py, hands out: 1 for a net whose state has no
+blocks) and `slot_lengths(slot_state)` (the positions each session
+holds, for whoever audits the state). `actor_state`,
+`episode_state`, `server_apply_fn` and `server_slots` then give its
+actors the `{slot, fresh}` row and its server the slot path; every
+construction site passes `**server_slots(cfg, net)`.
 
 How a further Q-learning family registers: its net in models/ with a
 row in `build_network`; its kind in `family_of`; a row in
@@ -104,7 +120,8 @@ def family_of(cfg: RunConfig) -> str:
             "afmoe_q": "decoder_q", "smallthinker_q": "decoder_q",
             "ouro_q": "decoder_q",
             "kimi_linear_q": "decoder_q",
-            "lfm2_moe_q": "decoder_q"}.get(cfg.network.kind, "dqn")
+            "lfm2_moe_q": "decoder_q",
+            "minicpm_sala_q": "decoder_q"}.get(cfg.network.kind, "dqn")
 
 
 # families whose replay items are whole sequences (the staging unit is
@@ -132,15 +149,87 @@ def _token_window(cfg: RunConfig) -> dict:
             "n": np.int32(0)}
 
 
+def _slot(cfg: RunConfig) -> dict:
+    # the slot is the env's (`episode_state`); `fresh` says that an
+    # episode begins, and the server answers 0
+    return {"slot": np.int32(0), "fresh": np.int32(1)}
+
+
 ACTOR_STATE = {
     "r2d2": ActorState(_lstm_state, ("c", "h")),
     "decoder_q": ActorState(_token_window, ()),
+    # a decoder the server keeps in slots (`keeps_slots`): nothing rides
+    # the query but the session's name
+    "decoder_q.slots": ActorState(_slot, ()),
 }
+
+
+def keeps_slots(net_or_cfg: Any) -> bool:
+    """Whether the inference server keeps this net's per-session state
+    in slots on the device: the net OFFERS it (`slot_state` and
+    `extend`: models/minicpm_sala_q.py), nothing here names a net.
+    Takes the net, its class, or a RunConfig."""
+    if isinstance(net_or_cfg, RunConfig):
+        from ape_x_dqn_tpu.models import DECODER_NETS
+
+        net_or_cfg = DECODER_NETS.get(net_or_cfg.network.kind)
+    return hasattr(net_or_cfg, "slot_state")
+
+
+def actor_state(cfg: RunConfig) -> ActorState:
+    """`ACTOR_STATE`'s row for cfg: the family's, or the slot row for a
+    net the server keeps in slots."""
+    family = family_of(cfg)
+    return ACTOR_STATE[family + ".slots" if keeps_slots(cfg) else family]
+
+
+def episode_state(cfg: RunConfig, index: int) -> dict:
+    """What the env of global index `index` (actor_index x
+    envs_per_actor + j; the eval worker's is the fleet's size) sends
+    with an episode's first query: the row's zeros, and where the state
+    lives in the server, the env's own slot."""
+    state = actor_state(cfg).zeros(cfg)
+    if "slot" in state:
+        state["slot"] = np.int32(index)
+    return state
+
+
+def slot_geometry(cfg: RunConfig, block: int = 1) -> tuple[int, int, int]:
+    """-> (slots, the longest session, the pool's positions) of cfg's
+    slot server; inference.slots / slot_max_len / slot_pool_tokens say
+    them, 0 meaning: a slot an env of the fleet and one for the eval
+    worker, an episode and its truncation query, and room for every
+    slot's longest session in whole blocks of `block` positions."""
+    inf = cfg.inference
+    slots = inf.slots or (cfg.actors.num_actors
+                          * max(cfg.actors.envs_per_actor, 1) + 1)
+    max_len = inf.slot_max_len or cfg.env.max_episode_frames + 1
+    return slots, max_len, (inf.slot_pool_tokens
+                            or slots * -(-max_len // block) * block)
+
+
+def server_slots(cfg: RunConfig, net: Any) -> dict:
+    """The keyword BatchedInferenceServer takes beside the family's
+    `server_apply_fn`: `slots=` for a net the server keeps in slots
+    (the ledger, the zeroed device state, the prefill bucket), nothing
+    for any other."""
+    if not keeps_slots(net):
+        return {}
+    from ape_x_dqn_tpu.parallel.inference_server import Slots
+    from ape_x_dqn_tpu.parallel.slot_pool import SlotPool
+
+    block = net.slot_block
+    slots, max_len, pool_tokens = slot_geometry(cfg, block)
+    return {"slots": Slots(
+        pool=SlotPool(slots, -(-pool_tokens // block), block, max_len),
+        state=net.slot_state(slots, pool_tokens, max_len),
+        prefill_chunk=cfg.inference.prefill_chunk,
+        prefill_rows=cfg.inference.prefill_rows)}
 
 
 def stored_state_spec(family: str, cfg: RunConfig) -> dict:
     """{name: shape} of the state entries a stored sequence carries."""
-    st = ACTOR_STATE[family]
+    st = actor_state(cfg)
     zeros = st.zeros(cfg)
     return {k: zeros[k].shape for k in st.stored}
 
@@ -157,6 +246,11 @@ def hbm_price(cfg: RunConfig, net: Any) -> dict:
     if family in ACTOR_STATE:
         price["stored_state_floats"] = sum(
             math.prod(s) for s in stored_state_spec(family, cfg).values())
+    if keeps_slots(net):
+        # what the server holds between queries, as the net prices it
+        slots, max_len, pool_tokens = slot_geometry(cfg, net.slot_block)
+        price["slot_state"] = net.slot_state_bytes(slots, pool_tokens,
+                                                   max_len)
     if hasattr(net, "step_transient_bytes"):
         price["step_transient"] = net.step_transient_bytes(
             cfg.learner.batch_size,
@@ -189,7 +283,8 @@ def actor_class(family: str, vector: bool = False) -> type:
             "dpg": ContinuousActor}.get(family, Actor)
 
 
-def server_apply_fn(family: str, net: Any) -> Callable:
+def server_apply_fn(family: str, net: Any,
+                    cfg: RunConfig | None = None) -> Callable:
     """The batched forward the inference server jits, per family.
 
     - dqn:  obs [B, ...]          -> q [B, A]
@@ -199,11 +294,28 @@ def server_apply_fn(family: str, net: Any) -> Callable:
       appends `obs` (dropping the oldest id of a full window), re-runs
       the whole window from an empty cache and answers with the
       Q-values at the last position. Fixed shapes, so one compiled
-      graph; the cost is a window per step. What is missing is a
-      per-slot latent cache inside the server.
+      graph; the cost is a window per step. The six older nets keep
+      this protocol (their five kinds of state in slots are ROADMAP
+      R2.1's).
+    - decoder_q, a net that `keeps_slots` (`cfg` required):
+      (params, state, {obs, slot, base, fresh[, n_valid]}) ->
+      ({q, sel, counters, slot, fresh}, state) — the net's own `extend`
+      over the slot state the server donates from one dispatch to the
+      next; a step costs one token. `slot` comes back as sent and
+      `fresh` as 0, so an actor's state round-trips like any other.
+      `server_slots(cfg, net)` makes the state and the ledger that go
+      with it.
     - dpg:  obs [B, ...]          -> {a: mu(s), q: Q(s, mu(s))}
       (params are the {actor, critic} dict publish_params produces)
     """
+    if family == "decoder_q" and keeps_slots(net):
+        max_len = slot_geometry(cfg)[1]
+
+        def apply_slots(p, state, inp):
+            out, state = net.extend(p, state, inp, max_len=max_len)
+            return {**out, "slot": inp["slot"],
+                    "fresh": jnp.zeros_like(inp["fresh"])}, state
+        return apply_slots
     if family == "decoder_q":
         def apply_window(p, inp):
             ctx, n = inp["ctx"], inp["n"]
@@ -542,7 +654,7 @@ def warmup_example(family: str, cfg: RunConfig, spec: Any) -> Any:
     shapes/dtypes only, content irrelevant."""
     obs = np.zeros(spec.obs_shape, spec.obs_dtype)
     if family in ACTOR_STATE:
-        return {"obs": obs, **ACTOR_STATE[family].zeros(cfg)}
+        return {"obs": obs, **actor_state(cfg).zeros(cfg)}
     return obs
 
 

@@ -69,7 +69,7 @@ from ape_x_dqn_tpu.runtime.evaluation import (
     EvalWorker, make_eval_policy_factory)
 from ape_x_dqn_tpu.runtime.family import (
     actor_class, build_learner, family_of, family_setup, hbm_price,
-    server_apply_fn, warmup_example)
+    server_apply_fn, server_slots, warmup_example)
 from ape_x_dqn_tpu.utils.checkpoint import CheckpointManager
 from ape_x_dqn_tpu.utils.hbm import check_hbm_fits
 from ape_x_dqn_tpu.utils.metrics import Metrics, log_run_header
@@ -196,15 +196,16 @@ class MultihostApexDriver:
                 deadline_ms=cfg.inference.deadline_ms,
                 mesh=self._inference_mesh, obs=self.obs)
             self.server = self.serving.register_policy(
-                cfg.env.id, server_apply_fn(self.family, self.net),
+                cfg.env.id, server_apply_fn(self.family, self.net, cfg),
                 server_params, family=self.family,
                 priority=cfg.serving.default_class)
         else:
             self.server = BatchedInferenceServer(
-                server_apply_fn(self.family, self.net), server_params,
+                server_apply_fn(self.family, self.net, cfg), server_params,
                 max_batch=cfg.inference.max_batch,
                 deadline_ms=cfg.inference.deadline_ms,
-                mesh=self._inference_mesh, obs=self.obs)
+                mesh=self._inference_mesh, obs=self.obs,
+                **server_slots(cfg, self.net))
         self.transport = transport if transport is not None \
             else LoopbackTransport()
         # fleet telemetry (obs/fleet.py): merge remote actor hosts'

@@ -256,8 +256,9 @@ class _RecurrentEnvCore:
 class RecurrentVectorActor:
     """Sequence-family vector actor (R2D2, decoder_q): K envs per
     thread, one batched query per vector step that carries each env's
-    state beside its observation ({obs, c, h} or {obs, ctx, n}, each
-    with a leading [K] axis; runtime/family.py `ACTOR_STATE`), per-env
+    state beside its observation ({obs, c, h}, {obs, ctx, n} or, for a
+    net the server keeps in slots, {obs, slot, fresh}, each with a
+    leading [K] axis; runtime/family.py `ACTOR_STATE`), per-env
     SequenceBuilders shipping sequences with what of that state the
     family stores.
 
@@ -280,10 +281,10 @@ class RecurrentVectorActor:
         self._hb = f"actor-{actor_index}"
         seed = cfg.seed if seed is None else seed
         self.K = max(cfg.actors.envs_per_actor, 1)
-        from ape_x_dqn_tpu.runtime.family import ACTOR_STATE, family_of
+        from ape_x_dqn_tpu.runtime.family import actor_state, episode_state
 
         self.gamma = cfg.learner.gamma
-        self._state_spec = ACTOR_STATE[family_of(cfg)]
+        self._state_spec = actor_state(cfg)
         total_slots = cfg.actors.num_actors * self.K
         frame_mode = cfg.replay.storage == "frame_ring"
         envs, self.cores = [], []
@@ -304,7 +305,8 @@ class RecurrentVectorActor:
                     priority_eta=cfg.replay.priority_eta,
                     frame_mode=frame_mode,
                     state_keys=self._state_spec.stored),
-                lambda: self._state_spec.zeros(cfg)))
+                # env g's state, and its slot where a server keeps it
+                lambda g=g: episode_state(cfg, g)))
         self.venv = SyncVectorEnv(envs)
         self.spec = self.venv.spec
         self.rng = np.random.default_rng(seed * 7919 + actor_index)

@@ -62,11 +62,14 @@ class HbmBudget:
     # number came from (device_hbm_bytes); unset on a bare run_budget
     limit: int | None = None
     limit_source: str = "none"
+    # what the inference server keeps between queries for a net it
+    # serves from slots (runtime/family.hbm_price; 0 for any other)
+    slot_state: int = 0
 
     @property
     def total(self) -> int:
         return (self.replay_storage + self.replay_tree
-                + self.model_state + self.headroom)
+                + self.model_state + self.headroom + self.slot_state)
 
     def table(self) -> str:
         gib = 1024 ** 3
@@ -74,6 +77,8 @@ class HbmBudget:
                 ("sum-tree", self.replay_tree),
                 ("model+opt state", self.model_state),
                 ("transient headroom", self.headroom),
+                *([("server slot state", self.slot_state)]
+                  if self.slot_state else []),
                 ("TOTAL per device", self.total)]
         body = "\n".join(f"  {k:<20} {v / gib:8.2f} GiB" for k, v in rows)
         extra = ", ".join(f"{k}={v}" for k, v in self.detail.items())
@@ -154,21 +159,23 @@ def model_state_bytes(param_count: int, adam: bool = True) -> int:
 def run_budget(cfg: Any, obs_shape: tuple[int, ...], obs_dtype=np.uint8,
                param_count: int = 5_000_000,
                step_transient: int | None = None,
-               stored_state_floats: int | None = None) -> HbmBudget:
+               stored_state_floats: int | None = None,
+               slot_state: int = 0) -> HbmBudget:
     """Budget a RunConfig per device. `param_count` defaults to a
     generous flagship-CNN-class estimate when the caller has not built
     the network yet (Nature-CNN ~1.7M, LSTM-Q ~6.5M params).
     `step_transient`: what a train step holds beside the persistent
     state, from a family whose step is not noise beside its replay
     (runtime/family.hbm_price); by default the flat
-    TRANSIENT_HEADROOM."""
+    TRANSIENT_HEADROOM. `slot_state`: the bytes a slot server holds
+    between queries, as the net prices them."""
     storage, tree, cap, detail = replay_budget(
         cfg, obs_shape, obs_dtype, stored_state_floats)
     return HbmBudget(replay_storage=storage, replay_tree=tree,
                      model_state=model_state_bytes(param_count),
                      headroom=(TRANSIENT_HEADROOM if step_transient is None
                                else step_transient),
-                     capacity=cap, detail=detail)
+                     capacity=cap, detail=detail, slot_state=slot_state)
 
 
 # usable HBM by device_kind substring, for a TPU backend whose
@@ -221,8 +228,9 @@ def check_hbm_fits(cfg: Any, obs_shape: tuple[int, ...], obs_dtype=np.uint8,
     Returns the budget, stamped with the limit it was checked against
     and that limit's source (`limit` is None only off the TPU — the
     virtual dryrun is a compile check, not a memory model).
-    `family_price`: run_budget's `step_transient` and
-    `stored_state_floats`, as the run's family prices them.
+    `family_price`: run_budget's `step_transient`,
+    `stored_state_floats` and `slot_state`, as the run's family prices
+    them.
     """
     budget = run_budget(cfg, obs_shape, obs_dtype, param_count,
                         **family_price)
