@@ -45,8 +45,9 @@ is as published; what they leave open is marked (+) and listed under
   dim); per head S_t = lambda_h S_{t-1} + k_t^T v_t, o_t = q_t S_t /
   sqrt(d), (+) lambda_h = exp(-2^(-8 (h + 1) / heads)) in every layer;
   out = W_o[N_o(o) * sigmoid(y W_gate)], N_o over the concatenated
-  heads. ops/lightning_attention.py: `step` at one position, `chunked`
-  for more.
+  heads. ops/lightning_attention.py: `step_slots` for a decode step, on
+  the slot pool in place; `chunked` for a prefill chunk and the learner,
+  between a gather and a scatter of the rows' matrices.
 - minicpm4: q = n_q(y W_q), k = n_k(y W_k), v = y W_v, no position
   encoding; attention as ops/block_select_attention.py's docstring
   says; out = W_o[o * sigmoid(y W_gate)].
@@ -62,7 +63,9 @@ inside; `sala.sparse` with `.proj`, `.compress`, `.select`, `.attend`
 (the gathered blocks of a decode step), `.dense` (the tile walk of a
 prefill chunk or the learner's pass, and a decode step's list of every
 block while a context is short), `.out`; `sala.mlp`; `sala.head`;
-`slots.read` / `slots.write` around whatever moves slot state.
+`slots.read` / `slots.write` around whatever moves slot state, but a
+decode step's lightning matrices: the kernel under `sala.lightning.state`
+reads and writes them where they lie.
 """
 
 from __future__ import annotations
@@ -292,10 +295,13 @@ class MiniCpmSalaQNet:
             _dot(u, p["o_gate"]).astype(jnp.float32)), u.dtype)
         return q, k, v, gate
 
-    def _lightning(self, p: dict, u: jax.Array, matrix: jax.Array,
-                   positions: jax.Array, valid: jax.Array, decode: bool):
-        """u = N1(x) [B, n, hidden], matrix [B, H, d, d] -> (the mixer's
-        output [B, n, hidden], the matrix after the valid positions)."""
+    def _lightning(self, p: dict, u: jax.Array, positions: jax.Array,
+                   recur):
+        """u = N1(x) [B, n, hidden]; `recur(q, k, v [B, n, H, d], slope,
+        scale) -> (o [B, n, H, d] float32, the state after the valid
+        positions)` is the recurrence over wherever the caller keeps the
+        rows' matrices -> (the mixer's output [B, n, hidden], that
+        state)."""
         s, dt, f32 = self.s, u.dtype, jnp.float32
         b, n, _ = u.shape
         heads, d = s.lightning_nh, s.lightning_head_dim
@@ -304,15 +310,7 @@ class MiniCpmSalaQNet:
             q = _held(_rope_rows(q.astype(f32), positions, s.rope_theta), dt)
             k = _held(_rope_rows(k.astype(f32), positions, s.rope_theta), dt)
         with jax.named_scope("sala.lightning.state"):
-            slope, scale = la.slopes(heads), 1.0 / math.sqrt(d)
-            if decode:
-                o, after = la.step(q[:, 0], k[:, 0], v[:, 0], matrix,
-                                   slope, scale)
-                after = jnp.where(valid[:, 0, None, None, None], after,
-                                  matrix)
-                o = o[:, None]
-            else:
-                o, after = la.chunked(q, k, v, matrix, slope, scale, valid)
+            o, after = recur(q, k, v, la.slopes(heads), 1.0 / math.sqrt(d))
         with jax.named_scope("sala.lightning.out"):
             o = _held(o, dt).reshape(b, n, heads * d)
             o = _norm(o, p["o_norm"], s.rms_norm_eps)
@@ -444,13 +442,26 @@ class MiniCpmSalaQNet:
             u = _norm(x, p["input_layernorm"], s.rms_norm_eps)
             if kind == LIGHTNING:
                 with jax.named_scope("sala.lightning"):
-                    with jax.named_scope("slots.read"):
-                        matrix = jnp.where(fresh[:, None, None, None], 0.0,
-                                           matrices[li][slot])
-                    out, after = self._lightning(
-                        p["self_attn"], u, matrix, positions, valid, decode)
-                    with jax.named_scope("slots.write"):
-                        matrices[li] = matrices[li].at[slot].set(after)
+                    if decode:
+                        # one pass over each row's matrices, in the pool
+                        def in_place(q, k, v, slope, scale):
+                            o, pool = la.step_slots(
+                                matrices[li], slot, fresh, valid[:, 0],
+                                q[:, 0], k[:, 0], v[:, 0], slope, scale)
+                            return o[:, None], pool
+
+                        out, matrices[li] = self._lightning(
+                            p["self_attn"], u, positions, in_place)
+                    else:
+                        with jax.named_scope("slots.read"):
+                            matrix = jnp.where(fresh[:, None, None, None],
+                                               0.0, matrices[li][slot])
+                        out, after = self._lightning(
+                            p["self_attn"], u, positions,
+                            lambda q, k, v, slope, scale: la.chunked(
+                                q, k, v, matrix, slope, scale, valid))
+                        with jax.named_scope("slots.write"):
+                            matrices[li] = matrices[li].at[slot].set(after)
                 li += 1
             else:
                 with jax.named_scope("sala.sparse"):

@@ -235,12 +235,56 @@ def test_the_scopes_are_in_the_lowered_extend(built):
             "sala.sparse/sala.sparse.proj", "sala.sparse/sala.sparse.compress",
             "sala.sparse/sala.sparse.out", "sala.mlp", "sala.head",
             "slots.read", "slots.write", "sala.sparse/slots.write",
-            "sala.lightning/slots.read",
             # inside a chunk's `lax.map` the name stack starts again
             "sala.sparse.select/", "sala.sparse.dense/")
     for name in both:
         assert name in decode and name in chunk, name
     assert "sala.sparse.attend/" in decode      # a branch of its `cond`
+    # a chunk gathers and scatters the rows' matrices around the scan; a
+    # decode step's kernel addresses the pool itself, under `.state`
+    for name in ("sala.lightning/slots.read", "sala.lightning/slots.write"):
+        assert name in chunk and name not in decode, name
+
+
+def test_the_slot_kernel_is_the_decode_steps_alone(built, monkeypatch):
+    """`la.step_slots` is traced once a lightning layer by a decode step
+    and never by a prefill chunk or the learner's pass (without and with
+    a burn-in state): their programs are the gather, `la.chunked` and the
+    scatter they were (at PR 56 their lowered text was the parent
+    commit's to the byte), and a decode step scatters no matrix."""
+    net, params = built["net"], built["params"]
+    calls, kernel = [], la.step_slots
+
+    def counted(*args):
+        calls.append(args[0].shape)
+        return kernel(*args)
+
+    monkeypatch.setattr(la, "step_slots", counted)
+    state = net.slot_state(2, 2 * 64, 64)
+    rows = {"slot": jnp.zeros(2, jnp.int32), "base": jnp.zeros(2, jnp.int32),
+            "fresh": jnp.ones(2, jnp.int32)}
+
+    def extend_text(inputs):
+        return jax.jit(lambda p, s, i: net.extend(
+            p, s, i, max_len=64)).lower(params, state, inputs).as_text()
+
+    def pool_scatters(text):      # whole [H, d, d] matrices by slot
+        return sum("stablehlo.scatter" in line
+                   and "update_window_dims = [1, 2, 3]" in line
+                   for line in text.splitlines())
+
+    chunk = extend_text({"obs": jnp.zeros((2, 16), jnp.int32),
+                         "n_valid": jnp.full(2, 16, jnp.int32), **rows})
+    tokens = jnp.zeros((2, 24), jnp.int32)
+    _, prefix, _ = jax.eval_shape(net.apply_with_stats, params, tokens)
+    jax.jit(lambda p, t: net.apply_with_stats(p, t)).lower(params, tokens)
+    jax.jit(lambda p, t, s: net.apply_with_stats(p, t, s)).lower(
+        params, tokens, prefix)
+    assert not calls
+    assert pool_scatters(chunk) == net.num_lightning
+    decode = extend_text({"obs": jnp.zeros(2, jnp.int32), **rows})
+    assert calls == [state["lightning"][0].shape] * net.num_lightning
+    assert pool_scatters(decode) == 0
 
 
 # -- the family's rows ---------------------------------------------------------

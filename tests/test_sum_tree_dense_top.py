@@ -397,20 +397,25 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled(jitted, *args):
-    """-> (gather/scatter fusions, ops the entry computation runs one
-    after another, HLO temp bytes) of `jitted` compiled for the device
-    its arguments' shardings describe."""
+def _compile(jitted, *args):
+    """`jitted` compiled for the device its arguments' shardings
+    describe, the persistent cache off around it."""
     from jax.experimental.compilation_cache import compilation_cache
 
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        compiled = jitted.lower(*args).compile()
+        return jitted.lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
+
+
+def _compiled(jitted, *args):
+    """-> (gather/scatter fusions, ops the entry computation runs one
+    after another, HLO temp bytes) of `_compile(jitted, *args)`."""
+    compiled = _compile(jitted, *args)
     text = compiled.as_text()
     entry = text[text.index("ENTRY"):]
     free = r"= \S+ (parameter|constant|bitcast|get-tuple-element|tuple)\("
@@ -473,3 +478,60 @@ def test_compiled_for_a_v5e_a_draw_of_sixteen_is_the_indexed_descents(
     now = _draw_compiled_for_v5e(sum_tree.sample, 2 ** 16, 16, one_chip)
     before = _draw_compiled_for_v5e(indexed_descent, 2 ** 16, 16, one_chip)
     assert now == before and now[0] == 17
+
+
+# -- the slot server's decode step (ISSUE 56), in this file because it is
+# the one that describes a chip -----------------------------------------------
+
+def test_compiled_for_a_v5e_a_decode_step_moves_no_lightning_pool(
+        one_chip, monkeypatch):
+    """MiniCPM-SALA's served stage as `minicpm_sala_decode` runs it (the
+    overrides of benchmarks/configs/minicpm_sala_9b_pp4_1chip.json:
+    published layers 9-16, 48 slots), a decode step of 16 rows with the
+    state donated as the server donates it: each lightning layer is one
+    `lightning_step_slots` kernel on its pool in place - no copy of a
+    [49, 32, 128, 128] pool (one is 0.25 ms a layer on the chip), the
+    whole state aliased, and the [rows, 32, 128, 128] float32 gather,
+    `after` and scatter operand (128 MiB a layer at 16 rows) gone from
+    the temp."""
+    import json
+    import os
+
+    from ape_x_dqn_tpu.models import build_network
+    from ape_x_dqn_tpu.ops import lightning_attention as la
+    from ape_x_dqn_tpu.runtime import family
+    from ape_x_dqn_tpu.runtime.train import apply_overrides
+
+    monkeypatch.setattr(la, "_interpret", lambda: False)
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmarks", "configs",
+            "minicpm_sala_9b_pp4_1chip.json")) as fh:
+        served = json.load(fh)
+    cfg = apply_overrides(get_config(served["preset"]), served["overrides"])
+    net = build_network(cfg.network, None)
+
+    def described(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: described(x, jnp.bfloat16),
+        jax.eval_shape(net.init, jax.random.PRNGKey(0)))
+    slots, max_len, pool_tokens = family.slot_geometry(cfg, net.slot_block)
+    state = jax.tree.map(described, jax.eval_shape(
+        lambda: net.slot_state(slots, pool_tokens, max_len)))
+    row = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    compiled = _compile(
+        jax.jit(family.server_apply_fn("decoder_q", net, cfg),
+                donate_argnums=(1,)),
+        params, state, {"obs": row, "slot": row, "base": row, "fresh": row})
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(kernels) == 6
+    assert all("sala.lightning.state" in line for line in kernels)
+    assert not re.search(r"= f32\[49,32,128,128\]\S* copy\(", text)
+    memory = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert memory.alias_size_in_bytes >= held       # `len` is padded
+    assert memory.temp_size_in_bytes < 2 ** 27      # 75.5 MiB at PR 56
