@@ -510,9 +510,46 @@ class MiniCpmSalaConfig:
 
 
 @dataclass(frozen=True)
+class JambaConfig:
+    """The decoder of network.kind="jamba_q" (models/jamba_q.py), under
+    the key names of the model's own config.json
+    (ai21labs/AI21-Jamba2-3B, `model_type` jamba); defaults are that
+    model's. Layer i is grouped-query attention WITHOUT position
+    encoding iff `i % attn_layer_period == attn_layer_offset`, else a
+    Mamba-1 mixer: `mamba_expand` x hidden channels behind a causal
+    depthwise conv of `mamba_d_conv` taps (with a bias,
+    `mamba_conv_bias`) and a SiLU, a selective scan with a float32
+    state of `mamba_d_state` coordinates a channel
+    (ops/selective_scan.py) whose step, B and C come from a projection
+    of rank `mamba_dt_rank` + 2 `mamba_d_state` through Jamba's three
+    inner RMSNorms, a SiLU gate. Every FFN is one dense SwiGLU
+    (`num_experts` 1: the `expert_layer_*` keys choose among no
+    experts), embedding and head tied. The net has no expert layer and
+    so no share of one; a served chip holds the whole model."""
+
+    hidden_size: int = 2560
+    num_hidden_layers: int = 28
+    attn_layer_offset: int = 7
+    attn_layer_period: int = 14
+    num_attention_heads: int = 20
+    num_key_value_heads: int = 1
+    intermediate_size: int = 8192
+    mamba_d_conv: int = 4
+    mamba_d_state: int = 16
+    mamba_dt_rank: int = 160
+    mamba_expand: int = 2
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    num_experts: int = 1
+    max_position_embeddings: int = 262_144
+    vocab_size: int = 65_536
+    rms_norm_eps: float = 1e-6
+
+
+@dataclass(frozen=True)
 class NetworkConfig:
     # mlp | nature_cnn | lstm_q | dpg | glm_moe_q | afmoe_q | smallthinker_q
-    # | ouro_q | kimi_linear_q | lfm2_moe_q | minicpm_sala_q
+    # | ouro_q | kimi_linear_q | lfm2_moe_q | minicpm_sala_q | jamba_q
     kind: str = "mlp"
     mlp_hidden: tuple[int, ...] = (256, 256)
     cnn_channels: tuple[int, ...] = (32, 64, 64)
@@ -545,6 +582,10 @@ class NetworkConfig:
     # attention, dense FFNs; the first net the server keeps in slots)
     minicpm_sala: MiniCpmSalaConfig = field(
         default_factory=MiniCpmSalaConfig)
+    # the decoder of kind="jamba_q" (the same family: Mamba-1 mixers
+    # beside full attention, dense FFNs, a tied head; the second net
+    # the server keeps in slots)
+    jamba: JambaConfig = field(default_factory=JambaConfig)
 
 
 @dataclass(frozen=True)
@@ -1782,6 +1823,73 @@ def _preset_minicpm_sala_tiny_q() -> RunConfig:
     )
 
 
+def _preset_jamba2_3b_q() -> RunConfig:
+    """Config 13: AI21-Jamba2-3B (3.03 B dense) as a token-level
+    Q-network, the decoder family's eighth net and the second the
+    inference server keeps IN SLOTS. The sizes are the model's
+    config.json (https://huggingface.co/ai21labs/AI21-Jamba2-3B): 28
+    layers, attention (20 heads of 128 over ONE key-value head, no
+    position encoding) at layers 7 and 21, Mamba-1 mixers (5,120
+    channels, a state of 16 a channel, a step of rank 160, conv4)
+    elsewhere, SwiGLU of 8,192 on every layer, 65,536 vocabulary rows,
+    tied. No learner fits it on one chip (one period of 14 layers with
+    an eighth of the vocabulary is 21.6 GiB at the learner's 16 B a
+    parameter, and no width may be cut): check_hbm_fits refuses the
+    preset as a TRAINING run, and it is SERVED whole
+    (benchmarks/configs/jamba2_3b_1chip.json: 5.64 GiB in bfloat16, 256
+    sessions of 8.89 MiB of state at any context beside 1 KiB a
+    position of keys and values). The server settings below are that
+    deployment's: 128-row steps for 256 live sessions. The learner
+    settings are this repo's, as Ouro's."""
+    jamba = JambaConfig()
+    return RunConfig(
+        name="jamba2_3b_q",
+        total_env_frames=10_000_000_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=jamba.vocab_size, max_episode_frames=4_096),
+        network=NetworkConfig(kind="jamba_q", dueling=False, jamba=jamba),
+        replay=ReplayConfig(kind="sequence", capacity=8_192,
+                            seq_length=4_096, seq_overlap=2_048,
+                            burn_in=1_024, min_fill=64),
+        learner=LearnerConfig(batch_size=1, n_step=5, value_rescale=True,
+                              target_sync_every=2500, lr=1e-4,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=32, envs_per_actor=8),
+        inference=InferenceConfig(
+            max_batch=128, deadline_ms=2.0, slots=256, slot_max_len=10_240,
+            slot_pool_tokens=2_097_152, prefill_chunk=2_048,
+            prefill_rows=8),
+    )
+
+
+def _preset_jamba2_tiny_q() -> RunConfig:
+    """jamba2_3b_q's sibling for CPU tests: six layers with attention at
+    layers 1 and 4 (`i % 3 == 1`), hidden 64, 4 heads of 16 over 1
+    key-value head, Mamba mixers of 128 channels with a state of 4 and
+    a step of rank 8, an MLP of 96, a vocabulary of 64; float32."""
+    jamba = JambaConfig(
+        hidden_size=64, num_hidden_layers=6, attn_layer_offset=1,
+        attn_layer_period=3, num_attention_heads=4, num_key_value_heads=1,
+        intermediate_size=96, mamba_d_state=4, mamba_dt_rank=8,
+        max_position_embeddings=4_096, vocab_size=64)
+    return RunConfig(
+        name="jamba2_tiny_q",
+        total_env_frames=100_000,
+        env=EnvConfig(id="tokens", kind="synthetic_tokens",
+                      num_tokens=jamba.vocab_size, max_episode_frames=64),
+        network=NetworkConfig(kind="jamba_q", dueling=False, jamba=jamba,
+                              compute_dtype="float32"),
+        replay=ReplayConfig(kind="sequence", capacity=64, seq_length=64,
+                            seq_overlap=32, burn_in=24, min_fill=8),
+        learner=LearnerConfig(batch_size=4, n_step=3, value_rescale=True,
+                              target_sync_every=100, lr=1e-3,
+                              sample_chunk=1, train_chunk=2),
+        actors=ActorConfig(num_actors=1, envs_per_actor=2),
+        inference=InferenceConfig(max_batch=8, deadline_ms=2.0,
+                                  prefill_chunk=16, prefill_rows=2),
+    )
+
+
 PRESETS = {
     "cartpole_smoke": _preset_cartpole_smoke,
     "pong": _preset_pong,
@@ -1802,6 +1910,8 @@ PRESETS = {
     "lfm2_tiny_q": _preset_lfm2_tiny_q,
     "minicpm_sala_9b_q": _preset_minicpm_sala_9b_q,
     "minicpm_sala_tiny_q": _preset_minicpm_sala_tiny_q,
+    "jamba2_3b_q": _preset_jamba2_3b_q,
+    "jamba2_tiny_q": _preset_jamba2_tiny_q,
 }
 
 

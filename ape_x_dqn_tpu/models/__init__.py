@@ -1,4 +1,4 @@
-"""Network factory + model helpers. The decoder_q family's seven nets
+"""Network factory + model helpers. The decoder_q family's eight nets
 (`DECODER_NETS`) share models/expert_layer.py (five of them),
 models/windowed_gqa.py (four), models/mla.py (glm_moe_q,
 kimi_linear_q), models/short_conv.py (kimi_linear_q, lfm2_moe_q),
@@ -6,7 +6,9 @@ models/q_head.py's column read (afmoe_q, smallthinker_q, and over a
 tied head lfm2_moe_q) and, the fifth alone so far,
 ops/chunked_delta_rule.py; the seventh (minicpm_sala_q) shares
 ouro_q's held arithmetic and brings ops/lightning_attention.py and
-ops/block_select_attention.py. Importing this module runs no JAX."""
+ops/block_select_attention.py; the eighth (jamba_q) shares that held
+arithmetic, short_conv.py's filter and block_select_attention's pool,
+and brings ops/selective_scan.py. Importing this module runs no JAX."""
 
 from ape_x_dqn_tpu.models.base import (
     hard_update, init_params, param_count, preprocess_obs, soft_update)
@@ -20,16 +22,18 @@ from ape_x_dqn_tpu.models.ouro_q import OuroQNet
 from ape_x_dqn_tpu.models.kimi_linear_q import KimiLinearQNet
 from ape_x_dqn_tpu.models.lfm2_moe_q import Lfm2MoeQNet
 from ape_x_dqn_tpu.models.minicpm_sala_q import MiniCpmSalaQNet
+from ape_x_dqn_tpu.models.jamba_q import JambaQNet
 
-# network.kind -> the net's class: the seven token-level Q-networks of the
+# network.kind -> the net's class: the eight token-level Q-networks of the
 # decoder_q family (GLM-4.7-Flash, Trinity-Mini, SmallThinker, Ouro,
-# Kimi-Linear, LFM2, MiniCPM-SALA). A further decoder is a row here and in `decoder_block`, a
+# Kimi-Linear, LFM2, MiniCPM-SALA, Jamba2). A further decoder is a row here and in `decoder_block`, a
 # config block, and a row in runtime/family.family_of
 DECODER_NETS = {"glm_moe_q": GlmMoeQNet, "afmoe_q": AfmoeQNet,
                 "smallthinker_q": SmallThinkerQNet, "ouro_q": OuroQNet,
                 "kimi_linear_q": KimiLinearQNet,
                 "lfm2_moe_q": Lfm2MoeQNet,
-                "minicpm_sala_q": MiniCpmSalaQNet}
+                "minicpm_sala_q": MiniCpmSalaQNet,
+                "jamba_q": JambaQNet}
 
 
 def decoder_block(net_cfg):
@@ -42,6 +46,7 @@ def decoder_block(net_cfg):
             "kimi_linear_q": ("kimi_linear", net_cfg.kimi_linear),
             "lfm2_moe_q": ("lfm2_moe", net_cfg.lfm2_moe),
             "minicpm_sala_q": ("minicpm_sala", net_cfg.minicpm_sala),
+            "jamba_q": ("jamba", net_cfg.jamba),
             }[net_cfg.kind]
 
 

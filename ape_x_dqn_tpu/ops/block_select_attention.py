@@ -189,10 +189,11 @@ def attended(sel: jax.Array, t: jax.Array, sz: Sizes
 
 def attend_gathered(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                     base: jax.Array, sel: jax.Array, t: jax.Array,
-                    sz: Sizes) -> jax.Array:
+                    sz: Sizes, counted: bool = False):
     """One query a sequence: q [B, G, g, d], pools [G, P, d], base [B]
     (positions), sel [B, G, K] block ids (-1: none), t [B] -> [B, G, g,
-    d] float32."""
+    d] float32; `counted`: -> (that, [B] int32 the keys THE MASK THAT
+    WAS APPLIED let each query's first kv head attend)."""
     f32 = jnp.float32
     g_heads, p, d = kpool.shape
     at = jnp.where(sel >= 0, base[:, None, None] // sz.block + sel, 0)
@@ -206,28 +207,33 @@ def attend_gathered(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                    preferred_element_type=f32) / jnp.sqrt(f32(d))
     s = s.reshape(*s.shape[:3], k * sz.block)
     p = _probs(s, ok.reshape(b, g_heads, 1, -1)).astype(q.dtype)
-    return jnp.einsum("bghk,bgkd->bghd", p,
-                      values.reshape(b, g_heads, k * sz.block, d),
-                      preferred_element_type=f32)
+    o = jnp.einsum("bghk,bgkd->bghd", p,
+                   values.reshape(b, g_heads, k * sz.block, d),
+                   preferred_element_type=f32)
+    if counted:
+        return o, ok[:, 0].sum(axis=(1, 2), dtype=jnp.int32)
+    return o
 
 
 def attend_tiles(q: jax.Array, t: jax.Array, allowed: jax.Array,
                  kpool: jax.Array, vpool: jax.Array, base: jax.Array,
-                 sz: Sizes, tile: int, tiles: int | None = None
-                 ) -> jax.Array:
+                 sz: Sizes, tile: int, tiles: int | None = None,
+                 counted: bool = False):
     """Many queries of ONE sequence: q [R, G, g, d], t [R], `allowed`
     [R, G, M] bool per (query, kv head, block), M a multiple of `tile /
     block`; pools [G, P, d], base a scalar (positions) -> [R, G, g, d]
     float32. Key tiles 0 .. max(t) // tile are walked (`tiles`: a static
     count instead, for a pass that is differentiated); P reaches a whole
-    tile past every sequence's last position."""
+    tile past every sequence's last position. `counted`: -> (that, [R]
+    int32 the keys the mask that was applied let each query's first kv
+    head attend)."""
     f32 = jnp.float32
     r, g_heads, group, d = q.shape
     per_tile = tile // sz.block
     scale = 1.0 / jnp.sqrt(f32(d))
 
     def walk(i, carry):
-        top, norm, acc = carry
+        top, norm, acc = carry[:3]
         keys = jax.lax.dynamic_slice_in_dim(kpool, base + i * tile, tile, 1)
         values = jax.lax.dynamic_slice_in_dim(vpool, base + i * tile, tile, 1)
         pos = i * tile + jnp.arange(tile)
@@ -246,12 +252,17 @@ def attend_tiles(q: jax.Array, t: jax.Array, allowed: jax.Array,
         acc = acc * shrink[..., None] + jnp.einsum(
             "ghrp,gpd->ghrd", e.astype(q.dtype), values,
             preferred_element_type=f32)
+        if counted:
+            return new_top, norm, acc, carry[3] + ok[0, 0].sum(
+                axis=-1, dtype=jnp.int32)
         return new_top, norm, acc
 
     start = (jnp.full((g_heads, group, r), NEG, f32),
              jnp.zeros((g_heads, group, r), f32),
              jnp.zeros((g_heads, group, r, d), f32))
+    if counted:
+        start += (jnp.zeros(r, jnp.int32),)
     count = jnp.max(t) // tile + 1 if tiles is None else tiles
-    _, norm, acc = jax.lax.fori_loop(0, count, walk, start)
-    out = acc / jnp.maximum(norm, 1e-30)[..., None]
-    return out.transpose(2, 0, 1, 3)
+    _, norm, acc, *seen = jax.lax.fori_loop(0, count, walk, start)
+    out = (acc / jnp.maximum(norm, 1e-30)[..., None]).transpose(2, 0, 1, 3)
+    return (out, seen[0]) if counted else out

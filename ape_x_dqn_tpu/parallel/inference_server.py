@@ -89,7 +89,8 @@ scratch rows. With a reply owed that the device has not finished, the
 serve thread spends the wait on its queue (`_collect_slots`): what
 arrives while a step runs is dispatched behind it. The reply is `outputs` minus `counters` (summed into
 `slot_counters`) and, unless some request of the batch said
-`want_sel`, minus `sel`. Spans `server.stack` / `server.dispatch` carry
+`want_sel`, minus `sel` (which only a net that selects answers:
+models/jamba_q.py has none). Spans `server.stack` / `server.dispatch` carry
 `n=` and `rows=` there; gauges `server.slots_live`,
 `server.slot_blocks_held`; marks `server.slot_admit` /
 `server.slot_free`.
@@ -809,7 +810,8 @@ class BatchedInferenceServer:
                 counters = out.pop("counters")
                 if not any(r.inputs.get("want_sel") is not None
                            for r in reqs):
-                    del out["sel"]
+                    # (a net that selects nothing answers none)
+                    out.pop("sel", None)
                 for leaf in jax.tree.leaves((out, counters)):
                     leaf.copy_to_host_async()
                 self._owed = out["q"]

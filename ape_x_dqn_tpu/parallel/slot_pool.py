@@ -2,7 +2,12 @@
 (parallel/inference_server.py, the slot path): which contiguous range of
 key blocks each live session owns, and how many positions of it are
 used. The device holds the blocks (models/minicpm_sala_q.py
-`slot_state`); this file only hands ranges out.
+`slot_state`); this file only hands ranges out. What a range holds is
+the net's: MiniCPM-SALA's sparse layers keep keys, values and compressed
+keys there beside a row of lightning matrices a slot; a net's state may
+hold NO blocks at all for most layers (models/jamba_q.py: 26 Mamba
+layers keep one row a slot at any context, and the ledger's ranges are
+its two attention layers' keys and values alone).
 
 A session is admitted with the longest length it DECLARES and gets that
 many positions' blocks, first fit, in one piece: no slot is padded to

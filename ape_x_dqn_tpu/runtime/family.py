@@ -39,9 +39,11 @@ and what differs is the state a sequence starts from:
   layer's float32 matrix, a sparse layer's keys, values and compressed
   keys) lives in parallel/inference_server.py between queries, a query
   names its slot ({obs, slot, fresh} -> {q, slot, fresh}) and a step
-  costs one token.
+  costs one token. So is the eighth, "jamba_q" (a Mamba layer's float32
+  state and conv tail, the same size at any context, beside two
+  attention layers' keys and values).
 
-The decoder_q family has seven nets. Five (network.kind "glm_moe_q",
+The decoder_q family has eight nets. Five (network.kind "glm_moe_q",
 "afmoe_q", "smallthinker_q", "kimi_linear_q", "lfm2_moe_q") share
 models/expert_layer.py (the plan and the application of an expert
 layer); the second, third and sixth of them and "ouro_q" - a stack of
@@ -77,10 +79,14 @@ more things, which `keeps_slots` finds by name on the net and never by
 the net's name: `slot_state(slots, pool_tokens, max_len)` (the zeroed
 device pytree), `slot_state_bytes(...)` (its price, for `hbm_price`),
 `extend(params, slot_state, inputs, max_len=)` (one dispatch: a decode
-step or a prefill chunk), `slot_block` (the unit the host's ledger,
+step or a prefill chunk; its outputs are `q`, `counters` and, from a net
+that selects, `sel`), `slot_block` (the unit the host's ledger,
 parallel/slot_pool.py, hands out: 1 for a net whose state has no
-blocks) and `slot_lengths(slot_state)` (the positions each session
-holds, for whoever audits the state). `actor_state`,
+blocks; a net's state may hold no blocks at all for MOST layers -
+models/jamba_q.py's Mamba layers keep a row a slot, and only its two
+attention layers' pools are handed out by the ledger) and
+`slot_lengths(slot_state)` (the positions each session holds, for
+whoever audits the state). `actor_state`,
 `episode_state`, `server_apply_fn` and `server_slots` then give its
 actors the `{slot, fresh}` row and its server the slot path; every
 construction site passes `**server_slots(cfg, net)`.
@@ -121,7 +127,8 @@ def family_of(cfg: RunConfig) -> str:
             "ouro_q": "decoder_q",
             "kimi_linear_q": "decoder_q",
             "lfm2_moe_q": "decoder_q",
-            "minicpm_sala_q": "decoder_q"}.get(cfg.network.kind, "dqn")
+            "minicpm_sala_q": "decoder_q",
+            "jamba_q": "decoder_q"}.get(cfg.network.kind, "dqn")
 
 
 # families whose replay items are whole sequences (the staging unit is
@@ -299,7 +306,7 @@ def server_apply_fn(family: str, net: Any,
       R2.1's).
     - decoder_q, a net that `keeps_slots` (`cfg` required):
       (params, state, {obs, slot, base, fresh[, n_valid]}) ->
-      ({q, sel, counters, slot, fresh}, state) — the net's own `extend`
+      ({q[, sel], counters, slot, fresh}, state) — the net's own `extend`
       over the slot state the server donates from one dispatch to the
       next; a step costs one token. `slot` comes back as sent and
       `fresh` as 0, so an actor's state round-trips like any other.

@@ -15,7 +15,7 @@ def test_eight_virtual_devices():
 def test_five_presets_exist():
     # The five attested reference configs (SURVEY.md §2.1), and the
     # decoder Q-networks with their CPU-test siblings (PRs 30, 32, 39, 41,
-    # 46, 50, 55).
+    # 46, 50, 55, 57).
     assert set(PRESETS) == {
         "cartpole_smoke", "pong", "atari57_apex", "r2d2", "apex_dpg",
         "glm47_flash_q", "glm_tiny_q", "trinity_mini_q", "trinity_tiny_q",
@@ -23,7 +23,8 @@ def test_five_presets_exist():
         "ouro_2p6b_q", "ouro_tiny_q",
         "kimi_linear_48b_q", "kimi_linear_tiny_q",
         "lfm2_24b_q", "lfm2_tiny_q",
-        "minicpm_sala_9b_q", "minicpm_sala_tiny_q"}
+        "minicpm_sala_9b_q", "minicpm_sala_tiny_q",
+        "jamba2_3b_q", "jamba2_tiny_q"}
 
 
 def test_preset_fields():

@@ -200,6 +200,111 @@ def test_a_reply_that_is_not_ready_is_waited_for_on_the_queue(tiny):
         server._stop.set()
 
 
+@pytest.fixture(scope="module")
+def tiny_jamba():
+    """The second net served from slots (models/jamba_q.py: a state that
+    holds no blocks for most layers, and no selection to answer)."""
+    cfg = get_config("jamba2_tiny_q")
+    net = build_network(cfg.network, None)
+    return cfg, net, net.init(jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("budget, each, waiting", [
+    (128, 8, 16), (128, 8, 20), (32, 8, 4), (8, 2, 5)])
+def test_a_full_batch_closes_the_collect_and_pads_no_row(
+        tiny_jamba, budget, each, waiting):
+    """`budget` rows a step and requests of `each` rows (the wide cell's
+    128 and 8): with `budget / each` requests waiting a collect takes
+    exactly those and returns WITHOUT the fill deadline, the dispatch is
+    the budget's own bucket, and what is left rides the next batch."""
+    slots = waiting * each
+    server = slot_server(tiny_jamba, max_batch=budget, slots=slots,
+                         slot_max_len=16, slot_pool_tokens=slots * 128)
+    server._deadline_s = 5.0        # a wait for it would show
+    try:
+        server._stop.set()              # park the serve thread
+        server._thread.join(timeout=5)
+        reqs = [_Request(rows(np.full(each, i), np.arange(
+            i * each, (i + 1) * each), np.ones(each)), each)
+            for i in range(waiting)]
+        for r in reqs:
+            server._q.put(r)
+        full = budget // each
+        t0 = time.perf_counter()
+        batch = server._collect(block=True)
+        assert time.perf_counter() - t0 < 1.0
+        assert batch == reqs[:full]
+        assert server._slot_bucket(budget, 1) == budget
+        flight = server._dispatch(batch)
+        assert (flight.n, flight.padded) == (budget, budget)
+        server._reply(flight)
+        assert [r.result["q"].shape for r in batch] == [(each, 64)] * full
+        assert "sel" not in batch[0].result     # the net selects nothing
+        rest = server._collect(block=False)
+        assert rest == reqs[full:]
+        if rest:
+            flight = server._dispatch(rest)
+            assert flight.n == (waiting - full) * each
+            assert flight.n <= flight.padded <= budget
+            server._reply(flight)
+        assert server.slot_counters["extend_tokens"] == slots
+        assert server.slot_counters["ssm_rows_updated"] == 4 * slots
+        assert server.slot_ledger["slots_live"] == slots
+    finally:
+        server.stop()
+
+
+def test_a_wide_fleet_is_answered_as_each_session_alone(tiny_jamba):
+    """8 client threads x 4 sessions through a 16-row server, prefill
+    chunks then decode steps, `want_sel` said by one of them: every
+    session's last Q is what the same tokens give in a server of their
+    own."""
+    cfg, net, params = tiny_jamba
+    rng = np.random.default_rng(1)
+    tokens = rng.integers(0, 64, (32, 24)).astype(np.int32)
+
+    def drive(server, sessions, slot_of):
+        k = len(sessions)
+        slots = np.asarray([slot_of(i) for i in sessions], np.int32)
+        out = server.query_batch(rows(
+            tokens[sessions, :16], slots, np.ones(k),
+            n_valid=np.full(k, 16, np.int32),
+            **({"want_sel": True} if 0 in sessions else {})), k)
+        for t in range(16, 24):
+            out = server.query_batch(
+                rows(tokens[sessions, t], slots, np.zeros(k)), k)
+        return out["q"]
+
+    server = slot_server(tiny_jamba, max_batch=16, slots=32,
+                         slot_max_len=32, slot_pool_tokens=32 * 128)
+    got = {}
+    try:
+        def client(i):
+            mine = list(range(4 * i, 4 * i + 4))
+            got[i] = drive(server, mine, lambda s: s)
+
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert server.slot_counters["extend_tokens"] == 32 * 24
+        assert np.asarray(net.slot_lengths(server.slot_state))[:32].tolist() \
+            == [24] * 32
+    finally:
+        server.stop()
+    alone = slot_server(tiny_jamba, max_batch=16, slots=32,
+                        slot_max_len=32, slot_pool_tokens=32 * 128)
+    try:
+        for i in (0, 3, 7):
+            want = drive(alone, list(range(4 * i, 4 * i + 4)),
+                         lambda s: s % 4)
+            np.testing.assert_allclose(got[i], want, atol=1e-5, rtol=0)
+    finally:
+        alone.stop()
+
+
 def test_admission_beyond_the_pool_fails_by_name(tiny):
     # room for two sessions of 65 positions; three slots
     server = slot_server(tiny, slots=3, slot_pool_tokens=2 * 72)

@@ -14,11 +14,12 @@ Both directions of config/doc drift:
 
 2. Every `replay.` / `comm.` / `obs.` / `actors.` / `serving.` /
    `glm.` / `afmoe.` / `smallthinker.` / `ouro.` / `kimi_linear.` /
-   `lfm2_moe.` / `minicpm_sala.` (as in `network.glm.shard_count`) knob
+   `lfm2_moe.` / `minicpm_sala.` / `jamba.` (as in `network.glm.shard_count`) knob
    mentioned in README must exist as a field on the matching dataclass
    (ReplayConfig / CommConfig / ObsConfig / ActorConfig /
    ServingConfig / GlmMoeConfig / AfmoeConfig / SmallThinkerConfig /
-   OuroConfig / KimiLinearConfig / Lfm2MoeConfig / MiniCpmSalaConfig).
+   OuroConfig / KimiLinearConfig / Lfm2MoeConfig / MiniCpmSalaConfig /
+   JambaConfig).
    Mentions
    that name a package MODULE instead of a knob (`obs.health`,
    `obs.report` — `ape_x_dqn_tpu/obs/health.py` exists) are skipped.
@@ -50,7 +51,8 @@ PREFIX_TO_CLASS = {"replay": "ReplayConfig", "comm": "CommConfig",
                    "ouro": "OuroConfig",
                    "kimi_linear": "KimiLinearConfig",
                    "lfm2_moe": "Lfm2MoeConfig",
-                   "minicpm_sala": "MiniCpmSalaConfig"}
+                   "minicpm_sala": "MiniCpmSalaConfig",
+                   "jamba": "JambaConfig"}
 KNOB_RE = re.compile(
     r"\b(" + "|".join(PREFIX_TO_CLASS) + r")"
     r"\.([a-z_][a-z0-9_]*)")
