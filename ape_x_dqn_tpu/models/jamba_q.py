@@ -33,6 +33,17 @@ whatever its slot holds). The last slot and the pool's tail are scratch:
 a padding row reads and writes there. `outputs`: `q` [B, A] at each
 row's last valid position and `counters`.
 
+The two shapes of work part at the Mamba state, on the input's rank and
+on nothing else. A PREFILL CHUNK (and `apply`) gathers the rows' `ssm`
+states, runs `selective_scan.chunked` from them and scatters them back.
+A DECODE STEP gathers and scatters no state: `_run` hands `_mamba` the
+layer's whole `ssm` pool with `slot` and `fresh`, and
+`selective_scan.step_slots` reads, advances and writes each row's
+`[d_state, channels]` block where it lies (one Pallas kernel a layer,
+the pool aliased: where XLA made three passes and a select over `[B,
+d_state, channels]` the state moves once, PERF.md section 6, PR 58). The
+conv tail is gathered, cut and scattered by rows in both.
+
 The equations (benchmarks/reference/jamba_q.py writes them again in
 float32, independently; what the catalog row's keys leave open is marked
 (+) and listed under `assumed` in benchmarks/configs/jamba2_3b_1chip.json):
@@ -59,8 +70,9 @@ rounded to the compute dtype, which the cast leaves as they are.
 
 Scopes: `jamba.embed`; `jamba.mamba` with `.in`, `.conv`, `.gates`
 (W_x, the three norms, W_dt, the softplus), `.scan` (the state's and the
-conv tail's read from the slot rows, the update, their write), `.out`
-inside; `jamba.attn` with `.proj`,
+conv tail's read from the slot rows, the update, their write; at a
+decode step the state's three are the one kernel, which carries the
+scope), `.out` inside; `jamba.attn` with `.proj`,
 `.attend`, `.out`; `jamba.mlp`; `jamba.head`; `slots.read` /
 `slots.write` around whatever moves slot state. Counters (`extend`):
 `extend_tokens`; `ssm_rows_updated` (rows with a valid position x Mamba
@@ -307,11 +319,15 @@ class JambaQNet:
                     p["down_proj"])
 
     def _mamba(self, p: dict, u: jax.Array, h: jax.Array, tail: jax.Array,
-               valid: jax.Array, decode: bool):
+               valid: jax.Array, decode: bool, slot: jax.Array,
+               fresh: jax.Array):
         """u = N1(x) [B, n, hidden], h [B, d_state, channels] float32 and
         tail [B, d_conv - 1, channels] what each row's session held ->
         (the mixer's output [B, n, hidden], h and the tail after each
-        row's valid positions)."""
+        row's valid positions). At a decode step h is the layer's whole
+        POOL [slots + 1, d_state, channels], in which row b's state is at
+        `slot[b]` and counts as zeros where `fresh[b]`, and comes back
+        as the pool: the op addresses it in place."""
         s, dt, f32 = self.s, u.dtype, jnp.float32
         b, n, _ = u.shape
         di, ds, r = self.d_inner, s.mamba_d_state, s.mamba_dt_rank
@@ -346,9 +362,9 @@ class JambaQNet:
             a = -jnp.exp(p["A_log"].astype(f32)).T       # [d_state, di]
         with jax.named_scope("jamba.mamba.scan"):
             if decode:
-                y, h = selective_scan.step(
-                    h, x[:, 0], delta[:, 0], a, bm[:, 0], cm[:, 0], p["D"],
-                    valid[:, 0])
+                y, h = selective_scan.step_slots(
+                    h, slot, fresh, valid[:, 0], x[:, 0], delta[:, 0], a,
+                    bm[:, 0], cm[:, 0], p["D"])
                 y = y[:, None]
             else:
                 y, h = selective_scan.chunked(h, x, delta, a, bm, cm,
@@ -441,16 +457,18 @@ class JambaQNet:
                 with jax.named_scope("jamba.mamba"):
                     with jax.named_scope("jamba.mamba.scan"), \
                             jax.named_scope("slots.read"):
-                        h = jnp.where(fresh[:, None, None], 0.0,
-                                      ssm[mi][slot])
+                        # a decode step's state stays where it is: the
+                        # scan's kernel reads and writes the pool's rows
+                        h = ssm[mi] if decode else jnp.where(
+                            fresh[:, None, None], 0.0, ssm[mi][slot])
                         tail = jnp.where(
                             fresh[:, None], 0, conv[mi][slot]).reshape(
                                 b, taps - 1, di)
                     out, h, tail = self._mamba(p["mamba"], u, h, tail,
-                                               valid, decode)
+                                               valid, decode, slot, fresh)
                     with jax.named_scope("jamba.mamba.scan"), \
                             jax.named_scope("slots.write"):
-                        ssm[mi] = ssm[mi].at[slot].set(h)
+                        ssm[mi] = h if decode else ssm[mi].at[slot].set(h)
                         conv[mi] = conv[mi].at[slot].set(
                             tail.reshape(b, -1).astype(conv[mi].dtype))
                 mi += 1

@@ -18,6 +18,7 @@ import pytest
 from ape_x_dqn_tpu.configs import JambaConfig, get_config
 from ape_x_dqn_tpu.models import DECODER_NETS, build_network, decoder_block
 from ape_x_dqn_tpu.models.jamba_q import JambaQNet
+from ape_x_dqn_tpu.ops import selective_scan
 from ape_x_dqn_tpu.runtime import family as fam
 from benchmarks.harness import jamba_params
 from benchmarks.reference import jamba_q as ref
@@ -304,6 +305,52 @@ def test_the_scopes_are_in_the_lowered_extend(built):
                  "jamba.attn/jamba.attn.out", "jamba.mlp", "jamba.head",
                  "slots.read", "slots.write"):
         assert name in decode and name in chunk, name
+
+
+def test_the_slot_kernel_is_the_decode_steps_alone(built, monkeypatch):
+    """`selective_scan.step_slots` is traced once a Mamba layer by a
+    decode step and never by a prefill chunk or the learner's pass
+    (without and with a burn-in state): their programs are the gather,
+    `selective_scan.chunked` and the scatter they were (at PR 58 their
+    lowered text was the parent commit's to the byte), and a decode step
+    gathers and scatters no state - only the conv tails' rows."""
+    net, params = built["net"], built["params"]
+    calls, kernel = [], selective_scan.step_slots
+
+    def counted(*args):
+        calls.append(args[0].shape)
+        return kernel(*args)
+
+    monkeypatch.setattr(selective_scan, "step_slots", counted)
+    state = net.slot_state(2, 2 * MAX_LEN, MAX_LEN)
+    rows = {"slot": jnp.zeros(2, jnp.int32), "base": jnp.zeros(2, jnp.int32),
+            "fresh": jnp.ones(2, jnp.int32)}
+
+    def extend_text(inputs):
+        return jax.jit(lambda p, s, i: net.extend(
+            p, s, i, max_len=MAX_LEN)).lower(params, state, inputs).as_text()
+
+    pool = "tensor<{}x{}x{}xf32>".format(*state["ssm"][0].shape)
+
+    def moved(text, op):          # states out of or into a layer's pool
+        lines = text.splitlines()
+        # (a scatter's types are on the line that closes its region,
+        # three lines down)
+        return sum(f"stablehlo.{op}" in line and pool in " ".join(
+            lines[i:i + 4]) for i, line in enumerate(lines))
+
+    chunk = extend_text({"obs": jnp.zeros((2, 16), jnp.int32),
+                         "n_valid": jnp.full(2, 16, jnp.int32), **rows})
+    tokens = jnp.zeros((2, 24), jnp.int32)
+    _, prefix, _ = jax.eval_shape(net.apply_with_stats, params, tokens)
+    jax.jit(lambda p, t: net.apply_with_stats(p, t)).lower(params, tokens)
+    jax.jit(lambda p, t, s: net.apply_with_stats(p, t, s)).lower(
+        params, tokens, prefix)
+    assert not calls
+    assert moved(chunk, "gather") == moved(chunk, "scatter") == net.num_mamba
+    decode = extend_text({"obs": jnp.zeros(2, jnp.int32), **rows})
+    assert calls == [state["ssm"][0].shape] * net.num_mamba
+    assert moved(decode, "gather") == moved(decode, "scatter") == 0
 
 
 def test_the_eighth_net_is_a_row_and_keeps_slots(built):

@@ -483,6 +483,33 @@ def test_compiled_for_a_v5e_a_draw_of_sixteen_is_the_indexed_descents(
 # -- the slot server's decode step (ISSUE 56), in this file because it is
 # the one that describes a chip -----------------------------------------------
 
+def _compiled_decode_step(cfg, rows: int, chip):
+    """-> (the server's slot program of `cfg`'s net at a decode step of
+    `rows` rows, compiled for `chip` with the state donated as the
+    server donates it and the matrices bfloat16; the net; its slot
+    state as described shapes)."""
+    from ape_x_dqn_tpu.models import build_network
+    from ape_x_dqn_tpu.runtime import family
+
+    net = build_network(cfg.network, None)
+
+    def described(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: described(x, jnp.bfloat16),
+        jax.eval_shape(net.init, jax.random.PRNGKey(0)))
+    slots, max_len, pool_tokens = family.slot_geometry(cfg, net.slot_block)
+    state = jax.tree.map(described, jax.eval_shape(
+        lambda: net.slot_state(slots, pool_tokens, max_len)))
+    row = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=chip)
+    compiled = _compile(
+        jax.jit(family.server_apply_fn("decoder_q", net, cfg),
+                donate_argnums=(1,)),
+        params, state, {"obs": row, "slot": row, "base": row, "fresh": row})
+    return compiled, net, state
+
+
 def test_compiled_for_a_v5e_a_decode_step_moves_no_lightning_pool(
         one_chip, monkeypatch):
     """MiniCPM-SALA's served stage as `minicpm_sala_decode` runs it (the
@@ -497,9 +524,7 @@ def test_compiled_for_a_v5e_a_decode_step_moves_no_lightning_pool(
     import json
     import os
 
-    from ape_x_dqn_tpu.models import build_network
     from ape_x_dqn_tpu.ops import lightning_attention as la
-    from ape_x_dqn_tpu.runtime import family
     from ape_x_dqn_tpu.runtime.train import apply_overrides
 
     monkeypatch.setattr(la, "_interpret", lambda: False)
@@ -508,23 +533,7 @@ def test_compiled_for_a_v5e_a_decode_step_moves_no_lightning_pool(
             "minicpm_sala_9b_pp4_1chip.json")) as fh:
         served = json.load(fh)
     cfg = apply_overrides(get_config(served["preset"]), served["overrides"])
-    net = build_network(cfg.network, None)
-
-    def described(x, dtype=None):
-        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
-                                    sharding=one_chip)
-
-    params = jax.tree.map(
-        lambda x: described(x, jnp.bfloat16),
-        jax.eval_shape(net.init, jax.random.PRNGKey(0)))
-    slots, max_len, pool_tokens = family.slot_geometry(cfg, net.slot_block)
-    state = jax.tree.map(described, jax.eval_shape(
-        lambda: net.slot_state(slots, pool_tokens, max_len)))
-    row = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
-    compiled = _compile(
-        jax.jit(family.server_apply_fn("decoder_q", net, cfg),
-                donate_argnums=(1,)),
-        params, state, {"obs": row, "slot": row, "base": row, "fresh": row})
+    compiled, net, state = _compiled_decode_step(cfg, 16, one_chip)
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
                if "custom-call(" in line and "tpu_custom_call" in line]
@@ -535,3 +544,39 @@ def test_compiled_for_a_v5e_a_decode_step_moves_no_lightning_pool(
     held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
     assert memory.alias_size_in_bytes >= held       # `len` is padded
     assert memory.temp_size_in_bytes < 2 ** 27      # 75.5 MiB at PR 56
+
+
+# -- and the second net's (ISSUE 58) ------------------------------------------
+
+@pytest.mark.parametrize("preset, rows", [("jamba2_3b_q", 128),
+                                          ("jamba2_tiny_q", 2)])
+def test_compiled_for_a_v5e_a_decode_step_moves_no_ssm_pool(
+        one_chip, monkeypatch, preset, rows):
+    """AI21-Jamba2-3B served whole as `jamba2_decode_wide` runs it (256
+    slots, a decode step of 128 rows with the state donated as the
+    server donates it), and the tiny preset: each Mamba layer is one
+    `selective_scan_step_slots` kernel on its pool in place under
+    `jamba.mamba.scan` - the chip's compiler takes the kernel at a
+    [16, 5120] block, no copy of a [slots + 1, d_state, channels] pool,
+    the whole state aliased, and the [rows, 16, 5120] float32 gather,
+    `after` and scatter operand (40 MiB each a layer at 128 rows) gone
+    from the temp."""
+    from ape_x_dqn_tpu.ops import selective_scan
+
+    monkeypatch.setattr(selective_scan, "_interpret", lambda: False)
+    compiled, net, state = _compiled_decode_step(get_config(preset), rows,
+                                                 one_chip)
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(kernels) == net.num_mamba
+    assert all("jamba.mamba/jamba.mamba.scan" in line for line in kernels)
+    pool = ",".join(str(n) for n in state["ssm"][0].shape)
+    assert not re.search(rf"= f32\[{pool}\]\S* copy\(", text)
+    memory = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert memory.alias_size_in_bytes >= held       # `len` is padded
+    if preset == "jamba2_3b_q":
+        assert net.num_mamba == 26 and pool == "257,16,5120"
+        # 0.611 GiB at PR 58 (1.382 with the gather, `step`, the scatter)
+        assert memory.temp_size_in_bytes < 0.75 * 2 ** 30
