@@ -40,6 +40,25 @@ class EnvConfig:
     num_tokens: int = 256
 
 
+def _check_share(block: str, cfg: Any, experts: str) -> None:
+    """A routed block's share of its deployment: `shard_index` names one
+    of `shard_count` chips, which divide the routed experts (the
+    block's field `experts`) evenly, and the vocabulary's rows go as
+    many ways, or `vocab_shard_count` ways where the block has that
+    field and it is not 0."""
+    if not 0 <= cfg.shard_index < cfg.shard_count:
+        raise ValueError(
+            f"network.{block}.shard_index must be in [0, "
+            f"{cfg.shard_count}) (got {cfg.shard_index})")
+    vocab_shards = getattr(cfg, "vocab_shard_count", 0) or cfg.shard_count
+    if (getattr(cfg, experts) % cfg.shard_count
+            or cfg.vocab_size % vocab_shards):
+        raise ValueError(
+            f"network.{block}.shard_count={cfg.shard_count} must divide "
+            f"{experts}={getattr(cfg, experts)}, and the vocabulary's "
+            f"{vocab_shards} shares vocab_size={cfg.vocab_size}")
+
+
 @dataclass(frozen=True)
 class GlmMoeConfig:
     """The decoder of network.kind="glm_moe_q" (models/glm_moe_q.py),
@@ -92,16 +111,7 @@ class GlmMoeConfig:
     force_balanced_routing: bool = False
 
     def __post_init__(self) -> None:
-        if not 0 <= self.shard_index < self.shard_count:
-            raise ValueError(
-                f"network.glm.shard_index must be in [0, "
-                f"{self.shard_count}) (got {self.shard_index})")
-        if (self.n_routed_experts % self.shard_count
-                or self.vocab_size % self.shard_count):
-            raise ValueError(
-                f"network.glm.shard_count={self.shard_count} must "
-                f"divide n_routed_experts={self.n_routed_experts} and "
-                f"vocab_size={self.vocab_size}")
+        _check_share("glm", self, "n_routed_experts")
 
 
 _PUBLISHED_AFMOE_LAYER_TYPES = (
@@ -163,18 +173,7 @@ class AfmoeConfig:
     vocab_shard_count: int = 0
 
     def __post_init__(self) -> None:
-        if not 0 <= self.shard_index < self.shard_count:
-            raise ValueError(
-                f"network.afmoe.shard_index must be in [0, "
-                f"{self.shard_count}) (got {self.shard_index})")
-        vocab_shards = self.vocab_shard_count or self.shard_count
-        if (self.num_experts % self.shard_count
-                or self.vocab_size % vocab_shards):
-            raise ValueError(
-                f"network.afmoe.shard_count={self.shard_count} must "
-                f"divide num_experts={self.num_experts} and "
-                f"vocab_shard_count={vocab_shards} must divide "
-                f"vocab_size={self.vocab_size}")
+        _check_share("afmoe", self, "num_experts")
         # (that there is one entry per layer held is the net's to
         # check: overrides set the two fields one after the other)
         kinds = {"sliding_attention", "full_attention"}
@@ -238,17 +237,7 @@ class SmallThinkerConfig:
     force_balanced_routing: bool = False
 
     def __post_init__(self) -> None:
-        if not 0 <= self.shard_index < self.shard_count:
-            raise ValueError(
-                f"network.smallthinker.shard_index must be in [0, "
-                f"{self.shard_count}) (got {self.shard_index})")
-        if (self.moe_num_primary_experts % self.shard_count
-                or self.vocab_size % self.shard_count):
-            raise ValueError(
-                f"network.smallthinker.shard_count={self.shard_count} "
-                f"must divide moe_num_primary_experts="
-                f"{self.moe_num_primary_experts} and vocab_size="
-                f"{self.vocab_size}")
+        _check_share("smallthinker", self, "moe_num_primary_experts")
         # (that there is one entry per layer held is the net's to
         # check: overrides set the fields one after the other)
         if self.num_attention_heads % self.num_key_value_heads:
@@ -360,18 +349,7 @@ class KimiLinearConfig:
     force_balanced_routing: bool = False
 
     def __post_init__(self) -> None:
-        if not 0 <= self.shard_index < self.shard_count:
-            raise ValueError(
-                f"network.kimi_linear.shard_index must be in [0, "
-                f"{self.shard_count}) (got {self.shard_index})")
-        vocab_shards = self.vocab_shard_count or self.shard_count
-        if (self.num_experts % self.shard_count
-                or self.vocab_size % vocab_shards):
-            raise ValueError(
-                f"network.kimi_linear.shard_count={self.shard_count} must "
-                f"divide num_experts={self.num_experts} and "
-                f"vocab_shard_count={vocab_shards} must divide "
-                f"vocab_size={self.vocab_size}")
+        _check_share("kimi_linear", self, "num_experts")
 
 
 _PUBLISHED_LFM2_LAYER_TYPES = ("conv", "conv") + (
@@ -430,18 +408,7 @@ class Lfm2MoeConfig:
     force_balanced_routing: bool = False
 
     def __post_init__(self) -> None:
-        if not 0 <= self.shard_index < self.shard_count:
-            raise ValueError(
-                f"network.lfm2_moe.shard_index must be in [0, "
-                f"{self.shard_count}) (got {self.shard_index})")
-        vocab_shards = self.vocab_shard_count or self.shard_count
-        if (self.num_experts % self.shard_count
-                or self.vocab_size % vocab_shards):
-            raise ValueError(
-                f"network.lfm2_moe.shard_count={self.shard_count} must "
-                f"divide num_experts={self.num_experts} and "
-                f"vocab_shard_count={vocab_shards} must divide "
-                f"vocab_size={self.vocab_size}")
+        _check_share("lfm2_moe", self, "num_experts")
 
 
 _PUBLISHED_SALA_MIXERS = (
@@ -548,8 +515,7 @@ class JambaConfig:
 
 @dataclass(frozen=True)
 class NetworkConfig:
-    # mlp | nature_cnn | lstm_q | dpg | glm_moe_q | afmoe_q | smallthinker_q
-    # | ouro_q | kimi_linear_q | lfm2_moe_q | minicpm_sala_q | jamba_q
+    # mlp | nature_cnn | lstm_q | dpg | a decoder's (models.DECODERS)
     kind: str = "mlp"
     mlp_hidden: tuple[int, ...] = (256, 256)
     cnn_channels: tuple[int, ...] = (32, 64, 64)
@@ -785,9 +751,8 @@ class LearnerConfig:
 @dataclass(frozen=True)
 class ActorConfig:
     num_actors: int = 8
-    # Envs per actor thread: >1 switches the dqn/dpg families to the
-    # vectorized actor (runtime/vector_actor.py) — one thread steps K
-    # envs and makes ONE batched inference query per vector step, so
+    # Envs per actor thread, K >= 1 (runtime/actor.py): one thread steps
+    # K envs and makes ONE batched inference query per vector step, so
     # RPC round-trips amortize K ways and the server sees batch-K work
     # (SURVEY.md §2.4 "inference batching parallelism", §7 hard part 3).
     # The eps schedule spans num_actors * envs_per_actor global slots.
@@ -1269,7 +1234,7 @@ def _preset_atari57_apex() -> RunConfig:
         # sample_chunk relaxation per shard). 256 actor threads x 16
         # envs = 4096 env slots across the remote actor hosts; each
         # thread ships one 16-item inference query per vector step
-        # (runtime/vector_actor.py)
+        # (runtime/actor.py)
         learner=LearnerConfig(batch_size=512, steps_per_frame_cap=1.6e-3,
                               sample_chunk=4),
         actors=ActorConfig(num_actors=256, envs_per_actor=16),
@@ -1313,7 +1278,7 @@ def _preset_r2d2() -> RunConfig:
                               target_sync_every=2500, lr=1e-4,
                               sample_chunk=4),
         # vectorized recurrent actors: one {obs,c,h} query of 16 envs
-        # per vector step (runtime/vector_actor.py:RecurrentVectorActor)
+        # per vector step (runtime/actor.py:RecurrentActor)
         actors=ActorConfig(num_actors=256, envs_per_actor=16),
         parallel=ParallelConfig(dp=4, tp=2),
     )

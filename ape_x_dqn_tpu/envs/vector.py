@@ -37,5 +37,7 @@ class SyncVectorEnv:
             rewards.append(r)
             dones.append(d)
             infos.append(info)
-        return (np.stack(obs), np.asarray(rewards, np.float32),
+        # rewards stay the envs' own doubles: an n-step return sums them
+        # and rounds once, where it is shipped
+        return (np.stack(obs), np.asarray(rewards, np.float64),
                 np.asarray(dones, bool), infos)

@@ -24,30 +24,32 @@ from ape_x_dqn_tpu.models.lfm2_moe_q import Lfm2MoeQNet
 from ape_x_dqn_tpu.models.minicpm_sala_q import MiniCpmSalaQNet
 from ape_x_dqn_tpu.models.jamba_q import JambaQNet
 
-# network.kind -> the net's class: the eight token-level Q-networks of the
-# decoder_q family (GLM-4.7-Flash, Trinity-Mini, SmallThinker, Ouro,
-# Kimi-Linear, LFM2, MiniCPM-SALA, Jamba2). A further decoder is a row here and in `decoder_block`, a
-# config block, and a row in runtime/family.family_of
-DECODER_NETS = {"glm_moe_q": GlmMoeQNet, "afmoe_q": AfmoeQNet,
-                "smallthinker_q": SmallThinkerQNet, "ouro_q": OuroQNet,
-                "kimi_linear_q": KimiLinearQNet,
-                "lfm2_moe_q": Lfm2MoeQNet,
-                "minicpm_sala_q": MiniCpmSalaQNet,
-                "jamba_q": JambaQNet}
+# The decoder_q family's nets, the ONE place a decoder registers:
+# network.kind -> (its block's name on configs.NetworkConfig, its
+# class). The token-level Q-networks over GLM-4.7-Flash, Trinity-Mini,
+# SmallThinker, Ouro, Kimi-Linear, LFM2, MiniCPM-SALA, Jamba2. A
+# further decoder is its module here in models/, a row here, and its
+# block, field and presets in configs.py: `DECODER_NETS`,
+# `decoder_block`, runtime/family.family_of and tools/apexlint's
+# config_coverage read this table or NetworkConfig's fields.
+DECODERS = {
+    "glm_moe_q": ("glm", GlmMoeQNet),
+    "afmoe_q": ("afmoe", AfmoeQNet),
+    "smallthinker_q": ("smallthinker", SmallThinkerQNet),
+    "ouro_q": ("ouro", OuroQNet),
+    "kimi_linear_q": ("kimi_linear", KimiLinearQNet),
+    "lfm2_moe_q": ("lfm2_moe", Lfm2MoeQNet),
+    "minicpm_sala_q": ("minicpm_sala", MiniCpmSalaQNet),
+    "jamba_q": ("jamba", JambaQNet),
+}
+DECODER_NETS = {kind: net for kind, (_, net) in DECODERS.items()}
 
 
 def decoder_block(net_cfg):
     """-> (the name of net_cfg's decoder block in NetworkConfig, the
     block)."""
-    return {"glm_moe_q": ("glm", net_cfg.glm),
-            "afmoe_q": ("afmoe", net_cfg.afmoe),
-            "smallthinker_q": ("smallthinker", net_cfg.smallthinker),
-            "ouro_q": ("ouro", net_cfg.ouro),
-            "kimi_linear_q": ("kimi_linear", net_cfg.kimi_linear),
-            "lfm2_moe_q": ("lfm2_moe", net_cfg.lfm2_moe),
-            "minicpm_sala_q": ("minicpm_sala", net_cfg.minicpm_sala),
-            "jamba_q": ("jamba", net_cfg.jamba),
-            }[net_cfg.kind]
+    name = DECODERS[net_cfg.kind][0]
+    return name, getattr(net_cfg, name)
 
 
 def build_network(net_cfg, spec):

@@ -111,7 +111,7 @@ def frame_segment_spec(seg_transitions: int, n_step: int,
 class FrameSegmentBuilder:
     """Actor-side segment assembly (host numpy; one per actor env).
 
-    Call order per actor loop (runtime/actor.py):
+    Call order per env of an actor loop (runtime/actor.py):
       on_reset(obs)            after every env.reset()
       on_step(next_obs)        after every env.step()
       add(action, reward, discount, span, priority)

@@ -268,14 +268,12 @@ def run_actor_host(cfg: RunConfig, host: str, port: int,
     errors: list[tuple[int, Exception]] = []
     frames = [0] * n
 
-    vector = cfg.actors.envs_per_actor > 1
-    cls = actor_class(family, vector=vector)
-    query = server.query_batch if vector else server.query
+    cls = actor_class(family)
 
     def actor_thread(slot: int) -> None:
         idx = actor_offset + slot
         try:
-            actor = cls(cfg, idx, query, transport,
+            actor = cls(cfg, idx, server.query_batch, transport,
                         obs=obs if obs.enabled else None)
             live_actors[slot] = actor  # puller paces pulls off .frames
             frames[slot] = actor.run(per_actor, stop_event)

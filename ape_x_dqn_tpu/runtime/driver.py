@@ -9,7 +9,8 @@ Transport interface (comm/), which multi-host deployments swap for the
 socket transport over DCN.
 
 Threads:
-- N actor threads: env stepping + priority bookkeeping (runtime/actor.py)
+- N actor threads, K envs each: env stepping + priority bookkeeping
+  (runtime/actor.py)
 - 1 ingest thread: transport -> learner.add (device ring + sum-tree)
 - 1 learner thread: train_step loop + periodic param publication
 - eval worker (runtime/evaluation.py) runs greedy episodes on demand
@@ -533,17 +534,14 @@ class ApexDriver:
         Exhausting the budget records the error, which fails the run
         report (actor_errors)."""
         stop = slot_stop if slot_stop is not None else self.stop_event
-        vector = self.cfg.actors.envs_per_actor > 1
-        actor_cls = actor_class(self.family, vector=vector)
-        query = self.server.query_batch if vector else self.server.query
         remaining = max_frames
         restarts_left = self.cfg.actors.max_restarts
         # registered here (not in the actor) so a constructor/run that
         # wedges before its first beat is still attributable
         self.obs.register(f"actor-{i}")
         try:
-            self._actor_attempts(i, actor_cls, query, remaining,
-                                 restarts_left, attempt0, stop)
+            self._actor_attempts(i, remaining, restarts_left, attempt0,
+                                 stop)
         finally:
             # a finished actor is not a stalled one — but only the slot's
             # CURRENT generation may clear the heartbeat (a superseded
@@ -555,9 +553,9 @@ class ApexDriver:
             if current:
                 self.obs.clear(f"actor-{i}")
 
-    def _actor_attempts(self, i, actor_cls, query, remaining,
-                        restarts_left, attempt,
+    def _actor_attempts(self, i, remaining, restarts_left, attempt,
                         stop: threading.Event) -> None:
+        actor_cls = actor_class(self.family)
         while remaining > 0 and not stop.is_set():
             actor = None
             try:
@@ -567,7 +565,7 @@ class ApexDriver:
                 # trajectory-dependent crash until the budget burns out
                 seed = (self.cfg.seed if attempt == 0
                         else self.cfg.seed + 7907 * attempt)
-                actor = actor_cls(self.cfg, i, query,
+                actor = actor_cls(self.cfg, i, self.server.query_batch,
                                   self.transport, seed=seed,
                                   episode_callback=self._on_episode,
                                   obs=self.obs)

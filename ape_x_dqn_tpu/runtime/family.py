@@ -69,10 +69,9 @@ read; a net WITH an expert layer also
 `router_trains`, `share` and the stats `expert_rows` and `topk`, a net
 without one the stats `block_applications` and `exit_gates`: the
 family's loss reads expert statistics only from a net that has a
-`share`), a config block in NetworkConfig, a row in
-models.DECODER_NETS, in models.decoder_block and in `family_of`;
-tools/apexlint's `config_coverage` learns the block's name. Nothing
-else here names a decoder.
+`share`), a config block in NetworkConfig, and ONE row in
+models.DECODERS (models/__init__.py says what reads it). No code here
+names a decoder.
 
 A decoder WITH A SLOT STATE registers the same way and OFFERS five
 more things, which `keeps_slots` finds by name on the net and never by
@@ -111,6 +110,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ape_x_dqn_tpu.configs import RunConfig
+from ape_x_dqn_tpu.models import DECODER_NETS
 from ape_x_dqn_tpu.models.base import dtype_of
 from ape_x_dqn_tpu.replay.frame_ring import (frame_ring_mode,
                                              frame_segment_spec)
@@ -122,13 +122,10 @@ from ape_x_dqn_tpu.utils.rng import component_key
 
 
 def family_of(cfg: RunConfig) -> str:
-    return {"lstm_q": "r2d2", "dpg": "dpg", "glm_moe_q": "decoder_q",
-            "afmoe_q": "decoder_q", "smallthinker_q": "decoder_q",
-            "ouro_q": "decoder_q",
-            "kimi_linear_q": "decoder_q",
-            "lfm2_moe_q": "decoder_q",
-            "minicpm_sala_q": "decoder_q",
-            "jamba_q": "decoder_q"}.get(cfg.network.kind, "dqn")
+    kind = cfg.network.kind
+    if kind in DECODER_NETS:
+        return "decoder_q"
+    return {"lstm_q": "r2d2", "dpg": "dpg"}.get(kind, "dqn")
 
 
 # families whose replay items are whole sequences (the staging unit is
@@ -177,8 +174,6 @@ def keeps_slots(net_or_cfg: Any) -> bool:
     `extend`: models/minicpm_sala_q.py), nothing here names a net.
     Takes the net, its class, or a RunConfig."""
     if isinstance(net_or_cfg, RunConfig):
-        from ape_x_dqn_tpu.models import DECODER_NETS
-
         net_or_cfg = DECODER_NETS.get(net_or_cfg.network.kind)
     return hasattr(net_or_cfg, "slot_state")
 
@@ -273,19 +268,11 @@ def hbm_price(cfg: RunConfig, net: Any) -> dict:
     return price
 
 
-def actor_class(family: str, vector: bool = False) -> type:
-    """Actor implementation per family. vector=True selects the
-    K-envs-per-thread vectorized actors (runtime/vector_actor.py),
-    whose query contract is the server's `query_batch` (the sequence
-    variant ships {obs, <state>} pytrees with a leading [K] axis).
-    Both sequence families run the same two actor classes: what their
-    queries carry is `ACTOR_STATE`'s row."""
-    if vector:
-        from ape_x_dqn_tpu.runtime.vector_actor import (
-            ContinuousVectorActor, RecurrentVectorActor, VectorActor)
-        return {"r2d2": RecurrentVectorActor,
-                "decoder_q": RecurrentVectorActor,
-                "dpg": ContinuousVectorActor}.get(family, VectorActor)
+def actor_class(family: str) -> type:
+    """The family's actor (runtime/actor.py): K = actors.envs_per_actor
+    envs a thread, K >= 1, whose query contract is the server's
+    `query_batch`. Both sequence families run the same class: what
+    their queries carry is `ACTOR_STATE`'s row."""
     return {"r2d2": RecurrentActor, "decoder_q": RecurrentActor,
             "dpg": ContinuousActor}.get(family, Actor)
 

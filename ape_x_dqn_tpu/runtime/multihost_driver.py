@@ -398,15 +398,12 @@ class MultihostApexDriver:
                 self.cfg, actors=dataclasses.replace(
                     self.cfg.actors,
                     num_actors=n_local * jax.process_count()))
-            # vector actors (envs_per_actor > 1) compute their per-env
-            # eps slots from acfg's global num_actors, so the schedule
-            # spans the whole nproc * num_actors * K fleet
-            vector = self.cfg.actors.envs_per_actor > 1
-            query = (self.server.query_batch if vector
-                     else self.server.query)
-            actor = actor_class(self.family, vector=vector)(
+            # actors compute their per-env eps slots from acfg's global
+            # num_actors, so the schedule spans the whole
+            # nproc * num_actors * envs_per_actor fleet
+            actor = actor_class(self.family)(
                 acfg, jax.process_index() * n_local + i,
-                query, self.transport,
+                self.server.query_batch, self.transport,
                 episode_callback=self._on_episode, obs=self.obs)
             actor.run(max_frames, self.stop_event)
         except Exception as e:  # noqa: BLE001 - reported in run() output

@@ -600,6 +600,41 @@ def test_config_coverage_serving_scope(tmp_path):
     assert len(res.findings) == 2
 
 
+def test_config_coverage_learns_a_new_block_from_network_config(tmp_path):
+    """A decoder's block is a field of NetworkConfig whose annotation
+    names a dataclass of configs.py: added to a copy of the package's
+    own configs.py, its knobs are in the README-knob scope and the
+    block itself is no dead knob, with no edit to the checker."""
+    src = open(os.path.join(REPO_ROOT, "ape_x_dqn_tpu", "configs.py"),
+               encoding="utf-8").read()
+    head = "@dataclass(frozen=True)\nclass NetworkConfig:\n"
+    assert src.count(head) == 1
+    configs = tmp_path / "configs.py"
+    configs.write_text(src.replace(
+        head,
+        "@dataclass(frozen=True)\nclass NinthConfig:\n"
+        "    width: int = 8\n    dead_knob: int = 0\n\n\n" + head
+        + "    ninth: NinthConfig = field(default_factory=NinthConfig)\n"))
+    reader = tmp_path / "reader.py"
+    reader.write_text("def f(block):\n    return block.width\n")
+    readme = tmp_path / "README.md"
+    readme.write_text("set network.ninth.width, not ninth.imaginary; "
+                      "network.jamba.vocab_size stays a knob\n")
+    res = config_coverage.check(
+        [str(configs), str(reader)], configs_path=str(configs),
+        readme_path=str(readme))
+    ours = [f.message for f in res.findings if "inth" in f.message]
+    assert any("ninth.imaginary" in m and "NinthConfig" in m for m in ours)
+    assert any("NinthConfig.dead_knob" in m for m in ours)
+    assert len(ours) == 2, ours
+    assert not any("jamba.vocab_size" in f.message for f in res.findings)
+    # no net's name in the checker
+    checker = open(config_coverage.__file__, encoding="utf-8").read()
+    assert not any(block in checker for block in (
+        "glm", "afmoe", "smallthinker", "ouro", "kimi", "lfm2", "minicpm",
+        "jamba"))
+
+
 def test_config_coverage_param_codec_scope(tmp_path):
     """ISSUE 19 knobs stay in scope: `comm.param_codec` read through
     getattr counts as a read (train.py reads the codec knobs exactly

@@ -42,8 +42,9 @@ def test_continuous_actor_ships_transitions():
     cfg = _dpg_cfg(num_actors=1)
     transport = LoopbackTransport()
 
-    def query_fn(obs):
-        return {"a": np.array([0.5], np.float32), "q": np.float32(1.0)}
+    def query_fn(obs, n):
+        return {"a": np.full((n, 1), 0.5, np.float32),
+                "q": np.ones(n, np.float32)}
 
     actor = ContinuousActor(cfg, 0, query_fn, transport)
     frames = actor.run(max_frames=300)

@@ -240,3 +240,21 @@ def test_explicit_dm_control_id_errors_without_dm_control(monkeypatch):
     env = control.make_control(EnvConfig(id="pendulum", kind="control"),
                                seed=0)
     assert env.spec.obs_shape == (3,)
+
+
+def test_vector_env_hands_rewards_back_unrounded():
+    """An n-step return sums the envs' own doubles and rounds once, where
+    it is shipped (runtime/actor.py): float32 here would round twice."""
+    from ape_x_dqn_tpu.envs.base import Env
+
+    class Third(Env):
+        spec = CartPole(seed=0).spec
+
+        def reset(self):
+            return np.zeros(4, np.float32)
+
+        def step(self, action):
+            return np.zeros(4, np.float32), 1 / 3, False, {}
+
+    _, rewards, _, _ = SyncVectorEnv([Third()]).step([0])
+    assert rewards.dtype == np.float64 and rewards[0] == 1 / 3

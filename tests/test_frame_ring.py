@@ -231,8 +231,9 @@ class _CaptureTransport:
         self.batches.append(batch)
 
 
-def _zero_query(obs):
-    return np.zeros(18, np.float32)  # greedy ties -> argmax 0, same both
+def _zero_query(obs, n):
+    # greedy ties -> argmax 0, same both
+    return np.zeros((n, 18), np.float32)
 
 
 def test_actor_equivalence_flat_vs_frame_ring():
@@ -242,7 +243,8 @@ def test_actor_equivalence_flat_vs_frame_ring():
     flat_t, ring_t = _CaptureTransport(), _CaptureTransport()
     a_flat = Actor(_catch_cfg("flat"), 0, _zero_query, flat_t)
     a_ring = Actor(_catch_cfg("frame_ring"), 0, _zero_query, ring_t)
-    assert a_ring._seg is not None and a_flat._seg is None
+    assert (a_ring.cores[0].seg is not None
+            and a_flat.cores[0].seg is None)
     a_flat.run(max_frames=150)
     a_ring.run(max_frames=150)
 

@@ -247,7 +247,7 @@ _R2D2_SETS = [
     "learner.batch_size=16", "learner.n_step=3", "learner.lr=1e-3",
     "learner.target_sync_every=100", "learner.publish_every=10",
     "learner.train_chunk=2",
-    # envs_per_actor=2 routes through RecurrentVectorActor
+    # two envs a thread: the queries carry a [2] axis
     "actors.num_actors=1", "actors.base_eps=0.4", "actors.ingest_batch=64",
     "actors.envs_per_actor=2",
     "inference.max_batch=8", "inference.deadline_ms=1.0",

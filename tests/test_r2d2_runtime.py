@@ -70,9 +70,9 @@ def test_recurrent_actor_ships_sequences():
     transport = LoopbackTransport()
     lstm = cfg.network.lstm_size
 
-    def query_fn(inp):
+    def query_fn(inp, n):
         # fake recurrent net: state accumulates, q fixed
-        return {"q": np.array([0.1, 0.2], np.float32),
+        return {"q": np.tile(np.array([0.1, 0.2], np.float32), (n, 1)),
                 "c": np.asarray(inp["c"]) + 1.0,
                 "h": np.asarray(inp["h"]) + 1.0}
 

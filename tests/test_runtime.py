@@ -104,8 +104,8 @@ def test_actor_ships_prioritized_batches():
     cfg = _tiny_cfg(num_actors=1)
     transport = LoopbackTransport()
 
-    def query_fn(obs):
-        return np.array([0.1, 0.2], np.float32)  # fixed Q-values
+    def query_fn(obs, n):
+        return np.tile(np.array([0.1, 0.2], np.float32), (n, 1))  # fixed
 
     actor = Actor(cfg, 0, query_fn, transport)
     frames = actor.run(max_frames=200)

@@ -56,12 +56,12 @@ def rows(tokens, slots, fresh, **more):
 
 
 def test_slot_path_is_the_stateless_window_over_two_episodes(tiny):
-    """RecurrentVectorActor, two envs, two episodes each and more: every
+    """RecurrentActor, two envs, two episodes each and more: every
     Q the slot server answers is what `apply_window` answers over the
     same episode's tokens (episodes here are no longer than the
     window)."""
     from ape_x_dqn_tpu.comm.transport import LoopbackTransport
-    from ape_x_dqn_tpu.runtime.vector_actor import RecurrentVectorActor
+    from ape_x_dqn_tpu.runtime.actor import RecurrentActor
 
     cfg, net, params = tiny
     server = slot_server(tiny)
@@ -91,7 +91,7 @@ def test_slot_path_is_the_stateless_window_over_two_episodes(tiny):
         return out
 
     try:
-        actor = RecurrentVectorActor(cfg, 0, query, LoopbackTransport())
+        actor = RecurrentActor(cfg, 0, query, LoopbackTransport())
         actor.run(max_frames=2 * 2 * 64 + 8)
     finally:
         server.stop()

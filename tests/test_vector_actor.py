@@ -1,5 +1,5 @@
-"""Vectorized actor runtime (runtime/vector_actor.py) and the
-inference server's multi-item query path that serves it
+"""The actor runtime's K-env loops (runtime/actor.py) and the
+inference server's multi-item query path that serves them
 (SURVEY.md §2.4 "inference batching parallelism", §7 hard part 3)."""
 
 import threading
@@ -13,9 +13,11 @@ from ape_x_dqn_tpu.configs import (
     ReplayConfig, get_config)
 from ape_x_dqn_tpu.comm.transport import LoopbackTransport
 from ape_x_dqn_tpu.parallel.inference_server import BatchedInferenceServer
-from ape_x_dqn_tpu.runtime.actor import Actor, actor_epsilon
+from ape_x_dqn_tpu.envs.base import Env, EnvSpec
+from ape_x_dqn_tpu.runtime.actor import (
+    Actor, ContinuousActor, RecurrentActor, actor_epsilon)
 from ape_x_dqn_tpu.runtime.driver import ApexDriver
-from ape_x_dqn_tpu.runtime.vector_actor import VectorActor
+from ape_x_dqn_tpu.runtime.family import actor_class
 
 
 # -- server query_batch ----------------------------------------------------
@@ -97,7 +99,7 @@ def test_vector_actor_ships_prioritized_batches():
         assert obs.shape == (n, 4)
         return np.tile(np.array([0.1, 0.2], np.float32), (n, 1))
 
-    actor = VectorActor(cfg, 0, query_fn, transport)
+    actor = Actor(cfg, 0, query_fn, transport)
     frames = actor.run(max_frames=200)
     assert frames >= 200 and frames % 4 == 0
     # one K-item query per vector step (plus rare truncation queries)
@@ -128,43 +130,11 @@ def test_vector_actor_eps_spans_global_slots():
     def query_fn(obs, n):
         return np.zeros((n, 2), np.float32)
 
-    a1 = VectorActor(cfg, 1, query_fn, LoopbackTransport())
+    a1 = Actor(cfg, 1, query_fn, LoopbackTransport())
     want = [actor_epsilon(1 * 3 + j, 6, 0.6, cfg.actors.eps_alpha)
             for j in range(3)]
     got = [c.eps for c in a1.cores]
     np.testing.assert_allclose(got, want)
-
-
-def test_vector_actor_matches_scalar_nstep_semantics():
-    """A K=1 vector actor and a scalar actor given identical Q-values
-    and seeds ship identical transition streams (same n-step math,
-    same priorities)."""
-    cfg = _vec_cfg(num_actors=1, envs_per_actor=1)
-
-    def scalar_q(obs):
-        return np.array([0.3, -0.1], np.float32)
-
-    def vec_q(obs, n):
-        return np.tile(np.array([0.3, -0.1], np.float32), (n, 1))
-
-    t_s, t_v = LoopbackTransport(), LoopbackTransport()
-    Actor(cfg, 0, scalar_q, t_s, seed=5).run(max_frames=120)
-    VectorActor(cfg, 0, vec_q, t_v, seed=5).run(max_frames=120)
-
-    def drain(t):
-        out = []
-        while True:
-            b = t.recv_experience(timeout=0.01)
-            if b is None:
-                return out
-            out.append(b)
-
-    bs, bv = drain(t_s), drain(t_v)
-    cat = lambda bl, k: np.concatenate([np.asarray(b[k]) for b in bl])
-    for k in ("obs", "action", "reward", "next_obs", "discount",
-              "priorities"):
-        np.testing.assert_allclose(cat(bs, k), cat(bv, k), rtol=1e-6,
-                                   err_msg=k)
 
 
 def test_vector_actor_frame_ring_segments():
@@ -185,7 +155,7 @@ def test_vector_actor_frame_ring_segments():
         assert obs.shape[0] == n and obs.shape[1:] == (84, 84, 4)
         return np.zeros((n, 6), np.float32)
 
-    actor = VectorActor(cfg, 0, query_fn, transport)
+    actor = Actor(cfg, 0, query_fn, transport)
     frames = actor.run(max_frames=300)
     assert frames >= 300
     segs = []
@@ -224,8 +194,6 @@ def _r2d2_vec_cfg(num_actors=1, envs_per_actor=3, seq=8, overlap=4):
 
 
 def test_recurrent_vector_actor_ships_sequences():
-    from ape_x_dqn_tpu.runtime.vector_actor import RecurrentVectorActor
-
     cfg = _r2d2_vec_cfg(envs_per_actor=3)
     transport = LoopbackTransport()
     lstm = cfg.network.lstm_size
@@ -236,7 +204,7 @@ def test_recurrent_vector_actor_ships_sequences():
                 "c": np.asarray(inp["c"]) + 1.0,
                 "h": np.asarray(inp["h"]) + 1.0}
 
-    actor = RecurrentVectorActor(cfg, 0, query_fn, transport)
+    actor = RecurrentActor(cfg, 0, query_fn, transport)
     frames = actor.run(max_frames=120)
     assert frames >= 120 and frames % 3 == 0
     batches, total = [], 0
@@ -258,46 +226,6 @@ def test_recurrent_vector_actor_ships_sequences():
     # init states advance with the fake recurrence except at episode
     # starts (zeros)
     assert any(np.any(b["init_c"] != 0) for b in batches)
-
-
-def test_recurrent_vector_matches_scalar_semantics():
-    """A K=1 recurrent vector actor and the scalar RecurrentActor with
-    identical fake Q/recurrence and seeds ship identical sequence
-    streams (same TD seeds, same stored states, same priorities)."""
-    from ape_x_dqn_tpu.runtime.actor import RecurrentActor
-    from ape_x_dqn_tpu.runtime.vector_actor import RecurrentVectorActor
-
-    cfg = _r2d2_vec_cfg(num_actors=1, envs_per_actor=1)
-    lstm = cfg.network.lstm_size
-
-    def scalar_q(inp):
-        return {"q": np.array([0.3, -0.1], np.float32),
-                "c": np.asarray(inp["c"]) + 1.0,
-                "h": np.asarray(inp["h"]) - 1.0}
-
-    def vec_q(inp, n):
-        return {"q": np.tile(np.array([0.3, -0.1], np.float32), (n, 1)),
-                "c": np.asarray(inp["c"]) + 1.0,
-                "h": np.asarray(inp["h"]) - 1.0}
-
-    t_s, t_v = LoopbackTransport(), LoopbackTransport()
-    RecurrentActor(cfg, 0, scalar_q, t_s, seed=5).run(max_frames=90)
-    RecurrentVectorActor(cfg, 0, vec_q, t_v, seed=5).run(max_frames=90)
-
-    def drain(t):
-        out = []
-        while True:
-            b = t.recv_experience(timeout=0.01)
-            if b is None:
-                return out
-            out.append(b)
-
-    bs, bv = drain(t_s), drain(t_v)
-    cat = lambda bl, k: np.concatenate([np.asarray(b[k]) for b in bl])
-    for k in ("obs", "actions", "rewards", "terminals", "mask",
-              "init_c", "init_h", "priorities"):
-        np.testing.assert_allclose(cat(bs, k), cat(bv, k), rtol=1e-6,
-                                   err_msg=k)
 
 
 def test_r2d2_driver_vector_end_to_end():
@@ -331,3 +259,207 @@ def test_apex_driver_vector_end_to_end():
     assert out["episodes"] > 0
     # the server saw multi-item requests: avg batch well above 1
     assert out["server"]["avg_batch"] > 2.0, out["server"]
+
+
+# -- one env an actor: K = 1 of the same loops -----------------------------
+
+class _Scripted(Env):
+    """obs = [steps into the episode], reward = that count after the
+    step; episode 0 is TRUNCATED at its 4th step, episode 1 ends in a
+    TERMINAL at its 3rd, the later ones run on."""
+
+    def __init__(self, spec):
+        self.spec = spec
+        self.episode, self.t = -1, 0
+
+    def reset(self):
+        self.episode += 1
+        self.t = 0
+        return np.zeros(self.spec.obs_shape, np.float32)
+
+    def step(self, action):
+        self.t += 1
+        obs = np.full(self.spec.obs_shape, self.t, np.float32)
+        ends = {0: 4, 1: 3}.get(self.episode)
+        done = self.t == ends
+        info = {"terminal": done and self.episode == 1}
+        if done:
+            info["episode_return"] = float(sum(range(1, self.t + 1)))
+        return obs, float(self.t), done, info
+
+
+class _Capture:
+    def __init__(self):
+        self.batches = []
+
+    def send_experience(self, batch):
+        self.batches.append(batch)
+
+
+_DISCRETE = EnvSpec((1,), np.dtype(np.float32), True, num_actions=2)
+_BOX = EnvSpec((1,), np.dtype(np.float32), False, action_dim=1)
+
+
+def _half_q(obs, n):
+    # Q(o) = [o / 2, -1]: the greedy action is 0 and V(o) = o / 2
+    o = np.asarray(obs, np.float32).reshape(n)
+    return np.stack([o / 2, -np.ones(n, np.float32)], axis=1)
+
+
+def _half_mu_q(obs, n):
+    o = np.asarray(obs, np.float32).reshape(n)
+    return {"a": np.zeros((n, 1), np.float32), "q": o / 2}
+
+
+def _half_lstm_q(inp, n):
+    return {"q": _half_q(inp["obs"], n), "c": np.asarray(inp["c"]) + 1.0,
+            "h": np.asarray(inp["h"]) - 1.0}
+
+
+# gamma = 1/2, n_step = 2, greedy, over the 7 frames of _Scripted's
+# first two episodes. Flat families: a transition from step t is
+# (o_t, r_t + r_{t+1} / 2, o_{t+2}, 1/4), shortened at an episode's end
+# (truncation: bootstraps from the 4th step's own observation, o = 4;
+# terminal: discount 0); its priority is |R + discount * V(next) - V(o_t)|.
+_FLAT_WANT = {
+    "obs": [0, 1, 2, 3, 0, 1, 2],
+    "reward": [1 + 2 / 2, 2 + 3 / 2, 3 + 4 / 2, 4, 1 + 2 / 2, 2 + 3 / 2, 3],
+    "next_obs": [2, 3, 4, 4, 2, 3, 3],
+    "discount": [.25, .25, .25, .5, .25, 0, 0],
+    "priorities": [2 + .25 * 1 - 0, 3.5 + .25 * 1.5 - .5, 5 + .25 * 2 - 1,
+                   4 + .5 * 2 - 1.5, 2 + .25 * 1 - 0, 3.5 - .5, 3 - 1],
+}
+# Sequences of 4: one an episode; a step's 1-step TD is
+# r_t + V(o_{t+1}) / 2 - V(o_t), the priority 0.9 max + 0.1 mean of them
+_TD = [[1 + .25 - 0, 2 + .5 - .5, 3 + .75 - 1, 4 + 1 - 1.5],
+       [1 + .25 - 0, 2 + .5 - .5, 3 - 1]]
+_SEQ_WANT = {
+    "obs": [[0, 1, 2, 3], [0, 1, 2, 0]],
+    "actions": [[0, 0, 0, 0], [0, 0, 0, 0]],
+    "rewards": [[1, 2, 3, 4], [1, 2, 3, 0]],
+    "terminals": [[0, 0, 0, 0], [0, 0, 1, 0]],
+    "mask": [[1, 1, 1, 1], [1, 1, 1, 0]],
+    "init_c": [[0, 0], [0, 0]], "init_h": [[0, 0], [0, 0]],
+    "priorities": [.9 * max(td) + .1 * sum(td) / len(td) for td in _TD],
+}
+
+
+def _scripted_cfg(family):
+    learner = LearnerConfig(batch_size=4, n_step=2, gamma=0.5)
+    actors = ActorConfig(num_actors=1, envs_per_actor=1, base_eps=0.0,
+                         noise_sigma=0.0, ingest_batch=64)
+    if family == "r2d2":
+        return _r2d2_vec_cfg(seq=4, overlap=0).replace(
+            network=NetworkConfig(kind="lstm_q", lstm_size=2),
+            learner=learner, actors=actors)
+    return get_config({"dqn": "cartpole_smoke", "dpg": "apex_dpg"}[family]
+                      ).replace(learner=learner, actors=actors)
+
+
+@pytest.mark.parametrize("family, spec, query, want", [
+    ("dqn", _DISCRETE, _half_q, _FLAT_WANT),
+    ("dpg", _BOX, _half_mu_q, _FLAT_WANT),
+    ("r2d2", _DISCRETE, _half_lstm_q, _SEQ_WANT)])
+def test_one_env_stream_is_the_arithmetic_by_hand(monkeypatch, family,
+                                                  spec, query, want):
+    """K = 1 of each family's class through a truncation and a terminal:
+    what it ships is the n-step (the 1-step, for sequences) arithmetic
+    worked out above, and the truncation costs one query more."""
+    monkeypatch.setattr("ape_x_dqn_tpu.runtime.actor.make_env",
+                        lambda cfg, seed=0, actor_index=0: _Scripted(spec))
+    asked, episodes = [], []
+
+    def counted(inputs, n):
+        asked.append(n)
+        return query(inputs, n)
+
+    transport = _Capture()
+    actor = actor_class(family)(
+        _scripted_cfg(family), 0, counted, transport,
+        episode_callback=lambda i, info: episodes.append(
+            info["episode_return"]))
+    assert actor.run(max_frames=7) == 7
+    assert asked == [1] * 8 and episodes == [10.0, 6.0]
+    (batch,) = transport.batches
+    assert batch["frames"] == 7 and batch["actor"] == 0
+    for key, value in want.items():
+        got = np.asarray(batch[key])
+        np.testing.assert_allclose(
+            got.reshape(np.shape(value)), value, rtol=1e-6, err_msg=key)
+
+
+@pytest.mark.parametrize("family, cls", [
+    ("dqn", Actor), ("dpg", ContinuousActor), ("r2d2", RecurrentActor),
+    ("decoder_q", RecurrentActor)])
+def test_one_actor_class_a_family(family, cls):
+    import inspect
+
+    assert actor_class(family) is cls
+    assert list(inspect.signature(actor_class).parameters) == ["family"]
+
+
+def test_spans_and_ship_marks_of_the_k_env_loops():
+    """`actor.env_step` beside `actor.inference`, one a vector step, and
+    an `actor.ship` mark a shipment, from both loops."""
+    class _Obs:
+        def __init__(self):
+            self.spans, self.marks, self.beats = [], [], 0
+
+        def beat(self, name):
+            self.beats += 1
+
+        def span(self, name, **args):
+            import contextlib
+            self.spans.append((name, args))
+            return contextlib.nullcontext()
+
+        def mark(self, name, **args):
+            self.marks.append((name, args))
+
+    for cfg, cls, query, unit in (
+            (_vec_cfg(envs_per_actor=2), Actor,
+             lambda obs, n: np.zeros((n, 2), np.float32), "rows"),
+            (_r2d2_vec_cfg(envs_per_actor=2), RecurrentActor,
+             lambda inp, n: {"q": np.zeros((n, 2), np.float32),
+                             "c": inp["c"], "h": inp["h"]}, "sequences")):
+        obs, transport = _Obs(), _Capture()
+        frames = cls(cfg, 0, query, transport, obs=obs).run(max_frames=200)
+        steps = frames // 2
+        assert obs.beats == steps
+        for name in ("actor.inference", "actor.env_step"):
+            assert obs.spans.count((name, {"k": 2})) == steps, name
+        assert [m[0] for m in obs.marks] == (["actor.ship"]
+                                             * len(transport.batches))
+        assert ([m[1][unit] for m in obs.marks]
+                == [len(b["priorities"]) for b in transport.batches])
+
+
+@pytest.mark.parametrize("preset, overrides", [
+    ("cartpole_smoke", dict(
+        replay=ReplayConfig(kind="prioritized", capacity=2048, min_fill=64),
+        learner=LearnerConfig(batch_size=32, n_step=3))),
+    ("minicpm_sala_tiny_q", {})])
+def test_a_preset_of_one_env_an_actor_ships_through_the_driver(
+        preset, overrides):
+    """`envs_per_actor=1` is a size of the same class: the driver builds
+    it over `query_batch` (the slot row for a net the server keeps in
+    slots) and its experience reaches the learner."""
+    from ape_x_dqn_tpu.runtime.family import keeps_slots
+
+    cfg = get_config(preset).replace(
+        eval_every_steps=0, eval_episodes=0, **overrides)
+    cfg = cfg.replace(actors=ActorConfig(
+        num_actors=2, envs_per_actor=1, ingest_batch=16))
+    assert keeps_slots(cfg) == (preset == "minicpm_sala_tiny_q")
+    driver = ApexDriver(cfg)
+    out = driver.run(total_env_frames=1200, max_grad_steps=4,
+                     wall_clock_limit_s=240)
+    made = list(driver._slot_actor_obj.values())
+    assert out["actor_errors"] == [] and out["loop_errors"] == [], out
+    assert out["grad_steps"] >= 4 and out["frames"] >= 64, out
+    assert len(made) == 2 and all(
+        type(a) is actor_class(driver.family) and a.K == 1
+        and a.query == driver.server.query_batch for a in made)
+    if keeps_slots(cfg):
+        assert set(made[0].cores[0].state) == {"slot", "fresh"}
+        assert driver.server.slot_counters["extend_tokens"] >= out["frames"]

@@ -13,13 +13,12 @@ Both directions of config/doc drift:
    declaration line.
 
 2. Every `replay.` / `comm.` / `obs.` / `actors.` / `serving.` /
-   `glm.` / `afmoe.` / `smallthinker.` / `ouro.` / `kimi_linear.` /
-   `lfm2_moe.` / `minicpm_sala.` / `jamba.` (as in `network.glm.shard_count`) knob
-   mentioned in README must exist as a field on the matching dataclass
-   (ReplayConfig / CommConfig / ObsConfig / ActorConfig /
-   ServingConfig / GlmMoeConfig / AfmoeConfig / SmallThinkerConfig /
-   OuroConfig / KimiLinearConfig / Lfm2MoeConfig / MiniCpmSalaConfig /
-   JambaConfig).
+   `remediation.` knob mentioned in README must exist as a field on
+   the matching dataclass (`PREFIX_TO_CLASS`), and so must every
+   `<block>.` knob (as in `network.<block>.shard_count`) of a block of
+   NetworkConfig: a field there whose annotation names a dataclass of
+   configs.py is a block, read off the file, so a new one needs no
+   edit here.
    Mentions
    that name a package MODULE instead of a knob (`obs.health`,
    `obs.report` — `ape_x_dqn_tpu/obs/health.py` exists) are skipped.
@@ -45,17 +44,20 @@ CHECKER = "config-coverage"
 PREFIX_TO_CLASS = {"replay": "ReplayConfig", "comm": "CommConfig",
                    "obs": "ObsConfig", "actors": "ActorConfig",
                    "serving": "ServingConfig",
-                   "remediation": "RemediationConfig",
-                   "glm": "GlmMoeConfig", "afmoe": "AfmoeConfig",
-                   "smallthinker": "SmallThinkerConfig",
-                   "ouro": "OuroConfig",
-                   "kimi_linear": "KimiLinearConfig",
-                   "lfm2_moe": "Lfm2MoeConfig",
-                   "minicpm_sala": "MiniCpmSalaConfig",
-                   "jamba": "JambaConfig"}
-KNOB_RE = re.compile(
-    r"\b(" + "|".join(PREFIX_TO_CLASS) + r")"
-    r"\.([a-z_][a-z0-9_]*)")
+                   "remediation": "RemediationConfig"}
+
+
+def network_blocks(src: ModuleSource, classes: dict) -> dict[str, str]:
+    """{field: class} of NetworkConfig's blocks: its fields whose
+    annotation names a dataclass of the same file."""
+    for node in src.tree.body:
+        if isinstance(node, ast.ClassDef) and node.name == "NetworkConfig":
+            return {item.target.id: item.annotation.id
+                    for item in node.body
+                    if isinstance(item, ast.AnnAssign)
+                    and isinstance(item.annotation, ast.Name)
+                    and item.annotation.id in classes}
+    return {}
 
 
 def _is_dataclass(cls: ast.ClassDef) -> bool:
@@ -120,11 +122,15 @@ def check(paths: list[str], configs_path: str | None = None,
     configs_src = ModuleSource(configs_path)
     classes = dataclass_fields(configs_src)
 
-    # direction 1: declared but never read
+    # direction 1: declared but never read (a block of NetworkConfig is
+    # fetched by the name models.DECODERS holds, `getattr(net_cfg, name)`:
+    # its own fields are the knobs)
+    blocks = network_blocks(configs_src, classes)
     reads = _attribute_reads(paths, os.path.abspath(configs_path))
     for cls_name, fields in classes.items():
         for field, line in fields.items():
-            if field in reads:
+            if field in reads or (cls_name == "NetworkConfig"
+                                  and field in blocks):
                 continue
             if configs_src.waiver(line, "unread") is not None:
                 result.waivers += 1
@@ -137,13 +143,16 @@ def check(paths: list[str], configs_path: str | None = None,
 
     # direction 2: README knobs that don't exist
     if readme_path and os.path.exists(readme_path):
+        prefixes = {**PREFIX_TO_CLASS, **blocks}
+        knob_re = re.compile(
+            r"\b(" + "|".join(prefixes) + r")\.([a-z_][a-z0-9_]*)")
         with open(readme_path, encoding="utf-8") as fh:
             for lineno, text in enumerate(fh, start=1):
-                for m in KNOB_RE.finditer(text):
+                for m in knob_re.finditer(text):
                     prefix, attr = m.group(1), m.group(2)
                     if attr == "py":
                         continue  # `remediation.py` is a filename
-                    cls_name = PREFIX_TO_CLASS[prefix]
+                    cls_name = prefixes[prefix]
                     fields = classes.get(cls_name)
                     if fields is None or attr in fields:
                         continue
