@@ -24,8 +24,9 @@ compute dtype, both addressed by ROWS (a gather and a scatter along the
 first dimension move no other byte of a pool); per attention layer pools
 `k`, `v` [G, positions, d] in which a session owns one contiguous range
 of blocks of `kv_block` positions the host handed out
-(ops/block_select_attention.py's pool, written and walked by its `write`
-/ `attend_tiles` / `attend_gathered`, every block allowed) - and
+(ops/block_select_attention.py's pool, written by its `write`, walked by
+its `attend_tiles` a prefill chunk and its `attend_range` a decode step,
+every block allowed) - and
 `inputs` says, per row, `obs` ([B] a decode step, [B, n] a prefill chunk
 of which `n_valid` [B] count), `slot`, `base` (the range's first block)
 and `fresh` (an episode's first query: the row starts from zeros
@@ -42,7 +43,12 @@ layer's whole `ssm` pool with `slot` and `fresh`, and
 `[d_state, channels]` block where it lies (one Pallas kernel a layer,
 the pool aliased: where XLA made three passes and a select over `[B,
 d_state, channels]` the state moves once, PERF.md section 6, PR 58). The
-conv tail is gathered, cut and scattered by rows in both.
+conv tail is gathered, cut and scattered by rows in both. A decode step's
+attention gathers nothing either: `attend_range` walks each row's OWN
+range of the key and value pools where it lies, tile by tile up to that
+row's own context (one Pallas kernel a layer, the pools inputs only: the
+gather to the longest range a session may own copied 2.3 positions for
+every one attended, PERF.md section 6, PR 60).
 
 The equations (benchmarks/reference/jamba_q.py writes them again in
 float32, independently; what the catalog row's keys leave open is marked
@@ -79,9 +85,13 @@ scope), `.out` inside; `jamba.attn` with `.proj`,
 layers: padding rows are not counted); `ssm_tokens_scanned` (valid
 positions x Mamba layers through `selective_scan.chunked`: a prefill
 chunk's); `attn_positions_read` (keys each valid query attended, summed
-over the attention layers: COUNTED FROM THE MASK THE OP APPLIED,
-`block_select_attention`'s `counted`, so that a mask one position short
-reads one key a query less than the positions sent say).
+over the attention layers: COUNTED FROM THE MASK THE OP APPLIED -
+`attend_tiles`' `counted`, inside `attend_range`'s kernel - so that a
+mask one position short reads one key a query less than the positions
+sent say); `attn_positions_fetched` (positions the key tiles a decode
+step walked brought on chip, summed over EVERY row, a padding row's one
+tile too, and over the attention layers; 0 from a prefill chunk: read /
+fetched is the share of a decode step's key traffic that is context).
 """
 
 from __future__ import annotations
@@ -381,7 +391,8 @@ class JambaQNet:
         """u = N1(x) [B, n, hidden], `pools` = this layer's (k, v),
         `base` [B] each row's range (in blocks) -> (the mixer's output,
         the pools with the new positions, the keys the applied mask let
-        the valid queries attend, summed)."""
+        the valid queries attend, summed, the positions a decode step's
+        walk fetched, summed over every row)."""
         s, sz, dt = self.s, self._pool, u.dtype
         b, n, _ = u.shape
         g, d = s.num_key_value_heads, self.head_dim
@@ -397,16 +408,16 @@ class JambaQNet:
             kpool = bsa.write(kpool, k, at, valid)
             vpool = bsa.write(vpool, v, at, valid)
         with jax.named_scope("jamba.attn.attend"):
+            tile_k = min(self.attn_tiles[1], blocks * sz.block)
             if decode:
-                t = positions[:, 0]
-                every = jnp.broadcast_to(
-                    bsa.dense_blocks(t, blocks, sz)[:, None], (b, g, blocks))
-                o, keys = bsa.attend_gathered(q[:, 0], kpool, vpool, start,
-                                              every, t, sz, counted=True)
-                o = o[:, None]
+                # each row's own range where it lies, up to its own
+                # context: nothing is gathered to `blocks`
+                o, keys, fetched = bsa.attend_range(
+                    q[:, 0], kpool, vpool, start, positions[:, 0], sz,
+                    tile_k, blocks * sz.block // tile_k)
+                o, fetched = o[:, None], jnp.sum(fetched)
             else:
                 tile_q = min(self.attn_tiles[0], n)
-                tile_k = min(self.attn_tiles[1], blocks * sz.block)
                 pad = -n % tile_q
                 qs = jnp.pad(q, ((0, 0), (0, pad)) + ((0, 0),) * 3)
                 ts = jnp.pad(positions, ((0, 0), (0, pad)), mode="edge")
@@ -423,11 +434,12 @@ class JambaQNet:
                     qs.reshape(b * parts, tile_q, g, group, d),
                     ts.reshape(b * parts, tile_q), jnp.repeat(base, parts)))
                 o = o.reshape(b, n + pad, g, group, d)[:, :n]
+                fetched = jnp.int32(0)
             # a padding query's count is dropped with its answer
             keys = jnp.sum(jnp.where(valid, keys.reshape(b, -1)[:, :n], 0))
         with jax.named_scope("jamba.attn.out"):
             o = _held(o, dt).reshape(b, n, g * group * d)
-            return _dot(o, p["o_proj"]), (kpool, vpool), keys
+            return _dot(o, p["o_proj"]), (kpool, vpool), keys, fetched
 
     def _run(self, params: dict, pools: dict, before: jax.Array,
              tokens: jax.Array, n_valid: jax.Array, slot: jax.Array,
@@ -450,7 +462,7 @@ class JambaQNet:
         ssm, conv, k, v = (list(pools[name]) for name in (
             "ssm", "conv", "k", "v"))
         mi = ai = 0
-        keys_read = jnp.int32(0)
+        keys_read = keys_fetched = jnp.int32(0)
         for kind, p in zip(self.kinds, params["layers"]):
             u = _norm(x, p["input_layernorm"], s.rms_norm_eps)
             if kind == MAMBA:
@@ -474,10 +486,11 @@ class JambaQNet:
                 mi += 1
             else:
                 with jax.named_scope("jamba.attn"):
-                    out, (k[ai], v[ai]), keys = self._attention(
+                    out, (k[ai], v[ai]), keys, fetched = self._attention(
                         p["self_attn"], u, (k[ai], v[ai]), base, positions,
                         valid, blocks, decode, tiles)
                 keys_read += keys
+                keys_fetched += fetched
                 ai += 1
             x = _add(x, out)
             with jax.named_scope("jamba.mlp"):
@@ -489,7 +502,8 @@ class JambaQNet:
             "ssm_rows_updated": self.num_mamba * jnp.sum(live),
             "ssm_tokens_scanned": (jnp.int32(0) if decode
                                    else self.num_mamba * jnp.sum(n_valid)),
-            "attn_positions_read": keys_read}
+            "attn_positions_read": keys_read,
+            "attn_positions_fetched": keys_fetched}
         return x, counters, {"ssm": tuple(ssm), "conv": tuple(conv),
                              "k": tuple(k), "v": tuple(v)}
 

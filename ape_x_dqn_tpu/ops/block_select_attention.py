@@ -32,8 +32,14 @@ Entry points, all float32 softmax over compute-dtype keys and values:
   compressed keys their arrival completes, from what the pool holds.
 - `select`: compressed keys + queries -> the chosen block ids
   (`-1` where fewer than `topk` blocks exist).
-- `attend_gathered`: ONE query a sequence (a decode step): the chosen
-  blocks are gathered, `[rows, kv heads, K, block, d]`, and attended.
+- `attend_gathered`: ONE query a sequence (a decode step) over a block
+  LIST: the chosen blocks are gathered, `[rows, kv heads, K, block, d]`,
+  and attended.
+- `attend_range`: ONE query a sequence over its WHOLE range (a decode
+  step of a net that selects nothing, models/jamba_q.py): a Pallas
+  kernel walks each row's own tiles of the pools where they lie, up to
+  that row's own context, with an online softmax - no gathered copy, no
+  tile past the row's last (PERF.md section 6, PR 60).
 - `attend_tiles`: MANY queries of one sequence (a prefill chunk, the
   learner's pass): key tiles of `tile` positions walked with an online
   softmax under the per-(query, block) mask, so a chunk's cost is dense
@@ -44,6 +50,8 @@ Scopes are the caller's (`sala.sparse.*`).
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -189,11 +197,10 @@ def attended(sel: jax.Array, t: jax.Array, sz: Sizes
 
 def attend_gathered(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                     base: jax.Array, sel: jax.Array, t: jax.Array,
-                    sz: Sizes, counted: bool = False):
+                    sz: Sizes) -> jax.Array:
     """One query a sequence: q [B, G, g, d], pools [G, P, d], base [B]
     (positions), sel [B, G, K] block ids (-1: none), t [B] -> [B, G, g,
-    d] float32; `counted`: -> (that, [B] int32 the keys THE MASK THAT
-    WAS APPLIED let each query's first kv head attend)."""
+    d] float32."""
     f32 = jnp.float32
     g_heads, p, d = kpool.shape
     at = jnp.where(sel >= 0, base[:, None, None] // sz.block + sel, 0)
@@ -207,12 +214,156 @@ def attend_gathered(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                    preferred_element_type=f32) / jnp.sqrt(f32(d))
     s = s.reshape(*s.shape[:3], k * sz.block)
     p = _probs(s, ok.reshape(b, g_heads, 1, -1)).astype(q.dtype)
-    o = jnp.einsum("bghk,bgkd->bghd", p,
-                   values.reshape(b, g_heads, k * sz.block, d),
-                   preferred_element_type=f32)
-    if counted:
-        return o, ok[:, 0].sum(axis=(1, 2), dtype=jnp.int32)
-    return o
+    return jnp.einsum("bghk,bgkd->bghd", p,
+                      values.reshape(b, g_heads, k * sz.block, d),
+                      preferred_element_type=f32)
+
+
+def _interpret() -> bool:
+    """Whether `attend_range` runs Pallas's interpreter: wherever the
+    backend is not a TPU (a CPU test runs the same kernel body)."""
+    return jax.default_backend() != "tpu"
+
+
+def attend_range(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
+                 base: jax.Array, t: jax.Array, sz: Sizes, tile: int,
+                 tiles: int):
+    """One query a sequence over its WHOLE range, the range read where
+    it lies: q [B, G, g, d], pools [G, P, d], base [B] (positions, a
+    multiple of `sz.block`), t [B]; `tile` positions a key tile (whole
+    blocks), of which a range holds `tiles` at most -> ([B, G, g, d]
+    float32, [B] int32 the keys THE MASK THAT WAS APPLIED let each query
+    attend, [B] int32 the positions its walk fetched). What
+    `attend_gathered` gives for `dense_blocks(t, ...)` up to the order
+    of a float32 sum: row b walks key tiles 0 .. t[b] // tile of ITS OWN
+    range (one at least, `tiles` at most) with an online softmax, no
+    copy of the range is made and no tile past the row's last is read.
+    P reaches `tiles` whole tiles past every base. On a TPU d is whole
+    lanes (a multiple of 128: the chip's compiler slices HBM by whole
+    tiles)."""
+    interpret = _interpret()
+    if not interpret and q.shape[-1] % 128:
+        raise NotImplementedError(
+            f"attend_range on a TPU: head_dim={q.shape[-1]} is not a "
+            f"multiple of 128 lanes")
+    walked = jnp.clip(t // tile + 1, 1, tiles).astype(jnp.int32)
+    o, keys = _attend_range(
+        q, kpool, vpool, base.astype(jnp.int32), t.astype(jnp.int32), walked,
+        tile=tile, align=sz.block, interpret=interpret)
+    return o, keys, walked * tile
+
+
+# one Pallas kernel under a `jax.jit` of its own, as
+# selective_scan._step_slots is and for its reason: a program traces and
+# lowers the body once however many layers call it. Grid step b is row
+# b; the pools stay in HBM and the row's tiles come by hand-made DMAs
+# into two buffers, the next tile (or the NEXT ROW's first) on its way
+# while this one is attended, so the walk is one pipeline over every
+# (row, tile) pair and makes no step that fetches nothing
+@functools.partial(jax.jit, static_argnames=("tile", "align", "interpret"))
+def _attend_range(q, kpool, vpool, base, t, walked, *, tile, align,
+                  interpret):
+    # here, not at the top: a second of import that only a process which
+    # serves this op's decode steps should pay
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    rows, g_heads, group, d = q.shape
+    scale = 1.0 / math.sqrt(d)
+
+    def walk(base_ref, t_ref, walked_ref, q_ref, k_hbm, v_hbm, o_ref, n_ref,
+             kbuf, vbuf, arrived, turn, top, norm, acc):
+        """One row: q, o [G, g, d]; the pools whole, in HBM; kbuf, vbuf
+        [2, G, tile, d] the two buffers, `turn` which of them the row's
+        first tile is in; top, norm [G, g, 1] and acc [G, g, d] the
+        online softmax's running maximum, sum and product."""
+        row = pl.program_id(0)
+        count = walked_ref[row]
+
+        def copies(r, i, slot):
+            at = pl.ds(pl.multiple_of(base_ref[r] + i * tile, align), tile)
+            return (pltpu.make_async_copy(k_hbm.at[:, at], kbuf.at[slot],
+                                          arrived.at[0, slot]),
+                    pltpu.make_async_copy(v_hbm.at[:, at], vbuf.at[slot],
+                                          arrived.at[1, slot]))
+
+        def fetch(r, i, slot):
+            for copy in copies(r, i, slot):
+                copy.start()
+
+        @pl.when(row == 0)
+        def _():
+            turn[0] = 0
+            fetch(0, 0, 0)
+
+        first = turn[0]
+        top[...] = jnp.full(top.shape, NEG, f32)
+        norm[...] = jnp.zeros(norm.shape, f32)
+        acc[...] = jnp.zeros(acc.shape, f32)
+
+        def one_tile(i, seen):
+            slot = (first + i) % 2
+
+            @pl.when(i + 1 < count)
+            def _():
+                fetch(row, i + 1, 1 - slot)
+
+            @pl.when((i + 1 == count) & (row + 1 < rows))
+            def _():
+                fetch(row + 1, 0, 1 - slot)
+
+            for copy in copies(row, i, slot):
+                copy.wait()
+            pos = i * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+            ok = pos <= t_ref[row]
+            for h in range(g_heads):
+                s = jax.lax.dot_general(
+                    q_ref[h], kbuf[slot, h], (((1,), (1,)), ((), ())),
+                    preferred_element_type=f32) * scale        # [g, tile]
+                s = jnp.where(ok, s, NEG)
+                new_top = jnp.maximum(top[h], s.max(axis=-1, keepdims=True))
+                safe = jnp.where(new_top == NEG, 0.0, new_top)
+                e = jnp.where(ok, jnp.exp(s - safe), 0.0)
+                shrink = jnp.exp(top[h] - safe)
+                norm[h] = norm[h] * shrink + e.sum(axis=-1, keepdims=True)
+                acc[h] = acc[h] * shrink + jnp.dot(
+                    e.astype(q_ref.dtype), vbuf[slot, h],
+                    preferred_element_type=f32)
+                top[h] = new_top
+            return seen + jnp.sum(ok.astype(f32), axis=-1, keepdims=True)
+
+        seen = jax.lax.fori_loop(0, count, one_tile, jnp.zeros((1, 1), f32))
+        turn[0] = (first + count) % 2
+        o_ref[...] = acc[...] / jnp.maximum(norm[...], 1e-30)
+        n_ref[...] = jnp.broadcast_to(seen, n_ref.shape).astype(jnp.int32)
+
+    heads = (None, g_heads, group, d)
+    o, keys = pl.pallas_call(
+        walk,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(rows,),
+            in_specs=[pl.BlockSpec(heads, lambda r, *_: (r, 0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec(heads, lambda r, *_: (r, 0, 0, 0)),
+                       pl.BlockSpec((None, 1, 128), lambda r, *_: (r, 0, 0))],
+            scratch_shapes=[
+                pltpu.VMEM((2, g_heads, tile, d), kpool.dtype),
+                pltpu.VMEM((2, g_heads, tile, d), vpool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((g_heads, group, 1), f32),
+                pltpu.VMEM((g_heads, group, 1), f32),
+                pltpu.VMEM((g_heads, group, d), f32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
+                   jax.ShapeDtypeStruct((rows, 1, 128), jnp.int32)],
+        # rows in turn: the buffers and `turn` pass from one to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="attend_range",
+    )(base, t, walked, q, kpool, vpool)
+    return o, keys[:, 0, 0]
 
 
 def attend_tiles(q: jax.Array, t: jax.Array, allowed: jax.Array,

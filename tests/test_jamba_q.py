@@ -149,6 +149,7 @@ def test_prefill_then_decode_through_the_slot_state(built, chunk):
     assert counted["ssm_tokens_scanned"] == 4 * 53
     assert counted["attn_positions_read"] == 2 * sum(
         n * (n + 1) // 2 for n in lengths)
+    assert counted["attn_positions_fetched"] == 0    # a decode step's
     pos = np.asarray(lengths)
     scratch_slot, scratch_base = 4, 4 * MAX_LEN // BLOCK
     for _ in range(T - min(lengths)):
@@ -170,6 +171,12 @@ def test_prefill_then_decode_through_the_slot_state(built, chunk):
         assert c["ssm_rows_updated"] == 4 * live.sum()
         assert c["ssm_tokens_scanned"] == 0
         assert c["attn_positions_read"] == 2 * int((pos + 1)[live].sum())
+        # whole key tiles of 16 up to each row's own position (a row past
+        # its end walks its range's four and no further), one tile each
+        # padding row, in both attention layers
+        walked = np.minimum(pos // TILES[1] + 1, MAX_LEN // TILES[1])
+        assert c["attn_positions_fetched"] == 2 * TILES[1] * (
+            int(walked.sum()) + 2)
         pos = pos + live
     assert np.asarray(net.slot_lengths(state)).tolist() == [T, 0, T, 0, 0]
     # what the slots hold of the recurrence is the reference's h after
@@ -188,11 +195,13 @@ def test_prefill_then_decode_through_the_slot_state(built, chunk):
         assert mi == len(held) == 4
 
 
-def test_the_key_count_is_read_off_the_applied_mask(built, monkeypatch):
+@pytest.mark.parametrize("walk", ["attend_tiles", "attend_range"])
+def test_the_key_count_is_read_off_the_applied_mask(built, monkeypatch, walk):
     """`attn_positions_read` comes from the mask the op applied: a mask
     one position short (the query not seeing its own key) reads one key
     a query and attention layer less, which is what the reference's
-    `keys_attended` says of `attn_one_short`."""
+    `keys_attended` says of `attn_one_short` - in a prefill chunk's walk
+    and, summed inside its kernel, in a decode step's."""
     from ape_x_dqn_tpu.ops import block_select_attention as bsa
 
     net = built["net"]
@@ -202,18 +211,37 @@ def test_the_key_count_is_read_off_the_applied_mask(built, monkeypatch):
         attn_one_short=True))
     assert sound == 2 * sum(n * (n + 1) // 2 for n in lengths)
     assert sound - short == 2 * sum(lengths)
-    real = bsa.attend_tiles
+    real = getattr(bsa, walk)
 
-    def one_short(q, t, *rest, **kw):
-        return real(q, t - 1, *rest, **kw)
+    def one_short(q, *rest, **kw):
+        # t is attend_tiles' second argument and attend_range's fifth
+        at = {"attend_tiles": 0, "attend_range": 3}[walk]
+        rest = (*rest[:at], rest[at] - 1, *rest[at + 1:])
+        return real(q, *rest, **kw)
 
-    monkeypatch.setattr(bsa, "attend_tiles", one_short)
+    monkeypatch.setattr(bsa, walk, one_short)
     extend = jax.jit(lambda p, s, i: net.extend(p, s, i, max_len=MAX_LEN),
                      donate_argnums=(1,))
-    _, _, counted = _prefill(
+    state, _, counted = _prefill(
         {**built, "extend": extend}, net.slot_state(4, 4 * MAX_LEN, MAX_LEN),
         lengths, [2, 0], [8, 0], 16)
-    assert counted["attn_positions_read"] == short
+    if walk == "attend_tiles":
+        assert counted["attn_positions_read"] == short
+        return
+    assert counted["attn_positions_read"] == sound
+    steps = 3
+    for i in range(steps):
+        out, state = extend(built["params"], state, {
+            "obs": built["tokens"][:, 40 + i], "fresh": np.zeros(2, np.int32),
+            "slot": np.asarray([2, 0], np.int32),
+            "base": np.asarray([8, 0], np.int32)})
+        counted["attn_positions_read"] += int(
+            out["counters"]["attn_positions_read"])
+    after = [n + steps for n in lengths]
+    # the decoded positions' queries each missed their own key in each of
+    # the two attention layers
+    assert counted["attn_positions_read"] == ref.keys_attended(
+        after, built["sizes"]) - 2 * 2 * steps
 
 
 def test_sessions_do_not_mix_and_fresh_resets_both_kinds(built):
@@ -251,7 +279,9 @@ def test_padding_rows_touch_the_scratch_slot_only(built):
         "fresh": np.ones(4, np.int32), "n_valid": np.zeros(4, np.int32)})
     assert {k: int(v) for k, v in out["counters"].items()} == {
         "extend_tokens": 0, "ssm_rows_updated": 0, "ssm_tokens_scanned": 0,
-        "attn_positions_read": 0}
+        "attn_positions_read": 0,
+        # one key tile a padding row and attention layer: nobody reads it
+        "attn_positions_fetched": 4 * 2 * TILES[1]}
     after = jax.tree.map(np.asarray, state)
     for kind in ("ssm", "conv"):
         for a, b in zip(before[kind], after[kind]):

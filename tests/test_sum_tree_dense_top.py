@@ -560,23 +560,42 @@ def test_compiled_for_a_v5e_a_decode_step_moves_no_ssm_pool(
     [16, 5120] block, no copy of a [slots + 1, d_state, channels] pool,
     the whole state aliased, and the [rows, 16, 5120] float32 gather,
     `after` and scatter operand (40 MiB each a layer at 128 rows) gone
-    from the temp."""
+    from the temp. Since ISSUE 60 each attention layer of the published
+    widths is one `attend_range` kernel under `jamba.attn.attend` that
+    reads the key and value pools where they lie: no [rows, 1, 80, 128,
+    128] gather of either (320 MiB each a layer at 128 rows) is left.
+    The tiny preset's heads are 16 wide, not whole lanes, which the
+    chip's compiler refuses: its walk stays the interpreter's."""
+    from ape_x_dqn_tpu.ops import block_select_attention as bsa
     from ape_x_dqn_tpu.ops import selective_scan
 
+    published = preset == "jamba2_3b_q"
     monkeypatch.setattr(selective_scan, "_interpret", lambda: False)
+    if published:
+        monkeypatch.setattr(bsa, "_interpret", lambda: False)
     compiled, net, state = _compiled_decode_step(get_config(preset), rows,
                                                  one_chip)
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
                if "custom-call(" in line and "tpu_custom_call" in line]
-    assert len(kernels) == net.num_mamba
-    assert all("jamba.mamba/jamba.mamba.scan" in line for line in kernels)
+    scans = [line for line in kernels
+             if "jamba.mamba/jamba.mamba.scan" in line]
+    walks = [line for line in kernels
+             if "jamba.attn/jamba.attn.attend" in line]
+    assert len(scans) == net.num_mamba
+    assert len(walks) == (net.num_attention if published else 0)
+    assert len(kernels) == len(scans) + len(walks)
     pool = ",".join(str(n) for n in state["ssm"][0].shape)
     assert not re.search(rf"= f32\[{pool}\]\S* copy\(", text)
     memory = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
     assert memory.alias_size_in_bytes >= held       # `len` is padded
-    if preset == "jamba2_3b_q":
+    if published:
         assert net.num_mamba == 26 and pool == "257,16,5120"
-        # 0.611 GiB at PR 58 (1.382 with the gather, `step`, the scatter)
-        assert memory.temp_size_in_bytes < 0.75 * 2 ** 30
+        assert net.num_attention == 2 and "bf16[128,1,80,128,128]" not in text
+        keys = ",".join(str(n) for n in state["k"][0].shape)
+        assert keys == "1,2107392,128"
+        assert not re.search(rf"= bf16\[{keys}\]\S* copy\(", text)
+        # 0.177 GiB (0.611 at PR 58 with the attention layers' gathers,
+        # 1.382 before it with the state's gather, `step` and scatter)
+        assert memory.temp_size_in_bytes < 0.25 * 2 ** 30
